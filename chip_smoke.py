@@ -1,0 +1,624 @@
+"""Does the system still start on the chip? The quickest end-to-end proof.
+
+    python chip_smoke.py            # on a machine with a TPU; ~minutes cold
+
+Drives the main path once through the entry points a user calls, at the
+full width of the model every chip claim in this repo is about (Nature
+conv encoder + LSTM-512 + dueling heads, obs 84x84x1, B=64, T=85, bf16):
+
+  kernels   every pallas_call of ops/pallas_lstm.py at T=85, B=64, H=512,
+            fp32 and bf16, against the lax.scan LSTM (models/lstm.py)
+  train     python -m r2d2_tpu.train, fused megastep: on-device collection,
+            HBM replay ring, K=16 scanned updates, fused sequence kernel
+            forward + backward, deferred priorities, orbax save
+  resume    the same command with --resume in a NEW process: must continue
+            from the saved step and hit the persistent compile cache
+  serve     python -m r2d2_tpu.serve --dryrun on that checkpoint (every
+            bucket warmed, N requests answered, ckpt_step = trained step)
+  serve_tcp the same server behind its TCP frontend, PolicyClient.act calls
+  dp4       the train phase with --dp 4 --replay sharded, when four chips
+            are present (printed as skipped when not)
+
+It measures nothing: the seconds it prints are set-up observations, not
+speeds. It exits non-zero, printing no result line, when no TPU is found
+(no CPU fallback), when run outside a checkout, or when ANY phase fails —
+and a phase is judged by what it printed (steps, ckpt_step, request
+counts, finite losses), never by an exit code alone. On success the last
+line of stdout is one JSON object naming the device as jax reports it.
+
+One process holds the chip at a time: this parent never imports jax and
+runs the phases as sequential children (the one deliberate pair is the TCP
+server, which holds the chip, and its client, which is pinned to the CPU).
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import json
+import math
+import os
+import re
+import shutil
+import signal
+import subprocess
+import sys
+import time
+from typing import Dict, List, NamedTuple, Optional, Tuple
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+DEADLINE_S = 1150.0  # the contract allows 1200 s, compilation included
+
+
+class Shape(NamedTuple):
+    """What the phases run at. FULL is the chip size; a tiny Shape exists
+    only so the parent's control flow can be debugged on a CPU through
+    run_phases() — main() never uses one."""
+
+    preset: str
+    train_sets: Tuple[str, ...]
+    k: int                      # updates per dispatch
+    kernel_tbh: Tuple[int, int, int]
+
+
+FULL = Shape(
+    preset="atari",
+    # examples/catch_demo.py --full at 256 actors. 409,600 transitions =
+    # 1,024 block slots: the deferred-priority ring guard (megastep.py
+    # _init_protocol) needs more than 2*(2*256-1) = 1,022
+    train_sets=(
+        "max_episode_steps=82", "num_actors=256", "buffer_capacity=409600",
+        "learning_starts=40000",
+    ),
+    k=16,
+    kernel_tbh=(85, 64, 512),
+)
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+# --------------------------------------------------------------------------
+# child: the kernel phase (imports jax; holds the chip while it runs)
+# --------------------------------------------------------------------------
+
+
+def _kernels_child(T: int, B: int, H: int) -> int:
+    """Each pallas_call at (T, B, H), both precisions, against the scan
+    LSTM on identical params, to tests/test_pallas_lstm.py's tolerance
+    classes. Prints one `KERNEL {json}` verdict line per call x dtype."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from r2d2_tpu.models.lstm import LSTM
+
+    D = H + 3 + 1  # core input: latent + one-hot action + reward (catch)
+    rng = np.random.default_rng(0)
+    xs = jnp.asarray(rng.normal(size=(B, T, D)).astype(np.float32))
+    carry = (
+        jnp.asarray(rng.normal(size=(B, H)).astype(np.float32) * 0.2),
+        jnp.asarray(rng.normal(size=(B, H)).astype(np.float32) * 0.2),
+    )
+    # collect.py emits seam 0 (first window of a block) or the full burn-in;
+    # the leading rows walk the rest of the contract range [0, T-1]
+    edge = [0, 1, T // 2 - 1, T // 2 + 1, T - 1, T // 5, 5 % T, (2 * T) // 3]
+    burn = jnp.asarray(np.concatenate([
+        edge, np.where(np.arange(len(edge), B) % 4 == 0, 0, (T * 40) // 85),
+    ])[:B].astype(np.int32))
+    dev = jax.devices()[0]
+    print("KERNELS_ON " + json.dumps({
+        "platform": dev.platform, "device_kind": dev.device_kind,
+        "interpreted": jax.default_backend() != "tpu",
+    }), flush=True)
+
+    def loss(mod, p, burn_in):
+        outs, _ = mod.apply(p, xs, carry, burn_in=burn_in)
+        return jnp.sum(jnp.tanh(outs.astype(jnp.float32)))
+
+    strides = [s for s in range(2, T) if T % s == 0][:2] or [T]
+    cases = [
+        # (name, the pallas_call it exercises, module kwargs, fwd|grad, seam)
+        ("fwd", "_lstm_fwd_call", {}, "fwd", None),
+        ("bwd_step", "_lstm_bwd_call", {}, "grad", None),
+        ("seq_bwd", "_lstm_seq_bwd_call", {}, "grad", burn),
+        ("seq_bwd_fused_dwh", "_lstm_seq_bwd_fused_call",
+         {"fused_dwh": True}, "grad", burn),
+    ] + [
+        (f"seq_bwd_ckpt{s}", "_lstm_seq_bwd_ckpt_call",
+         {"grad_checkpoint": s}, "grad", burn)
+        for s in strides
+    ]
+    failed = 0
+    for dtype in (jnp.float32, jnp.bfloat16):
+        fp32 = dtype == jnp.float32
+        scan_mod = LSTM(hidden_dim=H, in_dim=D, dtype=dtype, backend="scan")
+        params = scan_mod.init(jax.random.PRNGKey(0), xs, carry)
+        # fp32 parity needs true f32 matmuls on BOTH sides (the TPU's
+        # default f32 dot is a bf16 pass); bf16 runs as production does
+        ctx = (
+            jax.default_matmul_precision("highest") if fp32
+            else contextlib.nullcontext()
+        )
+        with ctx:
+            ref_fwd = jax.jit(lambda p: scan_mod.apply(p, xs, carry))(params)
+            ref_grad = {
+                False: jax.jit(jax.grad(lambda p: loss(scan_mod, p, None)))(params),
+                True: jax.jit(jax.grad(lambda p: loss(scan_mod, p, burn)))(params),
+            }
+        for name, call, kw, kind, seam in cases:
+            mod = LSTM(hidden_dim=H, in_dim=D, dtype=dtype, backend="pallas", **kw)
+            row = {"case": name, "call": call, "dtype": jnp.dtype(dtype).name}
+            t0 = time.time()
+            try:
+                with ctx:
+                    if kind == "fwd":
+                        got = jax.jit(lambda p: mod.apply(p, xs, carry))(params)
+                        ref = ref_fwd
+                    else:
+                        got = jax.jit(jax.grad(lambda p: loss(mod, p, seam)))(params)
+                        ref = ref_grad[seam is not None]
+                    got = jax.block_until_ready(got)
+                worst_abs, worst_l2, finite = 0.0, 0.0, True
+                for a, r in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+                    a = np.asarray(a, np.float32)
+                    r = np.asarray(r, np.float32)
+                    finite &= bool(np.isfinite(a).all())
+                    # error relative to the tensor's own scale: gradients
+                    # here are sums over T*B terms reaching 1e3, where an
+                    # elementwise atol of 1e-5 is below one f32 ulp
+                    worst_abs = max(worst_abs, float(
+                        np.max(np.abs(a - r)) / (np.max(np.abs(r)) + 1e-6)
+                    ))
+                    worst_l2 = max(worst_l2, float(
+                        np.linalg.norm(a - r) / (np.linalg.norm(r) + 1e-6)
+                    ))
+                row.update(max_err_over_scale=worst_abs, rel_l2=worst_l2)
+                # the tests' classes at this shape: fp32 within rtol 1e-4
+                # of the tensor scale; bf16 forward within atol 3e-2 on
+                # O(0.7) values, bf16 grads relative L2 < 0.05
+                if fp32:
+                    ok = finite and worst_abs <= 1e-4
+                elif kind == "fwd":
+                    ok = finite and worst_abs <= 5e-2
+                else:
+                    ok = finite and worst_l2 < 0.05
+                row["verdict"] = "ok" if ok else "mismatch"
+            except Exception as e:  # noqa: BLE001 — the verdict IS the error
+                row["verdict"] = "refused"
+                row["error"] = f"{type(e).__name__}: {str(e)[:600]}"
+            row["secs"] = round(time.time() - t0, 2)
+            failed += row["verdict"] != "ok"
+            print("KERNEL " + json.dumps(row), flush=True)
+    print(f"KERNELS_DONE {2 * len(cases)}", flush=True)
+    return 1 if failed else 0
+
+
+# --------------------------------------------------------------------------
+# child: the TCP client (pinned to the CPU — the server holds the chip)
+# --------------------------------------------------------------------------
+
+
+def _client_child(port: int, requests: int, obs_shape: Tuple[int, ...]) -> int:
+    import numpy as np
+
+    from r2d2_tpu.serve.client import PolicyClient
+
+    rng = np.random.default_rng(0)
+    steps, actions = [], []
+    with PolicyClient(port=port, timeout=60.0) as client:
+        for i in range(requests):
+            resp = client.act(
+                f"tcp-{i % 2}", rng.integers(0, 255, obs_shape, np.uint8),
+                reward=0.0, reset=(i < 2), want_q=True,
+            )
+            if not np.isfinite(np.asarray(resp["q"], np.float32)).all():
+                print(f"non-finite q in response {i}: {resp}")
+                return 1
+            steps.append(resp["ckpt_step"])
+            actions.append(resp["action"])
+    print("CLIENT " + json.dumps({"answered": len(actions), "ckpt_steps": steps}))
+    return 0
+
+
+# --------------------------------------------------------------------------
+# parent: runs phases as children, judges them by what they printed
+# --------------------------------------------------------------------------
+
+
+class Runner:
+    def __init__(self, platform: str, shape: Shape, work: str, logs: str,
+                 extra_env: Optional[Dict[str, str]] = None):
+        self.platform = platform
+        self.shape = shape
+        self.work = work
+        self.logs = logs
+        self.t_start = time.time()
+        self.env = {**os.environ, **(extra_env or {})}
+        # jax's own handle: a child that cannot initialise this platform
+        # fails at start-up instead of falling back to the CPU (the chip
+        # machine itself exports JAX_PLATFORMS=tpu,cpu)
+        self.env["JAX_PLATFORMS"] = platform
+        self.env["PYTHONPATH"] = os.pathsep.join(
+            p for p in (ROOT, self.env.get("PYTHONPATH", "")) if p
+        )
+        self.env["PYTHONUNBUFFERED"] = "1"
+        self.rows: List[dict] = []       # one summary row per phase
+        self.runtime: Optional[dict] = None  # first [runtime] banner seen
+        self.procs: List[subprocess.Popen] = []
+
+    # ------------------------------------------------------------ processes
+
+    def _remaining(self, cap: float) -> float:
+        left = DEADLINE_S - (time.time() - self.t_start)
+        if left <= 5:
+            raise PhaseFailed("out of time: the 1200 s budget is spent")
+        return min(cap, left)
+
+    def _spawn(self, name: str, argv: List[str], env=None) -> Tuple[subprocess.Popen, str]:
+        log = os.path.join(self.logs, f"{name}.log")
+        f = open(log, "w")
+        proc = subprocess.Popen(
+            [sys.executable, *argv], cwd=ROOT, env=env or self.env,
+            stdout=f, stderr=subprocess.STDOUT, start_new_session=True,
+        )
+        f.close()  # the child holds its own descriptor
+        self.procs.append(proc)
+        return proc, log
+
+    @staticmethod
+    def _kill(proc: subprocess.Popen) -> None:
+        if proc.poll() is None:
+            try:
+                os.killpg(proc.pid, signal.SIGKILL)
+            except ProcessLookupError:
+                pass
+            proc.wait()
+
+    def kill_all(self) -> None:
+        for p in self.procs:
+            self._kill(p)
+
+    def _run(self, name: str, argv: List[str], cap: float, env=None,
+             check: bool = True) -> str:
+        """Run one child to its end; returns its combined output. A
+        non-zero exit fails the phase unless the caller judges the output
+        itself (check=False: the kernel phase prints its own verdicts)."""
+        proc, log = self._spawn(name, argv, env)
+        try:
+            rc = proc.wait(timeout=self._remaining(cap))
+        except subprocess.TimeoutExpired:
+            self._kill(proc)
+            raise PhaseFailed(f"{name}: no end within its time cap; killed "
+                              f"(log: {log})\n{_tail(log)}")
+        if rc != 0 and check:
+            raise PhaseFailed(f"{name}: exit code {rc}\n{_tail(log)}")
+        return _read(log)
+
+    # --------------------------------------------------------------- checks
+
+    def _check_runtime(self, name: str, text: str) -> dict:
+        info = _runtime_line(text)
+        if info is None:
+            raise PhaseFailed(f"{name}: printed no [runtime] banner")
+        if info["platform"] != self.platform:
+            raise PhaseFailed(
+                f"{name}: ran on {info['platform']!r}, not {self.platform!r}"
+            )
+        if self.platform == "tpu" and (
+            info["core"] != "pallas" or info["pallas_interpreted"]
+        ):
+            raise PhaseFailed(
+                f"{name}: the recurrent core is not a compiled Pallas kernel "
+                f"(core={info['core']}, interpreted={info['pallas_interpreted']})"
+            )
+        if self.runtime is None:
+            self.runtime = info
+        return info
+
+    def _train_args(self, ckpt: str, steps: int, metrics: str) -> List[str]:
+        sets = [*self.shape.train_sets, f"checkpoint_dir={ckpt}",
+                # a save at every dispatch: the run ends on a checkpoint
+                f"save_interval={self.shape.k}", "log_interval=0"]
+        argv = ["-m", "r2d2_tpu.train", "--preset", self.shape.preset,
+                "--env", "catch", "--mode", "fused",
+                "--updates-per-dispatch", str(self.shape.k),
+                "--steps", str(steps), "--metrics", metrics]
+        for s in sets:
+            argv += ["--set", s]
+        return argv
+
+    def _check_train(self, name: str, text: str, metrics: str, ckpt: str,
+                     start: int, steps: int) -> dict:
+        info = self._check_runtime(name, text)
+        if info["start_step"] != start:
+            # train.py starts from scratch, exit 0, when --resume finds nothing
+            raise PhaseFailed(
+                f"{name}: started at step {info['start_step']}, expected {start}"
+            )
+        if "replay snapshot failed" in text:
+            raise PhaseFailed(f"{name}: snapshot on exit failed (swallowed)")
+        recs = [json.loads(l) for l in _read(metrics).splitlines() if l.strip()]
+        if not recs:
+            raise PhaseFailed(f"{name}: no metrics record was logged")
+        bad = [r["step"] for r in recs
+               if not (math.isfinite(r["loss"]) and math.isfinite(r["q_mean"]))]
+        if bad:
+            raise PhaseFailed(f"{name}: non-finite loss/q at steps {bad}")
+        first, last = recs[0]["step"], recs[-1]["step"]
+        if first != start + self.shape.k or last != steps:
+            raise PhaseFailed(
+                f"{name}: logged steps {first}..{last}, expected "
+                f"{start + self.shape.k}..{steps}"
+            )
+        if recs[0].get("platform") != self.platform:
+            raise PhaseFailed(f"{name}: first metrics record carries no "
+                              "runtime stamp")
+        if not os.path.exists(
+            os.path.join(ckpt, f"step_{steps}", "_CHECKPOINT_METADATA")
+        ):
+            raise PhaseFailed(f"{name}: no finalized checkpoint step_{steps}")
+        return {"steps": f"{first}..{last}", "loss_last": recs[-1]["loss"],
+                **_cache_line(name, text)}
+
+    # --------------------------------------------------------------- phases
+
+    def phase(self, name: str, fn) -> None:
+        t0 = time.time()
+        row = {"phase": name}
+        try:
+            row.update(fn() or {})
+        finally:
+            row["wall_s"] = round(time.time() - t0, 1)
+            self.rows.append(row)
+            print(f"[chip_smoke] {json.dumps(row)}", flush=True)
+
+    def kernels(self) -> dict:
+        T, B, H = self.shape.kernel_tbh
+        # judged by its verdict lines (exit code 1 = some verdict not "ok")
+        text = self._run("kernels", [
+            os.path.join(ROOT, "chip_smoke.py"), "--phase", "kernels",
+            "--tbh", f"{T},{B},{H}",
+        ], 420, check=False)
+        on = [json.loads(l[11:]) for l in text.splitlines()
+              if l.startswith("KERNELS_ON ")]
+        verdicts = [json.loads(l[7:]) for l in text.splitlines()
+                    if l.startswith("KERNEL ")]
+        if not on or f"KERNELS_DONE {len(verdicts)}" not in text:
+            raise PhaseFailed(f"kernels: did not run to its end\n{text[-3000:]}")
+        if on[0]["platform"] != self.platform or (
+            self.platform == "tpu" and on[0]["interpreted"]
+        ):
+            raise PhaseFailed(f"kernels: ran as {on[0]}")
+        for v in verdicts:
+            print(f"[chip_smoke] kernel {v['call']:<26} {v['dtype']:<8} "
+                  f"{v['case']:<18} {v['verdict']}"
+                  + (f"  {v['error'][:300]}" if "error" in v else
+                     f"  err/scale={v['max_err_over_scale']:.2e} "
+                     f"rel_l2={v['rel_l2']:.2e}"), flush=True)
+        bad = [f"{v['case']}[{v['dtype']}]={v['verdict']}" for v in verdicts
+               if v["verdict"] != "ok"]
+        if bad:
+            raise PhaseFailed(f"kernels: {bad}")
+        return {"calls_ok": len(verdicts)}
+
+    def train(self) -> dict:
+        ckpt, m = os.path.join(self.work, "ckpt"), os.path.join(self.work, "m_train.jsonl")
+        steps = 3 * self.shape.k
+        text = self._run("train", self._train_args(ckpt, steps, m), 500)
+        self.trained_step = steps
+        return self._check_train("train", text, m, ckpt, 0, steps)
+
+    def resume(self) -> dict:
+        ckpt, m = os.path.join(self.work, "ckpt"), os.path.join(self.work, "m_resume.jsonl")
+        start, steps = self.trained_step, self.trained_step + 2 * self.shape.k
+        text = self._run(
+            "resume", [*self._train_args(ckpt, steps, m), "--resume"], 300
+        )
+        out = self._check_train("resume", text, m, ckpt, start, steps)
+        if out["cache_hits"] <= 0:
+            raise PhaseFailed(
+                "resume: the same programs in a second process hit the "
+                f"persistent compile cache 0 times ({out})"
+            )
+        self.trained_step = steps
+        return out
+
+    def _serve_args(self) -> List[str]:
+        eps = dict(s.split("=") for s in self.shape.train_sets)["max_episode_steps"]
+        return ["-m", "r2d2_tpu.serve", "--preset", self.shape.preset,
+                "--set", "env_name=catch", "action_dim=3",
+                f"max_episode_steps={eps}",
+                "--ckpt", os.path.join(self.work, "ckpt")]
+
+    def serve(self) -> dict:
+        n = 24
+        text = self._run("serve", [*self._serve_args(), "--dryrun", str(n)], 300)
+        self._check_runtime("serve", text)
+        m = re.search(r"dryrun ok: (\d+) requests, ckpt_step=(-?\d+)", text)
+        if not m:
+            raise PhaseFailed(f"serve: no dry-run result line\n{text[-2000:]}")
+        answered, step = int(m.group(1)), int(m.group(2))
+        if answered != n or step != self.trained_step:
+            # ckpt_step=-1 is fresh-init params: an empty --ckpt dir serves
+            # those and still exits 0
+            raise PhaseFailed(
+                f"serve: {answered}/{n} requests at ckpt_step={step}, "
+                f"expected {self.trained_step}"
+            )
+        return {"requests": answered, "ckpt_step": step,
+                **_cache_line("serve", text, "serve compile-cache")}
+
+    def serve_tcp(self) -> dict:
+        n = 6
+        server, log = self._spawn("serve_tcp", [*self._serve_args(), "--port", "0"])
+        try:
+            port = None
+            limit = time.time() + self._remaining(300)
+            while port is None:
+                m = re.search(r"listening on [\d.]+:(\d+)", _read(log))
+                if m:
+                    port = int(m.group(1))
+                elif server.poll() is not None:
+                    raise PhaseFailed(f"serve_tcp: server exited "
+                                      f"{server.returncode}\n{_tail(log)}")
+                elif time.time() > limit:
+                    raise PhaseFailed(f"serve_tcp: never listened\n{_tail(log)}")
+                else:
+                    time.sleep(0.5)
+            self._check_runtime("serve_tcp", _read(log))
+            # the client needs no chip, and must not ask for the one the
+            # server holds
+            client_env = {**self.env, "JAX_PLATFORMS": "cpu"}
+            obs = ",".join(map(str, _obs_shape(self.shape.preset)))
+            text = self._run("serve_tcp_client", [
+                os.path.join(ROOT, "chip_smoke.py"), "--phase", "client",
+                "--port", str(port), "--requests", str(n), "--obs-shape", obs,
+            ], 120, env=client_env)
+            got = [json.loads(l[7:]) for l in text.splitlines()
+                   if l.startswith("CLIENT ")]
+            if not got or got[0]["answered"] != n or any(
+                s != self.trained_step for s in got[0]["ckpt_steps"]
+            ):
+                raise PhaseFailed(f"serve_tcp: client saw {got}, expected {n} "
+                                  f"answers at ckpt_step={self.trained_step}")
+            os.killpg(server.pid, signal.SIGINT)  # the CLI's clean-stop path
+            try:
+                rc = server.wait(timeout=60)
+            except subprocess.TimeoutExpired:
+                raise PhaseFailed("serve_tcp: server did not stop on SIGINT")
+            if rc != 0:
+                raise PhaseFailed(f"serve_tcp: server exit {rc}\n{_tail(log)}")
+            return {"requests": n, "ckpt_step": self.trained_step, "port": port}
+        finally:
+            self._kill(server)
+
+    def dp4(self) -> dict:
+        count = self.runtime["device_count"]
+        if count < 4:
+            print(f"[chip_smoke] dp4 skipped: {count} chip(s)", flush=True)
+            return {"skipped": f"{count} chip(s)"}
+        ckpt, m = os.path.join(self.work, "ckpt_dp4"), os.path.join(self.work, "m_dp4.jsonl")
+        steps = 2 * self.shape.k
+        text = self._run("dp4", [
+            *self._train_args(ckpt, steps, m), "--dp", "4", "--replay", "sharded",
+        ], 500)
+        out = self._check_train("dp4", text, m, ckpt, 0, steps)
+        placed = [json.loads(l[12:]) for l in text.splitlines()
+                  if l.startswith("[placement] ")]
+        if not placed:
+            raise PhaseFailed("dp4: no [placement] line")
+        p = placed[0]
+        idle = [d for d, b in p["bytes_in_use"].items() if not b]
+        if len(p["params"]) < 4 or len(p["replay"]) < 4 or (
+            self.platform == "tpu" and idle
+        ):
+            raise PhaseFailed(f"dp4: work is not on four devices: {p}")
+        return {**out, "param_devices": p["params"], "replay_devices": p["replay"],
+                "bytes_in_use": p["bytes_in_use"]}
+
+
+def _read(path: str) -> str:
+    try:
+        with open(path, errors="replace") as f:
+            return f.read()
+    except FileNotFoundError:
+        return ""
+
+
+def _tail(path: str, n: int = 3000) -> str:
+    return _read(path)[-n:]
+
+
+def _runtime_line(text: str) -> Optional[dict]:
+    for line in text.splitlines():
+        if line.startswith("[runtime] "):
+            return json.loads(line[len("[runtime] "):])
+    return None
+
+
+def _cache_line(name: str, text: str, prefix: str = "compile-cache") -> dict:
+    m = re.search(
+        re.escape(f"[{prefix}]") + r" dir=(\S+) source=(\S+) hits=(\d+) misses=(\d+)",
+        text,
+    )
+    if not m:
+        raise PhaseFailed(f"{name}: printed no [{prefix}] line")
+    return {"cache_dir": m.group(1), "cache_source": m.group(2),
+            "cache_hits": int(m.group(3)), "cache_misses": int(m.group(4))}
+
+
+def _obs_shape(preset: str) -> Tuple[int, ...]:
+    from r2d2_tpu.config import PRESETS  # config only: no jax in the parent
+
+    return tuple(PRESETS[preset]().obs_shape)
+
+
+def run_phases(platform: str, shape: Shape,
+               extra_env: Optional[Dict[str, str]] = None) -> Runner:
+    """Run every phase in order; raises PhaseFailed at the first failure.
+    Scratch (checkpoints, metrics) lives in a git-ignored directory of the
+    checkout and is removed; the children's logs stay under chiprun_out/."""
+    work = os.path.join(ROOT, ".chip_smoke")
+    logs = os.path.join(ROOT, "chiprun_out", "chip_smoke")
+    shutil.rmtree(work, ignore_errors=True)
+    os.makedirs(work)
+    os.makedirs(logs, exist_ok=True)
+    r = Runner(platform, shape, work, logs, extra_env)
+    try:
+        for name in ("kernels", "train", "resume", "serve", "serve_tcp", "dp4"):
+            r.phase(name, getattr(r, name))
+    finally:
+        r.kill_all()
+        shutil.rmtree(work, ignore_errors=True)
+    return r
+
+
+def main() -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    # internal: the two child roles this file plays for itself
+    p.add_argument("--phase", choices=["kernels", "client"], help=argparse.SUPPRESS)
+    p.add_argument("--tbh", default="", help=argparse.SUPPRESS)
+    p.add_argument("--port", type=int, default=0, help=argparse.SUPPRESS)
+    p.add_argument("--requests", type=int, default=0, help=argparse.SUPPRESS)
+    p.add_argument("--obs-shape", default="", help=argparse.SUPPRESS)
+    args = p.parse_args()
+    if args.phase == "kernels":
+        return _kernels_child(*map(int, args.tbh.split(",")))
+    if args.phase == "client":
+        return _client_child(
+            args.port, args.requests, tuple(map(int, args.obs_shape.split(",")))
+        )
+
+    asked = os.environ.get("JAX_PLATFORMS", "")
+    if asked and "tpu" not in asked.split(","):
+        print(f"chip_smoke: FAILED: no TPU: JAX_PLATFORMS={asked!r} excludes "
+              "it, and this check never falls back to another backend",
+              file=sys.stderr)
+        return 1
+    # a terminated parent must still stop its children (they run in their
+    # own sessions): turn SIGTERM into an exit that unwinds run_phases
+    signal.signal(signal.SIGTERM, lambda *_: sys.exit(143))
+    t0 = time.time()
+    try:
+        r = run_phases("tpu", FULL)
+    except PhaseFailed as e:
+        print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
+        return 1
+    rt = r.runtime
+    print(f"[chip_smoke] ran on platform={rt['platform']} "
+          f"device_kind={rt['device_kind']!r} devices={rt['device_count']} "
+          f"core={rt['core']} pallas_interpreted={rt['pallas_interpreted']} "
+          f"backward_arm={rt['backward_arm']} replay_core={rt['replay_core']}; "
+          f"{len(r.rows)} phases in {time.time() - t0:.0f} s "
+          "(set-up observations, not speeds)", flush=True)
+    print(json.dumps({"ok": True, "device": {
+        "platform": rt["platform"], "kind": rt["device_kind"],
+        "count": rt["device_count"],
+    }}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

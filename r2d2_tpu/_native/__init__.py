@@ -1,18 +1,27 @@
 """ctypes loader for the native replay core (replay_core.cpp).
 
-Builds the shared library with g++ on first import if it is missing or
-older than the source (pybind11 is not in this image; plain C ABI +
-ctypes needs no build-time Python dependency at all). Thread/process safe
-via an atomic rename. `load_native()` returns a NativeReplayCore or None —
-every caller must tolerate None and fall back to the numpy path, so a
-missing toolchain degrades performance, never correctness.
+Builds the shared library with g++ on first import (pybind11 is not in
+this image; plain C ABI + ctypes needs no build-time Python dependency at
+all). The library is keyed on the SOURCE'S CONTENT — its file name
+carries a hash of replay_core.cpp — so what loads was built from exactly
+the source beside it: a stale or foreign .so that a tree copy brought
+along (git-ignored files travel with a directory copy; their mtimes may
+not) has another name and is never trusted. Thread/process safe via an
+atomic rename. `load_native()` returns a NativeReplayCore or None — every
+caller must tolerate None and fall back to the numpy path, so a missing
+toolchain degrades performance, never correctness; the fallback is said
+once on stderr with the compiler's message, and the trainer's start-up
+banner reports which core loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import sys
 import tempfile
 import threading
 from typing import Optional
@@ -21,7 +30,6 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "replay_core.cpp")
-_LIB = os.path.join(_DIR, "libreplay_core.so")
 
 _lock = threading.Lock()
 _core: Optional["NativeReplayCore"] = None
@@ -33,12 +41,22 @@ _f32p = np.ctypeslib.ndpointer(np.float32, flags="C_CONTIGUOUS")
 _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 
 
-def _build() -> bool:
-    """(Re)compile the .so if missing/stale. Returns True if usable."""
+def lib_path(src: str = _SRC) -> str:
+    """Where the library built from `src`'s current content lives."""
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(src), f"libreplay_core.{digest}.so")
+
+
+def _build() -> Optional[str]:
+    """Compile the library for the current source unless that exact build
+    exists. Returns its path, or None (reason on stderr) when it cannot
+    be built."""
     try:
-        if os.path.exists(_LIB) and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC):
-            return True
-        fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
+        lib = lib_path()
+        if os.path.exists(lib):
+            return lib
+        fd, tmp = tempfile.mkstemp(suffix=".so.tmp", dir=_DIR)
         os.close(fd)
         cmd = [
             "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-fopenmp",
@@ -51,11 +69,24 @@ def _build() -> bool:
             r = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
             if r.returncode != 0:
                 os.unlink(tmp)
-                return False
-        os.replace(tmp, _LIB)  # atomic: concurrent builders race benignly
-        return True
-    except (OSError, subprocess.SubprocessError):
-        return False
+                print(
+                    "[native] replay core build failed, using numpy: "
+                    + (r.stderr.strip().splitlines() or ["g++ failed"])[-1],
+                    file=sys.stderr,
+                )
+                return None
+        os.replace(tmp, lib)  # atomic: concurrent builders race benignly
+        for old in sorted(glob.glob(os.path.join(_DIR, "libreplay_core*.so"))):
+            if old != lib:  # builds of other source contents
+                try:
+                    os.unlink(old)
+                except OSError:
+                    pass
+        return lib
+    except (OSError, subprocess.SubprocessError) as e:
+        print(f"[native] replay core build failed, using numpy: {e!r}",
+              file=sys.stderr)
+        return None
 
 
 class NativeReplayCore:
@@ -168,17 +199,15 @@ def load_native() -> Optional[NativeReplayCore]:
     with _lock:
         if _core is not None or _load_failed:
             return _core
-        if not _build():
+        lib_file = _build()
+        if lib_file is None:
             _load_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_LIB)
-            _core = NativeReplayCore(lib)
-        except (OSError, AttributeError):
-            # AttributeError: a stale .so missing a newer entry point (e.g.
-            # hand-copied into an image whose mtime defeats the rebuild
-            # check) — degrade to the numpy path instead of crashing every
-            # importer, including pytest collection of the -m native tests
+            _core = NativeReplayCore(ctypes.CDLL(lib_file))
+        except OSError as e:
+            print(f"[native] replay core load failed, using numpy: {e!r}",
+                  file=sys.stderr)
             _load_failed = True
             return None
         return _core

@@ -82,11 +82,11 @@ Rule catalog (ids, severities — the table in ARCHITECTURE.md mirrors this):
   both stalls the dispatch pipeline per iteration and silently forks the
   host tree away from the device tree. The in-jit device ops
   (replay/device_sum_tree.py module functions) are not flagged.
-- raw-shard-map-import   (error)    a `jax.experimental.shard_map` import
-  anywhere outside parallel/jax_compat.py: every shard_map must come
-  through the version shim (check_rep/auto vs check_vma/axis_names), and
-  the manual tp×fsdp train step depends on the shim's axis_names=None ->
-  fully-manual defaulting.
+- raw-shard-map-import   (error)    a shard_map import from jax itself
+  (`from jax import shard_map`, or the removed `jax.experimental.
+  shard_map`) anywhere outside parallel/jax_compat.py: every shard_map
+  must come through that one wrapper, and the manual tp×fsdp train step
+  depends on its axis_names=None -> fully-manual defaulting.
 - codec-decode-in-hot-loop (warning) a block-codec decode
   (`decode_field` / `decode_block` / `read_block`) or an mmap page-in
   (`np.memmap` / `mmap.mmap`) inside a for/while body in the learner
@@ -921,11 +921,11 @@ def _rule_lock_discipline(tree: ast.AST, path: str) -> List[Finding]:
 
 def _rule_raw_shard_map_import(tree: ast.Module, path: str) -> List[Finding]:
     """Every shard_map must come through parallel/jax_compat.shard_map —
-    the version shim that maps the old check_rep/auto API onto the new
-    check_vma/axis_names one. A raw `jax.experimental.shard_map` import
-    anywhere else would pin one jax era's signature and silently diverge
-    from the shim's manual/auto-axis semantics (the tp×fsdp manual train
-    step depends on axis_names=None meaning FULLY manual)."""
+    the one wrapper that states the manual-axis convention (the tp×fsdp
+    manual train step depends on axis_names=None meaning FULLY manual).
+    A `from jax import shard_map` anywhere else skips that defaulting; a
+    `jax.experimental.shard_map` import is the removed older API
+    (check_rep/auto) on top of that."""
     norm = path.replace(os.sep, "/")
     if norm.endswith("parallel/jax_compat.py"):
         return []
@@ -939,10 +939,9 @@ def _rule_raw_shard_map_import(tree: ast.Module, path: str) -> List[Finding]:
                 path=path,
                 line=node.lineno,
                 col=node.col_offset,
-                message=f"{what} bypasses the parallel/jax_compat shim; "
-                "raw jax.experimental.shard_map pins one jax era's "
-                "signature (check_rep vs check_vma) and skips the shim's "
-                "manual-axis defaulting",
+                message=f"{what} bypasses the parallel/jax_compat wrapper "
+                "and its manual-axis defaulting (jax.experimental."
+                "shard_map is, besides, the removed check_rep/auto API)",
                 hint="from r2d2_tpu.parallel.jax_compat import shard_map",
             )
         )
@@ -952,10 +951,10 @@ def _rule_raw_shard_map_import(tree: ast.Module, path: str) -> List[Finding]:
             mod = node.module or ""
             if mod.startswith("jax.experimental.shard_map"):
                 flag(node, f"`from {mod} import ...`")
-            elif mod == "jax.experimental" and any(
+            elif mod in ("jax", "jax.experimental") and any(
                 a.name == "shard_map" for a in node.names
             ):
-                flag(node, "`from jax.experimental import shard_map`")
+                flag(node, f"`from {mod} import shard_map`")
         elif isinstance(node, ast.Import):
             for a in node.names:
                 if a.name.startswith("jax.experimental.shard_map"):

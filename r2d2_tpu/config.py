@@ -528,7 +528,7 @@ class R2D2Config:
     use_native_replay: bool = True  # C++ replay core if built, else numpy
     # replay data plane: "host" (numpy store, batches shipped per update),
     # "tiered" (full-capacity host store + double-buffered HBM staging
-    # pipeline hiding the tunnel behind the K-update scan;
+    # pipeline hiding the host->HBM copies behind the K-update scan;
     # replay/tiered_store.py), "device" (HBM store + fused in-jit gather,
     # single chip), "sharded" (HBM store sharded over the dp mesh axis +
     # shard_map train step), "multihost" (per-process local shards over a
@@ -618,6 +618,41 @@ class R2D2Config:
             return self.partitioning
         return "manual" if (self.tp_size > 1 and self.fsdp_size > 1) else "gspmd"
 
+    @property
+    def resolved_core_backend(self) -> str:
+        """"pallas" | "scan" | "lru": the recurrent-core implementation
+        this config runs on the process's jax backend. THE resolution of
+        lstm_backend="auto" — models/r2d2.from_config builds the net from
+        it, resolve_backward_arm budgets from it, and the entry-point
+        banner (utils/runtime.py) prints it, so the choice is never made
+        silently at trace time. auto = the fused Pallas kernel on a TPU,
+        lax.scan elsewhere, and scan wherever the update body sits under
+        a GSPMD-partitioned mesh axis: Mosaic refuses a pallas_call there
+        ("Mosaic kernels cannot be automatically partitioned"). On more
+        than one device that leaves the kernel to the bodies that are
+        fully manual — the sharded/multihost shard_map planes with every
+        non-dp axis of size 1 (parallel/mesh.dp_manual_axes) and the
+        manual-partitioned step; plain-jit planes over a dp mesh and
+        anything tp-sharded run the scan core."""
+        if self.recurrent_core != "lstm":
+            return self.recurrent_core
+        if self.lstm_backend != "auto":
+            return self.lstm_backend
+        if self.tp_shards_params:
+            return "scan"
+        if self.dp_size * self.tp_size * self.fsdp_size > 1 and not (
+            self.resolved_partitioning == "manual"
+            or (
+                self.replay_plane in ("sharded", "multihost")
+                and self.tp_size == 1
+                and self.fsdp_size == 1
+            )
+        ):
+            return "scan"
+        import jax  # deferred: config stays import-light
+
+        return "pallas" if jax.default_backend() == "tpu" else "scan"
+
     def resolve_backward_arm(self, batch_size: Optional[int] = None):
         """-> (arm, ckpt_stride): the backward arm the fused sequence
         kernel actually runs, with arm in {"default", "fused_dwh",
@@ -640,17 +675,14 @@ class R2D2Config:
             or not self.fused_sequence
         ):
             return ("default", 0)
-        backend = self.lstm_backend
-        if backend == "auto":
-            if self.tp_shards_params:
-                backend = "scan"  # models/r2d2.from_config's resolution
-            else:
-                import jax
-
-                backend = "pallas" if jax.default_backend() == "tpu" else "scan"
-        if backend != "pallas":
+        if self.resolved_core_backend != "pallas":
             return ("default", 0)
-        from r2d2_tpu.ops.pallas_lstm import choose_backward_arm
+        import jax
+
+        from r2d2_tpu.ops.pallas_lstm import (
+            choose_backward_arm,
+            vmem_capacity_bytes,
+        )
 
         B = self.batch_size if batch_size is None else batch_size
         # residuals live per device: the batch shards over dp (and over
@@ -665,6 +697,11 @@ class R2D2Config:
             self.resolved_compute_dtype,
             self.backward_residual_budget_mb * (1 << 20),
             mode=self.backward_arm,
+            # compiled kernels live under the device's VMEM; the
+            # interpreter (explicit pallas off-TPU) has none to respect
+            vmem_bytes=(
+                vmem_capacity_bytes() if jax.default_backend() == "tpu" else None
+            ),
         )
 
     @property

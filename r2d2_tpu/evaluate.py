@@ -335,6 +335,9 @@ def main(argv=None):
         cfg = cfg.replace(env_name=args.env)
     if args.set:
         cfg = cfg.replace(**parse_overrides(args.set))
+    from r2d2_tpu.utils.runtime import print_runtime_banner
+
+    print_runtime_banner("evaluate", cfg)
 
     fn_env = pick_device_eval_env(cfg, args.evaluator)
     if fn_env is not None:

@@ -313,9 +313,10 @@ def make_fused_multi_train_step(
 
     Exactly equivalent to running the K single fused steps sequentially on
     the same pre-drawn coordinates (pinned by test) — the host simply was
-    not involved between them. This is the dispatch-latency amortizer: on
-    hardware where each jit call costs ~milliseconds of launch/tunnel
-    latency, per-update overhead drops K-fold. The semantic trade is that
+    not involved between them. This is the dispatch-overhead amortizer: the
+    host's per-call launch cost is paid once per K updates (whether that
+    still pays on a directly attached chip is a benchmark question,
+    ROADMAP S2). The semantic trade is that
     priorities and new blocks apply to the tree at K-update granularity —
     the reference's own pipeline already tolerates a deeper lag (its batch
     queue + learner prefetch hold ~12 batches, reference worker.py:364-371).
@@ -393,6 +394,7 @@ def make_sharded_fused_multi_train_step(
     update with a pmin over dp (the multihost K-dispatch path)."""
     from jax.sharding import PartitionSpec as P
     from r2d2_tpu.parallel.jax_compat import shard_map
+    from r2d2_tpu.parallel.mesh import dp_manual_axes
 
     multi = make_multi_update_core(
         cfg, net, num_steps, axis_name="dp", is_from_priorities=is_from_priorities
@@ -405,16 +407,17 @@ def make_sharded_fused_multi_train_step(
 
     # P("dp") is a PREFIX spec for the stores dict: it applies to every
     # field array (same idiom as make_sharded_fused_train_step).
-    # axis_names={"dp"}: the map is MANUAL over dp only — the mesh's tp
-    # axis stays GSPMD-auto, so params arriving with tp NamedShardings
-    # (parallel/mesh.train_state_shardings) are Megatron-partitioned
-    # inside the per-dp-shard body by the compiler, composing dp×tp.
+    # dp_manual_axes: with tp > 1 the map is MANUAL over dp only — the
+    # mesh's tp axis stays GSPMD-auto, so params arriving with tp
+    # NamedShardings (parallel/mesh.train_state_shardings) are
+    # Megatron-partitioned inside the per-dp-shard body by the compiler,
+    # composing dp×tp; with tp == 1 it is fully manual (Pallas core).
     sharded = shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P("dp"), P(None, "dp"), P(None, "dp"), P(None, "dp")),
         out_specs=(P(), P(), P(None, "dp")),
-        axis_names={"dp"},
+        axis_names=dp_manual_axes(mesh),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
@@ -483,6 +486,7 @@ def make_sharded_gather_step(cfg: R2D2Config, mesh):
     step (XLA inserts the gradient psum)."""
     from jax.sharding import PartitionSpec as P
     from r2d2_tpu.parallel.jax_compat import shard_map
+    from r2d2_tpu.parallel.mesh import dp_manual_axes
 
     gather_batch = make_store_gather(cfg)
 
@@ -499,7 +503,7 @@ def make_sharded_gather_step(cfg: R2D2Config, mesh):
         mesh=mesh,
         in_specs=(P("dp"), P("dp"), P("dp"), P("dp")),
         out_specs=out_specs,
-        axis_names={"dp"},
+        axis_names=dp_manual_axes(mesh),
         check_vma=False,
     )
     return jax.jit(gathered)
@@ -535,6 +539,7 @@ def make_sharded_fused_train_step(
     collective finds the global min."""
     from jax.sharding import PartitionSpec as P
     from r2d2_tpu.parallel.jax_compat import shard_map
+    from r2d2_tpu.parallel.mesh import dp_manual_axes
 
     raw = _raw_train_step(cfg, net, axis_name="dp")
     gather_batch = make_store_gather(cfg)
@@ -562,7 +567,7 @@ def make_sharded_fused_train_step(
         mesh=mesh,
         in_specs=(P(), P("dp"), P("dp"), P("dp"), P("dp")),
         out_specs=(P(), P(), P("dp")),
-        axis_names={"dp"},
+        axis_names=dp_manual_axes(mesh),
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())

@@ -114,12 +114,13 @@ class R2D2Network(nn.Module):
 
     @classmethod
     def from_config(cls, cfg: R2D2Config, manual_tp: int = 1) -> "R2D2Network":
-        # GSPMD cannot partition around the Pallas unroll, so auto resolves
-        # to scan exactly where the kernels are tp-sharded (shard_map
-        # planes keep params replicated and keep the fused kernel)
-        backend = cfg.lstm_backend
-        if cfg.tp_shards_params and backend == "auto":
-            backend = "scan"
+        # "auto" is resolved HERE, once, by the config's own rule — the
+        # module never picks a backend at trace time on this path
+        backend = (
+            cfg.resolved_core_backend
+            if cfg.recurrent_core == "lstm"
+            else cfg.lstm_backend
+        )
         # the fused-kernel backward arm actually run: explicit legacy
         # knobs verbatim, else the backward_arm budget selector
         arm, stride = cfg.resolve_backward_arm()

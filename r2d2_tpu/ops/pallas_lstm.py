@@ -34,12 +34,20 @@ preferred_element_type=float32 (bfloat16 feeds the MXU at double rate).
 On non-TPU backends the kernels run in Pallas interpret mode, which is how
 the CPU test suite pins forward/gradient parity against the lax.scan
 reference implementation (models/lstm.py).
+
+VMEM: Mosaic's default scoped-VMEM limit (16 MiB) is below what the
+backward kernels need at the production shape — the first compile on a
+v5e refused every fp32 backward at B=64, H=512 ("Scoped allocation with
+size 21.41M and limit 16.00M" for the default arm, 28.22M fused-dWh,
+33.78M ckpt). Every call therefore passes `vmem_limit_bytes` sized from
+its own blocks (`_compiler_params`), and a call whose blocks cannot fit
+the device's VMEM at all raises here, by name, instead of inside Mosaic.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +58,48 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _nbytes(shape, dtype) -> int:
+    return int(np.prod(shape, dtype=np.int64)) * jnp.dtype(dtype).itemsize
+
+
+def kernel_vmem_bytes(blocks, scratch, B: int, H: int, wh_dtype) -> int:
+    """Upper estimate of one call's scoped-VMEM need: every pipelined
+    in/out block double-buffered, the scratch, and the kernel body's
+    temporaries — the loaded `wh` value plus its transposed copy (the
+    dz @ wh.T carry matmul) plus slack, and a dozen (B, 4H) f32 gate /
+    pre-activation-grad arrays. `blocks` and `scratch` are (shape, dtype)
+    lists. Checked against the v5e compiler's own totals at T=85, B=64,
+    H=512 fp32: it asked 21.4 / 28.2 / 33.8 MiB (default / fused-dWh /
+    ckpt S=5) where this gives 31 / 43 / 53."""
+    pipelined = 2 * sum(_nbytes(s, d) for s, d in blocks)
+    held = sum(_nbytes(s, d) for s, d in scratch)
+    body = 3 * _nbytes((H, 4 * H), wh_dtype) + 12 * _nbytes((B, 4 * H), jnp.float32)
+    return pipelined + held + body + (1 << 20)
+
+
+def vmem_capacity_bytes() -> int:
+    """Per-core VMEM of the attached TPU, from jax's own device table
+    (an unknown device kind raises there — no assumed default)."""
+    return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+
+
+def _compiler_params(what: str, blocks, scratch, B: int, H: int, wh_dtype,
+                     interpret: bool):
+    """CompilerParams carrying this call's VMEM limit; None under the
+    interpreter (no VMEM to budget, and no TPU to ask for its size)."""
+    if interpret:
+        return None
+    need = kernel_vmem_bytes(blocks, scratch, B, H, wh_dtype)
+    cap = vmem_capacity_bytes()
+    if need > cap:
+        raise ValueError(
+            f"{what}: blocks need ~{need >> 20} MiB of VMEM at B={B}, H={H} "
+            f"but the device has {cap >> 20} MiB — shard the batch (dp), "
+            "or for the checkpointed backward pick a shorter segment"
+        )
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
 def _split_gates(z: jnp.ndarray, H: int):
@@ -91,9 +141,18 @@ def _fwd_kernel(proj_ref, wh_ref, h0_ref, c0_ref, outs_ref, cs_ref, h_s, c_s):
 def _lstm_fwd_call(proj_t, wh, h0, c0, *, interpret: bool):
     T, B, fourH = proj_t.shape
     H = fourH // 4
+    f32 = jnp.float32
+    params = _compiler_params(
+        "lstm forward kernel",
+        [((1, B, 4 * H), proj_t.dtype), ((H, 4 * H), wh.dtype),
+         ((B, H), h0.dtype), ((B, H), c0.dtype),
+         ((1, B, H), proj_t.dtype), ((1, B, H), f32)],
+        [((B, H), f32)] * 2, B, H, wh.dtype, interpret,
+    )
     outs, cs = pl.pallas_call(
         _fwd_kernel,
         grid=(T,),
+        compiler_params=params,
         in_specs=[
             pl.BlockSpec((1, B, 4 * H), lambda t: (t, 0, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((H, 4 * H), lambda t: (0, 0), memory_space=pltpu.VMEM),
@@ -173,9 +232,19 @@ def _lstm_bwd_call(dout, proj_t, hprev, cprev, cs, wh, dcT, *, interpret: bool):
     T, B, H = cs.shape
     rev3 = lambda t: (T - 1 - t, 0, 0)
     pinned = lambda t: (0, 0)
+    f32 = jnp.float32
+    params = _compiler_params(
+        "lstm backward kernel",
+        [((1, B, H), dout.dtype), ((1, B, 4 * H), proj_t.dtype),
+         ((1, B, H), hprev.dtype), ((1, B, H), f32), ((1, B, H), f32),
+         ((H, 4 * H), wh.dtype), ((B, H), f32),
+         ((1, B, 4 * H), f32), ((B, H), f32), ((B, H), f32)],
+        [((B, H), f32)] * 2, B, H, wh.dtype, interpret,
+    )
     dz, dh0, dc0 = pl.pallas_call(
         _bwd_kernel,
         grid=(T,),
+        compiler_params=params,
         in_specs=[
             pl.BlockSpec((1, B, H), rev3, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, B, 4 * H), rev3, memory_space=pltpu.VMEM),
@@ -334,14 +403,41 @@ def _seq_bwd_kernel(
     dc_s[:] = jnp.where(carry_keep, dc * f, 0.0)
 
 
+def _seq_bwd_vmem_spec(fused_dwh: bool, B, H, dout_dtype, proj_dtype,
+                       h_dtype, wh_dtype):
+    """(blocks, scratch) of the per-step-grid sequence backward calls —
+    their BlockSpecs and scratch_shapes as (shape, dtype) for the VMEM
+    estimate. The fused-dWh arm emits dz in the proj dtype and adds the
+    pinned f32 dWh output plus its f32 accumulator scratch."""
+    f32 = jnp.float32
+    blocks = [
+        ((1, B, H), dout_dtype), ((1, B, 4 * H), proj_dtype),
+        ((1, B, H), h_dtype), ((1, B, H), f32), ((1, B, H), f32),
+        ((H, 4 * H), wh_dtype), ((B, H), f32), ((B, 128), jnp.int32),
+        ((1, B, 4 * H), proj_dtype if fused_dwh else f32),
+    ]
+    scratch = [((B, H), f32)] * 2
+    if fused_dwh:
+        blocks.append(((H, 4 * H), f32))
+        scratch.append(((H, 4 * H), f32))
+    return blocks, scratch
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _lstm_seq_bwd_call(dout, proj_t, hprev, cprev, cs, wh, dcT, burn, *, interpret: bool):
     T, B, H = cs.shape
     rev3 = lambda t: (T - 1 - t, 0, 0)
     pinned = lambda t: (0, 0)
+    params = _compiler_params(
+        "lstm sequence backward kernel (default arm)",
+        *_seq_bwd_vmem_spec(False, B, H, dout.dtype, proj_t.dtype, hprev.dtype,
+                            wh.dtype),
+        B, H, wh.dtype, interpret,
+    )
     (dz,) = pl.pallas_call(
         _seq_bwd_kernel,
         grid=(T,),
+        compiler_params=params,
         in_specs=[
             pl.BlockSpec((1, B, H), rev3, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, B, 4 * H), rev3, memory_space=pltpu.VMEM),
@@ -509,9 +605,16 @@ def _lstm_seq_bwd_fused_call(
     T, B, H = cs.shape
     rev3 = lambda t: (T - 1 - t, 0, 0)
     pinned = lambda t: (0, 0)
+    params = _compiler_params(
+        "lstm sequence backward kernel (fused_dwh arm)",
+        *_seq_bwd_vmem_spec(True, B, H, dout.dtype, proj_t.dtype, hprev.dtype,
+                            wh.dtype),
+        B, H, wh.dtype, interpret,
+    )
     dz, dwh = pl.pallas_call(
         _seq_bwd_fused_kernel,
         grid=(T,),
+        compiler_params=params,
         in_specs=[
             pl.BlockSpec((1, B, H), rev3, memory_space=pltpu.VMEM),
             pl.BlockSpec((1, B, 4 * H), rev3, memory_space=pltpu.VMEM),
@@ -690,6 +793,21 @@ def _seq_bwd_ckpt_kernel(
     dwh_ref[:] = dwh_s[:]
 
 
+def _ckpt_bwd_vmem_spec(S, B, H, dout_dtype, proj_dtype, h_dtype, wh_dtype):
+    """(blocks, scratch) of the checkpointed backward call for segment
+    length S — whole S-step segments are VMEM blocks, so this is the arm
+    whose need grows with the stride (choose_backward_arm reads it)."""
+    f32 = jnp.float32
+    blocks = [
+        ((S, B, H), dout_dtype), ((S, B, 4 * H), proj_dtype),
+        ((1, B, H), h_dtype), ((1, B, H), f32),
+        ((H, 4 * H), wh_dtype), ((B, H), f32), ((B, 128), jnp.int32),
+        ((S, B, 4 * H), proj_dtype), ((H, 4 * H), f32),
+    ]
+    scratch = [((S + 1, B, H), f32)] * 2 + [((B, H), f32)] * 2 + [((H, 4 * H), f32)]
+    return blocks, scratch
+
+
 @functools.partial(jax.jit, static_argnames=("S", "interpret"))
 def _lstm_seq_bwd_ckpt_call(
     dout, proj_t, h_ckpt, c_ckpt, wh, dcT, burn, *, S: int, interpret: bool
@@ -698,9 +816,16 @@ def _lstm_seq_bwd_ckpt_call(
     N = T // S
     revseg3 = lambda k: (N - 1 - k, 0, 0)
     pinned = lambda k: (0, 0)
+    params = _compiler_params(
+        f"lstm sequence backward kernel (ckpt arm, segment {S})",
+        *_ckpt_bwd_vmem_spec(S, B, H, dout.dtype, proj_t.dtype, h_ckpt.dtype,
+                             wh.dtype),
+        B, H, wh.dtype, interpret,
+    )
     dz, dwh = pl.pallas_call(
         functools.partial(_seq_bwd_ckpt_kernel, S=S),
         grid=(N,),
+        compiler_params=params,
         in_specs=[
             pl.BlockSpec((S, B, H), revseg3, memory_space=pltpu.VMEM),
             pl.BlockSpec((S, B, 4 * H), revseg3, memory_space=pltpu.VMEM),
@@ -817,7 +942,8 @@ def seq_backward_residual_bytes(T: int, B: int, H: int, proj_dtype,
 
 
 def choose_backward_arm(
-    T: int, B: int, H: int, proj_dtype, budget_bytes: int, mode: str = "auto"
+    T: int, B: int, H: int, proj_dtype, budget_bytes: int, mode: str = "auto",
+    vmem_bytes: Optional[int] = None,
 ) -> Tuple[str, int]:
     """Pick the sequence backward arm from a peak-residual-bytes budget.
 
@@ -836,16 +962,42 @@ def choose_backward_arm(
     checkpoints but whole-segment gate recompute). When no stride fits,
     the largest divisor (minimum possible residual) is used — the budget
     is a selection dial, not a hard allocator. mode="fused_dwh"/"ckpt"/
-    "default" force that arm (ckpt still auto-picks S)."""
+    "default" force that arm (ckpt still auto-picks S).
+
+    `vmem_bytes` (the device's VMEM, config.resolve_backward_arm passes
+    it on a TPU) is the hard side: the HBM budget alone once walked B=256
+    fp32 to ("ckpt", 85) — one segment, the whole sequence as VMEM
+    blocks. With it, a ckpt stride whose blocks cannot fit is never a
+    candidate, `auto` steps past an arm that cannot fit, and when nothing
+    fits the error says so here rather than inside the compiler. None
+    (the interpreter: no VMEM) skips the check."""
     itemsize = jnp.dtype(proj_dtype).itemsize
+    f32 = jnp.float32
     dz_f32 = T * B * 4 * H * 4
     dz_proj = T * B * 4 * H * itemsize
     carry_full = seq_backward_residual_bytes(T, B, H, proj_dtype)[
         "carry_residual_bytes"
     ]
+    # the calls' own block lists at this shape (dout arrives f32; proj, h
+    # and wh share the compute dtype)
+    dtypes = (f32, proj_dtype, proj_dtype, proj_dtype)
+
+    def fits(blocks, scratch) -> bool:
+        return vmem_bytes is None or (
+            kernel_vmem_bytes(blocks, scratch, B, H, proj_dtype) <= vmem_bytes
+        )
 
     def ckpt_stride() -> int:
-        divisors = [s for s in range(2, T + 1) if T % s == 0]
+        divisors = [
+            s for s in range(2, T + 1)
+            if T % s == 0
+            and fits(*_ckpt_bwd_vmem_spec(s, B, H, *dtypes))
+        ]
+        if not divisors and T > 1:
+            raise ValueError(
+                f"no checkpoint segment of T={T} fits {vmem_bytes >> 20} MiB "
+                f"of VMEM at B={B}, H={H}: shard the batch (dp)"
+            )
         for s in divisors:
             peak = (
                 seq_backward_residual_bytes(T, B, H, proj_dtype, s)[
@@ -865,8 +1017,12 @@ def choose_backward_arm(
         return ("ckpt", ckpt_stride())
     if mode != "auto":
         raise ValueError(f"unknown backward-arm mode {mode!r}")
-    if carry_full + dz_f32 <= budget_bytes:
+    if carry_full + dz_f32 <= budget_bytes and fits(
+        *_seq_bwd_vmem_spec(False, B, H, *dtypes)
+    ):
         return ("default", 0)
-    if carry_full + dz_proj <= budget_bytes:
+    if carry_full + dz_proj <= budget_bytes and fits(
+        *_seq_bwd_vmem_spec(True, B, H, *dtypes)
+    ):
         return ("fused_dwh", 0)
     return ("ckpt", ckpt_stride())

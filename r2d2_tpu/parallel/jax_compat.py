@@ -1,39 +1,22 @@
-"""Shims over jax API drift so the parallel planes run on both the
-current jax (`jax.shard_map`, `check_vma=`/`axis_names=`) and the older
-releases that only ship `jax.experimental.shard_map.shard_map`
-(`check_rep=`/`auto=`). Every shard_map call in the codebase routes
-through here instead of importing from jax directly."""
+"""The one place `shard_map` is imported from jax.
+
+Every shard_map call in the codebase routes through this wrapper (the
+`raw-shard-map-import` lint, analysis/ast_rules.py, keeps it so) so the
+manual-axis convention is stated once: `axis_names` is the set of mesh
+axes the body is manual over, any other mesh axis stays GSPMD-auto, and
+`axis_names=None` means FULLY manual (the tp x fsdp train step depends on
+that). `jax.shard_map` is the top-level export of the installed jax
+(pyproject.toml pins the floor); no older-API branch is kept."""
 
 from __future__ import annotations
 
-try:  # jax >= 0.6: top-level export with the new kwarg names
-    from jax import shard_map as _new_shard_map
-except ImportError:  # older jax: experimental module, old kwarg names
-    _new_shard_map = None
-    from jax.experimental.shard_map import shard_map as _old_shard_map
+from jax import shard_map as _shard_map
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, axis_names=None):
-    """New-style shard_map signature, translated for old jax.
-
-    `axis_names` is the set of mesh axes the body is manual over; any
-    other mesh axis stays GSPMD-auto (old API: the `auto` frozenset is
-    the complement). `check_vma` maps to the old `check_rep`."""
-    if _new_shard_map is not None:
-        kwargs = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return _new_shard_map(f, **kwargs)
-    auto = frozenset()
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _old_shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
-        auto=auto,
+    kwargs = dict(
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return _shard_map(f, **kwargs)

@@ -53,6 +53,21 @@ def make_mesh(
     )
 
 
+def dp_manual_axes(mesh: Mesh):
+    """`axis_names` for the dp-sharded planes' shard_maps (the sharded and
+    multihost train steps and megasteps). While another mesh axis has
+    size > 1 the map is manual over dp ONLY, so GSPMD keeps partitioning
+    tp-sharded kernels inside each dp shard. When every other axis has
+    size 1 there is nothing left for GSPMD to partition and the map is
+    made FULLY manual (None) — which the TPU demands: Mosaic refuses a
+    pallas_call under any auto axis, even a size-1 one ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — the first --dp 4 run on real chips). config.
+    resolved_core_backend picks the Pallas core exactly in that case."""
+    others = [a for a in mesh.axis_names if a != "dp" and mesh.shape[a] > 1]
+    return {"dp"} if others else None
+
+
 def batch_sharding(mesh: Mesh) -> NamedSharding:
     """Leading axis over dp, rest replicated."""
     return NamedSharding(mesh, P("dp"))

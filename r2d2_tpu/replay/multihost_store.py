@@ -419,10 +419,7 @@ class MultiHostShardedReplay:
             new_state, metrics, priorities = multi_fn(
                 state, self.global_stores(), b, s, w
             )
-        try:
-            priorities.copy_to_host_async()
-        except AttributeError:
-            pass
+        priorities.copy_to_host_async()
         prev, self._pending = self._pending, (priorities, draws)
         if prev is not None:
             self.drain_pending(prev)

@@ -70,11 +70,6 @@ def main(argv=None) -> int:
     p.add_argument("--dryrun", type=int, default=0, metavar="N",
                    help="serve N synthetic requests in-process (no TCP) "
                         "and exit 0 — the multi-device smoke path")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="persistent XLA compilation cache directory "
-                        "(R2D2_COMPILE_CACHE env var is the same knob) — "
-                        "amortizes the bucket-warmup compiles across "
-                        "server restarts")
     args = p.parse_args(argv)
 
     from r2d2_tpu.utils.compilation_cache import (
@@ -82,7 +77,10 @@ def main(argv=None) -> int:
         log_compile_cache_stats,
     )
 
-    enable_compilation_cache(args.compile_cache)
+    # amortizes the bucket-warmup compiles across server restarts; the
+    # directory rule (JAX_COMPILATION_CACHE_DIR, else the checkout on a
+    # TPU) lives in utils/compilation_cache.py
+    enable_compilation_cache()
     cfg = PRESETS[args.preset]()
     if args.set:
         cfg = cfg.replace(**parse_overrides(args.set))
@@ -93,6 +91,9 @@ def main(argv=None) -> int:
     if args.autoscale:
         cfg = cfg.replace(serve_autoscale=True)
     cfg = cfg.validate()
+    from r2d2_tpu.utils.runtime import print_runtime_banner
+
+    print_runtime_banner("serve", cfg)
     serve_cfg = ServeConfig(
         buckets=tuple(args.buckets),
         max_wait_ms=args.max_wait_ms,
@@ -107,12 +108,20 @@ def main(argv=None) -> int:
         # and the router only exist on the multi-device server
         server = MultiDeviceServer(cfg, serve_cfg, checkpoint_dir=args.ckpt,
                                    metrics=metrics)
+        # the replica -> device map, so a fleet sharing one chip shows
+        # (MultiDeviceServer._pick_device co-locates when none is free)
+        placed = " ".join(
+            f"{r.name}->{d}" for r, d in zip(server.replicas, server.devices)
+        )
+        shared = len(server.devices) - len(set(server.devices))
         print(f"[serve] {cfg.serve_devices} replicas"
               + (" (elastic, "
                  f"{cfg.autoscale_min_replicas}.."
                  f"{cfg.autoscale_max_replicas})" if cfg.serve_autoscale
                  else "")
-              + f": {[str(d) for d in server.devices]}", file=sys.stderr)
+              + f": {placed}"
+              + (f" ({shared} co-located on an already used device)"
+                 if shared else ""), file=sys.stderr)
     else:
         server = PolicyServer(cfg, serve_cfg, checkpoint_dir=args.ckpt,
                               metrics=metrics)

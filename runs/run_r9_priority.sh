@@ -13,19 +13,18 @@
 #      host_ms_per_update pair: the host-thread cost of the priority
 #      plane per update under priority_plane=host (numpy sample +
 #      write-back on the critical path) vs =device (dispatch-only).
-#   3. learner headline   — best-of-matrix with vs_r05 (trajectory vs
-#      BENCH_r05.json's 1004177.5), unchanged machinery: the synthetic-
-#      feed ceiling the system rows are read against.
+#   3. learner headline   — best-of-matrix, unchanged machinery: the
+#      synthetic-feed ceiling the system rows are read against.
 #   4. system A/B         — the full system (concurrent on-device
 #      collection + learning) three ways: priority_plane=host (the
 #      per-update host fence), =device N=1 (fence in-jit), =device N=4
 #      (host re-enters every 64 updates). Each row carries
-#      priority_plane/superstep_dispatches and vs_r05.
+#      priority_plane/superstep_dispatches.
 #
 # PRE-REGISTERED read: rung 4's device rows beating its host row is the
-# tentpole's claim on real hardware, and the device N=4 row's vs_r05
-# > 1.0 (full-system learner rate above the round-5 synthetic-feed
-# headline, which paid no replay fence at all) is the BENCH_r09 headline.
+# tentpole's claim on real hardware, and the device N=4 row passing
+# rung 3's synthetic-feed learner rate (which pays no replay fence at
+# all) is the headline read.
 # Rung 2's host_ms_per_update["priority_plane=device"] collapsing to
 # dispatch cost (~0.1ms-class vs the host arm's tree walk) is the
 # mechanism check behind that read.
@@ -52,7 +51,7 @@ echo "=== RUNG 2: per-phase breakdown (host_ms_per_update pair) ==="
 python bench.py --mode breakdown | tee -a "$OUT"
 echo "=== BREAKDOWN EXIT: $? ==="
 
-echo "=== RUNG 3: learner headline (vs_r05) ==="
+echo "=== RUNG 3: learner headline ==="
 python bench.py --mode learner --precision both | tee -a "$OUT"
 echo "=== LEARNER EXIT: $? ==="
 

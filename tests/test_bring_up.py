@@ -1,0 +1,153 @@
+"""The rules PR 21 (first run on the chip) pinned, all CPU and near-free:
+one compile-cache directory rule, chip_smoke.py never falling back, the
+native core keyed on its source's content. (The launch-counter rule sits
+with the other analysis tests, tests/test_analysis.py.)"""
+
+import ast
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+from r2d2_tpu.utils import compilation_cache as cc
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+# ------------------------------------------------- compile-cache directory
+
+
+def test_cache_dir_rule():
+    """Variable set -> the program sets nothing (jax already has its
+    directory); unset on a TPU -> <checkout>/.jax_cache; unset elsewhere
+    -> no cache."""
+    env = {"JAX_COMPILATION_CACHE_DIR": "/some/dir"}
+    assert cc.cache_dir_to_set(env, "tpu") is None
+    assert cc.cache_dir_to_set(env, "cpu") is None
+    assert cc.cache_dir_to_set({}, "tpu") == os.path.join(REPO, ".jax_cache")
+    assert cc.cache_dir_to_set({"JAX_COMPILATION_CACHE_DIR": ""}, "tpu") == (
+        os.path.join(REPO, ".jax_cache")
+    )
+    assert cc.cache_dir_to_set({}, "cpu") is None
+
+
+def _py_files():
+    for root in ("r2d2_tpu", "runs", "examples"):
+        for d, _, names in os.walk(os.path.join(REPO, root)):
+            yield from (os.path.join(d, n) for n in sorted(names) if n.endswith(".py"))
+    yield from (os.path.join(REPO, n) for n in ("bench.py", "chip_smoke.py",
+                                                 "__graft_entry__.py"))
+
+
+def test_no_second_cache_rule_anywhere():
+    """enable_compilation_cache takes no directory (so no mkdtemp, pid or
+    time can feed it — a directory that moves never hits), nobody else
+    writes jax's cache-dir option, and the duplicate variable is gone."""
+    rule_home = os.path.join(REPO, "r2d2_tpu", "utils", "compilation_cache.py")
+    for path in _py_files():
+        with open(path) as f:
+            src = f.read()
+        assert "R2D2_COMPILE_CACHE" not in src, path
+        for node in ast.walk(ast.parse(src)):
+            if not isinstance(node, ast.Call):
+                continue
+            name = getattr(node.func, "attr", getattr(node.func, "id", ""))
+            if name == "enable_compilation_cache":
+                assert not node.args and not node.keywords, (
+                    f"{path}:{node.lineno} passes a directory to "
+                    "enable_compilation_cache"
+                )
+            if name == "update" and node.args and isinstance(
+                node.args[0], ast.Constant
+            ) and node.args[0].value == "jax_compilation_cache_dir":
+                assert path == rule_home, (
+                    f"{path}:{node.lineno} sets the compile-cache directory "
+                    "outside utils/compilation_cache.py"
+                )
+
+
+# ------------------------------------------------------- chip_smoke.py
+
+
+def _run_smoke(cwd, env):
+    return subprocess.run(
+        [sys.executable, os.path.join(cwd, "chip_smoke.py")], cwd=cwd, env=env,
+        capture_output=True, text=True, timeout=120,
+    )
+
+
+def test_chip_smoke_refuses_cpu():
+    """The no-fallback rule: held to the CPU it exits non-zero in seconds,
+    names the missing chip, and prints no result line."""
+    r = _run_smoke(REPO, {**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr and "JAX_PLATFORMS='cpu'" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+def test_chip_smoke_alone_fails(tmp_path):
+    """In a directory holding chip_smoke.py and nothing else of the repo
+    the first child cannot import the package: non-zero, no result."""
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_PLATFORMS", "PYTHONPATH")}
+    r = _run_smoke(str(tmp_path), env)
+    assert r.returncode != 0
+    assert "FAILED" in r.stderr and "kernels" in r.stderr
+    assert '"ok"' not in r.stdout
+
+
+# ------------------------------------------------------- native replay core
+
+
+def test_native_library_is_keyed_on_source_content(tmp_path):
+    """The library's name carries a hash of replay_core.cpp, so a build of
+    other source (a stale .so a tree copy brought along, whatever its
+    mtime) has another name and is never the one loaded."""
+    from r2d2_tpu import _native
+
+    a, b = tmp_path / "a.cpp", tmp_path / "b.cpp"
+    a.write_text("int f() { return 1; }\n")
+    b.write_text("int f() { return 2; }\n")
+    assert _native.lib_path(str(a)) != _native.lib_path(str(b))
+    b.write_text("int f() { return 1; }\n")
+    os.utime(b, (0, 0))  # mtime plays no part
+    assert os.path.basename(_native.lib_path(str(a))) == os.path.basename(
+        _native.lib_path(str(b))
+    )
+    core = _native.load_native()
+    if core is None:
+        pytest.skip("no C++ toolchain: numpy core")
+    assert core._lib._name == _native.lib_path()
+
+
+# ------------------------------------------- where the Pallas core may live
+
+
+def test_pallas_core_only_where_no_mesh_axis_is_auto(monkeypatch):
+    """Mosaic refuses a pallas_call under any GSPMD-auto mesh axis (the
+    first --dp 4 run on real chips). So on a TPU `auto` picks the kernel
+    on one device and under the fully-manual dp planes, and the scan core
+    everywhere else — and those planes' shard_maps are fully manual
+    exactly when every non-dp axis has size 1."""
+    import jax
+
+    from r2d2_tpu.config import tiny_test
+    from r2d2_tpu.parallel.mesh import dp_manual_axes, make_mesh
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    base = tiny_test()
+    assert base.resolved_core_backend == "pallas"
+    assert base.replace(recurrent_core="lru").resolved_core_backend == "lru"
+    assert base.replace(lstm_backend="scan").resolved_core_backend == "scan"
+    sharded = base.replace(dp_size=4, replay_plane="sharded", buffer_capacity=1280)
+    assert sharded.resolved_core_backend == "pallas"
+    assert sharded.replace(dp_size=2, tp_size=2).resolved_core_backend == "scan"
+    # plain-jit plane over a dp mesh: GSPMD partitions the whole step
+    assert base.replace(dp_size=4).resolved_core_backend == "scan"
+
+    devices = jax.devices()
+    assert dp_manual_axes(make_mesh(dp=4, tp=1, devices=devices[:4])) is None
+    assert dp_manual_axes(make_mesh(dp=2, tp=2, devices=devices[:4])) == {"dp"}
