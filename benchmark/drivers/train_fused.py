@@ -1,0 +1,200 @@
+"""Driver kind `train_fused`: the whole actor-learner loop, as one process.
+
+The body of `Trainer.run_fused` (train.py) driven from here: `Trainer(cfg)`,
+`warmup()` (ring fill by on-device collection), then `FusedSystemRunner` or
+`ShardedFusedRunner.step` in a loop, paced by the program's own
+samples_per_insert rule. A closed system: there is no client.
+
+Copied from bench.py::fused_system_main, with its count repaired: env steps
+are counted 1:1 (it multiplied by an Atari frameskip of 4 on an env that has
+none), and the configurations use an env whose episodes fill every block (its
+82-step catch episodes sat in 400-step slots, so most "learned" steps were
+masked padding).
+
+The window covers whole collect periods. The pacer collects in bursts (two
+consecutive collecting dispatches, because chunk accounting lags one
+dispatch), so a period boundary is the first dispatch that records no chunk
+after one that did: there the consumed:inserted ratio is back where it was at
+the previous boundary. The window opens at the first boundary (both dispatch
+variants have run by then), and closes at the last boundary before
+`--seconds` is up; each end is a readback of `state.step`, so every dispatch
+counted has finished on the device.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import time
+from typing import List
+
+import numpy as np
+
+from benchmark import correct, harness
+
+
+def _make_runner(trainer, cfg):
+    from r2d2_tpu.megastep import FusedSystemRunner, ShardedFusedRunner
+
+    common = dict(collect_every=1, chunk_len=trainer.actor.chunk,
+                  sample_rng=trainer.sample_rng, samples_per_insert=cfg.samples_per_insert)
+    args = (cfg, trainer.net, trainer.fn_env, trainer.replay, trainer.actor.epsilons,
+            trainer.actor.env_state, trainer.actor.key)
+    if cfg.replay_plane == "sharded":
+        return ShardedFusedRunner(*args, trainer.mesh, **common)
+    if cfg.replay_plane == "device":
+        return FusedSystemRunner(*args, **common)
+    raise harness.BenchmarkError(f"train_fused needs replay_plane device|sharded, got {cfg.replay_plane}")
+
+
+def _sync(state) -> int:
+    return int(np.asarray(state.step))
+
+
+def _planes(replay) -> list:
+    return list(getattr(replay, "shards", [replay]))
+
+
+def valid_step_share(replay, learning_steps: int) -> float:
+    """Stored learning steps over (stored sequences x learning_steps), from
+    the control plane's own per-block counters: the share of a sampled
+    window that is not padding."""
+    steps = seqs = 0
+    for p in _planes(replay):
+        occ = np.asarray(p.occupied, bool)
+        steps += int(np.asarray(p.learning_sum)[occ].sum())
+        seqs += int(np.asarray(p.num_seq_store)[occ].sum())
+    return steps / max(seqs * learning_steps, 1)
+
+
+def _sample_batch(cfg, trainer, n: int, seed: int):
+    """`n` stored sequences drawn through the replay's own sampler with a
+    generator of the benchmark's (the trainer's stream is left alone),
+    gathered from the device store and brought to the host."""
+    import jax
+    import jax.numpy as jnp
+
+    from r2d2_tpu.learner import make_store_gather
+
+    idx = trainer.replay.sample_indices(np.random.default_rng(seed))
+    b, s = np.asarray(idx.b), np.asarray(idx.s)
+    if b.ndim == 2:  # sharded: (dp, B/dp) block slots LOCAL to each shard
+        per = cfg.num_blocks // b.shape[0]
+        b = b + (np.arange(b.shape[0]) * per)[:, None]
+    b, s = b.reshape(-1)[:n].astype(np.int32), s.reshape(-1)[:n].astype(np.int32)
+    gather = jax.jit(make_store_gather(cfg))
+    batch = trainer.replay.run_with_stores(
+        lambda st: gather(st, jnp.asarray(b), jnp.asarray(s), jnp.ones(b.shape[0], jnp.float32)))
+    return jax.device_get(batch)  # host arrays: the check then runs on one device
+
+
+def run(ctx: harness.Context) -> harness.Measured:
+    import jax
+
+    from r2d2_tpu.train import Trainer
+
+    cell, tr_cfg = ctx.cell, ctx.cell.traffic
+    extra = {
+        "samples_per_insert": float(tr_cfg["samples_per_insert"]),
+        "training_steps": 10**9, "save_interval": 10**9, "log_interval": 3600.0,
+        "checkpoint_dir": ctx.work_dir("ckpt", cell.name), "metrics_path": None,
+    }
+    cfg = harness.build_config(cell.config, ctx.seed, extra)
+    cfg = cfg.replace(learning_starts=int(cfg.buffer_capacity * float(tr_cfg["fill_fraction"])))
+    ctx.cfg = cfg
+    runtime = harness.check_runtime(cfg, cell.workload["chips"], ctx.require_tpu)
+    print(f"[bench] {cell.name} runtime {runtime}", flush=True)
+
+    trainer = Trainer(cfg)
+    t = time.perf_counter()
+    trainer.warmup()
+    fill_s = time.perf_counter() - t
+    print(f"[bench] ring filled to {len(trainer.replay)} of {cfg.buffer_capacity} "
+          f"in {fill_s:.1f}s", flush=True)
+    runner = _make_runner(trainer, cfg)
+    state = trainer.state
+    K = cfg.updates_per_dispatch
+    prev_rec = 0
+
+    def at_boundary(rec: int) -> bool:
+        """True on the first dispatch that records no chunk after one that
+        did (every dispatch when the pacer is off and each one collects)."""
+        nonlocal prev_rec
+        hit = cfg.samples_per_insert <= 0 or (rec == 0 and prev_rec > 0)
+        prev_rec = rec
+        return hit
+
+    # ---- warm-up: until the first period boundary (both variants have run)
+    losses: List[object] = []
+    seen_collect, warm = False, 0
+    while True:
+        state, m, rec = runner.step(state)
+        warm += 1
+        seen_collect |= rec > 0
+        if at_boundary(rec) and seen_collect:
+            break
+        if warm > int(tr_cfg.get("max_warm_dispatches", 400)):
+            raise harness.BenchmarkError("no collect period boundary during warm-up")
+    _sync(state)
+    compiles0 = harness.compile_requests()
+    ctx.counters["cli.compile_misses"] = harness.compile_misses()
+    setup_s = time.perf_counter() - ctx.t_start
+
+    # ---- the window: whole periods, at most `seconds` (one period at least)
+    budget = ctx.seconds
+    if ctx.trace:
+        budget = min(budget, float(tr_cfg.get("trace_seconds", 8.0)))
+    dispatches = 0
+    period_s, last_boundary_t = None, 0.0
+    with harness.Tracer(ctx) if ctx.trace else contextlib.nullcontext():
+        t0 = time.perf_counter()
+        while True:
+            with harness.span("bench.step"):
+                state, m, rec = runner.step(state)
+            dispatches += 1
+            losses.append(m["loss"])
+            if not at_boundary(rec):
+                continue
+            now = time.perf_counter() - t0
+            period_s, last_boundary_t = now - last_boundary_t, now
+            if now + period_s > budget:
+                break
+        with harness.span("bench.sync"):
+            _sync(state)
+        elapsed = time.perf_counter() - t0
+    compiles_in_window = harness.compile_requests() - compiles0
+    ctx.counters["memory_peak_bytes"] = harness.memory_peak_bytes()
+    runner.finish()
+
+    updates = dispatches * K
+    steps_per_update = cfg.batch_size * cfg.learning_steps
+    loss_host = np.asarray(jax.device_get(losses), np.float32)
+    share = valid_step_share(trainer.replay, cfg.learning_steps)
+    ctx.counters.update({
+        "updates": updates, "dispatches": dispatches, "window_s": elapsed,
+        "updates_per_s": updates / elapsed, "replay.valid_step_share": 100.0 * share,
+        "compiles_in_window": compiles_in_window,
+    })
+
+    # ---- correct: outside the window
+    n_seq = int(tr_cfg.get("correct_sequences", 8))
+    checks = {
+        "kernels": correct.kernels_vs_scan(cfg, ctx.seed, max(cfg.batch_size // max(cfg.dp_size, 1), 1)),
+        "reference": correct.system_vs_reference(
+            cfg, trainer.net, jax.device_get(state), _sample_batch(cfg, trainer, n_seq, ctx.seed)),
+    }
+    finite = bool(np.isfinite(loss_host).all())
+    ok = (finite and compiles_in_window == 0 and all(c["ok"] for c in checks.values())
+          and share >= float(tr_cfg.get("min_valid_step_share", 0.0)))
+    print(f"[bench] {updates} updates in {elapsed:.2f}s over {dispatches} dispatches, "
+          f"period {period_s:.2f}s, loss {loss_host[-1]:.5f}, checks {checks}", flush=True)
+    from r2d2_tpu.utils.compilation_cache import log_compile_cache_stats
+
+    log_compile_cache_stats()
+    return harness.Measured(
+        correct=ok, attempted=updates, failed=int((~np.isfinite(loss_host)).sum()) * K,
+        end_to_end={"learn_steps_per_s": updates * steps_per_update / elapsed, "setup_s": setup_s},
+        notes={"checks": checks, "window_s": elapsed, "dispatches": dispatches,
+               "period_s": period_s, "ring_fill_s": fill_s, "valid_step_share": share,
+               "compiles_in_window": compiles_in_window, "runtime": runtime,
+               "loss_last": float(loss_host[-1]), "warm_dispatches": warm},
+    )

@@ -1,0 +1,211 @@
+"""The drivers end to end at tiny size on the CPU, through the same
+`run_cell` the CLI calls — in a temporary COPY of the benchmark to which a
+throw-away configuration, traffic mix and `workloads` entries are ADDED as
+files and manifest entries only (no existing file is edited: that a later PR
+can add a cell as data is the point). Also: the CLI's refusal of a CPU."""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from benchmark import flops, harness
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+TINY = {"env_name": "drift", "action_dim": 3, "max_episode_steps": 16, "collector": "device",
+        "replay_plane": "device", "updates_per_dispatch": 2, "num_actors": 2}
+SERVE_LAYER_METRICS = ["device.idle_share_serve", "serve.batch_occupancy", "serve.device_ms_per_batch",
+                       "loadgen.late_p99_ms"]
+
+
+@pytest.fixture(scope="module")
+def tmp_root(tmp_path_factory):
+    root = str(tmp_path_factory.mktemp("benchroot"))
+    shutil.copytree(os.path.join(ROOT, "benchmark"), os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        m = json.load(fh)
+    before = {os.path.join(d, f): os.path.getmtime(os.path.join(d, f))
+              for d, _, fs in os.walk(os.path.join(root, "benchmark")) for f in fs}
+    configs = {
+        "tiny": TINY,
+        "tiny-lru": dict(TINY, recurrent_core="lru"),
+        "tiny-bf16": dict(TINY, compute_dtype="bfloat16"),
+        "tiny-dp4": dict(TINY, replay_plane="sharded", dp_size=4, num_actors=4, buffer_capacity=2560),
+    }
+    for name, over in configs.items():
+        with open(os.path.join(root, "benchmark", "configs", name + ".json"), "w") as fh:
+            json.dump({"name": name, "source": "test", "preset": "tiny_test", "overrides": over,
+                       "reduced": []}, fh)
+        m["configs"].append({"name": name, "source": "test", "why": "test", "reduced": [],
+                             "file": f"benchmark/configs/{name}.json"})
+        m["workloads"].append({"name": name + ".learn", "config": name, "traffic": "learn",
+                               "chips": 4 if name == "tiny-dp4" else 1, "why": "test"})
+    with open(os.path.join(root, "benchmark", "traffic", "serve-tiny.json"), "w") as fh:
+        json.dump({"driver": "serve_open_loop", "rate_per_s": 150.0, "sessions": 16,
+                   "cache_capacity": 64, "buckets": [2, 4], "max_wait_ms": 2.0, "queue_depth": 64,
+                   "correct_sessions": 2, "correct_steps": 6, "trace_seconds": 0.4}, fh)
+    m["workloads"].append({"name": "tiny.serve-tiny", "config": "tiny", "traffic": "serve-tiny",
+                           "chips": 1, "why": "test"})
+    learn = [w["name"] for w in m["workloads"] if w["name"].startswith("tiny") and w["name"].endswith(".learn")]
+    for e in m["end_to_end"] + m["per_layer"]:
+        if "workloads" in e:
+            e["workloads"] = e["workloads"] + learn
+    # the serve cell comes back as entries only: its metric, the layer files that
+    # are kept for it, and one new layer file (the device's idle share beside it)
+    m["end_to_end"].append({"name": "serve_p99_ms", "unit": "ms", "better": "lower", "bound": 0.1,
+                            "source": "host_clock", "workloads": ["tiny.serve-tiny"]})
+    with open(os.path.join(root, "benchmark", "layers", "device.idle_share_serve.json"), "w") as fh:
+        json.dump({"name": "device.idle_share_serve", "layer": "device", "unit": "%",
+                   "moves": "serve_p99_ms", "reader": "trace_idle"}, fh)
+    for name in SERVE_LAYER_METRICS:
+        spec = harness.load_json(os.path.join(root, "benchmark", "layers", name + ".json"))
+        m["per_layer"].append({"name": name, "unit": spec["unit"], "better": "lower", "source": "host_clock",
+                               "layer": spec["layer"], "moves": spec["moves"], "workloads": ["tiny.serve-tiny"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:
+        json.dump(m, fh)
+    yield root
+    # nothing that existed was edited
+    for path, mtime in before.items():
+        assert os.path.getmtime(path) == mtime, path
+
+
+def _check_line(r, metrics):
+    assert set(r) >= {"correct", "attempted", "failed", "metrics", "device"}
+    json.dumps(r)  # serialisable as it stands
+    assert r["correct"] is True and r["failed"] == 0 and r["attempted"] > 0
+    assert set(r["metrics"]) == set(metrics)
+    for v in r["metrics"].values():
+        assert set(v) == {"value", "unit"} and np.isfinite(v["value"]) and v["value"] > 0
+    assert set(r["device"]) >= {"platform", "kind", "count", "memory_peak_bytes"}
+
+
+@pytest.fixture(scope="module")
+def learn_result(tmp_root):
+    return harness.run_cell(tmp_root, "tiny.learn", seed=3, seconds=1.0, trace=False, require_tpu=False)
+
+
+def test_learn_last_line_contract(learn_result):
+    _check_line(learn_result, ["learn_steps_per_s", "setup_s"])
+    n = learn_result["notes"]
+    assert n["compiles_in_window"] == 0 and n["valid_step_share"] == 1.0
+    # steps are counted 1:1: updates x batch x learning_steps / window
+    got = learn_result["metrics"]["learn_steps_per_s"]["value"]
+    assert got == pytest.approx(learn_result["attempted"] * 8 * 4 / n["window_s"])
+    assert n["window_s"] <= 1.0 + n["period_s"]
+
+
+def test_learn_matches_plain_reference(learn_result):
+    ref = learn_result["notes"]["checks"]["reference"]
+    assert ref["ok"] and ref["sequences"] == 8
+    assert ref["q_err_over_scale"] < 1e-5 and ref["loss_rel"] < 1e-5 and ref["grad_norm_rel"] < 1e-5
+    assert "skipped" in learn_result["notes"]["checks"]["kernels"]  # scan core on a CPU
+
+
+def test_lru_core_matches_plain_reference(tmp_root):
+    r = harness.run_cell(tmp_root, "tiny-lru.learn", seed=4, seconds=0.5, trace=False, require_tpu=False)
+    _check_line(r, ["learn_steps_per_s", "setup_s"])
+    ref = r["notes"]["checks"]["reference"]
+    assert ref["q_err_over_scale"] < 1e-4 and ref["loss_rel"] < 1e-4 and ref["grad_norm_rel"] < 1e-4
+    assert r["notes"]["runtime"]["core"] == "lru"
+
+
+def test_bf16_program_sits_between_the_two_tolerance_classes(tmp_root):
+    """A bf16 program against the f32 reference passes the bf16 limits and
+    would fail the float32 ones: the limits tell the precisions apart."""
+    from benchmark import correct
+
+    r = harness.run_cell(tmp_root, "tiny-bf16.learn", seed=5, seconds=0.5, trace=False, require_tpu=False)
+    ref = r["notes"]["checks"]["reference"]
+    assert r["correct"] and ref["ok"]
+    assert ref["q_err_over_scale"] > correct.TOL["float32"]["q"]
+    assert ref["q_err_over_scale"] < correct.TOL["bfloat16"]["q"]
+
+
+def test_dp4_driver_on_virtual_devices(tmp_root):
+    r = harness.run_cell(tmp_root, "tiny-dp4.learn", seed=6, seconds=0.5, trace=False, require_tpu=False)
+    _check_line(r, ["learn_steps_per_s", "setup_s"])
+    assert r["device"]["count"] >= 4 and r["notes"]["checks"]["reference"]["ok"]
+
+
+def test_more_chips_than_present_is_refused(tmp_root, monkeypatch):
+    import jax
+
+    monkeypatch.setattr(jax, "devices", lambda *a: jax.local_devices()[:1])
+    with pytest.raises(harness.BenchmarkError, match="needs 4 chips"):
+        harness.run_cell(tmp_root, "tiny-dp4.learn", 0, 0.2, False, require_tpu=False)
+
+
+def test_a_cpu_is_refused_unless_a_test_says_otherwise(tmp_root):
+    with pytest.raises(harness.BenchmarkError, match="no TPU"):
+        harness.run_cell(tmp_root, "tiny.learn", 0, 0.2, False, require_tpu=True)
+    with pytest.raises(harness.BenchmarkError, match="no workload"):
+        harness.run_cell(tmp_root, "nope", 0, 0.2, False, require_tpu=False)
+
+
+@pytest.fixture(scope="module")
+def cpu_trace_patterns(tmp_root):
+    """On a CPU the XLA ops sit on the host plane's client threads; the
+    patterns file is data, so the temporary copy gets one that says so."""
+    path = os.path.join(tmp_root, "benchmark", "trace_patterns_cpu.json")
+    with open(os.path.join(tmp_root, "benchmark", "trace_patterns.json")) as fh:
+        pats = json.load(fh)
+    pats.update(device_plane="^/host:CPU$", op_lines=["^tf_XLA"], module_lines=["^no such line$"])
+    with open(path, "w") as fh:
+        json.dump(pats, fh)
+    return path
+
+
+def test_serve_last_line_contract_and_reference(tmp_root):
+    r = harness.run_cell(tmp_root, "tiny.serve-tiny", seed=3, seconds=1.0, trace=False, require_tpu=False)
+    _check_line(r, ["serve_p99_ms", "setup_s"])
+    n = r["notes"]
+    assert n["check"]["ok"] and n["check"]["steps"] == 6 and n["check"]["q_err_over_scale_all"] < 1e-5
+    assert n["compiles_in_window"] == 0 and n["rejected"] == 0 and n["cache_hit_rate"] == 100.0
+    assert n["late_p99_ms"] >= 0.0 and n["requests"] == r["attempted"]
+    assert n["p99_ms"] >= n["p50_ms"] > 0 and r["metrics"]["serve_p99_ms"]["value"] == n["p99_ms"]
+
+
+def test_traced_runs_yield_layer_metrics_and_breakdown(tmp_root, cpu_trace_patterns, monkeypatch, tmp_path):
+    from benchmark import trace as tr
+
+    real = tr.load_patterns
+    monkeypatch.setattr(tr, "load_patterns", lambda path=None: real(cpu_trace_patterns))
+    peaks = tmp_path / "peaks.json"
+    peaks.write_text(json.dumps({"cpu": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}))
+    monkeypatch.setattr(flops, "_PEAKS_PATH", str(peaks))
+    r = harness.run_cell(tmp_root, "tiny.serve-tiny", seed=3, seconds=0.4, trace=True, require_tpu=False)
+    # every per-layer metric listed for the serve cell is read, and no other
+    assert r["correct"] and set(r["metrics"]) == set(SERVE_LAYER_METRICS) | {"cli.compile_misses"}
+    assert 0 < r["device"]["busy_s"] < r["device"]["window_s"]
+    assert len(r["breakdown"]["device_ops"]) <= 10 and r["breakdown"]["idle_gaps"]
+    # a learn cell: the readers that have nothing to read (one device, no
+    # module line on a CPU, no kernel time to hold against a roofline) leave
+    # their metric out; a pattern that matches no event of the trace (no Pallas
+    # kernel, no whole-store copy here) reads 0, so that the line of a later
+    # PR that removes such an operation still carries the metric
+    r = harness.run_cell(tmp_root, "tiny.learn", seed=3, seconds=0.2, trace=True, require_tpu=False)
+    assert r["correct"] and {"device.idle_share", "model.mfu", "replay.valid_step_share",
+                             "cli.compile_misses"} <= set(r["metrics"])
+    assert not {"kernels.lstm_roofline", "collectives.exposed_ms_per_update",
+                "dispatch.gap_ms"} & set(r["metrics"])
+    assert r["metrics"]["kernels.lstm_ms_per_update"]["value"] == 0.0
+    assert r["metrics"]["replay.store_copy_ms_per_update"]["value"] == 0.0
+    assert r["metrics"]["replay.valid_step_share"]["value"] == 100.0
+
+
+def test_cli_refuses_a_machine_without_a_tpu():
+    """The command itself pins JAX to the TPU: here it must exit non-zero and
+    print no result line, whatever JAX_PLATFORMS the caller exported."""
+    p = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "benchmark", "run.py"), "--workload",
+         "nature-lstm512.learn", "--seed", "0", "--seconds", "1", "--trace", "0"],
+        cwd=ROOT, env={**os.environ, "JAX_PLATFORMS": "cpu"}, capture_output=True, text=True,
+        timeout=300)
+    assert p.returncode != 0
+    assert not [l for l in p.stdout.splitlines() if l.startswith("{") and "metrics" in l]
+    assert "no result" in p.stderr
