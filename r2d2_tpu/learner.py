@@ -42,6 +42,7 @@ from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.priority import mixed_td_priorities
 from r2d2_tpu.ops.value_rescale import inverse_value_rescale, value_rescale
 from r2d2_tpu.replay.replay_buffer import SampledBatch
+from r2d2_tpu.utils.profiling import scoped
 
 
 class TrainState(struct.PyTreeNode):
@@ -150,6 +151,13 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
             target_params, b.obs, b.last_action, b.last_reward, b.hidden,
             b.burn_in_steps, b.learning_steps, b.forward_steps, b.task,
         )
+        return island(
+            q_learn, q_boot_online, q_boot_target, mask, b.action,
+            b.n_step_reward, b.gamma, b.is_weights, denom,
+        )
+
+    def island(q_learn, q_boot_online, q_boot_target, mask, action,
+               n_step_reward, gamma, is_weights, denom):
         # fp32 island (precision policy, config.precision): Q-target math,
         # value rescaling, n-step folding, TD/priorities, IS weighting,
         # and the loss reduction stay float32 no matter the compute dtype.
@@ -161,16 +169,16 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
         q_tgt = jnp.take_along_axis(q_boot_target, a_star[..., None], axis=-1)[..., 0]
         q_tgt = q_tgt.astype(jnp.float32)
         y = value_rescale(
-            b.n_step_reward.astype(jnp.float32)
-            + b.gamma.astype(jnp.float32) * inverse_value_rescale(q_tgt, eps),
+            n_step_reward.astype(jnp.float32)
+            + gamma.astype(jnp.float32) * inverse_value_rescale(q_tgt, eps),
             eps,
         )
         y = jax.lax.stop_gradient(y)
 
-        q_taken = jnp.take_along_axis(q_learn, b.action[..., None], axis=-1)[..., 0]
+        q_taken = jnp.take_along_axis(q_learn, action[..., None], axis=-1)[..., 0]
         q_taken = q_taken.astype(jnp.float32)
         td = y - q_taken
-        w = b.is_weights.astype(jnp.float32)[:, None]
+        w = is_weights.astype(jnp.float32)[:, None]
         loss = jnp.sum(w * jnp.square(td) * mask) / denom
 
         abs_td = jnp.abs(td) * mask
@@ -182,6 +190,7 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
         }
         return loss, (priorities, aux)
 
+    island = scoped(island, "r2d2_loss")
     return loss_fn
 
 
@@ -212,6 +221,9 @@ def _raw_train_step(cfg: R2D2Config, net: R2D2Network, axis_name: Optional[str] 
         (loss, (priorities, aux)), grads = jax.value_and_grad(loss_fn, has_aux=True)(
             state.params, state.target_params, b, denom
         )
+        return apply(state, grads, loss, aux, priorities)
+
+    def apply(state, grads, loss, aux, priorities):
         if axis_name is not None:
             grads = jax.lax.psum(grads, axis_name)
             loss = jax.lax.psum(loss, axis_name)
@@ -234,6 +246,7 @@ def _raw_train_step(cfg: R2D2Config, net: R2D2Network, axis_name: Optional[str] 
         )
         return new_state, metrics, priorities
 
+    apply = scoped(apply, "r2d2_optimizer")
     return train_step
 
 
@@ -323,10 +336,14 @@ def make_fused_multi_train_step(
 
     Signature: (state, stores, b, s, w) with b/s/w of shape (K, B);
     returns (state, metrics-of-last-step, priorities (K, B))."""
-    return jax.jit(
-        make_multi_update_core(cfg, net, num_steps),
-        donate_argnums=(0,) if donate else (),
-    )
+    core = make_multi_update_core(cfg, net, num_steps)
+
+    # the module keeps its name `jit_multi` (a trace's module line and the
+    # benchmark's `step_program` pattern read it); the core is `r2d2_update`
+    def multi(state: TrainState, stores, b, s, w):
+        return core(state, stores, b, s, w)
+
+    return jax.jit(multi, donate_argnums=(0,) if donate else ())
 
 
 def make_multi_update_core(
@@ -351,7 +368,7 @@ def make_multi_update_core(
     if is_from_priorities and axis_name is None:
         raise ValueError("is_from_priorities needs an axis_name (pmin)")
     raw = _raw_train_step(cfg, net, axis_name=axis_name)
-    gather_batch = make_store_gather(cfg)
+    gather_batch = scoped(make_store_gather(cfg), "r2d2_gather")
 
     def multi(state: TrainState, stores, b, s, w):
         if b.shape[0] != num_steps:
@@ -375,7 +392,9 @@ def make_multi_update_core(
         state, (metrics, prios) = jax.lax.scan(body, state, (b, s, w))
         return state, jax.tree.map(lambda x: x[-1], metrics), prios
 
-    return multi
+    # the device scope of the whole K-update scan: every dispatch path that
+    # shares this core (megastep, sharded megastep, supersteps) carries it
+    return scoped(multi, "r2d2_update")
 
 
 def make_sharded_fused_multi_train_step(

@@ -50,6 +50,27 @@ from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.collect import default_chunk_len, make_collect_core
 from r2d2_tpu.learner import TrainState, make_multi_update_core
 from r2d2_tpu.models.r2d2 import R2D2Network
+from r2d2_tpu.utils.profiling import counted, register_program, scoped, span
+
+
+def _stack_coordinates(draws):
+    """K host draws -> the (K, ...) b / s / w device arrays of one dispatch."""
+    return (
+        jnp.asarray(np.stack([d.b for d in draws])),
+        jnp.asarray(np.stack([d.s for d in draws])),
+        jnp.asarray(np.stack([d.is_weights for d in draws])),
+    )
+
+
+def _priorities_span() -> span:
+    """The span over one dispatch's priority rows, stamped with the rows
+    offered and applied SO FAR: a traced window's share is its last span's
+    stamps less its first's (the counters themselves run from process start)."""
+    return span(
+        "r2d2.replay.priorities",
+        offered=int(counted("replay.priority_rows_offered")),
+        applied=int(counted("replay.priority_rows_applied")),
+    )
 
 
 def _start_async_copy(arrs) -> None:
@@ -57,6 +78,14 @@ def _start_async_copy(arrs) -> None:
     later while subsequent dispatches execute."""
     for arr in jax.tree.leaves(arrs):
         arr.copy_to_host_async()
+
+
+def _slab_write(stores, fields, start):
+    """The E new blocks into E CONTIGUOUS slots of every store field."""
+    return {
+        k: jax.lax.dynamic_update_slice_in_dim(arr, fields[k], start, axis=0)
+        for k, arr in stores.items()
+    }
 
 
 def make_megastep(
@@ -83,8 +112,11 @@ def make_megastep(
     equivalent to running learner.make_fused_multi_train_step on the same
     coordinates followed by collect + DeviceReplayBuffer.add_blocks_batch
     with the same key (pinned by tests/test_megastep.py)."""
-    collect_core = make_collect_core(cfg, net, fn_env, num_envs, chunk_len)
-    multi_core = make_multi_update_core(cfg, net, num_updates)
+    collect_core = scoped(
+        make_collect_core(cfg, net, fn_env, num_envs, chunk_len), "r2d2_collect"
+    )
+    multi_core = make_multi_update_core(cfg, net, num_updates)  # scope r2d2_update
+    slab_write = scoped(_slab_write, "r2d2_slab_write")
 
     def mega(state: TrainState, stores, env_state, epsilons, key, b, s, w, ptr0):
         # collection uses the dispatch-entry params: the freshest policy any
@@ -95,10 +127,7 @@ def make_megastep(
         (fields, chunk_prios, num_seq, sizes, dones, ep_rewards, fresh_env, key2) = (
             collect_core(act_params, env_state, epsilons, key)
         )
-        new_stores = {
-            k: jax.lax.dynamic_update_slice_in_dim(arr, fields[k], ptr0, axis=0)
-            for k, arr in stores.items()
-        }
+        new_stores = slab_write(stores, fields, ptr0)
         return (
             state,
             new_stores,
@@ -122,8 +151,9 @@ class _DeferredDrainRunner:
 
       _dispatch(state, collect) -> (state', metrics, priorities, draws,
                                     token, chunk_host)
-        reservation + draws + the jitted call, under the plane's locks
-        (token identifies the reserved slots; chunk_host the bookkeeping
+        reservation + draws + the jitted call through _launch (which
+        starts the async readbacks), under the plane's locks (token
+        identifies the reserved slots; chunk_host the bookkeeping
         arrays, both None when collect is False);
       _account_chunk(token, arrays) -> recorded
         install a drained chunk's accounting into the tree(s);
@@ -228,33 +258,47 @@ class _DeferredDrainRunner:
             collect = self._dispatch_count % self.collect_every == 0
         self._dispatch_count += 1
 
-        state, m, prios, draws, token, chunk_host = self._dispatch(state, collect)
+        # one host span for the whole dispatch; its children (sample, launch,
+        # readback, account, priorities) nest inside it on this thread and
+        # share the `dispatch` id through it
+        with span("r2d2.dispatch", dispatch=self._dispatch_count, collect=int(collect)):
+            state, m, prios, draws, token, chunk_host = self._dispatch(state, collect)
 
-        # start this dispatch's readbacks async; collect them next call
-        _start_async_copy((prios, chunk_host) if collect else prios)
-        recorded = 0
-        prev_chunk = self._pending_chunk
-        self._pending_chunk = (token, chunk_host) if collect else None
-        if prev_chunk is not None:
-            recorded = self._drain_chunk(prev_chunk)
-        prev, self._pending = self._pending, (prios, draws)
-        if prev is not None:
-            self._drain(prev)
+            recorded = 0
+            prev_chunk = self._pending_chunk
+            self._pending_chunk = (token, chunk_host) if collect else None
+            if prev_chunk is not None:
+                recorded = self._drain_chunk(prev_chunk)
+            prev, self._pending = self._pending, (prios, draws)
+            if prev is not None:
+                self._drain(prev)
         return state, m, recorded
+
+    def _launch(self, program, collect: bool, *args):
+        """The jitted call, and the start of this dispatch's readbacks
+        (async: collected next call, while the next dispatch executes)."""
+        out = program(*args)
+        _start_async_copy((out[3], out[4]) if collect else out[2])
+        return out
 
     def _drain_chunk(self, pending) -> int:
         """Install a deferred chunk's accounting (tree priorities, sizes,
         episode stats) at its reserved slots; returns recorded steps."""
         token, chunk_host = pending
-        arrays = tuple(map(np.asarray, chunk_host))
-        recorded = self._account_chunk(token, arrays)
+        with span("r2d2.dispatch.readback"):
+            arrays = tuple(map(np.asarray, chunk_host))
+        with span("r2d2.replay.account"):
+            recorded = self._account_chunk(token, arrays)
         self.total_env_steps += recorded
         return recorded
 
     def _drain(self, pending) -> None:
         prios, draws = pending
-        for row, d in zip(np.asarray(prios), draws):
-            self._apply_priorities(d, row)
+        with span("r2d2.dispatch.readback"):
+            rows = np.asarray(prios)
+        with _priorities_span():
+            for row, d in zip(rows, draws):
+                self._apply_priorities(d, row)
 
     def finish(self) -> int:
         """Apply the final in-flight readbacks (chunk accounting first,
@@ -333,33 +377,40 @@ class FusedSystemRunner(_DeferredDrainRunner):
         self.epsilons = epsilons
         self.env_state = env_state
         self.key = key
-        self._mega = make_megastep(cfg, net, fn_env, self.E, self.chunk, self.K)
-        self._multi = make_fused_multi_train_step(cfg, net, self.K)
+        self._mega = register_program(
+            "mega", make_megastep(cfg, net, fn_env, self.E, self.chunk, self.K)
+        )
+        self._multi = register_program(
+            "multi", make_fused_multi_train_step(cfg, net, self.K)
+        )
 
     def _dispatch(self, state: TrainState, collect: bool):
         replay = self.replay
         ptr0 = chunk_host = None
         with replay.lock:
-            if collect:
-                # reserve BEFORE drawing: retires the slots' old blocks and
-                # advances the ring pointer, so the draws below can neither
-                # target the in-flight chunk's slots nor produce priority
-                # rows the staleness mask would miss
-                ptr0 = replay._reserve_advance(self.E)
-            draws = [replay._draw_sample_idx(self.replay_rng) for _ in range(self.K)]
-            b = jnp.asarray(np.stack([d.b for d in draws]))
-            s = jnp.asarray(np.stack([d.s for d in draws]))
-            w = jnp.asarray(np.stack([d.is_weights for d in draws]))
-            if collect:
-                (state, new_stores, m, prios, chunk_host, self.env_state, self.key) = (
-                    self._mega(
-                        state, replay.stores, self.env_state, self.epsilons,
-                        self.key, b, s, w, jnp.int32(ptr0),
+            with span("r2d2.replay.sample"):
+                if collect:
+                    # reserve BEFORE drawing: retires the slots' old blocks and
+                    # advances the ring pointer, so the draws below can neither
+                    # target the in-flight chunk's slots nor produce priority
+                    # rows the staleness mask would miss
+                    ptr0 = replay._reserve_advance(self.E)
+                draws = [replay._draw_sample_idx(self.replay_rng) for _ in range(self.K)]
+            with span("r2d2.dispatch.launch"):
+                b, s, w = _stack_coordinates(draws)
+                if collect:
+                    (state, new_stores, m, prios, chunk_host, self.env_state, self.key) = (
+                        self._launch(
+                            self._mega, True,
+                            state, replay.stores, self.env_state, self.epsilons,
+                            self.key, b, s, w, jnp.int32(ptr0),
+                        )
                     )
-                )
-                replay.stores = new_stores
-            else:
-                state, m, prios = self._multi(state, replay.stores, b, s, w)
+                    replay.stores = new_stores
+                else:
+                    state, m, prios = self._launch(
+                        self._multi, False, state, replay.stores, b, s, w
+                    )
         return state, m, prios, draws, ptr0, chunk_host
 
     def _account_chunk(self, ptr0: int, arrays) -> int:
@@ -431,11 +482,14 @@ def make_sharded_megastep(
     if num_envs % dp:
         raise ValueError(f"num_envs {num_envs} not divisible by dp {dp}")
     E_local = num_envs // dp
-    collect_core = make_collect_core(cfg, net, fn_env, E_local, chunk_len)
-    multi_core = make_multi_update_core(
+    collect_core = scoped(
+        make_collect_core(cfg, net, fn_env, E_local, chunk_len), "r2d2_collect"
+    )
+    multi_core = make_multi_update_core(  # scope r2d2_update
         cfg, net, num_updates, axis_name="dp",
         is_from_priorities=is_from_priorities,
     )
+    slab_write = scoped(_slab_write, "r2d2_slab_write")
 
     def body(state, stores, env_state, epsilons, keys, b, s, w, starts):
         # local views: stores (nb/dp, ...), env_state/epsilons (E/dp, ...),
@@ -445,10 +499,7 @@ def make_sharded_megastep(
         (fields, chunk_prios, num_seq, sizes, dones, ep_rewards, fresh_env, key2) = (
             collect_core(act_params, env_state, epsilons, keys[0])
         )
-        new_stores = {
-            k: jax.lax.dynamic_update_slice_in_dim(arr, fields[k], starts[0], axis=0)
-            for k, arr in stores.items()
-        }
+        new_stores = slab_write(stores, fields, starts[0])
         return (
             state,
             new_stores,
@@ -530,43 +581,48 @@ class ShardedFusedRunner(_DeferredDrainRunner):
         self.env_state = jax.device_put(env_state, shd)
         # one PRNG stream per shard, sharded alongside its envs
         self.keys = jax.device_put(jax.random.split(key, dp), shd)
-        self._mega = make_sharded_megastep(
+        self._mega = register_program("mega", make_sharded_megastep(
             cfg, net, fn_env, mesh, E, self.chunk, self.K
+        ))
+        self._multi = register_program(
+            "multi", make_sharded_fused_multi_train_step(cfg, net, mesh, self.K)
         )
-        self._multi = make_sharded_fused_multi_train_step(cfg, net, mesh, self.K)
 
     def _dispatch(self, state: TrainState, collect: bool):
         replay = self.replay
         starts = chunk_host = None
         with replay.lock:
-            locks = [sh.lock for sh in replay.shards]
-            for lk in locks:
-                lk.acquire()
-            try:
+            with span("r2d2.replay.sample"):
+                locks = [sh.lock for sh in replay.shards]
+                for lk in locks:
+                    lk.acquire()
+                try:
+                    if collect:
+                        starts = np.asarray(
+                            [sh._reserve_advance(self.E_local) for sh in replay.shards],
+                            np.int32,
+                        )
+                    draws = [
+                        replay.sample_indices(self.replay_rng, locked=True)
+                        for _ in range(self.K)
+                    ]
+                finally:
+                    for lk in reversed(locks):
+                        lk.release()
+            with span("r2d2.dispatch.launch"):
+                b, s, w = _stack_coordinates(draws)
                 if collect:
-                    starts = np.asarray(
-                        [sh._reserve_advance(self.E_local) for sh in replay.shards],
-                        np.int32,
+                    (state, new_stores, m, prios, chunk_host,
+                     self.env_state, self.keys) = self._launch(
+                        self._mega, True,
+                        state, replay.stores, self.env_state, self.epsilons,
+                        self.keys, b, s, w, jnp.asarray(starts),
                     )
-                draws = [
-                    replay.sample_indices(self.replay_rng, locked=True)
-                    for _ in range(self.K)
-                ]
-            finally:
-                for lk in reversed(locks):
-                    lk.release()
-            b = jnp.asarray(np.stack([d.b for d in draws]))
-            s = jnp.asarray(np.stack([d.s for d in draws]))
-            w = jnp.asarray(np.stack([d.is_weights for d in draws]))
-            if collect:
-                (state, new_stores, m, prios, chunk_host,
-                 self.env_state, self.keys) = self._mega(
-                    state, replay.stores, self.env_state, self.epsilons,
-                    self.keys, b, s, w, jnp.asarray(starts),
-                )
-                replay.stores = new_stores
-            else:
-                state, m, prios = self._multi(state, replay.stores, b, s, w)
+                    replay.stores = new_stores
+                else:
+                    state, m, prios = self._launch(
+                        self._multi, False, state, replay.stores, b, s, w
+                    )
         return state, m, prios, draws, starts, chunk_host
 
     def _account_chunk(self, starts, arrays) -> int:
@@ -677,13 +733,13 @@ class MultiHostFusedRunner(_DeferredDrainRunner):
         self.epsilons = replay._assemble(per_eps, (E,), P("dp"))
         self.env_state = self._assemble_tree(per_env, E)
         self.keys = self._assemble_tree(per_key, dp)
-        self._mega = make_sharded_megastep(
+        self._mega = register_program("mega", make_sharded_megastep(
             cfg, net, fn_env, mesh, E, self.chunk, self.K,
             is_from_priorities=True,
-        )
-        self._multi = make_sharded_fused_multi_train_step(
+        ))
+        self._multi = register_program("multi", make_sharded_fused_multi_train_step(
             cfg, net, mesh, self.K, is_from_priorities=True
-        )
+        ))
 
     # ------------------------------------------------------------ helpers
 
@@ -711,30 +767,35 @@ class MultiHostFusedRunner(_DeferredDrainRunner):
         replay = self.replay
         starts_d = chunk_host = None
         with replay.lock:
-            if collect:
-                starts_d, per_start = {}, {}
-                for g in replay.local_ids:
-                    sh = replay.shards[g]
-                    with sh.lock:
-                        starts_d[g] = sh._reserve_advance(self.E_local)
-                    per_start[g] = jax.device_put(
-                        # host int -> tiny per-shard upload, once per chunk
-                        np.asarray([starts_d[g]], np.int32),  # r2d2: disable=host-sync-in-hot-path
-                        replay._shard_device[g],
+            with span("r2d2.replay.sample"):
+                if collect:
+                    starts_d, per_start = {}, {}
+                    for g in replay.local_ids:
+                        sh = replay.shards[g]
+                        with sh.lock:
+                            starts_d[g] = sh._reserve_advance(self.E_local)
+                        per_start[g] = jax.device_put(
+                            # host int -> tiny per-shard upload, once per chunk
+                            np.asarray([starts_d[g]], np.int32),  # r2d2: disable=host-sync-in-hot-path
+                            replay._shard_device[g],
+                        )
+                    starts = replay._assemble(per_start, (self.dp,), P("dp"))
+                # the draws AND their upload (sample_global_k assembles the
+                # global coordinate arrays): this plane's sample span holds both
+                (b, s, w), draws = replay.sample_global_k(self.K)
+            with span("r2d2.dispatch.launch"):
+                if collect:
+                    (state, new_stores, m, prios, chunk_host,
+                     self.env_state, self.keys) = self._launch(
+                        self._mega, True,
+                        state, replay.global_stores(), self.env_state,
+                        self.epsilons, self.keys, b, s, w, starts,
                     )
-                starts = replay._assemble(per_start, (self.dp,), P("dp"))
-            (b, s, w), draws = replay.sample_global_k(self.K)
-            if collect:
-                (state, new_stores, m, prios, chunk_host,
-                 self.env_state, self.keys) = self._mega(
-                    state, replay.global_stores(), self.env_state,
-                    self.epsilons, self.keys, b, s, w, starts,
-                )
-                replay.install_global_stores(new_stores)
-            else:
-                state, m, prios = self._multi(
-                    state, replay.global_stores(), b, s, w
-                )
+                    replay.install_global_stores(new_stores)
+                else:
+                    state, m, prios = self._launch(
+                        self._multi, False, state, replay.global_stores(), b, s, w
+                    )
         return state, m, prios, draws, starts_d, chunk_host
 
     def _drain_chunk(self, pending) -> int:
@@ -745,27 +806,31 @@ class MultiHostFusedRunner(_DeferredDrainRunner):
         starts_d, chunk_host = pending
         replay = self.replay
         per_g = {g: [None] * len(chunk_host) for g in replay.local_ids}
-        for fi, field in enumerate(chunk_host):
-            for piece in field.addressable_shards:
-                # deliberate readback: tiny accounting arrays, once per chunk
-                per_g[self._dev_to_g[piece.device]][fi] = np.asarray(piece.data)  # r2d2: disable=host-sync-in-hot-path
+        with span("r2d2.dispatch.readback"):
+            for fi, field in enumerate(chunk_host):
+                for piece in field.addressable_shards:
+                    # deliberate readback: tiny accounting arrays, once per chunk
+                    per_g[self._dev_to_g[piece.device]][fi] = np.asarray(piece.data)  # r2d2: disable=host-sync-in-hot-path
         recorded = 0
-        for g in replay.local_ids:
-            chunk_prios, num_seq, sizes, dones, ep_rewards = per_g[g]
-            with replay.shards[g].lock:
-                replay.shards[g]._account_blocks_at(
-                    int(starts_d[g]), num_seq, sizes, chunk_prios,
-                    ep_rewards, dones,
-                )
-            recorded += int(sizes.sum())
+        with span("r2d2.replay.account"):
+            for g in replay.local_ids:
+                chunk_prios, num_seq, sizes, dones, ep_rewards = per_g[g]
+                with replay.shards[g].lock:
+                    replay.shards[g]._account_blocks_at(
+                        int(starts_d[g]), num_seq, sizes, chunk_prios,
+                        ep_rewards, dones,
+                    )
+                recorded += int(sizes.sum())
         self.total_env_steps += recorded
         return recorded
 
     def _drain(self, pending) -> None:
         # the store's deferred-drain applier handles an explicit pending
         # pair: addressable pieces only, row i under draw i's per-shard
-        # staleness window + lap stamp
-        self.replay.drain_pending(pending)
+        # staleness window + lap stamp. It reads the priorities back itself,
+        # so this plane's readback wait is inside the priorities span
+        with _priorities_span():
+            self.replay.drain_pending(pending)
 
 
 # ---------------------------------------------------------------------------

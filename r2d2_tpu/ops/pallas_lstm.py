@@ -151,6 +151,7 @@ def _lstm_fwd_call(proj_t, wh, h0, c0, *, interpret: bool):
     )
     outs, cs = pl.pallas_call(
         _fwd_kernel,
+        name="_lstm_fwd_call",
         grid=(T,),
         compiler_params=params,
         in_specs=[
@@ -243,6 +244,7 @@ def _lstm_bwd_call(dout, proj_t, hprev, cprev, cs, wh, dcT, *, interpret: bool):
     )
     dz, dh0, dc0 = pl.pallas_call(
         _bwd_kernel,
+        name="_lstm_bwd_call",
         grid=(T,),
         compiler_params=params,
         in_specs=[
@@ -436,6 +438,7 @@ def _lstm_seq_bwd_call(dout, proj_t, hprev, cprev, cs, wh, dcT, burn, *, interpr
     )
     (dz,) = pl.pallas_call(
         _seq_bwd_kernel,
+        name="_lstm_seq_bwd_call",
         grid=(T,),
         compiler_params=params,
         in_specs=[
@@ -613,6 +616,7 @@ def _lstm_seq_bwd_fused_call(
     )
     dz, dwh = pl.pallas_call(
         _seq_bwd_fused_kernel,
+        name="_lstm_seq_bwd_fused_call",
         grid=(T,),
         compiler_params=params,
         in_specs=[
@@ -824,6 +828,7 @@ def _lstm_seq_bwd_ckpt_call(
     )
     dz, dwh = pl.pallas_call(
         functools.partial(_seq_bwd_ckpt_kernel, S=S),
+        name="_lstm_seq_bwd_ckpt_call",
         grid=(N,),
         compiler_params=params,
         in_specs=[

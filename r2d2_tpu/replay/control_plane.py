@@ -18,6 +18,7 @@ import numpy as np
 
 from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.replay.sum_tree import SumTree
+from r2d2_tpu.utils.profiling import count
 
 
 def shard_config(cfg: R2D2Config, dp: int) -> R2D2Config:
@@ -278,6 +279,9 @@ class ReplayControlPlane:
         rejects the whole batch. Callers without the stamp keep the
         window-mask-only behavior (the reference's own guarantee)."""
         S = self.cfg.seqs_per_block
+        # offered / applied: the replay layer's useful-outcomes-over-attempts
+        # ratio (the mask and the full-lap check discard silently)
+        count("replay.priority_rows_offered", len(idxes))
         with self.lock:
             if self.slot_stamp is not None and old_advances is not None:
                 # Disk mode: demotion moves blocks between arbitrary slots,
@@ -288,6 +292,7 @@ class ReplayControlPlane:
                 # overwriting every slot.)
                 mask = self.slot_stamp[idxes // S] <= old_advances
                 self._tree_write(idxes[mask], td_errors[mask])
+                count("replay.priority_rows_applied", int(mask.sum()))
                 return
             if (
                 old_advances is not None
@@ -302,6 +307,7 @@ class ReplayControlPlane:
             else:
                 mask = np.ones_like(idxes, dtype=bool)
             self._tree_write(idxes[mask], td_errors[mask])
+            count("replay.priority_rows_applied", int(mask.sum()))
 
     def pop_episode_stats(self):
         with self.lock:

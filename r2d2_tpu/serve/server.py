@@ -44,6 +44,7 @@ tests can pin traces <= len(buckets).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import queue
 import threading
 import time
@@ -63,6 +64,7 @@ from r2d2_tpu.serve.state_cache import RecurrentStateCache
 from r2d2_tpu.utils.checkpoint import latest_checkpoint_step, restore_checkpoint
 from r2d2_tpu.utils.faults import Backoff, InjectedFault, fault_point, total_retries
 from r2d2_tpu.utils.metrics import MetricsLogger
+from r2d2_tpu.utils.profiling import span
 from r2d2_tpu.utils.supervision import Supervisor
 
 
@@ -128,6 +130,8 @@ class _PipelineRecord:
     action: object
     staged: StagedBatch
     tap_rows: Optional[tuple]
+    seq: int = 0  # the `batch` id of the stage and complete spans
+    t_dispatched: float = 0.0  # time.monotonic(), the clock of t_enqueue
 
 
 _REF_JITS: Dict[R2D2Network, object] = {}
@@ -322,6 +326,15 @@ class PolicyServer:
         self._complete_q: "queue.Queue[_PipelineRecord]" = queue.Queue()
         self._complete_worker = None
         self.completed_batches = 0
+        # per-batch host time sums (seconds; stats() reports them, a window is
+        # a delta of two stats() calls): oldest request's queue wait, stage +
+        # dispatch, dispatched -> q/action on the host, then resolving the
+        # futures and retiring the batch
+        self._batch_ids = itertools.count(1)  # the `batch` id of a batch's spans
+        self.queue_wait_s_sum = 0.0
+        self.stage_s_sum = 0.0
+        self.device_wait_s_sum = 0.0
+        self.complete_s_sum = 0.0
         # deferred serve metrics (cfg.serve_log_interval > 0): batches that
         # skipped the metrics row, so rates stay computable from the rows
         # that did log
@@ -616,102 +629,112 @@ class PolicyServer:
         stores. Host-blocking materialization is banned here (the
         `blocking-host-sync-in-serve-step` lint enforces it); everything
         that must wait on the device lives in _complete."""
-        # single read of the publish cell: the whole batch — and the
-        # results' provenance — come from one (params, arm) pair; a reload
-        # landing between stage and complete changes NOTHING for this
-        # batch (mid-pipeline provenance invariant)
-        params, ckpt_step, version, arm = self._published
-        step_fn = self._step_for(arm)
-        n = len(batch)
-        bucket = self.batcher.bucket_for(n)
-        slots, fresh = self.cache.assign([r.session_id for r in batch])
+        t_stage = time.monotonic()
+        queue_wait = t_stage - batch[0].t_enqueue  # the oldest request's
+        seq = next(self._batch_ids)
+        with span("r2d2.serve.stage", batch=seq, rows=len(batch),
+                  queue_wait_us=int(queue_wait * 1e6)):
+            # single read of the publish cell: the whole batch — and the
+            # results' provenance — come from one (params, arm) pair; a reload
+            # landing between stage and complete changes NOTHING for this
+            # batch (mid-pipeline provenance invariant)
+            params, ckpt_step, version, arm = self._published
+            step_fn = self._step_for(arm)
+            n = len(batch)
+            bucket = self.batcher.bucket_for(n)
+            slots, fresh = self.cache.assign([r.session_id for r in batch])
 
-        obs_rows = [r.obs for r in batch]
-        target = tuple(self.cfg.obs_shape)
-        if any(o.shape != target for o in obs_rows):
-            # mixed-shape task interleaving (multi-task serving): pad every
-            # row to the union geometry the compiled step expects, so one
-            # bucket serves the whole family without per-shape retraces
-            obs_rows = [_pad_obs(o, target) for o in obs_rows]
-        # zero-copy assembly: single vectorized writes into this bucket's
-        # staging set (obs stack, rewards, reset|fresh, slots, task) —
-        # no per-batch np.stack/np.concatenate allocs, no per-row loops
-        staged = self._staging.stage(batch, bucket, obs_rows, self.serve_cfg.epsilon)
-        # a row starts from zero state when the client asked for a reset OR
-        # the cache admitted it fresh (new session, or evicted + returned);
-        # pad rows were pre-set to reset so the scratch row never compounds
-        staged.reset_mask[:n] |= fresh
-        staged.slots[:n] = slots
-        staged.slots[n:] = self.cache.pad_slot
-        # per-row exploration: request override > per-session assignment
-        # (liveloop's ladder) > the ServeConfig.epsilon fleet default.
-        # RNG discipline keeps the legacy stream bit-exact: the coin and
-        # random-action draws happen iff ANY row explores, in the same
-        # order and count as the old scalar path — all-zero rows (the
-        # default config) draw nothing, a uniform fleet epsilon draws
-        # exactly what it used to. epsilon_for runs in arrival order
-        # (sticky ladder rungs assign on first call).
-        assigner = self.eps_assigner
-        if assigner is not None:
-            staged.eps[:n] = [
-                r.epsilon if r.epsilon is not None
-                else assigner.epsilon_for(r.session_id)
-                for r in batch
-            ]
-        elif any(r.epsilon is not None for r in batch):
-            staged.eps[:n] = [
-                self.serve_cfg.epsilon if r.epsilon is None else r.epsilon
-                for r in batch
-            ]
-        if float(staged.eps.max()) > 0.0:
-            staged.explore[:] = self._rng.random(bucket) < staged.eps
-            if staged.task is not None and self._task_dims is not None:
-                # exploration stays NATIVE per row: a drawn action must be
-                # legal for the row's task, not just the union head
-                staged.randoms[:] = self._rng.integers(
-                    0, self._task_dims[staged.task]
-                )
-            else:
-                staged.randoms[:] = self._rng.integers(
-                    0, self.cfg.action_dim, bucket
-                )
+            obs_rows = [r.obs for r in batch]
+            target = tuple(self.cfg.obs_shape)
+            if any(o.shape != target for o in obs_rows):
+                # mixed-shape task interleaving (multi-task serving): pad every
+                # row to the union geometry the compiled step expects, so one
+                # bucket serves the whole family without per-shape retraces
+                obs_rows = [_pad_obs(o, target) for o in obs_rows]
+            # zero-copy assembly: single vectorized writes into this bucket's
+            # staging set (obs stack, rewards, reset|fresh, slots, task) —
+            # no per-batch np.stack/np.concatenate allocs, no per-row loops
+            staged = self._staging.stage(batch, bucket, obs_rows, self.serve_cfg.epsilon)
+            # a row starts from zero state when the client asked for a reset OR
+            # the cache admitted it fresh (new session, or evicted + returned);
+            # pad rows were pre-set to reset so the scratch row never compounds
+            staged.reset_mask[:n] |= fresh
+            staged.slots[:n] = slots
+            staged.slots[n:] = self.cache.pad_slot
+            # per-row exploration: request override > per-session assignment
+            # (liveloop's ladder) > the ServeConfig.epsilon fleet default.
+            # RNG discipline keeps the legacy stream bit-exact: the coin and
+            # random-action draws happen iff ANY row explores, in the same
+            # order and count as the old scalar path — all-zero rows (the
+            # default config) draw nothing, a uniform fleet epsilon draws
+            # exactly what it used to. epsilon_for runs in arrival order
+            # (sticky ladder rungs assign on first call).
+            assigner = self.eps_assigner
+            if assigner is not None:
+                staged.eps[:n] = [
+                    r.epsilon if r.epsilon is not None
+                    else assigner.epsilon_for(r.session_id)
+                    for r in batch
+                ]
+            elif any(r.epsilon is not None for r in batch):
+                staged.eps[:n] = [
+                    self.serve_cfg.epsilon if r.epsilon is None else r.epsilon
+                    for r in batch
+                ]
+            if float(staged.eps.max()) > 0.0:
+                staged.explore[:] = self._rng.random(bucket) < staged.eps
+                if staged.task is not None and self._task_dims is not None:
+                    # exploration stays NATIVE per row: a drawn action must be
+                    # legal for the row's task, not just the union head
+                    staged.randoms[:] = self._rng.integers(
+                        0, self._task_dims[staged.task]
+                    )
+                else:
+                    staged.randoms[:] = self._rng.integers(
+                        0, self.cfg.action_dim, bucket
+                    )
 
-        h, c, la, lr = self.cache.arrays()
-        step_args = [
-            params, h, c, la, lr,
-            jnp.asarray(staged.obs), jnp.asarray(staged.rewards),
-            jnp.asarray(staged.slots), jnp.asarray(staged.reset_mask),
-            jnp.asarray(staged.explore),
-            jnp.asarray(staged.randoms, jnp.int32),
-        ]
-        if staged.task is not None:
-            step_args.append(jnp.asarray(staged.task))
-        q, action, h, c, la, lr = step_fn(*step_args)
-        # JAX async dispatch: q/action come back as futures. Start the D2H
-        # copy NOW so it overlaps the remaining dispatch work and the next
-        # batch's staging; _complete's materialization then finds the
-        # bytes already on host (or waits the residue).
-        if hasattr(q, "copy_to_host_async"):
-            q.copy_to_host_async()
-            action.copy_to_host_async()
-        # stores commit at DISPATCH time, before the next batch can stage:
-        # a same-session follow-up (only admissible in a later batch)
-        # gathers from these arrays, and the device stream orders the
-        # donated in-place update ahead of any later step that reads it
-        self.cache.commit(h, c, la, lr)
-        tap_rows = None
-        if self.tap is not None:
-            # gather the batch rows' committed carries HERE, on the serve
-            # thread: on donating backends batch k's stores are consumed
-            # by step k+1, so a completion-time gather could read freed
-            # buffers. The gather is itself async — dispatch-ordered after
-            # the commit, materialized by the tap/completion side.
-            tap_rows = self.tap.gather_rows(h, c, staged.slots[:n])
-        return _PipelineRecord(
-            batch=batch, n=n, bucket=bucket, ckpt_step=ckpt_step,
-            version=version, arm=arm, q=q, action=action, staged=staged,
-            tap_rows=tap_rows,
-        )
+            h, c, la, lr = self.cache.arrays()
+            step_args = [
+                params, h, c, la, lr,
+                jnp.asarray(staged.obs), jnp.asarray(staged.rewards),
+                jnp.asarray(staged.slots), jnp.asarray(staged.reset_mask),
+                jnp.asarray(staged.explore),
+                jnp.asarray(staged.randoms, jnp.int32),
+            ]
+            if staged.task is not None:
+                step_args.append(jnp.asarray(staged.task))
+            q, action, h, c, la, lr = step_fn(*step_args)
+            # JAX async dispatch: q/action come back as futures. Start the D2H
+            # copy NOW so it overlaps the remaining dispatch work and the next
+            # batch's staging; _complete's materialization then finds the
+            # bytes already on host (or waits the residue).
+            if hasattr(q, "copy_to_host_async"):
+                q.copy_to_host_async()
+                action.copy_to_host_async()
+            # stores commit at DISPATCH time, before the next batch can stage:
+            # a same-session follow-up (only admissible in a later batch)
+            # gathers from these arrays, and the device stream orders the
+            # donated in-place update ahead of any later step that reads it
+            self.cache.commit(h, c, la, lr)
+            tap_rows = None
+            if self.tap is not None:
+                # gather the batch rows' committed carries HERE, on the serve
+                # thread: on donating backends batch k's stores are consumed
+                # by step k+1, so a completion-time gather could read freed
+                # buffers. The gather is itself async — dispatch-ordered after
+                # the commit, materialized by the tap/completion side.
+                tap_rows = self.tap.gather_rows(h, c, staged.slots[:n])
+            rec = _PipelineRecord(
+                batch=batch, n=n, bucket=bucket, ckpt_step=ckpt_step,
+                version=version, arm=arm, q=q, action=action, staged=staged,
+                tap_rows=tap_rows, seq=seq,
+            )
+        rec.t_dispatched = time.monotonic()
+        with self._state_lock:
+            self.queue_wait_s_sum += queue_wait
+            self.stage_s_sum += rec.t_dispatched - t_stage
+        return rec
 
     def _complete(self, rec: _PipelineRecord) -> None:
         """COMPLETE: materialize q/action (the only host-blocking reads in
@@ -720,21 +743,24 @@ class PolicyServer:
         Runs on the serve-complete worker (pipelined), or inline on the
         serve thread (serial); records arrive in dispatch order either
         way."""
-        q_np = np.asarray(rec.q)
-        act_np = np.asarray(rec.action)
-        t_done = time.monotonic()
-        for i, r in enumerate(rec.batch):
-            # .done() guard: _serve_recover may have failed these futures
-            # after a serve-loop crash while this record was still queued
-            if not r.future.done():
-                r.future.set_result(
-                    ServeResult(int(act_np[i]), q_np[i], rec.ckpt_step,
-                                rec.version, bucket=rec.bucket)
-                )
-        with self._state_lock:
-            done = set(map(id, rec.batch))
-            self._inflight = [r for r in self._inflight if id(r) not in done]
-            self.completed_batches += 1
+        with span("r2d2.serve.complete", batch=rec.seq):
+            q_np = np.asarray(rec.q)
+            act_np = np.asarray(rec.action)
+            t_done = time.monotonic()
+            for i, r in enumerate(rec.batch):
+                # .done() guard: _serve_recover may have failed these futures
+                # after a serve-loop crash while this record was still queued
+                if not r.future.done():
+                    r.future.set_result(
+                        ServeResult(int(act_np[i]), q_np[i], rec.ckpt_step,
+                                    rec.version, bucket=rec.bucket)
+                    )
+            with self._state_lock:
+                done = set(map(id, rec.batch))
+                self._inflight = [r for r in self._inflight if id(r) not in done]
+                self.completed_batches += 1
+                self.device_wait_s_sum += t_done - rec.t_dispatched
+                self.complete_s_sum += time.monotonic() - t_done
         n = rec.n
         if self.tap is not None:
             # live-loop capture, after the clients have their answers. The
@@ -1042,6 +1068,10 @@ class PolicyServer:
             "serve_quantization": self.cfg.serve_quantization,
             "quantized_leaves": self.quantized_leaves,
             "completed_batches": self.completed_batches,
+            "queue_wait_s_sum": self.queue_wait_s_sum,
+            "stage_s_sum": self.stage_s_sum,
+            "device_wait_s_sum": self.device_wait_s_sum,
+            "complete_s_sum": self.complete_s_sum,
             "metrics_skipped": self.metrics_skipped,
             # dispatched-not-yet-completed requests: with the queue depth
             # and last_request_age_s (batcher stats) this is the idle

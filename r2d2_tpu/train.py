@@ -74,7 +74,8 @@ from r2d2_tpu.replay.tiered_store import (
 from r2d2_tpu.utils.checkpoint import latest_checkpoint_step, restore_checkpoint, save_checkpoint
 from r2d2_tpu.utils.faults import fault_point, install_from_env, total_retries, with_retries
 from r2d2_tpu.utils.metrics import MetricsLogger
-from r2d2_tpu.utils.profiling import TransferTimer, span, start_profiler_server, step_span
+from r2d2_tpu.utils import profiling
+from r2d2_tpu.utils.profiling import TransferTimer, span, spanned, start_profiler_server, step_span
 from r2d2_tpu.utils.supervision import PREEMPT_EXIT_CODE, Supervisor, WorkerStalledError
 
 
@@ -188,7 +189,7 @@ class _HostPlane:
             self.step_fn = make_train_step(tr.cfg, tr.net)
 
     def sample(self, pipelined: bool = False):
-        with span("replay/sample"):
+        with span("r2d2.replay.sample"):
             b = self.replay.sample_batch(self.tr.sample_rng)
 
             def lift():
@@ -254,7 +255,7 @@ class _TieredPlane:
             # RNG race with write-backs, so the sampling stream is
             # bit-reproducible (the chaos suite's resume contract); trades
             # away the pipeline's transfer/compute overlap
-            with span("replay/staged_chunk"):
+            with span("r2d2.replay.stage"):
                 chunk = stage_chunk(
                     self.replay, self.tr.sample_rng, self.K, self.xfer
                 )
@@ -262,7 +263,7 @@ class _TieredPlane:
         # both modes consume the staging pipeline: it IS the prefetcher
         # (threaded mode's sampler thread just forwards chunks into its
         # queue, adding one more buffered chunk of depth)
-        with span("replay/staged_chunk"):
+        with span("r2d2.replay.stage"):
             return "staged", self._ensure_pipeline().get(), None, None
 
     def update(self, state, item):
@@ -393,7 +394,7 @@ class _DevicePlane:
             # time (atomically with the dispatch) — queued coordinates
             # could be retargeted by adds landing while the item waits
             return ("multi", None, None, None)
-        with span("replay/sample"):
+        with span("r2d2.replay.sample"):
             si = self.replay.sample_indices(self.tr.sample_rng)
             coords = (jax.device_put(si.b), jax.device_put(si.s), jax.device_put(si.is_weights))
             stamp = (si.old_ptr, si.old_advances)
@@ -547,7 +548,7 @@ class _ShardedPlane:
             # multi-update dispatch draws its own coordinates at update
             # time, atomically with the dispatch (_DevicePlane rationale)
             return ("multi", None, None, None)
-        with span("replay/sample"):
+        with span("r2d2.replay.sample"):
             si = self.replay.sample_indices(self.tr.sample_rng)
             coords = (jnp.asarray(si.b), jnp.asarray(si.s), jnp.asarray(si.is_weights))
             stamp = (si.old_ptrs, si.old_advances)
@@ -659,6 +660,9 @@ _PLANES = {
 
 
 class Trainer:
+    # set-up is read through the spans' aggregates (profiling.counters): it
+    # runs before any traced window
+    @spanned("r2d2.setup.init")
     def __init__(
         self,
         cfg: R2D2Config,
@@ -677,6 +681,7 @@ class Trainer:
         self.profile_dir = profile_dir
         self._profile_remaining = profile_steps if profile_dir else 0
         self._profile_active = False
+        self._span_mark: dict = {}  # profiling.counters() at the last metrics row
         self.cfg = cfg
         self.fn_env = None
         if cfg.collector == "device":
@@ -1029,7 +1034,7 @@ class Trainer:
             and not self._profile_active
             and self._step >= self._initial_step + 1
         ):
-            jax.profiler.start_trace(self.profile_dir)
+            profiling.start_trace(self.profile_dir)  # Python tracer off
             self._profile_active = True
 
     def _profile_tick(self, n: int) -> None:
@@ -1042,7 +1047,7 @@ class Trainer:
         fault_point("trainer.update")
         self._profile_gate()
         prev = self._step
-        with step_span("learner_update", prev):
+        with step_span("r2d2.step.update", prev):
             self.state, m = self.plane.update(self.state, item)
         self._step += self.plane.steps_per_update
         step = self._step
@@ -1302,7 +1307,7 @@ class Trainer:
         cannot lose the requested trace."""
         if self._profile_active:
             jax.block_until_ready(self.state.params)
-            jax.profiler.stop_trace()
+            profiling.stop_trace()
             self._profile_active = False
             self._profile_remaining = 0
 
@@ -1327,6 +1332,39 @@ class Trainer:
         if time.time() - self._last_log_emit >= self.cfg.log_interval:
             self._flush_log()
 
+    def _dispatch_host_row(self) -> dict:
+        """Host ms per fused dispatch since the last metrics row, from the
+        span aggregates and counters (the operator's view of
+        utils/profiling.SPANS): draw, readback wait, everything but the wait,
+        and the share of priority rows the staleness mask let through. Empty
+        off the fused path."""
+        now, mark = profiling.counters(), self._span_mark
+        self._span_mark = now
+
+        def grown(key: str) -> float:
+            return now.get(key, 0) - mark.get(key, 0)
+
+        n = grown("r2d2.dispatch.count")
+        if n <= 0:
+            return {}
+
+        def ms(name: str) -> float:
+            return grown(name + ".total_ns") / n / 1e6
+
+        wait = ms("r2d2.dispatch.readback")
+        row = {
+            "host_sample_ms": round(ms("r2d2.replay.sample"), 3),
+            "host_readback_ms": round(wait, 3),
+            "host_busy_ms": round(ms("r2d2.dispatch") - wait, 3),
+        }
+        offered = grown("replay.priority_rows_offered")
+        if offered:
+            # below 100: rows whose slot was overwritten before they came back
+            row["priority_applied_pct"] = round(
+                100.0 * grown("replay.priority_rows_applied") / offered, 2
+            )
+        return row
+
     def _flush_log(self) -> None:
         """Materialize and emit the queued metrics record, if any."""
         pend, self._pending_metrics = self._pending_metrics, None
@@ -1340,6 +1378,7 @@ class Trainer:
         retries = total_retries()
         if retries:
             extra = {**(extra or {}), "io_retries": retries}
+        extra = {**(extra or {}), **self._dispatch_host_row()}
         n_ep, r_sum = self.replay.pop_episode_stats()
         if self.cfg.replay_plane == "multihost" and jax.process_count() > 1:
             # env_steps_offset is a GLOBAL restored total (the snapshot
@@ -1379,6 +1418,7 @@ class Trainer:
 
     # ---------------------------------------------------------------- modes
 
+    @spanned("r2d2.setup.ring_fill")
     def warmup(
         self, max_steps: Optional[int] = None, beat: Optional[Callable[[], None]] = None
     ) -> None:
@@ -1690,7 +1730,7 @@ class Trainer:
                     break
                 self._profile_gate()
                 prev = self._step
-                with step_span("fused_megastep", prev):
+                with step_span("r2d2.step.megastep", prev):
                     self.state, m, recorded = runner.step(self.state)
                 self._step += cfg.updates_per_dispatch
                 self._profile_tick(cfg.updates_per_dispatch)
@@ -1783,7 +1823,9 @@ def main(argv=None):
                         "(repeatable; e.g. --set gamma=0.99 --set "
                         "batch_size=32 --set obs_shape=64,64,3)")
     p.add_argument("--profile-dir", default=None,
-                   help="record a jax.profiler trace of the first post-warmup updates")
+                   help="record a jax.profiler trace of the first post-warmup updates "
+                        "(Python tracer off; host spans r2d2.<layer>.<phase>, device "
+                        "scopes r2d2_<region>: utils/profiling.SPANS)")
     p.add_argument("--profile-steps", type=int, default=20)
     p.add_argument("--profile-port", type=int, default=0,
                    help="if set, start a live profiler server on this port")

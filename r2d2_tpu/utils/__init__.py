@@ -1,17 +1,5 @@
-"""Infrastructure utilities: checkpointing, metrics, profiling."""
+"""Infrastructure utilities: checkpointing, metrics, profiling, faults.
 
-from r2d2_tpu.utils.checkpoint import (
-    latest_checkpoint_step,
-    list_checkpoint_steps,
-    restore_checkpoint,
-    save_checkpoint,
-)
-from r2d2_tpu.utils.metrics import MetricsLogger
-
-__all__ = [
-    "save_checkpoint",
-    "restore_checkpoint",
-    "latest_checkpoint_step",
-    "list_checkpoint_steps",
-    "MetricsLogger",
-]
+Import the submodule you need (`from r2d2_tpu.utils.checkpoint import ...`):
+nothing is re-exported here, because `utils.checkpoint` imports the learner and
+the learner imports `utils.profiling`."""

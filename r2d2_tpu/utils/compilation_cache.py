@@ -28,12 +28,16 @@ jax.monitoring listener counting the persistent-cache events jax's
 compiler emits; log_compile_cache_stats() prints one
 `[compile-cache] dir=... source=... hits=H misses=M` line (the CLIs call
 it after warmup/run so a driver log shows whether the cache served, and
-who chose the directory)."""
+who chose the directory). The same listener sums jax's own duration events
+(trace, lowering, backend compile or cache load; they carry `fun_name`) per
+function: `compile_seconds()` is their total, and the report's second line
+names the ten costliest."""
 
 from __future__ import annotations
 
 import os
-from typing import Mapping, Optional
+import time
+from typing import Dict, List, Mapping, Optional
 
 _DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
@@ -47,10 +51,36 @@ _REQ_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
 _counts = {_HIT_EVENT: 0, _REQ_EVENT: 0}
 _listener_installed = False
 
+# duration events (jax._src.dispatch / pxla / compiler emit these names)
+_TRACE, _LOWER, _COMPILE = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+    "/jax/core/compile/backend_compile_duration",
+)
+_seconds: Dict[str, List[float]] = {}  # function -> [trace, lower, compile] s
+_total = [0.0]
+_open_traces: List[tuple] = []  # (start, duration) of traces not yet claimed by a parent
+
 
 def _count_event(event: str, **kwargs) -> None:
     if event in _counts:
         _counts[event] += 1
+
+
+def _sum_duration(event: str, duration: float, fun_name: str = "?", **kwargs) -> None:
+    if event not in (_TRACE, _LOWER, _COMPILE):
+        return
+    name = fun_name[4:-1] if fun_name.startswith("jit(") else fun_name
+    row = _seconds.setdefault(name, [0.0, 0.0, 0.0])
+    row[(_TRACE, _LOWER, _COMPILE).index(event)] += duration
+    if event == _TRACE:
+        # an inner jit is traced inside its caller's trace and reports first:
+        # the total takes each second once (a parent subtracts its children)
+        start, whole = time.perf_counter() - duration, duration
+        while _open_traces and _open_traces[-1][0] >= start:
+            duration -= _open_traces.pop()[1]
+        _open_traces.append((start, whole))
+    _total[0] += max(duration, 0.0)
 
 
 def _install_listener() -> None:
@@ -60,6 +90,7 @@ def _install_listener() -> None:
     import jax
 
     jax.monitoring.register_event_listener(_count_event)
+    jax.monitoring.register_event_duration_secs_listener(_sum_duration)
     _listener_installed = True
 
 
@@ -80,8 +111,22 @@ def compile_cache_stats() -> dict:
     return {"hits": hits, "misses": max(_counts[_REQ_EVENT] - hits, 0)}
 
 
+def compile_seconds() -> float:
+    """Seconds this process has spent tracing, lowering and compiling (or
+    loading from the persistent cache) so far, by jax's own duration events."""
+    return _total[0]
+
+
+def costliest_compiles(n: int = 10) -> List[tuple]:
+    """[(function, trace s, lower s, compile s), ...] by their sum; a
+    function's trace seconds include the inner jits traced inside it."""
+    rows = sorted(_seconds.items(), key=lambda kv: -sum(kv[1]))[:n]
+    return [(name, *secs) for name, secs in rows]
+
+
 def log_compile_cache_stats(prefix: str = "compile-cache") -> str:
-    """Print and return the one-line cache report the CLIs emit."""
+    """Print the cache report the CLIs emit (and the ten costliest compiles
+    beneath it); returns its first line."""
     import jax
 
     d = jax.config.jax_compilation_cache_dir
@@ -92,6 +137,11 @@ def log_compile_cache_stats(prefix: str = "compile-cache") -> str:
         f"hits={s['hits']} misses={s['misses']}"
     )
     print(line, flush=True)
+    rows = costliest_compiles()
+    if rows:
+        print(f"[{prefix}] {compile_seconds():.1f}s in trace+lower+compile; costliest: "
+              + ", ".join(f"{n} {t + l + c:.2f}s ({t:.2f}+{l:.2f}+{c:.2f})" for n, t, l, c in rows),
+              flush=True)
     return line
 
 

@@ -1,35 +1,101 @@
-"""Tracing / profiling hooks (SURVEY.md section 5.1 rebuild).
+"""Tracing / profiling: ONE facility, one naming table, one clock.
 
 The reference has no profiler at all — its only timing is wall-clock
 minutes stored in checkpoints (reference worker.py:378,452) and derived
 rates printed every 10 s (worker.py:126,135). Here:
 
-- `start_profiler_server(port)` exposes the live process to
-  `xprof`/TensorBoard-profile capture at any time (device + host traces).
-- `trace_to(dir)` context manager records a bounded trace programmatically
-  (e.g. `--profile-dir` on the trainer CLI traces the first post-warmup
-  updates, where the steady-state pipeline shape is visible).
-- `span(name)` / `step_span(name, step)` annotate HOST-side phases (replay
-  sample, block pack, priority update) so they line up against device
-  activity in the trace viewer. They are no-ops costing one context-manager
-  enter/exit when no trace is being captured, so the hot paths keep them
-  permanently.
-- `TransferTimer` is the tiered replay plane's staging accountant: it
-  measures how much of the host->HBM copy time is hidden behind update
-  compute (the plane's whole reason to exist), without needing a trace
-  capture.
+- `span(name, **ids)` annotates a HOST phase. It is a
+  `jax.profiler.TraceAnnotation` (free when no profiler session is active,
+  and written into the profiler's own trace, so it shares the device
+  events' clock by construction; `ids` become the event's stats and tie one
+  dispatch's or one batch's spans together) plus an always-on aggregate per
+  name (count, total ns). Nothing is kept per event. `spanned(name)` is the
+  same as a decorator.
+- `count(name, n)` counts where the work happens; `counters()` returns the
+  counts and the span aggregates as one flat dict. Readers: the benchmark's
+  `program_counter` reader and the trainer's metrics row (host ms per dispatch).
+- `scoped(fn, name)` is the DEVICE-side scope: `fn` under a named inner
+  `jax.jit(..., inline=False)`. The function name is part of the canonical
+  IR (`func.func private @r2d2_collect`), hence of the persistent
+  compilation cache's key, and of every `op_name` beneath it. A bare
+  `jax.named_scope` is metadata only: jax strips it from the cache key, so
+  a program with a new scope HITS the entry its scope-less parent wrote and
+  runs an executable without the names (PERF.md section 3). XLA inlines the
+  call, so instructions and their times do not change.
+- `register_program(name, jitted)` wraps a step program: the first call notes
+  the abstract signature and its seconds; `program_scopes(name)` lowers and
+  compiles from that signature ONLY WHEN ASKED (the benchmark's reader, after
+  the window; the executable the run built serves it, 0.1 s on the chip) and
+  returns {instruction: op_name}.
+- `SPANS` is the one table of names; a name outside it is refused.
+- `start_trace(dir)` / `stop_trace()` start jax's profiler with the Python
+  tracer OFF (it hooks every call of every thread: 8,000 -> 2,500 req/s
+  served, PERF.md finding 5). `start_profiler_server(port)` exposes the live
+  process to `xprof`/TensorBoard-profile capture.
+- `TransferTimer` is the tiered replay plane's staging accountant: how much
+  of the host->HBM copy time is hidden behind update compute.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
+import re
 import threading
 import time
-from typing import Iterator, Optional
+from typing import Callable, Dict, Iterator, Optional
 
 import jax
 
+from r2d2_tpu.utils.compilation_cache import compile_seconds
+
 _server = None
+
+# name -> (layer, what it covers). Host spans are `r2d2.<layer>.<phase>`,
+# device scopes `r2d2_<region>` (an identifier: it becomes a function name in
+# the IR), counters `<layer>.<what>`. PERF.md section 3 prints this table and
+# names the metric that reads each entry.
+SPANS: Dict[str, tuple] = {
+    # host spans
+    "r2d2.dispatch": ("dispatch", "one fused dispatch, whole body of runner.step (ids: dispatch, collect)"),
+    "r2d2.replay.sample": ("replay", "lock(s), slot reservation and the K coordinate draws of one dispatch"),
+    "r2d2.dispatch.launch": ("dispatch", "stacking b/s/w, their upload, the jitted call, the async readback kick-off"),
+    "r2d2.dispatch.readback": ("dispatch", "np.asarray of the previous dispatch's priorities / chunk bookkeeping: the host waits for the device here"),
+    "r2d2.replay.account": ("replay", "installing a drained chunk's blocks into the tree(s)"),
+    "r2d2.replay.priorities": ("replay", "the K priority rows of the previous dispatch applied to the tree(s) (ids: offered, applied = the two counters below as the span opens)"),
+    "r2d2.replay.stage": ("replay", "tiered plane: one staged K-batch chunk handed to the learner"),
+    "r2d2.setup.init": ("CLIs", "Trainer.__init__: env, mesh, model init, replay allocation, checkpoint restore"),
+    "r2d2.setup.ring_fill": ("CLIs", "Trainer.warmup: collection until sampling opens"),
+    "r2d2.serve.stage": ("serve", "stage + dispatch of one batch on the serve thread (ids: batch, rows, queue_wait_us)"),
+    "r2d2.serve.complete": ("serve", "materialize q/action, resolve futures, retire the batch (ids: batch)"),
+    # step-correlated spans of the run loops (StepTraceAnnotation)
+    "r2d2.step.update": ("run loop", "one learner update of the threaded/inline planes"),
+    "r2d2.step.megastep": ("run loop", "one fused dispatch as Trainer.run_fused issues it"),
+    # device scopes
+    "r2d2_update": ("dispatch", "the K-update scan (learner.make_multi_update_core)"),
+    "r2d2_collect": ("dispatch", "the collection chunk: policy, env dynamics, block packing"),
+    "r2d2_slab_write": ("replay", "dynamic_update_slice of the chunk's blocks into the stores"),
+    "r2d2_gather": ("replay", "in-jit window gather of one batch from the stores"),
+    "r2d2_loss": ("model", "the fp32 island: double-Q target, rescale, TD, priorities, loss"),
+    "r2d2_optimizer": ("model", "gradient psum, optimizer update, apply, target sync"),
+    # counters
+    "setup.first_call_s": ("CLIs", "seconds of the first call of each step program (trace, lower, compile or cache load), summed"),
+    "setup.compile_s": ("CLIs", "jax's compile-duration total (trace + lower + backend) when the last step program's first call returned"),
+    "replay.priority_rows_offered": ("replay", "priority rows handed to ReplayControlPlane.update_priorities"),
+    "replay.priority_rows_applied": ("replay", "of those, rows the staleness mask let through to the tree"),
+}
+
+# name -> [count, total ns]; plain counts beside them. Single-writer
+# per name on every hot path (the dispatch loop; one serve thread per span
+# name; counts under the replay lock), so no lock of their own.
+_agg: Dict[str, list] = {}
+_counts: Dict[str, float] = {}
+
+
+def _known(name: str) -> str:
+    if name not in SPANS:
+        raise KeyError(f"{name!r} is not in profiling.SPANS: add it to the table first")
+    return name
 
 
 class TransferTimer:
@@ -109,25 +175,163 @@ def start_profiler_server(port: int = 9012) -> None:
         _server = jax.profiler.start_server(port)
 
 
-@contextlib.contextmanager
-def trace_to(log_dir: Optional[str]) -> Iterator[None]:
-    """Record a profiler trace into `log_dir` for the duration of the
-    context; None disables (zero overhead)."""
-    if not log_dir:
-        yield
-        return
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
+def start_trace(log_dir: str) -> None:
+    """Start jax's profiler into `log_dir` with the Python tracer off; the
+    `TraceAnnotation` spans and the device planes stay. `stop_trace()` ends it."""
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(log_dir, profiler_options=options)
 
 
-def span(name: str):
-    """Named host-span annotation visible in the trace viewer."""
-    return jax.profiler.TraceAnnotation(name)
+stop_trace = jax.profiler.stop_trace
+
+
+class span:
+    """Named host span: a TraceAnnotation (ids -> the event's stats) plus the
+    always-on aggregate of its name."""
+
+    __slots__ = ("_name", "_ann", "_t0")
+
+    def __init__(self, name: str, **ids):
+        self._name = name
+        self._ann = jax.profiler.TraceAnnotation(name, **ids)
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter_ns() - self._t0
+        self._ann.__exit__(*exc)
+        a = _agg.get(self._name)
+        if a is None:
+            a = _agg[_known(self._name)] = [0, 0]
+        a[0] += 1
+        a[1] += dt
+        return False
+
+
+def spanned(name: str) -> Callable:
+    """Decorator: every call of the function runs under `span(name)`; the
+    function keeps its signature."""
+    _known(name)
+
+    def decorate(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def under_span(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+
+        return under_span
+
+    return decorate
 
 
 def step_span(name: str, step: int):
     """Step-correlated span: groups device work under learner step N."""
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+    return jax.profiler.StepTraceAnnotation(_known(name), step_num=step)
+
+
+def count(name: str, n: float = 1) -> None:
+    """Add `n` to the counter `name` (a name of the table)."""
+    try:
+        _counts[name] += n
+    except KeyError:
+        _counts[_known(name)] = n
+
+
+def counted(name: str) -> float:
+    """The counter `name` so far."""
+    return _counts.get(name, 0)
+
+
+def counters() -> Dict[str, float]:
+    """Every count, and `<span>.count|.total_ns` of every span that has run
+    in this process, as one flat dict."""
+    out: Dict[str, float] = dict(_counts)
+    for name, (n, total) in list(_agg.items()):
+        out[name + ".count"] = n
+        out[name + ".total_ns"] = total
+    return out
+
+
+# ------------------------------------------------------------ device scopes
+
+
+def scoped(fn: Callable, name: str) -> Callable:
+    """`fn` as a named, non-inlined inner jit: the name reaches the IR (so
+    the compilation cache's key) and the `op_name` of everything beneath it."""
+
+    def inner(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    inner.__name__ = inner.__qualname__ = _known(name)
+    return jax.jit(inner, inline=False)
+
+
+class _Program:
+    """A registered step program: calls through to the jitted function, and
+    at the first call notes the abstract signature and the call's seconds."""
+
+    def __init__(self, name: str, jitted):
+        self.name = name
+        self.jitted = jitted
+        self.signature = None
+
+    def __call__(self, *args):
+        if self.signature is not None:
+            return self.jitted(*args)
+        self.signature = jax.tree.map(_abstract, args)
+        t0 = time.perf_counter()
+        out = self.jitted(*args)
+        count("setup.first_call_s", time.perf_counter() - t0)
+        _counts["setup.compile_s"] = compile_seconds()
+        return out
+
+
+def _abstract(x):
+    """ShapeDtypeStruct of one argument; the sharding only where the array is
+    committed to it (an uncommitted array follows the others, as at the call)."""
+    if not hasattr(x, "shape") or not hasattr(x, "dtype"):
+        return x
+    sharding = getattr(x, "sharding", None) if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
+_programs: Dict[str, _Program] = {}
+_OP_NAME = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?\bop_name="([^"]*)"')
+
+
+def register_program(name: str, jitted) -> _Program:
+    """Register a jitted step program under `name` (latest wins) and return
+    the callable to use in its place."""
+    prog = _programs[name] = _Program(name, jitted)
+    return prog
+
+
+def registered_programs() -> list:
+    """Names of the registered programs that have been called."""
+    return [n for n, p in _programs.items() if p.signature is not None]
+
+
+def program_scopes(name: str) -> Dict[str, str]:
+    """{HLO instruction name: op_name} of the executable of program `name`,
+    lowered and compiled from the signature of its first call. Costs a trace,
+    a lowering and, where the run's executable is no longer at hand, a cache
+    load or a compile: for a reader after the measured window, never for the
+    program itself."""
+    prog = _programs[name]
+    if prog.signature is None:
+        raise ValueError(f"program {name!r} has not been called yet")
+    text = prog.jitted.lower(*prog.signature).compile().as_text()
+    return parse_op_names(text)
+
+
+def parse_op_names(hlo_text: str) -> Dict[str, str]:
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _OP_NAME.match(line)
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out

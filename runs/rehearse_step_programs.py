@@ -1,0 +1,143 @@
+"""Compile a benchmark configuration's two step programs at REAL size for a
+described (not attached) TPU v5e:2x2, here on the CPU, and say what the chip's
+compiler made of them. No chip time, nothing runs (on-chip-measurement guide,
+section 2, third rehearsal). Run it before a chip call whenever a step
+program's structure, a device scope or a kernel name changes:
+
+    JAX_PLATFORMS=cpu python runs/rehearse_step_programs.py nature-lstm512
+    JAX_PLATFORMS=cpu python runs/rehearse_step_programs.py nature-lstm512-dp4 --hlo-dir /tmp/hlo
+
+For the collecting program (`mega`) and the update-only one (`multi`) it
+prints one JSON line: the module's name (the benchmark's `step_program`
+pattern wants jit_mega / jit_multi / jit_body), how many instructions each
+category of benchmark/trace_patterns.json matches (`store_copy` must be
+exactly one, its operand an entry parameter; `lstm_kernel` the three Mosaic
+calls), the compiler's memory count (it refuses what does not fit 15.75 GB),
+and how many instructions carry each device scope of utils/profiling.SPANS and
+land in each bucket of benchmark/trace_scopes.json. A renamed scope recompiles
+the step programs cold on the chip (~200 s, nature): settle names here.
+
+PR 22 found the whole-store copy and the 20 GB refusal this way; PR 23 settled
+its scopes this way (same instruction count as the parent, 9,508 / 4,274)."""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import re
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    p.add_argument("config", help="a name under benchmark/configs/")
+    p.add_argument("--hlo-dir", default=None, help="write each program's as_text() here")
+    args = p.parse_args(argv)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+    # a compile for a described device cannot be read back from the persistent cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    from benchmark import harness
+    from benchmark.readers import trace_scope
+    from r2d2_tpu import learner, megastep
+    from r2d2_tpu.collect import default_chunk_len
+    from r2d2_tpu.models.r2d2 import R2D2Network
+    from r2d2_tpu.ops import pallas_lstm
+    from r2d2_tpu.replay.block import store_field_specs
+    from r2d2_tpu.train import build_fn_env
+    from r2d2_tpu.utils import profiling
+
+    # the code asks the ATTACHED device (a CPU here): steer it to the chip's answers
+    pallas_lstm._interpret = lambda: False
+    pallas_lstm.vmem_capacity_bytes = lambda: 128 << 20  # v5e
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    conf = harness.load_json(os.path.join(ROOT, "benchmark", "configs", args.config + ".json"))
+    traffic = harness.load_json(os.path.join(ROOT, "benchmark", "traffic", "learn.json"))
+    cfg = harness.build_config(conf, 1, {"samples_per_insert": float(traffic["samples_per_insert"])})
+    if cfg.recurrent_core == "lstm":
+        cfg = cfg.replace(lstm_backend="pallas")
+    dp = max(cfg.dp_size, 1)
+    net = R2D2Network.from_config(cfg)
+    fn_env = build_fn_env(cfg)
+    E, K, B, chunk = cfg.num_actors, cfg.updates_per_dispatch, cfg.batch_size, default_chunk_len(cfg)
+
+    def sds(shape, dtype, sharding):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=sharding)
+
+    if dp == 1:
+        rep = per_dp = coord = SingleDeviceSharding(topo.devices[0])
+        kb, key, start = (K, B), sds((2,), jnp.uint32, rep), sds((), jnp.int32, rep)
+        mega = megastep.make_megastep(cfg, net, fn_env, E, chunk, K)
+        multi = learner.make_fused_multi_train_step(cfg, net, K)
+    else:
+        mesh = Mesh(np.array(topo.devices[:dp]).reshape(dp, 1), ("dp", "tp"))
+        rep, per_dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+        coord = NamedSharding(mesh, P(None, "dp"))
+        kb, key, start = (K, dp, B // dp), sds((dp, 2), jnp.uint32, per_dp), sds((dp,), jnp.int32, per_dp)
+        mega = megastep.make_sharded_megastep(cfg, net, fn_env, mesh, E, chunk, K)
+        multi = learner.make_sharded_fused_multi_train_step(cfg, net, mesh, K)
+    state = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype, rep),
+        jax.eval_shape(lambda: learner.init_train_state(cfg, jax.random.PRNGKey(0))[1]))
+    stores = {k: sds((cfg.num_blocks, *shape), dt, per_dp) for k, (shape, dt) in store_field_specs(cfg).items()}
+    env = jax.tree.map(
+        lambda x: sds(x.shape, x.dtype, per_dp),
+        jax.eval_shape(lambda: jax.vmap(fn_env.reset)(jax.random.split(jax.random.PRNGKey(0), E))))
+    coords = [sds(kb, jnp.int32, coord), sds(kb, jnp.int32, coord), sds(kb, jnp.float32, coord)]
+    programs = {
+        "mega": (mega, (state, stores, env, sds((E,), jnp.float32, per_dp), key, *coords, start)),
+        "multi": (multi, (state, stores, *coords)),
+    }
+
+    patterns = harness.load_json(os.path.join(ROOT, "benchmark", "trace_patterns.json"))["categories"]
+    scopes = trace_scope.load_scopes(os.path.join(ROOT, "benchmark"))
+    buckets = [(name, re.compile(rx)) for name, rx in scopes["buckets"]]
+    device_scopes = [n for n in profiling.SPANS if n.startswith("r2d2_")]
+    for name, (fn, fn_args) in programs.items():
+        t = time.time()
+        compiled = fn.lower(*fn_args).compile()  # raises what the chip's compiler would raise
+        text = compiled.as_text()
+        if args.hlo_dir:
+            os.makedirs(args.hlo_dir, exist_ok=True)
+            with open(os.path.join(args.hlo_dir, f"{args.config}.{name}.hlo"), "w") as fh:
+                fh.write(text)
+        instr = [re.sub(r"^\s*(ROOT )?", "", l) for l in text.splitlines()
+                 if re.match(r"\s*(ROOT )?%[\w.\-]+ = ", l)]
+        op_names = profiling.parse_op_names(text)
+        memory = compiled.memory_analysis()
+        in_bucket = {b: 0 for b, _ in buckets}
+        for op in op_names.values():
+            hit = next((b for b, rx in buckets if rx.search(op)), None)
+            if hit:
+                in_bucket[hit] += 1
+        row = {
+            "program": name, "module": re.search(r"HloModule (\S+?)[,\s]", text).group(1),
+            "compile_s": round(time.time() - t, 1), "instructions": len(instr),
+            "matches": {k: sum(1 for l in instr if re.search(rx, l)) for k, rx in patterns.items()
+                        if k != "step_program"},
+            "store_copy": [l.split(", backend_config")[0] for l in instr if re.search(patterns["store_copy"], l)],
+            "lstm_kernels": [l.split(" = ")[0] for l in instr if re.search(patterns["lstm_kernel"], l)],
+            "temp_gb": round(memory.temp_size_in_bytes / 1e9, 2),
+            "argument_gb": round(memory.argument_size_in_bytes / 1e9, 2),
+            "with_op_name": len(op_names),
+            "in_scope": {s: sum(f"jit({s})" in v for v in op_names.values()) for s in device_scopes},
+            "in_bucket": in_bucket,
+        }
+        print(json.dumps(row), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,112 @@
+"""Compiles for a described (not attached) TPU v5e: what the chip's compiler
+makes of the Pallas LSTM kernels at the benchmark cells' own T, B, H, with no
+chip time. Pinned here: every kernel is a Mosaic custom call whose HLO
+instruction is named after its jitted wrapper (`pl.pallas_call(name=...)`),
+which is what the benchmark's `lstm_kernel` trace pattern anchors on
+(`kernels.lstm_ms_per_update`, `kernels.lstm_roofline`: a line without them is
+refused).
+
+All such compiles live in THIS file: the process that describes the topology
+holds the TPU library until it exits (on-chip-measurement guide, section 2),
+and the topology is described inside a fixture, never at import. They need ONE
+worker: under pytest-xdist run the file with `--dist loadfile` (the tier-1
+command) or `--dist loadgroup` (the `xdist_group` mark below). Under plain
+`-n N` the cases land on several workers, only one of which gets libtpu's
+lock: the others FAIL and say so, because a skip would let the pin vanish
+unnoticed. The only skip is an installation without libtpu."""
+
+import importlib.util
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from r2d2_tpu.ops import pallas_lstm as pk
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+T, H = 85, 512  # nature-lstm512: burn-in 40 + learning 40 + n-step 5, LSTM-512
+ROWS = {"one_chip": 64, "dp4_per_chip": 16}
+
+pytestmark = pytest.mark.xdist_group("v5e_compile")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("no TPU compiler (libtpu) in this installation")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.fail(
+            f"libtpu is installed but no v5e:2x2 topology can be described: {e}\n"
+            "If the error names libtpu's lock file, another process holds the library "
+            "(another xdist worker: run this file with --dist loadfile or loadgroup)."
+        )
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def compiled_kernels(monkeypatch):
+    """The kernels as they are built on the chip: not interpreted, VMEM limit
+    from the v5e's 128 MiB (the code asks the attached device, a CPU here)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    monkeypatch.setattr(pk, "vmem_capacity_bytes", lambda: 128 << 20)
+    # a compile for a described device is written to the persistent cache but
+    # cannot be read back without a chip: keep it out
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _lstm_kernel_pattern():
+    with open(os.path.join(ROOT, "benchmark", "trace_patterns.json")) as fh:
+        return re.compile(json.load(fh)["categories"]["lstm_kernel"])
+
+
+ARMS = {
+    # arm -> (op, the wrappers whose kernels its forward + backward launch)
+    "plain": (lambda: pk.lstm_unroll, ["_lstm_fwd_call", "_lstm_bwd_call"]),
+    "seq_default": (lambda: pk.lstm_seq_unroll, ["_lstm_fwd_call", "_lstm_seq_bwd_call"]),
+    "seq_fused_dwh": (lambda: pk.lstm_seq_unroll_fused_dwh, ["_lstm_fwd_call", "_lstm_seq_bwd_fused_call"]),
+    "seq_ckpt5": (lambda: pk.lstm_seq_unroll_ckpt(5), ["_lstm_fwd_call", "_lstm_seq_bwd_ckpt_call"]),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(ROWS))
+@pytest.mark.parametrize("arm", sorted(ARMS))
+def test_every_kernel_instruction_matches_the_benchmarks_lstm_pattern(arm, rows, one_chip, compiled_kernels):
+    B = ROWS[rows]
+    op, wrappers = ARMS[arm][0](), ARMS[arm][1]
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    args = [sds((T, B, 4 * H), jnp.bfloat16), sds((H, 4 * H), jnp.bfloat16),
+            sds((B, H), jnp.float32), sds((B, H), jnp.float32)]
+    if arm != "plain":
+        args.append(sds((B,), jnp.int32))
+
+    def loss(proj, wh, h0, c0, *burn):
+        outs, (hT, cT) = op(proj, wh, h0, c0, *burn)
+        return outs.astype(jnp.float32).sum() + hT.sum() + cT.sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).compile().as_text()
+    calls = [l.strip() for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    names = [re.sub(r"^ROOT ", "", l).split(" = ")[0] for l in calls]
+    pattern = _lstm_kernel_pattern()
+    assert len(calls) == 2 and all(pattern.search(re.sub(r"^ROOT ", "", l)) for l in calls), names
+    # named after the wrapper, so a pattern can tell the arms apart
+    assert sorted(re.sub(r"^%|\.\d+$", "", n) for n in names) == sorted(wrappers)
