@@ -8,6 +8,13 @@ conv encoder + LSTM-512 + dueling heads, obs 84x84x1, B=64, T=85, bf16):
 
   kernels   every pallas_call of ops/pallas_lstm.py at T=85, B=64, H=512,
             fp32 and bf16, against the lax.scan LSTM (models/lstm.py)
+  store_bytes  the replay store's obs bytes where no loss can see them: a
+            row store (replay/block.frames_to_rows) filled with a pattern of
+            (block, row, offset), gathered through learner.make_store_gather
+            under plain jit and, on four chips, inside the sharded plane's
+            shard_map and through the GSPMD-partitioned gather of
+            run_with_stores; bit for bit against numpy, before, in the same
+            program as, and after an in-place slab write the gather reads
   train     python -m r2d2_tpu.train, fused megastep: on-device collection,
             HBM replay ring, K=16 scanned updates, fused sequence kernel
             forward + backward, deferred priorities, orbax save
@@ -192,6 +199,209 @@ def _kernels_child(T: int, B: int, H: int) -> int:
             failed += row["verdict"] != "ok"
             print("KERNEL " + json.dumps(row), flush=True)
     print(f"KERNELS_DONE {2 * len(cases)}", flush=True)
+    return 1 if failed else 0
+
+
+# --------------------------------------------------------------------------
+# child: the store-bytes phase (imports jax; holds the chip while it runs)
+# --------------------------------------------------------------------------
+
+
+def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
+    """Does the gather hand back the bytes that were stored? `correct` in
+    the benchmark and every loss in the tests compare the program with a
+    reference on the SAME gathered batch, so neither can see a store that
+    holds, or a gather that returns, the wrong bytes. Here the obs store of a
+    real replay plane is filled on the device with a byte that encodes
+    (generation, global block, slot row, offset in the frame) and gathered
+    `batches` times through learner.make_store_gather, each frame compared
+    with numpy's evaluation of the same pattern:
+
+      jit        DeviceReplayBuffer, the gather plainly jitted on one chip
+      shard_map  ShardedDeviceReplay on four chips: per-shard LOCAL indices
+                 inside the sharded megastep's shard_map
+      gspmd      the same store through run_with_stores with GLOBAL indices
+                 (what benchmark/drivers/train_fused.py::_sample_batch runs)
+
+    each `before` a slab write, in the `same_program` as a donated in-place
+    slab write of generation-1 blocks into slots the gather also reads (the
+    step programs' order: the batch must hold the OLD bytes), and `after`
+    it. Prints one `STORE_BYTES {json}` verdict line per plane x moment."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import PartitionSpec as P
+
+    from r2d2_tpu.config import PRESETS, parse_overrides
+    from r2d2_tpu.learner import make_store_gather
+    from r2d2_tpu.megastep import _slab_write
+    from r2d2_tpu.replay.block import LANES, obs_rows, store_field_specs
+
+    base = PRESETS[preset]().replace(**parse_overrides(sets))
+    n_bytes, R = math.prod(base.obs_shape), obs_rows(base.obs_shape)
+    dev = jax.devices()[0]
+    print("STORE_BYTES_ON " + json.dumps({
+        "platform": dev.platform, "device_kind": dev.device_kind,
+        "devices": len(jax.devices()),
+    }), flush=True)
+
+    def pattern(gen, block, row, offset):  # numpy or jax.numpy integers alike
+        # odd factors: neighbours along any one coordinate differ
+        return (gen * 101 + block * 131 + row * 31 + offset * 7 + (offset >> 7) * 3) & 0xFF
+
+    def fill(cfg, blocks: int, block0, gen: int):
+        """(blocks, slot, R, 128) uint8 rows of the pattern, zero tail; the
+        other fields as a block whose windows all lie inside it."""
+        S, L = cfg.seqs_per_block, cfg.learning_steps
+        shape = (blocks, cfg.block_slot_len, R, LANES)
+        i = [jax.lax.broadcasted_iota(jnp.int32, shape, d) for d in range(4)]
+        off = i[2] * LANES + i[3]
+        out = {k: jnp.zeros((blocks, *sh), dt) for k, (sh, dt) in store_field_specs(cfg).items()}
+        out["obs"] = jnp.where(off < n_bytes, pattern(gen, i[0] + block0, i[1], off), 0).astype(jnp.uint8)
+        out["burn_in"] = jnp.broadcast_to(
+            jnp.minimum(jnp.arange(S, dtype=jnp.int32) * L, cfg.burn_in_steps), (blocks, S))
+        out["learning"] = jnp.full((blocks, S), L, jnp.int32)
+        out["forward"] = jnp.full((blocks, S), cfg.forward_steps, jnp.int32)
+        return out
+
+    def expected(cfg, gen_of_block, b, s):
+        """numpy: the frames make_store_gather's contract promises for
+        GLOBAL blocks b and sequences s of the filled store."""
+        L, T, slot = cfg.learning_steps, cfg.seq_len, cfg.block_slot_len
+        win = s * L - np.minimum(s * L, cfg.burn_in_steps)
+        rows = np.clip(win[:, None] + np.arange(T)[None, :], 0, slot - 1)
+        off = np.arange(n_bytes, dtype=np.int32)
+        want = pattern(gen_of_block[b][:, None, None], b[:, None, None], rows[:, :, None], off[None, None, :])
+        return want.astype(np.uint8).reshape(len(b), T, *cfg.obs_shape)
+
+    failed = 0
+
+    def verdict(plane: str, moment: str, cfg, gen_of_block, slab, b, s, got) -> None:
+        """`slab`: the global blocks the slab write lands on, whenever it does."""
+        nonlocal failed
+        b, s, got = np.asarray(b).reshape(-1), np.asarray(s).reshape(-1), np.asarray(got)
+        got = got.reshape(len(b), *got.shape[-(1 + len(cfg.obs_shape)):])
+        bad = int((got != expected(cfg, gen_of_block, b, s)).sum())
+        row = {"plane": plane, "moment": moment, "frames": int(got.shape[0] * got.shape[1]),
+               "read_from_slab_slots": int(np.isin(b, slab).sum()), "mismatched_bytes": bad,
+               "verdict": "ok" if bad == 0 and got.dtype == np.uint8 else "mismatch"}
+        failed += row["verdict"] != "ok"
+        print("STORE_BYTES " + json.dumps(row), flush=True)
+
+    rng = np.random.default_rng(0)
+
+    def slab_blocks(cfg) -> int:  # per shard: the collector's chunk of blocks
+        return cfg.num_actors // max(cfg.dp_size, 1)
+
+    def draw(cfg, blocks: int, shape):
+        """Block indices in [0, blocks) and sequences; every other block is
+        one of those the slab write lands on (slots 1 .. slab_blocks)."""
+        b = rng.integers(0, blocks, shape).astype(np.int32)
+        hit = rng.integers(1, 1 + slab_blocks(cfg), shape).astype(np.int32)
+        b = np.where(np.arange(b.size).reshape(shape) % 2 == 0, hit, b)
+        return b, rng.integers(0, cfg.seqs_per_block, shape).astype(np.int32)
+
+    # ---- one chip, plain jit
+    from r2d2_tpu.replay.device_store import DeviceReplayBuffer
+
+    cfg = base
+    nb, B, E = cfg.num_blocks, cfg.batch_size, slab_blocks(base)
+    replay = DeviceReplayBuffer(cfg)
+    with replay.lock:
+        replay.stores = jax.jit(lambda: fill(cfg, nb, 0, 0))()
+    gens, slab = np.zeros(nb, np.int64), np.arange(1, 1 + E)
+    gather = jax.jit(make_store_gather(cfg))
+    ones = jnp.ones(B, jnp.float32)
+
+    def read(b, s):
+        return replay.run_with_stores(lambda st: gather(st, jnp.asarray(b), jnp.asarray(s), ones)).obs
+
+    def step(stores, chunk, start, b, s, w):  # the step programs' order
+        batch = make_store_gather(cfg)(stores, b, s, w)
+        return _slab_write(stores, chunk, start), batch.obs
+
+    step_jit = jax.jit(step, donate_argnums=(0,))
+    for _ in range(batches):
+        b, s = draw(cfg, nb, (B,))
+        verdict("jit", "before", cfg, gens, slab, b, s, read(b, s))
+    b, s = draw(cfg, nb, (B,))
+    chunk = jax.jit(lambda: fill(cfg, E, 1, 1))()
+    with replay.lock:
+        replay.stores, got = step_jit(replay.stores, chunk, jnp.int32(1), jnp.asarray(b), jnp.asarray(s), ones)
+    verdict("jit", "same_program", cfg, gens, slab, b, s, got)
+    gens[slab] = 1
+    for _ in range(batches):
+        b, s = draw(cfg, nb, (B,))
+        verdict("jit", "after", cfg, gens, slab, b, s, read(b, s))
+    del replay, chunk, got
+
+    # ---- four chips: the sharded plane
+    if len(jax.devices()) >= 4:
+        from r2d2_tpu.parallel.jax_compat import shard_map
+        from r2d2_tpu.parallel.mesh import dp_manual_axes, make_mesh
+        from r2d2_tpu.replay.sharded_store import ShardedDeviceReplay
+
+        dp = 4
+        cfg = base.replace(dp_size=dp, replay_plane="sharded", buffer_capacity=base.buffer_capacity * dp)
+        nb, per, E = cfg.num_blocks, cfg.num_blocks // dp, slab_blocks(cfg)
+        mesh = make_mesh(dp=dp, tp=1, devices=jax.devices()[:dp])
+        replay = ShardedDeviceReplay(cfg, mesh)
+        shard = jax.sharding.NamedSharding(mesh, P("dp"))
+        gather = make_store_gather(cfg)
+
+        def local(fn):
+            """fn over each shard's LOCAL view, as make_sharded_megastep maps its body."""
+            return shard_map(fn, mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
+                             axis_names=dp_manual_axes(mesh), check_vma=False)
+
+        def fill_local(gen, blocks):
+            return lambda ids: fill(cfg, blocks, ids[0] * per + (gen > 0), gen)
+
+        shard_ids = jax.device_put(jnp.arange(dp, dtype=jnp.int32), shard)
+        with replay.lock:
+            replay.stores = jax.jit(local(fill_local(0, per)))(shard_ids)
+        gens = np.zeros(nb, np.int64)
+        slab = np.concatenate([np.arange(g * per + 1, g * per + 1 + E) for g in range(dp)])
+        w_local = jnp.ones((dp, B // dp), jnp.float32)
+
+        def body(stores, b, s, w):
+            return gather(stores, b[0], s[0], w[0]).obs[None]
+
+        def body_step(stores, chunk, starts, b, s, w):
+            obs = gather(stores, b[0], s[0], w[0]).obs[None]
+            return _slab_write(stores, chunk, starts[0]), obs
+
+        read_local = jax.jit(local(body))
+        step_local = jax.jit(local(body_step), donate_argnums=(0,))
+        gspmd = jax.jit(gather)
+        offsets = (np.arange(dp) * per)[:, None]
+
+        def reads(moment: str) -> None:
+            for _ in range(batches):
+                b, s = draw(cfg, per, (dp, B // dp))  # LOCAL to each shard
+                got = replay.run_with_stores(lambda st: read_local(st, jnp.asarray(b), jnp.asarray(s), w_local))
+                verdict("shard_map", moment, cfg, gens, slab, b + offsets, s, got)
+                # _sample_batch's way: local draws made global, the first n of them
+                gb, gs = (b + offsets).reshape(-1)[:8], s.reshape(-1)[:8]
+                got = replay.run_with_stores(
+                    lambda st: gspmd(st, jnp.asarray(gb), jnp.asarray(gs), jnp.ones(8, jnp.float32))).obs
+                verdict("gspmd", moment, cfg, gens, slab, gb, gs, jax.device_get(got))
+                gb, gs = draw(cfg, nb, (B,))  # and any global block, a whole batch
+                got = replay.run_with_stores(lambda st: gspmd(st, jnp.asarray(gb), jnp.asarray(gs), ones)).obs
+                verdict("gspmd", moment, cfg, gens, slab, gb, gs, jax.device_get(got))
+
+        reads("before")
+        b, s = draw(cfg, per, (dp, B // dp))
+        chunk = jax.jit(local(fill_local(1, E)))(shard_ids)
+        starts = jax.device_put(jnp.ones(dp, jnp.int32), shard)
+        with replay.lock:
+            replay.stores, got = step_local(replay.stores, chunk, starts, jnp.asarray(b), jnp.asarray(s), w_local)
+        verdict("shard_map", "same_program", cfg, gens, slab, b + offsets, s, got)
+        gens[slab] = 1
+        reads("after")
+    else:
+        print(f"STORE_BYTES_SKIPPED sharded plane: {len(jax.devices())} chip(s)", flush=True)
+    print(f"STORE_BYTES_DONE failed={failed}", flush=True)
     return 1 if failed else 0
 
 
@@ -403,6 +613,30 @@ class Runner:
             raise PhaseFailed(f"kernels: {bad}")
         return {"calls_ok": len(verdicts)}
 
+    def store_bytes(self) -> dict:
+        # judged by its verdict lines (exit code 1 = some verdict not "ok")
+        text = self._run("store_bytes", [
+            os.path.join(ROOT, "chip_smoke.py"), "--phase", "store_bytes",
+            "--preset", self.shape.preset, "--sets", *self.shape.train_sets,
+        ], 300, check=False)
+        on = [json.loads(l[15:]) for l in text.splitlines()
+              if l.startswith("STORE_BYTES_ON ")]
+        verdicts = [json.loads(l[12:]) for l in text.splitlines()
+                    if l.startswith("STORE_BYTES ")]
+        if not on or "STORE_BYTES_DONE" not in text or not verdicts:
+            raise PhaseFailed(f"store_bytes: did not run to its end\n{text[-3000:]}")
+        if on[0]["platform"] != self.platform:
+            raise PhaseFailed(f"store_bytes: ran as {on[0]}")
+        bad = [v for v in verdicts if v["verdict"] != "ok"]
+        if bad:
+            raise PhaseFailed(f"store_bytes: {bad}")
+        planes = sorted({v["plane"] for v in verdicts})
+        if on[0]["devices"] >= 4 and planes != ["gspmd", "jit", "shard_map"]:
+            raise PhaseFailed(f"store_bytes: four chips, but only {planes} were read")
+        return {"planes": planes, "gathers_ok": len(verdicts),
+                "frames": sum(v["frames"] for v in verdicts),
+                "read_from_slab_slots": sum(v["read_from_slab_slots"] for v in verdicts)}
+
     def train(self) -> dict:
         ckpt, m = os.path.join(self.work, "ckpt"), os.path.join(self.work, "m_train.jsonl")
         steps = 3 * self.shape.k
@@ -567,7 +801,7 @@ def run_phases(platform: str, shape: Shape,
     os.makedirs(logs, exist_ok=True)
     r = Runner(platform, shape, work, logs, extra_env)
     try:
-        for name in ("kernels", "train", "resume", "serve", "serve_tcp", "dp4"):
+        for name in ("kernels", "store_bytes", "train", "resume", "serve", "serve_tcp", "dp4"):
             r.phase(name, getattr(r, name))
     finally:
         r.kill_all()
@@ -577,8 +811,10 @@ def run_phases(platform: str, shape: Shape,
 
 def main() -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    # internal: the two child roles this file plays for itself
-    p.add_argument("--phase", choices=["kernels", "client"], help=argparse.SUPPRESS)
+    # internal: the child roles this file plays for itself
+    p.add_argument("--phase", choices=["kernels", "store_bytes", "client"], help=argparse.SUPPRESS)
+    p.add_argument("--preset", default="", help=argparse.SUPPRESS)
+    p.add_argument("--sets", nargs="*", default=[], help=argparse.SUPPRESS)
     p.add_argument("--tbh", default="", help=argparse.SUPPRESS)
     p.add_argument("--port", type=int, default=0, help=argparse.SUPPRESS)
     p.add_argument("--requests", type=int, default=0, help=argparse.SUPPRESS)
@@ -586,6 +822,8 @@ def main() -> int:
     args = p.parse_args()
     if args.phase == "kernels":
         return _kernels_child(*map(int, args.tbh.split(",")))
+    if args.phase == "store_bytes":
+        return _store_bytes_child(args.preset, args.sets)
     if args.phase == "client":
         return _client_child(
             args.port, args.requests, tuple(map(int, args.obs_shape.split(",")))

@@ -64,6 +64,7 @@ from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.epsilon import epsilon_ladder
 from r2d2_tpu.ops.priority import mixed_td_priorities
 from r2d2_tpu.ops.value_rescale import inverse_value_rescale, value_rescale
+from r2d2_tpu.replay.block import frames_to_rows
 
 
 def _where_rows(mask: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
@@ -182,7 +183,8 @@ def make_collect_core(
         """Pack ONE env's chunk into store-slot-shaped block fields.
 
         Mirrors SequenceAccumulator.finish (replay/accumulator.py) with
-        fixed shapes + masks: obs (T, ...), actions/rewards (T,) already
+        fixed shapes + masks: obs (T, R, 128) and final_obs (R, 128), frames
+        as the store's lane-aligned rows, actions/rewards (T,) already
         zero-masked past `size`, qs (T, A), hiddens (T, 2, H) post-step
         states, size scalar int, done scalar bool, qf (A,) the final
         policy eval for the truncation bootstrap. init_la/init_lr/init_hid
@@ -317,7 +319,14 @@ def make_collect_core(
             act = jnp.where(active, act, 0)
             done = done & active
             rec = {
-                "obs": obs,
+                # the store's row format from the first write on: the scan
+                # stacks (E, R, 128) slabs whose lanes are the frame's own
+                # bytes. Stacked as raw frames, the compiler lays the (T, E,
+                # 84, 84, 1) buffer out to suit whatever reshapes it later
+                # and may put T on the lanes: every env step then rewrites
+                # the whole buffer a byte per tile (3 ms a step at T=1024,
+                # E=64; PERF.md finding 25.3)
+                "obs": frames_to_rows(obs, cfg.obs_shape),
                 "action": act,
                 "reward": reward,
                 "q": q.astype(jnp.float32),
@@ -345,7 +354,7 @@ def make_collect_core(
         env_major = lambda x: jnp.swapaxes(x, 0, 1)  # (T, E, ...) -> (E, T, ...)
         fields, priorities, num_seq = jax.vmap(_pack)(
             env_major(rec["obs"]),
-            final_obs,
+            frames_to_rows(final_obs, cfg.obs_shape),
             env_major(rec["action"]),
             env_major(rec["reward"]),
             env_major(rec["q"]),

@@ -41,6 +41,7 @@ from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.priority import mixed_td_priorities
 from r2d2_tpu.ops.value_rescale import inverse_value_rescale, value_rescale
+from r2d2_tpu.replay.block import rows_to_frames
 from r2d2_tpu.replay.replay_buffer import SampledBatch
 from r2d2_tpu.utils.profiling import scoped
 
@@ -276,8 +277,17 @@ def make_store_gather(cfg: R2D2Config):
         rows = jnp.clip(win[:, None] + t[None, :], 0, slot - 1)
         bcol = b[:, None]
         lrow = jnp.clip(s[:, None] * L + jnp.arange(L, dtype=jnp.int32)[None, :], 0, bl - 1)
+        # obs: frames stored as lane-aligned rows (replay/block.py). Gathered
+        # with ONE index over the flattened (block * slot) axis (a bitcast of
+        # the row-major store), not with the (block, row) pair the other
+        # fields use: on the v5e (libtpu 0.0.34) the two-index gather of
+        # (56, 128) uint8 slices compiles and then halts the core on its first
+        # execution, alone or inside the step programs; the one-index form
+        # runs, and faster than any other that was tried (PERF.md finding 25.2)
+        obs = stores["obs"]
+        flat = obs.reshape(obs.shape[0] * slot, *obs.shape[2:])
         return DeviceBatch(
-            obs=stores["obs"][bcol, rows],
+            obs=rows_to_frames(jnp.take(flat, bcol * slot + rows, axis=0), cfg.obs_shape),
             last_action=stores["last_action"][bcol, rows],
             last_reward=stores["last_reward"][bcol, rows],
             hidden=stores["hidden"][b, s],

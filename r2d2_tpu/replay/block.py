@@ -13,6 +13,14 @@ changes:
 
 Observations keep the reference's uint8 storage; normalization to [0, 1]
 happens exactly once, on device (SURVEY.md quirk 15).
+
+The DEVICE stores keep each frame as lane-aligned rows (`frames_to_rows`):
+the frame's bytes, zero-padded to a multiple of 128, as (R, 128). The TPU
+runtime lays a buffer out with whichever dimension pads least on the 128
+lanes; with raw (84, 84, 1) frames that is the block index, one frame is
+scattered a byte at a time over the whole store, and every step program
+re-laid the whole store out before it could gather (PERF.md finding 1).
+Blocks, the host ReplayBuffer, the disk tier and snapshot files keep frames.
 """
 
 from __future__ import annotations
@@ -57,6 +65,45 @@ class Block:
         return len(self.obs)
 
 
+LANES = 128  # the TPU's minor tile dimension
+
+
+def obs_rows(obs_shape) -> int:
+    """R: how many 128-byte rows hold one frame."""
+    return -(-int(np.prod(obs_shape)) // LANES)
+
+
+def frames_to_rows(frames, obs_shape):
+    """(..., *obs_shape) -> (..., R, 128): flatten each frame, zero-pad its
+    tail to R * 128 bytes. numpy in, numpy out; anything else goes through
+    jax.numpy (traceable)."""
+    obs_shape = tuple(obs_shape)
+    lead = frames.shape[: frames.ndim - len(obs_shape)]
+    if frames.shape[len(lead):] != obs_shape:
+        raise ValueError(f"frames {frames.shape} do not end in obs_shape {obs_shape}")
+    n, R = int(np.prod(obs_shape)), obs_rows(obs_shape)
+    flat = frames.reshape(*lead, n)
+    if R * LANES != n:
+        if isinstance(frames, np.ndarray):
+            pad = np.pad
+        else:
+            import jax.numpy as jnp
+
+            pad = jnp.pad
+        flat = pad(flat, [(0, 0)] * len(lead) + [(0, R * LANES - n)])
+    return flat.reshape(*lead, R, LANES)
+
+
+def rows_to_frames(rows, obs_shape):
+    """(..., R, 128) -> (..., *obs_shape): the inverse of frames_to_rows."""
+    obs_shape = tuple(obs_shape)
+    n, R = int(np.prod(obs_shape)), obs_rows(obs_shape)
+    if rows.shape[-2:] != (R, LANES):
+        raise ValueError(f"rows {rows.shape} do not end in {(R, LANES)}")
+    lead = rows.shape[:-2]
+    return rows.reshape(*lead, R * LANES)[..., :n].reshape(*lead, *obs_shape)
+
+
 def store_field_specs(cfg):
     """Per-slot (shape, dtype) of every replay-store field, WITHOUT the
     leading block axis — the single source of truth shared by all device
@@ -64,7 +111,8 @@ def store_field_specs(cfg):
     Block field means extending this map and pad_block_fields once."""
     S, slot, bl = cfg.seqs_per_block, cfg.block_slot_len, cfg.block_length
     return {
-        "obs": ((slot, *cfg.obs_shape), np.uint8),
+        # frames as lane-aligned rows (module docstring, frames_to_rows)
+        "obs": ((slot, obs_rows(cfg.obs_shape), LANES), np.uint8),
         "last_action": ((slot,), np.int32),
         "last_reward": ((slot,), np.float32),
         "action": ((bl,), np.int32),

@@ -26,10 +26,25 @@ consumer through `run_with_stores(fn)` — it holds the buffer lock across
 the dispatch, serializing against add_block's swap. Never cache
 `self.stores` across calls.
 
-Capacity note: obs dominates HBM use at ~7 KB/transition for 84x84; a
-16 GB chip holds ~2M transitions with little room for anything else, so
-configure buffer_capacity to budget (bench uses 100k ~= 0.7 GB). Scaling
-to the full reference capacity shards the block dimension over the mesh.
+Store layout: every field is (num_blocks, *store_field_specs(cfg)[field]).
+Obs is NOT (slot, *obs_shape): each frame is kept as lane-aligned rows,
+(slot, R, 128) with R = ceil(frame bytes / 128) and a zero tail
+(replay/block.frames_to_rows; an 84x84x1 frame is 56 rows, 1.6 % padding).
+The TPU runtime then lays the store out row-major, a frame is R contiguous
+tiles, and the in-jit gather and the donated slab write work on the store in
+place. With raw frames the runtime put the BLOCK index on the lanes and both
+step programs re-laid the whole store out on every dispatch (PERF.md
+finding 1, repaired in PR 25). pad_block_fields and the collector (from its scan
+body on) write rows, learner.make_store_gather hands frames back; blocks, the host buffer, the
+disk tier and snapshot files keep frames.
+
+Capacity note: obs dominates HBM use at 7,168 bytes per stored step for
+84x84 (block_slot_len steps per block_length transitions: 7.9 KB per
+transition at block 400); a 16 GB chip holds the store plus what the step
+programs need beside it (the benchmark runs 512,000 transitions = 4.05 GB;
+what the compiler admits above that: PERF.md finding 25.1), so configure
+buffer_capacity to budget. Scaling to the full reference capacity shards the
+block dimension over the mesh.
 """
 
 from __future__ import annotations
@@ -42,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
-from r2d2_tpu.replay.block import Block, store_field_specs
+from r2d2_tpu.replay.block import Block, frames_to_rows, store_field_specs
 from r2d2_tpu.replay.control_plane import ReplayControlPlane
 
 
@@ -116,7 +131,7 @@ class DeviceReplayBuffer(ReplayControlPlane):
             return out
 
         out = {
-            "obs": pad(block.obs, slot, np.uint8),
+            "obs": frames_to_rows(pad(block.obs, slot, np.uint8), cfg.obs_shape),
             "last_action": pad(block.last_action.astype(np.int32), slot, np.int32),
             "last_reward": pad(block.last_reward, slot, np.float32),
             "action": pad(block.action.astype(np.int32), bl, np.int32),

@@ -62,6 +62,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from r2d2_tpu.replay.block import frames_to_rows
 from r2d2_tpu.replay.control_plane import ReplayControlPlane
 from r2d2_tpu.replay.device_store import DeviceReplayBuffer
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer
@@ -369,21 +370,28 @@ def _apply_plane(plane: ReplayControlPlane, d: Dict) -> None:
 
 
 def _cast_stores(
-    stores: Dict[str, np.ndarray], targets: Dict[str, Tuple]
+    stores: Dict[str, np.ndarray], targets: Dict[str, Tuple], obs_shape=None
 ) -> Dict[str, np.ndarray]:
     """Validate shapes against the destination and cast dtypes across the
     host/device family boundary (uint8 <-> int32 action fields; lossless,
-    actions < 256). Raises BEFORE the caller mutates anything."""
+    actions < 256). `obs_shape` says the destination keeps frames as rows
+    (the device planes; snapshot files hold frames on every plane): obs is
+    checked against the frame shape and handed back as rows. Raises BEFORE
+    the caller mutates anything."""
     out = {}
     for k in STORE_FIELDS:
         shape, dtype = targets[k]
+        rows = k == "obs" and obs_shape is not None
+        if rows:
+            shape = (*shape[:-2], *obs_shape)
         v = stores[k]
         if tuple(v.shape) != tuple(shape):
             raise ValueError(
                 f"store {k}: snapshot slab {v.shape} != destination {shape} "
                 "(incompatible config, not just topology)"
             )
-        out[k] = v if v.dtype == dtype else v.astype(dtype)
+        v = v if v.dtype == dtype else v.astype(dtype)
+        out[k] = frames_to_rows(v, obs_shape) if rows else v
     return out
 
 
@@ -454,7 +462,7 @@ def _scatter(replay, plane_kind: str, per_dest: Dict[int, Dict], meta: Dict) -> 
         }
         with replay.lock:
             cast = {
-                g: _cast_stores(per_dest[g]["stores"], targets)
+                g: _cast_stores(per_dest[g]["stores"], targets, replay.cfg.obs_shape)
                 for g in replay.local_ids
             }
             for g in replay.local_ids:
@@ -480,7 +488,7 @@ def _scatter(replay, plane_kind: str, per_dest: Dict[int, Dict], meta: Dict) -> 
         }
         with replay.lock:
             cast = {
-                i: _cast_stores(per_dest[i]["stores"], targets)
+                i: _cast_stores(per_dest[i]["stores"], targets, replay.cfg.obs_shape)
                 for i in range(replay.dp)
             }
             flat = {
@@ -501,7 +509,7 @@ def _scatter(replay, plane_kind: str, per_dest: Dict[int, Dict], meta: Dict) -> 
             for k in STORE_FIELDS
         }
         with replay.lock:
-            cast = _cast_stores(per_dest[0]["stores"], targets)
+            cast = _cast_stores(per_dest[0]["stores"], targets, replay.cfg.obs_shape)
             _apply_plane(replay, per_dest[0])
             replay.stores = {k: jax.device_put(v) for k, v in cast.items()}
     else:  # host / tiered

@@ -26,7 +26,11 @@ Format: one .npz (uncompressed — obs dominate and are incompressible-ish
 uint8; write speed matters more). Obs storage dominates the file size:
 ~7 KB/transition at 84x84, so snapshot cadence is the caller's cost knob —
 the Trainer writes one at end-of-run when cfg.snapshot_replay is set and
-restores it on --resume.
+restores it on --resume. The FILE holds obs as frames, (block, slot,
+*obs_shape), on every plane: the device planes' lane-aligned rows
+(replay/block.frames_to_rows) are converted on the way out and back in, so
+a file is one format whichever plane or release wrote it, and reshard moves
+it between host and device planes without knowing the row format.
 """
 
 from __future__ import annotations
@@ -38,6 +42,7 @@ import jax
 import ml_dtypes
 import numpy as np
 
+from r2d2_tpu.replay.block import frames_to_rows, rows_to_frames
 from r2d2_tpu.replay.control_plane import ReplayControlPlane
 from r2d2_tpu.replay.device_store import DeviceReplayBuffer
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer
@@ -241,22 +246,34 @@ def _check_kind(kind: str, want: str, replay, saved_topo: Optional[Dict]) -> Non
 
 
 def _validated_stores(
-    d, current: Dict[str, np.ndarray], prefix: str = "store_"
+    d, current: Dict[str, np.ndarray], prefix: str = "store_", obs_shape=None
 ) -> Dict[str, np.ndarray]:
     """Load every store field from the npz ONCE (NpzFile re-parses per
     access, and obs dominate the file), checking shape/dtype against the
     live buffer BEFORE the caller mutates anything — a mismatched snapshot
-    must leave the buffer untouched."""
+    must leave the buffer untouched. `obs_shape` says the live store keeps
+    frames as rows (the device planes): the file's frames are checked
+    against the frame shape and handed back as rows."""
     out = {}
     for k in STORE_FIELDS:
         cur = current[k]
         val = d[prefix + k]
-        if val.shape != cur.shape or val.dtype != cur.dtype:
+        rows = k == "obs" and obs_shape is not None
+        want = (*cur.shape[:-2], *obs_shape) if rows else cur.shape
+        if val.shape != want or val.dtype != cur.dtype:
             raise ValueError(
                 f"store {prefix}{k}: snapshot {val.shape}/{val.dtype} != "
-                f"buffer {cur.shape}/{cur.dtype}"
+                f"buffer {want}/{cur.dtype}"
             )
-        out[k] = val
+        out[k] = frames_to_rows(val, obs_shape) if rows else val
+    return out
+
+
+def _download_stores(cfg, stores) -> Dict[str, np.ndarray]:
+    """A device plane's stores as the file holds them: host arrays, obs
+    back as frames."""
+    out = {k: np.asarray(stores[k]) for k in STORE_FIELDS}
+    out["obs"] = rows_to_frames(out["obs"], cfg.obs_shape)
     return out
 
 
@@ -337,8 +354,8 @@ def save_replay(
                 shard = replay.shards[g]
                 with shard.lock:
                     payload.update(_plane_state(shard, prefix=f"g{g}_"))
-                    for k in STORE_FIELDS:
-                        payload[f"g{g}_store_{k}"] = np.asarray(replay.stores[g][k])
+                    for k, v in _download_stores(replay.cfg, replay.stores[g]).items():
+                        payload[f"g{g}_store_{k}"] = v
     elif isinstance(replay, ShardedDeviceReplay):
         with replay.lock:
             payload: Dict[str, np.ndarray] = {"kind": np.asarray("sharded")}
@@ -346,14 +363,14 @@ def save_replay(
             for i, shard in enumerate(replay.shards):
                 with shard.lock:
                     payload.update(_plane_state(shard, prefix=f"shard{i}_"))
-            for k in STORE_FIELDS:
-                payload["store_" + k] = np.asarray(replay.stores[k])
+            for k, v in _download_stores(replay.cfg, replay.stores).items():
+                payload["store_" + k] = v
     elif isinstance(replay, DeviceReplayBuffer):
         with replay.lock:
             payload = {"kind": np.asarray("device")}
             payload.update(_plane_state(replay))
-            for k in STORE_FIELDS:
-                payload["store_" + k] = np.asarray(replay.stores[k])
+            for k, v in _download_stores(replay.cfg, replay.stores).items():
+                payload["store_" + k] = v
     elif isinstance(replay, ReplayBuffer):
         with replay.lock:
             payload = {"kind": np.asarray("host")}
@@ -427,7 +444,8 @@ def restore_replay(replay, path: str) -> Dict[str, np.ndarray]:
                     if len(d[f"g{g}_tree_leaves"]) != replay.shards[g].tree.capacity:
                         raise ValueError(f"shard {g}: tree size mismatch")
                     vals_by_shard[g] = _validated_stores(
-                        d, replay.stores[g], prefix=f"g{g}_store_"
+                        d, replay.stores[g], prefix=f"g{g}_store_",
+                        obs_shape=replay.cfg.obs_shape,
                     )
                 replay._rr = int(d["rr"][()])
                 for g in replay.local_ids:
@@ -454,7 +472,9 @@ def restore_replay(replay, path: str) -> Dict[str, np.ndarray]:
                     f"snapshot holds {saved_dp} dp shards, replay has {replay.dp}",
                 )
             with replay.lock:
-                vals = _validated_stores(d, replay.stores)
+                vals = _validated_stores(
+                    d, replay.stores, obs_shape=replay.cfg.obs_shape
+                )
                 for i in range(len(replay.shards)):  # leaf-count pre-check
                     if len(d[f"shard{i}_tree_leaves"]) != replay.shards[i].tree.capacity:
                         raise ValueError(f"shard {i}: tree size mismatch")
@@ -469,7 +489,9 @@ def restore_replay(replay, path: str) -> Dict[str, np.ndarray]:
         elif isinstance(replay, DeviceReplayBuffer):
             _check_kind(kind, "device", replay, saved_topo)
             with replay.lock:
-                vals = _validated_stores(d, replay.stores)
+                vals = _validated_stores(
+                    d, replay.stores, obs_shape=replay.cfg.obs_shape
+                )
                 if len(d["tree_leaves"]) != replay.tree.capacity:
                     raise ValueError("tree size mismatch")
                 _restore_plane(replay, d)

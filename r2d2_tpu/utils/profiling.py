@@ -335,3 +335,27 @@ def parse_op_names(hlo_text: str) -> Dict[str, str]:
         if m:
             out[m.group(1)] = m.group(2)
     return out
+
+
+_RELAYOUT = re.compile(r"^\s*(?:ROOT\s+)?(%?[\w.\-]+ = ([a-z]+\d*)\[([\d,]*)\]\S* (?:copy|transpose|reshape)\([^)]*\))")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4,
+                "s64": 8, "u64": 8, "f64": 8}
+
+
+def relayouts_at_least(hlo_text: str, min_bytes: int) -> list:
+    """Every `copy` / `transpose` / `reshape` instruction of a compiled
+    module, inside a fusion or out, whose result holds at least `min_bytes`: a
+    pass over that much memory that only re-lays data out (a reshape that
+    moves nothing is a `bitcast` by then). What the whole-store copy of
+    PERF.md finding 1 was; the rehearsal and tests/test_v5e_compile.py ask
+    with half the obs store's bytes and want none."""
+    out = []
+    for line in hlo_text.splitlines():
+        m = _RELAYOUT.match(line)
+        if m and m.group(2) in _DTYPE_BYTES:
+            size = _DTYPE_BYTES[m.group(2)]
+            for d in filter(None, m.group(3).split(",")):
+                size *= int(d)
+            if size >= min_bytes:
+                out.append(m.group(1))
+    return out

@@ -10,15 +10,21 @@ program's structure, a device scope or a kernel name changes:
 For the collecting program (`mega`) and the update-only one (`multi`) it
 prints one JSON line: the module's name (the benchmark's `step_program`
 pattern wants jit_mega / jit_multi / jit_body), how many instructions each
-category of benchmark/trace_patterns.json matches (`store_copy` must be
-exactly one, its operand an entry parameter; `lstm_kernel` the three Mosaic
-calls), the compiler's memory count (it refuses what does not fit 15.75 GB),
-and how many instructions carry each device scope of utils/profiling.SPANS and
-land in each bucket of benchmark/trace_scopes.json. A renamed scope recompiles
-the step programs cold on the chip (~200 s, nature): settle names here.
+category of benchmark/trace_patterns.json matches (`lstm_kernel` the three
+Mosaic calls; `store_copy` is a pattern for a 5-D u8 copy, which the 4-D row
+store of PR 25 can no longer match, so its 0 proves nothing), every
+instruction that re-lays-out something store-sized (`store_sized_relayouts`: a
+`copy`, `transpose` or `reshape` whose result is at least half the obs store's bytes,
+wherever it sits; must be empty), the compiler's memory count (it refuses what
+does not fit 15.75 GB; `temp_gb` is slab and batch only, `alias_gb` the donated
+arguments the output reuses: the whole store in `mega`), and how many
+instructions carry each device scope of utils/profiling.SPANS and land in each
+bucket of benchmark/trace_scopes.json. A renamed scope recompiles the step
+programs cold on the chip (~200 s, nature): settle names here.
 
 PR 22 found the whole-store copy and the 20 GB refusal this way; PR 23 settled
-its scopes this way (same instruction count as the parent, 9,508 / 4,274)."""
+its scopes this way (same instruction count as the parent, 9,508 / 4,274); PR
+25 chose the store's row format this way (PERF.md finding 25.1)."""
 
 from __future__ import annotations
 
@@ -38,6 +44,8 @@ def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("config", help="a name under benchmark/configs/")
     p.add_argument("--hlo-dir", default=None, help="write each program's as_text() here")
+    p.add_argument("--buffer-capacity", type=int, default=None,
+                   help="compile at another replay capacity than the configuration's (what would fit)")
     args = p.parse_args(argv)
 
     import jax
@@ -65,7 +73,10 @@ def main(argv=None) -> int:
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     conf = harness.load_json(os.path.join(ROOT, "benchmark", "configs", args.config + ".json"))
     traffic = harness.load_json(os.path.join(ROOT, "benchmark", "traffic", "learn.json"))
-    cfg = harness.build_config(conf, 1, {"samples_per_insert": float(traffic["samples_per_insert"])})
+    extra = {"samples_per_insert": float(traffic["samples_per_insert"])}
+    if args.buffer_capacity:
+        extra["buffer_capacity"] = args.buffer_capacity
+    cfg = harness.build_config(conf, 1, extra)
     if cfg.recurrent_core == "lstm":
         cfg = cfg.replace(lstm_backend="pallas")
     dp = max(cfg.dp_size, 1)
@@ -92,6 +103,8 @@ def main(argv=None) -> int:
         lambda x: sds(x.shape, x.dtype, rep),
         jax.eval_shape(lambda: learner.init_train_state(cfg, jax.random.PRNGKey(0))[1]))
     stores = {k: sds((cfg.num_blocks, *shape), dt, per_dp) for k, (shape, dt) in store_field_specs(cfg).items()}
+    # per device: under dp the block axis is split over the shards
+    obs_store_bytes = int(np.prod(stores["obs"].shape)) // dp
     env = jax.tree.map(
         lambda x: sds(x.shape, x.dtype, per_dp),
         jax.eval_shape(lambda: jax.vmap(fn_env.reset)(jax.random.split(jax.random.PRNGKey(0), E))))
@@ -128,9 +141,11 @@ def main(argv=None) -> int:
             "matches": {k: sum(1 for l in instr if re.search(rx, l)) for k, rx in patterns.items()
                         if k != "step_program"},
             "store_copy": [l.split(", backend_config")[0] for l in instr if re.search(patterns["store_copy"], l)],
+            "store_sized_relayouts": profiling.relayouts_at_least(text, obs_store_bytes // 2),
             "lstm_kernels": [l.split(" = ")[0] for l in instr if re.search(patterns["lstm_kernel"], l)],
             "temp_gb": round(memory.temp_size_in_bytes / 1e9, 2),
             "argument_gb": round(memory.argument_size_in_bytes / 1e9, 2),
+            "alias_gb": round(memory.alias_size_in_bytes / 1e9, 2),
             "with_op_name": len(op_names),
             "in_scope": {s: sum(f"jit({s})" in v for v in op_names.values()) for s in device_scopes},
             "in_bucket": in_bucket,

@@ -4,6 +4,7 @@ native core keyed on its source's content. (The launch-counter rule sits
 with the other analysis tests, tests/test_analysis.py.)"""
 
 import ast
+import json
 import os
 import shutil
 import subprocess
@@ -97,6 +98,72 @@ def test_chip_smoke_alone_fails(tmp_path):
     assert r.returncode != 0
     assert "FAILED" in r.stderr and "kernels" in r.stderr
     assert '"ok"' not in r.stdout
+
+
+_TINY_STORE = ["num_actors=8", "batch_size=8", "buffer_capacity=512"]
+
+
+def test_chip_smoke_store_bytes_reads_every_plane_on_four_devices():
+    """The store-bytes phase at tiny size on four host devices: the plain
+    jit, the sharded plane's shard_map and the GSPMD gather, each before, in
+    the same program as, and after an in-place slab write — all bit for bit."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4", "PYTHONPATH": REPO}
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--phase", "store_bytes",
+         "--preset", "tiny_test", "--sets", *_TINY_STORE],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
+    rows = [json.loads(l[12:]) for l in r.stdout.splitlines() if l.startswith("STORE_BYTES ")]
+    assert {(v["plane"], v["moment"]) for v in rows} == {
+        (p, m) for p in ("jit", "shard_map") for m in ("before", "same_program", "after")
+    } | {("gspmd", "before"), ("gspmd", "after")}
+    assert all(v["verdict"] == "ok" and v["mismatched_bytes"] == 0 for v in rows)
+    # the write lands on slots the gathers read, in every plane and moment
+    assert all(v["read_from_slab_slots"] > 0 for v in rows)
+    assert "STORE_BYTES_DONE failed=0" in r.stdout
+
+
+@pytest.mark.parametrize("fault", ["next_row", "next_byte", "write_skips_obs"])
+def test_chip_smoke_store_bytes_sees_wrong_bytes(fault, monkeypatch, capsys):
+    """The pattern tells a gather that is off by one slot row or by one byte
+    inside the frame, and a slab write that leaves the obs store as it was,
+    from the right ones."""
+    import jax.numpy as jnp
+
+    import chip_smoke
+    from r2d2_tpu import learner, megastep
+
+    good_gather, good_write = learner.make_store_gather, megastep._slab_write
+
+    def broken_gather(cfg):
+        gather = good_gather(cfg)
+
+        def gather_batch(stores, b, s, w):
+            if fault == "next_row":
+                stores = {**stores, "obs": jnp.roll(stores["obs"], -1, axis=1)}
+            batch = gather(stores, b, s, w)
+            if fault == "next_byte":
+                flat = batch.obs.reshape(*batch.obs.shape[:2], -1)
+                batch = batch._replace(obs=jnp.roll(flat, -1, axis=-1).reshape(batch.obs.shape))
+            return batch
+
+        return gather_batch
+
+    if fault == "write_skips_obs":
+        monkeypatch.setattr(megastep, "_slab_write",
+                            lambda stores, fields, start: {**good_write(stores, fields, start), "obs": stores["obs"]})
+    else:
+        monkeypatch.setattr(learner, "make_store_gather", broken_gather)
+    assert chip_smoke._store_bytes_child("tiny_test", _TINY_STORE, batches=1) == 1
+    rows = [json.loads(l[12:]) for l in capsys.readouterr().out.splitlines() if l.startswith("STORE_BYTES ")]
+    bad = {(v["plane"], v["moment"]) for v in rows if v["verdict"] != "ok"}
+    planes = {v["plane"] for v in rows}
+    if fault == "write_skips_obs":  # only what follows the write is wrong
+        assert bad == {(p, "after") for p in planes}
+    else:
+        assert bad == {(v["plane"], v["moment"]) for v in rows}
 
 
 # ------------------------------------------------------- native replay core

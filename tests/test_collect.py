@@ -18,6 +18,7 @@ from r2d2_tpu.config import tiny_test
 from r2d2_tpu.envs.catch import CatchEnv
 from r2d2_tpu.envs.fake import ScriptedEnv, ScriptedFnEnv
 from r2d2_tpu.learner import init_train_state, make_fused_train_step
+from r2d2_tpu.replay.block import frames_to_rows, rows_to_frames
 from r2d2_tpu.replay.device_store import DeviceReplayBuffer
 
 E = 3
@@ -63,9 +64,12 @@ def _compare(cfg, fields, prios, num_seq, sizes, i, block, host_prios):
     assert size == len(block.action)
     ns = int(num_seq[i])
     assert ns == block.num_sequences
-    np.testing.assert_array_equal(np.asarray(fields["obs"][i])[: size + 1], block.obs)
-    # entries past size+1 are zeroed padding
-    assert not np.asarray(fields["obs"][i])[size + 1 :].any()
+    # the collector packs obs as the store holds them: lane-aligned rows
+    obs_rows = np.asarray(fields["obs"][i])
+    np.testing.assert_array_equal(rows_to_frames(obs_rows, cfg.obs_shape)[: size + 1], block.obs)
+    # entries past size+1 are zeroed padding, and so is every frame's tail
+    assert not obs_rows[size + 1 :].any()
+    np.testing.assert_array_equal(obs_rows, frames_to_rows(rows_to_frames(obs_rows, cfg.obs_shape), cfg.obs_shape))
     np.testing.assert_array_equal(
         np.asarray(fields["last_action"][i])[: size + 1], block.last_action.astype(np.int32)
     )

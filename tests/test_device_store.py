@@ -139,3 +139,106 @@ def test_multi_step_matches_sequential_fused():
     for a, bb in zip(jax.tree.leaves(state_m.target_params), jax.tree.leaves(state.target_params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(bb), atol=1e-6)
     np.testing.assert_allclose(np.asarray(p_m), np.stack(prios_seq), atol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# The obs store's row format (replay/block.frames_to_rows): each frame is its
+# bytes, zero-padded to a multiple of 128, as (R, 128). Writers and readers
+# share ONE pair of helpers; nothing downstream of a batch sees rows.
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+@pytest.mark.parametrize(
+    "obs_shape, rows",
+    [((84, 84, 1), 56), ((16, 8), 1), ((50,), 1), ((12, 12, 1), 2), ((128, 2), 2)],
+    ids=["nature-7056B", "exactly-128B", "vector-50B", "tiny-144B", "exactly-256B"],
+)
+def test_frames_to_rows_and_back_is_the_identity(obs_shape, rows, backend):
+    from r2d2_tpu.replay.block import LANES, frames_to_rows, obs_rows, rows_to_frames
+
+    frames = np.random.default_rng(0).integers(1, 256, (3, 5, *obs_shape), dtype=np.uint8)
+    given = frames if backend == "numpy" else jax.numpy.asarray(frames)
+    packed = frames_to_rows(given, obs_shape)
+    assert isinstance(packed, np.ndarray) == (backend == "numpy")
+    assert obs_rows(obs_shape) == rows and packed.shape == (3, 5, rows, LANES) and packed.dtype == np.uint8
+    flat = np.asarray(packed).reshape(3, 5, -1)
+    n = int(np.prod(obs_shape))
+    np.testing.assert_array_equal(flat[..., :n], frames.reshape(3, 5, n))  # the frame's bytes, in order
+    assert not flat[..., n:].any()                                        # the tail is zero
+    np.testing.assert_array_equal(np.asarray(rows_to_frames(packed, obs_shape)), frames)
+    with pytest.raises(ValueError):
+        frames_to_rows(given[..., :-1], obs_shape)
+    with pytest.raises(ValueError):
+        rows_to_frames(packed[..., :-1, :], obs_shape)
+
+
+@pytest.mark.parametrize("obs_shape", [(3, 3, 1), (16, 8), (50,)], ids=["image", "exactly-128B", "vector"])
+def test_gather_on_a_row_store_equals_the_frame_gather(obs_shape):
+    """`gather_batch` reads rows; its batch carries the frames the parent's
+    gather (`frames[bcol, rows]`, kept here on the host buffer's frame store)
+    takes from the same blocks, bit for bit."""
+    from r2d2_tpu.learner import make_store_gather
+    from r2d2_tpu.replay.accumulator import SequenceAccumulator
+    from r2d2_tpu.replay.block import store_field_specs
+
+    cfg = small_cfg(obs_shape=obs_shape, batch_size=6)
+    host, dev = ReplayBuffer(cfg), DeviceReplayBuffer(cfg)
+    rng = np.random.default_rng(3)
+    for k in range(4):
+        acc = SequenceAccumulator(cfg)
+        acc.reset(rng.integers(1, 256, obs_shape, dtype=np.uint8))
+        for t in range(cfg.block_length - (k % 2)):
+            acc.add(int(rng.integers(cfg.action_dim)), float(rng.normal()), rng.integers(1, 256, obs_shape, dtype=np.uint8),
+                    rng.normal(size=cfg.action_dim).astype(np.float32), rng.normal(size=(2, cfg.hidden_dim)).astype(np.float32))
+        block, prios, ep = acc.finish(rng.normal(size=cfg.action_dim).astype(np.float32))
+        host.add_block(block, prios, ep)
+        dev.add_block(block, prios, ep)
+    assert dev.stores["obs"].shape == (cfg.num_blocks, *store_field_specs(cfg)["obs"][0])
+    assert dev.stores["obs"].shape[-1] == 128 and dev.stores["obs"].dtype == np.uint8
+
+    si = dev.sample_indices(np.random.default_rng(11))
+    batch = dev.run_with_stores(
+        lambda stores: jax.jit(make_store_gather(cfg))(stores, si.b, si.s, si.is_weights))
+    assert batch.obs.shape == (cfg.batch_size, cfg.seq_len, *obs_shape) and batch.obs.dtype == np.uint8
+    # the parent's gather, on frames
+    L, T = cfg.learning_steps, cfg.seq_len
+    burn = host.burn_in_store[si.b, si.s]
+    win = host.burn_in_store[si.b, 0] + si.s * L - burn
+    rows = np.clip(win[:, None] + np.arange(T)[None, :], 0, cfg.block_slot_len - 1)
+    np.testing.assert_array_equal(np.asarray(batch.obs), host.obs_store[si.b[:, None], rows])
+    hb = host.sample_batch(np.random.default_rng(11))
+    np.testing.assert_array_equal(np.asarray(batch.obs), hb.obs)
+
+
+@pytest.mark.parametrize("writer", ["pad_block_fields", "collector"])
+@pytest.mark.parametrize("precision", ["fp32", "bf16"])
+def test_every_writer_produces_store_field_specs(writer, precision):
+    """Host blocks (`pad_block_fields`) and the on-device collector's packed
+    chunk are what the donated writes receive: each field must match
+    `store_field_specs` exactly, obs rows included (the analysis plane's
+    rule, not a looser copy of it)."""
+    from r2d2_tpu.analysis.jaxpr_rules import check_store_field_dtypes, compare_store_fields
+    from r2d2_tpu.collect import make_collect_fn
+    from r2d2_tpu.envs.fake import ScriptedFnEnv
+    from r2d2_tpu.models.r2d2 import R2D2Network
+    from r2d2_tpu.replay.block import obs_rows, store_field_specs
+
+    if writer == "pad_block_fields":
+        assert check_store_field_dtypes(precision) == []
+        return
+    cfg = tiny_test().replace(precision=precision)
+    net = R2D2Network.from_config(cfg)
+    fn_env = ScriptedFnEnv(obs_shape=cfg.obs_shape, action_dim=cfg.action_dim)
+    collect = make_collect_fn(cfg, net, fn_env, cfg.num_actors, cfg.block_length)
+    _, state = init_train_state(cfg, jax.random.PRNGKey(0))
+    env_state = jax.eval_shape(lambda: jax.vmap(fn_env.reset)(jax.random.split(jax.random.PRNGKey(0), cfg.num_actors)))
+    out = jax.eval_shape(collect, state.params, env_state, jax.numpy.zeros(cfg.num_actors), jax.random.PRNGKey(1))
+    fields = out[0]
+    specs = store_field_specs(cfg)
+    assert specs["obs"][0] == (cfg.block_slot_len, obs_rows(cfg.obs_shape), 128)
+    per_env = {k: jax.ShapeDtypeStruct(v.shape[1:], v.dtype) for k, v in fields.items()}
+    assert {v.shape[0] for v in fields.values()} == {cfg.num_actors}
+    assert compare_store_fields(per_env, specs, "collector") == []
+    # and the rule still bites: frames where rows are expected are a finding
+    per_env["obs"] = jax.ShapeDtypeStruct((cfg.block_slot_len, *cfg.obs_shape), np.uint8)
+    assert [f.rule for f in compare_store_fields(per_env, specs, "collector")] == ["jaxpr-store-field-mismatch"]

@@ -260,3 +260,30 @@ def test_compile_listener_sums_seconds_per_function():
     rows = {name: (tr, lo, co) for name, tr, lo, co in cc.costliest_compiles(n=10**6)}
     tr, lo, co = rows["a_function_with_this_name"]
     assert tr > 0 and lo > 0 and co > 0
+
+
+@pytest.mark.parametrize(
+    "line, found",
+    [
+        # the parent's whole-store copy, as its compiled module has it
+        ("  %copy.187 = u8[1280,441,84,84,1]{3,2,1,0,4:T(8,128)(4,1)} copy(%stores__obs__.1), metadata={op_name=\"x\"}", True),
+        ("  ROOT %transpose.3 = u8[1280,441,56,128]{3,2,1,0} transpose(%p.1), dimensions={0,1,3,2}", True),
+        ("  %reshape.9 = bf16[640000,7168]{1,0} reshape(%fusion.1)", True),
+        # inside a fusion's computation the line reads the same
+        ("    %copy.5 = f32[1000,1000,1000]{2,1,0} copy(%param_0.7)", True),
+        # a reshape that moves nothing, a small copy, and an in-place update are not re-layouts
+        ("  %bitcast.4 = u8[564480,56,128]{2,1,0} bitcast(%stores__obs__.1)", False),
+        ("  %copy.297 = u8[5440,84,84]{2,1,0} copy(%fusion.522)", False),
+        ("  %dynamic-update-slice.15 = u8[1280,441,56,128]{3,2,1,0} dynamic-update-slice(%a, %b, %c, %d, %e, %f)", False),
+        # a dtype the table does not know is skipped, not guessed
+        ("  %copy.1 = token[] copy(%t)", False),
+    ],
+    ids=["parent-copy", "root-transpose", "reshape", "in-fusion", "bitcast", "small", "in-place-write", "unknown-dtype"],
+)
+def test_relayouts_at_least_finds_store_sized_passes_only(line, found):
+    store_bytes = 1280 * 441 * 56 * 128
+    got = profiling.relayouts_at_least("HloModule m\n" + line + "\n", store_bytes // 2)
+    assert bool(got) == found
+    if found:  # the instruction up to its operands, without the attributes after them
+        body = line.strip().removeprefix("ROOT ")
+        assert len(got) == 1 and body.startswith(got[0]) and got[0].endswith(")") and "metadata" not in got[0]

@@ -20,6 +20,7 @@ from r2d2_tpu.learner import (
     make_train_step,
 )
 from r2d2_tpu.parallel.mesh import make_mesh
+from r2d2_tpu.replay.block import rows_to_frames
 from r2d2_tpu.replay.sharded_store import ShardedDeviceReplay
 from tests.test_replay_buffer import make_block
 
@@ -113,7 +114,7 @@ def test_sharded_step_matches_single_device(mesh):
     rows = np.clip((start - burn)[:, None] + np.arange(T)[None, :], 0, cfg.block_slot_len - 1)
     lrow = s[:, None] * L + np.arange(L)[None, :]
     batch = DeviceBatch(
-        obs=jnp.asarray(host["obs"][gb[:, None], rows]),
+        obs=jnp.asarray(rows_to_frames(host["obs"][gb[:, None], rows], cfg.obs_shape)),
         last_action=jnp.asarray(host["last_action"][gb[:, None], rows]),
         last_reward=jnp.asarray(host["last_reward"][gb[:, None], rows]),
         hidden=jnp.asarray(host["hidden"][gb, s]),
@@ -332,7 +333,7 @@ def test_sharded_step_tp2_matches_single_device():
     )
     lrow = s[:, None] * L + np.arange(L)[None, :]
     batch = DeviceBatch(
-        obs=jnp.asarray(host["obs"][gb[:, None], rows]),
+        obs=jnp.asarray(rows_to_frames(host["obs"][gb[:, None], rows], cfg.obs_shape)),
         last_action=jnp.asarray(host["last_action"][gb[:, None], rows]),
         last_reward=jnp.asarray(host["last_reward"][gb[:, None], rows]),
         hidden=jnp.asarray(host["hidden"][gb, s]),

@@ -2,6 +2,8 @@
 bit-identical to the saved one across all three data planes — same
 counters, same tree, and the same RNG stream draws the same batches."""
 
+import os
+
 import jax
 import numpy as np
 import pytest
@@ -144,3 +146,66 @@ def test_restore_failure_leaves_buffer_untouched(tmp_path):
     assert len(other) == 0
     assert other.tree.total == 0.0
     assert not other.occupied.any()
+
+
+FIXTURE = os.path.join(os.path.dirname(__file__), "fixtures", "replay_snapshot_pr23_device.npz")
+
+
+def _fixture_replay(cfg, cls):
+    """The buffer tests/fixtures/replay_snapshot_pr23_device.npz was saved
+    from: written by the tree of PR 23 (commit 2dd2d55, whose device store
+    held raw frames) with exactly these calls on a DeviceReplayBuffer."""
+    from bench import synth_block
+
+    replay = cls(cfg)
+    rng = np.random.default_rng(24)
+    for _ in range(3):
+        replay.add_block(
+            synth_block(cfg, rng), rng.uniform(0.5, 2.0, cfg.seqs_per_block).astype(np.float32), float(rng.normal()))
+    return replay
+
+
+@pytest.mark.parametrize("how", ["restore", "reshard_to_device", "reshard_to_sharded", "reshard_to_host"])
+def test_a_snapshot_written_before_the_row_format_loads(how):
+    """Snapshot files hold obs as FRAMES on every plane, before and after PR
+    25: a file the parent wrote restores into today's row store, reshards
+    onto any plane, and today's device plane writes the same bytes back."""
+    from r2d2_tpu.replay.block import frames_to_rows
+    from r2d2_tpu.replay.reshard import reshard_replay
+    from r2d2_tpu.replay.sharded_store import ShardedDeviceReplay
+    from r2d2_tpu.parallel.mesh import make_mesh
+
+    cfg = tiny_test().replace(buffer_capacity=64)
+    with np.load(FIXTURE) as npz:
+        frames = npz["store_obs"]
+    assert frames.shape == (cfg.num_blocks, cfg.block_slot_len, *cfg.obs_shape) and frames.any()
+    want = _fixture_replay(cfg, DeviceReplayBuffer)
+    if how == "restore":
+        fresh = DeviceReplayBuffer(cfg)
+        restore_replay(fresh, FIXTURE)
+    elif how == "reshard_to_device":
+        fresh = DeviceReplayBuffer(cfg)
+        reshard_replay(fresh, [FIXTURE])
+    elif how == "reshard_to_sharded":
+        fresh = ShardedDeviceReplay(cfg, make_mesh(dp=1, tp=1, devices=jax.devices()[:1]))
+        reshard_replay(fresh, [FIXTURE])
+    else:
+        fresh = ReplayBuffer(cfg)
+        reshard_replay(fresh, [FIXTURE])
+        np.testing.assert_array_equal(fresh.obs_store, frames)  # the host plane keeps frames
+        return
+    np.testing.assert_array_equal(np.asarray(fresh.stores["obs"]), frames_to_rows(frames, cfg.obs_shape))
+    for k, v in want.stores.items():
+        np.testing.assert_array_equal(np.asarray(fresh.stores[k]), np.asarray(v), err_msg=k)
+
+
+def test_the_device_plane_still_writes_the_parents_file(tmp_path):
+    cfg = tiny_test().replace(buffer_capacity=64)
+    path = str(tmp_path / "snap.npz")
+    save_replay(_fixture_replay(cfg, DeviceReplayBuffer), path)
+    with np.load(FIXTURE) as old, np.load(path) as new:
+        assert sorted(old.files) == sorted(new.files)
+        for k in old.files:
+            if k.startswith("store_") or k in ("tree_leaves", "block_ptr", "size", "occupied"):
+                assert old[k].dtype == new[k].dtype and old[k].shape == new[k].shape, k
+                np.testing.assert_array_equal(old[k], new[k], err_msg=k)

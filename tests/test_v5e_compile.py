@@ -17,6 +17,7 @@ unnoticed. The only skip is an installation without libtpu."""
 
 import importlib.util
 import json
+import math
 import os
 import re
 
@@ -110,3 +111,46 @@ def test_every_kernel_instruction_matches_the_benchmarks_lstm_pattern(arm, rows,
     assert len(calls) == 2 and all(pattern.search(re.sub(r"^ROOT ", "", l)) for l in calls), names
     # named after the wrapper, so a pattern can tell the arms apart
     assert sorted(re.sub(r"^%|\.\d+$", "", n) for n in names) == sorted(wrappers)
+
+
+@pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581"])
+def test_store_gather_and_slab_write_read_the_obs_store_in_place(config, one_chip, compiled_kernels):
+    """The replay half of both step programs at a benchmark configuration's
+    real store shape: K-free gather of one batch, then the donated slab write
+    of one collected chunk. With frames stored as lane-aligned rows
+    (replay/block.frames_to_rows) the chip's compiler reads and writes the
+    4 GB obs store in place; with raw (84, 84, 1) frames it re-laid the whole
+    store out on every dispatch (PERF.md finding 1: 6.4 GB of temp)."""
+    from benchmark import harness
+    from r2d2_tpu import learner, megastep
+    from r2d2_tpu.replay.block import store_field_specs
+    from r2d2_tpu.utils import profiling
+
+    conf = harness.load_json(os.path.join(ROOT, "benchmark", "configs", config + ".json"))
+    cfg = harness.build_config(conf, 1)
+    E, B = cfg.num_actors, cfg.batch_size
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+    specs = store_field_specs(cfg)
+    stores = {k: sds((cfg.num_blocks, *shape), dt) for k, (shape, dt) in specs.items()}
+    chunk = {k: sds((E, *shape), dt) for k, (shape, dt) in specs.items()}
+    gather = learner.make_store_gather(cfg)
+
+    def replay_half(stores, chunk, ptr0, b, s, w):
+        batch = gather(stores, b, s, w)
+        return megastep._slab_write(stores, chunk, ptr0), batch
+
+    compiled = jax.jit(replay_half, donate_argnums=(0,)).lower(
+        stores, chunk, sds((), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.float32)
+    ).compile()
+    obs_store_bytes = cfg.num_blocks * math.prod(specs["obs"][0])
+    assert obs_store_bytes > 3.9e9  # the benchmark's real size, not a toy
+    text = compiled.as_text()
+    assert profiling.relayouts_at_least(text, obs_store_bytes // 2) == []
+    # the obs gather indexes ONE flattened (block * slot) axis: the two-index
+    # form of the same gather compiles too, and halts the v5e's core when it
+    # runs (PERF.md finding 25.2; learner.make_store_gather says the same)
+    obs_gathers = [l.strip() for l in text.splitlines() if re.search(r"= u8\[[\d,]+,128\]\S* gather\(", l)]
+    assert obs_gathers and all("collapsed_slice_dims={0}," in l for l in obs_gathers), obs_gathers
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= obs_store_bytes  # the store is updated in place
+    assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes  # slab and batch only
