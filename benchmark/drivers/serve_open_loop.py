@@ -102,7 +102,8 @@ def run(ctx: harness.Context) -> harness.Measured:
         q_served, acts = _closed_rounds(
             server, [f"check{i}" for i in range(S)], lambda j, r: c_obs[j, r],
             lambda j, r: float(c_rew[j, r]), True, T)
-        check = correct.serve_vs_reference(cfg, template.params, c_obs, acts, c_rew, q_served)
+        check = correct.serve_vs_reference(
+            harness.reference_for(cell), cfg, template.params, c_obs, acts, c_rew, q_served)
         for i in range(S):
             server.evict(f"check{i}")
 

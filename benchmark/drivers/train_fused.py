@@ -19,6 +19,17 @@ the previous boundary. The window opens at the first boundary (both dispatch
 variants have run by then), and closes at the last boundary before
 `--seconds` is up; each end is a readback of `state.step`, so every dispatch
 counted has finished on the device.
+
+`correct`'s reference check is judged at the window's START state: the
+parameters and a batch of stored sequences are taken after `setup_s` is
+stamped and before the window opens. Warm-up ends on the pacer's counters
+(the first period boundary), not on the clock, so a slow program and a fast
+one, a parent and a change, are all checked after the same number of updates.
+The comparison itself runs after the window. The same comparison at the END
+state (`notes.checks.reference_end`) is judged on Q alone, which is measured
+on the tensor's own scale; its loss and gradient norm move with how far the
+window trained and are recorded.
+The loss island is judged alone too, at the start state.
 """
 
 from __future__ import annotations
@@ -66,14 +77,13 @@ def valid_step_share(replay, learning_steps: int) -> float:
     return steps / max(seqs * learning_steps, 1)
 
 
-def _sample_batch(cfg, trainer, n: int, seed: int):
+def _sample_batch(cfg, trainer, gather, n: int, seed: int):
     """`n` stored sequences drawn through the replay's own sampler with a
-    generator of the benchmark's (the trainer's stream is left alone),
-    gathered from the device store and brought to the host."""
+    generator of the benchmark's (the trainer's stream is left alone; a draw
+    changes nothing in the replay), gathered from the device store by
+    `gather` (the jitted learner.make_store_gather) and brought to the host."""
     import jax
     import jax.numpy as jnp
-
-    from r2d2_tpu.learner import make_store_gather
 
     idx = trainer.replay.sample_indices(np.random.default_rng(seed))
     b, s = np.asarray(idx.b), np.asarray(idx.s)
@@ -81,18 +91,30 @@ def _sample_batch(cfg, trainer, n: int, seed: int):
         per = cfg.num_blocks // b.shape[0]
         b = b + (np.arange(b.shape[0]) * per)[:, None]
     b, s = b.reshape(-1)[:n].astype(np.int32), s.reshape(-1)[:n].astype(np.int32)
-    gather = jax.jit(make_store_gather(cfg))
     batch = trainer.replay.run_with_stores(
         lambda st: gather(st, jnp.asarray(b), jnp.asarray(s), jnp.ones(b.shape[0], jnp.float32)))
     return jax.device_get(batch)  # host arrays: the check then runs on one device
 
 
+def _operating_point(cfg, trainer, gather, state, n_seq: int, seed: int) -> dict:
+    """What the reference check is asked at: the online and target parameters
+    as they are now (host copies: the runners donate their state) and `n_seq`
+    stored sequences drawn now."""
+    import jax
+
+    params, target_params = jax.device_get((state.params, state.target_params))
+    return {"updates": _sync(state), "params": params, "target_params": target_params,
+            "batch": _sample_batch(cfg, trainer, gather, n_seq, seed)}
+
+
 def run(ctx: harness.Context) -> harness.Measured:
     import jax
 
+    from r2d2_tpu.learner import make_store_gather
     from r2d2_tpu.train import Trainer
 
     cell, tr_cfg = ctx.cell, ctx.cell.traffic
+    ref = harness.reference_for(cell)
     extra = {
         "samples_per_insert": float(tr_cfg["samples_per_insert"]),
         "training_steps": 10**9, "save_interval": 10**9, "log_interval": 3600.0,
@@ -135,9 +157,16 @@ def run(ctx: harness.Context) -> harness.Measured:
         if warm > int(tr_cfg.get("max_warm_dispatches", 400)):
             raise harness.BenchmarkError("no collect period boundary during warm-up")
     _sync(state)
-    compiles0 = harness.compile_requests()
     ctx.counters["cli.compile_misses"] = harness.compile_misses()
     setup_s = time.perf_counter() - ctx.t_start
+
+    # ---- the reference check's operating point: after the stamp (it is the
+    # benchmark's work, not the program's set-up), before the window opens
+    n_seq = int(tr_cfg.get("correct_sequences", 8))
+    gather = jax.jit(make_store_gather(cfg))
+    start = _operating_point(cfg, trainer, gather, state, n_seq, ctx.seed)
+    capture_s = time.perf_counter() - ctx.t_start - setup_s
+    compiles0 = harness.compile_requests()
 
     # ---- the window: whole periods, at most `seconds` (one period at least)
     budget = ctx.seconds
@@ -175,13 +204,18 @@ def run(ctx: harness.Context) -> harness.Measured:
         "compiles_in_window": compiles_in_window,
     })
 
-    # ---- correct: outside the window
-    n_seq = int(tr_cfg.get("correct_sequences", 8))
+    # ---- correct: outside the window. The reference check is judged at the
+    # window's start state and recorded at its end state
+    check = correct.ReferenceCheck(ref, cfg, trainer.net, cell.config)
+    end = _operating_point(cfg, trainer, gather, state, n_seq, ctx.seed)
     checks = {
-        "kernels": correct.kernels_vs_scan(cfg, ctx.seed, max(cfg.batch_size // max(cfg.dp_size, 1), 1)),
-        "reference": correct.system_vs_reference(
-            cfg, trainer.net, jax.device_get(state), _sample_batch(cfg, trainer, n_seq, ctx.seed)),
+        "kernels": correct.kernel_checks(ref, cfg, ctx.seed, max(cfg.batch_size // max(cfg.dp_size, 1), 1)),
+        "reference": check(start["params"], start["target_params"], start["batch"]),
+        "reference_end": check(end["params"], end["target_params"], end["batch"], judged=correct.END_STATE),
+        "loss_island": correct.loss_island(ref, cfg, start["params"], start["target_params"], start["batch"]),
     }
+    checks["reference"]["updates_at_check"] = start["updates"]
+    checks["reference_end"]["updates_at_check"] = end["updates"]
     finite = bool(np.isfinite(loss_host).all())
     ok = (finite and compiles_in_window == 0 and all(c["ok"] for c in checks.values())
           and share >= float(tr_cfg.get("min_valid_step_share", 0.0)))
@@ -196,5 +230,6 @@ def run(ctx: harness.Context) -> harness.Measured:
         notes={"checks": checks, "window_s": elapsed, "dispatches": dispatches,
                "period_s": period_s, "ring_fill_s": fill_s, "valid_step_share": share,
                "compiles_in_window": compiles_in_window, "runtime": runtime,
-               "loss_last": float(loss_host[-1]), "warm_dispatches": warm},
+               "loss_last": float(loss_host[-1]), "warm_dispatches": warm,
+               "start_capture_s": capture_s},
     )

@@ -5,6 +5,9 @@ name alone, so a later PR adds a cell by adding files and manifest entries and
 edits nothing that exists:
 
     workloads[i].config  -> configs[j].file      (a JSON of sizes, preset + overrides)
+    config["reference"]  -> <paths[0]>/reference/<name>.py      (default "model": what the
+                            yardstick knows about the architecture, see reference_for)
+    config["limits"]     -> limits of `correct` that this configuration states for itself
     workloads[i].traffic -> <paths[0]>/traffic/<traffic>.json   (parameters + "driver")
     traffic["driver"]    -> benchmark.drivers.<driver>.run(ctx) (code; two kinds today)
     per_layer[k].name    -> <paths[0]>/layers/<name>.json       (reader kind + parameters)
@@ -19,7 +22,9 @@ left out) with --trace 1.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
+import importlib.util
 import json
 import math
 import os
@@ -104,6 +109,51 @@ def load_cell(root: str, workload: str) -> Cell:
         traffic=load_json(os.path.join(bench_dir, "traffic", w["traffic"] + ".json")),
         bench_dir=bench_dir,
     )
+
+
+# What a reference module must define, and what it may. The whole contract
+# between the benchmark and an architecture (PERF.md section 3):
+#   sizes_of(cfg) -> the shape facts its functions need
+#   loss_q_gradnorm(params, target_params, batch, sizes) -> (loss, q_learn, grad norm)
+#       `batch` is a dict of arrays; batch["hidden"] is the stored state as
+#       the replay holds it, whatever its shape
+#   act_unroll(params, obs, last_action, last_reward, sizes) -> Q (S, T, A)
+#       acting from the module's own zero state
+#   update_flops(cfg) -> operations one learner update requires (model.mfu)
+#   optional kernel_checks(cfg, seed, batch) -> dict with "ok": each kernel of
+#       the architecture against its plain form at the cell's own shapes
+#   optional TOL: {compute dtype: limits} where correct.TOL does not fit it
+#   optional island_inputs(params, target_params, batch, sizes) -> the Q views the
+#       loss island reads (q_learn, q_boot, q_boot_target, mask), with
+#       loss_from_q(q_learn, q_boot, q_boot_target, mask, batch, sizes):
+#       correct.loss_island asks the program's own island on them, alone
+# A configuration file may state limits of its own for its cells (`"limits"`,
+# keys of correct.TOL, each with its reason under `"limits_why"`).
+REFERENCE_CONTRACT = ("sizes_of", "loss_q_gradnorm", "act_unroll", "update_flops")
+
+
+@functools.lru_cache(maxsize=None)
+def _module_from_file(path: str):
+    spec = importlib.util.spec_from_file_location("benchmark_reference_" + os.path.basename(path)[:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference_for(cell: Cell):
+    """The reference module the cell's configuration names (`"reference"`,
+    default `model`), loaded from <bench_dir>/reference/<name>.py: by file, so
+    that a later PR adds an architecture as a file and edits nothing."""
+    name = cell.config.get("reference", "model")
+    path = os.path.join(cell.bench_dir, "reference", f"{name}.py")
+    if not str(name).isidentifier() or not os.path.isfile(path):
+        raise BenchmarkError(f"configuration {cell.config_entry['name']!r} names the reference "
+                             f"{name!r}, and there is no file {path}")
+    mod = _module_from_file(os.path.realpath(path))
+    missing = [f for f in REFERENCE_CONTRACT if not callable(getattr(mod, f, None))]
+    if missing:
+        raise BenchmarkError(f"reference module {path} does not define {missing}")
+    return mod
 
 
 def layer_spec(cell: Cell, metric_name: str) -> dict:

@@ -1,23 +1,18 @@
-"""Reader `mfu`: model FLOP/s utilization of the learner, in percent:
-flops.update_flops (what the algorithm requires; recompute not counted) x
-updates/s / (chips x the device_kind's published bf16 peak)."""
+"""Reader `mfu`: model FLOP/s utilization of the learner, in percent: what
+the algorithm requires per update (the `update_flops(cfg)` of the reference
+module the configuration names; recompute not counted) x updates/s /
+(chips x the device_kind's published bf16 peak)."""
 
 import jax
 
-from benchmark import flops
+from benchmark import flops, harness
 
 
 def read(spec, ctx):
     rate = ctx.counters.get("updates_per_s")
-    cfg = ctx.cfg
-    if not rate or cfg is None:
+    if not rate or ctx.cfg is None:
         return None
     peaks = flops.device_peaks(jax.devices()[0].device_kind)
-    per_update = flops.update_flops(
-        encoder=cfg.encoder, obs_shape=cfg.obs_shape, hidden=cfg.hidden_dim,
-        action_dim=cfg.action_dim, core=cfg.recurrent_core, lru_chunk=cfg.lru_chunk,
-        batch=cfg.batch_size, burn_in=cfg.burn_in_steps, learning=cfg.learning_steps,
-        forward=cfg.forward_steps,
-    )
+    per_update = harness.reference_for(ctx.cell).update_flops(ctx.cfg)
     chips = ctx.cell.workload["chips"]
     return 100.0 * per_update * rate / (chips * peaks["bf16_flops_per_s"])

@@ -26,14 +26,24 @@ comparison is with the program:
 - the bootstrap index is clamped at the end of the stored sequence
   (edge-repeat), as the reference implementation pads it.
 - gelu is the tanh approximation (flax's default).
+
+This module is the reference `model` (harness.reference_for): what the
+benchmark knows about the architectures {nature, mlp} x {lstm, lru}. Besides
+the forward, loss and gradients it holds their operation count (update_flops,
+through flops.py) and the check of their one kernel (kernel_checks: the
+Pallas LSTM against the scan LSTM). Another architecture is another file here.
 """
 
 from __future__ import annotations
 
-from typing import Dict, NamedTuple, Tuple
+import contextlib
+from typing import Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from benchmark import correct, flops
 
 F32 = jnp.float32
 
@@ -48,6 +58,24 @@ class Sizes(NamedTuple):
     learning: int
     forward: int
     eps: float = 1e-3     # value-rescale epsilon
+
+
+def sizes_of(cfg) -> Sizes:
+    return Sizes(
+        encoder=cfg.encoder, core=cfg.recurrent_core, hidden=cfg.hidden_dim,
+        action_dim=cfg.action_dim, learning=cfg.learning_steps,
+        forward=cfg.forward_steps, eps=cfg.value_rescale_eps,
+    )
+
+
+def update_flops(cfg) -> int:
+    """Operations one learner update requires (flops.update_flops)."""
+    return flops.update_flops(
+        encoder=cfg.encoder, obs_shape=cfg.obs_shape, hidden=cfg.hidden_dim,
+        action_dim=cfg.action_dim, core=cfg.recurrent_core, lru_chunk=cfg.lru_chunk,
+        batch=cfg.batch_size, burn_in=cfg.burn_in_steps, learning=cfg.learning_steps,
+        forward=cfg.forward_steps,
+    )
 
 
 def _f32(tree):
@@ -173,10 +201,9 @@ def inverse_value_rescale(x, eps):
     return jnp.sign(x) * (t * t - 1.0)
 
 
-def loss_and_q(params, target_params, batch, sz: Sizes):
-    """-> (loss, q_learn). `params` are the flax trees' inner "params" dicts."""
-    q_learn, q_boot, mask = q_views(params, batch, sz)
-    _, q_boot_target, _ = q_views(target_params, batch, sz)
+def loss_from_q(q_learn, q_boot, q_boot_target, mask, batch, sz: Sizes):
+    """The loss island alone: double-Q n-step target under the value
+    rescaling, importance-weighted squared TD error over the valid steps."""
     a_star = jnp.argmax(jax.lax.stop_gradient(q_boot), axis=-1)
     q_next = jnp.take_along_axis(q_boot_target, a_star[..., None], axis=-1)[..., 0]
     y = value_rescale(
@@ -186,9 +213,16 @@ def loss_and_q(params, target_params, batch, sz: Sizes):
     y = jax.lax.stop_gradient(y)
     q_taken = jnp.take_along_axis(q_learn, batch["action"][..., None], axis=-1)[..., 0]
     td = y - q_taken
-    w = batch["is_weights"].astype(F32)[:, None]
-    loss = jnp.sum(w * td * td * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return loss, q_learn
+    w = batch["is_weights"][:, None]
+    return jnp.sum(w * td * td * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def loss_and_q(params, target_params, batch, sz: Sizes):
+    """-> (loss, q_learn). `params` are the flax trees' inner "params" dicts."""
+    q_learn, q_boot, mask = q_views(params, batch, sz)
+    _, q_boot_target, _ = q_views(target_params, batch, sz)
+    batch = dict(batch, is_weights=batch["is_weights"].astype(F32))
+    return loss_from_q(q_learn, q_boot, q_boot_target, mask, batch, sz), q_learn
 
 
 def loss_q_gradnorm(params, target_params, batch, sz: Sizes):
@@ -199,6 +233,15 @@ def loss_q_gradnorm(params, target_params, batch, sz: Sizes):
     )
     gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
     return loss, q_learn, gnorm
+
+
+def island_inputs(params, target_params, batch, sz: Sizes) -> Dict:
+    """What the loss island reads, made by the reference alone: the three Q
+    views and the validity mask."""
+    params, target_params = _f32(params), _f32(target_params)
+    q_learn, q_boot, mask = q_views(params, batch, sz)
+    return {"q_learn": q_learn, "q_boot": q_boot, "mask": mask,
+            "q_boot_target": q_views(target_params, batch, sz)[1]}
 
 
 def act_unroll(params, obs, last_action, last_reward, sz: Sizes) -> jnp.ndarray:
@@ -218,3 +261,51 @@ def act_unroll(params, obs, last_action, last_reward, sz: Sizes) -> jnp.ndarray:
 
     _, outs = jax.lax.scan(step, (zero, zero), jnp.swapaxes(x, 0, 1))
     return dueling(params, jnp.swapaxes(outs, 0, 1))
+
+
+def kernel_checks(cfg, seed: int, batch: int) -> Dict:
+    """The Pallas LSTM sequence kernel, forward and default backward arm,
+    against the lax.scan LSTM at (cfg.seq_len, batch, cfg.hidden_dim) and the
+    compute dtype, on seeded inputs: chip_smoke.py's kernel phase, cut to the
+    two programs the cells run. Skipped (ok, with the reason) where the
+    configuration's core is not that kernel."""
+    if cfg.resolved_core_backend != "pallas":
+        return {"ok": True, "skipped": f"core is {cfg.resolved_core_backend}, not the Pallas kernel"}
+    from r2d2_tpu.models.lstm import LSTM
+
+    T, B, H = cfg.seq_len, batch, cfg.hidden_dim
+    D = H + cfg.action_dim + 1
+    dtype = jnp.dtype(cfg.resolved_compute_dtype)
+    fp32 = dtype == jnp.float32
+    rng = np.random.default_rng(seed)
+    xs = jnp.asarray(rng.normal(size=(B, T, D)).astype(np.float32))
+    carry = tuple(jnp.asarray(rng.normal(size=(B, H)).astype(np.float32) * 0.2) for _ in range(2))
+    # the seams collect.py emits: 0 (first window of a block) or the full burn-in
+    burn = jnp.asarray(np.where(np.arange(B) % 4 == 0, 0, cfg.burn_in_steps).astype(np.int32))
+    scan_mod = LSTM(hidden_dim=H, in_dim=D, dtype=dtype, backend="scan")
+    pal_mod = LSTM(hidden_dim=H, in_dim=D, dtype=dtype, backend="pallas")
+    params = scan_mod.init(jax.random.PRNGKey(seed), xs, carry)
+
+    def loss(mod, p):
+        outs, _ = mod.apply(p, xs, carry, burn_in=burn)
+        return jnp.sum(jnp.tanh(outs.astype(jnp.float32)))
+
+    # fp32 parity needs true f32 matmuls on both sides; bf16 runs as production
+    # does (a bf16 kernel under "highest" is refused by Mosaic, PERF.md 6)
+    ctx = jax.default_matmul_precision("highest") if fp32 else contextlib.nullcontext()
+    with ctx:
+        fwd = {n: jax.jit(lambda p, m=m: m.apply(p, xs, carry, burn_in=burn)[0])(params)
+               for n, m in (("scan", scan_mod), ("pallas", pal_mod))}
+        grad = {n: jax.jit(jax.grad(lambda p, m=m: loss(m, p)))(params)
+                for n, m in (("scan", scan_mod), ("pallas", pal_mod))}
+    fwd_err = correct.scale_err(fwd["pallas"], fwd["scan"])
+    l2 = max(
+        float(np.linalg.norm(np.asarray(a, np.float32) - np.asarray(r, np.float32))
+              / (np.linalg.norm(np.asarray(r, np.float32)) + 1e-6))
+        for a, r in zip(jax.tree.leaves(grad["pallas"]), jax.tree.leaves(grad["scan"]))
+    )
+    tol = correct.TOL[dtype.name]
+    finite = bool(np.isfinite(np.asarray(fwd["pallas"], np.float32)).all())
+    return {"ok": finite and fwd_err <= tol["kernel_fwd"] and l2 <= tol["kernel_grad_l2"],
+            "fwd_err_over_scale": fwd_err, "grad_rel_l2": l2, "tbh": [T, B, H],
+            "limits": {"fwd_err_over_scale": tol["kernel_fwd"], "grad_rel_l2": tol["kernel_grad_l2"]}}

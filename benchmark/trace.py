@@ -275,8 +275,10 @@ def top_ops(trace: Trace, container: str, n: int = 10) -> List[List[object]]:
 
 def idle_gaps_by_host_span(trace: Trace, n: int = 10, longest: int = 200) -> List[List[object]]:
     """[[host span name, seconds], ...]: the first device's `longest` idle
-    gaps, each attributed to the benchmark's host span that covers most of it
-    ("unattributed" where none does), summed by name."""
+    gaps, each attributed to the host span that covers most of it, of those
+    that cover it alike the innermost (the program's `r2d2.` spans nest inside
+    the benchmark's `bench.step`; "unattributed" where none does), summed by
+    name."""
     if not trace.ops:
         return []
     dev = sorted(trace.ops)[0]
@@ -289,7 +291,7 @@ def idle_gaps_by_host_span(trace: Trace, n: int = 10, longest: int = 200) -> Lis
             if h.start >= e:
                 break
             ov = min(e, h.end) - max(s, h.start)
-            if ov > cover:
+            if ov > 0.0 and ov >= cover:  # sorted outermost first: a tie goes inwards
                 best, cover = h.name, ov
         acc[best] = acc.get(best, 0.0) + (e - s)
     return [[k, v / 1e9] for k, v in sorted(acc.items(), key=lambda kv: -kv[1])[:n]]

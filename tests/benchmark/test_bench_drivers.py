@@ -124,6 +124,97 @@ def test_bf16_program_sits_between_the_two_tolerance_classes(tmp_root):
     assert r["correct"] and ref["ok"]
     assert ref["q_err_over_scale"] > correct.TOL["float32"]["q"]
     assert ref["q_err_over_scale"] < correct.TOL["bfloat16"]["q"]
+    # judged where the window starts (four warm-up dispatches of K = 2), and
+    # well inside the limits there: the floors are not what lets it pass
+    assert ref["updates_at_check"] == 8 < r["notes"]["checks"]["reference_end"]["updates_at_check"]
+    assert ref["loss_abs_err"] < 0.5 * correct.TOL["bfloat16"]["loss"] * ref["loss_ref"]
+    assert ref["grad_norm_abs_err"] < 0.5 * correct.TOL["bfloat16"]["grad_norm"] * ref["grad_norm_ref"]
+
+
+def test_loss_math_in_bfloat16_fails_at_the_window_start_state(tmp_root, monkeypatch):
+    """The control on the program's side: the loss island's target math (the
+    two value rescalings) computed in bfloat16, where the configuration states
+    float32. The reference check reads false at the same window-start losses
+    that the sound bf16 program passes above; nothing else is at fault."""
+    import jax.numpy as jnp
+
+    import r2d2_tpu.learner as learner
+    from benchmark import correct
+
+    for name in ("value_rescale", "inverse_value_rescale"):
+        real = getattr(learner, name)
+        monkeypatch.setattr(learner, name, lambda x, eps, real=real: real(
+            x.astype(jnp.bfloat16), eps).astype(jnp.float32))
+    r = harness.run_cell(tmp_root, "tiny-bf16.learn", seed=5, seconds=0.5, trace=False, require_tpu=False)
+    ref = r["notes"]["checks"]["reference"]
+    assert r["correct"] is False and not ref["ok"] and r["failed"] == 0
+    assert ref["loss_abs_err"] > 10 * ref["limits"]["loss_abs_err"]
+    assert ref["q_err_over_scale"] < correct.TOL["bfloat16"]["q"]  # the network itself is untouched
+    # the island alone, on the reference's own Q views, says the same with no
+    # bf16 trunk in the way (on the chip this is the check that catches it)
+    island = r["notes"]["checks"]["loss_island"]
+    assert not island["ok"] and island["loss_rel"] > 100 * island["limits"]["loss_rel"]
+
+
+def _host_state(obj, depth=0):
+    """Everything the host keeps in `obj`: arrays byte for byte, counters,
+    generators' states, the program's own objects within (the sum tree, the
+    shards); device buffers by identity."""
+    out = {}
+    for k, v in sorted(vars(obj).items()):
+        if isinstance(v, np.ndarray):
+            out[k] = (v.dtype.str, v.shape, v.tobytes())
+        elif isinstance(v, (bool, int, float, str, type(None))):
+            out[k] = v
+        elif isinstance(v, np.random.Generator):
+            out[k] = repr(v.bit_generator.state)
+        elif callable(getattr(v, "leaves", None)):  # the sum tree: every priority
+            out[k] = np.asarray(v.leaves()).tobytes()
+        elif isinstance(v, dict):
+            out[k] = {kk: id(vv) for kk, vv in v.items()}
+        elif isinstance(v, (list, tuple)) and v and all(hasattr(x, "__dict__") for x in v) and depth < 3:
+            out[k] = [_host_state(x, depth + 1) for x in v]
+        elif type(v).__module__.startswith("r2d2_tpu") and hasattr(v, "__dict__") and depth < 3:
+            out[k] = _host_state(v, depth + 1)
+    return out
+
+
+@pytest.mark.parametrize("cell_name", ["tiny.learn", "tiny-dp4.learn"])
+def test_the_start_state_capture_changes_nothing_in_the_replay_or_the_pacer(tmp_root, tmp_path, cell_name):
+    """What train_fused does between the `setup_s` stamp and `t0` (a draw
+    through the replay's own sampler with the benchmark's generator, a gather
+    from the device stores, a readback of the parameters) leaves the replay's
+    control plane (priorities, counters, pointers), the trainer's sampling
+    stream and the runner's pacing byte for byte as they were, on both planes."""
+    import jax
+
+    from benchmark.drivers import train_fused as drv
+    from r2d2_tpu.learner import make_store_gather
+    from r2d2_tpu.train import Trainer
+
+    cell = harness.load_cell(tmp_root, cell_name)
+    cfg = harness.build_config(cell.config, 3, {
+        "samples_per_insert": 8.0, "training_steps": 10**9, "save_interval": 10**9, "log_interval": 3600.0,
+        "checkpoint_dir": str(tmp_path), "metrics_path": None})
+    cfg = cfg.replace(learning_starts=cfg.buffer_capacity // 2)
+    trainer = Trainer(cfg)
+    trainer.warmup()
+    runner = drv._make_runner(trainer, cfg)
+    state = trainer.state
+    for _ in range(3):
+        state, _, _ = runner.step(state)
+    gather = jax.jit(make_store_gather(cfg))
+    snapshot = lambda: (_host_state(trainer.replay), _host_state(runner), repr(trainer.sample_rng.bit_generator.state))
+    before = snapshot()
+    planes = before[0].get("shards", [before[0]])
+    assert all(len(p["tree"]) > 0 and "ptr_advances" in p and "learning_sum" in p for p in planes)
+    assert "_consumed" in before[1] and "replay_rng" in before[1]
+    at = drv._operating_point(cfg, trainer, gather, state, 8, 3)
+    assert at["updates"] == 3 * cfg.updates_per_dispatch and np.asarray(at["batch"].obs).shape[0] == 8
+    assert snapshot() == before
+    # and the program goes on from there as if nothing had been asked
+    state, _, _ = runner.step(state)
+    runner.finish()
 
 
 def test_dp4_driver_on_virtual_devices(tmp_root):
@@ -186,15 +277,14 @@ def test_traced_runs_yield_layer_metrics_and_breakdown(tmp_root, cpu_trace_patte
     # a learn cell: the readers that have nothing to read (one device, no
     # module line on a CPU, no kernel time to hold against a roofline) leave
     # their metric out; a pattern that matches no event of the trace (no Pallas
-    # kernel, no whole-store copy here) reads 0, so that the line of a later
-    # PR that removes such an operation still carries the metric
+    # kernel here) reads 0, so that the line of a later PR that removes such an
+    # operation still carries the metric
     r = harness.run_cell(tmp_root, "tiny.learn", seed=3, seconds=0.2, trace=True, require_tpu=False)
     assert r["correct"] and {"device.idle_share", "model.mfu", "replay.valid_step_share",
                              "cli.compile_misses"} <= set(r["metrics"])
     assert not {"kernels.lstm_roofline", "collectives.exposed_ms_per_update",
                 "dispatch.gap_ms"} & set(r["metrics"])
     assert r["metrics"]["kernels.lstm_ms_per_update"]["value"] == 0.0
-    assert r["metrics"]["replay.store_copy_ms_per_update"]["value"] == 0.0
     assert r["metrics"]["replay.valid_step_share"]["value"] == 100.0
 
 

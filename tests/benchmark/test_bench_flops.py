@@ -39,12 +39,9 @@ def test_cores_and_heads_by_hand():
 def test_update_flops_of_the_three_configs(name, expect):
     with open(os.path.join(ROOT, "benchmark", "configs", name + ".json")) as fh:
         cfg = harness.build_config(json.load(fh), 0)
-    got = flops.update_flops(
-        encoder=cfg.encoder, obs_shape=cfg.obs_shape, hidden=cfg.hidden_dim,
-        action_dim=cfg.action_dim, core=cfg.recurrent_core, lru_chunk=cfg.lru_chunk,
-        batch=cfg.batch_size, burn_in=cfg.burn_in_steps, learning=cfg.learning_steps,
-        forward=cfg.forward_steps)
-    assert got == expect
+    # through the reference module the configuration names, as readers/mfu.py asks
+    cell = harness.load_cell(ROOT, name + ".learn")
+    assert harness.reference_for(cell).update_flops(cfg) == expect
 
 
 def test_lstm_kernel_costs_and_roofline_by_hand():

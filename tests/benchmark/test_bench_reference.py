@@ -46,18 +46,22 @@ def setup(request):
     return _make(request.param)
 
 
+def _check(cfg, net, state, batch):
+    return correct.ReferenceCheck(ref, cfg, net)(state.params, state.target_params, batch)
+
+
 def test_program_step_matches_reference(setup):
     cfg, net, state, batch = setup
-    out = correct.system_vs_reference(cfg, net, state, batch)
+    out = _check(cfg, net, state, batch)
     assert out["ok"], out
     assert out["q_err_over_scale"] < 1e-5 and out["loss_rel"] < 1e-4 and out["grad_norm_rel"] < 1e-4
 
 
 def test_a_different_rescaling_epsilon_is_caught(setup, monkeypatch):
     cfg, net, state, batch = setup
-    real = correct.sizes_of
-    monkeypatch.setattr(correct, "sizes_of", lambda c: real(c)._replace(eps=1e-2))
-    out = correct.system_vs_reference(cfg, net, state, batch)
+    real = ref.sizes_of
+    monkeypatch.setattr(ref, "sizes_of", lambda c: real(c)._replace(eps=1e-2))
+    out = _check(cfg, net, state, batch)
     assert not out["ok"] and out["loss_rel"] > correct.TOL["float32"]["loss"]
 
 
@@ -65,7 +69,7 @@ def test_a_wrong_weight_is_caught(setup, monkeypatch):
     cfg, net, state, batch = setup
     real = ref.dueling
     monkeypatch.setattr(ref, "dueling", lambda p, h: real(p, h) * 1.01)
-    out = correct.system_vs_reference(cfg, net, state, batch)
+    out = _check(cfg, net, state, batch)
     assert not out["ok"] and out["q_err_over_scale"] > correct.TOL["float32"]["q"]
 
 
@@ -73,11 +77,8 @@ def test_lstm_burn_in_seam_matters_for_the_gradient(monkeypatch):
     # (the LRU reference follows the program: no seam, PERF.md open question)
     cfg, net, state, batch = _make("lstm")
     monkeypatch.setattr(jax.lax, "stop_gradient", lambda x: x)
-    sz = correct.sizes_of(cfg)
-    rb = {"obs": batch.obs, "last_action": batch.last_action, "last_reward": batch.last_reward,
-          "hidden": batch.hidden, "action": batch.action, "n_step_reward": batch.n_step_reward,
-          "gamma": batch.gamma, "burn_in": batch.burn_in_steps, "learning": batch.learning_steps,
-          "forward": batch.forward_steps, "is_weights": batch.is_weights}
+    sz = ref.sizes_of(cfg)
+    rb = correct.reference_batch(batch)
     _, _, g_noseam = ref.loss_q_gradnorm(state.params["params"], state.target_params["params"], rb, sz)
     monkeypatch.undo()
     _, _, g_seam = ref.loss_q_gradnorm(state.params["params"], state.target_params["params"], rb, sz)
@@ -98,7 +99,7 @@ def test_act_unroll_matches_the_programs_act_steps(setup):
                              jnp.asarray(lr[:, t]), carry, method=net.act)
         qs.append(np.asarray(q))
     want = ref.act_unroll(state.params["params"], jnp.asarray(obs), jnp.asarray(la), jnp.asarray(lr),
-                          correct.sizes_of(cfg))
+                          ref.sizes_of(cfg))
     np.testing.assert_allclose(np.stack(qs, axis=1), np.asarray(want), rtol=1e-4, atol=1e-5)
 
 
@@ -106,3 +107,193 @@ def test_value_rescale_is_invertible():
     x = jnp.linspace(-50.0, 50.0, 41)
     np.testing.assert_allclose(ref.inverse_value_rescale(ref.value_rescale(x, 1e-3), 1e-3), x,
                                rtol=1e-4, atol=1e-4)
+
+
+# ---- the floors of the two relative limits (PERF.md finding 26.2)
+
+BF16 = correct.TOL["bfloat16"]
+NO_FLOOR = dict(BF16, loss_floor=0.0, grad_norm_floor=0.0)
+
+
+@pytest.mark.parametrize("loss_ref,abs_err,ok", [
+    (0.003, 2e-5, True),    # the bf16 program's absolute error at a small sampled loss
+    (0.003, 2e-4, False),   # ten times that: the floor has not made the loss blind
+    (0.23, 3.8e-4, True),   # the largest absolute error PR 25 read: the relative limit governs
+    (0.23, 1.0e-3, False),
+])
+def test_the_loss_limit_has_an_absolute_floor(loss_ref, abs_err, ok):
+    out = correct.reference_verdict(loss_ref + abs_err, loss_ref, 1.0, 1.0, 0.0, BF16)
+    assert out["ok"] is ok and out["loss_abs_err"] == pytest.approx(abs_err, rel=1e-3)
+    assert out["limits"]["loss_abs_err"] == max(BF16["loss"] * loss_ref, BF16["loss_floor"])
+    if loss_ref == 0.003 and ok:  # what refused PR 24: the same error under the relative limit alone
+        assert not correct.reference_verdict(loss_ref + abs_err, loss_ref, 1.0, 1.0, 0.0, NO_FLOOR)["ok"]
+
+
+@pytest.mark.parametrize("norm_ref,abs_err,ok", [
+    (0.008, 4.1e-4, True),   # the smallest norm the start states drew, the largest error under 0.05
+    (0.008, 1.5e-3, True),   # the floor's room: under the relative limit alone this fails
+    (0.008, 4.0e-3, False),  # ten times the largest error seen
+    (0.069, 6.3e-3, False),  # PR 25's crossing, at an end state: still a failure
+    (1.2, 6.0e-2, True),     # the relative limit governs
+])
+def test_the_gradient_norm_limit_has_an_absolute_floor(norm_ref, abs_err, ok):
+    out = correct.reference_verdict(0.1, 0.1, norm_ref + abs_err, norm_ref, 0.0, BF16)
+    assert out["ok"] is ok
+    assert out["limits"]["grad_norm_abs_err"] == max(BF16["grad_norm"] * norm_ref, BF16["grad_norm_floor"])
+    if abs_err == 1.5e-3:
+        assert not correct.reference_verdict(0.1, 0.1, norm_ref + abs_err, norm_ref, 0.0, NO_FLOOR)["ok"]
+
+
+def test_q_and_non_finite_numbers_still_fail():
+    assert not correct.reference_verdict(0.1, 0.1, 1.0, 1.0, 2 * BF16["q"], BF16)["ok"]
+    assert not correct.reference_verdict(float("nan"), 0.1, 1.0, 1.0, 0.0, BF16)["ok"]
+    assert not correct.reference_verdict(0.1, 0.1, float("inf"), 1.0, 0.0, BF16)["ok"]
+    assert correct.reference_verdict(0.1, 0.1, 1.0, 1.0, 0.0, correct.TOL["float32"])["ok"]
+
+
+def test_the_end_state_is_judged_on_q_alone():
+    """`judged` names the numbers `ok` is taken over: at the window's end the
+    loss and the gradient norm are recorded beside their limits and gate
+    nothing, Q still does."""
+    end = correct.END_STATE
+    far = correct.reference_verdict(0.003 * 1.05, 0.003, 0.02 * 1.5, 0.02, 0.5 * BF16["q"], BF16, judged=end)
+    assert far["ok"] and far["judged"] == list(end)
+    assert far["loss_abs_err"] > far["limits"]["loss_abs_err"]  # recorded, crossing and all
+    assert not correct.reference_verdict(0.003 * 1.05, 0.003, 0.02 * 1.5, 0.02, 0.5 * BF16["q"], BF16)["ok"]
+    assert not correct.reference_verdict(0.1, 0.1, 1.0, 1.0, 1.1 * BF16["q"], BF16, judged=end)["ok"]
+    assert not correct.reference_verdict(float("nan"), 0.1, 1.0, 1.0, 0.0, BF16, judged=end)["ok"]
+
+
+def test_a_configuration_states_limits_of_its_own():
+    cfg = tiny_test().replace(compute_dtype="bfloat16")
+    own = correct.tolerances(ref, cfg, {"limits": {"q": 8e-3, "q_abs": 5e-4}})
+    assert own["q"] == 8e-3 and own["q_abs"] == 5e-4
+    assert {k: v for k, v in own.items() if k not in ("q", "q_abs")} == {k: v for k, v in BF16.items() if k != "q"}
+    assert correct.tolerances(ref, cfg, {}) == correct.tolerances(ref, cfg) == BF16
+    with pytest.raises(KeyError, match="q_limit"):
+        correct.tolerances(ref, cfg, {"limits": {"q_limit": 1.0}})
+    # the absolute Q limit is judged where it is stated, and only there
+    args = (0.1, 0.1, 1.0, 1.0, 0.5 * BF16["q"])
+    stated = correct.reference_verdict(*args, own, q_abs_err=6e-4)
+    assert not stated["ok"] and stated["limits"]["q_abs_err"] == 5e-4 and "q_abs_err" in stated["judged"]
+    assert correct.reference_verdict(*args, own, q_abs_err=4e-4)["ok"]
+    assert correct.reference_verdict(*args, own, judged=correct.END_STATE, q_abs_err=6e-4)["ok"]
+    table = correct.reference_verdict(*args, BF16, q_abs_err=6e-4)
+    assert table["ok"] and "q_abs_err" not in table["limits"] and table["q_abs_err"] == 6e-4
+
+
+@pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581", "nature-lstm512-dp4"])
+def test_the_cells_own_limits_only_tighten_the_table(config):
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "configs", config + ".json")) as fh:
+        conf = json.load(fh)
+    limits = conf.get("limits", {})
+    assert set(limits) <= set(BF16) | {"q_abs"} and set(limits) == set(conf.get("limits_why", {}))
+    assert all(0 < limits[k] <= BF16.get(k, 1.0) for k in limits)
+    assert ("q_abs" in limits) == (conf["overrides"].get("recurrent_core", "lstm") == "lstm")  # T=85: finding 26.3
+
+
+def _excess(out):
+    return max(out[k] / limit for k, limit in out["limits"].items())
+
+
+def _controls(cfg, state, batch, monkeypatch):
+    """The reference put in the program's place, one precision down: (a) every
+    part the configuration states as float32 (heads, loss, stored state)
+    computed in bfloat16 with the rest; (b) the weights held in float8 (e4m3),
+    the step below the bfloat16 it states for the matmuls."""
+    sz, tol = ref.sizes_of(cfg), correct.tolerances(ref, cfg)
+    rb = correct.reference_batch(batch)
+    p, tp = state.params["params"], state.target_params["params"]
+    want = ref.loss_q_gradnorm(p, tp, rb, sz)
+    with monkeypatch.context() as m:
+        m.setattr(ref, "F32", jnp.bfloat16)
+        all_bf16 = ref.loss_q_gradnorm(p, tp, rb, sz)
+    fp8 = lambda tree: jax.tree.map(lambda w: w.astype(jnp.float8_e4m3fn).astype(jnp.float32), tree)
+    fp8_weights = ref.loss_q_gradnorm(fp8(p), fp8(tp), rb, sz)
+    return {name: correct.reference_verdict(
+        np.float32(got[0]), want[0], np.float32(got[2]), want[2],
+        correct.scale_err(np.asarray(got[1], np.float32), want[1]), tol)
+        for name, got in (("all_bf16", all_bf16), ("fp8_weights", fp8_weights))}
+
+
+@pytest.mark.parametrize("core", ["lstm", "lru"])
+def test_the_reference_one_precision_down_is_caught(core, monkeypatch):
+    """The controls of `correct` at tiny size: each fails the limits that the
+    bf16 program passes, on every batch, by three times or more. (On the chip
+    at the cells' own sizes, PERF.md finding 26.3: both fail on every batch at
+    T=581; at T=85 float8 weights fail on about half the batches and bfloat16
+    heads, loss and state are NOT told from the sound program.)"""
+    cfg = tiny_test().replace(compute_dtype="bfloat16", recurrent_core=core)
+    net, state = init_train_state(cfg, jax.random.PRNGKey(11))
+    state = state.replace(target_params=jax.tree.map(lambda x: x * 0.9, state.params))
+    check = correct.ReferenceCheck(ref, cfg, net)
+    sound, controls = [], []
+    for seed in (2, 3, 4):
+        batch = _batch(cfg, np.random.default_rng(seed))
+        sound.append(check(state.params, state.target_params, batch))
+        controls.append(_controls(cfg, state, batch, monkeypatch))
+    print(core, [_excess(o) for o in sound], [{k: _excess(v) for k, v in c.items()} for c in controls])
+    assert all(o["ok"] for o in sound)
+    for name in ("all_bf16", "fp8_weights"):
+        assert not any(c[name]["ok"] for c in controls), name
+        assert min(_excess(c[name]) for c in controls) > 3 * max(map(_excess, sound)), name
+
+
+# ---- the loss island alone, on Q views the reference provides
+
+
+@pytest.fixture(scope="module", params=["lstm", "lru"])
+def bf16_setup(request):
+    cfg = tiny_test().replace(compute_dtype="bfloat16", recurrent_core=request.param)
+    net, state = init_train_state(cfg, jax.random.PRNGKey(11))
+    state = state.replace(target_params=jax.tree.map(lambda x: x * 0.9, state.params))
+    return cfg, net, state, _batch(cfg, np.random.default_rng(2))
+
+
+def test_the_loss_island_of_a_bf16_program_agrees_to_float32(bf16_setup):
+    """The loss island of a program whose trunk computes in bfloat16, alone on
+    the reference's Q views: float32 agreement, where the whole program's loss
+    sits a thousand times further off."""
+    cfg, net, state, batch = bf16_setup
+    out = correct.loss_island(ref, cfg, state.params, state.target_params, batch)
+    assert out["ok"] and set(out["limits"]) == set(correct.LOSS_ISLAND) == {"loss_rel", "dq_err_over_scale"}
+    assert max(out["loss_rel"], out["dq_err_over_scale"]) < 1e-5
+    whole = _check(cfg, net, state, batch)
+    assert whole["ok"] and whole["loss_rel"] > 100 * max(out["loss_rel"], 1e-7)
+
+
+@pytest.mark.parametrize("what", ["rescaling", "weights", "target"])
+def test_a_loss_island_off_its_float32_is_caught(bf16_setup, what, monkeypatch):
+    """Controls on the program's side that the whole-program limits pass on
+    the chip (PERF.md finding 26.3): the target math's rescalings in bfloat16,
+    the importance weights in bfloat16; and plain wrong mathematics (the
+    target left un-rescaled)."""
+    import r2d2_tpu.learner as learner
+
+    cfg, net, state, batch = bf16_setup
+    bf16 = lambda x: x.astype(jnp.bfloat16).astype(jnp.float32)
+    if what == "rescaling":
+        for name in ("value_rescale", "inverse_value_rescale"):
+            real = getattr(learner, name)
+            monkeypatch.setattr(learner, name, lambda x, eps, real=real: bf16(real(x.astype(jnp.bfloat16), eps)))
+    elif what == "weights":
+        batch = batch._replace(is_weights=bf16(batch.is_weights) * (1 + 2.0 ** -9))
+        monkeypatch.setattr(correct, "reference_batch", lambda b, real=correct.reference_batch: dict(
+            real(b), is_weights=b.is_weights / (1 + 2.0 ** -9)))
+    else:
+        monkeypatch.setattr(learner, "value_rescale", lambda x, eps: x)
+    out = correct.loss_island(ref, cfg, state.params, state.target_params, batch)
+    assert not out["ok"] and out["loss_rel"] > 10 * out["limits"]["loss_rel"]
+
+
+def test_a_module_without_island_inputs_has_no_such_check(bf16_setup):
+    import types
+
+    cfg, net, state, batch = bf16_setup
+    bare = types.SimpleNamespace(__name__="bare", sizes_of=ref.sizes_of)
+    out = correct.loss_island(bare, cfg, state.params, state.target_params, batch)
+    assert out == {"ok": True, "skipped": "reference module bare provides no island inputs"}
