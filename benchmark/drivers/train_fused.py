@@ -30,11 +30,24 @@ state (`notes.checks.reference_end`) is judged on Q alone, which is measured
 on the tensor's own scale; its loss and gradient norm move with how far the
 window trained and are recorded.
 The loss island is judged alone too, at the start state.
+
+The comparisons run with the program's device state RELEASED. Everything that
+needs the program on the device (set-up, the two captures, the window) happens
+in `_drive`, which hands back host arrays, numbers and `trainer.net` (a module,
+no arrays). When it has returned nothing references the trainer, the runner,
+the train state, the replay stores or the collector's carry, and `run` collects
+them (`gc.collect()`) before it builds the first comparison: the check then needs room for the
+uploaded parameters, their gradients and the activations of 8 sequences, not
+for those beside a learner (PERF.md section 7: the sizing rule).
+`notes.window_resident_gb` is what the device held after the window,
+`notes.check_resident_gb` what it holds just before the first comparison, and
+`notes.check_peak_gb` the process's peak after the last.
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import time
 from typing import List
 
@@ -107,14 +120,34 @@ def _operating_point(cfg, trainer, gather, state, n_seq: int, seed: int) -> dict
             "batch": _sample_batch(cfg, trainer, gather, n_seq, seed)}
 
 
-def run(ctx: harness.Context) -> harness.Measured:
+def _stall_notes(boundaries: List[float], elapsed: float, steps: int) -> dict:
+    """Where a window's time went when it reads low: a period is the same work
+    every time (the device is never idle and the pacer counts work, not time),
+    so a period longer than the median one held a pause of the host that
+    outlasted the one dispatch the program keeps in flight. `stall_s` is the
+    periods' time beyond the median period's, summed, and `steady_steps_per_s`
+    the window's work over its time less that: notes only, beside
+    `learn_steps_per_s`, which stays all the work over all the time."""
+    periods = np.diff(np.asarray([0.0] + list(boundaries)))
+    if periods.size == 0:
+        return {}
+    median = float(np.median(periods))
+    stall_s = float(np.clip(periods - median, 0.0, None).sum())
+    return {"periods": int(periods.size), "period_s_median": median, "period_s_max": float(periods.max()),
+            "stall_s": stall_s, "steady_steps_per_s": steps / (elapsed - stall_s)}
+
+
+def _drive(ctx: harness.Context) -> dict:
+    """Everything that needs the program on the device: set-up, warm-up, the
+    start capture, the window, the end capture. Returns what `run` judges and
+    reports: host arrays, numbers and `trainer.net`, and nothing that holds a
+    device buffer of the program."""
     import jax
 
     from r2d2_tpu.learner import make_store_gather
     from r2d2_tpu.train import Trainer
 
     cell, tr_cfg = ctx.cell, ctx.cell.traffic
-    ref = harness.reference_for(cell)
     extra = {
         "samples_per_insert": float(tr_cfg["samples_per_insert"]),
         "training_steps": 10**9, "save_interval": 10**9, "log_interval": 3600.0,
@@ -174,6 +207,7 @@ def run(ctx: harness.Context) -> harness.Measured:
         budget = min(budget, float(tr_cfg.get("trace_seconds", 8.0)))
     dispatches = 0
     period_s, last_boundary_t = None, 0.0
+    boundaries: List[float] = []  # seconds from t0 at which each period closed: `_stall_notes`
     with harness.Tracer(ctx) if ctx.trace else contextlib.nullcontext():
         t0 = time.perf_counter()
         while True:
@@ -185,6 +219,7 @@ def run(ctx: harness.Context) -> harness.Measured:
                 continue
             now = time.perf_counter() - t0
             period_s, last_boundary_t = now - last_boundary_t, now
+            boundaries.append(now)
             if now + period_s > budget:
                 break
         with harness.span("bench.sync"):
@@ -204,10 +239,42 @@ def run(ctx: harness.Context) -> harness.Measured:
         "compiles_in_window": compiles_in_window,
     })
 
-    # ---- correct: outside the window. The reference check is judged at the
-    # window's start state and recorded at its end state
-    check = correct.ReferenceCheck(ref, cfg, trainer.net, cell.config)
+    # ---- the END operating point, while the program is still there to be asked
     end = _operating_point(cfg, trainer, gather, state, n_seq, ctx.seed)
+    print(f"[bench] {updates} updates in {elapsed:.2f}s over {dispatches} dispatches, "
+          f"period {period_s:.2f}s, loss {loss_host[-1]:.5f}", flush=True)
+    finite = np.isfinite(loss_host)
+    return {
+        "cfg": cfg, "net": trainer.net, "start": start, "end": end,
+        "attempted": updates, "failed": int((~finite).sum()) * K,
+        "sound": bool(finite.all() and compiles_in_window == 0
+                      and share >= float(tr_cfg.get("min_valid_step_share", 0.0))),
+        "end_to_end": {"learn_steps_per_s": updates * steps_per_update / elapsed, "setup_s": setup_s},
+        "notes": {"window_s": elapsed, "dispatches": dispatches, "period_s": period_s,
+                  "ring_fill_s": fill_s, "valid_step_share": share,
+                  "compiles_in_window": compiles_in_window, "runtime": runtime,
+                  "loss_last": float(loss_host[-1]), "warm_dispatches": warm,
+                  "start_capture_s": capture_s,
+                  **_stall_notes(boundaries, elapsed, updates * steps_per_update),
+                  "window_resident_gb": harness.device_bytes_in_use() / 1e9},
+    }
+
+
+def run(ctx: harness.Context) -> harness.Measured:
+    cell = ctx.cell
+    ref = harness.reference_for(cell)
+    d = _drive(ctx)
+    cfg, start, end = d["cfg"], d["start"], d["end"]
+
+    # ---- correct: outside the window, and without the program on the device:
+    # `_drive`'s frame was the last holder of trainer, runner, state and stores,
+    # and trainer and runner point at each other, so their buffers wait for the collector
+    gc.collect()
+    resident_gb = harness.device_bytes_in_use() / 1e9
+    print(f"[bench] device memory in use: {d['notes']['window_resident_gb']:.3f} GB after the window, "
+          f"{resident_gb:.3f} GB before the first comparison", flush=True)
+    # the reference check is judged at the window's start state and recorded at its end state
+    check = correct.ReferenceCheck(ref, cfg, d["net"], cell.config)
     checks = {
         "kernels": correct.kernel_checks(ref, cfg, ctx.seed, max(cfg.batch_size // max(cfg.dp_size, 1), 1)),
         "reference": check(start["params"], start["target_params"], start["batch"]),
@@ -216,20 +283,13 @@ def run(ctx: harness.Context) -> harness.Measured:
     }
     checks["reference"]["updates_at_check"] = start["updates"]
     checks["reference_end"]["updates_at_check"] = end["updates"]
-    finite = bool(np.isfinite(loss_host).all())
-    ok = (finite and compiles_in_window == 0 and all(c["ok"] for c in checks.values())
-          and share >= float(tr_cfg.get("min_valid_step_share", 0.0)))
-    print(f"[bench] {updates} updates in {elapsed:.2f}s over {dispatches} dispatches, "
-          f"period {period_s:.2f}s, loss {loss_host[-1]:.5f}, checks {checks}", flush=True)
+    print(f"[bench] checks {checks}", flush=True)
     from r2d2_tpu.utils.compilation_cache import log_compile_cache_stats
 
     log_compile_cache_stats()
     return harness.Measured(
-        correct=ok, attempted=updates, failed=int((~np.isfinite(loss_host)).sum()) * K,
-        end_to_end={"learn_steps_per_s": updates * steps_per_update / elapsed, "setup_s": setup_s},
-        notes={"checks": checks, "window_s": elapsed, "dispatches": dispatches,
-               "period_s": period_s, "ring_fill_s": fill_s, "valid_step_share": share,
-               "compiles_in_window": compiles_in_window, "runtime": runtime,
-               "loss_last": float(loss_host[-1]), "warm_dispatches": warm,
-               "start_capture_s": capture_s},
+        correct=d["sound"] and all(c["ok"] for c in checks.values()),
+        attempted=d["attempted"], failed=d["failed"], end_to_end=d["end_to_end"],
+        notes={"checks": checks, **d["notes"], "check_resident_gb": resident_gb,
+               "check_peak_gb": harness.memory_peak_bytes() / 1e9},
     )

@@ -196,6 +196,22 @@ def memory_peak_bytes() -> int:
     return int(max(peaks)) if peaks else 0
 
 
+def device_bytes_in_use() -> int:
+    """Bytes in use NOW on the fullest local device, as the backend counts
+    them (`memory_stats`); where it counts nothing, as the CPU, the bytes of
+    the shards jax still holds there."""
+    import jax
+
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    if all(s and "bytes_in_use" in s for s in stats):
+        return int(max(s["bytes_in_use"] for s in stats))
+    held: Dict[Any, int] = {}
+    for a in jax.live_arrays():
+        for shard in a.addressable_shards:
+            held[shard.device] = held.get(shard.device, 0) + shard.data.nbytes
+    return int(max(held.values(), default=0))
+
+
 def check_runtime(cfg, chips: int, require_tpu: bool) -> dict:
     """The program's own `[runtime]` facts; on the chip the LSTM core must be
     the compiled Pallas kernel (other cores: whatever the config resolves)."""

@@ -9,6 +9,13 @@ busy time; a container (`while`) keeps only its self time.
 
 spec: {"bucket": <name>, "per": "updates", "scale": 1000.0}   time per update
   or  {"bucket": "unscoped", "share": true}                   % of busy time
+  or  {"op_name": <regex>, "within": <bucket>, "per": "updates", "scale": 1000.0}
+      a scope of the layer file's own: the self time of the events that the
+      ordered buckets gave to `within` and whose op_name the regex finds. A new
+      kind of layer inside the core (an attention, an expert layer) gets its
+      metric as a layer file and a manifest entry; trace_scopes.json is shared
+      by every cell and is not edited for it. The regex sees the whole op_name
+      (flax module paths, `jit(<scope>)` of profiling.scoped); `.` is the bucket.
 
 Nothing to read (None): no trace, or a program without the facility (a parent
 commit). With no registered program every event is unscoped: a bucket reads
@@ -176,10 +183,25 @@ def attribution(ctx) -> Optional[dict]:
     return _done[key]
 
 
+def seconds_within(got: dict, within: str, op_name: str, label: str = "") -> float:
+    """Self time of the rows of bucket `within` whose op_name `op_name` (a
+    regex) finds; says so where it finds none."""
+    if within not in got["seconds"]:
+        raise KeyError(f"{label or op_name}: no bucket {within!r} (have {sorted(got['seconds'])})")
+    rx = re.compile(op_name)
+    found = [v for b, _, op, v in got["rows"] if b == within and rx.search(op)]
+    if not found:
+        print(f"[bench] {label}: op_name {op_name!r} finds nothing in bucket {within!r} "
+              f"({got['seconds'][within]:.4f}s): reads 0", flush=True)
+    return sum(found)
+
+
 def read(spec, ctx):
     got = attribution(ctx)
     if got is None:
         return None
+    if "op_name" in spec:
+        return scaled(spec, ctx, seconds_within(got, spec["within"], spec["op_name"], spec.get("name", "")))
     seconds = got["seconds"].get(spec["bucket"], 0.0)
     if spec.get("share"):
         return 100.0 * seconds / got["busy"] if got["busy"] > 0 else 0.0

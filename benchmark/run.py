@@ -55,6 +55,12 @@ def main(argv=None) -> int:
         print(f"benchmark: no result: {type(e).__name__}: {e}", file=sys.stderr)
         return 3
     sys.stdout.flush()
+    # each number compared beside its limit: the last lines of standard error
+    # (the result line carries the same under `notes`, its last key)
+    notes = result.get("notes", {})
+    for name, check in (notes.get("checks") or {"check": notes.get("check")}).items():
+        print(f"benchmark: correct={result['correct']} {name}: {json.dumps(check)}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result, allow_nan=False), flush=True)
     return 0
 

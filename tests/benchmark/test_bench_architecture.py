@@ -5,6 +5,7 @@ through `run_cell` at tiny size on the CPU. Also: what the toy's reference and
 operation count are for (a wrong weight and a wrong count are caught), a
 reference that has no file, and the reference check's operating point."""
 
+import gc
 import hashlib
 import json
 import os
@@ -12,7 +13,7 @@ import shutil
 
 import pytest
 
-from benchmark import correct, flops, harness
+from benchmark import correct, flops, harness, manifest
 from benchmark import trace as tr
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -250,14 +251,147 @@ def test_a_reference_module_must_keep_the_contract(toy_root, tmp_path):
         harness.reference_for(cell)
 
 
-def test_one_loader_serves_the_cells_and_the_copy_alike():
-    """Every reference module is loaded from its file, the three cells' default
-    `model` like the toy of a temporary copy, and once per file."""
-    mods = [harness.reference_for(harness.load_cell(ROOT, w["name"]))
-            for w in json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["workloads"]]
-    assert all(m is mods[0] for m in mods)
-    assert os.path.samefile(mods[0].__file__, os.path.join(ROOT, "benchmark", "reference", "model.py"))
-    assert callable(mods[0].island_inputs) and callable(mods[0].kernel_checks)
+def test_one_loader_serves_the_cells_and_the_copy_alike(toy_root):
+    """Each cell's reference module is the file its configuration names
+    (default `model`), loaded from that file and once per file: the repo's
+    cells that name none share `reference/model.py`, and in the temporary copy
+    a cell with a reference of its own gets its own file beside them."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        m = json.load(fh)
+    default = os.path.realpath(os.path.join(ROOT, "benchmark", "reference", "model.py"))
+    files = manifest.reference_files(ROOT, m)
+    for cell, path in files.items():
+        named = harness.load_cell(ROOT, cell).config.get("reference", "model")
+        assert path == (default if named == "model" else os.path.realpath(
+            os.path.join(ROOT, "benchmark", "reference", named + ".py")))
+    assert default in files.values()
+    mod = harness.reference_for(harness.load_cell(ROOT, next(c for c, f in files.items() if f == default)))
+    assert callable(mod.island_inputs) and callable(mod.kernel_checks)
+    # the copy: the same rule, the same loader (`nowhere` names a file that is not there)
+    with open(os.path.join(toy_root, "BENCHMARK.json")) as fh:
+        tm = json.load(fh)
+    tm["workloads"] = [w for w in tm["workloads"] if w["config"] != "nowhere"]
+    copied = manifest.reference_files(toy_root, tm)
+    bench = os.path.realpath(os.path.join(toy_root, "benchmark", "reference"))
+    assert copied["toy.learn"] == os.path.join(bench, "toy.py")
+    assert copied["toy_wrong.learn"] == os.path.join(bench, "toy_wrong.py")
+    assert {copied[c] for c in files} == {os.path.join(bench, "model.py")}
+
+
+# ------------------------------------------------ a fourth configuration, tests included
+
+FOURTH = {
+    "name": "toy-share",
+    "source": "test: the toy architecture of tests/benchmark/test_bench_architecture.py, as a chip's share",
+    "deployment": "one chip of 32 that share a layer (test)",
+    "preset": "tiny_test",
+    "overrides": TINY,
+    "reference": "toy",
+    "expect": {"hidden_dim": 32, "seq_len": 10, "batch_size": 8, "num_blocks": 40, "encoder": "mlp",
+               "obs_shape": [12, 12, 1]},
+    "reduced": ["num_hidden_layers", "num_experts_held", "num_key_value_heads"],
+    "reduced_why": {"num_hidden_layers": "test", "num_experts_held": "test", "num_key_value_heads": "test"},
+    "deployment_share": {"chips_per_layer": 32, "num_experts_held": {"published": 256, "held": 8},
+                         "num_key_value_heads": {"published": 8, "held": 1}},
+    "assumed": {"action_dim": "3, the drift env's"},
+}
+OWN_SCOPE = {"name": "model.core_matmul_ms_per_update", "layer": "model", "unit": "ms",
+             "moves": "learn_steps_per_s", "reader": "trace_scope", "op_name": "dot_general",
+             "within": "core", "per": "updates", "scale": 1000.0}
+
+
+@pytest.fixture(scope="module")
+def fourth_root(tmp_path_factory):
+    """The repo's benchmark with a FOURTH configuration, its reference file,
+    its cell and a layer file of its own added as files and entries."""
+    root = str(tmp_path_factory.mktemp("fourth"))
+    bench = os.path.join(root, "benchmark")
+    shutil.copytree(os.path.join(ROOT, "benchmark"), bench, ignore=shutil.ignore_patterns("__pycache__"))
+    before = _hashes(bench)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        m = json.load(fh)
+    with open(os.path.join(bench, "reference", "toy.py"), "w") as fh:
+        fh.write(TOY)
+    with open(os.path.join(bench, "configs", "toy-share.json"), "w") as fh:
+        json.dump(FOURTH, fh)
+    with open(os.path.join(bench, "layers", OWN_SCOPE["name"] + ".json"), "w") as fh:
+        json.dump(OWN_SCOPE, fh)
+    m["configs"].append({"name": "toy-share", "source": FOURTH["source"], "why": "test", "reduced": FOURTH["reduced"],
+                         "file": "benchmark/configs/toy-share.json"})
+    m["workloads"].append({"name": "toy-share.learn", "config": "toy-share", "traffic": "learn", "chips": 1,
+                           "why": "test"})
+    for e in m["end_to_end"] + m["per_layer"]:
+        # what the new cell's architecture has: no LSTM kernel, no second chip
+        if "workloads" in e and e.get("layer") not in ("kernels", "collectives"):
+            e["workloads"] = e["workloads"] + ["toy-share.learn"]
+    m["per_layer"].append({"name": OWN_SCOPE["name"], "unit": "ms", "better": "lower", "source": "device_trace",
+                           "layer": "model", "moves": "learn_steps_per_s", "workloads": ["toy-share.learn"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as fh:
+        json.dump(m, fh)
+    pats = harness.load_json(os.path.join(bench, "trace_patterns.json"))
+    pats.update(device_plane="^/host:CPU$", op_lines=["^tf_XLA"], module_lines=["^no such line$"])
+    with open(os.path.join(bench, "trace_patterns_cpu.json"), "w") as fh:
+        json.dump(pats, fh)
+    yield root, m
+    after = _hashes(bench)
+    assert {p: h for p, h in after.items() if p in before} == before  # byte for byte
+
+
+def test_a_fourth_configuration_passes_every_manifest_check_and_runs_as_files_and_entries(
+        fourth_root, monkeypatch, tmp_path):
+    """The tripwire: every rule that tests/benchmark/test_bench_manifest.py
+    asks of the repo's manifest is asked of the copy, the reference loader's
+    rule too; then the new cell runs traced to `correct: true` and its own
+    layer file (the `op_name` form) is read. A rule that pins the cells or the
+    configurations that are there today fails HERE, not in the PR that adds one."""
+    root, m = fourth_root
+    assert manifest.check_all(root, m) > 100
+    files = manifest.reference_files(root, m)
+    bench = os.path.realpath(os.path.join(root, "benchmark", "reference"))
+    assert files.pop("toy-share.learn") == os.path.join(bench, "toy.py")
+    assert set(files.values()) == {os.path.join(bench, "model.py")}
+    real = tr.load_patterns
+    monkeypatch.setattr(tr, "load_patterns",
+                        lambda path=None: real(os.path.join(root, "benchmark", "trace_patterns_cpu.json")))
+    peaks = tmp_path / "peaks.json"
+    peaks.write_text(json.dumps({"cpu": {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}}))
+    monkeypatch.setattr(flops, "_PEAKS_PATH", str(peaks))
+    gc.collect()  # what earlier tests of this process still hold is not this run's
+    base = harness.device_bytes_in_use() / 1e9
+    r = _run(root, "toy-share.learn", seconds=0.2, trace=True)
+    assert r["correct"] is True and r["failed"] == 0 and r["notes"]["checks"]["reference"]["ok"]
+    assert r["notes"]["check_resident_gb"] - base < 0.02 * (r["notes"]["window_resident_gb"] - base)
+    got = r["metrics"]
+    own, core = got[OWN_SCOPE["name"]]["value"], got["model.core_ms_per_update"]["value"]
+    assert 0.0 < own <= core
+    # the line carries what the manifest lists for the cell, less the readers with nothing to read on one CPU
+    listed = {e["name"] for e in m["per_layer"] if manifest.applies(e, "toy-share.learn")}
+    assert set(got) <= listed and {"cli.compile_misses", "model.mfu", "device.unscoped_share"} <= set(got)
+    assert not {"kernels.lstm_ms_per_update", "kernels.lstm_roofline", "collectives.exposed_ms_per_update"} & listed
+
+
+@pytest.mark.parametrize("break_it,why", [
+    (lambda conf, entry: conf.pop("expect"), "has no 'expect'"),
+    (lambda conf, entry: conf["expect"].update(seq_len=11), "'expect' says seq_len = 11"),
+    (lambda conf, entry: conf.pop("deployment_share"), "needs 'deployment_share'"),
+    (lambda conf, entry: (conf["reduced"].append("hidden_dim"), entry["reduced"].append("hidden_dim"),
+                          conf["reduced_why"].update(hidden_dim="test")), "names a width"),
+    (lambda conf, entry: conf.update(reference="toy2"), "no file"),
+])
+def test_the_copys_checks_have_teeth(fourth_root, tmp_path, break_it, why):
+    """The same copy with one thing wrong in the new configuration's file."""
+    root, m = fourth_root
+    broken = str(tmp_path / "root")
+    shutil.copytree(root, broken, ignore=shutil.ignore_patterns("__pycache__", ".benchmark_work"))
+    m = json.loads(json.dumps(m))
+    conf = json.loads(json.dumps(FOURTH))
+    break_it(conf, m["configs"][-1])
+    with open(os.path.join(broken, "benchmark", "configs", "toy-share.json"), "w") as fh:
+        json.dump(conf, fh)
+    with open(os.path.join(broken, "BENCHMARK.json"), "w") as fh:
+        json.dump(m, fh)
+    with pytest.raises((manifest.ManifestError, harness.BenchmarkError), match=why):
+        manifest.check_all(broken, m)
 
 
 def test_the_check_is_taken_where_the_window_starts(toy_root, toy_result):

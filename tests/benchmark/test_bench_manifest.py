@@ -1,177 +1,169 @@
-"""BENCHMARK.json against its contract, and every cell's files found by name."""
+"""BENCHMARK.json against its contract, and every cell's files found by name.
+The rules are functions of (root, manifest) in benchmark/manifest.py; here each
+is asked of the repo's own manifest, entry by entry, and
+tests/benchmark/test_bench_architecture.py asks all of them of a temporary copy
+with a fourth configuration added. Also: both sides of the rules that a new
+configuration meets first (`expect`, `_held` + `deployment_share`)."""
 
+import copy
 import json
 import os
-import re
 
 import pytest
 
-from benchmark import harness
+from benchmark import harness, manifest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 with open(os.path.join(ROOT, "BENCHMARK.json")) as _fh:
     M = json.load(_fh)
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 CELLS = [w["name"] for w in M["workloads"]]
-METRICS = M["end_to_end"] + M["per_layer"]
-
-
-def _applies(metric, cell):
-    return "workloads" not in metric or cell in metric["workloads"]
+_applies = manifest.applies
 
 
 def test_top_level_keys_sizes_and_command():
-    assert set(M) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    assert 1 <= len(M["paths"]) <= 16 and 1 <= len(M["command"]) <= 32
-    assert isinstance(M["run_seconds"], int) and 1 <= M["run_seconds"] <= 51
-    for word in M["command"]:
-        assert not word.startswith("/") and ".." not in word
-        if "/" in word:  # a file of the repo: must lie under `paths`
-            assert any(word.startswith(p + "/") for p in M["paths"])
-    # the full check with 24 cells fits the driver's budget
-    runs = 2 + 14 * 24
-    assert runs * (M["run_seconds"] + 60) + 24 * 2 * 90 + 1200 <= 43200
+    manifest.check_top_level(ROOT, M)
 
 
-@pytest.mark.parametrize("entry", METRICS + M["workloads"] + M["configs"], ids=lambda e: e["name"])
+@pytest.mark.parametrize("entry", manifest.metrics(M) + M["workloads"] + M["configs"], ids=lambda e: e["name"])
 def test_names_units_and_keys(entry):
-    assert NAME.match(entry["name"])
-    if "unit" in entry:
-        assert UNIT.match(entry["unit"]) and entry["better"] in ("lower", "higher")
-        assert entry["source"] in SOURCES
-        allowed = {"name", "unit", "better", "source", "workloads"}
-        allowed |= {"bound"} if "bound" in entry else {"layer", "moves"}
-        assert set(entry) <= allowed
-    for key in ("why", "layer", "source"):
-        if key in entry:
-            assert 1 <= len(entry[key]) <= 200 and "\n" not in entry[key] and "\t" not in entry[key]
-    for key in ("config", "traffic"):
-        if key in entry:
-            assert NAME.match(entry[key])
+    manifest.check_entry(M, entry)
 
 
 def test_names_are_unique_and_cells_bounded():
-    for group in (METRICS, M["workloads"], M["configs"]):
-        names = [e["name"] for e in group]
-        assert len(names) == len(set(names))
-    assert 2 <= len(M["workloads"]) <= 24 and 1 <= len(M["configs"]) <= 24
-    pairs = [(w["config"], w["traffic"]) for w in M["workloads"]]
-    assert len(pairs) == len(set(pairs))
-    four = [w for w in M["workloads"] if w["chips"] == 4]
-    assert all(w["chips"] in (1, 4) for w in M["workloads"])
-    assert len(four) <= max(1, len(M["workloads"]) // 4)
-    assert {c["name"] for c in M["configs"]} == {w["config"] for w in M["workloads"]}
+    manifest.check_unique_and_bounded(M)
 
 
 def test_end_to_end_bounds():
-    e2e = {m["name"]: m for m in M["end_to_end"]}
-    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
-    for m in M["end_to_end"]:
-        assert 0.01 <= m["bound"] <= 0.1
-        assert m["source"] in ("host_clock", "device_trace")
+    manifest.check_end_to_end_bounds(M)
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_resolves_its_files_and_metrics(cell):
-    c = harness.load_cell(ROOT, cell)
-    assert c.traffic["driver"] in ("train_fused", "serve_open_loop")
-    assert os.path.exists(os.path.join(c.bench_dir, "drivers", c.traffic["driver"] + ".py"))
-    entry = c.config_entry
-    assert any(entry["file"].startswith(p + "/") for p in M["paths"])
-    assert c.config["name"] == entry["name"] and sorted(c.config["reduced"]) == sorted(entry["reduced"])
-    assert len(entry["reduced"]) <= 16
-    # a width is never reduced
-    for key in entry["reduced"]:
-        assert NAME.match(key)
-        assert not re.search(r"hidden|intermediate|latent|state|_dim$|_rank$|head|expan|experts_per", key)
-    e2e = [m["name"] for m in M["end_to_end"] if _applies(m, cell)]
-    assert "setup_s" in e2e and len(e2e) >= 2
-    layer = [m for m in M["per_layer"] if _applies(m, cell)]
-    assert layer and all(m["moves"] in e2e for m in layer)
+    manifest.check_cell(ROOT, M, cell)
 
 
 @pytest.mark.parametrize("metric", M["per_layer"], ids=lambda m: m["name"])
 def test_layer_metric_has_a_reader_file(metric):
-    cell = harness.load_cell(ROOT, CELLS[0])
-    spec = harness.layer_spec(cell, metric["name"])
-    assert spec["name"] == metric["name"] and spec["layer"] == metric["layer"]
-    assert spec["unit"] == metric["unit"] and spec["moves"] == metric["moves"]
-    assert os.path.exists(os.path.join(cell.bench_dir, "readers", spec["reader"] + ".py"))
-    for w in metric.get("workloads", []):
-        assert w in CELLS
-    if "category" in spec:
-        from benchmark import trace
-
-        assert spec["category"] in trace.load_patterns()["categories"]
+    manifest.check_layer_metric(ROOT, M, metric)
 
 
 def test_files_under_paths_are_named_from_name_characters():
-    for p in M["paths"]:
-        for d, _, files in os.walk(os.path.join(ROOT, p)):
-            if "__pycache__" in d:
-                continue
-            for f in files:
-                rel = os.path.relpath(os.path.join(d, f), ROOT)
-                assert re.match(r"^[A-Za-z0-9_.\-/]+$", rel), rel
-
-
-# hand-computed from each configuration's source: (hidden, T, batch, block slots)
-EXPECTED = {
-    "nature-lstm512": (512, 40 + 40 + 5, 64, 512000 // 400),
-    "lru-seq581": (512, 64 + 512 + 5, 32, 524288 // 1024),
-    "nature-lstm512-dp4": (512, 40 + 40 + 5, 64, 4 * 1280),
-}
+    manifest.check_file_names(ROOT, M)
 
 
 @pytest.mark.parametrize("config", M["configs"], ids=lambda c: c["name"])
 def test_config_builds_and_validates(config):
-    """The file's preset + overrides is a valid R2D2Config with the published widths."""
-    with open(os.path.join(ROOT, config["file"])) as fh:
-        conf = json.load(fh)
-    cfg = harness.build_config(conf, seed=5, extra={"samples_per_insert": 8.0})
-    hidden, seq_len, batch, slots = EXPECTED[config["name"]]
-    assert cfg.seed == 5 and cfg.hidden_dim == hidden and cfg.seq_len == seq_len
-    assert tuple(cfg.obs_shape) == (84, 84, 1) and cfg.batch_size == batch
-    assert cfg.num_blocks == slots and cfg.encoder == "nature"
-    # the fused collector's rule: an episode fits one chunk, and fills the block
-    assert cfg.max_episode_steps == cfg.block_length
-    assert conf["source"] == config["source"] and len(conf["source"]) <= 200
-    # every changed key says why; a width that follows from a swap is at least stated
-    assert set(conf["reduced"]) == set(conf["reduced_why"]) and "action_dim" in conf["assumed"]
+    """The file's preset + overrides is a valid R2D2Config that reads what the
+    file's own `expect` says (hand-computed from its source)."""
+    manifest.check_config(ROOT, M, config)
 
 
-def _data_files(sub):
-    d = os.path.join(ROOT, M["paths"][0], sub)
-    return sorted(f[:-5] for f in os.listdir(d) if f.endswith(".json"))
-
-
-# end-to-end metrics that data files kept for a later cell may name (PERF.md section 7)
-PLANNED_E2E = {"serve_p99_ms"}
-
-
-@pytest.mark.parametrize("name", _data_files("layers"))
+@pytest.mark.parametrize("name", manifest.data_files(ROOT, M, "layers"))
 def test_every_layer_file_names_a_reader_and_a_metric_to_move(name):
-    """Also the files of cells that are not in the manifest today: a later PR
-    adds them back as entries only, so the files must already be sound."""
-    spec = harness.load_json(os.path.join(ROOT, M["paths"][0], "layers", name + ".json"))
-    assert spec["name"] == name and NAME.match(name) and UNIT.match(spec["unit"])
-    assert os.path.exists(os.path.join(ROOT, M["paths"][0], "readers", spec["reader"] + ".py"))
-    assert spec["moves"] in {m["name"] for m in M["end_to_end"]} | PLANNED_E2E
-    listed = {m["name"] for m in M["per_layer"]}
-    assert (name in listed) == (spec["moves"] not in PLANNED_E2E)
+    manifest.check_layer_file(ROOT, M, name)
 
 
-@pytest.mark.parametrize("name", _data_files("traffic"))
+@pytest.mark.parametrize("name", manifest.data_files(ROOT, M, "traffic"))
 def test_every_traffic_file_names_a_driver(name):
-    t = harness.load_json(os.path.join(ROOT, M["paths"][0], "traffic", name + ".json"))
-    assert NAME.match(name)
-    assert os.path.exists(os.path.join(ROOT, M["paths"][0], "drivers", t["driver"] + ".py"))
-    if t["driver"] == "serve_open_loop":
-        # no reserved pool the traffic never fills: the cache holds the resident sessions
-        assert t["cache_capacity"] == t["sessions"] and t["rate_per_s"] > 0
+    manifest.check_traffic_file(ROOT, M, name)
+
+
+def test_a_per_layer_metric_without_a_list_of_cells_is_refused():
+    """Such a metric is owed by every cell that reports what it moves, those
+    of later PRs too (`cli.compile_misses` moved `setup_s` with no list until
+    PR 31: no PR could add a cell)."""
+    for entry in M["per_layer"]:
+        bare = {k: v for k, v in entry.items() if k != "workloads"}
+        with pytest.raises(manifest.ManifestError, match="lists its cells"):
+            manifest.check_entry(M, bare)
+
+
+# -------------------------------- `expect`: each configuration's own file says what it builds
+
+
+def _copy_with_config(tmp_path, change):
+    """A root holding the repo's manifest and one configuration file, the
+    first configuration's, changed by `change(conf)`."""
+    m = copy.deepcopy(M)
+    entry = m["configs"][0]
+    conf = harness.load_json(os.path.join(ROOT, entry["file"]))
+    change(conf)
+    path = tmp_path / entry["file"]
+    path.parent.mkdir(parents=True)
+    path.write_text(json.dumps(conf))
+    return str(tmp_path), m, entry
+
+
+def test_a_configuration_without_expect_is_refused_by_name(tmp_path):
+    root, m, entry = _copy_with_config(tmp_path, lambda conf: conf.pop("expect"))
+    with pytest.raises(manifest.ManifestError, match="has no 'expect'"):
+        manifest.check_config(root, m, entry)
+    root, m, entry = _copy_with_config(tmp_path / "b", lambda conf: conf["expect"].pop("num_blocks"))
+    with pytest.raises(manifest.ManifestError, match=r"'expect' lacks \['num_blocks'\]"):
+        manifest.check_config(root, m, entry)
+
+
+@pytest.mark.parametrize("key", [k for k in manifest.EXPECT_KEYS if k not in ("encoder", "obs_shape")])
+def test_an_expect_that_is_wrong_by_one_is_caught(tmp_path, key):
+    def off_by_one(conf):
+        conf["expect"][key] += 1
+
+    root, m, entry = _copy_with_config(tmp_path, off_by_one)
+    with pytest.raises(manifest.ManifestError, match=f"'expect' says {key} = "):
+        manifest.check_config(root, m, entry)
+
+
+def test_an_expect_of_another_encoder_or_frame_is_caught(tmp_path):
+    root, m, entry = _copy_with_config(tmp_path, lambda conf: conf["expect"].update(encoder="mlp"))
+    with pytest.raises(manifest.ManifestError, match="'expect' says encoder = 'mlp'"):
+        manifest.check_config(root, m, entry)
+    root, m, entry = _copy_with_config(tmp_path / "b", lambda conf: conf["expect"].update(obs_shape=[84, 84, 4]))
+    with pytest.raises(manifest.ManifestError, match="'expect' says obs_shape"):
+        manifest.check_config(root, m, entry)
+
+
+# ------------------- `reduced`: never a width; a count of what is held here ends in `_held`
+
+_SHARE = {"chips_per_layer": 8, "num_kv_heads_held": {"published": 8, "held": 1},
+          "num_experts_held": {"published": 256, "held": 32}}
+
+
+@pytest.mark.parametrize("key", ["hidden_dim", "head_dim", "num_heads", "num_kv_heads", "experts_per_tok",
+                                 "intermediate_size", "kv_lora_rank", "ssm_state_size", "expand", "q_latent"])
+def test_a_width_is_never_reduced(key):
+    with pytest.raises(manifest.ManifestError, match="names a width"):
+        manifest.check_reduced({"name": "x", "reduced": ["buffer_capacity", key]}, {"deployment_share": _SHARE})
+
+
+@pytest.mark.parametrize("key", ["head_dim_held", "kv_lora_rank_held", "experts_per_tok_held", "hidden_size_held",
+                                 "head_dim", "hidden_size", "experts_per_tok"])
+def test_a_size_is_not_let_through_by_its_ending_or_by_an_entry(key):
+    with pytest.raises(manifest.ManifestError, match="names a width"):
+        manifest.check_reduced({"name": "x", "reduced": [key]},
+                               {"deployment_share": dict(_SHARE, **{key: {"published": 128, "held": 64}})})
+
+
+def test_a_count_of_what_is_held_here_passes_beside_its_deployment():
+    """Also depth (`num_hidden_layers` holds the word `hidden` and is no width)
+    and a published key that a catalog file cannot rename, which passes by its
+    entry under `deployment_share` alone."""
+    entry = {"name": "x", "reduced": ["num_hidden_layers", "num_kv_heads_held", "num_experts_held",
+                                      "num_key_value_heads"]}
+    share = dict(_SHARE, num_key_value_heads={"published": 8, "held": 1})
+    manifest.check_reduced(entry, {"deployment_share": share, "overrides": {"num_experts_held": 32}})
+
+
+@pytest.mark.parametrize("conf,why", [
+    ({}, "needs 'deployment_share'"),
+    ({"deployment_share": {"num_kv_heads_held": {"published": 8, "held": 1}}}, "chips_per_layer"),
+    ({"deployment_share": {"chips_per_layer": 8}}, "gives no"),
+    ({"deployment_share": {"chips_per_layer": 8, "num_kv_heads_held": {"published": 8, "held": 8}}}, "gives no"),
+    ({"deployment_share": {"chips_per_layer": 8, "num_kv_heads_held": {"published": 8, "held": 1}},
+      "overrides": {"num_kv_heads_held": 2}}, "runs 2"),
+])
+def test_a_held_key_without_its_deployment_share_is_refused(conf, why):
+    with pytest.raises(manifest.ManifestError, match=why):
+        manifest.check_reduced({"name": "x", "reduced": ["num_kv_heads_held"]}, conf)
 
 
 def test_last_line_holds_only_finite_numbers():
@@ -197,7 +189,14 @@ _CELL_NAMES = {
 }
 
 
-@pytest.mark.parametrize("cell", CELLS)
+def _cells_of_the_default_reference():
+    """The cells whose trace the instruction names above are taken from: those
+    of the architecture that `reference/model.py` describes. A cell of another
+    architecture brings instruction names, and such a test, of its own."""
+    return [c for c in CELLS if harness.load_cell(ROOT, c).config.get("reference", "model") == "model"]
+
+
+@pytest.mark.parametrize("cell", _cells_of_the_default_reference())
 def test_a_traced_line_carries_every_per_layer_metric_of_the_cell(cell, monkeypatch, tmp_path):
     """The driver wants each per-layer metric listed for a cell in its traced
     line (the first version was refused over dp4's, which had lost one to a
