@@ -197,14 +197,65 @@ class R2D2Network(nn.Module):
 
     # ----------------------------------------------------------------- util
 
-    def _core_input(self, obs, last_action, last_reward):
-        """(N, *obs) uint8, (N,) int, (N,) float -> (N, latent+A+1)."""
+    def _core_input(self, obs, last_action, last_reward, burn_in=None):
+        """(N, *obs) uint8, (N,) int, (N,) float -> (N, latent+A+1).
+
+        With `burn_in` (B,) the inputs are (B, T, ...) sequences whose core
+        cuts the gradient at each row's burn-in seam, and the result is
+        (B, T, latent+A+1) in time order. Behind the seam a frame can
+        receive a cotangent only inside `burn_in[b] + [0, L + F)`: below it
+        the core's backward gives exactly zero, and `unroll` reads no
+        output after it (an output's cotangent never moves forward in
+        time). `burn_in` is per row, so the compiler cannot see that; the
+        encoder therefore runs as two sub-batches, each row's L + F frames
+        from its seam with gradient and its other T - L - F frames
+        without. Per frame the forward is the one call's, and the gradient
+        is the same sum without its zero terms, whatever the loss."""
         dtype = jnp.dtype(self.compute_dtype)
-        x = obs.astype(dtype) / 255.0
-        latent = self.enc(x)
-        onehot = jax.nn.one_hot(last_action, self.action_dim, dtype=dtype)
-        reward = last_reward.astype(dtype)[:, None]
-        return jnp.concatenate([latent, onehot, reward], axis=-1)
+
+        def encode(obs, last_action, last_reward):
+            x = obs.astype(dtype) / 255.0
+            latent = self.enc(x)
+            onehot = jax.nn.one_hot(last_action, self.action_dim, dtype=dtype)
+            reward = last_reward.astype(dtype)[:, None]
+            return jnp.concatenate([latent, onehot, reward], axis=-1)
+
+        if burn_in is None:
+            return encode(obs, last_action, last_reward)
+
+        B, T = obs.shape[:2]
+        W = self.learning_steps + self.forward_steps
+        # a window that would run past T starts earlier: it still covers
+        # every frame that can receive a gradient
+        start = jnp.clip(burn_in, 0, T - W).astype(jnp.int32)[:, None]  # (B, 1)
+        window = start + jnp.arange(W, dtype=jnp.int32)[None, :]         # (B, W)
+        c = jnp.arange(T - W, dtype=jnp.int32)[None, :]
+        others = jnp.where(c < start, c, c + W)                          # (B, T-W)
+        row0 = jnp.arange(B, dtype=jnp.int32)[:, None] * T
+        # each frame as one row of bytes: the gather moves whole rows, and
+        # the chip's compiler then re-lays each part out for the first conv
+        # as it does the one call's batch (gathered as (84, 84, 1) frames,
+        # that re-layout lands inside the conv and doubles its time)
+        frames = obs.reshape(B * T, -1)
+        actions, rewards = last_action.reshape(B * T), last_reward.reshape(B * T)
+
+        def encode_at(idx):
+            # ONE flattened index, as learner.make_store_gather: the
+            # two-index gather of uint8 frames halts the v5e's core
+            flat = (row0 + idx).reshape(-1)
+            take = lambda a: jnp.take(a, flat, axis=0, mode="clip")
+            return encode(
+                take(frames).reshape(-1, *obs.shape[2:]), take(actions), take(rewards)
+            ).reshape(B, idx.shape[1], -1)
+
+        x = jnp.concatenate(
+            [encode_at(window), jax.lax.stop_gradient(encode_at(others))], axis=1
+        )
+        # back to time order: t sits at W + t below the window, at
+        # t - start inside it, and at t after it
+        t = jnp.arange(T, dtype=jnp.int32)[None, :]
+        pos = jnp.where(t < start, W + t, jnp.where(t < start + W, t - start, t))
+        return jnp.take_along_axis(x, pos[:, :, None], axis=1)
 
     def _task_mask(self, task: jnp.ndarray | None) -> jnp.ndarray | None:
         """(B, A) bool valid-action mask for each row's task, or None when
@@ -300,16 +351,24 @@ class R2D2Network(nn.Module):
         B, T = obs.shape[:2]
         L, F = self.learning_steps, self.forward_steps
 
-        x = self._core_input(
-            obs.reshape(B * T, *obs.shape[2:]),
-            last_action.reshape(B * T),
-            last_reward.reshape(B * T),
-        ).reshape(B, T, -1)
+        # fused-sequence semantics: burn-in steps refresh state only; the
+        # stop-gradient seam lives inside the core's backward pass, so the
+        # encoder differentiates each row's L + F frames from the seam only
+        # (a sequence no longer than that is all window; un-jitted
+        # initialisation wants the parameters alone, not the index work,
+        # which would compile op by op in every process)
+        seam = self.recurrent_core == "lstm" and self.fused_sequence
+        if seam and T > L + F and not self.is_initializing():
+            x = self._core_input(obs, last_action, last_reward, burn_in)
+        else:
+            x = self._core_input(
+                obs.reshape(B * T, *obs.shape[2:]),
+                last_action.reshape(B * T),
+                last_reward.reshape(B * T),
+            ).reshape(B, T, -1)
 
         carry = (hidden[:, 0], hidden[:, 1])
-        if self.recurrent_core == "lstm" and self.fused_sequence:
-            # fused-sequence semantics: burn-in steps refresh state only;
-            # the stop-gradient seam lives inside the core's backward pass
+        if seam:
             outs, _ = self.core(x, carry, burn_in=burn_in)  # (B, T, H)
         else:
             outs, _ = self.core(x, carry)  # (B, T, H)

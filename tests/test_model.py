@@ -209,3 +209,170 @@ def test_encoder_depth_adds_dense_layers():
         ones * cfg.burn_in_steps, ones * cfg.learning_steps, ones * cfg.forward_steps,
     )
     assert np.isfinite(np.asarray(q_learn)).all()
+
+
+# ---------------------------------------------------------------------------
+# Behind the LSTM's burn-in seam the encoder differentiates each row's L + F
+# frames from the seam only (PR 32). The one-call form `unroll` had until then
+# lives HERE, not in the package: it is what the split has to equal, values
+# and gradients.
+
+
+def _views_from_core_input(m, x, hid, burn, learn, fwd):
+    """Core + both Q views from a time-ordered core input (B, T, D)."""
+    T = x.shape[1]
+    L, F = m.learning_steps, m.forward_steps
+    carry = (hid[:, 0], hid[:, 1])
+    if m.recurrent_core == "lstm" and m.fused_sequence:
+        outs, _ = m.core(x, carry, burn_in=burn)
+    else:
+        outs, _ = m.core(x, carry)
+    t = jnp.arange(L, dtype=jnp.int32)
+    learn_idx = jnp.clip(burn[:, None] + t[None, :], 0, T - 1)
+    boot_idx = jnp.minimum(burn[:, None] + F + t[None, :], (burn + learn + fwd)[:, None] - 1)
+    boot_idx = jnp.clip(boot_idx, 0, T - 1)
+    q_learn = m._dueling(jnp.take_along_axis(outs, learn_idx[:, :, None], axis=1))
+    q_boot = m._dueling(jnp.take_along_axis(outs, boot_idx[:, :, None], axis=1))
+    return q_learn, q_boot, (t[None, :] < learn[:, None]).astype(jnp.float32)
+
+
+def one_call_unroll(m, obs, la, lr, hid, burn, learn, fwd):
+    """Every frame of the batch through the encoder in ONE call, all of it
+    differentiated: `unroll` as it was before the split."""
+    B, T = obs.shape[:2]
+    x = m._core_input(
+        obs.reshape(B * T, *obs.shape[2:]), la.reshape(B * T), lr.reshape(B * T)
+    ).reshape(B, T, -1)
+    return _views_from_core_input(m, x, hid, burn, learn, fwd)
+
+
+def learner_like_loss(views, action, reward, boot_weight=None):
+    """Double-Q TD loss as learner.make_loss_fn reads the views: `q_learn`
+    is differentiated, `q_boot` selects and evaluates under stop_gradient.
+    With `boot_weight` (B, L, A) a term that differentiates `q_boot` too:
+    `unroll` must serve a loss that does."""
+    q_learn, q_boot, mask = views
+    extra = 0.0 if boot_weight is None else jnp.mean(q_boot * boot_weight)
+    q_boot = jax.lax.stop_gradient(q_boot)
+    a_star = jnp.argmax(q_boot, axis=-1)
+    y = reward + 0.99 * jnp.take_along_axis(q_boot, a_star[..., None], axis=-1)[..., 0]
+    td = y - jnp.take_along_axis(q_learn, action[..., None], axis=-1)[..., 0]
+    return jnp.sum(jnp.square(td) * mask) / jnp.sum(mask) + extra
+
+
+@pytest.mark.parametrize("encoder", ["nature", "mlp"])
+def test_window_split_equals_the_one_call_form_in_values_and_every_gradient(encoder):
+    # the benchmark's window geometry (burn-in 40 + learning 40 + n-step 5)
+    # at a width the CPU unrolls in seconds
+    cfg = tiny_test().replace(
+        burn_in_steps=40, learning_steps=40, forward_steps=5, block_length=80,
+        encoder=encoder, obs_shape=(36, 36, 1) if encoder == "nature" else (12, 12, 1),
+    )
+    net, params = make_net(cfg)
+    assert net.fused_sequence and net.recurrent_core == "lstm"
+    rng = np.random.default_rng(32)
+    B, L = 4, cfg.learning_steps
+    obs, la, lr, hid = random_inputs(cfg, rng, B=B)
+    burn = jnp.array([0, 40, 17, 40], jnp.int32)   # per-row seams in one batch
+    learn = jnp.array([40, 40, 40, 23], jnp.int32)  # last row: learning < L
+    fwd = jnp.array([5, 5, 5, 2], jnp.int32)
+    action = jnp.asarray(rng.integers(0, cfg.action_dim, size=(B, L)).astype(np.int32))
+    reward = jnp.asarray(rng.normal(size=(B, L)).astype(np.float32))
+    boot_weight = jnp.asarray(rng.normal(size=(B, L, cfg.action_dim)).astype(np.float32))
+
+    def run(method):
+        def loss(p):
+            views = net.apply(p, obs, la, lr, hid, burn, learn, fwd, method=method)
+            return learner_like_loss(views, action, reward, boot_weight), views
+
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    (loss_split, views_split), g_split = run(net.unroll)
+    (loss_one, views_one), g_one = run(one_call_unroll)
+    for got, want in zip(views_split, views_one):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(float(loss_split), float(loss_one), rtol=1e-5)
+    flat_split = jax.tree_util.tree_leaves_with_path(g_split)
+    flat_one = jax.tree_util.tree_leaves_with_path(g_one)
+    assert [p for p, _ in flat_split] == [p for p, _ in flat_one]
+    for (path, got), (_, want) in zip(flat_split, flat_one):
+        want = np.asarray(want)
+        assert np.abs(want).max() > 0, jax.tree_util.keystr(path)  # every parameter is reached
+        np.testing.assert_allclose(
+            np.asarray(got), want, rtol=1e-5, atol=1e-5 * np.abs(want).max(),
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+@pytest.mark.parametrize("core", ["scan", "pallas", "lru"])
+def test_core_input_cotangent_is_zero_outside_the_window_only_behind_a_seam(core):
+    """The premise of the split, in the one-call form: behind the LSTM's
+    burn-in seam (scan and Pallas alike) the core's input has a cotangent of
+    exactly 0.0 outside [burn_in[b], burn_in[b] + L + F) whatever the loss
+    does with the views, and outside [burn_in[b], burn_in[b] + L) under the
+    learner's loss (`q_boot` behind stop_gradient: the n-step tail's five
+    frames that the split still differentiates). The LRU core publishes no
+    seam and back-propagates through burn-in: it must keep the one call, and
+    a later core without a seam cannot take the split unnoticed."""
+    cfg = tiny_test()
+    cfg = (
+        cfg.replace(recurrent_core="lru") if core == "lru"
+        else cfg.replace(lstm_backend=core)
+    )
+    net, params = make_net(cfg)
+    rng = np.random.default_rng(5)
+    B, T, L, F = 3, cfg.seq_len, cfg.learning_steps, cfg.forward_steps
+    _, _, _, hid = random_inputs(cfg, rng, B=B)
+    x = jnp.asarray(rng.normal(size=(B, T, cfg.hidden_dim + cfg.action_dim + 1)).astype(np.float32))
+    burn = jnp.array([0, 2, 4], jnp.int32)
+    learn = jnp.array([4, 4, 3], jnp.int32)
+    fwd = jnp.array([2, 2, 1], jnp.int32)
+    action = jnp.asarray(rng.integers(0, cfg.action_dim, size=(B, L)).astype(np.int32))
+    reward = jnp.asarray(rng.normal(size=(B, L)).astype(np.float32))
+    boot_weight = jnp.asarray(rng.normal(size=(B, L, cfg.action_dim)).astype(np.float32))
+    t = np.arange(T)[None, :]
+    b = np.asarray(burn)[:, None]
+
+    for weight, width in ((boot_weight, L + F), (None, L)):
+        def loss(x):
+            views = net.apply(params, x, hid, burn, learn, fwd, method=_views_from_core_input)
+            return learner_like_loss(views, action, reward, weight)
+
+        dx = np.asarray(jax.grad(loss)(x))
+        inside = (t >= b) & (t < b + width)
+        assert all(np.abs(dx[i][inside[i]]).max() > 0 for i in range(B))
+        after = np.broadcast_to((t >= b + width)[:, :, None], dx.shape)
+        below = np.broadcast_to((t < b)[:, :, None], dx.shape)
+        assert (dx[after] == 0.0).all()  # an output's cotangent never moves forward in time
+        if core == "lru":
+            assert np.abs(dx[below]).max() > 0  # no seam: burn-in frames carry gradient
+        else:
+            assert (dx[below] == 0.0).all()
+
+
+def test_parameter_tree_is_the_parents():
+    """Names and shapes as every checkpoint, `tests/fixtures/` snapshot and
+    `benchmark/reference/model.py` read them: two encoder calls share ONE set
+    of encoder parameters."""
+    from r2d2_tpu.config import default_atari
+
+    cfg = default_atari()
+    shapes = jax.eval_shape(lambda k: init_params(k, cfg)[1], jax.random.PRNGKey(0))
+    got = {
+        jax.tree_util.keystr(p): tuple(v.shape)
+        for p, v in jax.tree_util.tree_leaves_with_path(shapes)
+    }
+    H, A = cfg.hidden_dim, cfg.action_dim
+    assert (H, A) == (512, 9)
+    want = {}
+    for name, kernel in [
+        ("enc']['Conv_0", (8, 8, 1, 32)), ("enc']['Conv_1", (4, 4, 32, 64)),
+        ("enc']['Conv_2", (3, 3, 64, 64)), ("enc']['Dense_0", (3136, H)),
+        ("adv_hidden", (H, H)), ("adv_out", (H, A)), ("val_hidden", (H, H)), ("val_out", (H, 1)),
+    ]:
+        want[f"['params']['{name}']['kernel']"] = kernel
+        want[f"['params']['{name}']['bias']"] = kernel[-1:]
+    want["['params']['core']['wi']"] = (H + A + 1, 4 * H)
+    want["['params']['core']['wh']"] = (H, 4 * H)
+    want["['params']['core']['b']"] = (4 * H,)
+    assert got == want

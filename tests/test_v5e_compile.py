@@ -154,3 +154,66 @@ def test_store_gather_and_slab_write_read_the_obs_store_in_place(config, one_chi
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= obs_store_bytes  # the store is updated in place
     assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes  # slab and batch only
+
+
+def _encoder_backward_leading_dims(text):
+    """{conv layer: leading dimensions} over every instruction of a compiled
+    program whose op_name sits in the encoder's BACKWARD (`transpose(jvp` ...
+    `enc/Conv_n`): its own result shapes and its operands', arrays of rank 3
+    and more (activations, cotangents and filters; the compiled text prints an
+    operand by name, so shapes are looked up where the operand is defined)."""
+    shape_rx = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]")
+    shapes, flagged = {}, []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = ", line)
+        if not m:
+            continue
+        body = line[m.end():].split(", metadata=")[0]
+        cut = re.search(r"\s[a-z][\w\-]*\((?=%|\))", body)  # `fusion(%a, ...`: where the operands start
+        head, operands = (body[:cut.start()], body[cut.start():]) if cut else (body, "")
+        shapes[m.group(1)] = [tuple(map(int, s.split(","))) for s in shape_rx.findall(head)]
+        op = re.search(r'op_name="([^"]*)"', line)
+        hit = op and re.search(r"transpose\(jvp.*enc/Conv_(\d)", op.group(1))
+        if hit:
+            flagged.append((int(hit.group(1)), [m.group(1), *re.findall(r"%[\w.\-]+", operands)]))
+    dims = {}
+    for conv, names in flagged:
+        dims.setdefault(conv, set()).update(s[0] for n in names for s in shapes.get(n, []) if len(s) >= 3)
+    return dims
+
+
+@pytest.mark.parametrize("config,rows,differentiated", [
+    ("nature-lstm512", 64, "window"), ("nature-lstm512-dp4", 16, "window"), ("lru-seq581", 32, "sequence")])
+def test_encoder_backward_runs_over_the_frames_that_can_receive_a_gradient(
+        config, rows, differentiated, one_chip, compiled_kernels):
+    """`unroll` under value_and_grad at a cell's own rows per chip and T:
+    behind the LSTM's burn-in seam every backward instruction of the three
+    convs has B*(L+F) frames (each row's from its seam) and none has B*T; the
+    LRU core publishes no seam and its encoder backward keeps B*T. (With B*L
+    frames, a multiple of 128 at 16, 32 and 48 rows, the chip's compiler took
+    five minutes and more over conv1's backward-filter: PERF.md finding 32.3.)"""
+    from benchmark import harness
+    from r2d2_tpu.models.r2d2 import R2D2Network, init_params
+
+    cfg = harness.build_config(harness.load_json(os.path.join(ROOT, "benchmark", "configs", config + ".json")), 1)
+    if cfg.recurrent_core == "lstm":
+        cfg = cfg.replace(lstm_backend="pallas")  # "auto" asks the attached device, a CPU here
+    net = R2D2Network.from_config(cfg)
+    B, seq, W = rows, cfg.seq_len, cfg.learning_steps + cfg.forward_steps
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(lambda k: init_params(k, cfg)[1], jax.random.PRNGKey(0)))
+    batch = [sds((B, seq, *cfg.obs_shape), jnp.uint8), sds((B, seq), jnp.int32), sds((B, seq), jnp.float32),
+             sds((B, 2, cfg.hidden_dim), jnp.float32), sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32)]
+
+    def loss(p, *b):
+        q_learn, q_boot, mask = net.apply(p, *b)
+        return jnp.sum(q_learn * mask[..., None]) + jnp.sum(q_boot)
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, *batch).compile().as_text()
+    dims = _encoder_backward_leading_dims(text)
+    frames = {"window": B * W, "sequence": B * seq}[differentiated]
+    assert sorted(dims) == [0, 1, 2], dims
+    for conv, got in dims.items():
+        filters = {d for d in got if d <= 8}  # 8x8, 4x4, 3x3 kernels
+        assert got - filters == {frames}, (conv, got)
