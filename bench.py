@@ -400,7 +400,6 @@ def main(
     batch: int = 0,
     emit: bool = True,
     precision: str = "bf16",
-    fused: bool = True,
 ):
     """frame_multiplier: env frames per env step — 4 for Atari (frameskip,
     reference test.py:28,36), 1 for envs without frameskip. baseline: the
@@ -410,8 +409,7 @@ def main(
     so cross-batch rows compare updates/s x batch, not the headline).
     precision selects the mixed-precision arm (_precision_overrides;
     ignored when an explicit cfg is passed — the row reports
-    cfg.precision either way). fused=False runs the per-step Pallas path
-    (config.fused_sequence off) — the fused_seq row's denominator arm.
+    cfg.precision either way).
     Returns the result row; emit=False suppresses the JSON print so
     matrix drivers (learner_matrix_main) keep exactly one line on
     stdout."""
@@ -420,7 +418,6 @@ def main(
         **_precision_overrides(precision),
         **_core_overrides(core, lru_chunk),
     )
-    cfg = cfg.replace(fused_sequence=fused)
     if batch:
         cfg = cfg.replace(batch_size=batch)
     rng = np.random.default_rng(0)
@@ -551,7 +548,6 @@ def main(
         "vs_baseline": round(frames_per_sec / baseline, 3),
         "core": cfg.recurrent_core + (f"_c{cfg.lru_chunk}" if cfg.lru_chunk else ""),
         "precision": cfg.precision,
-        "fused_sequence": cfg.fused_sequence,
         "batch": cfg.batch_size,
         "updates_per_sec": round(updates_per_sec, 2),
     }
@@ -571,12 +567,7 @@ def learner_matrix_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
 
     The headline always carries `vs_fp32`: under bf16 a silent fp32
     reference runs at the winning batch so the speedup is measured at the
-    same shape; --precision both additionally attaches the fp32 row.
-
-    For the LSTM core the row carries a `fused_seq` sub-row — the
-    per-step Pallas path (fused_sequence=False) re-run at the winning
-    batch, so the fused sequence kernel's contribution is measured at the
-    same shape instead of inferred across rounds."""
+    same shape; --precision both additionally attaches the fp32 row."""
     arm = "bf16" if precision == "both" else precision
     batches = (batch,) if batch else (64, 128)
     rows = [
@@ -603,27 +594,6 @@ def learner_matrix_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
         "metric": "learner_env_frames_per_sec_per_chip",
         "vs_fp32": round(vs_fp32, 3),
     }
-    if core == "lstm":
-        # fused_seq row: the per-step Pallas path at the winning shape.
-        # (The LRU core has no per-step/fused split — its unroll is one
-        # associative scan either way — so the row is LSTM-only.)
-        per_step = main(
-            core=core, lru_chunk=lru_chunk, batch=best["batch"],
-            emit=False, precision=arm, fused=False,
-        )
-        speedup = best["value"] / per_step["value"]
-        print(
-            f"[fused_seq] fused {best['value']:.0f} vs per-step "
-            f"{per_step['value']:.0f} env-frames/s = {speedup:.2f}x "
-            f"at batch {best['batch']}",
-            file=sys.stderr,
-        )
-        out["fused_seq"] = {
-            "batch": best["batch"],
-            "per_step_value": per_step["value"],
-            "per_step_updates_per_sec": per_step["updates_per_sec"],
-            "speedup_vs_per_step": round(speedup, 3),
-        }
     if not batch:
         out["matrix"] = [
             {
@@ -3037,7 +3007,6 @@ def breakdown_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
         "core": cfg.recurrent_core
         + (f"_c{cfg.lru_chunk}" if cfg.lru_chunk else ""),
         "precision": cfg.precision,
-        "fused_sequence": cfg.fused_sequence,
         "backward_arm": backward_arm,
         "backward_arm_mode": arm_mode,
         "model_preset": model_preset or "base",

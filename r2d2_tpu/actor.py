@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
+from r2d2_tpu.models.core import pack_state, zero_carry
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.replay.accumulator import SequenceAccumulator
 from r2d2_tpu.utils.faults import fault_point
@@ -138,6 +139,13 @@ class ThreadedHostEnvPool(HostEnvPool):
             pass
 
 
+def _carry_keys(carry) -> List[str]:
+    """npz keys of the carry's rows in the preemption carry: the first two
+    keep the names every snapshot on disk has."""
+    on_disk = ("carry_h", "carry_c")
+    return [on_disk[i] if i < 2 else f"carry_{i}" for i in range(len(carry))]
+
+
 class VectorizedActor:
     def __init__(
         self,
@@ -201,10 +209,7 @@ class VectorizedActor:
         self.obs = obs
         self.last_action = np.zeros(E, np.int32)
         self.last_reward = np.zeros(E, np.float32)
-        self.carry = (
-            jnp.zeros((E, cfg.hidden_dim), jnp.float32),
-            jnp.zeros((E, cfg.hidden_dim), jnp.float32),
-        )
+        self.carry = zero_carry(cfg, E)
         self.episode_steps = np.zeros(E, np.int64)
         # envs whose accumulator awaits a bootstrap Q from the next policy call
         self._pending_cut = np.zeros(E, bool)
@@ -270,8 +275,8 @@ class VectorizedActor:
         actions[fresh] = 0
         term_obs, rewards, dones, next_obs = self.env.step(actions)
 
-        h, c = carry
-        hidden_np = np.stack([np.asarray(h), np.asarray(c)], axis=1)  # (E, 2, H)
+        # (E, *state_shape): the stored-state rule, models/core.py
+        hidden_np = pack_state(tuple(np.asarray(x) for x in carry))
 
         keep = np.ones(E, np.float32)
         for i in range(E):
@@ -303,9 +308,9 @@ class VectorizedActor:
 
         if not keep.all():
             k = jnp.asarray(keep)[:, None]
-            self.carry = (h * k, c * k)
+            self.carry = tuple(x * k for x in carry)
         else:
-            self.carry = (h, c)
+            self.carry = carry
 
         self.total_steps += E
         self._steps_since_refresh += E
@@ -329,14 +334,12 @@ class VectorizedActor:
         config makes the next step() bit-identical to the one an
         uninterrupted run would have taken — unlike resync(), which
         discards in-flight windows and restarts the episode streams."""
-        h, c = self.carry
         d = {
             "rng": np.asarray(json.dumps(self.rng.bit_generator.state)),
             "obs": np.asarray(self.obs),
             "last_action": self.last_action.copy(),
             "last_reward": self.last_reward.copy(),
-            "carry_h": np.asarray(h),
-            "carry_c": np.asarray(c),
+            **{k: np.asarray(x) for k, x in zip(_carry_keys(self.carry), self.carry)},
             "episode_steps": self.episode_steps.copy(),
             "pending_cut": self._pending_cut.copy(),
             "pending_truncate": self._pending_truncate.copy(),
@@ -357,7 +360,7 @@ class VectorizedActor:
         self.obs = np.array(d["obs"])
         self.last_action = np.asarray(d["last_action"], np.int32)
         self.last_reward = np.asarray(d["last_reward"], np.float32)
-        self.carry = (jnp.asarray(d["carry_h"]), jnp.asarray(d["carry_c"]))
+        self.carry = tuple(jnp.asarray(d[k]) for k in _carry_keys(self.carry))
         self.episode_steps = np.asarray(d["episode_steps"], np.int64)
         self._pending_cut = np.asarray(d["pending_cut"], bool)
         self._pending_truncate = np.asarray(d["pending_truncate"], bool)

@@ -70,6 +70,26 @@ def _net_and_state(precision: str):
     return net, state
 
 
+def _state_struct(cfg, *lead):
+    """Aval of a stored recurrent state with leading axes `lead`: the
+    core's own shape at cfg.state_dtype (models/core.py)."""
+    import jax
+
+    from r2d2_tpu.models.core import state_spec
+
+    shape, dtype = state_spec(cfg)
+    return jax.ShapeDtypeStruct((*lead, *shape), dtype)
+
+
+def _carry_struct(cfg, num_envs: int):
+    """Avals of the acting carry for `num_envs` rows."""
+    import jax
+
+    from r2d2_tpu.models.core import zero_carry
+
+    return jax.eval_shape(lambda: zero_carry(cfg, num_envs))
+
+
 def _stacked_batch_struct(precision: str, num_steps: int):
     """ShapeDtypeStructs of a (K, B, ...) stacked DeviceBatch at tiny_test
     shapes — tracing needs only avals, not data."""
@@ -87,7 +107,7 @@ def _stacked_struct_from_cfg(cfg, num_steps: int):
         obs=sds((K, B, T, *cfg.obs_shape), np.uint8),
         last_action=sds((K, B, T), np.int32),
         last_reward=sds((K, B, T), np.float32),
-        hidden=sds((K, B, 2, cfg.hidden_dim), cfg.state_dtype),
+        hidden=_state_struct(cfg, K, B),
         action=sds((K, B, L), np.int32),
         n_step_reward=sds((K, B, L), np.float32),
         gamma=sds((K, B, L), np.float32),
@@ -187,7 +207,7 @@ def act_jaxpr(precision: str, num_envs: int = 4) -> str:
     cfg = _cfg(precision)
     net, state = _net_and_state(precision)
     sds = jax.ShapeDtypeStruct
-    E, H = num_envs, cfg.hidden_dim
+    E = num_envs
 
     def policy(params, obs, la, lr, carry):
         return net.apply(params, obs, la, lr, carry, method=net.act)
@@ -198,7 +218,7 @@ def act_jaxpr(precision: str, num_envs: int = 4) -> str:
             sds((E, *cfg.obs_shape), np.uint8),
             sds((E,), np.int32),
             sds((E,), np.float32),
-            (sds((E, H), np.float32), sds((E, H), np.float32)),
+            _carry_struct(cfg, E),
         )
     )
 
@@ -241,7 +261,7 @@ def fused_unroll_jaxpr(precision: str):
         sds((B, T, *cfg.obs_shape), np.uint8),
         sds((B, T), np.int32),
         sds((B, T), np.float32),
-        sds((B, 2, cfg.hidden_dim), cfg.state_dtype),
+        _state_struct(cfg, B),
         sds((B,), np.int32),
         sds((B,), np.int32),
         sds((B,), np.int32),
@@ -393,7 +413,7 @@ def act_select_jaxpr(precision: str, num_envs: int = 4) -> str:
     cfg = _cfg(precision)
     net, state = _net_and_state(precision)
     sds = jax.ShapeDtypeStruct
-    E, H = num_envs, cfg.hidden_dim
+    E = num_envs
 
     def policy(params, obs, la, lr, carry, explore, rand_a):
         return net.apply(
@@ -406,7 +426,7 @@ def act_select_jaxpr(precision: str, num_envs: int = 4) -> str:
             sds((E, *cfg.obs_shape), np.uint8),
             sds((E,), np.int32),
             sds((E,), np.float32),
-            (sds((E, H), np.float32), sds((E, H), np.float32)),
+            _carry_struct(cfg, E),
             sds((E,), bool),
             sds((E,), np.int32),
         )
@@ -737,6 +757,7 @@ def check_store_field_dtypes(precision: str) -> List[Finding]:
     the device store's donated `_write` jit requires vals dtypes == store
     dtypes (the PR-4 bug class: an f32 hidden slab against a bf16 store
     retraces or fails the donation)."""
+    from r2d2_tpu.models.core import zero_state
     from r2d2_tpu.replay.block import Block, store_field_specs
     from r2d2_tpu.replay.device_store import DeviceReplayBuffer
 
@@ -752,7 +773,7 @@ def check_store_field_dtypes(precision: str) -> List[Finding]:
         action=np.zeros(bl, np.uint8),
         n_step_reward=np.zeros(bl, np.float32),
         gamma=np.zeros(bl, np.float32),
-        hidden=np.zeros((S, 2, cfg.hidden_dim), np.float32),
+        hidden=zero_state(cfg, S),
         num_sequences=S,
         burn_in_steps=np.full(S, cfg.burn_in_steps, np.int32),
         learning_steps=np.full(S, cfg.learning_steps, np.int32),
@@ -1003,7 +1024,7 @@ def scan_act_select(precision: str) -> List[Finding]:
     cfg = _cfg(precision)
     net, state = _net_and_state(precision)
     sds = jax.ShapeDtypeStruct
-    E, H = 4, cfg.hidden_dim
+    E = 4
     _, action, _ = jax.eval_shape(
         lambda p, o, la, lr, cy, ex, ra: net.apply(
             p, o, la, lr, cy, ex, ra, method=net.act_select
@@ -1012,7 +1033,7 @@ def scan_act_select(precision: str) -> List[Finding]:
         sds((E, *cfg.obs_shape), np.uint8),
         sds((E,), np.int32),
         sds((E,), np.float32),
-        (sds((E, H), np.float32), sds((E, H), np.float32)),
+        _carry_struct(cfg, E),
         sds((E,), bool),
         sds((E,), np.int32),
     )
@@ -1220,7 +1241,7 @@ def _manual_batch_struct(precision: str, dp: int, tp: int, fsdp: int):
         obs=sds((B, T, *cfg.obs_shape), np.uint8),
         last_action=sds((B, T), np.int32),
         last_reward=sds((B, T), np.float32),
-        hidden=sds((B, 2, cfg.hidden_dim), cfg.state_dtype),
+        hidden=_state_struct(cfg, B),
         action=sds((B, L), np.int32),
         n_step_reward=sds((B, L), np.float32),
         gamma=sds((B, L), np.float32),

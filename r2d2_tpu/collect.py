@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
+from r2d2_tpu.models.core import Carry, pack_state, zero_carry
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.epsilon import epsilon_ladder
 from r2d2_tpu.ops.priority import mixed_td_priorities
@@ -86,8 +87,7 @@ class CollectCarry(NamedTuple):
     internal horizon is looser than the config cap)."""
 
     env_state: object
-    h: jnp.ndarray              # (E, H) f32
-    c: jnp.ndarray              # (E, H) f32
+    core: Carry                 # the core's carry, (E, H) f32 each (models/core.py)
     last_action: jnp.ndarray    # (E,) int32
     last_reward: jnp.ndarray    # (E,) f32
     prefix_reward: jnp.ndarray  # (E,) f32
@@ -97,11 +97,10 @@ class CollectCarry(NamedTuple):
 def initial_carry(cfg: R2D2Config, fn_env, num_envs: int, key) -> CollectCarry:
     """Fresh episodes in every slot: reset env states, zero recurrent
     state / NOOP last action / zero reward (reference worker.py:488-509)."""
-    E, H = num_envs, cfg.hidden_dim
+    E = num_envs
     return CollectCarry(
         env_state=jax.vmap(fn_env.reset)(jax.random.split(key, E)),
-        h=jnp.zeros((E, H), jnp.float32),
-        c=jnp.zeros((E, H), jnp.float32),
+        core=zero_carry(cfg, E),
         last_action=jnp.zeros(E, jnp.int32),
         last_reward=jnp.zeros(E, jnp.float32),
         prefix_reward=jnp.zeros(E, jnp.float32),
@@ -159,7 +158,6 @@ def make_collect_core(
     E, T = num_envs, chunk_len
     L, Bn, n = cfg.learning_steps, cfg.burn_in_steps, cfg.forward_steps
     S, bl, slot = cfg.seqs_per_block, cfg.block_length, cfg.block_slot_len
-    H = cfg.hidden_dim
     A = cfg.action_dim if action_dim is None else int(action_dim)
     gamma = cfg.gamma if gamma is None else float(gamma)
     eps_h = cfg.value_rescale_eps
@@ -185,9 +183,10 @@ def make_collect_core(
         Mirrors SequenceAccumulator.finish (replay/accumulator.py) with
         fixed shapes + masks: obs (T, R, 128) and final_obs (R, 128), frames
         as the store's lane-aligned rows, actions/rewards (T,) already
-        zero-masked past `size`, qs (T, A), hiddens (T, 2, H) post-step
-        states, size scalar int, done scalar bool, qf (A,) the final
-        policy eval for the truncation bootstrap. init_la/init_lr/init_hid
+        zero-masked past `size`, qs (T, A), hiddens (T, *state_shape)
+        post-step states (models/core.py), size scalar int, done scalar
+        bool, qf (A,) the final policy eval for the truncation bootstrap.
+        init_la/init_lr/init_hid
         are the pre-chunk last action / last reward / recurrent state:
         zeros at an episode start, the carried values on a continuation
         chunk (carry_episodes)."""
@@ -285,30 +284,29 @@ def make_collect_core(
         if carry_episodes:
             carry0: CollectCarry = env_state
             env_state = carry0.env_state
-            h0, c0 = carry0.h, carry0.c
+            core0 = carry0.core
             la0, lr0 = carry0.last_action, carry0.last_reward
         else:
-            h0 = jnp.zeros((E, H), jnp.float32)
-            c0 = jnp.zeros((E, H), jnp.float32)
+            core0 = zero_carry(cfg, E)
             la0 = jnp.zeros(E, jnp.int32)
             lr0 = jnp.zeros(E, jnp.float32)
 
         def body(carry, key_t):
-            env_state, h, c, la, lr, active = carry
+            env_state, core, la, lr, active = carry
             obs = vrender(env_state)
             ke, ka = jax.random.split(key_t)
             explore = jax.random.uniform(ke, (E,)) < epsilons
             rand_a = jax.random.randint(ka, (E,), 0, A)
             # fused act tail (ops/act_tail.py): same math as the former
             # argmax/where pair, selection fused with the core step
-            q, act, (h2, c2) = net.apply(
-                params, obs, la, lr, (h, c), explore, rand_a,
+            q, act, core2 = net.apply(
+                params, obs, la, lr, core, explore, rand_a,
                 task=task_vec, method=net.act_select,
             )
             # scan carry stays f32 regardless of compute dtype (bf16->f32
             # is exact, and act re-casts on use — same values as the host
             # actor's bf16 carry)
-            h2, c2 = h2.astype(jnp.float32), c2.astype(jnp.float32)
+            core2 = tuple(x.astype(jnp.float32) for x in core2)
             new_env, reward, done = vstep(env_state, act)
             # freeze slots whose episode already ended: their remaining
             # steps are padding (and step `size` renders the terminal obs)
@@ -330,21 +328,21 @@ def make_collect_core(
                 "action": act,
                 "reward": reward,
                 "q": q.astype(jnp.float32),
-                "hidden": jnp.stack([h2, c2], axis=1).astype(jnp.float32),
+                "hidden": pack_state(core2).astype(jnp.float32),
                 "applied": active,
                 "done": done,
             }
             la2 = jnp.where(active, act, la)
             lr2 = jnp.where(active, reward, lr)
-            return (env_state, h2, c2, la2, lr2, active & ~done), rec
+            return (env_state, core2, la2, lr2, active & ~done), rec
 
         keys = jax.random.split(key, T + 2)
-        init = (env_state, h0, c0, la0, lr0, jnp.ones(E, bool))
-        (env_f, h_f, c_f, la_f, lr_f, alive_f), rec = jax.lax.scan(body, init, keys[:T])
+        init = (env_state, core0, la0, lr0, jnp.ones(E, bool))
+        (env_f, core_f, la_f, lr_f, alive_f), rec = jax.lax.scan(body, init, keys[:T])
 
         final_obs = vrender(env_f)
         q_final, _ = net.apply(
-            params, final_obs, la_f, lr_f, (h_f, c_f), task=task_vec, method=net.act
+            params, final_obs, la_f, lr_f, core_f, task=task_vec, method=net.act
         )
 
         sizes = jnp.sum(rec["applied"].astype(jnp.int32), axis=0)  # (E,)
@@ -364,7 +362,7 @@ def make_collect_core(
             q_final,
             la0,
             lr0,
-            jnp.stack([h0, c0], axis=1),
+            pack_state(core0),
         )
         fresh_env = vreset(jax.random.split(keys[T + 1], E))
         if carry_episodes:
@@ -384,8 +382,7 @@ def make_collect_core(
             ep_total = carry0.prefix_reward + ep_rewards
             new_carry = CollectCarry(
                 env_state=next_env,
-                h=jnp.where(cont[:, None], h_f, 0.0),
-                c=jnp.where(cont[:, None], c_f, 0.0),
+                core=tuple(jnp.where(cont[:, None], x, 0.0) for x in core_f),
                 last_action=jnp.where(cont, la_f, 0),
                 last_reward=jnp.where(cont, lr_f, 0.0),
                 prefix_reward=jnp.where(cont, ep_total, 0.0),

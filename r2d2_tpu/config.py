@@ -16,6 +16,16 @@ import dataclasses
 import math
 from typing import Optional, Tuple
 
+# The recurrent cores: name -> "module:Class". The ONE table that names a
+# core: validation reads its keys, models/core.py resolves the class (a
+# dotted path, so that validating a config imports no flax), and everything
+# else asks that class. Adding a core is one line here plus its module
+# (ARCHITECTURE.md, "Adding a recurrent core").
+RECURRENT_CORES = {
+    "lstm": "r2d2_tpu.models.lstm:LSTM",
+    "lru": "r2d2_tpu.models.lru:LRU",
+}
+
 
 @dataclasses.dataclass(frozen=True)
 class R2D2Config:
@@ -357,20 +367,6 @@ class R2D2Config:
     # raw frames (and vice versa).
     block_codec: str = "none"
 
-    # Fused-sequence training semantics for the LSTM core: the T-step
-    # unroll treats each row's burn-in prefix as state-refresh only — a
-    # stop-gradient seam at burn_in[b] cuts the backward pass so burn-in
-    # steps contribute exactly zero to dWh/dWi and the initial carry grads
-    # vanish (the R2D2 paper's stored-state + burn-in semantics). Applies
-    # to BOTH backends identically: the Pallas sequence kernel
-    # (ops/pallas_lstm.py lstm_seq_unroll) masks inside its backward
-    # kernel, the lax.scan fallback applies the operator-equivalent
-    # where/stop_gradient masks, so CPU and TPU train the same function.
-    # Forward values are bit-identical either way (the seam only gates
-    # gradients). False restores the pre-seam behavior of backpropagating
-    # through burn-in. The LRU core ignores this knob (its associative
-    # scan has no per-row seam kernel; documented in ARCHITECTURE.md).
-    fused_sequence: bool = True
     # Backward-pass kernel arms for the fused sequence unroll
     # (ops/pallas_lstm.py). Both default OFF: the default backward path is
     # bit-identical to every earlier release.
@@ -469,11 +465,12 @@ class R2D2Config:
     # LSTM unroll backend: "auto" = fused Pallas kernel on TPU, lax.scan
     # elsewhere; "scan"/"pallas" force one (ops/pallas_lstm.py)
     lstm_backend: str = "auto"
-    # recurrent core family: "lstm" (reference parity, sequential unroll)
-    # or "lru" (models/lru.py — diagonal linear recurrence whose unroll is
-    # ONE associative_scan: O(log T) depth over time, the long-context
-    # core). Both share the (B, 2, H) stored-state contract, so replay /
-    # burn-in / zero-state machinery is identical.
+    # recurrent core family, a name of RECURRENT_CORES above: "lstm"
+    # (reference parity, sequential unroll) or "lru" (models/lru.py —
+    # diagonal linear recurrence whose unroll is ONE associative_scan:
+    # O(log T) depth over time, the long-context core). What a core stores
+    # in replay, and whether it cuts the gradient at burn-in, is the core's
+    # own statement (models/core.py).
     recurrent_core: str = "lstm"
     # lru only: > 0 switches the unroll from one associative scan
     # (bandwidth-bound: ~log2 T full sweeps over four f32 (B,T,H)
@@ -669,11 +666,7 @@ class R2D2Config:
             return ("ckpt", self.seq_grad_checkpoint)
         if self.seq_fused_dwh:
             return ("fused_dwh", 0)
-        if (
-            self.backward_arm == "default"
-            or self.recurrent_core != "lstm"
-            or not self.fused_sequence
-        ):
+        if self.backward_arm == "default" or self.recurrent_core != "lstm":
             return ("default", 0)
         if self.resolved_core_backend != "pallas":
             return ("default", 0)
@@ -946,8 +939,11 @@ class R2D2Config:
                 )
         if self.lstm_backend not in ("auto", "scan", "pallas"):
             raise ValueError(f"unknown lstm_backend {self.lstm_backend!r}")
-        if self.recurrent_core not in ("lstm", "lru"):
-            raise ValueError(f"unknown recurrent_core {self.recurrent_core!r}")
+        if self.recurrent_core not in RECURRENT_CORES:
+            raise ValueError(
+                f"unknown recurrent_core {self.recurrent_core!r}; registered: "
+                f"{sorted(RECURRENT_CORES)}"
+            )
         if self.lru_chunk < 0:
             raise ValueError("lru_chunk must be >= 0")
         if self.lru_chunk > 0 and self.recurrent_core != "lru":

@@ -21,6 +21,7 @@ import numpy as np
 
 from r2d2_tpu.config import PRESETS, R2D2Config, parse_overrides
 from r2d2_tpu.learner import init_train_state
+from r2d2_tpu.models.core import zero_carry
 from r2d2_tpu.utils.checkpoint import list_checkpoint_steps, restore_checkpoint
 
 
@@ -66,10 +67,7 @@ def evaluate_params(
     obs = vec_env.reset_all()
     last_action = np.zeros(E, np.int32)
     last_reward = np.zeros(E, np.float32)
-    carry = (
-        jnp.zeros((E, cfg.hidden_dim), jnp.float32),
-        jnp.zeros((E, cfg.hidden_dim), jnp.float32),
-    )
+    carry = zero_carry(cfg, E)
     cur_reward = np.zeros(E)
     completed = np.zeros(E, np.int64)
     finished_returns: list = []

@@ -61,13 +61,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
+from r2d2_tpu.models.core import check_two_row_state, pack_state, state_spec
 from r2d2_tpu.replay.accumulator import SequenceAccumulator
 
 
 def gather_carry_rows(h_store, c_store, slots):
     """Pure gather of the batch rows' post-step carries out of the session
     stores, cast to float32 (the cache may hold bf16 — the accumulator
-    contract is f32 (2, H) stored state)."""
+    packs the stored state in float32)."""
     return (
         jnp.take(h_store, slots, axis=0).astype(jnp.float32),
         jnp.take(c_store, slots, axis=0).astype(jnp.float32),
@@ -109,7 +110,7 @@ class _SessionStream:
 
     def __init__(self, cfg: R2D2Config):
         self.acc = SequenceAccumulator(cfg)
-        self.pending = None  # (action, q, hidden(2,H), eps, version)
+        self.pending = None  # (action, q, hidden (*state_shape), eps, version)
         self.eps_stamps: List[float] = []
         self.ver_stamps: List[int] = []
 
@@ -126,6 +127,10 @@ class TransitionTap:
 
     def __init__(self, cfg: R2D2Config, depth: Optional[int] = None,
                  emit: Optional[Callable] = None):
+        # the records carry the serve cache's h / c rows (BatchRecord)
+        check_two_row_state(
+            state_spec(cfg)[0], cfg.hidden_dim, cfg.recurrent_core, "TransitionTap"
+        )
         self.cfg = cfg
         self.depth = int(depth if depth is not None else cfg.liveloop_tap_depth)
         # r2d2: ephemeral(process-local plumbing: the owner rewires the callback via set_emit on every (re)construction, it is never part of replayed state)
@@ -255,6 +260,7 @@ class TransitionTap:
 
     def _apply(self, rec: BatchRecord, broken=None) -> None:
         broken = set() if broken is None else broken
+        hidden_rows = pack_state((rec.h_rows, rec.c_rows))  # (n, *state_shape)
         for i, sid in enumerate(rec.sids):
             st = self._sessions.get(sid)
             severed = sid in broken
@@ -271,7 +277,7 @@ class TransitionTap:
                     self.seam_breaks += 1
                 st = None
             row_obs = rec.obs[i]
-            hidden = np.stack([rec.h_rows[i], rec.c_rows[i]])
+            hidden = hidden_rows[i]
             if st is None:
                 st = _SessionStream(self.cfg)
                 st.acc.reset(row_obs)

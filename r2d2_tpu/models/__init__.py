@@ -8,10 +8,11 @@ the padded fixed-length sequence) — and `unroll` returns BOTH gather views
 they differ only in output indexing. That collapses the reference's
 3 conv + 3 LSTM evaluations per update to 2 + 2.
 
-Two recurrent core families behind one carry contract (pair of (B, H)
-states; stored as (B, 2, H) in replay): `LSTM` (reference parity,
-sequential scan / fused Pallas unroll) and `LRU` (time-parallel diagonal
-linear recurrence via associative_scan — models/lru.py).
+Two recurrent core families behind one seam (models/core.py: each core
+states what it stores in replay and whether it cuts the gradient at
+burn-in): `LSTM` (reference parity, sequential scan / fused Pallas unroll)
+and `LRU` (time-parallel diagonal linear recurrence via associative_scan —
+models/lru.py).
 """
 
 from r2d2_tpu.models.encoders import ImpalaEncoder, MLPEncoder, NatureEncoder

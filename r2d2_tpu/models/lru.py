@@ -19,12 +19,12 @@ back the standard way: a nonlinear readout of the state plus an input
 skip, with stability guaranteed by parameterizing |lambda| < 1 through
 exp(-exp(nu_log)).
 
-Drop-in contract (zero plumbing changes anywhere else):
+Drop-in contract (models/core.py is the rule):
 - carry is a pair of (B, H) real arrays — here (Re h, Im h) instead of
-  the LSTM's (h, c) — so the replay planes' stored (B, 2, H) hidden
-  field, the actors' carries, burn-in, and zero-state ablation all work
-  unchanged (models/r2d2.py `carry = (hidden[:, 0], hidden[:, 1])`).
-- `__call__(xs (B,T,D), carry) -> (outs (B,T,H), carry)` and
+  the LSTM's (h, c) — stated by `state_shape`, so the replay planes, the
+  actors' carries, burn-in, and zero-state ablation take it as they take
+  the LSTM's.
+- `__call__(xs (B,T,D), carry, burn_in=None) -> (outs (B,T,H), carry)` and
   `step(x (B,D), carry) -> (out, carry)` mirror models/lstm.py.
 
 Numerics: input/readout matmuls run in the configured compute dtype
@@ -94,6 +94,28 @@ class LRU(nn.Module):
     # the chunk GEMMs run at Precision.HIGHEST so the MXU does not round
     # the f32 operands to bf16; see _chunked_states for the cost note).
     chunk: int = 0
+
+    # the seam's statements (models/core.py): the associative scan has no
+    # per-row seam, so __call__ ignores burn_in and backpropagates through
+    # burn-in; the stored state is the (Re h, Im h) pair
+    cuts_at_burn_in = False
+
+    @staticmethod
+    def state_shape(cfg):
+        return (2, cfg.hidden_dim)
+
+    @classmethod
+    def from_config(cls, cfg, in_dim: int, tp_size: int = 1) -> "LRU":
+        # every param is replicated under the sharding table, so the
+        # shard-local net (tp_size > 1) takes the global module unchanged
+        return cls(
+            cfg.hidden_dim,
+            in_dim=in_dim,
+            dtype=jnp.dtype(cfg.resolved_compute_dtype),
+            chunk=cfg.lru_chunk,
+            r_min=cfg.lru_r_min,
+            r_max=cfg.lru_r_max,
+        )
 
     def setup(self):
         H, D = self.hidden_dim, self.in_dim
@@ -237,10 +259,13 @@ class LRU(nn.Module):
             hi.reshape(B, T + pad, H)[:, :T],
         )
 
-    def __call__(self, xs: jnp.ndarray, carry: Carry) -> Tuple[jnp.ndarray, Carry]:
+    def __call__(
+        self, xs: jnp.ndarray, carry: Carry, burn_in=None
+    ) -> Tuple[jnp.ndarray, Carry]:
         """Time-parallel unroll over (B, T, D) from carry; returns
         ((B, T, H), final carry). chunk selects the formulation (same
-        math): 0 = one associative scan, > 0 = chunked MXU matmuls."""
+        math): 0 = one associative scan, > 0 = chunked MXU matmuls.
+        `burn_in` is ignored (cuts_at_burn_in = False)."""
         _, _, gamma = self._decay()
         u_re, u_im = self._project_in(xs, gamma)  # (B, T, H) f32
         if self.chunk > 0:

@@ -65,6 +65,34 @@ class LSTM(nn.Module):
     tp_size: int = 1
     tp_axis: str = "tp"
 
+    # the seam's statements (models/core.py): __call__ cuts the gradient
+    # at each row's burn_in, and the stored state is the (h, c) pair
+    cuts_at_burn_in = True
+
+    @staticmethod
+    def state_shape(cfg):
+        return (2, cfg.hidden_dim)
+
+    @classmethod
+    def from_config(cls, cfg, in_dim: int, tp_size: int = 1) -> "LSTM":
+        # "auto" is resolved HERE, once, by the config's own rule — the
+        # module never picks a backend at trace time on this path; the
+        # backward arm likewise: explicit legacy knobs verbatim, else the
+        # backward_arm budget selector
+        arm, stride = cfg.resolve_backward_arm()
+        return cls(
+            cfg.hidden_dim,
+            in_dim=in_dim,
+            # precision="bf16" forces bfloat16 compute; fp32 precision
+            # defers to the legacy compute_dtype knob (config.py)
+            dtype=jnp.dtype(cfg.resolved_compute_dtype),
+            scan_chunk=cfg.scan_chunk,
+            backend=cfg.resolved_core_backend,
+            fused_dwh=(arm == "fused_dwh"),
+            grad_checkpoint=(stride if arm == "ckpt" else 0),
+            tp_size=tp_size,
+        )
+
     def setup(self):
         H = self.hidden_dim
         scale = 1.0 / np.sqrt(H)

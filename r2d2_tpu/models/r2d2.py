@@ -36,12 +36,10 @@ from typing import Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from r2d2_tpu.config import R2D2Config
+from r2d2_tpu.models.core import Carry, core_class, state_spec, unpack_state
 from r2d2_tpu.models.encoders import make_encoder
-from r2d2_tpu.models.lru import LRU
-from r2d2_tpu.models.lstm import LSTM, Carry
 
 
 class RowDense(nn.Module):
@@ -71,28 +69,15 @@ class RowDense(nn.Module):
 
 class R2D2Network(nn.Module):
     action_dim: int
+    # the recurrent core, built by its registered class (models/core.py);
+    # the network asks it and never names it. Parameter path: `core`.
+    core: nn.Module
     hidden_dim: int = 512
     learning_steps: int = 40
     forward_steps: int = 5
     encoder: str = "nature"
     compute_dtype: str = "float32"
     impala_channels: Tuple[int, ...] = (16, 32, 32)
-    scan_chunk: int | None = None
-    lstm_backend: str = "auto"
-    # "lstm" (reference parity) or "lru" (models/lru.py time-parallel core)
-    recurrent_core: str = "lstm"
-    lru_chunk: int = 0  # lru unroll formulation, see config.lru_chunk
-    lru_r_min: float = 0.9   # lru eigenvalue ring, see config.lru_r_min
-    lru_r_max: float = 0.999
-    # stop-gradient seam at each row's burn-in boundary during unroll
-    # (config.fused_sequence). LSTM core only; the LRU's associative-scan
-    # unroll keeps full backprop regardless (documented in ARCHITECTURE.md).
-    fused_sequence: bool = True
-    # Pallas backward arms for the fused sequence unroll (config.
-    # seq_fused_dwh / seq_grad_checkpoint; ops/pallas_lstm.py). LSTM core
-    # + pallas backend only; both default OFF (default path bit-identical).
-    seq_fused_dwh: bool = False
-    seq_grad_checkpoint: int = 0
     # multi-task head conditioning (config.num_tasks): > 1 widens the
     # dueling-head input by a one-hot task embedding and (with
     # task_action_dims set) masks each task's invalid action tail out of
@@ -114,18 +99,12 @@ class R2D2Network(nn.Module):
 
     @classmethod
     def from_config(cls, cfg: R2D2Config, manual_tp: int = 1) -> "R2D2Network":
-        # "auto" is resolved HERE, once, by the config's own rule — the
-        # module never picks a backend at trace time on this path
-        backend = (
-            cfg.resolved_core_backend
-            if cfg.recurrent_core == "lstm"
-            else cfg.lstm_backend
-        )
-        # the fused-kernel backward arm actually run: explicit legacy
-        # knobs verbatim, else the backward_arm budget selector
-        arm, stride = cfg.resolve_backward_arm()
         return cls(
             action_dim=cfg.action_dim,
+            # core input = concat(latent, one-hot action, reward) (model.py:59)
+            core=core_class(cfg).from_config(
+                cfg, in_dim=cfg.hidden_dim + cfg.action_dim + 1, tp_size=manual_tp
+            ),
             hidden_dim=cfg.hidden_dim,
             learning_steps=cfg.learning_steps,
             forward_steps=cfg.forward_steps,
@@ -134,15 +113,6 @@ class R2D2Network(nn.Module):
             # defers to the legacy compute_dtype knob (config.py)
             compute_dtype=cfg.resolved_compute_dtype,
             impala_channels=tuple(cfg.impala_channels),
-            scan_chunk=cfg.scan_chunk,
-            lstm_backend=backend,
-            recurrent_core=cfg.recurrent_core,
-            lru_chunk=cfg.lru_chunk,
-            lru_r_min=cfg.lru_r_min,
-            lru_r_max=cfg.lru_r_max,
-            fused_sequence=cfg.fused_sequence,
-            seq_fused_dwh=(arm == "fused_dwh"),
-            seq_grad_checkpoint=(stride if arm == "ckpt" else 0),
             num_tasks=cfg.num_tasks,
             task_action_dims=tuple(cfg.task_action_dims),
             encoder_depth=cfg.encoder_depth,
@@ -156,30 +126,6 @@ class R2D2Network(nn.Module):
             self.encoder, self.hidden_dim, dtype, self.impala_channels,
             depth=self.encoder_depth, tp_size=tp,
         )
-        # core input = concat(latent, one-hot action, reward) (model.py:59)
-        core_in = self.hidden_dim + self.action_dim + 1
-        if self.recurrent_core == "lru":
-            # the LRU's params are all replicated under the sharding
-            # table, so the shard-local net reuses the global module
-            # unchanged (enc + heads carry all the tp math)
-            self.core = LRU(
-                self.hidden_dim, in_dim=core_in, dtype=dtype,
-                chunk=self.lru_chunk,
-                r_min=self.lru_r_min, r_max=self.lru_r_max,
-            )
-        elif self.recurrent_core == "lstm":
-            self.core = LSTM(
-                self.hidden_dim,
-                in_dim=core_in,
-                dtype=dtype,
-                scan_chunk=self.scan_chunk,
-                backend=self.lstm_backend,
-                fused_dwh=self.seq_fused_dwh,
-                grad_checkpoint=self.seq_grad_checkpoint,
-                tp_size=tp,
-            )
-        else:
-            raise ValueError(f"unknown recurrent_core {self.recurrent_core!r}")
         if tp > 1:
             # Megatron column/row pair per head: the hidden's column
             # slice feeds this shard's relu'd activations straight into
@@ -303,7 +249,7 @@ class R2D2Network(nn.Module):
         obs: jnp.ndarray,          # (B, *obs_shape) uint8
         last_action: jnp.ndarray,  # (B,) int32
         last_reward: jnp.ndarray,  # (B,) float32
-        carry: Carry,              # ((B, H), (B, H))
+        carry: Carry,              # models/core.py
         task: jnp.ndarray | None = None,  # (B,) int32 (multi-task only)
     ) -> Tuple[jnp.ndarray, Carry]:
         x = self._core_input(obs, last_action, last_reward)
@@ -315,7 +261,7 @@ class R2D2Network(nn.Module):
         obs: jnp.ndarray,             # (B, *obs_shape) uint8
         last_action: jnp.ndarray,     # (B,) int32
         last_reward: jnp.ndarray,     # (B,) float32
-        carry: Carry,                 # ((B, H), (B, H))
+        carry: Carry,                 # models/core.py
         explore: jnp.ndarray,         # (B,) bool ε-coin per row
         random_actions: jnp.ndarray,  # (B,) int random draws in [0, A)
         task: jnp.ndarray | None = None,  # (B,) int32 (multi-task only)
@@ -341,7 +287,7 @@ class R2D2Network(nn.Module):
         obs: jnp.ndarray,           # (B, T, *obs_shape) uint8
         last_action: jnp.ndarray,   # (B, T) int32
         last_reward: jnp.ndarray,   # (B, T) float32
-        hidden: jnp.ndarray,        # (B, 2, H) stored (h, c)
+        hidden: jnp.ndarray,        # (B, *state_shape) stored state (models/core.py)
         burn_in: jnp.ndarray,       # (B,) int32
         learning: jnp.ndarray,      # (B,) int32
         forward: jnp.ndarray,       # (B,) int32
@@ -351,14 +297,13 @@ class R2D2Network(nn.Module):
         B, T = obs.shape[:2]
         L, F = self.learning_steps, self.forward_steps
 
-        # fused-sequence semantics: burn-in steps refresh state only; the
+        # a core that cuts at burn-in: burn-in steps refresh state only; the
         # stop-gradient seam lives inside the core's backward pass, so the
         # encoder differentiates each row's L + F frames from the seam only
         # (a sequence no longer than that is all window; un-jitted
         # initialisation wants the parameters alone, not the index work,
         # which would compile op by op in every process)
-        seam = self.recurrent_core == "lstm" and self.fused_sequence
-        if seam and T > L + F and not self.is_initializing():
+        if self.core.cuts_at_burn_in and T > L + F and not self.is_initializing():
             x = self._core_input(obs, last_action, last_reward, burn_in)
         else:
             x = self._core_input(
@@ -367,11 +312,7 @@ class R2D2Network(nn.Module):
                 last_reward.reshape(B * T),
             ).reshape(B, T, -1)
 
-        carry = (hidden[:, 0], hidden[:, 1])
-        if seam:
-            outs, _ = self.core(x, carry, burn_in=burn_in)  # (B, T, H)
-        else:
-            outs, _ = self.core(x, carry)  # (B, T, H)
+        outs, _ = self.core(x, unpack_state(hidden), burn_in=burn_in)  # (B, T, H)
 
         t = jnp.arange(L, dtype=jnp.int32)
         learn_idx = jnp.clip(burn_in[:, None] + t[None, :], 0, T - 1)
@@ -396,14 +337,6 @@ class R2D2Network(nn.Module):
         )
 
 
-def initial_carry(batch: int, hidden_dim: int) -> Carry:
-    """Zero (h, c) — the episode-start state (reference worker.py:502)."""
-    return (
-        jnp.zeros((batch, hidden_dim), jnp.float32),
-        jnp.zeros((batch, hidden_dim), jnp.float32),
-    )
-
-
 def init_params(rng: jax.Array, cfg: R2D2Config):
     """Initialize parameters with dummy fixed-shape unroll inputs."""
     net = R2D2Network.from_config(cfg)
@@ -411,7 +344,7 @@ def init_params(rng: jax.Array, cfg: R2D2Config):
     obs = jnp.zeros((B, T, *cfg.obs_shape), jnp.uint8)
     la = jnp.zeros((B, T), jnp.int32)
     lr = jnp.zeros((B, T), jnp.float32)
-    hid = jnp.zeros((B, 2, cfg.hidden_dim), jnp.float32)
+    hid = jnp.zeros((B, *state_spec(cfg)[0]), jnp.float32)
     ones = jnp.ones((B,), jnp.int32)
     # the task input widens the head's Dense inputs, so multi-task init
     # must trace with it for the params to take the wider shape

@@ -28,6 +28,7 @@ import numpy as np
 from r2d2_tpu.actor import ParamStore, VectorizedActor
 from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.learner import DeviceBatch, init_train_state, make_train_step
+from r2d2_tpu.models.core import zero_carry
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.multitask.registry import TaskSpec, build_registry
 from r2d2_tpu.ops.epsilon import multitask_epsilon_ladders
@@ -76,10 +77,7 @@ def rollout_returns(
     obs = np.array(env.reset_all())
     la = np.zeros(E, np.int32)
     lr = np.zeros(E, np.float32)
-    carry = (
-        jnp.zeros((E, cfg.hidden_dim), jnp.float32),
-        jnp.zeros((E, cfg.hidden_dim), jnp.float32),
-    )
+    carry = zero_carry(cfg, E)
     task_vec = (
         jnp.full((E,), spec.task_id, jnp.int32) if cfg.num_tasks > 1 else None
     )

@@ -27,6 +27,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
+from r2d2_tpu.models.core import zero_state
 from r2d2_tpu.ops.priority import mixed_td_priorities_np
 from r2d2_tpu.ops.returns import n_step_gammas, n_step_returns
 from r2d2_tpu.ops.value_rescale import inverse_value_rescale_np, value_rescale_np
@@ -59,9 +60,7 @@ class SequenceAccumulator:
         self.obs_buf: List[np.ndarray] = [np.array(init_obs)]
         self.last_action_buf: List[int] = [0]
         self.last_reward_buf: List[float] = [0.0]
-        self.hidden_buf: List[np.ndarray] = [
-            np.zeros((2, self.cfg.hidden_dim), dtype=np.float32)
-        ]
+        self.hidden_buf: List[np.ndarray] = [zero_state(self.cfg)]
         self.action_buf: List[int] = []
         self.reward_buf: List[float] = []
         self.qval_buf: List[np.ndarray] = []
@@ -114,7 +113,8 @@ class SequenceAccumulator:
         q_value: np.ndarray,
         hidden: np.ndarray,
     ) -> None:
-        """Append one transition. `hidden` is the (2, H) LSTM state AFTER
+        """Append one transition. `hidden` is the core's stored state
+        (models/core.py: one row of pack_state, float32) AFTER
         consuming the pre-step observation, i.e. the state to use when the
         network next consumes `next_obs` (reference worker.py:511-527)."""
         self.action_buf.append(int(action))

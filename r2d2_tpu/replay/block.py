@@ -29,6 +29,8 @@ import dataclasses
 
 import numpy as np
 
+from r2d2_tpu.models.core import state_spec
+
 
 @dataclasses.dataclass
 class Block:
@@ -45,10 +47,11 @@ class Block:
     n_step_reward: np.ndarray
     # (T,) float32 — bootstrap discount gamma_n(t); 0 past a terminal
     gamma: np.ndarray
-    # (num_sequences, 2, hidden_dim) — LSTM (h, c) at the TRUE replay-
-    # window start of each sequence (fixes SURVEY.md quirk 1). Packed
-    # float32 by the accumulator; the stores downcast to cfg.state_dtype
-    # (bfloat16 under precision="bf16") at write time.
+    # (num_sequences, *state_shape) — the core's stored state
+    # (models/core.py) at the TRUE replay-window start of each sequence
+    # (fixes SURVEY.md quirk 1). Packed float32 by the accumulator; the
+    # stores downcast to cfg.state_dtype (bfloat16 under precision="bf16")
+    # at write time.
     hidden: np.ndarray
     num_sequences: int
     # (num_sequences,) int32 each
@@ -107,9 +110,12 @@ def rows_to_frames(rows, obs_shape):
 def store_field_specs(cfg):
     """Per-slot (shape, dtype) of every replay-store field, WITHOUT the
     leading block axis — the single source of truth shared by all device
-    store planes (device_store / sharded_store / multihost_store). Adding a
-    Block field means extending this map and pad_block_fields once."""
+    store planes (device_store / sharded_store / multihost_store) and, for
+    the per-sequence fields, by the host stores (replay_buffer /
+    tiered_store). Adding a Block field means extending this map and
+    pad_block_fields once."""
     S, slot, bl = cfg.seqs_per_block, cfg.block_slot_len, cfg.block_length
+    state_shape, state_dtype = state_spec(cfg)
     return {
         # frames as lane-aligned rows (module docstring, frames_to_rows)
         "obs": ((slot, obs_rows(cfg.obs_shape), LANES), np.uint8),
@@ -118,10 +124,11 @@ def store_field_specs(cfg):
         "action": ((bl,), np.int32),
         "n_step_reward": ((bl,), np.float32),
         "gamma": ((bl,), np.float32),
-        # carries store at cfg.state_dtype: float32 on the golden path,
-        # bfloat16 under precision="bf16" (half the HBM/H2D bytes; the
-        # model cores cast back to their compute dtype on use)
-        "hidden": ((S, 2, cfg.hidden_dim), cfg.state_dtype),
+        # the core's own statement (models/core.py), at cfg.state_dtype:
+        # float32 on the golden path, bfloat16 under precision="bf16"
+        # (half the HBM/H2D bytes; the model cores cast back to their
+        # compute dtype on use)
+        "hidden": ((S, *state_shape), state_dtype),
         "burn_in": ((S,), np.int32),
         "learning": ((S,), np.int32),
         "forward": ((S,), np.int32),

@@ -26,7 +26,7 @@ from typing import Optional
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
-from r2d2_tpu.replay.block import Block
+from r2d2_tpu.replay.block import Block, store_field_specs
 from r2d2_tpu.replay.control_plane import ReplayControlPlane
 
 
@@ -37,7 +37,7 @@ class SampledBatch:
     obs: np.ndarray            # (B, seq_len, *obs_shape) uint8
     last_action: np.ndarray    # (B, seq_len) uint8 scalar actions
     last_reward: np.ndarray    # (B, seq_len) float32
-    hidden: np.ndarray         # (B, 2, H) cfg.state_dtype (f32 | bf16)
+    hidden: np.ndarray         # (B, *state_shape) cfg.state_dtype (f32 | bf16)
     action: np.ndarray         # (B, L) int32
     n_step_reward: np.ndarray  # (B, L) float32
     gamma: np.ndarray          # (B, L) float32
@@ -71,7 +71,8 @@ class ReplayBuffer(ReplayControlPlane):
         # cfg.state_dtype: float32, or bfloat16 under precision="bf16" —
         # halves the carry slab and every sampled batch's hidden bytes
         # (block.hidden arrives float32; the slab assignment downcasts)
-        self.hidden_store = np.zeros((nb, S, 2, cfg.hidden_dim), dtype=cfg.state_dtype)
+        hidden_shape, hidden_dtype = store_field_specs(cfg)["hidden"]
+        self.hidden_store = np.zeros((nb, *hidden_shape), dtype=hidden_dtype)
         self.burn_in_store = np.zeros((nb, S), dtype=np.int32)
         self.learning_store = np.zeros((nb, S), dtype=np.int32)
         self.forward_store = np.zeros((nb, S), dtype=np.int32)

@@ -45,6 +45,7 @@ from typing import Optional
 
 import numpy as np
 
+from r2d2_tpu.replay.block import store_field_specs
 from r2d2_tpu.replay.disk_tier import DiskTier
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer, SampledBatch
 from r2d2_tpu.replay.sum_tree import SumTree
@@ -64,7 +65,7 @@ class StagedWindows:
     obs: np.ndarray            # (K, B, seq_len, *obs_shape) uint8
     last_action: np.ndarray    # (K, B, seq_len) uint8
     last_reward: np.ndarray    # (K, B, seq_len) float32
-    hidden: np.ndarray         # (K, B, 2, H) float32
+    hidden: np.ndarray         # (K, B, *state_shape) cfg.state_dtype
     action: np.ndarray         # (K, B, L) int32
     n_step_reward: np.ndarray  # (K, B, L) float32
     gamma: np.ndarray          # (K, B, L) float32
@@ -158,9 +159,8 @@ class TieredReplayBuffer(ReplayBuffer):
         self.occupied = np.zeros(total, bool)
         self.num_seq_store = np.zeros(total, np.int32)
         self.slot_stamp = np.zeros(total, np.int64)
-        self.hidden_store = np.zeros(
-            (total, S, 2, cfg.hidden_dim), dtype=cfg.state_dtype
-        )
+        hidden_shape, hidden_dtype = store_field_specs(cfg)["hidden"]
+        self.hidden_store = np.zeros((total, *hidden_shape), dtype=hidden_dtype)
         self.burn_in_store = np.zeros((total, S), dtype=np.int32)
         self.learning_store = np.zeros((total, S), dtype=np.int32)
         self.forward_store = np.zeros((total, S), dtype=np.int32)

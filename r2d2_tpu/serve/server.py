@@ -57,6 +57,7 @@ import numpy as np
 
 from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.learner import init_train_state
+from r2d2_tpu.models.core import state_spec
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.serve.batcher import BucketStaging, MicroBatcher, ServeRequest, StagedBatch
 from r2d2_tpu.serve.degrade import DegradeConfig, DegradeController
@@ -304,6 +305,7 @@ class PolicyServer:
         self.cache = RecurrentStateCache(
             serve_cfg.cache_capacity, cfg.hidden_dim, dtype=cfg.state_dtype,
             spill_capacity=cfg.serve_spill, device=device,
+            state_shape=state_spec(cfg)[0], core=cfg.recurrent_core,
         )
         self.batcher = MicroBatcher(
             buckets=serve_cfg.buckets,

@@ -37,8 +37,8 @@ the slab has never seen (or has itself LRU-dropped) start fresh.
 
 `spill_capacity == 0` keeps the original semantics: an evicted session
 that returns is re-admitted FRESH (zero carry, NOOP last action, zero
-last reward — exactly the training episode-start state, models/r2d2.py
-`initial_carry`), which is also what per-session reset produces.
+last reward — exactly the training episode-start state, models/core.py
+`zero_carry`), which is also what per-session reset produces.
 
 Array mutation (`arrays` / `commit` / the demote readback / the promote
 scatter) is single-writer by contract — only the serve loop touches the
@@ -67,13 +67,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from r2d2_tpu.models.core import check_two_row_state
+
 
 class RecurrentStateCache:
     """Fixed-capacity device store: session_id -> (carry, last_action,
     last_reward) with LRU eviction into an optional host spill tier."""
 
     def __init__(self, capacity: int, hidden_dim: int, dtype=jnp.float32,
-                 spill_capacity: int = 0, device=None):
+                 spill_capacity: int = 0, device=None,
+                 state_shape: Optional[Tuple[int, ...]] = None, core: str = ""):
+        # h / c / _spill_h / _spill_c below were written for a two-row
+        # state: a core that stores another shape (models/core.py
+        # state_spec, passed by the server) is refused HERE, not in a
+        # scatter (ROADMAP D1b)
+        if state_shape is not None:
+            check_two_row_state(state_shape, hidden_dim, core, "RecurrentStateCache")
         if capacity < 1:
             raise ValueError("cache capacity must be >= 1")
         if spill_capacity < 0:

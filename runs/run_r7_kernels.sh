@@ -9,13 +9,11 @@
 #      throughput number is noise.
 #   2. breakdown          — per-phase step timing (unroll / head /
 #      loss+grad / optimizer), the denominator map kernel rows cite.
-#   3. learner headline   — best-of-matrix with the fused_seq sub-row
-#      (per-step Pallas path re-run at the winning batch).
+#   3. learner headline   — best-of-matrix.
 #   4. serve 3-arm        — fp32 -> bf16 -> int8; the serve_int8 sub-row
 #      carries vs_fp32 and the q_drift_vs_fp32 bounded-parity column.
 #
-# PRE-REGISTERED read: rung 3's fused_seq.speedup_vs_per_step > 1.0 is
-# the tentpole's claim on real hardware. Rung 4's q_drift_vs_fp32 staying ~1e-2 of the
+# PRE-REGISTERED read: rung 4's q_drift_vs_fp32 staying ~1e-2 of the
 # Q scale is the int8 arm's bounded-parity claim at full network size.
 cd /root/repo
 
@@ -40,7 +38,7 @@ echo "=== RUNG 2: per-phase breakdown ==="
 python bench.py --mode breakdown | tee -a "$OUT"
 echo "=== BREAKDOWN EXIT: $? ==="
 
-echo "=== RUNG 3: learner headline (fused_seq row) ==="
+echo "=== RUNG 3: learner headline ==="
 python bench.py --mode learner --precision both | tee -a "$OUT"
 echo "=== LEARNER EXIT: $? ==="
 

@@ -232,7 +232,7 @@ def test_carry_episodes_across_chunks():
     assert np.asarray(f2["obs"])[:, 0].max() == 12
     np.testing.assert_allclose(
         np.asarray(f2["hidden"])[:, 0],
-        np.stack([np.asarray(carry1.h), np.asarray(carry1.c)], axis=1),
+        np.stack([np.asarray(x) for x in carry1.core], axis=1),
         atol=1e-6,
     )
     np.testing.assert_array_equal(

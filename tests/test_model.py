@@ -14,7 +14,7 @@ import pytest
 
 from r2d2_tpu.config import R2D2Config, tiny_test
 from r2d2_tpu.models.lstm import LSTM
-from r2d2_tpu.models.r2d2 import R2D2Network, init_params, initial_carry
+from r2d2_tpu.models.r2d2 import R2D2Network, init_params
 
 
 def make_net(cfg):
@@ -222,11 +222,7 @@ def _views_from_core_input(m, x, hid, burn, learn, fwd):
     """Core + both Q views from a time-ordered core input (B, T, D)."""
     T = x.shape[1]
     L, F = m.learning_steps, m.forward_steps
-    carry = (hid[:, 0], hid[:, 1])
-    if m.recurrent_core == "lstm" and m.fused_sequence:
-        outs, _ = m.core(x, carry, burn_in=burn)
-    else:
-        outs, _ = m.core(x, carry)
+    outs, _ = m.core(x, (hid[:, 0], hid[:, 1]), burn_in=burn)
     t = jnp.arange(L, dtype=jnp.int32)
     learn_idx = jnp.clip(burn[:, None] + t[None, :], 0, T - 1)
     boot_idx = jnp.minimum(burn[:, None] + F + t[None, :], (burn + learn + fwd)[:, None] - 1)
@@ -269,7 +265,7 @@ def test_window_split_equals_the_one_call_form_in_values_and_every_gradient(enco
         encoder=encoder, obs_shape=(36, 36, 1) if encoder == "nature" else (12, 12, 1),
     )
     net, params = make_net(cfg)
-    assert net.fused_sequence and net.recurrent_core == "lstm"
+    assert net.core.cuts_at_burn_in
     rng = np.random.default_rng(32)
     B, L = 4, cfg.learning_steps
     obs, la, lr, hid = random_inputs(cfg, rng, B=B)

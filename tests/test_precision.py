@@ -15,7 +15,8 @@ import pytest
 
 from r2d2_tpu.config import tiny_test
 from r2d2_tpu.learner import init_train_state, make_train_step
-from r2d2_tpu.models.r2d2 import R2D2Network, init_params, initial_carry
+from r2d2_tpu.models.core import zero_carry
+from r2d2_tpu.models.r2d2 import R2D2Network, init_params
 
 from tests.test_learner import random_batch
 
@@ -69,7 +70,7 @@ def _act_inputs(cfg, B=8, seed=0):
     obs = rng.integers(0, 255, size=(B, *cfg.obs_shape), dtype=np.uint8)
     la = rng.integers(0, cfg.action_dim, size=B).astype(np.int32)
     lr = rng.normal(size=B).astype(np.float32)
-    carry = initial_carry(B, cfg.hidden_dim)
+    carry = zero_carry(cfg, B)
     return jnp.asarray(obs), jnp.asarray(la), jnp.asarray(lr), carry
 
 
