@@ -635,8 +635,15 @@ class R2D2Config:
             return self.recurrent_core
         if self.lstm_backend != "auto":
             return self.lstm_backend
+        return "pallas" if self._mosaic_call_allowed() else "scan"
+
+    def _mosaic_call_allowed(self) -> bool:
+        """Whether the update body may hold a Mosaic (Pallas TPU) call: a TPU
+        backend, and no GSPMD-partitioned mesh axis over the body
+        (resolved_core_backend states the rule; the LSTM's and the LRU's
+        kernels both sit under it)."""
         if self.tp_shards_params:
-            return "scan"
+            return False
         if self.dp_size * self.tp_size * self.fsdp_size > 1 and not (
             self.resolved_partitioning == "manual"
             or (
@@ -645,10 +652,38 @@ class R2D2Config:
                 and self.fsdp_size == 1
             )
         ):
-            return "scan"
+            return False
         import jax  # deferred: config stays import-light
 
-        return "pallas" if jax.default_backend() == "tpu" else "scan"
+        return jax.default_backend() == "tpu"
+
+    def _rows_per_device(self, batch_size: Optional[int] = None) -> int:
+        """Rows of a training batch that one device holds: the batch shards
+        over dp (and over fsdp too under manual partitioning's ZeRO-2 data
+        layout)."""
+        B = self.batch_size if batch_size is None else batch_size
+        shards = max(self.dp_size, 1)
+        if self.resolved_partitioning == "manual":
+            shards *= max(self.fsdp_size, 1)
+        return max(B // shards, 1)
+
+    @property
+    def resolved_lru_recurrence(self) -> str:
+        """"pallas" | "scan" | "chunked": how the LRU core's training unroll
+        runs its recurrence (models/lru.py), resolved as the LSTM's backend
+        is, from what the code observes. lru_chunk > 0 selects the chunked
+        MXU form; otherwise the sequential Pallas kernel (ops/pallas_lru.py)
+        wherever a Mosaic call may sit (resolved_core_backend's rule) and the
+        training batch's rows per device and H are whole (8, 128) tiles, and
+        jax.lax.associative_scan everywhere else (CPU, GSPMD meshes, odd
+        shapes). The module repeats the shape test on what it is called
+        with, so an unroll at another batch size falls back by itself."""
+        if self.lru_chunk > 0:
+            return "chunked"
+        from r2d2_tpu.ops.pallas_lru import kernel_fits  # deferred, as above
+
+        fits = kernel_fits(self._rows_per_device(), self.hidden_dim)
+        return "pallas" if fits and self._mosaic_call_allowed() else "scan"
 
     def resolve_backward_arm(self, batch_size: Optional[int] = None):
         """-> (arm, ckpt_stride): the backward arm the fused sequence
@@ -677,15 +712,9 @@ class R2D2Config:
             vmem_capacity_bytes,
         )
 
-        B = self.batch_size if batch_size is None else batch_size
-        # residuals live per device: the batch shards over dp (and over
-        # fsdp too under manual partitioning's ZeRO-2 data layout)
-        shards = max(self.dp_size, 1)
-        if self.resolved_partitioning == "manual":
-            shards *= max(self.fsdp_size, 1)
         return choose_backward_arm(
             self.seq_len,
-            max(B // shards, 1),
+            self._rows_per_device(batch_size),  # residuals live per device
             self.hidden_dim,
             self.resolved_compute_dtype,
             self.backward_residual_budget_mb * (1 << 20),

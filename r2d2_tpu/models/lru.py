@@ -11,13 +11,32 @@ reached for the same reason: a DIAGONAL LINEAR complex recurrence
 
 per the Linear Recurrent Unit design (Orvieto et al. 2023, "Resurrecting
 Recurrent Neural Networks for Long Sequences" — public literature;
-pattern only, no code copied). Linearity makes the recurrence
-ASSOCIATIVE, so the whole unroll runs as one `jax.lax.associative_scan`:
-O(log T) dependent steps instead of O(T), mapping a 1024-step window onto
-the VPU as ~10 parallel sweeps. Expressivity lost to linearity is bought
-back the standard way: a nonlinear readout of the state plus an input
-skip, with stability guaranteed by parameterizing |lambda| < 1 through
-exp(-exp(nu_log)).
+pattern only, no code copied). Linearity is what the LSTM lacks, and it is
+used twice here, by two implementations of the SAME recurrence that the
+config picks between from what it observes (platform, placement, shape:
+`config.resolved_lru_recurrence`, the rule the LSTM's kernel sits under):
+
+- ON THE CHIP, outside any GSPMD-partitioned mesh axis, with H a multiple
+  of 128 and a multiple of 8 rows per device: one sequential Pallas pass
+  over time (ops/pallas_lru.py), the carry in VMEM, `u` read once and `h`
+  written once; the backward is the same pass over reversed time. The
+  recurrence is elementwise, so T dependent steps cost T x a few VPU
+  instructions and the pass runs at the pace of its bytes (PERF.md finding
+  34). The module runs time-major around it: input and output are
+  transposed in the compute dtype, never an f32 state-sized array.
+- ELSEWHERE (CPU, GSPMD meshes, other shapes, e.g. a B = 1 evaluation
+  unroll): the recurrence is ASSOCIATIVE, so the whole unroll is one
+  `jax.lax.associative_scan`: O(log T) dependent steps instead of O(T), at
+  the price of ~2 log2 T passes over four f32 (B, T, H) arrays (on the v5e
+  at T = 581 some 3,000 instructions where the kernel path has three calls:
+  the reason the kernel exists).
+
+`lru_chunk > 0` selects a third formulation, the chunked MXU form
+(`_chunked_states`), wherever it is set; the acting `step` is one
+elementwise multiply-add per call in every case. Expressivity lost to
+linearity is bought back the standard way: a nonlinear readout of the state
+plus an input skip, with stability guaranteed by parameterizing
+|lambda| < 1 through exp(-exp(nu_log)).
 
 Drop-in contract (models/core.py is the rule):
 - carry is a pair of (B, H) real arrays — here (Re h, Im h) instead of
@@ -28,10 +47,11 @@ Drop-in contract (models/core.py is the rule):
   `step(x (B,D), carry) -> (out, carry)` mirror models/lstm.py.
 
 Numerics: input/readout matmuls run in the configured compute dtype
-(bf16 on TPU — MXU work); the elementwise recurrence and the scan run in
-float32 (it is bandwidth-light, and f32 keeps 1000-step cumulative
-products honest). Complex math is spelled out over (re, im) real pairs —
-no complex dtypes, so XLA:TPU sees plain f32 elementwise ops.
+(bf16 on TPU — MXU work); the elementwise recurrence runs in float32 in
+every formulation (f32 keeps 1000-step cumulative products honest; the
+kernel sums in `step`'s sequential order, the scan in a tree's). Complex
+math is spelled out over (re, im) real pairs — no complex dtypes, so
+XLA:TPU sees plain f32 elementwise ops.
 
 Select with `recurrent_core="lru"` (config.py); params deliberately use
 none of the Megatron-annotated names in parallel/mesh.train_state_shardings
@@ -49,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.models.lstm import _uniform_init
+from r2d2_tpu.ops.pallas_lru import kernel_fits, lru_scan
 
 Carry = Tuple[jnp.ndarray, jnp.ndarray]  # (re, im), each (B, H) float32
 
@@ -94,6 +115,14 @@ class LRU(nn.Module):
     # the chunk GEMMs run at Precision.HIGHEST so the MXU does not round
     # the f32 operands to bf16; see _chunked_states for the cost note).
     chunk: int = 0
+    # which implementation runs the plain (chunk == 0) recurrence: "scan" is
+    # jax.lax.associative_scan, "pallas" the sequential kernel
+    # (ops/pallas_lru.py) wherever the shapes it is called with are whole
+    # (8, 128) tiles, and the associative scan where they are not (a B = 1
+    # evaluation unroll). from_config takes it from the config's placement
+    # rule (config.resolved_lru_recurrence); off a TPU "pallas" runs the
+    # kernel under the Pallas interpreter, which is what the CPU tests do.
+    backend: str = "scan"
 
     # the seam's statements (models/core.py): the associative scan has no
     # per-row seam, so __call__ ignores burn_in and backpropagates through
@@ -113,6 +142,7 @@ class LRU(nn.Module):
             in_dim=in_dim,
             dtype=jnp.dtype(cfg.resolved_compute_dtype),
             chunk=cfg.lru_chunk,
+            backend="pallas" if cfg.resolved_lru_recurrence == "pallas" else "scan",
             r_min=cfg.lru_r_min,
             r_max=cfg.lru_r_max,
         )
@@ -161,13 +191,21 @@ class LRU(nn.Module):
         y = hr @ self.out_re.astype(self.dtype) - hi @ self.out_im.astype(self.dtype)
         return nn.gelu(y) + xs.astype(self.dtype) @ self.skip.astype(self.dtype)
 
-    def _scan_states(self, u_re, u_im, carry):
-        """All T states via ONE associative scan: elements (a, b) of the
-        recurrence h_t = a_t h_{t-1} + b_t with a_t = lambda (constant),
-        combined under (a1,b1) o (a2,b2) = (a2 a1, a2 b1 + b2); the
-        scan's prefix (A_t, B_t) satisfies h_t = A_t h0 + B_t."""
-        B, T, H = u_re.shape
+    def _scan_states(self, u_re, u_im, carry, kernel: bool = False):
+        """All T states of h_t = lambda h_{t-1} + u_t from `carry`, by one of
+        two implementations of the same recurrence. `kernel`: u is TIME-major
+        (T, B, H) and one sequential Pallas pass walks it (ops/pallas_lru.py;
+        its backward is the same pass reversed). Otherwise u is (B, T, H) and
+        ONE associative scan combines elements (a, b) of h_t = a_t h_{t-1} +
+        b_t with a_t = lambda under (a1,b1) o (a2,b2) = (a2 a1, a2 b1 + b2),
+        whose prefix (A_t, B_t) satisfies h_t = A_t h0 + B_t: O(log T) depth,
+        but every level re-reads and re-writes four f32 (B, T, H) arrays."""
         lam_re, lam_im, _ = self._decay()
+        h0_re = carry[0].astype(jnp.float32)
+        h0_im = carry[1].astype(jnp.float32)
+        if kernel:
+            return lru_scan(lam_re, lam_im, u_re, u_im, h0_re, h0_im)
+        B, T, H = u_re.shape
         a_re = jnp.broadcast_to(lam_re, (B, T, H))
         a_im = jnp.broadcast_to(lam_im, (B, T, H))
 
@@ -183,8 +221,7 @@ class LRU(nn.Module):
         A_re, A_im, B_re, B_im = jax.lax.associative_scan(
             combine, (a_re, a_im, u_re, u_im), axis=1
         )
-        h0_re = carry[0].astype(jnp.float32)[:, None]
-        h0_im = carry[1].astype(jnp.float32)[:, None]
+        h0_re, h0_im = h0_re[:, None], h0_im[:, None]
         h_re = A_re * h0_re - A_im * h0_im + B_re
         h_im = A_re * h0_im + A_im * h0_re + B_im
         return h_re, h_im
@@ -262,17 +299,30 @@ class LRU(nn.Module):
     def __call__(
         self, xs: jnp.ndarray, carry: Carry, burn_in=None
     ) -> Tuple[jnp.ndarray, Carry]:
-        """Time-parallel unroll over (B, T, D) from carry; returns
-        ((B, T, H), final carry). chunk selects the formulation (same
-        math): 0 = one associative scan, > 0 = chunked MXU matmuls.
+        """Unroll over (B, T, D) from carry; returns ((B, T, H), final
+        carry). Same math by three formulations: chunk > 0 = chunked MXU
+        matmuls; else the sequential kernel where `backend` and the shapes
+        allow it, and one associative scan elsewhere. The kernel wants time
+        leading, so that path runs the whole module time-major: the input is
+        transposed once in the compute dtype, the output once likewise, and
+        no f32 state-sized array is ever transposed.
         `burn_in` is ignored (cuts_at_burn_in = False)."""
         _, _, gamma = self._decay()
-        u_re, u_im = self._project_in(xs, gamma)  # (B, T, H) f32
+        kernel = (
+            self.chunk == 0
+            and self.backend == "pallas"
+            and kernel_fits(xs.shape[0], self.hidden_dim)
+        )
+        if kernel:
+            xs = jnp.swapaxes(xs.astype(self.dtype), 0, 1)  # (T, B, D)
+        u_re, u_im = self._project_in(xs, gamma)  # f32, (B, T, H) or (T, B, H)
         if self.chunk > 0:
             h_re, h_im = self._chunked_states(u_re, u_im, carry)
         else:
-            h_re, h_im = self._scan_states(u_re, u_im, carry)
+            h_re, h_im = self._scan_states(u_re, u_im, carry, kernel)
         outs = self._readout(h_re, h_im, xs)
+        if kernel:
+            return jnp.swapaxes(outs, 0, 1), (h_re[-1], h_im[-1])
         return outs, (h_re[:, -1], h_im[:, -1])
 
     def step(self, x: jnp.ndarray, carry: Carry) -> Tuple[jnp.ndarray, Carry]:

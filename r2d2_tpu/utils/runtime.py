@@ -33,6 +33,9 @@ def describe_runtime(cfg: R2D2Config) -> dict:
         "device_kind": devices[0].device_kind,
         "device_count": len(devices),
         "core": core,
+        # the LRU's training recurrence: pallas (compiled kernel) | scan |
+        # chunked; `core` itself stays "lru"
+        **({"lru_recurrence": cfg.resolved_lru_recurrence} if core == "lru" else {}),
         # a Pallas core off-TPU runs under the interpreter (how the CPU
         # tests pin kernel parity) — never a device measurement
         "pallas_interpreted": core == "pallas" and jax.default_backend() != "tpu",

@@ -40,47 +40,36 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def main(argv=None) -> int:
-    p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    p.add_argument("config", help="a name under benchmark/configs/")
-    p.add_argument("--hlo-dir", default=None, help="write each program's as_text() here")
-    p.add_argument("--buffer-capacity", type=int, default=None,
-                   help="compile at another replay capacity than the configuration's (what would fit)")
-    args = p.parse_args(argv)
-
+def step_programs(config: str, topo, buffer_capacity=None):
+    """-> (cfg, {"mega": (jitted, abstract arguments), "multi": ...}, the obs
+    store's bytes per device) for a benchmark configuration at its real size
+    on the described topology's devices. The caller steers what the code asks
+    the ATTACHED device (`pallas_lstm._interpret`, `vmem_capacity_bytes`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
-    # a compile for a described device cannot be read back from the persistent cache
-    jax.config.update("jax_enable_compilation_cache", False)
     from benchmark import harness
-    from benchmark.readers import trace_scope
     from r2d2_tpu import learner, megastep
     from r2d2_tpu.collect import default_chunk_len
     from r2d2_tpu.models.r2d2 import R2D2Network
-    from r2d2_tpu.ops import pallas_lstm
     from r2d2_tpu.replay.block import store_field_specs
     from r2d2_tpu.train import build_fn_env
-    from r2d2_tpu.utils import profiling
 
-    # the code asks the ATTACHED device (a CPU here): steer it to the chip's answers
-    pallas_lstm._interpret = lambda: False
-    pallas_lstm.vmem_capacity_bytes = lambda: 128 << 20  # v5e
-
-    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    conf = harness.load_json(os.path.join(ROOT, "benchmark", "configs", args.config + ".json"))
+    conf = harness.load_json(os.path.join(ROOT, "benchmark", "configs", config + ".json"))
     traffic = harness.load_json(os.path.join(ROOT, "benchmark", "traffic", "learn.json"))
     extra = {"samples_per_insert": float(traffic["samples_per_insert"])}
-    if args.buffer_capacity:
-        extra["buffer_capacity"] = args.buffer_capacity
+    if buffer_capacity:
+        extra["buffer_capacity"] = buffer_capacity
     cfg = harness.build_config(conf, 1, extra)
     if cfg.recurrent_core == "lstm":
         cfg = cfg.replace(lstm_backend="pallas")
     dp = max(cfg.dp_size, 1)
     net = R2D2Network.from_config(cfg)
+    if cfg.recurrent_core == "lru" and cfg.lru_chunk == 0:
+        # no option names the LRU's kernel: take the module the chip would build
+        net = net.clone(core=net.core.clone(backend="pallas"))
     fn_env = build_fn_env(cfg)
     E, K, B, chunk = cfg.num_actors, cfg.updates_per_dispatch, cfg.batch_size, default_chunk_len(cfg)
 
@@ -113,10 +102,48 @@ def main(argv=None) -> int:
         "mega": (mega, (state, stores, env, sds((E,), jnp.float32, per_dp), key, *coords, start)),
         "multi": (multi, (state, stores, *coords)),
     }
+    return cfg, programs, obs_store_bytes
 
+
+def instructions_in_buckets(text: str) -> dict:
+    """{bucket of benchmark/trace_scopes.json: named instructions of a
+    compiled program's text that it claims}, by the reader's own order."""
+    from benchmark.readers import trace_scope
+    from r2d2_tpu.utils import profiling
+
+    buckets = [(name, re.compile(rx)) for name, rx in trace_scope.load_scopes(os.path.join(ROOT, "benchmark"))["buckets"]]
+    in_bucket = {b: 0 for b, _ in buckets}
+    for op in profiling.parse_op_names(text).values():
+        hit = next((b for b, rx in buckets if rx.search(op)), None)
+        if hit:
+            in_bucket[hit] += 1
+    return in_bucket
+
+
+def main(argv=None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    p.add_argument("config", help="a name under benchmark/configs/")
+    p.add_argument("--hlo-dir", default=None, help="write each program's as_text() here")
+    p.add_argument("--buffer-capacity", type=int, default=None,
+                   help="compile at another replay capacity than the configuration's (what would fit)")
+    args = p.parse_args(argv)
+
+    import jax
+    from jax.experimental import topologies
+
+    # a compile for a described device cannot be read back from the persistent cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    from benchmark import harness
+    from r2d2_tpu.ops import pallas_lstm
+    from r2d2_tpu.utils import profiling
+
+    # the code asks the ATTACHED device (a CPU here): steer it to the chip's answers
+    pallas_lstm._interpret = lambda: False
+    pallas_lstm.vmem_capacity_bytes = lambda: 128 << 20  # v5e
+
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    _, programs, obs_store_bytes = step_programs(args.config, topo, args.buffer_capacity)
     patterns = harness.load_json(os.path.join(ROOT, "benchmark", "trace_patterns.json"))["categories"]
-    scopes = trace_scope.load_scopes(os.path.join(ROOT, "benchmark"))
-    buckets = [(name, re.compile(rx)) for name, rx in scopes["buckets"]]
     device_scopes = [n for n in profiling.SPANS if n.startswith("r2d2_")]
     for name, (fn, fn_args) in programs.items():
         t = time.time()
@@ -130,11 +157,6 @@ def main(argv=None) -> int:
                  if re.match(r"\s*(ROOT )?%[\w.\-]+ = ", l)]
         op_names = profiling.parse_op_names(text)
         memory = compiled.memory_analysis()
-        in_bucket = {b: 0 for b, _ in buckets}
-        for op in op_names.values():
-            hit = next((b for b, rx in buckets if rx.search(op)), None)
-            if hit:
-                in_bucket[hit] += 1
         row = {
             "program": name, "module": re.search(r"HloModule (\S+?)[,\s]", text).group(1),
             "compile_s": round(time.time() - t, 1), "instructions": len(instr),
@@ -148,7 +170,7 @@ def main(argv=None) -> int:
             "alias_gb": round(memory.alias_size_in_bytes / 1e9, 2),
             "with_op_name": len(op_names),
             "in_scope": {s: sum(f"jit({s})" in v for v in op_names.values()) for s in device_scopes},
-            "in_bucket": in_bucket,
+            "in_bucket": instructions_in_buckets(text),
         }
         print(json.dumps(row), flush=True)
     return 0

@@ -1,6 +1,6 @@
 """Compiles for a described (not attached) TPU v5e: what the chip's compiler
-makes of the Pallas LSTM kernels at the benchmark cells' own T, B, H, with no
-chip time. Pinned here: every kernel is a Mosaic custom call whose HLO
+makes of the Pallas LSTM and LRU kernels at the benchmark cells' own T, B, H,
+with no chip time. Pinned here: every kernel is a Mosaic custom call whose HLO
 instruction is named after its jitted wrapper (`pl.pallas_call(name=...)`),
 which is what the benchmark's `lstm_kernel` trace pattern anchors on
 (`kernels.lstm_ms_per_update`, `kernels.lstm_roofline`: a line without them is
@@ -217,3 +217,49 @@ def test_encoder_backward_runs_over_the_frames_that_can_receive_a_gradient(
     for conv, got in dims.items():
         filters = {d for d in got if d <= 8}  # 8x8, 4x4, 3x3 kernels
         assert got - filters == {frames}, (conv, got)
+
+
+def test_lru_kernels_compile_at_the_cells_shape_named_after_their_wrappers(one_chip, compiled_kernels):
+    """ops/pallas_lru.py at lru-seq581's own (T, B, H) = (581, 32, 512):
+    forward and its VJP are two Mosaic custom calls (the chip's compiler
+    refuses a block that is not whole tiles or asks for more VMEM than it
+    was given), named after their jitted wrappers, in 7 chunks of 83 steps
+    with no padding."""
+    from r2d2_tpu.ops import pallas_lru
+
+    T, B, H = 581, 32, 512
+    assert pallas_lru.chunk_len(T, B) == 83
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def loss(*args):
+        h_re, h_im = pallas_lru.lru_scan(*args)
+        return jnp.sum(h_re * h_im) + jnp.sum(h_re[-1])
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        sds(H), sds(H), sds(T, B, H), sds(T, B, H), sds(B, H), sds(B, H)).compile().as_text()
+    calls = [l.strip() for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    names = sorted(re.sub(r"^%|\.\d+$", "", re.sub(r"^ROOT ", "", l).split(" = ")[0]) for l in calls)
+    assert names == ["_lru_fwd_call", "_lru_rev_call"], names
+    assert not re.search(r"f32\[(58[2-9]|59\d|6\d\d),32,512\]", text)  # no padded copy of u or h
+
+
+def test_lru_step_program_keeps_the_recurrence_in_three_kernel_calls(topo, compiled_kernels):
+    """lru-seq581's update-only step program (`jit_multi`) at real size with
+    the core the chip builds: the online forward, the target forward and the
+    reversed pass are Mosaic calls under `core/..._scan_states` (what
+    `model.lru_recurrence_ms_per_update` anchors on), and the core is a few
+    hundred instructions where the associative scan made 3,399 of it."""
+    spec = importlib.util.spec_from_file_location(
+        "rehearse_step_programs", os.path.join(ROOT, "runs", "rehearse_step_programs.py"))
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+    from r2d2_tpu.utils import profiling
+
+    _, programs, _ = rehearse.step_programs("lru-seq581", topo)
+    fn, args = programs["multi"]
+    text = fn.lower(*args).compile().as_text()
+    op_names = profiling.parse_op_names(text)
+    kernels = {k: v for k, v in op_names.items() if re.match(r"%?_lru_(fwd|rev)_call", k)}
+    assert sorted(re.sub(r"^%|\.\d+$", "", k) for k in kernels) == ["_lru_fwd_call", "_lru_fwd_call", "_lru_rev_call"]
+    assert all("R2D2Network.unroll/core" in v and "_scan_states" in v for v in kernels.values()), kernels
+    assert rehearse.instructions_in_buckets(text)["core"] < 600
