@@ -2759,9 +2759,9 @@ def _model_fits_table(cfg, hbm_gb: float = 16.0):
     """Largest-model-that-fits probe per mesh shape (ISSUE 16): for each
     (dp, tp, fsdp) shape and each config.MODEL_PRESETS entry, sum the
     PER-DEVICE TrainState bytes under the sharding table (tp splits the
-    Megatron kernels, fsdp the Adam moments) plus the peak sequence-
-    backward residual of the arm choose_backward_arm picks for whatever
-    HBM remains. Analytic (abstract shapes, no allocation), so the table
+    Megatron kernels, fsdp the Adam moments) plus what the LSTM sequence
+    kernel's backward keeps in HBM (the h and c sequences and the float32
+    dz). Analytic (abstract shapes, no allocation), so the table
     is exact arithmetic on any host — activations/XLA temps are NOT
     modeled, making "fits" an upper bound on feasibility, not a promise.
 
@@ -2772,10 +2772,6 @@ def _model_fits_table(cfg, hbm_gb: float = 16.0):
 
     from r2d2_tpu.config import MODEL_PRESETS, apply_model_preset
     from r2d2_tpu.learner import init_train_state
-    from r2d2_tpu.ops.pallas_lstm import (
-        choose_backward_arm,
-        seq_backward_residual_bytes,
-    )
     from r2d2_tpu.parallel.sharding_map import process_name, spec_for
 
     class _AbstractMesh:
@@ -2818,23 +2814,13 @@ def _model_fits_table(cfg, hbm_gb: float = 16.0):
                 state_bytes += size * jnp.dtype(leaf.dtype).itemsize // div
             B_local = max(pcfg.batch_size // (dp * fsdp), 1)
             H = pcfg.hidden_dim
-            dtype = pcfg.resolved_compute_dtype
-            arm, stride = choose_backward_arm(
-                T, B_local, H, dtype, max(budget - state_bytes, 1)
-            )
-            dz_item = 4 if arm == "default" else jnp.dtype(dtype).itemsize
-            peak = (
-                seq_backward_residual_bytes(T, B_local, H, dtype, stride)[
-                    "carry_residual_bytes"
-                ]
-                + T * B_local * 4 * H * dz_item
-            )
+            itemsize = jnp.dtype(pcfg.resolved_compute_dtype).itemsize
+            # outs (compute dtype) + cs (f32) + dz (f32, 4H wide)
+            peak = T * B_local * H * (itemsize + 4 + 16)
             total = state_bytes + peak
             fits = total <= budget
             rows[preset] = {
                 "state_bytes": state_bytes,
-                "backward_arm": arm,
-                **({"ckpt_stride": stride} if arm == "ckpt" else {}),
                 "peak_residual_bytes": peak,
                 "total_bytes": total,
                 "fits": fits,
@@ -2850,8 +2836,7 @@ def _model_fits_table(cfg, hbm_gb: float = 16.0):
 
 
 def breakdown_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
-                   precision: str = "bf16", backward_arm: str = "auto",
-                   ckpt_every: int = 0, hbm_gb: float = 16.0,
+                   precision: str = "bf16", hbm_gb: float = 16.0,
                    model_preset: str = ""):
     """Per-phase learner step breakdown: the denominator map for kernel
     work. Times the train step's constituent programs as SEPARATELY
@@ -2892,31 +2877,6 @@ def breakdown_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
         from r2d2_tpu.config import apply_model_preset
 
         cfg = apply_model_preset(cfg, model_preset)
-    # Backward-arm selection (ISSUE 14): time the pallas backward kernels
-    # themselves instead of the scan VJP. Only meaningful on a real TPU —
-    # on CPU the pallas path runs in interpret mode and the timings say
-    # nothing; the analytic backward_arms/residual section below covers
-    # the CPU story for every arm regardless of which one is timed.
-    seq_T = cfg.burn_in_steps + cfg.learning_steps + cfg.forward_steps
-    ckpt_S = ckpt_every or max(
-        s for s in range(1, seq_T) if seq_T % s == 0
-    )
-    if seq_T % ckpt_S:
-        raise SystemExit(f"--ckpt-every {ckpt_S} does not divide T={seq_T}")
-    # "auto" routes through config.resolve_backward_arm — the budget-driven
-    # selector the trainer itself runs (ISSUE 16) — so BENCH rows record
-    # the arm the selector actually picked, not a hand-chosen one.
-    arm_mode = backward_arm
-    if backward_arm == "auto":
-        backward_arm, auto_stride = cfg.replace(
-            backward_arm="auto"
-        ).resolve_backward_arm()
-        if backward_arm == "ckpt" and auto_stride:
-            ckpt_S = auto_stride
-    if backward_arm == "fused_dwh":
-        cfg = cfg.replace(lstm_backend="pallas", seq_fused_dwh=True)
-    elif backward_arm == "ckpt":
-        cfg = cfg.replace(lstm_backend="pallas", seq_grad_checkpoint=ckpt_S)
     dev = jax.devices()[0]
     print(f"device: {dev.device_kind} ({dev.platform})", file=sys.stderr)
 
@@ -3007,8 +2967,6 @@ def breakdown_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
         "core": cfg.recurrent_core
         + (f"_c{cfg.lru_chunk}" if cfg.lru_chunk else ""),
         "precision": cfg.precision,
-        "backward_arm": backward_arm,
-        "backward_arm_mode": arm_mode,
         "model_preset": model_preset or "base",
         "phases": {
             name: {
@@ -3079,52 +3037,6 @@ def breakdown_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
 
     # largest-model-that-fits per mesh shape (config.MODEL_PRESETS sizing)
     report["model_fits"] = _model_fits_table(cfg, hbm_gb=hbm_gb)
-
-    # Peak-residual-bytes row: what each backward arm pins in HBM across
-    # the forward/backward boundary at THESE shapes, from the same
-    # accounting the kernel tests assert (analytic, so it holds on this
-    # host even when only the scan arm is timed). The fused/ckpt arms
-    # also shrink the dz output from f32 to the proj dtype.
-    from r2d2_tpu.ops.pallas_lstm import seq_backward_residual_bytes
-
-    H = cfg.hidden_dim
-    itemsize = jnp.dtype(cfg.resolved_compute_dtype).itemsize
-    dz_f32 = seq_T * B * 4 * H * 4
-    dz_proj = seq_T * B * 4 * H * itemsize
-    arms = {
-        "default": dict(
-            seq_backward_residual_bytes(seq_T, B, H, cfg.resolved_compute_dtype),
-            dz_bytes=dz_f32,
-        ),
-        "fused_dwh": dict(
-            seq_backward_residual_bytes(seq_T, B, H, cfg.resolved_compute_dtype),
-            dz_bytes=dz_proj,
-        ),
-        "ckpt": dict(
-            seq_backward_residual_bytes(
-                seq_T, B, H, cfg.resolved_compute_dtype, ckpt_S
-            ),
-            dz_bytes=dz_proj,
-            segment=ckpt_S,
-        ),
-    }
-    for a in arms.values():
-        a["peak_residual_bytes"] = a["carry_residual_bytes"] + a["dz_bytes"]
-    report["backward_arms"] = {
-        "T": seq_T,
-        "hidden_dim": H,
-        "proj_dtype": str(jnp.dtype(cfg.resolved_compute_dtype)),
-        "arms": arms,
-    }
-    # compiled peak for the timed arm, when this jax exposes it
-    try:
-        fn, args_fn = programs["loss_grad"]
-        mem = fn.lower(*args_fn()).compile().memory_analysis()
-        report["backward_arms"]["compiled_temp_bytes"] = int(
-            mem.temp_size_in_bytes
-        )
-    except Exception:
-        pass
 
     print(json.dumps(report))
 
@@ -3564,24 +3476,9 @@ if __name__ == "__main__":
         help="replay-scale mode: report JSON path ('' to skip the file)",
     )
     p.add_argument(
-        "--backward-arm", default="auto",
-        choices=["auto", "default", "fused_dwh", "ckpt"],
-        help="breakdown mode: which seq-backward arm the timed programs "
-             "run (fused_dwh / ckpt force lstm_backend=pallas; only "
-             "meaningful on TPU — on CPU pallas runs in interpret mode). "
-             "auto (the default) runs config.resolve_backward_arm's "
-             "budget-driven selection and stamps the pick into the row",
-    )
-    p.add_argument(
         "--hbm-gb", type=float, default=16.0,
         help="breakdown mode: per-device HBM budget for the largest-"
              "model-that-fits table (analytic; activations not modeled)",
-    )
-    p.add_argument(
-        "--ckpt-every", type=int, default=0,
-        help="breakdown mode: checkpoint segment length S for the ckpt "
-             "arm (0 = largest proper divisor of T); also sets the S the "
-             "analytic residual row reports",
     )
     p.add_argument(
         "--model-preset", default="",
@@ -3604,9 +3501,7 @@ if __name__ == "__main__":
         recovery_main(precision)
     elif args.mode == "breakdown":
         breakdown_main(args.core, args.lru_chunk, args.batch, precision,
-                       backward_arm=args.backward_arm,
-                       ckpt_every=args.ckpt_every, hbm_gb=args.hbm_gb,
-                       model_preset=args.model_preset)
+                       hbm_gb=args.hbm_gb, model_preset=args.model_preset)
     elif args.mode == "serve":
         if args.rate_search:
             serve_rate_search_main(

@@ -284,69 +284,6 @@ def fused_train_step_jaxpr(precision: str):
     return jax.make_jaxpr(step)(state, _stacked_batch_struct(precision, _NUM_STEPS))
 
 
-# ckpt segment length for the tiny_test trace: seq_len = 4+4+2 = 10, so 5
-# walks two real segments (the recompute loop AND the segment grid are
-# both exercised, not degenerate)
-_CKPT_S = 5
-
-
-def _backward_arm_cfg(precision: str, arm: str):
-    """tiny config with one alternative backward arm armed (ops/pallas_lstm):
-    'fused_dwh' accumulates dWh in kernel scratch, 'ckpt' checkpoints every
-    _CKPT_S-th carry and recomputes segments in the backward kernel."""
-    cfg = _cfg(precision).replace(lstm_backend="pallas")
-    if arm == "fused_dwh":
-        return cfg.replace(seq_fused_dwh=True)
-    if arm == "ckpt":
-        return cfg.replace(seq_grad_checkpoint=_CKPT_S)
-    raise ValueError(f"unknown backward arm {arm!r}")
-
-
-@functools.lru_cache(maxsize=None)
-def _backward_arm_net_and_state(precision: str, arm: str):
-    import jax
-
-    from r2d2_tpu.learner import init_train_state
-
-    cfg = _backward_arm_cfg(precision, arm)
-    net, state = init_train_state(cfg, jax.random.PRNGKey(0))
-    return net, state
-
-
-@functools.lru_cache(maxsize=None)
-def backward_arm_train_step_jaxpr(precision: str, arm: str):
-    """ClosedJaxpr of the stacked train step with a backward arm armed —
-    same trace as fused_train_step_jaxpr, different VJP program. Gated on
-    the SAME 3-launch budget: the fused-dWh arm replaces the outside
-    hᵀ@dz matmul with scratch accumulation (not an extra launch), and the
-    ckpt arm recomputes segments inside its one backward launch."""
-    import jax
-
-    from r2d2_tpu.learner import make_stacked_batch_train_step
-
-    cfg = _backward_arm_cfg(precision, arm)
-    net, state = _backward_arm_net_and_state(precision, arm)
-    step = make_stacked_batch_train_step(cfg, net, _NUM_STEPS, donate=False)
-    return jax.make_jaxpr(step)(state, _stacked_batch_struct(precision, _NUM_STEPS))
-
-
-def check_backward_arm_donation(precision: str, arm: str) -> List[Finding]:
-    """Donation contract per backward arm: the alternative VJPs change the
-    residual set, which must not break full TrainState consumption."""
-    import jax
-
-    from r2d2_tpu.learner import make_stacked_batch_train_step
-
-    label = f"backward_arm[{arm}][{precision}].donation"
-    cfg = _backward_arm_cfg(precision, arm)
-    net, state = _backward_arm_net_and_state(precision, arm)
-    step = make_stacked_batch_train_step(cfg, net, _NUM_STEPS, donate=True)
-    out_state, _, _ = jax.eval_shape(
-        step, state, _stacked_batch_struct(precision, _NUM_STEPS)
-    )
-    return compare_donated_leaves(state, out_state, label)
-
-
 _SUPERSTEP_N = 2  # dispatches: >1 so the outer scan over dispatch keys is real
 
 
@@ -934,31 +871,6 @@ def scan_fused_unroll(precision: str) -> List[Finding]:
     return out
 
 
-def scan_backward_arms(precision: str) -> List[Finding]:
-    """The alternative backward-arm entries (fused-dWh, ckpt): each arm's
-    train step holds the SAME 3-launch budget as the default pallas path
-    (no extra launches bought with the memory savings), stays off f64,
-    keeps the precision plane's dtype contract, and still donates the
-    whole TrainState."""
-    out: List[Finding] = []
-    for arm in ("fused_dwh", "ckpt"):
-        label = f"backward_arm[{arm}][{precision}]"
-        jaxpr = backward_arm_train_step_jaxpr(precision, arm)
-        text = str(jaxpr)
-        out += check_no_float64(text, label)
-        if precision == "fp32":
-            out += check_no_bf16(text, label)
-        else:
-            out += check_fp32_island(text, label)
-        out += check_kernel_launch_count(
-            jaxpr, label, 3,
-            "train step (online fwd + target fwd + one backward kernel — "
-            "the arm must not add launches)",
-        )
-        out += check_backward_arm_donation(precision, arm)
-    return out
-
-
 def scan_superstep(precision: str) -> List[Finding]:
     """The N×K priority superstep entry: the tree descent / IS-weight /
     write-back math must stay off f64 at either precision (the device
@@ -1212,7 +1124,7 @@ def scan_donation(precision: str) -> List[Finding]:
     return check_train_state_donation(precision) + check_store_field_dtypes(precision)
 
 
-# --------------------------------------- manual tp x fsdp / auto-arm entries
+# ------------------------------------------------- manual tp x fsdp entries
 
 
 @functools.lru_cache(maxsize=None)
@@ -1322,118 +1234,6 @@ def scan_manual_train_step(
     return out
 
 
-# Budget-discriminable trace shapes for backward_arm="auto": at tiny_test
-# geometry (T=10, B=8, H=32) every arm fits inside the 1 MB budget floor
-# and auto always resolves to "default".
-_AUTO_ARM_H = 512
-_AUTO_ARM_B = 32
-_AUTO_ARM_BUDGET_MB = {
-    # Integer-MB budgets that land choose_backward_arm on each arm at
-    # (T=10, B=32, H=512): bf16 thresholds are default 3.44 MB / fused
-    # 2.19 MB; fp32 default and fused coincide at 3.75 MB (dz_proj ==
-    # dz_f32 — fused buys nothing at fp32, auto skips it by design, so
-    # the fp32 fused cell pins the arm via backward_arm="fused_dwh").
-    ("fp32", "ckpt"): 3,
-    ("bf16", "fused_dwh"): 3,
-    ("bf16", "ckpt"): 2,
-}
-
-
-@functools.lru_cache(maxsize=None)
-def _auto_arm_cfg(precision: str, arm: str):
-    """tiny config whose `backward_arm` knob RESOLVES to the given arm —
-    the trace exercises the new selection path end-to-end
-    (config.resolve_backward_arm -> models/r2d2.from_config), not the
-    legacy seq_fused_dwh / seq_grad_checkpoint knobs the r14 traces pin."""
-    cfg = _cfg(precision).replace(
-        lstm_backend="pallas", hidden_dim=_AUTO_ARM_H, batch_size=_AUTO_ARM_B
-    )
-    mb = _AUTO_ARM_BUDGET_MB.get((precision, arm))
-    if mb is None:
-        return cfg.replace(backward_arm=arm)
-    return cfg.replace(backward_arm="auto", backward_residual_budget_mb=mb)
-
-
-@functools.lru_cache(maxsize=None)
-def _auto_arm_net_and_state(precision: str, arm: str):
-    import jax
-
-    from r2d2_tpu.learner import init_train_state
-
-    return init_train_state(_auto_arm_cfg(precision, arm), jax.random.PRNGKey(0))
-
-
-@functools.lru_cache(maxsize=None)
-def auto_backward_arm_train_step_jaxpr(precision: str, arm: str):
-    """ClosedJaxpr of the stacked train step with the backward arm chosen
-    by the budget knob rather than the legacy flags."""
-    import jax
-
-    from r2d2_tpu.learner import make_stacked_batch_train_step
-
-    cfg = _auto_arm_cfg(precision, arm)
-    net, state = _auto_arm_net_and_state(precision, arm)
-    step = make_stacked_batch_train_step(cfg, net, _NUM_STEPS, donate=False)
-    return jax.make_jaxpr(step)(state, _stacked_struct_from_cfg(cfg, _NUM_STEPS))
-
-
-def check_auto_arm_donation(precision: str, arm: str) -> List[Finding]:
-    import jax
-
-    from r2d2_tpu.learner import make_stacked_batch_train_step
-
-    label = f"auto_backward_arm[{arm}][{precision}].donation"
-    cfg = _auto_arm_cfg(precision, arm)
-    net, state = _auto_arm_net_and_state(precision, arm)
-    step = make_stacked_batch_train_step(cfg, net, _NUM_STEPS, donate=True)
-    out_state, _, _ = jax.eval_shape(
-        step, state, _stacked_struct_from_cfg(cfg, _NUM_STEPS)
-    )
-    return compare_donated_leaves(state, out_state, label)
-
-
-def scan_auto_backward_arms(precision: str) -> List[Finding]:
-    """The backward_arm selection path end-to-end: for each non-default
-    arm, a config whose budget (or explicit knob, for the fp32 fused cell
-    auto cannot reach) resolves to it, traced under the same contracts as
-    the legacy-knob arms — no f64, the precision plane's dtype contract,
-    the 3-launch budget, full TrainState donation. A selection drift (the
-    residual accounting moving so the pinned budget stops landing on the
-    arm) is itself a finding, not a silently weaker gate."""
-    out: List[Finding] = []
-    for arm in ("fused_dwh", "ckpt"):
-        label = f"auto_backward_arm[{arm}][{precision}]"
-        cfg = _auto_arm_cfg(precision, arm)
-        resolved, _stride = cfg.resolve_backward_arm()
-        if resolved != arm:
-            out.append(
-                _finding(
-                    "jaxpr-auto-arm-resolution", label,
-                    f"backward_arm={cfg.backward_arm!r} with budget="
-                    f"{cfg.backward_residual_budget_mb}MB resolved to "
-                    f"{resolved!r}, expected {arm!r} — the residual "
-                    "accounting moved under the gate's pinned budgets",
-                    hint="re-derive _AUTO_ARM_BUDGET_MB from "
-                    "ops/pallas_lstm.seq_backward_residual_bytes",
-                )
-            )
-            continue
-        jaxpr = auto_backward_arm_train_step_jaxpr(precision, arm)
-        text = str(jaxpr)
-        out += check_no_float64(text, label)
-        if precision == "fp32":
-            out += check_no_bf16(text, label)
-        else:
-            out += check_fp32_island(text, label)
-        out += check_kernel_launch_count(
-            jaxpr, label, 3,
-            "train step (online fwd + target fwd + one backward kernel — "
-            "arm selection must not add launches)",
-        )
-        out += check_auto_arm_donation(precision, arm)
-    return out
-
-
 def scan_entry_points(
     precisions: Sequence[str] = ("fp32", "bf16"),
 ) -> List[Finding]:
@@ -1448,8 +1248,6 @@ def scan_entry_points(
         out += scan_act(p)
         out += scan_act_select(p)
         out += scan_fused_unroll(p)
-        out += scan_backward_arms(p)
-        out += scan_auto_backward_arms(p)
         out += scan_manual_train_step(p)
         out += scan_superstep(p)
         out += scan_serve_step(p)

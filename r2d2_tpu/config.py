@@ -367,51 +367,6 @@ class R2D2Config:
     # raw frames (and vice versa).
     block_codec: str = "none"
 
-    # Backward-pass kernel arms for the fused sequence unroll
-    # (ops/pallas_lstm.py). Both default OFF: the default backward path is
-    # bit-identical to every earlier release.
-    #
-    # seq_fused_dwh: accumulate the (H, 4H) recurrent-weight gradient in a
-    # VMEM scratch inside the reversed-T backward kernel (each step already
-    # holds h_{t-1} and dz in VMEM) instead of the separate
-    # (T*B, H)^T @ (T*B, 4H) matmul outside it — and stream dz out directly
-    # in the compute dtype (it only feeds dproj once dWh is fused), so the
-    # full-size f32 dz array disappears from the backward.
-    seq_fused_dwh: bool = False
-    # seq_grad_checkpoint = S > 0: gradient-checkpointed backward. The VJP
-    # saves only every-S-step (h, c) carries as residuals — O((T/S)*B*H)
-    # HBM instead of O(T*B*H) — and the backward kernel recomputes each
-    # S-segment's gates from its checkpoint before walking it in reverse.
-    # Implies the fused dWh accumulation (the full h sequence is never in
-    # HBM for the outside matmul to read). Requires seq_len % S == 0.
-    # 0 = off. Pallas-backend knob; the scan backend has scan_chunk.
-    seq_grad_checkpoint: int = 0
-    # Backward-arm selector for the fused sequence kernel. The explicit
-    # knobs above (seq_fused_dwh / seq_grad_checkpoint) always win; when
-    # both are off this knob decides which backward the kernel runs:
-    #   "default"   — the bit-identical default backward.
-    #   "fused_dwh" — force the fused-dWh arm.
-    #   "ckpt"      — force the checkpointed arm; the stride S is the
-    #                 smallest divisor >= 2 of seq_len whose residual
-    #                 footprint fits the budget below (least recompute
-    #                 within budget), falling back to the largest divisor.
-    #   "auto"      — pick the first arm whose peak backward-residual
-    #                 bytes (ops/pallas_lstm.seq_backward_residual_bytes
-    #                 carries + the dz pre-activation-grad array) fit
-    #                 backward_residual_budget_mb: default, then
-    #                 fused_dwh, then ckpt. Resolved per-device (the
-    #                 batch slice after dp/fsdp sharding).
-    # These are Pallas sequence-kernel backwards: on the scan backend (or
-    # the lru core) every choice resolves to ("default", 0) — scan_chunk
-    # is that backend's rematerialization knob.
-    backward_arm: str = "auto"
-    # Per-device budget in MiB for the sequence backward's residuals,
-    # read by backward_arm="auto"/"ckpt". The default keeps every
-    # shipped preset on the default arm (default_atari peaks at ~61 MiB
-    # at batch 64), so auto only engages once model presets grow the
-    # residual footprint past one chip's comfort zone.
-    backward_residual_budget_mb: int = 128
-
     # --- parallelism ------------------------------------------------------
     # Data-parallel learner shards the batch over the "dp" mesh axis;
     # "tp" shards wide layers (impala encoder / LSTM kernels) when > 1.
@@ -620,8 +575,8 @@ class R2D2Config:
         """"pallas" | "scan" | "lru": the recurrent-core implementation
         this config runs on the process's jax backend. THE resolution of
         lstm_backend="auto" — models/r2d2.from_config builds the net from
-        it, resolve_backward_arm budgets from it, and the entry-point
-        banner (utils/runtime.py) prints it, so the choice is never made
+        it and the entry-point banner (utils/runtime.py) prints it, so the
+        choice is never made
         silently at trace time. auto = the fused Pallas kernel on a TPU,
         lax.scan elsewhere, and scan wherever the update body sits under
         a GSPMD-partitioned mesh axis: Mosaic refuses a pallas_call there
@@ -657,15 +612,14 @@ class R2D2Config:
 
         return jax.default_backend() == "tpu"
 
-    def _rows_per_device(self, batch_size: Optional[int] = None) -> int:
+    def _rows_per_device(self) -> int:
         """Rows of a training batch that one device holds: the batch shards
         over dp (and over fsdp too under manual partitioning's ZeRO-2 data
         layout)."""
-        B = self.batch_size if batch_size is None else batch_size
         shards = max(self.dp_size, 1)
         if self.resolved_partitioning == "manual":
             shards *= max(self.fsdp_size, 1)
-        return max(B // shards, 1)
+        return max(self.batch_size // shards, 1)
 
     @property
     def resolved_lru_recurrence(self) -> str:
@@ -684,47 +638,6 @@ class R2D2Config:
 
         fits = kernel_fits(self._rows_per_device(), self.hidden_dim)
         return "pallas" if fits and self._mosaic_call_allowed() else "scan"
-
-    def resolve_backward_arm(self, batch_size: Optional[int] = None):
-        """-> (arm, ckpt_stride): the backward arm the fused sequence
-        kernel actually runs, with arm in {"default", "fused_dwh",
-        "ckpt"} and ckpt_stride the checkpoint segment length (0 unless
-        arm == "ckpt").
-
-        Explicit legacy knobs (seq_grad_checkpoint / seq_fused_dwh) win
-        verbatim. Otherwise `backward_arm` decides; "auto" budgets the
-        per-device peak residual bytes via ops/pallas_lstm.
-        choose_backward_arm. Non-pallas backends (and the lru core)
-        always resolve to ("default", 0) — the arms are Pallas sequence-
-        kernel backwards. Deferred imports keep config import-light."""
-        if self.seq_grad_checkpoint > 0:
-            return ("ckpt", self.seq_grad_checkpoint)
-        if self.seq_fused_dwh:
-            return ("fused_dwh", 0)
-        if self.backward_arm == "default" or self.recurrent_core != "lstm":
-            return ("default", 0)
-        if self.resolved_core_backend != "pallas":
-            return ("default", 0)
-        import jax
-
-        from r2d2_tpu.ops.pallas_lstm import (
-            choose_backward_arm,
-            vmem_capacity_bytes,
-        )
-
-        return choose_backward_arm(
-            self.seq_len,
-            self._rows_per_device(batch_size),  # residuals live per device
-            self.hidden_dim,
-            self.resolved_compute_dtype,
-            self.backward_residual_budget_mb * (1 << 20),
-            mode=self.backward_arm,
-            # compiled kernels live under the device's VMEM; the
-            # interpreter (explicit pallas off-TPU) has none to respect
-            vmem_bytes=(
-                vmem_capacity_bytes() if jax.default_backend() == "tpu" else None
-            ),
-        )
 
     @property
     def seq_len(self) -> int:
@@ -1003,31 +916,6 @@ class R2D2Config:
                 "lstm_backend='scan' (or 'auto', which resolves to scan "
                 "there)"
             )
-        if self.seq_grad_checkpoint < 0:
-            raise ValueError("seq_grad_checkpoint must be >= 0 (0 = off)")
-        if self.seq_grad_checkpoint > 0:
-            if self.seq_len % self.seq_grad_checkpoint != 0:
-                raise ValueError(
-                    f"seq_grad_checkpoint={self.seq_grad_checkpoint} must "
-                    f"divide seq_len={self.seq_len} (burn_in + learning + "
-                    "forward): the checkpointed backward kernel walks whole "
-                    "S-step segments"
-                )
-            if self.seq_fused_dwh:
-                raise ValueError(
-                    "seq_fused_dwh and seq_grad_checkpoint are alternative "
-                    "backward arms; the checkpointed arm already fuses dWh "
-                    "(it never materializes the h sequence for the outside "
-                    "matmul) — set at most one"
-                )
-        if (self.seq_fused_dwh or self.seq_grad_checkpoint > 0) and (
-            self.recurrent_core != "lstm"
-        ):
-            raise ValueError(
-                "seq_fused_dwh / seq_grad_checkpoint tune the fused LSTM "
-                "sequence kernel's backward; they require "
-                "recurrent_core='lstm'"
-            )
         if self.fsdp_size < 1:
             raise ValueError("fsdp_size must be >= 1")
         if self.fsdp_size > 1 and self.replay_plane == "multihost":
@@ -1078,26 +966,6 @@ class R2D2Config:
                     f"(ZeRO-2 data layout); batch_size={self.batch_size} "
                     f"must divide by dp_size*fsdp_size={shards}"
                 )
-        if self.backward_arm not in ("auto", "default", "fused_dwh", "ckpt"):
-            raise ValueError(
-                f"unknown backward_arm {self.backward_arm!r}; 'auto' "
-                "budgets peak residual bytes, or force 'default'/"
-                "'fused_dwh'/'ckpt'"
-            )
-        if self.backward_residual_budget_mb < 1:
-            raise ValueError(
-                "backward_residual_budget_mb is the per-device residual "
-                "budget backward_arm='auto' selects against; it must be "
-                ">= 1"
-            )
-        if (
-            self.backward_arm in ("fused_dwh", "ckpt")
-            and self.recurrent_core != "lstm"
-        ):
-            raise ValueError(
-                "backward_arm forces a fused LSTM sequence-kernel "
-                "backward; it requires recurrent_core='lstm'"
-            )
         if self.encoder_depth < 0:
             raise ValueError("encoder_depth must be >= 0 (extra latent layers)")
         if self.model_preset not in MODEL_PRESETS:

@@ -48,13 +48,6 @@ class LSTM(nn.Module):
     # pallas_lstm.py) — recurrent weights + carry stay VMEM-resident for
     # the whole unroll. "auto": pallas on TPU, scan elsewhere.
     backend: str = "auto"
-    # Pallas-backend backward arms (config.seq_fused_dwh /
-    # seq_grad_checkpoint; ops/pallas_lstm.py). Both default OFF — the
-    # default backward path stays bit-identical. Applied only on the
-    # fused-sequence (burn_in) path; the scan backend ignores them
-    # (scan_chunk is its rematerialization knob).
-    fused_dwh: bool = False
-    grad_checkpoint: int = 0
     # Manual tensor parallelism (learner.make_manual_train_step's
     # shard_map): > 1 builds the SHARD-LOCAL module — wi/wh/b carry this
     # device's contiguous 4H/tp column slice, matching the sharding_map
@@ -76,20 +69,27 @@ class LSTM(nn.Module):
     @classmethod
     def from_config(cls, cfg, in_dim: int, tp_size: int = 1) -> "LSTM":
         # "auto" is resolved HERE, once, by the config's own rule — the
-        # module never picks a backend at trace time on this path; the
-        # backward arm likewise: explicit legacy knobs verbatim, else the
-        # backward_arm budget selector
-        arm, stride = cfg.resolve_backward_arm()
+        # module never picks a backend at trace time on this path
+        backend = cfg.resolved_core_backend
+        # precision="bf16" forces bfloat16 compute; fp32 precision
+        # defers to the legacy compute_dtype knob (config.py)
+        dtype = jnp.dtype(cfg.resolved_compute_dtype)
+        if backend == "pallas" and jax.default_backend() == "tpu":
+            # compiled kernels live under the device's VMEM (the
+            # interpreter has none to respect): a training shape the
+            # sequence kernel cannot hold is refused here, by shape
+            from r2d2_tpu.ops import pallas_lstm
+
+            pallas_lstm.require_seq_backward_fits(
+                cfg.seq_len, cfg._rows_per_device(), cfg.hidden_dim, dtype,
+                pallas_lstm.vmem_capacity_bytes(),
+            )
         return cls(
             cfg.hidden_dim,
             in_dim=in_dim,
-            # precision="bf16" forces bfloat16 compute; fp32 precision
-            # defers to the legacy compute_dtype knob (config.py)
-            dtype=jnp.dtype(cfg.resolved_compute_dtype),
+            dtype=dtype,
             scan_chunk=cfg.scan_chunk,
-            backend=cfg.resolved_core_backend,
-            fused_dwh=(arm == "fused_dwh"),
-            grad_checkpoint=(stride if arm == "ckpt" else 0),
+            backend=backend,
             tp_size=tp_size,
         )
 
@@ -166,23 +166,10 @@ class LSTM(nn.Module):
                 "(config.validate routes tp here via tp_shards_params)"
             )
         if use_pallas:
-            from r2d2_tpu.ops.pallas_lstm import (
-                lstm_seq_unroll,
-                lstm_seq_unroll_ckpt,
-                lstm_seq_unroll_fused_dwh,
-                lstm_unroll,
-            )
+            from r2d2_tpu.ops.pallas_lstm import lstm_seq_unroll, lstm_unroll
 
             if burn_in is None:
                 outs_t, (hT, cT) = lstm_unroll(proj_t, wh, h, c)
-            elif self.grad_checkpoint:
-                outs_t, (hT, cT) = lstm_seq_unroll_ckpt(self.grad_checkpoint)(
-                    proj_t, wh, h, c, burn_in.astype(jnp.int32)
-                )
-            elif self.fused_dwh:
-                outs_t, (hT, cT) = lstm_seq_unroll_fused_dwh(
-                    proj_t, wh, h, c, burn_in.astype(jnp.int32)
-                )
             else:
                 outs_t, (hT, cT) = lstm_seq_unroll(
                     proj_t, wh, h, c, burn_in.astype(jnp.int32)

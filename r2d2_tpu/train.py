@@ -726,12 +726,6 @@ class Trainer:
                                   devices=jax.devices()[:n_mesh],
                                   fsdp=cfg.fsdp_size)
 
-        # resolved sequence-backward arm (config-static): stamped into
-        # every metrics record so runs are attributable to the arm the
-        # auto-selector actually picked (bench.py stamps BENCH rows the
-        # same way)
-        self._backward_arm, self._backward_arm_stride = cfg.resolve_backward_arm()
-
         self.net, self.state = init_train_state(cfg, jax.random.PRNGKey(cfg.seed))
         if self.mesh is not None:
             if cfg.replay_plane != "multihost":
@@ -1225,9 +1219,6 @@ class Trainer:
         #    same way __init__ places it (values untouched -> bit-exact)
         cfg = self.cfg.replace(**topology).validate()
         self.cfg = cfg
-        self._backward_arm, self._backward_arm_stride = (
-            cfg.resolve_backward_arm()
-        )
         self.mesh = None
         if cfg.dp_size * cfg.tp_size * cfg.fsdp_size > 1:
             n_mesh = cfg.dp_size * cfg.tp_size * cfg.fsdp_size
@@ -1404,12 +1395,6 @@ class Trainer:
                 "q_mean": float(m["q_mean"]),
                 "episodes": n_ep,
                 "mean_return": (r_sum / n_ep) if n_ep else None,
-                "backward_arm": self._backward_arm,
-                **(
-                    {"backward_arm_stride": self._backward_arm_stride}
-                    if self._backward_arm == "ckpt"
-                    else {}
-                ),
                 **(extra or {}),
                 # first record only: what the run is on (utils/runtime.py)
                 **(stamp or {}),

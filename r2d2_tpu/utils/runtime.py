@@ -1,8 +1,8 @@
 """What a process actually runs on, said once at start-up.
 
 Every selection this codebase makes from the jax backend — Pallas kernel
-or lax.scan core, compiled or interpreted kernels, which sequence-backward
-arm — used to be made at trace time and recorded nowhere, and stock jax
+or lax.scan core, compiled or interpreted kernels — used to be made at
+trace time and recorded nowhere, and stock jax
 falls back to the CPU when the TPU does not initialise: a run could exit
 0 on the scan reference on a CPU and look like a slow TPU run. The train,
 serve and evaluate CLIs therefore print ONE line, in one format,
@@ -27,7 +27,6 @@ def describe_runtime(cfg: R2D2Config) -> dict:
 
     devices = jax.devices()
     core = cfg.resolved_core_backend
-    arm, stride = cfg.resolve_backward_arm()
     return {
         "platform": devices[0].platform,
         "device_kind": devices[0].device_kind,
@@ -39,8 +38,6 @@ def describe_runtime(cfg: R2D2Config) -> dict:
         # a Pallas core off-TPU runs under the interpreter (how the CPU
         # tests pin kernel parity) — never a device measurement
         "pallas_interpreted": core == "pallas" and jax.default_backend() != "tpu",
-        "backward_arm": arm,
-        **({"backward_arm_stride": stride} if arm == "ckpt" else {}),
     }
 
 

@@ -6,15 +6,13 @@
 #   1. shardmap gate — the manual-partition parity suites (tp2 x fsdp2 x
 #      dp2 step vs the unsharded reference at fp32 AND bf16; ZeRO-2
 #      moment shards + update equality vs replicated Adam; resume across
-#      a CHANGED tp x fsdp layout), the backward-arm auto-selection
-#      tests, and the static analysis CLI (the shard_mapped step and
-#      both auto arms are traced at fp32+bf16; raw shard_map imports
+#      a CHANGED tp x fsdp layout) and the static analysis CLI (the
+#      shard_mapped step is traced at fp32+bf16; raw shard_map imports
 #      outside parallel/jax_compat.py are an AST error). A parity
 #      regression aborts the chain: a wrong collective's speedup is
 #      noise.
-#   2. breakdown (auto arm) — per-phase step timing with the vs_r14
-#      column (per-phase deltas against BENCH_r14.json), the
-#      backward_arm/backward_arm_mode stamps, and the
+#   2. breakdown — per-phase step timing with the vs_r14 column
+#      (per-phase deltas against BENCH_r14.json) and the
 #      largest-model-that-fits table per mesh shape (model_fits).
 #   3. breakdown (grown presets) — the same timing at --model-preset
 #      wide/deep: the "grow the brain" rung. TPU-gated: on CPU the
@@ -28,9 +26,8 @@
 #      through the sharded restore template.
 #
 # PRE-REGISTERED read: rung 2's model_fits.largest_fit growing
-# monotonically with tp x fsdp (more shards -> bigger largest model),
-# the auto backward_arm stamp matching resolve_backward_arm at the
-# benched shapes, and rung 4's resume crossing the layout change with
+# monotonically with tp x fsdp (more shards -> bigger largest model)
+# and rung 4's resume crossing the layout change with
 # training continuing from the saved step — the BENCH_r16 headline.
 cd /root/repo
 
@@ -39,7 +36,7 @@ cd /root/repo
 OUT=runs/bench_shardmap_r16.jsonl
 : > "$OUT"
 
-echo "=== RUNG 1: shardmap + auto-arm gate ==="
+echo "=== RUNG 1: shardmap gate ==="
 XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 python -m pytest tests/test_sharding_map.py tests/test_pallas_lstm.py \
   tests/test_analysis.py -q -p no:cacheprovider
@@ -54,9 +51,9 @@ if [ $RC -ne 0 ] || [ $RCA -ne 0 ]; then
   exit 1
 fi
 
-echo "=== RUNG 2: breakdown, auto arm (vs_r14 + model_fits) ==="
+echo "=== RUNG 2: breakdown (vs_r14 + model_fits) ==="
 python bench.py --mode breakdown --batch 8 | tee -a "$OUT"
-echo "=== BREAKDOWN_AUTO EXIT: $? ==="
+echo "=== BREAKDOWN EXIT: $? ==="
 
 if python -c 'import jax, sys; sys.exit(0 if jax.default_backend() == "tpu" else 1)'; then
   echo "=== RUNG 3: breakdown, grown model presets ==="

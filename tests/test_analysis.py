@@ -670,22 +670,6 @@ def test_multitask_train_step_gate_both_precisions():
     assert "scan" in text and "i32[" in text
 
 
-def test_backward_arm_gate_both_precisions():
-    """The alternative backward arms (ISSUE 14: fused-dWh scratch
-    accumulation, S-step gradient checkpointing) trace clean at fp32 AND
-    bf16 under every jaxpr checker, hold the default path's exact
-    3-launch budget (the memory savings must not buy extra launches), and
-    still donate the full TrainState despite the changed residual set."""
-    from r2d2_tpu.analysis import jaxpr_rules
-
-    for precision in ("fp32", "bf16"):
-        findings = jaxpr_rules.scan_backward_arms(precision)
-        assert findings == [], render_text(findings)
-    for arm in ("fused_dwh", "ckpt"):
-        jaxpr = jaxpr_rules.backward_arm_train_step_jaxpr("fp32", arm)
-        assert jaxpr_rules.count_pallas_launches(jaxpr) == 3
-
-
 def test_manual_train_step_gate_both_precisions():
     """The explicitly-partitioned tp x fsdp train step (ISSUE 16:
     learner.make_manual_train_step on the dp2 x tp2 x fsdp2 mesh) traces
@@ -704,24 +688,6 @@ def test_manual_train_step_gate_both_precisions():
     assert "all_gather" in text  # tp gate seam + ZeRO-2 update re-gather
     assert "psum" in text  # data-axis (and replicated-leaf tp) reductions
     assert "reduce_scatter" in text  # ZeRO-2 grads onto moment shards
-
-
-def test_auto_backward_arm_gate_both_precisions():
-    """The backward_arm budget-selection path (ISSUE 16: backward_arm=
-    "auto" + backward_residual_budget_mb, resolved by config.
-    resolve_backward_arm into models/r2d2.from_config): each reachable
-    non-default cell traces clean at both precisions under the same
-    contracts as the legacy-knob arms, including the 3-launch budget."""
-    from r2d2_tpu.analysis import jaxpr_rules
-
-    for precision in ("fp32", "bf16"):
-        findings = jaxpr_rules.scan_auto_backward_arms(precision)
-        assert findings == [], render_text(findings)
-    # the gate's pinned budgets genuinely land on the arms they claim
-    arm, stride = jaxpr_rules._auto_arm_cfg("bf16", "fused_dwh").resolve_backward_arm()
-    assert (arm, stride) == ("fused_dwh", 0)
-    arm, stride = jaxpr_rules._auto_arm_cfg("fp32", "ckpt").resolve_backward_arm()
-    assert arm == "ckpt" and stride >= 2
 
 
 def test_raw_shard_map_import_fires_and_shim_exempt():

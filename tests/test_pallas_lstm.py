@@ -11,13 +11,7 @@ import pytest
 
 from r2d2_tpu.config import tiny_test
 from r2d2_tpu.models.lstm import LSTM
-from r2d2_tpu.ops.pallas_lstm import (
-    lstm_seq_unroll,
-    lstm_seq_unroll_ckpt,
-    lstm_seq_unroll_fused_dwh,
-    lstm_unroll,
-    seq_backward_residual_bytes,
-)
+from r2d2_tpu.ops.pallas_lstm import lstm_seq_unroll, lstm_unroll
 
 pytestmark = pytest.mark.kernels
 
@@ -315,7 +309,9 @@ class TestFusedSequence:
 
 
 # --------------------------------------------------------------------------
-# alternative backward arms (ISSUE 14): fused-dWh and checkpointed kernels
+# the sequence op's parity ladder at training shapes (PR 37: the kernel has
+# ONE backward; these cases stand where the fused-dWh and checkpointed arms'
+# cases stood)
 # --------------------------------------------------------------------------
 
 
@@ -326,230 +322,48 @@ def _seam_loss(fn, proj_t, wh, h0, c0, burn):
     )
 
 
-class TestFusedDwhArm:
-    """lstm_seq_unroll_fused_dwh: dWh accumulated in VMEM scratch inside
-    the reversed backward kernel — no outside (T·B,H)ᵀ@(T·B,4H) matmul,
-    no full-size f32 dz in HBM. Forward and dproj are the SAME program as
-    the default arm, so those are bitwise; dWh differs only in summation
-    order (per-step scratch += vs one big matmul)."""
-
-    def test_forward_bit_identical_to_default_arm(self):
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(20))
-        burn = jnp.asarray(_BURN)
-        outs_a, (hT_a, cT_a) = lstm_seq_unroll(proj_t, wh, h0, c0, burn)
-        outs_b, (hT_b, cT_b) = lstm_seq_unroll_fused_dwh(proj_t, wh, h0, c0, burn)
-        assert np.array_equal(np.asarray(outs_a), np.asarray(outs_b))
-        assert np.array_equal(np.asarray(hT_a), np.asarray(hT_b))
-        assert np.array_equal(np.asarray(cT_a), np.asarray(cT_b))
-
-    def test_grads_match_default_arm_fp32(self):
-        """dproj is bitwise (identical dz program); dWh within a few ulp
-        (summation order only); dh0/dc0 exact zeros on both arms."""
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(21))
-        burn = jnp.asarray(_BURN)
-        g_d = jax.grad(
-            lambda *a: _seam_loss(lstm_seq_unroll, *a, burn), argnums=(0, 1, 2, 3)
-        )(proj_t, wh, h0, c0)
-        g_f = jax.grad(
-            lambda *a: _seam_loss(lstm_seq_unroll_fused_dwh, *a, burn),
-            argnums=(0, 1, 2, 3),
-        )(proj_t, wh, h0, c0)
-        assert np.array_equal(np.asarray(g_d[0]), np.asarray(g_f[0]))  # dproj
-        np.testing.assert_allclose(
-            np.asarray(g_d[1]), np.asarray(g_f[1]), rtol=1e-5, atol=1e-6
-        )
-        assert not np.asarray(g_f[2]).any() and not np.asarray(g_f[3]).any()
-
-    def test_exact_zero_below_seam(self):
-        """The seam contract carries over verbatim: dproj rows strictly
-        below each row's burn are EXACT zeros (the masked dz contributes
-        exact zeros to the scratch dWh too)."""
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(22))
-        burn = jnp.asarray(_BURN)
-        dproj = jax.grad(
-            lambda *a: _seam_loss(lstm_seq_unroll_fused_dwh, *a, burn)
-        )(proj_t, wh, h0, c0)
-        dproj = np.asarray(dproj)
-        for b, bi in enumerate(_BURN):
-            assert not dproj[:bi, b, :].any(), f"row {b}: leak below seam {bi}"
-            if bi < dproj.shape[0]:
-                assert dproj[bi:, b, :].any()
-
-    def test_grads_match_seam_scan_reference(self):
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(23))
-        burn = jnp.asarray(_BURN)
-        for wrt in (0, 1):
-            g_k = jax.grad(
-                lambda *a: _seam_loss(lstm_seq_unroll_fused_dwh, *a, burn),
-                argnums=wrt,
-            )(proj_t, wh, h0, c0)
-            g_s = jax.grad(
-                lambda *a: _seam_loss(_seam_scan_reference, *a, burn), argnums=wrt
-            )(proj_t, wh, h0, c0)
-            np.testing.assert_allclose(
-                np.asarray(g_k), np.asarray(g_s), rtol=1e-4, atol=1e-5
-            )
+_LADDER_SHAPES = [(10, 8, 128), (85, 16, 512), (45, 64, 128), (83, 32, 256)]
+_LADDER_SEAMS = {"0": lambda T: 0, "1": lambda T: 1, "half": lambda T: T // 2,
+                 "last": lambda T: T - 1}
 
 
-class TestCheckpointedArm:
-    """lstm_seq_unroll_ckpt(S): residuals are every-S-step (h, c) carries
-    only — O((T/S)·B·H) instead of O(T·B·H) — and the backward kernel
-    recomputes each segment's gates from its checkpoint before walking it
-    in reverse. dWh is inherently fused (the full h sequence never exists
-    in HBM)."""
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("seam", list(_LADDER_SEAMS))
+@pytest.mark.parametrize("shape", _LADDER_SHAPES, ids=lambda s: "x".join(map(str, s)))
+def test_seq_unroll_parity_ladder(shape, seam, dtype):
+    """lstm_seq_unroll at (T, B, H) with every row's seam at 0, 1, T/2 or
+    T-1: forward bit-identical to lstm_unroll; gradients against the scan
+    LSTM with the same seam (f32: tight on each tensor's own scale; bf16:
+    bounded relative L2, the precision plane's class); exact zeros below
+    the seam and into the initial state."""
+    T, B, H = shape
+    burn_at = _LADDER_SEAMS[seam](T)
+    args = tuple(a.astype(dtype) for a in _rand_inputs(
+        np.random.default_rng(37 + T + B + H + burn_at), T=T, B=B, H=H))
+    burn = jnp.full((B,), burn_at, jnp.int32)
 
-    def test_forward_bit_identical_to_default_arm(self):
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(30))
-        burn = jnp.asarray(_BURN)
-        outs_a, (hT_a, cT_a) = lstm_seq_unroll(proj_t, wh, h0, c0, burn)
-        outs_b, (hT_b, cT_b) = lstm_seq_unroll_ckpt(2)(proj_t, wh, h0, c0, burn)
-        assert np.array_equal(np.asarray(outs_a), np.asarray(outs_b))
-        assert np.array_equal(np.asarray(hT_a), np.asarray(hT_b))
-        assert np.array_equal(np.asarray(cT_a), np.asarray(cT_b))
+    outs_a, (hT_a, cT_a) = lstm_unroll(*args)
+    outs_b, (hT_b, cT_b) = lstm_seq_unroll(*args, burn)
+    assert np.array_equal(np.asarray(outs_a, np.float32), np.asarray(outs_b, np.float32))
+    assert np.array_equal(np.asarray(hT_a), np.asarray(hT_b))
+    assert np.array_equal(np.asarray(cT_a), np.asarray(cT_b))
 
-    @pytest.mark.parametrize("S", [1, 2, 3, 6])
-    def test_grads_match_default_arm_fp32(self, S):
-        """Every divisor segment length, including the degenerate S=1
-        (checkpoint every step — pure recompute overhead, same math) and
-        S=T (one segment — the whole unroll recomputed from h0/c0). The
-        recompute replays identical f32 ops, but XLA fuses the two
-        programs differently, so parity is one-ulp-tight, not bitwise."""
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(31))
-        burn = jnp.asarray(_BURN)
-        g_d = jax.grad(
-            lambda *a: _seam_loss(lstm_seq_unroll, *a, burn), argnums=(0, 1, 2, 3)
-        )(proj_t, wh, h0, c0)
-        g_c = jax.grad(
-            lambda *a: _seam_loss(lstm_seq_unroll_ckpt(S), *a, burn),
-            argnums=(0, 1, 2, 3),
-        )(proj_t, wh, h0, c0)
-        np.testing.assert_allclose(
-            np.asarray(g_d[0]), np.asarray(g_c[0]), rtol=1e-5, atol=1e-6
-        )
-        np.testing.assert_allclose(
-            np.asarray(g_d[1]), np.asarray(g_c[1]), rtol=1e-5, atol=1e-6
-        )
-        assert not np.asarray(g_c[2]).any() and not np.asarray(g_c[3]).any()
-
-    @pytest.mark.parametrize(
-        "burn_vec",
-        [
-            # seams ON segment boundaries (S=2 over T=6: boundaries 0/2/4)
-            np.array([0, 2, 4, 2, 4, 0, 2, 4], np.int32),
-            # seams strictly INSIDE recomputed segments
-            np.array([1, 3, 5, 1, 3, 5, 1, 3], np.int32),
-            # mixed, plus the all-learn and nearly-all-burn extremes
-            np.array([0, 5, 1, 4, 2, 3, 0, 5], np.int32),
-        ],
-    )
-    def test_seam_exact_zero_at_and_inside_segment_boundaries(self, burn_vec):
-        """The hard case the segment recompute must not soften: a seam
-        landing exactly on an S-boundary (the carry cut coincides with a
-        checkpoint reload) or mid-segment (the cut applies inside the
-        recomputed walk). Below-seam dproj must be EXACT zeros either
-        way."""
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(32))
-        burn = jnp.asarray(burn_vec)
-        dproj, dwh, dh0, dc0 = jax.grad(
-            lambda *a: _seam_loss(lstm_seq_unroll_ckpt(2), *a, burn),
-            argnums=(0, 1, 2, 3),
-        )(proj_t, wh, h0, c0)
-        dproj = np.asarray(dproj)
-        for b, bi in enumerate(burn_vec):
-            assert not dproj[:bi, b, :].any(), f"row {b}: leak below seam {bi}"
-            if bi < dproj.shape[0]:
-                assert dproj[bi:, b, :].any(), f"row {b}: train segment empty"
-        assert not np.asarray(dh0).any() and not np.asarray(dc0).any()
-        assert np.asarray(dwh).any()
-
-    def test_grads_match_seam_scan_reference(self):
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(33))
-        burn = jnp.asarray(_BURN)
-        for wrt in (0, 1):
-            g_k = jax.grad(
-                lambda *a: _seam_loss(lstm_seq_unroll_ckpt(3), *a, burn),
-                argnums=wrt,
-            )(proj_t, wh, h0, c0)
-            g_s = jax.grad(
-                lambda *a: _seam_loss(_seam_scan_reference, *a, burn), argnums=wrt
-            )(proj_t, wh, h0, c0)
-            np.testing.assert_allclose(
-                np.asarray(g_k), np.asarray(g_s), rtol=1e-4, atol=1e-5
-            )
-
-    def test_rejects_non_divisor_segment(self):
-        proj_t, wh, h0, c0 = _rand_inputs(np.random.default_rng(34))
-        burn = jnp.asarray(_BURN)
-        with pytest.raises(ValueError, match="not divisible"):
-            jax.grad(
-                lambda *a: _seam_loss(lstm_seq_unroll_ckpt(4), *a, burn)
-            )(proj_t, wh, h0, c0)
-
-    def test_residual_bytes_scale_with_segment_length(self):
-        """The measurable claim behind the arm: carry residuals shrink by
-        exactly T/S (h at proj dtype + c at f32, per the vjp_fwd's
-        concatenated checkpoint tensors)."""
-        T, B, H = 80, 32, 512
-        full = seq_backward_residual_bytes(T, B, H, jnp.bfloat16)
-        ck = seq_backward_residual_bytes(T, B, H, jnp.bfloat16, ckpt_every=5)
-        assert full["carry_residual_bytes"] == T * B * H * (2 + 4)
-        assert ck["carry_residual_bytes"] == (T // 5) * B * H * (2 + 4)
-        assert full["carry_residual_bytes"] == 5 * ck["carry_residual_bytes"]
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("arm", ["fused_dwh", "ckpt"])
-def test_backward_arm_module_parity(arm, dtype):
-    """Full LSTM module with an arm enabled vs the default pallas path:
-    identical params, seam active, both precisions. fp32 is one-ulp
-    tight; bf16 recompute parity holds by construction (bf16 h round-trip
-    is identity, c checkpoints are f32-exact), so bf16 is ALSO tight
-    against the default arm — the drift-vs-scan class does not widen."""
-    B, T, D, H = 8, 6, 24, tiny_test().hidden_dim
-    kw = dict(hidden_dim=H, in_dim=D, dtype=dtype, backend="pallas")
-    default_mod = LSTM(**kw)
-    arm_mod = LSTM(**kw, fused_dwh=True) if arm == "fused_dwh" else LSTM(
-        **kw, grad_checkpoint=3
-    )
-    rng = np.random.default_rng(40)
-    xs = jnp.asarray(rng.normal(size=(B, T, D)).astype(np.float32))
-    carry = (
-        jnp.asarray(rng.normal(size=(B, H)).astype(np.float32) * 0.2),
-        jnp.asarray(rng.normal(size=(B, H)).astype(np.float32) * 0.2),
-    )
-    burn = jnp.asarray(np.minimum(_BURN, T - 1))
-    params = default_mod.init(jax.random.PRNGKey(3), xs, carry)
-
-    outs_d, _ = default_mod.apply(params, xs, carry, burn_in=burn)
-    outs_a, _ = arm_mod.apply(params, xs, carry, burn_in=burn)
-    assert np.array_equal(np.asarray(outs_d), np.asarray(outs_a))  # fwd bitwise
-
-    def loss(mod, p):
-        outs, _ = mod.apply(p, xs, carry, burn_in=burn)
-        return jnp.sum(jnp.tanh(outs.astype(jnp.float32)))
-
-    g_d = jax.tree.leaves(jax.grad(lambda p: loss(default_mod, p))(params))
-    g_a = jax.tree.leaves(jax.grad(lambda p: loss(arm_mod, p))(params))
-    for a, b in zip(g_a, g_d):
-        np.testing.assert_allclose(
-            np.asarray(a, np.float32), np.asarray(b, np.float32),
-            rtol=2e-5, atol=2e-6,
-        )
-
-
-def test_backward_arm_launch_budget():
-    """Each armed train step holds the default path's exact 3-launch
-    budget — the fused dWh and the segment recompute live INSIDE the one
-    backward launch, they do not buy extra launches."""
-    from r2d2_tpu.analysis.jaxpr_rules import (
-        backward_arm_train_step_jaxpr,
-        count_pallas_launches,
-        scan_backward_arms,
-    )
-
-    assert scan_backward_arms("fp32") == []
-    for arm in ("fused_dwh", "ckpt"):
-        assert count_pallas_launches(backward_arm_train_step_jaxpr("fp32", arm)) == 3
+    g_k = jax.grad(lambda *a: _seam_loss(lstm_seq_unroll, *a, burn), argnums=(0, 1, 2, 3))(*args)
+    g_s = jax.jit(jax.grad(
+        lambda *a: _seam_loss(_seam_scan_reference, *a, burn), argnums=(0, 1)))(*args)
+    for k, s in zip(g_k[:2], g_s):
+        k, s = np.asarray(k, np.float32), np.asarray(s, np.float32)
+        assert np.isfinite(k).all()
+        if dtype == jnp.float32:
+            # sums over up to T*B terms: measured on the tensor's own scale
+            assert np.max(np.abs(k - s)) <= 1e-4 * (np.max(np.abs(s)) + 1e-6)
+        else:
+            assert np.linalg.norm(k - s) / (np.linalg.norm(s) + 1e-6) < 0.05
+    dproj = np.asarray(g_k[0], np.float32)
+    assert not dproj[:burn_at].any(), "gradient below the seam"
+    assert dproj[burn_at:].any(), "the train segment got no gradient"
+    assert not np.asarray(g_k[2], np.float32).any() and not np.asarray(g_k[3], np.float32).any()
+    assert np.asarray(g_k[1], np.float32).any()
 
 
 class TestScanChunkRemainder:
@@ -597,110 +411,3 @@ class TestScanChunkRemainder:
         np.testing.assert_allclose(np.asarray(outs_a), np.asarray(outs_b), atol=1e-6)
         np.testing.assert_allclose(np.asarray(h_a), np.asarray(h_b), atol=1e-6)
         np.testing.assert_allclose(np.asarray(c_a), np.asarray(c_b), atol=1e-6)
-
-
-class TestChooseBackwardArm:
-    """choose_backward_arm (ops/pallas_lstm.py) + config.resolve_backward_arm:
-    the auto-selector that picks the sequence backward from the peak-
-    residual-bytes budget (ISSUE 16 satellite). Pure shape math — no
-    kernel runs."""
-
-    T, B, H = 84, 8, 512
-
-    def _peaks(self, dtype):
-        d = seq_backward_residual_bytes(self.T, self.B, self.H, dtype)
-        dz_f32 = self.T * self.B * 4 * self.H * 4
-        dz_proj = self.T * self.B * 4 * self.H * jnp.dtype(dtype).itemsize
-        return d["carry_residual_bytes"], dz_f32, dz_proj
-
-    def test_auto_prefers_default_when_budget_fits(self):
-        from r2d2_tpu.ops.pallas_lstm import choose_backward_arm
-
-        carry, dz_f32, _ = self._peaks(jnp.bfloat16)
-        arm, stride = choose_backward_arm(
-            self.T, self.B, self.H, jnp.bfloat16, carry + dz_f32
-        )
-        assert (arm, stride) == ("default", 0)
-
-    def test_auto_steps_down_to_fused_dwh_then_ckpt(self):
-        from r2d2_tpu.ops.pallas_lstm import choose_backward_arm
-
-        carry, dz_f32, dz_proj = self._peaks(jnp.bfloat16)
-        # budget excludes the f32 dz residual but fits the bf16 one
-        arm, stride = choose_backward_arm(
-            self.T, self.B, self.H, jnp.bfloat16, carry + dz_f32 - 1
-        )
-        assert (arm, stride) == ("fused_dwh", 0)
-        # budget below even the fused arm: checkpointing, with the
-        # SMALLEST divisor stride of T=84 whose peak fits
-        arm, stride = choose_backward_arm(
-            self.T, self.B, self.H, jnp.bfloat16, carry + dz_proj - 1
-        )
-        assert arm == "ckpt"
-        assert stride >= 2 and self.T % stride == 0
-        ck = seq_backward_residual_bytes(self.T, self.B, self.H, jnp.bfloat16, stride)
-        assert ck["carry_residual_bytes"] + dz_proj <= carry + dz_proj - 1
-
-    def test_explicit_modes_pass_through(self):
-        from r2d2_tpu.ops.pallas_lstm import choose_backward_arm
-
-        assert choose_backward_arm(10, 4, 16, jnp.float32, 1, "default") == ("default", 0)
-        assert choose_backward_arm(10, 4, 16, jnp.float32, 1, "fused_dwh") == ("fused_dwh", 0)
-        arm, stride = choose_backward_arm(10, 4, 16, jnp.float32, 1, "ckpt")
-        assert arm == "ckpt" and 10 % stride == 0
-        with pytest.raises(ValueError, match="backward-arm"):
-            choose_backward_arm(10, 4, 16, jnp.float32, 1, "nope")
-
-    def test_auto_never_offers_a_stride_that_cannot_fit_vmem(self):
-        """The residual budget alone walks B=256 fp32 to one whole-sequence
-        segment — (85, 256, 2048) f32 blocks, refused by the compiler on a
-        chip. Given the device's VMEM the smallest fitting stride is the
-        answer, and a shape nothing fits raises here, by name."""
-        from r2d2_tpu.ops.pallas_lstm import choose_backward_arm
-
-        shape = (85, 256, 512, jnp.float32, 128 << 20)
-        assert choose_backward_arm(*shape) == ("ckpt", 85)
-        assert choose_backward_arm(*shape, vmem_bytes=128 << 20) == ("ckpt", 5)
-        with pytest.raises(ValueError, match="VMEM"):
-            choose_backward_arm(*shape, vmem_bytes=16 << 20)
-
-    def test_config_resolution_legacy_knobs_win(self):
-        cfg = tiny_test().replace(lstm_backend="pallas", seq_fused_dwh=True)
-        assert cfg.resolve_backward_arm() == ("fused_dwh", 0)
-        cfg = tiny_test().replace(lstm_backend="pallas", seq_grad_checkpoint=5)
-        assert cfg.resolve_backward_arm() == ("ckpt", 5)
-
-    def test_config_resolution_non_pallas_is_default(self):
-        # scan backend (and the CPU test backend's auto resolution) has no
-        # Pallas sequence backward to pick between
-        assert tiny_test().replace(lstm_backend="scan").resolve_backward_arm() == ("default", 0)
-        assert tiny_test().resolve_backward_arm() == ("default", 0)
-        lru = tiny_test().replace(recurrent_core="lru", lstm_backend="auto")
-        assert lru.resolve_backward_arm() == ("default", 0)
-
-    def test_config_resolution_budget_divides_by_data_shards(self):
-        """The per-device residual budget sees B/(dp*fsdp) under manual
-        partitioning — a model that needs ckpt on one chip can ride the
-        default arm once the batch shards."""
-        carry, dz_f32, _ = self._peaks(jnp.bfloat16)
-        budget_mb = -(-(carry + dz_f32) // (1 << 20))  # ceil to MB: fits 1 shard
-        base = dict(
-            lstm_backend="pallas",
-            precision="bf16",
-            hidden_dim=self.H,
-            batch_size=8 * self.B,
-            burn_in_steps=40,
-            learning_steps=40,
-            block_length=40,
-            forward_steps=4,  # seq_len = 84
-            backward_residual_budget_mb=int(budget_mb),
-        )
-        crowded = tiny_test().replace(**base)
-        arm_1chip, _ = crowded.resolve_backward_arm()
-        assert arm_1chip != "default"  # 8x the batch per device
-        sharded = tiny_test().replace(
-            **base, dp_size=4, fsdp_size=2, replay_plane="host",
-            partitioning="manual",
-        )
-        assert sharded.resolved_partitioning == "manual"
-        assert sharded.resolve_backward_arm() == ("default", 0)

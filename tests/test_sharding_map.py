@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from r2d2_tpu.config import tiny_test
+from r2d2_tpu.config import parse_overrides, tiny_test
 from r2d2_tpu.learner import init_train_state, make_train_step
 from r2d2_tpu.parallel import (
     DEFAULT_RULES,
@@ -408,15 +408,15 @@ class TestConfigKnobs:
         cfg.validate()
         assert cfg.resolved_partitioning == "manual"
 
-    def test_backward_arm_knobs_validation(self):
-        cfg = tiny_test().replace(lstm_backend="pallas")
-        # divisor constraint: tiny_test seq_len = 4+4+2 = 10
-        cfg.replace(seq_grad_checkpoint=5)  # ok
-        with pytest.raises(ValueError, match="divide"):
-            cfg.replace(seq_grad_checkpoint=4)
-        with pytest.raises(ValueError, match="at most one"):
-            cfg.replace(seq_grad_checkpoint=5, seq_fused_dwh=True)
-        with pytest.raises(ValueError, match="recurrent_core"):
-            tiny_test().replace(
-                recurrent_core="lru", lstm_backend="auto", seq_fused_dwh=True
-            )
+    @pytest.mark.parametrize("name, value", [
+        ("seq_fused_dwh", True), ("seq_grad_checkpoint", 5),
+        ("backward_arm", "default"), ("backward_residual_budget_mb", 128),
+    ])
+    def test_removed_backward_arm_knobs_are_unknown_fields(self, name, value):
+        """PR 37: the LSTM sequence kernel has one backward and no option
+        selects it; a configuration that still names one of the four
+        removed fields fails like any unknown field (MIGRATION.md)."""
+        with pytest.raises(TypeError, match=name):  # a configuration file
+            tiny_test().replace(**{name: value})
+        with pytest.raises(ValueError, match=f"unknown config field '{name}'"):
+            parse_overrides([f"{name}={value}"])  # a command line's --set

@@ -81,11 +81,9 @@ def _lstm_kernel_pattern():
 
 
 ARMS = {
-    # arm -> (op, the wrappers whose kernels its forward + backward launch)
+    # op -> (op, the wrappers whose kernels its forward + backward launch)
     "plain": (lambda: pk.lstm_unroll, ["_lstm_fwd_call", "_lstm_bwd_call"]),
     "seq_default": (lambda: pk.lstm_seq_unroll, ["_lstm_fwd_call", "_lstm_seq_bwd_call"]),
-    "seq_fused_dwh": (lambda: pk.lstm_seq_unroll_fused_dwh, ["_lstm_fwd_call", "_lstm_seq_bwd_fused_call"]),
-    "seq_ckpt5": (lambda: pk.lstm_seq_unroll_ckpt(5), ["_lstm_fwd_call", "_lstm_seq_bwd_ckpt_call"]),
 }
 
 
@@ -109,8 +107,72 @@ def test_every_kernel_instruction_matches_the_benchmarks_lstm_pattern(arm, rows,
     names = [re.sub(r"^ROOT ", "", l).split(" = ")[0] for l in calls]
     pattern = _lstm_kernel_pattern()
     assert len(calls) == 2 and all(pattern.search(re.sub(r"^ROOT ", "", l)) for l in calls), names
-    # named after the wrapper, so a pattern can tell the arms apart
+    # named after the wrapper, so a pattern can tell the calls apart
     assert sorted(re.sub(r"^%|\.\d+$", "", n) for n in names) == sorted(wrappers)
+
+
+def _lstm_cfg(T_, B, H_):
+    """The `atari` preset at another training shape, on the Pallas backend:
+    T = 85 is its own window (burn-in 40 + learning 40 + n-step 5), T = 581
+    bench.py::long_context_main's (64 + 512 + 5, blocks of 1,024)."""
+    from r2d2_tpu.config import default_atari
+
+    burn, learning, block = {85: (40, 40, 400), 581: (64, 512, 1024)}[T_]
+    return default_atari().replace(
+        lstm_backend="pallas", batch_size=B, hidden_dim=H_, burn_in_steps=burn,
+        learning_steps=learning, forward_steps=5, block_length=block,
+        buffer_capacity=256 * block)
+
+
+@pytest.fixture()
+def as_on_the_chip(monkeypatch, compiled_kernels):
+    """What LSTM.from_config observes on a v5e: a TPU backend (the VMEM size is
+    compiled_kernels')."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+# the two shapes at which backward_arm="auto" left the one backward that is
+# kept (PR 37): for its 128 MB dial on HBM residuals, never for VMEM
+@pytest.mark.parametrize("shape", [(581, 32, 512), (85, 256, 512)], ids=lambda s: "x".join(map(str, s)))
+def test_the_sequence_backward_compiles_within_vmem_where_auto_used_to_leave_it(shape, one_chip, as_on_the_chip):
+    from r2d2_tpu.models.lstm import LSTM
+
+    T_, B, H_ = shape
+    cfg = _lstm_cfg(T_, B, H_)
+    assert (cfg.seq_len, cfg._rows_per_device(), cfg.hidden_dim) == shape
+    LSTM.from_config(cfg, in_dim=H_ + cfg.action_dim + 1)  # the core resolves: not refused
+    sds = lambda shp, dt: jax.ShapeDtypeStruct(shp, dt, sharding=one_chip)
+    args = [sds((T_, B, 4 * H_), jnp.bfloat16), sds((H_, 4 * H_), jnp.bfloat16),
+            sds((B, H_), jnp.float32), sds((B, H_), jnp.float32), sds((B,), jnp.int32)]
+
+    def loss(proj, wh, h0, c0, burn):
+        outs, (hT, cT) = pk.lstm_seq_unroll(proj, wh, h0, c0, burn)
+        return outs.astype(jnp.float32).sum() + hT.sum() + cT.sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(*args).compile()
+    # it compiled under the limit the call itself passed (`vmem_limit_bytes` =
+    # this estimate: Mosaic refuses a kernel that needs more than it was given)
+    need = pk.kernel_vmem_bytes(
+        *pk._seq_bwd_vmem_spec(B, H_, jnp.float32, *[jnp.bfloat16] * 3), B, H_, jnp.bfloat16)
+    assert need <= 128 << 20 and need >> 20 == {(581, 32, 512): 15, (85, 256, 512): 46}[shape]
+    calls = [l for l in compiled.as_text().splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+    assert len(calls) == 2
+    # what the one backward keeps in HBM here: the float32 dz (PERF.md finding 37)
+    dz = T_ * B * 4 * H_ * 4
+    assert dz == {(581, 32, 512): 152_305_664, (85, 256, 512): 178_257_920}[shape]
+    assert compiled.memory_analysis().temp_size_in_bytes >= dz
+
+
+def test_a_shape_over_vmem_is_refused_where_the_core_is_resolved_with_its_shape(as_on_the_chip):
+    """H = 2,048 at B = 64 fits no backward this kernel ever had (~196 MiB of
+    128): the configuration is refused when the core is built from it, by
+    shape, and not at the first trace or inside Mosaic."""
+    from r2d2_tpu.models.lstm import LSTM
+
+    cfg = _lstm_cfg(85, 64, 2048)
+    with pytest.raises(ValueError, match=r"T=85, B=64, H=2048 \(bfloat16\).*128 MiB"):
+        LSTM.from_config(cfg, in_dim=2048 + cfg.action_dim + 1)
+    LSTM.from_config(_lstm_cfg(85, 64, 1024), in_dim=1024 + cfg.action_dim + 1)  # 58 MiB: fits
 
 
 @pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581"])
