@@ -9,12 +9,14 @@ conv encoder + LSTM-512 + dueling heads, obs 84x84x1, B=64, T=85, bf16):
   kernels   every pallas_call of ops/pallas_lstm.py at T=85, B=64, H=512,
             fp32 and bf16, against the lax.scan LSTM (models/lstm.py)
   store_bytes  the replay store's obs bytes where no loss can see them: a
-            row store (replay/block.frames_to_rows) filled with a pattern of
-            (block, row, offset), gathered through learner.make_store_gather
-            under plain jit and, on four chips, inside the sharded plane's
-            shard_map and through the GSPMD-partitioned gather of
-            run_with_stores; bit for bit against numpy, before, in the same
-            program as, and after an in-place slab write the gather reads
+            row store filled through replay/block.frames_to_rows (the
+            config's block order) with frames of a pattern of (block, row,
+            offset), gathered through learner.make_store_gather as canonical
+            frames and as stored (the step programs' form), under plain jit
+            and, on four chips, inside the sharded plane's shard_map and
+            through the GSPMD-partitioned gather of run_with_stores; bit for
+            bit against numpy, before, in the same program as, and after an
+            in-place slab write the gather reads
   train     python -m r2d2_tpu.train, fused megastep: on-device collection,
             HBM replay ring, K=16 scanned updates, fused sequence kernel
             forward + backward, deferred priorities, orbax save
@@ -207,8 +209,10 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
     holds, or a gather that returns, the wrong bytes. Here the obs store of a
     real replay plane is filled on the device with a byte that encodes
     (generation, global block, slot row, offset in the frame) and gathered
-    `batches` times through learner.make_store_gather, each frame compared
-    with numpy's evaluation of the same pattern:
+    `batches` times through learner.make_store_gather, as canonical frames
+    and as stored (the step programs' form: PR 38 keeps a frame's bytes in
+    the encoder's block order), each frame compared with numpy's evaluation
+    of the same pattern:
 
       jit        DeviceReplayBuffer, the gather plainly jitted on one chip
       shard_map  ShardedDeviceReplay on four chips: per-shard LOCAL indices
@@ -228,10 +232,10 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
     from r2d2_tpu.config import PRESETS, parse_overrides
     from r2d2_tpu.learner import make_store_gather
     from r2d2_tpu.megastep import _slab_write
-    from r2d2_tpu.replay.block import LANES, obs_rows, store_field_specs
+    from r2d2_tpu.replay.block import frames_to_rows, store_field_specs
 
     base = PRESETS[preset]().replace(**parse_overrides(sets))
-    n_bytes, R = math.prod(base.obs_shape), obs_rows(base.obs_shape)
+    n_bytes, frame_block = math.prod(base.obs_shape), base.resolved_frame_block
     dev = jax.devices()[0]
     print("STORE_BYTES_ON " + json.dumps({
         "platform": dev.platform, "device_kind": dev.device_kind,
@@ -243,39 +247,61 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
         return (gen * 101 + block * 131 + row * 31 + offset * 7 + (offset >> 7) * 3) & 0xFF
 
     def fill(cfg, blocks: int, block0, gen: int):
-        """(blocks, slot, R, 128) uint8 rows of the pattern, zero tail; the
+        """(blocks, slot, R, 128) uint8 rows: frames of the pattern, written
+        as every writer of a device store writes them, through
+        frames_to_rows with the config's block (one block of frames at a
+        time: the whole store as frames beside its rows would not fit); the
         other fields as a block whose windows all lie inside it."""
         S, L = cfg.seqs_per_block, cfg.learning_steps
-        shape = (blocks, cfg.block_slot_len, R, LANES)
-        i = [jax.lax.broadcasted_iota(jnp.int32, shape, d) for d in range(4)]
-        off = i[2] * LANES + i[3]
+        shape = (cfg.block_slot_len, n_bytes)
+        row, off = (jax.lax.broadcasted_iota(jnp.int32, shape, d) for d in range(2))
+
+        def rows_of(block):
+            frames = pattern(gen, block + block0, row, off).astype(jnp.uint8)
+            return frames_to_rows(
+                frames.reshape(cfg.block_slot_len, *cfg.obs_shape), cfg.obs_shape, frame_block)
+
         out = {k: jnp.zeros((blocks, *sh), dt) for k, (sh, dt) in store_field_specs(cfg).items()}
-        out["obs"] = jnp.where(off < n_bytes, pattern(gen, i[0] + block0, i[1], off), 0).astype(jnp.uint8)
+        out["obs"] = jax.lax.map(rows_of, jnp.arange(blocks, dtype=jnp.int32))
         out["burn_in"] = jnp.broadcast_to(
             jnp.minimum(jnp.arange(S, dtype=jnp.int32) * L, cfg.burn_in_steps), (blocks, S))
         out["learning"] = jnp.full((blocks, S), L, jnp.int32)
         out["forward"] = jnp.full((blocks, S), cfg.forward_steps, jnp.int32)
         return out
 
-    def expected(cfg, gen_of_block, b, s):
+    def expected(cfg, gen_of_block, b, s, as_stored: bool):
         """numpy: the frames make_store_gather's contract promises for
-        GLOBAL blocks b and sequences s of the filled store."""
+        GLOBAL blocks b and sequences s of the filled store: canonical, or
+        in the store's block order (the permutation stated here on its own,
+        not through the program's functions)."""
         L, T, slot = cfg.learning_steps, cfg.seq_len, cfg.block_slot_len
         win = s * L - np.minimum(s * L, cfg.burn_in_steps)
         rows = np.clip(win[:, None] + np.arange(T)[None, :], 0, slot - 1)
         off = np.arange(n_bytes, dtype=np.int32)
         want = pattern(gen_of_block[b][:, None, None], b[:, None, None], rows[:, :, None], off[None, None, :])
-        return want.astype(np.uint8).reshape(len(b), T, *cfg.obs_shape)
+        want = want.astype(np.uint8).reshape(len(b), T, *cfg.obs_shape)
+        if as_stored and frame_block > 1:
+            (H, W, C), k = cfg.obs_shape, frame_block
+            want = want.reshape(len(b), T, H // k, k, W // k, k, C).transpose(0, 1, 2, 4, 3, 5, 6)
+            want = want.reshape(len(b), T, H // k, W // k, k * k * C)
+        return want
 
     failed = 0
 
-    def verdict(plane: str, moment: str, cfg, gen_of_block, slab, b, s, got) -> None:
-        """`slab`: the global blocks the slab write lands on, whenever it does."""
+    def verdict(plane: str, moment: str, cfg, gen_of_block, slab, b, s, both) -> None:
+        """`slab`: the global blocks the slab write lands on, whenever it
+        does; `both`: the canonical gather's obs and the as-stored one's."""
+        for got, as_stored in zip(both, (False, True)):
+            _verdict(plane, moment, cfg, gen_of_block, slab, b, s, got, as_stored)
+
+    def _verdict(plane, moment, cfg, gen_of_block, slab, b, s, got, as_stored) -> None:
         nonlocal failed
-        b, s, got = np.asarray(b).reshape(-1), np.asarray(s).reshape(-1), np.asarray(got)
+        b, s, got = np.asarray(b).reshape(-1), np.asarray(s).reshape(-1), np.asarray(jax.device_get(got))
         got = got.reshape(len(b), *got.shape[-(1 + len(cfg.obs_shape)):])
-        bad = int((got != expected(cfg, gen_of_block, b, s)).sum())
-        row = {"plane": plane, "moment": moment, "frames": int(got.shape[0] * got.shape[1]),
+        want = expected(cfg, gen_of_block, b, s, as_stored)
+        bad = int((got != want).sum()) if got.shape == want.shape else int(want.size)
+        row = {"plane": plane, "moment": moment, "order": "stored" if as_stored else "canonical",
+               "frame_block": frame_block, "frames": int(got.shape[0] * got.shape[1]),
                "read_from_slab_slots": int(np.isin(b, slab).sum()), "mismatched_bytes": bad,
                "verdict": "ok" if bad == 0 and got.dtype == np.uint8 else "mismatch"}
         failed += row["verdict"] != "ok"
@@ -303,15 +329,22 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
     with replay.lock:
         replay.stores = jax.jit(lambda: fill(cfg, nb, 0, 0))()
     gens, slab = np.zeros(nb, np.int64), np.arange(1, 1 + E)
-    gather = jax.jit(make_store_gather(cfg))
     ones = jnp.ones(B, jnp.float32)
 
+    def both_orders(cfg):
+        """(stores, b, s, w) -> (canonical obs, obs as stored): the gather
+        as everyone calls it and as the step programs do."""
+        canonical, stored = make_store_gather(cfg), make_store_gather(cfg, as_stored=True)
+        return lambda *a: (canonical(*a).obs, stored(*a).obs)
+
+    gather = jax.jit(both_orders(cfg))
+
     def read(b, s):
-        return replay.run_with_stores(lambda st: gather(st, jnp.asarray(b), jnp.asarray(s), ones)).obs
+        return replay.run_with_stores(lambda st: gather(st, jnp.asarray(b), jnp.asarray(s), ones))
 
     def step(stores, chunk, start, b, s, w):  # the step programs' order
-        batch = make_store_gather(cfg)(stores, b, s, w)
-        return _slab_write(stores, chunk, start), batch.obs
+        obs = both_orders(cfg)(stores, b, s, w)
+        return _slab_write(stores, chunk, start), obs
 
     step_jit = jax.jit(step, donate_argnums=(0,))
     for _ in range(batches):
@@ -340,7 +373,7 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
         mesh = make_mesh(dp=dp, tp=1, devices=jax.devices()[:dp])
         replay = ShardedDeviceReplay(cfg, mesh)
         shard = jax.sharding.NamedSharding(mesh, P("dp"))
-        gather = make_store_gather(cfg)
+        gather = both_orders(cfg)
 
         def local(fn):
             """fn over each shard's LOCAL view, as make_sharded_megastep maps its body."""
@@ -358,11 +391,10 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
         w_local = jnp.ones((dp, B // dp), jnp.float32)
 
         def body(stores, b, s, w):
-            return gather(stores, b[0], s[0], w[0]).obs[None]
+            return tuple(obs[None] for obs in gather(stores, b[0], s[0], w[0]))
 
         def body_step(stores, chunk, starts, b, s, w):
-            obs = gather(stores, b[0], s[0], w[0]).obs[None]
-            return _slab_write(stores, chunk, starts[0]), obs
+            return _slab_write(stores, chunk, starts[0]), body(stores, b, s, w)
 
         read_local = jax.jit(local(body))
         step_local = jax.jit(local(body_step), donate_argnums=(0,))
@@ -377,11 +409,11 @@ def _store_bytes_child(preset: str, sets: List[str], batches: int = 3) -> int:
                 # _sample_batch's way: local draws made global, the first n of them
                 gb, gs = (b + offsets).reshape(-1)[:8], s.reshape(-1)[:8]
                 got = replay.run_with_stores(
-                    lambda st: gspmd(st, jnp.asarray(gb), jnp.asarray(gs), jnp.ones(8, jnp.float32))).obs
-                verdict("gspmd", moment, cfg, gens, slab, gb, gs, jax.device_get(got))
+                    lambda st: gspmd(st, jnp.asarray(gb), jnp.asarray(gs), jnp.ones(8, jnp.float32)))
+                verdict("gspmd", moment, cfg, gens, slab, gb, gs, got)
                 gb, gs = draw(cfg, nb, (B,))  # and any global block, a whole batch
-                got = replay.run_with_stores(lambda st: gspmd(st, jnp.asarray(gb), jnp.asarray(gs), ones)).obs
-                verdict("gspmd", moment, cfg, gens, slab, gb, gs, jax.device_get(got))
+                got = replay.run_with_stores(lambda st: gspmd(st, jnp.asarray(gb), jnp.asarray(gs), ones))
+                verdict("gspmd", moment, cfg, gens, slab, gb, gs, got)
 
         reads("before")
         b, s = draw(cfg, per, (dp, B // dp))

@@ -61,6 +61,7 @@ import numpy as np
 
 from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.models.core import Carry, pack_state, zero_carry
+from r2d2_tpu.models.encoders import block_frames, blocked_shape
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.epsilon import epsilon_ladder
 from r2d2_tpu.ops.priority import mixed_td_priorities
@@ -171,6 +172,10 @@ def make_collect_core(
     vreset = jax.vmap(fn_env.reset)
     vstep = jax.vmap(fn_env.step)
     vrender = jax.vmap(fn_env.render)
+    # a frame in the device stores' byte order (replay/block.py) and its shape
+    frame_block = cfg.resolved_frame_block
+    stored_shape = blocked_shape(cfg.obs_shape, frame_block)
+    stored_order = lambda frames: block_frames(frames, cfg.obs_shape, frame_block)
 
     t1 = jnp.arange(T + 1)
     tT = jnp.arange(T)
@@ -293,7 +298,9 @@ def make_collect_core(
 
         def body(carry, key_t):
             env_state, core, la, lr, active = carry
-            obs = vrender(env_state)
+            # each step's frames are put in the store's block order ONCE:
+            # acting's first conv and the store's rows read that one tensor
+            obs = stored_order(vrender(env_state))
             ke, ka = jax.random.split(key_t)
             explore = jax.random.uniform(ke, (E,)) < epsilons
             rand_a = jax.random.randint(ka, (E,), 0, A)
@@ -324,7 +331,7 @@ def make_collect_core(
                 # and may put T on the lanes: every env step then rewrites
                 # the whole buffer a byte per tile (3 ms a step at T=1024,
                 # E=64; PERF.md finding 25.3)
-                "obs": frames_to_rows(obs, cfg.obs_shape),
+                "obs": frames_to_rows(obs, stored_shape),
                 "action": act,
                 "reward": reward,
                 "q": q.astype(jnp.float32),
@@ -340,7 +347,7 @@ def make_collect_core(
         init = (env_state, core0, la0, lr0, jnp.ones(E, bool))
         (env_f, core_f, la_f, lr_f, alive_f), rec = jax.lax.scan(body, init, keys[:T])
 
-        final_obs = vrender(env_f)
+        final_obs = stored_order(vrender(env_f))
         q_final, _ = net.apply(
             params, final_obs, la_f, lr_f, core_f, task=task_vec, method=net.act
         )
@@ -352,7 +359,7 @@ def make_collect_core(
         env_major = lambda x: jnp.swapaxes(x, 0, 1)  # (T, E, ...) -> (E, T, ...)
         fields, priorities, num_seq = jax.vmap(_pack)(
             env_major(rec["obs"]),
-            frames_to_rows(final_obs, cfg.obs_shape),
+            frames_to_rows(final_obs, stored_shape),
             env_major(rec["action"]),
             env_major(rec["reward"]),
             env_major(rec["q"]),

@@ -640,6 +640,18 @@ class R2D2Config:
         return "pallas" if fits and self._mosaic_call_allowed() else "scan"
 
     @property
+    def resolved_frame_block(self) -> int:
+        """The block `s` in which the encoder's first conv reads a frame
+        (models/encoders.frame_block: the Nature trunk's stride 4 where it
+        divides the frame's height and width, else 1). The device stores
+        keep each frame's bytes in that order (replay/block.frames_to_rows)
+        and the step programs hand the encoder frames as stored; 1 is
+        frames as they are, everywhere."""
+        from r2d2_tpu.models.encoders import frame_block  # deferred, as above
+
+        return frame_block(self.encoder, self.obs_shape)
+
+    @property
     def seq_len(self) -> int:
         """burn_in + learning + forward = 85 at defaults (config.py:30)."""
         return self.burn_in_steps + self.learning_steps + self.forward_steps

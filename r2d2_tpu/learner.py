@@ -41,7 +41,7 @@ from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.priority import mixed_td_priorities
 from r2d2_tpu.ops.value_rescale import inverse_value_rescale, value_rescale
-from r2d2_tpu.replay.block import rows_to_frames
+from r2d2_tpu.replay.block import rows_as_stored, rows_to_frames
 from r2d2_tpu.replay.replay_buffer import SampledBatch
 from r2d2_tpu.utils.profiling import scoped
 
@@ -258,13 +258,20 @@ def make_train_step(cfg: R2D2Config, net: R2D2Network, donate: bool = True):
     return jax.jit(raw, donate_argnums=(0,) if donate else ())
 
 
-def make_store_gather(cfg: R2D2Config):
+def make_store_gather(cfg: R2D2Config, as_stored: bool = False):
     """(stores, b, s, is_weights) -> DeviceBatch: in-jit clamped-window
     gather straight out of the HBM-resident stores. b is a block index
     LOCAL to whatever store shard the caller passes (the whole store under
-    plain jit; one dp shard under shard_map)."""
+    plain jit; one dp shard under shard_map).
+
+    `obs` is canonical (B, T, *obs_shape) frames whatever order the store
+    keeps a frame's bytes in (replay/block.py). as_stored=True hands the
+    frames back in the store's own order, (B, T, *blocked_shape): a reshape
+    of the gathered rows and nothing else. The step programs ask for that;
+    the encoder takes either (models/encoders.BlockedConv)."""
     L, T = cfg.learning_steps, cfg.seq_len
     slot, bl = cfg.block_slot_len, cfg.block_length
+    to_frames = rows_as_stored if as_stored else rows_to_frames
 
     def gather_batch(stores, b, s, is_weights) -> DeviceBatch:
         burn = stores["burn_in"][b, s]
@@ -287,7 +294,10 @@ def make_store_gather(cfg: R2D2Config):
         obs = stores["obs"]
         flat = obs.reshape(obs.shape[0] * slot, *obs.shape[2:])
         return DeviceBatch(
-            obs=rows_to_frames(jnp.take(flat, bcol * slot + rows, axis=0), cfg.obs_shape),
+            obs=to_frames(
+                jnp.take(flat, bcol * slot + rows, axis=0),
+                cfg.obs_shape, cfg.resolved_frame_block,
+            ),
             last_action=stores["last_action"][bcol, rows],
             last_reward=stores["last_reward"][bcol, rows],
             hidden=stores["hidden"][b, s],
@@ -318,7 +328,7 @@ def make_fused_train_step(cfg: R2D2Config, net: R2D2Network, donate: bool = True
     to make_train_step on the equivalent host-assembled batch (pinned by
     test)."""
     raw = _raw_train_step(cfg, net)
-    gather_batch = make_store_gather(cfg)
+    gather_batch = make_store_gather(cfg, as_stored=True)
 
     def fused(state: TrainState, stores, b, s, is_weights):
         batch = gather_batch(stores, b, s, is_weights)
@@ -378,7 +388,7 @@ def make_multi_update_core(
     if is_from_priorities and axis_name is None:
         raise ValueError("is_from_priorities needs an axis_name (pmin)")
     raw = _raw_train_step(cfg, net, axis_name=axis_name)
-    gather_batch = scoped(make_store_gather(cfg), "r2d2_gather")
+    gather_batch = scoped(make_store_gather(cfg, as_stored=True), "r2d2_gather")
 
     def multi(state: TrainState, stores, b, s, w):
         if b.shape[0] != num_steps:
@@ -571,7 +581,7 @@ def make_sharded_fused_train_step(
     from r2d2_tpu.parallel.mesh import dp_manual_axes
 
     raw = _raw_train_step(cfg, net, axis_name="dp")
-    gather_batch = make_store_gather(cfg)
+    gather_batch = make_store_gather(cfg, as_stored=True)
 
     def body(state: TrainState, stores, b, s, is_weights):
         # local views: stores = this device's (nb/dp, ...) block shard;

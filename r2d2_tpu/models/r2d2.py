@@ -96,6 +96,10 @@ class R2D2Network(nn.Module):
     # math. Only meaningful inside a shard_map manual over "tp"; 1 keeps
     # the historical global modules bit-exactly.
     tp_size: int = 1
+    # a canonical frame's shape (config.obs_shape): with it the encoder
+    # also takes frames in the device stores' block order
+    # (models/encoders.frame_block), told apart by their trailing shape
+    obs_shape: Tuple[int, ...] = ()
 
     @classmethod
     def from_config(cls, cfg: R2D2Config, manual_tp: int = 1) -> "R2D2Network":
@@ -117,6 +121,7 @@ class R2D2Network(nn.Module):
             task_action_dims=tuple(cfg.task_action_dims),
             encoder_depth=cfg.encoder_depth,
             tp_size=manual_tp,
+            obs_shape=tuple(cfg.obs_shape),
         )
 
     def setup(self):
@@ -124,7 +129,7 @@ class R2D2Network(nn.Module):
         tp = self.tp_size
         self.enc = make_encoder(
             self.encoder, self.hidden_dim, dtype, self.impala_channels,
-            depth=self.encoder_depth, tp_size=tp,
+            depth=self.encoder_depth, tp_size=tp, obs_shape=self.obs_shape,
         )
         if tp > 1:
             # Megatron column/row pair per head: the hidden's column

@@ -20,6 +20,13 @@ runtime lays a buffer out with whichever dimension pads least on the 128
 lanes; with raw (84, 84, 1) frames that is the block index, one frame is
 scattered a byte at a time over the whole store, and every step program
 re-laid the whole store out before it could gather (PERF.md finding 1).
+Within its rows a frame's bytes stand in the order the encoder's first conv
+reads them: blocks of `cfg.resolved_frame_block` (models/encoders.frame_block;
+4 for the Nature trunk on 84x84, where a frame is 21 x 21 blocks of 16 bytes
+and the conv a 2x2/1 conv over 16 channels; 1 = the frame as it is). The step
+programs reshape gathered rows to the conv's input and nothing else.
+`frames_to_rows` / `rows_to_frames` own that order: every writer and reader of
+a device store goes through them with the config's block.
 Blocks, the host ReplayBuffer, the disk tier and snapshot files keep frames.
 """
 
@@ -30,6 +37,7 @@ import dataclasses
 import numpy as np
 
 from r2d2_tpu.models.core import state_spec
+from r2d2_tpu.models.encoders import block_frames, blocked_shape, unblock_frames
 
 
 @dataclasses.dataclass
@@ -76,16 +84,16 @@ def obs_rows(obs_shape) -> int:
     return -(-int(np.prod(obs_shape)) // LANES)
 
 
-def frames_to_rows(frames, obs_shape):
-    """(..., *obs_shape) -> (..., R, 128): flatten each frame, zero-pad its
-    tail to R * 128 bytes. numpy in, numpy out; anything else goes through
-    jax.numpy (traceable)."""
+def frames_to_rows(frames, obs_shape, block: int = 1):
+    """(..., *obs_shape) -> (..., R, 128): put each frame in `block` order
+    (module docstring), flatten it, zero-pad its tail to R * 128 bytes. numpy
+    in, numpy out; anything else goes through jax.numpy (traceable)."""
     obs_shape = tuple(obs_shape)
     lead = frames.shape[: frames.ndim - len(obs_shape)]
     if frames.shape[len(lead):] != obs_shape:
         raise ValueError(f"frames {frames.shape} do not end in obs_shape {obs_shape}")
     n, R = int(np.prod(obs_shape)), obs_rows(obs_shape)
-    flat = frames.reshape(*lead, n)
+    flat = block_frames(frames, obs_shape, block).reshape(*lead, n)
     if R * LANES != n:
         if isinstance(frames, np.ndarray):
             pad = np.pad
@@ -97,14 +105,20 @@ def frames_to_rows(frames, obs_shape):
     return flat.reshape(*lead, R, LANES)
 
 
-def rows_to_frames(rows, obs_shape):
-    """(..., R, 128) -> (..., *obs_shape): the inverse of frames_to_rows."""
+def rows_as_stored(rows, obs_shape, block: int = 1):
+    """(..., R, 128) -> (..., *blocked_shape): the frames in the order the
+    rows keep them, by a slice and a reshape alone."""
     obs_shape = tuple(obs_shape)
     n, R = int(np.prod(obs_shape)), obs_rows(obs_shape)
     if rows.shape[-2:] != (R, LANES):
         raise ValueError(f"rows {rows.shape} do not end in {(R, LANES)}")
     lead = rows.shape[:-2]
-    return rows.reshape(*lead, R * LANES)[..., :n].reshape(*lead, *obs_shape)
+    return rows.reshape(*lead, R * LANES)[..., :n].reshape(*lead, *blocked_shape(obs_shape, block))
+
+
+def rows_to_frames(rows, obs_shape, block: int = 1):
+    """(..., R, 128) -> (..., *obs_shape): the inverse of frames_to_rows."""
+    return unblock_frames(rows_as_stored(rows, obs_shape, block), tuple(obs_shape), block)
 
 
 def store_field_specs(cfg):

@@ -29,13 +29,15 @@ the dispatch, serializing against add_block's swap. Never cache
 Store layout: every field is (num_blocks, *store_field_specs(cfg)[field]).
 Obs is NOT (slot, *obs_shape): each frame is kept as lane-aligned rows,
 (slot, R, 128) with R = ceil(frame bytes / 128) and a zero tail
-(replay/block.frames_to_rows; an 84x84x1 frame is 56 rows, 1.6 % padding).
+(replay/block.frames_to_rows; an 84x84x1 frame is 56 rows, 1.6 % padding),
+its bytes in the encoder's block order (cfg.resolved_frame_block).
 The TPU runtime then lays the store out row-major, a frame is R contiguous
 tiles, and the in-jit gather and the donated slab write work on the store in
 place. With raw frames the runtime put the BLOCK index on the lanes and both
 step programs re-laid the whole store out on every dispatch (PERF.md
 finding 1, repaired in PR 25). pad_block_fields and the collector (from its scan
-body on) write rows, learner.make_store_gather hands frames back; blocks, the host buffer, the
+body on) write rows, learner.make_store_gather hands frames back (canonical,
+or as stored to the step programs); blocks, the host buffer, the
 disk tier and snapshot files keep frames.
 
 Capacity note: obs dominates HBM use at 7,168 bytes per stored step for
@@ -131,7 +133,9 @@ class DeviceReplayBuffer(ReplayControlPlane):
             return out
 
         out = {
-            "obs": frames_to_rows(pad(block.obs, slot, np.uint8), cfg.obs_shape),
+            "obs": frames_to_rows(
+                pad(block.obs, slot, np.uint8), cfg.obs_shape, cfg.resolved_frame_block
+            ),
             "last_action": pad(block.last_action.astype(np.int32), slot, np.int32),
             "last_reward": pad(block.last_reward, slot, np.float32),
             "action": pad(block.action.astype(np.int32), bl, np.int32),

@@ -370,20 +370,20 @@ def _apply_plane(plane: ReplayControlPlane, d: Dict) -> None:
 
 
 def _cast_stores(
-    stores: Dict[str, np.ndarray], targets: Dict[str, Tuple], obs_shape=None
+    stores: Dict[str, np.ndarray], targets: Dict[str, Tuple], rows_cfg=None
 ) -> Dict[str, np.ndarray]:
     """Validate shapes against the destination and cast dtypes across the
     host/device family boundary (uint8 <-> int32 action fields; lossless,
-    actions < 256). `obs_shape` says the destination keeps frames as rows
-    (the device planes; snapshot files hold frames on every plane): obs is
-    checked against the frame shape and handed back as rows. Raises BEFORE
-    the caller mutates anything."""
+    actions < 256). `rows_cfg` says the destination keeps frames as rows, in
+    that config's block order (the device planes; snapshot files hold frames
+    on every plane): obs is checked against the frame shape and handed back
+    as rows. Raises BEFORE the caller mutates anything."""
     out = {}
     for k in STORE_FIELDS:
         shape, dtype = targets[k]
-        rows = k == "obs" and obs_shape is not None
+        rows = k == "obs" and rows_cfg is not None
         if rows:
-            shape = (*shape[:-2], *obs_shape)
+            shape = (*shape[:-2], *rows_cfg.obs_shape)
         v = stores[k]
         if tuple(v.shape) != tuple(shape):
             raise ValueError(
@@ -391,7 +391,9 @@ def _cast_stores(
                 "(incompatible config, not just topology)"
             )
         v = v if v.dtype == dtype else v.astype(dtype)
-        out[k] = frames_to_rows(v, obs_shape) if rows else v
+        if rows:
+            v = frames_to_rows(v, rows_cfg.obs_shape, rows_cfg.resolved_frame_block)
+        out[k] = v
     return out
 
 
@@ -462,7 +464,7 @@ def _scatter(replay, plane_kind: str, per_dest: Dict[int, Dict], meta: Dict) -> 
         }
         with replay.lock:
             cast = {
-                g: _cast_stores(per_dest[g]["stores"], targets, replay.cfg.obs_shape)
+                g: _cast_stores(per_dest[g]["stores"], targets, replay.cfg)
                 for g in replay.local_ids
             }
             for g in replay.local_ids:
@@ -488,7 +490,7 @@ def _scatter(replay, plane_kind: str, per_dest: Dict[int, Dict], meta: Dict) -> 
         }
         with replay.lock:
             cast = {
-                i: _cast_stores(per_dest[i]["stores"], targets, replay.cfg.obs_shape)
+                i: _cast_stores(per_dest[i]["stores"], targets, replay.cfg)
                 for i in range(replay.dp)
             }
             flat = {
@@ -509,7 +511,7 @@ def _scatter(replay, plane_kind: str, per_dest: Dict[int, Dict], meta: Dict) -> 
             for k in STORE_FIELDS
         }
         with replay.lock:
-            cast = _cast_stores(per_dest[0]["stores"], targets, replay.cfg.obs_shape)
+            cast = _cast_stores(per_dest[0]["stores"], targets, replay.cfg)
             _apply_plane(replay, per_dest[0])
             replay.stores = {k: jax.device_put(v) for k, v in cast.items()}
     else:  # host / tiered

@@ -246,26 +246,29 @@ def _check_kind(kind: str, want: str, replay, saved_topo: Optional[Dict]) -> Non
 
 
 def _validated_stores(
-    d, current: Dict[str, np.ndarray], prefix: str = "store_", obs_shape=None
+    d, current: Dict[str, np.ndarray], prefix: str = "store_", rows_cfg=None
 ) -> Dict[str, np.ndarray]:
     """Load every store field from the npz ONCE (NpzFile re-parses per
     access, and obs dominate the file), checking shape/dtype against the
     live buffer BEFORE the caller mutates anything — a mismatched snapshot
-    must leave the buffer untouched. `obs_shape` says the live store keeps
-    frames as rows (the device planes): the file's frames are checked
-    against the frame shape and handed back as rows."""
+    must leave the buffer untouched. `rows_cfg` says the live store keeps
+    frames as rows in that config's block order (the device planes): the
+    file's frames are checked against the frame shape and handed back as
+    rows."""
     out = {}
     for k in STORE_FIELDS:
         cur = current[k]
         val = d[prefix + k]
-        rows = k == "obs" and obs_shape is not None
-        want = (*cur.shape[:-2], *obs_shape) if rows else cur.shape
+        rows = k == "obs" and rows_cfg is not None
+        want = (*cur.shape[:-2], *rows_cfg.obs_shape) if rows else cur.shape
         if val.shape != want or val.dtype != cur.dtype:
             raise ValueError(
                 f"store {prefix}{k}: snapshot {val.shape}/{val.dtype} != "
                 f"buffer {want}/{cur.dtype}"
             )
-        out[k] = frames_to_rows(val, obs_shape) if rows else val
+        if rows:
+            val = frames_to_rows(val, rows_cfg.obs_shape, rows_cfg.resolved_frame_block)
+        out[k] = val
     return out
 
 
@@ -273,7 +276,7 @@ def _download_stores(cfg, stores) -> Dict[str, np.ndarray]:
     """A device plane's stores as the file holds them: host arrays, obs
     back as frames."""
     out = {k: np.asarray(stores[k]) for k in STORE_FIELDS}
-    out["obs"] = rows_to_frames(out["obs"], cfg.obs_shape)
+    out["obs"] = rows_to_frames(out["obs"], cfg.obs_shape, cfg.resolved_frame_block)
     return out
 
 
@@ -445,7 +448,7 @@ def restore_replay(replay, path: str) -> Dict[str, np.ndarray]:
                         raise ValueError(f"shard {g}: tree size mismatch")
                     vals_by_shard[g] = _validated_stores(
                         d, replay.stores[g], prefix=f"g{g}_store_",
-                        obs_shape=replay.cfg.obs_shape,
+                        rows_cfg=replay.cfg,
                     )
                 replay._rr = int(d["rr"][()])
                 for g in replay.local_ids:
@@ -473,7 +476,7 @@ def restore_replay(replay, path: str) -> Dict[str, np.ndarray]:
                 )
             with replay.lock:
                 vals = _validated_stores(
-                    d, replay.stores, obs_shape=replay.cfg.obs_shape
+                    d, replay.stores, rows_cfg=replay.cfg
                 )
                 for i in range(len(replay.shards)):  # leaf-count pre-check
                     if len(d[f"shard{i}_tree_leaves"]) != replay.shards[i].tree.capacity:
@@ -490,7 +493,7 @@ def restore_replay(replay, path: str) -> Dict[str, np.ndarray]:
             _check_kind(kind, "device", replay, saved_topo)
             with replay.lock:
                 vals = _validated_stores(
-                    d, replay.stores, obs_shape=replay.cfg.obs_shape
+                    d, replay.stores, rows_cfg=replay.cfg
                 )
                 if len(d["tree_leaves"]) != replay.tree.capacity:
                     raise ValueError("tree size mismatch")

@@ -38,6 +38,11 @@ def describe_runtime(cfg: R2D2Config) -> dict:
         # a Pallas core off-TPU runs under the interpreter (how the CPU
         # tests pin kernel parity) — never a device measurement
         "pallas_interpreted": core == "pallas" and jax.default_backend() != "tpu",
+        # the block in which the encoder's first conv reads a frame, and so
+        # the byte order of a frame in the device stores' rows
+        # (replay/block.py): blocked | frames (block 1: frames as they are)
+        "frame_block": cfg.resolved_frame_block,
+        "store_order": "blocked" if cfg.resolved_frame_block > 1 else "frames",
     }
 
 

@@ -101,17 +101,23 @@ def test_chip_smoke_alone_fails(tmp_path):
 
 
 _TINY_STORE = ["num_actors=8", "batch_size=8", "buffer_capacity=512"]
+# the Nature trunk's smallest frame that its stride divides: the store keeps
+# each frame's bytes in 4x4 blocks (PR 38), 1,296 bytes in 11 rows with a tail
+_TINY_BLOCKED = [*_TINY_STORE, "encoder=nature", "obs_shape=36,36,1"]
 
 
-def test_chip_smoke_store_bytes_reads_every_plane_on_four_devices():
+@pytest.mark.parametrize("sets, block", [(_TINY_STORE, 1), (_TINY_BLOCKED, 4)], ids=["frames", "blocked"])
+def test_chip_smoke_store_bytes_reads_every_plane_on_four_devices(sets, block):
     """The store-bytes phase at tiny size on four host devices: the plain
     jit, the sharded plane's shard_map and the GSPMD gather, each before, in
-    the same program as, and after an in-place slab write — all bit for bit."""
+    the same program as, and after an in-place slab write — all bit for bit,
+    as canonical frames and as the store keeps them (in 4x4 blocks under the
+    Nature trunk)."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4", "PYTHONPATH": REPO}
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "chip_smoke.py"), "--phase", "store_bytes",
-         "--preset", "tiny_test", "--sets", *_TINY_STORE],
+         "--preset", "tiny_test", "--sets", *sets],
         cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
     )
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
@@ -119,17 +125,22 @@ def test_chip_smoke_store_bytes_reads_every_plane_on_four_devices():
     assert {(v["plane"], v["moment"]) for v in rows} == {
         (p, m) for p in ("jit", "shard_map") for m in ("before", "same_program", "after")
     } | {("gspmd", "before"), ("gspmd", "after")}
+    assert {v["frame_block"] for v in rows} == {block}
+    for order in ("canonical", "stored"):  # every plane and moment, in both orders
+        assert {(v["plane"], v["moment"]) for v in rows if v["order"] == order} == {
+            (v["plane"], v["moment"]) for v in rows}
     assert all(v["verdict"] == "ok" and v["mismatched_bytes"] == 0 for v in rows)
     # the write lands on slots the gathers read, in every plane and moment
     assert all(v["read_from_slab_slots"] > 0 for v in rows)
     assert "STORE_BYTES_DONE failed=0" in r.stdout
 
 
-@pytest.mark.parametrize("fault", ["next_row", "next_byte", "write_skips_obs"])
+@pytest.mark.parametrize("fault", ["next_row", "next_byte", "write_skips_obs", "order_not_undone"])
 def test_chip_smoke_store_bytes_sees_wrong_bytes(fault, monkeypatch, capsys):
     """The pattern tells a gather that is off by one slot row or by one byte
-    inside the frame, and a slab write that leaves the obs store as it was,
-    from the right ones."""
+    inside the frame, a slab write that leaves the obs store as it was, and a
+    canonical gather that hands the store's block order back as it is, from
+    the right ones."""
     import jax.numpy as jnp
 
     import chip_smoke
@@ -137,12 +148,16 @@ def test_chip_smoke_store_bytes_sees_wrong_bytes(fault, monkeypatch, capsys):
 
     good_gather, good_write = learner.make_store_gather, megastep._slab_write
 
-    def broken_gather(cfg):
-        gather = good_gather(cfg)
+    def broken_gather(cfg, as_stored=False):
+        gather = good_gather(cfg, as_stored)
 
         def gather_batch(stores, b, s, w):
             if fault == "next_row":
                 stores = {**stores, "obs": jnp.roll(stores["obs"], -1, axis=1)}
+            if fault == "order_not_undone":  # what `correct` could not see (PERF.md finding 25.5)
+                stored = good_gather(cfg, True)(stores, b, s, w)
+                return stored if as_stored else stored._replace(
+                    obs=stored.obs.reshape(*stored.obs.shape[:2], *cfg.obs_shape))
             batch = gather(stores, b, s, w)
             if fault == "next_byte":
                 flat = batch.obs.reshape(*batch.obs.shape[:2], -1)
@@ -156,14 +171,17 @@ def test_chip_smoke_store_bytes_sees_wrong_bytes(fault, monkeypatch, capsys):
                             lambda stores, fields, start: {**good_write(stores, fields, start), "obs": stores["obs"]})
     else:
         monkeypatch.setattr(learner, "make_store_gather", broken_gather)
-    assert chip_smoke._store_bytes_child("tiny_test", _TINY_STORE, batches=1) == 1
+    sets = _TINY_BLOCKED if fault == "order_not_undone" else _TINY_STORE
+    assert chip_smoke._store_bytes_child("tiny_test", sets, batches=1) == 1
     rows = [json.loads(l[12:]) for l in capsys.readouterr().out.splitlines() if l.startswith("STORE_BYTES ")]
-    bad = {(v["plane"], v["moment"]) for v in rows if v["verdict"] != "ok"}
+    bad = {(v["plane"], v["moment"], v["order"]) for v in rows if v["verdict"] != "ok"}
     planes = {v["plane"] for v in rows}
     if fault == "write_skips_obs":  # only what follows the write is wrong
-        assert bad == {(p, "after") for p in planes}
+        assert bad == {(p, "after", o) for p in planes for o in ("canonical", "stored")}
+    elif fault == "order_not_undone":  # the as-stored gather is right, the canonical one is not
+        assert bad == {(v["plane"], v["moment"], "canonical") for v in rows}
     else:
-        assert bad == {(v["plane"], v["moment"]) for v in rows}
+        assert bad == {(v["plane"], v["moment"], v["order"]) for v in rows}
 
 
 # ------------------------------------------------------- native replay core
@@ -218,3 +236,23 @@ def test_pallas_core_only_where_no_mesh_axis_is_auto(monkeypatch):
     devices = jax.devices()
     assert dp_manual_axes(make_mesh(dp=4, tp=1, devices=devices[:4])) is None
     assert dp_manual_axes(make_mesh(dp=2, tp=2, devices=devices[:4])) == {"dp"}
+
+
+@pytest.mark.parametrize("preset, block, order", [
+    ("atari", 4, "blocked"), ("atari_v4_8", 4, "blocked"),
+    ("long_context", 1, "frames"), ("procgen_impala", 1, "frames"), ("tiny_test", 1, "frames"),
+])
+def test_runtime_line_says_the_frame_block_and_the_store_order(preset, block, order):
+    """`[runtime]` names the block in which the encoder's first conv reads a
+    frame and the byte order of the device stores' rows (PR 38): 4 / blocked
+    under the Nature trunk on 84x84, 1 / frames for every other encoder, and
+    for the Nature trunk where its stride does not divide the frame."""
+    from r2d2_tpu.config import PRESETS
+    from r2d2_tpu.utils.runtime import describe_runtime
+
+    cfg = PRESETS[preset]()
+    info = describe_runtime(cfg)
+    assert (info["frame_block"], info["store_order"]) == (block, order) == (cfg.resolved_frame_block, order)
+    if cfg.encoder == "nature":
+        odd = describe_runtime(cfg.replace(obs_shape=(86, 86, 1)))
+        assert (odd["frame_block"], odd["store_order"]) == (1, "frames")

@@ -148,28 +148,45 @@ def test_multi_step_matches_sequential_fused():
 
 
 @pytest.mark.parametrize("backend", ["numpy", "jax"])
+@pytest.mark.parametrize("encoder", ["mlp", "nature"], ids=["block-of-mlp", "block-of-nature"])
 @pytest.mark.parametrize(
     "obs_shape, rows",
     [((84, 84, 1), 56), ((16, 8), 1), ((50,), 1), ((12, 12, 1), 2), ((128, 2), 2)],
     ids=["nature-7056B", "exactly-128B", "vector-50B", "tiny-144B", "exactly-256B"],
 )
-def test_frames_to_rows_and_back_is_the_identity(obs_shape, rows, backend):
-    from r2d2_tpu.replay.block import LANES, frames_to_rows, obs_rows, rows_to_frames
+def test_frames_to_rows_and_back_is_the_identity(obs_shape, rows, encoder, backend):
+    """With the block the encoder publishes for the shape (PR 38: the Nature
+    trunk's 4 where it divides an (H, W, C) frame's sides, else 1) the rows
+    hold the frame's bytes in 4x4 blocks; with block 1, in order."""
+    from r2d2_tpu.models.encoders import frame_block
+    from r2d2_tpu.replay.block import LANES, frames_to_rows, obs_rows, rows_as_stored, rows_to_frames
 
+    block = frame_block(encoder, obs_shape)
+    assert block == (4 if encoder == "nature" and obs_shape in ((84, 84, 1), (12, 12, 1)) else 1)
     frames = np.random.default_rng(0).integers(1, 256, (3, 5, *obs_shape), dtype=np.uint8)
     given = frames if backend == "numpy" else jax.numpy.asarray(frames)
-    packed = frames_to_rows(given, obs_shape)
+    packed = frames_to_rows(given, obs_shape, block)
     assert isinstance(packed, np.ndarray) == (backend == "numpy")
     assert obs_rows(obs_shape) == rows and packed.shape == (3, 5, rows, LANES) and packed.dtype == np.uint8
     flat = np.asarray(packed).reshape(3, 5, -1)
     n = int(np.prod(obs_shape))
-    np.testing.assert_array_equal(flat[..., :n], frames.reshape(3, 5, n))  # the frame's bytes, in order
-    assert not flat[..., n:].any()                                        # the tail is zero
-    np.testing.assert_array_equal(np.asarray(rows_to_frames(packed, obs_shape)), frames)
+    stored = frames
+    if block > 1:  # (H/s, s, W/s, s, C) -> (H/s, W/s, s, s, C)
+        H, W, C = obs_shape
+        stored = frames.reshape(3, 5, H // block, block, W // block, block, C).transpose(0, 1, 2, 4, 3, 5, 6)
+        assert (stored.reshape(3, 5, n) != frames.reshape(3, 5, n)).any()
+        # byte (dy*s + dx)*C + c of block (i, j) is pixel (i*s + dy, j*s + dx, c)
+        assert flat[1, 2, (2 * (W // block) + 1) * block * block * C + (3 * block + 2) * C] == frames[1, 2, 2 * block + 3, block + 2, 0]
+    np.testing.assert_array_equal(flat[..., :n], stored.reshape(3, 5, n))  # the frame's bytes, in the block's order
+    assert not flat[..., n:].any()                                         # the tail is zero
+    np.testing.assert_array_equal(np.asarray(rows_to_frames(packed, obs_shape, block)), frames)
+    as_stored = np.asarray(rows_as_stored(packed, obs_shape, block))
+    assert as_stored.shape == ((3, 5, H // block, W // block, block * block * C) if block > 1 else frames.shape)
+    np.testing.assert_array_equal(as_stored.reshape(3, 5, n), flat[..., :n])
     with pytest.raises(ValueError):
-        frames_to_rows(given[..., :-1], obs_shape)
+        frames_to_rows(given[..., :-1], obs_shape, block)
     with pytest.raises(ValueError):
-        rows_to_frames(packed[..., :-1, :], obs_shape)
+        rows_to_frames(packed[..., :-1, :], obs_shape, block)
 
 
 @pytest.mark.parametrize("obs_shape", [(3, 3, 1), (16, 8), (50,)], ids=["image", "exactly-128B", "vector"])

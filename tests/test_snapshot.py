@@ -209,3 +209,38 @@ def test_the_device_plane_still_writes_the_parents_file(tmp_path):
             if k.startswith("store_") or k in ("tree_leaves", "block_ptr", "size", "occupied"):
                 assert old[k].dtype == new[k].dtype and old[k].shape == new[k].shape, k
                 np.testing.assert_array_equal(old[k], new[k], err_msg=k)
+
+
+@pytest.mark.parametrize("how", ["restore", "reshard_to_device", "reshard_to_sharded"])
+def test_the_parents_file_loads_into_a_blocked_store_and_is_written_back_as_it_was(how, tmp_path):
+    """PR 38: under an encoder that publishes a block (the Nature trunk's 4,
+    which divides the fixture's 12x12x1 frames) the device stores keep each
+    frame's bytes in 4x4 blocks; the FILE stays canonical frames, so PR 23's
+    fixture loads on every device plane and is saved back byte for byte."""
+    from r2d2_tpu.parallel.mesh import make_mesh
+    from r2d2_tpu.replay.block import frames_to_rows, rows_to_frames
+    from r2d2_tpu.replay.reshard import reshard_replay
+    from r2d2_tpu.replay.sharded_store import ShardedDeviceReplay
+
+    cfg = tiny_test().replace(buffer_capacity=64, encoder="nature")
+    assert cfg.resolved_frame_block == 4 and tiny_test().resolved_frame_block == 1
+    with np.load(FIXTURE) as npz:
+        frames = npz["store_obs"]
+    if how == "reshard_to_sharded":
+        fresh = ShardedDeviceReplay(cfg, make_mesh(dp=1, tp=1, devices=jax.devices()[:1]))
+    else:
+        fresh = DeviceReplayBuffer(cfg)
+    restore_replay(fresh, FIXTURE) if how == "restore" else reshard_replay(fresh, [FIXTURE])
+    held = np.asarray(fresh.stores["obs"])
+    np.testing.assert_array_equal(held, frames_to_rows(frames, cfg.obs_shape, 4))
+    assert (held != frames_to_rows(frames, cfg.obs_shape)).any()  # not the frames as they are
+    np.testing.assert_array_equal(rows_to_frames(held, cfg.obs_shape, 4), frames)
+    # one 4x4 block of frame (0, 0) is 16 consecutive stored bytes
+    np.testing.assert_array_equal(held[0, 0].reshape(-1)[16:32], frames[0, 0, 0:4, 4:8, 0].reshape(-1))
+    path = str(tmp_path / "snap.npz")
+    save_replay(fresh, path)
+    with np.load(FIXTURE) as old, np.load(path) as new:
+        for k in old.files:
+            if k.startswith("store_"):
+                assert old[k].dtype == new[k].dtype and old[k].shape == new[k].shape, k
+                np.testing.assert_array_equal(old[k], new[k], err_msg=k)

@@ -244,6 +244,42 @@ def _encoder_backward_leading_dims(text):
     return dims
 
 
+def _compiled_unroll_gradient(cfg, rows, one_chip):
+    """-> (compiled text, seconds) of `unroll` under value_and_grad at `rows`
+    sequences of the configuration's T, fed frames AS STORED (PR 38: the
+    step programs' form, `(B, T, 21, 21, 16)` under the Nature trunk on
+    84x84x1), for the described chip."""
+    import time
+
+    from r2d2_tpu.models.encoders import blocked_shape
+    from r2d2_tpu.models.r2d2 import R2D2Network, init_params
+
+    if cfg.recurrent_core == "lstm":
+        cfg = cfg.replace(lstm_backend="pallas")  # "auto" asks the attached device, a CPU here
+    net = R2D2Network.from_config(cfg)
+    B, seq = rows, cfg.seq_len
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
+    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
+                          jax.eval_shape(lambda k: init_params(k, cfg)[1], jax.random.PRNGKey(0)))
+    stored = blocked_shape(cfg.obs_shape, cfg.resolved_frame_block)
+    batch = [sds((B, seq, *stored), jnp.uint8), sds((B, seq), jnp.int32), sds((B, seq), jnp.float32),
+             sds((B, 2, cfg.hidden_dim), jnp.float32), sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32)]
+
+    def loss(p, *b):
+        q_learn, q_boot, mask = net.apply(p, *b)
+        return jnp.sum(q_learn * mask[..., None]) + jnp.sum(q_boot)
+
+    t = time.time()
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, *batch).compile().as_text()
+    return text, time.time() - t
+
+
+def _cell_config(config):
+    from benchmark import harness
+
+    return harness.build_config(harness.load_json(os.path.join(ROOT, "benchmark", "configs", config + ".json")), 1)
+
+
 @pytest.mark.parametrize("config,rows,differentiated", [
     ("nature-lstm512", 64, "window"), ("nature-lstm512-dp4", 16, "window"), ("lru-seq581", 32, "sequence")])
 def test_encoder_backward_runs_over_the_frames_that_can_receive_a_gradient(
@@ -253,32 +289,39 @@ def test_encoder_backward_runs_over_the_frames_that_can_receive_a_gradient(
     convs has B*(L+F) frames (each row's from its seam) and none has B*T; the
     LRU core publishes no seam and its encoder backward keeps B*T. (With B*L
     frames, a multiple of 128 at 16, 32 and 48 rows, the chip's compiler took
-    five minutes and more over conv1's backward-filter: PERF.md finding 32.3.)"""
-    from benchmark import harness
-    from r2d2_tpu.models.r2d2 import R2D2Network, init_params
+    five minutes and more over conv1's backward-filter: PERF.md finding 32.3.)
 
-    cfg = harness.build_config(harness.load_json(os.path.join(ROOT, "benchmark", "configs", config + ".json")), 1)
-    if cfg.recurrent_core == "lstm":
-        cfg = cfg.replace(lstm_backend="pallas")  # "auto" asks the attached device, a CPU here
-    net = R2D2Network.from_config(cfg)
+    Fed frames as stored (PR 38), the program holds no `[N,84,84,1]` array at
+    all: conv1 reads `(N, 21, 21, 16)` by a reshape of the rows, and its
+    backward-filter is the 2x2/1 conv's `f32[2,2,16,32]`, which the graph
+    re-indexes to the `(8, 8, 1, 32)` parameter's gradient."""
+    cfg = _cell_config(config)
+    assert cfg.resolved_frame_block == 4
     B, seq, W = rows, cfg.seq_len, cfg.learning_steps + cfg.forward_steps
-    sds = lambda shape, dt: jax.ShapeDtypeStruct(tuple(shape), dt, sharding=one_chip)
-    params = jax.tree.map(lambda x: sds(x.shape, x.dtype),
-                          jax.eval_shape(lambda k: init_params(k, cfg)[1], jax.random.PRNGKey(0)))
-    batch = [sds((B, seq, *cfg.obs_shape), jnp.uint8), sds((B, seq), jnp.int32), sds((B, seq), jnp.float32),
-             sds((B, 2, cfg.hidden_dim), jnp.float32), sds((B,), jnp.int32), sds((B,), jnp.int32), sds((B,), jnp.int32)]
-
-    def loss(p, *b):
-        q_learn, q_boot, mask = net.apply(p, *b)
-        return jnp.sum(q_learn * mask[..., None]) + jnp.sum(q_boot)
-
-    text = jax.jit(jax.value_and_grad(loss)).lower(params, *batch).compile().as_text()
+    text, _ = _compiled_unroll_gradient(cfg, rows, one_chip)
     dims = _encoder_backward_leading_dims(text)
     frames = {"window": B * W, "sequence": B * seq}[differentiated]
     assert sorted(dims) == [0, 1, 2], dims
     for conv, got in dims.items():
-        filters = {d for d in got if d <= 8}  # 8x8, 4x4, 3x3 kernels
+        filters = {d for d in got if d <= 8}  # 2x2 (conv1 over blocks), 4x4, 3x3 kernels
         assert got - filters == {frames}, (conv, got)
+    assert not re.search(r"\[\d+,84,84,1\]", text), re.findall(r"\S+\[\d+,84,84,1\]\S*", text)[:5]
+    conv1_backward = [l for l in text.splitlines() if re.search(r"transpose\(jvp.*enc/Conv_0", l)]
+    assert any(re.search(r"= f32\[2,2,16,32\]", l) for l in conv1_backward)
+    assert not any(re.search(r"= f32\[8,8,1,32\]\S* (fusion|convolution)\(", l) and "kind=kOutput" in l
+                   for l in conv1_backward)
+
+
+@pytest.mark.parametrize("rows", [16, 32, 48, 64])
+def test_conv1s_blocked_backward_filter_compiles_in_seconds_at_every_row_count(rows, one_chip, compiled_kernels):
+    """PERF.md finding 32.3's hazard, asked of the 2x2/1 conv's emitter: with
+    rows x 45 frames behind the LSTM's seam (720, 1,440, 2,160, 2,880; the
+    8x8/4 conv's backward-filter took minutes at some frame counts), the whole
+    `unroll` gradient compiles in well under two minutes."""
+    cfg = _cell_config("nature-lstm512")
+    text, seconds = _compiled_unroll_gradient(cfg, rows, one_chip)
+    assert re.search(r"= f32\[2,2,16,32\]", text)
+    assert seconds < 120, seconds
 
 
 def test_lru_kernels_compile_at_the_cells_shape_named_after_their_wrappers(one_chip, compiled_kernels):
