@@ -258,6 +258,27 @@ def make_train_step(cfg: R2D2Config, net: R2D2Network, donate: bool = True):
     return jax.jit(raw, donate_argnums=(0,) if donate else ())
 
 
+def _windows(field, b, first, length: int):
+    """field (blocks, n) -> (B, length): `field[b[:, None], clip(first[:, None]
+    + arange(length), 0, n - 1)]` for first >= 0, read as ONE run of each row.
+
+    Indexed entry by entry, as that formula says it, a v5e pays ~9 ns an
+    index whatever it reads: the five scalar fields were 0.19 of the 0.38 ms
+    of nature's gather and 0.87 of lru's 2.08 (PERF.md finding 41). So: B row
+    reads, each row extended by its last entry (what the clip repeats), then a
+    barrel shifter brings each row's window to the front: for every bit of
+    `first`, a static roll by 2**k, selected per row. Static shapes, selects
+    only: every bit of every entry is the row's. (A roll wraps the row's head
+    round to its tail, past the end of every window.)"""
+    n = field.shape[1]
+    rows = field[b]
+    rows = jnp.concatenate([rows, jnp.broadcast_to(rows[:, -1:], (rows.shape[0], length - 1))], axis=1)
+    first = jnp.clip(first, 0, n - 1)
+    for k in range((n - 1).bit_length()):
+        rows = jnp.where(((first >> k) & 1 == 1)[:, None], jnp.roll(rows, -(1 << k), axis=1), rows)
+    return rows[:, :length]
+
+
 def make_store_gather(cfg: R2D2Config, as_stored: bool = False):
     """(stores, b, s, is_weights) -> DeviceBatch: in-jit clamped-window
     gather straight out of the HBM-resident stores. b is a block index
@@ -268,9 +289,23 @@ def make_store_gather(cfg: R2D2Config, as_stored: bool = False):
     keeps a frame's bytes in (replay/block.py). as_stored=True hands the
     frames back in the store's own order, (B, T, *blocked_shape): a reshape
     of the gathered rows and nothing else. The step programs ask for that;
-    the encoder takes either (models/encoders.BlockedConv)."""
+    the encoder takes either (models/encoders.BlockedConv).
+
+    A sampled sequence is one window of its slot. accumulator.finish stores
+    burn_in[s] = min(s L + first_burn, burn_in), so win = first_burn + s L -
+    burn_in[s] = max(0, first_burn + s L - burn_in) lies in [0, (S - 1) L] =
+    [0, block_length - L], and win + burn_in + L + 1 <= block_slot_len: the
+    first T - F + 1 rows of a window never leave their slot, for every preset
+    (tests/test_store_gather.py pins it); only the last F - 1 can meet the
+    clip, and do in the last sequence of every full block. The per-step
+    scalar fields are read as such windows (`_windows`, which keeps the clip's
+    answer for ANY start, so nothing traced rests on this geometry).
+    The frames keep one clipped index each: a frame is 7 KiB, its index costs
+    nothing beside it, and that gather already ran at 80-90 % of the HBM's
+    pace; B windows copied by a loop of dynamic slices ran at half of it
+    (PERF.md finding 41.1)."""
     L, T = cfg.learning_steps, cfg.seq_len
-    slot, bl = cfg.block_slot_len, cfg.block_length
+    slot = cfg.block_slot_len
     to_frames = rows_as_stored if as_stored else rows_to_frames
 
     def gather_batch(stores, b, s, is_weights) -> DeviceBatch:
@@ -282,28 +317,29 @@ def make_store_gather(cfg: R2D2Config, as_stored: bool = False):
         win = start - burn
         t = jnp.arange(T, dtype=jnp.int32)
         rows = jnp.clip(win[:, None] + t[None, :], 0, slot - 1)
-        bcol = b[:, None]
-        lrow = jnp.clip(s[:, None] * L + jnp.arange(L, dtype=jnp.int32)[None, :], 0, bl - 1)
         # obs: frames stored as lane-aligned rows (replay/block.py). Gathered
         # with ONE index over the flattened (block * slot) axis (a bitcast of
-        # the row-major store), not with the (block, row) pair the other
-        # fields use: on the v5e (libtpu 0.0.34) the two-index gather of
-        # (56, 128) uint8 slices compiles and then halts the core on its first
-        # execution, alone or inside the step programs; the one-index form
-        # runs, and faster than any other that was tried (PERF.md finding 25.2)
+        # the row-major store), not with a (block, row) pair: on the v5e
+        # (libtpu 0.0.34) the two-index gather of (56, 128) uint8 slices
+        # compiles and then halts the core on its first execution, alone or
+        # inside the step programs; the one-index form runs, and faster than
+        # any other that was tried (PERF.md findings 25.2, 41.1). mode="clip":
+        # the indices are in bounds by the clip above, and jnp.take's default
+        # ("fill") compiled to a select against the fill value over the whole
+        # batch, a pass that guarded nothing
         obs = stores["obs"]
         flat = obs.reshape(obs.shape[0] * slot, *obs.shape[2:])
         return DeviceBatch(
             obs=to_frames(
-                jnp.take(flat, bcol * slot + rows, axis=0),
+                jnp.take(flat, b[:, None] * slot + rows, axis=0, mode="clip"),
                 cfg.obs_shape, cfg.resolved_frame_block,
             ),
-            last_action=stores["last_action"][bcol, rows],
-            last_reward=stores["last_reward"][bcol, rows],
+            last_action=_windows(stores["last_action"], b, win, T),
+            last_reward=_windows(stores["last_reward"], b, win, T),
             hidden=stores["hidden"][b, s],
-            action=stores["action"][bcol, lrow],
-            n_step_reward=stores["n_step_reward"][bcol, lrow],
-            gamma=stores["gamma"][bcol, lrow],
+            action=_windows(stores["action"], b, s * L, L),
+            n_step_reward=_windows(stores["n_step_reward"], b, s * L, L),
+            gamma=_windows(stores["gamma"], b, s * L, L),
             burn_in_steps=burn,
             learning_steps=learn,
             forward_steps=fwd,

@@ -43,6 +43,11 @@ def describe_runtime(cfg: R2D2Config) -> dict:
         # (replay/block.py): blocked | frames (block 1: frames as they are)
         "frame_block": cfg.resolved_frame_block,
         "store_order": "blocked" if cfg.resolved_frame_block > 1 else "frames",
+        # how learner.make_store_gather reads a sampled sequence out of the
+        # device stores: its frames by one clipped index each, its five
+        # per-step scalar fields as one window of their row (PR 41; one
+        # algorithm everywhere: a tree before it has no such key)
+        "store_gather": "frames+windows",
     }
 
 

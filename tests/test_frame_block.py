@@ -316,22 +316,26 @@ def _step_program_texts(cfg):
     }
 
 
-# (characters, sha256) of each program's jaxpr text on the PARENT tree (commit
-# 18bb6d7, jax 0.9.0; taken by this function in a checkout of it, PR 38). A
-# jax release that prints a jaxpr differently moves every row at once: take
-# them again from a tree known to be good
+# (characters, sha256) of each program's jaxpr text with the frames as they are
+# (jax 0.9.0; taken by this function). First taken on PR 38's PARENT (commit
+# 18bb6d7), which is what the name says: PR 38 left all six as they were.
+# Taken again in PR 41, whose store gather is every preset's (the scalar
+# fields read as windows, learner._windows: all six moved, +26 k to +56 k characters
+# each) and which changed nothing else in them. A jax release that prints a
+# jaxpr differently moves every row at once: take them again from a tree known
+# to be good
 PARENT_PROGRAMS = {
     "procgen_impala": {
-        "mega": (558779, "6a1f05a3ea5b536d7b618ca1276fbe583f335c5d2aa884d2b28de008fccb2b0c"),
-        "multi": (398955, "d146a7ba1419517f0fbfcb9d0f6dff090d066f031fbe0d65c1fe94a006d7a4fa"),
+        "mega": (615074, "6a19d279088fb2c90488597e556f993853ba49811159ef9a72948667a81a3511"),
+        "multi": (454988, "9bb0612f2d6bd056b112033278419c97845ebb14e483226b9c08bacf98b6a0d7"),
     },
     "tiny_test": {
-        "mega": (215352, "6abd4471b33e2e3299d51316aeb06736d8f56c24614f306ec71b74bd1f3d2e85"),
-        "multi": (150508, "4f2175cdf69ee84fa2173581543a15580bec88c0dade6c36b027d482d23ba70c"),
+        "mega": (241909, "12797b2b4720480aa6d956fb3546ed9a6a2a494fb181e1531e416019370cf7f2"),
+        "multi": (176818, "9b2136999f535521b590080805c515fa511a44cc3a5ee8bce602faa10052c7c8"),
     },
     "tiny_test-deep-bf16": {
-        "mega": (253367, "5029dfde1e3c04af3836f09263ea322980c2320dafd5fcf4df20e7e31fbe8a9c"),
-        "multi": (180626, "9379622ada38d425c87e367c8e1295518e490050eac867f1e118259bdb3af136"),
+        "mega": (279954, "2b0378fc63f7acfe907ec90e9118634ee5aaf9494044f38ad45125d99f30445c"),
+        "multi": (206949, "f01c1a88a5177b6f6eead8c4365b77238c878320c8743bfb711ef52c3ce28488"),
     },
 }
 

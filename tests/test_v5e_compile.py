@@ -178,11 +178,13 @@ def test_a_shape_over_vmem_is_refused_where_the_core_is_resolved_with_its_shape(
 @pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581"])
 def test_store_gather_and_slab_write_read_the_obs_store_in_place(config, one_chip, compiled_kernels):
     """The replay half of both step programs at a benchmark configuration's
-    real store shape: K-free gather of one batch, then the donated slab write
-    of one collected chunk. With frames stored as lane-aligned rows
-    (replay/block.frames_to_rows) the chip's compiler reads and writes the
-    4 GB obs store in place; with raw (84, 84, 1) frames it re-laid the whole
-    store out on every dispatch (PERF.md finding 1: 6.4 GB of temp)."""
+    real store shape: K-free gather of one batch (PR 41: the frames by one
+    clipped index each, the scalar fields as windows of B rows), then the
+    donated slab write of one collected chunk. With frames stored as
+    lane-aligned rows (replay/block.frames_to_rows) the chip's compiler reads
+    and writes the 4 GB obs store in place; with raw (84, 84, 1) frames it
+    re-laid the whole store out on every dispatch (PERF.md finding 1: 6.4 GB
+    of temp)."""
     from benchmark import harness
     from r2d2_tpu import learner, megastep
     from r2d2_tpu.replay.block import store_field_specs
@@ -210,9 +212,26 @@ def test_store_gather_and_slab_write_read_the_obs_store_in_place(config, one_chi
     assert profiling.relayouts_at_least(text, obs_store_bytes // 2) == []
     # the obs gather indexes ONE flattened (block * slot) axis: the two-index
     # form of the same gather compiles too, and halts the v5e's core when it
-    # runs (PERF.md finding 25.2; learner.make_store_gather says the same)
-    obs_gathers = [l.strip() for l in text.splitlines() if re.search(r"= u8\[[\d,]+,128\]\S* gather\(", l)]
+    # runs (PERF.md finding 25.2; learner.make_store_gather says the same).
+    # Nothing else reads the obs store: no dynamic-slice of it (B windows copied
+    # by a loop ran at half the gather's pace, finding 41.1) and no loop at all
+    lines = [l.strip() for l in text.splitlines()]
+    obs_gathers = [l for l in lines if re.search(r"= u8\[[\d,]+,128\]\S* gather\(", l)]
     assert obs_gathers and all("collapsed_slice_dims={0}," in l for l in obs_gathers), obs_gathers
+    assert not [l for l in lines if re.search(r"= u8\[[\d,]+\]\S* dynamic-slice\(", l)]
+    assert not [l for l in lines if re.search(r" while\(", l)]
+    # no second pass over the uint8 batch: jnp.take's default mode was a
+    # `select` against the fill value over u8[B,T,7056] (PR 41 took it out);
+    # nor a pad or a concatenate that assembles the batch from parts
+    passes = [l for l in lines if re.search(r"= u8\[[\d,]+\]\S* (select|pad|concatenate)\(", l)]
+    assert passes == [], passes
+    # the five per-step scalar fields are B row reads (learner._windows), not
+    # B x T or B x L single entries (~9 ns an index on the chip, finding 41)
+    T_, L_ = cfg.seq_len, cfg.learning_steps
+    shapes = [re.search(r"= [sf]32\[([\d,]+)\]\S* gather\(", l) for l in lines]
+    shapes = [tuple(map(int, m.group(1).split(","))) for m in shapes if m]
+    assert not {(B, T_), (B, L_)} & set(shapes), shapes
+    assert shapes.count((B, cfg.block_slot_len)) == 2 and shapes.count((B, cfg.block_length)) == 3, shapes
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= obs_store_bytes  # the store is updated in place
     assert memory.temp_size_in_bytes < 2.5e9, memory.temp_size_in_bytes  # slab and batch only
