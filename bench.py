@@ -2729,318 +2729,6 @@ def long_context_main(core: str = "lstm", lru_chunk: int = 0,
     )
 
 
-def _load_r09_breakdown():
-    """The committed round-9 breakdown (BENCH_r09.json next to this file):
-    the baseline the vs_r09 column is measured against. None when the
-    file is missing or carries no parsed breakdown."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "BENCH_r09.json")
-    try:
-        with open(path) as fh:
-            return json.load(fh)["parsed"]["breakdown"]
-    except (OSError, KeyError, ValueError):
-        return None
-
-
-def _load_r14_breakdown():
-    """The committed round-14 breakdown (BENCH_r14.json): baseline for the
-    vs_r14 column — the pre-manual-partitioning step whose loss_grad phase
-    was ~100% of the train step (frac 1.027)."""
-    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "BENCH_r14.json")
-    try:
-        with open(path) as fh:
-            return json.load(fh)["parsed"]["breakdown"]
-    except (OSError, KeyError, ValueError):
-        return None
-
-
-def _model_fits_table(cfg, hbm_gb: float = 16.0):
-    """Largest-model-that-fits probe per mesh shape (ISSUE 16): for each
-    (dp, tp, fsdp) shape and each config.MODEL_PRESETS entry, sum the
-    PER-DEVICE TrainState bytes under the sharding table (tp splits the
-    Megatron kernels, fsdp the Adam moments) plus what the LSTM sequence
-    kernel's backward keeps in HBM (the h and c sequences and the float32
-    dz). Analytic (abstract shapes, no allocation), so the table
-    is exact arithmetic on any host — activations/XLA temps are NOT
-    modeled, making "fits" an upper bound on feasibility, not a promise.
-
-    Mesh shapes are abstract (axis sizes only): the probe is sharding
-    arithmetic, so it covers slices larger than this host."""
-    import jax.numpy as jnp
-    import jax.tree_util as jtu
-
-    from r2d2_tpu.config import MODEL_PRESETS, apply_model_preset
-    from r2d2_tpu.learner import init_train_state
-    from r2d2_tpu.parallel.sharding_map import process_name, spec_for
-
-    class _AbstractMesh:
-        """Duck-types the two attrs spec_for reads (axis_names/shape)."""
-
-        axis_names = ("dp", "tp", "fsdp")
-
-        def __init__(self, dp, tp, fsdp):
-            self.shape = {"dp": dp, "tp": tp, "fsdp": fsdp}
-
-    budget = int(hbm_gb * (1 << 30))
-    T = cfg.burn_in_steps + cfg.learning_steps + cfg.forward_steps
-    # ascending by state size so "largest fit" is the last that fits
-    order = [p for p in ("base", "deep", "wide", "deep_wide", "xl")
-             if p in MODEL_PRESETS]
-    table = {}
-    for dp, tp, fsdp in [(1, 1, 1), (8, 1, 1), (4, 2, 1), (2, 2, 2),
-                         (4, 4, 2), (2, 8, 4)]:
-        mesh = _AbstractMesh(dp, tp, fsdp)
-        rows, largest = {}, None
-        for preset in order:
-            pcfg = apply_model_preset(cfg, preset)
-            if pcfg.hidden_dim % tp:
-                rows[preset] = {"fits": False, "reason": f"hidden_dim % tp={tp}"}
-                continue
-            template = jax.eval_shape(
-                lambda k, c=pcfg: init_train_state(c, k)[1],
-                jax.random.PRNGKey(0),
-            )
-            state_bytes = 0
-            for path, leaf in jtu.tree_flatten_with_path(template)[0]:
-                spec = spec_for(process_name(path), leaf, mesh)
-                div = 1
-                for entry in spec:
-                    if entry is None:
-                        continue
-                    for ax in (entry if isinstance(entry, tuple) else (entry,)):
-                        div *= mesh.shape[ax]
-                size = int(np.prod(leaf.shape)) if leaf.shape else 1
-                state_bytes += size * jnp.dtype(leaf.dtype).itemsize // div
-            B_local = max(pcfg.batch_size // (dp * fsdp), 1)
-            H = pcfg.hidden_dim
-            itemsize = jnp.dtype(pcfg.resolved_compute_dtype).itemsize
-            # outs (compute dtype) + cs (f32) + dz (f32, 4H wide)
-            peak = T * B_local * H * (itemsize + 4 + 16)
-            total = state_bytes + peak
-            fits = total <= budget
-            rows[preset] = {
-                "state_bytes": state_bytes,
-                "peak_residual_bytes": peak,
-                "total_bytes": total,
-                "fits": fits,
-            }
-            if fits:
-                largest = preset
-        table[f"dp{dp}_tp{tp}_fsdp{fsdp}"] = {
-            "largest_fit": largest,
-            "models": rows,
-        }
-    return {"hbm_gb": hbm_gb, "seq_len": T, "batch": cfg.batch_size,
-            "per_mesh_shape": table}
-
-
-def breakdown_main(core: str = "lstm", lru_chunk: int = 0, batch: int = 0,
-                   precision: str = "bf16", hbm_gb: float = 16.0,
-                   model_preset: str = ""):
-    """Per-phase learner step breakdown: the denominator map for kernel
-    work. Times the train step's constituent programs as SEPARATELY
-    jitted pieces on one synthetic DeviceBatch —
-
-      unroll    forward unroll, online params (encoder + recurrent core +
-                both dueling head evaluations; the fused-sequence kernel
-                lives here)
-      head      the dueling head alone on (B, L, H) features
-      loss_grad value_and_grad over the full loss (learner.make_loss_fn):
-                both unrolls + TD/priority math + backward
-      optimizer the optax update + target-net sync at fixed gradients
-
-    — each wrapped in a utils/profiling span (jax.profiler annotation),
-    so an xprof capture of this process groups device activity by phase.
-    Fractions are each phase's time over the full jitted train step's.
-    They are a MAP, not a partition: the pieces re-run shared work
-    (loss_grad contains both unrolls) and XLA fuses the monolith
-    differently, so fractions need not sum to 1."""
-    import optax
-
-    from r2d2_tpu.learner import (
-        DeviceBatch,
-        make_batch_train_step,
-        make_loss_fn,
-        make_optimizer,
-    )
-    from r2d2_tpu.utils.profiling import span
-
-    arm = "bf16" if precision == "both" else precision
-    cfg = default_atari().replace(
-        **_precision_overrides(arm),
-        **_core_overrides(core, lru_chunk),
-    )
-    if batch:
-        cfg = cfg.replace(batch_size=batch)
-    if model_preset:
-        from r2d2_tpu.config import apply_model_preset
-
-        cfg = apply_model_preset(cfg, model_preset)
-    dev = jax.devices()[0]
-    print(f"device: {dev.device_kind} ({dev.platform})", file=sys.stderr)
-
-    import jax.numpy as jnp
-
-    B = cfg.batch_size
-    Bn, L, F = cfg.burn_in_steps, cfg.learning_steps, cfg.forward_steps
-    T = Bn + L + F
-    rng = np.random.default_rng(0)
-    b = DeviceBatch(
-        obs=jnp.asarray(rng.integers(0, 255, (B, T, *cfg.obs_shape), dtype=np.uint8)),
-        last_action=jnp.asarray(rng.integers(0, cfg.action_dim, (B, T)), jnp.int32),
-        last_reward=jnp.asarray(rng.normal(size=(B, T)).astype(np.float32)),
-        hidden=jnp.asarray((rng.normal(size=(B, 2, cfg.hidden_dim)) * 0.1).astype(np.float32)),
-        action=jnp.asarray(rng.integers(0, cfg.action_dim, (B, L)), jnp.int32),
-        n_step_reward=jnp.asarray(rng.normal(size=(B, L)).astype(np.float32)),
-        gamma=jnp.full((B, L), cfg.gamma**F, jnp.float32),
-        burn_in_steps=jnp.full((B,), Bn, jnp.int32),
-        learning_steps=jnp.full((B,), L, jnp.int32),
-        forward_steps=jnp.full((B,), F, jnp.int32),
-        is_weights=jnp.ones((B,), jnp.float32),
-    )
-    net, state = init_train_state(cfg, jax.random.PRNGKey(0))
-    denom = jnp.asarray(float(B * L), jnp.float32)
-    feats = jnp.asarray(
-        rng.normal(size=(B, L, cfg.hidden_dim)).astype(np.float32)
-    ).astype(jnp.dtype(cfg.resolved_compute_dtype))
-    grads = jax.tree.map(lambda x: jnp.full_like(x, 1e-3), state.params)
-    loss_fn = make_loss_fn(cfg, net)
-    optimizer = make_optimizer(cfg)
-
-    def opt_only(state, grads):
-        updates, opt_state = optimizer.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        sync = ((state.step + 1) % cfg.target_net_update_interval) == 0
-        target = jax.tree.map(
-            lambda t, p: jnp.where(sync, p, t), state.target_params, params
-        )
-        return params, target, opt_state
-
-    full_step = make_batch_train_step(cfg, net, donate=False)
-    programs = {
-        "unroll": (
-            jax.jit(lambda s, b: net.apply(
-                s.params, b.obs, b.last_action, b.last_reward, b.hidden,
-                b.burn_in_steps, b.learning_steps, b.forward_steps,
-            )),
-            lambda: (state, b),
-        ),
-        "head": (
-            jax.jit(lambda s, h: net.apply(
-                s.params, h, method=lambda mdl, h: mdl._dueling(h)
-            )),
-            lambda: (state, feats),
-        ),
-        "loss_grad": (
-            jax.jit(lambda s, b, d: jax.value_and_grad(loss_fn, has_aux=True)(
-                s.params, s.target_params, b, d
-            )),
-            lambda: (state, b, denom),
-        ),
-        "optimizer": (jax.jit(opt_only), lambda: (state, grads)),
-        "train_step": (full_step, lambda: (state, b)),
-    }
-
-    def time_program(name, fn, args_fn, iters=20):
-        jax.block_until_ready(fn(*args_fn()))  # compile outside the window
-        with span(f"breakdown/{name}"):
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = fn(*args_fn())
-            jax.block_until_ready(out)
-            ms = (time.perf_counter() - t0) / iters * 1e3
-        print(f"[breakdown] {name}: {ms:.3f} ms", file=sys.stderr)
-        return ms
-
-    times = {
-        name: time_program(name, fn, args_fn)
-        for name, (fn, args_fn) in programs.items()
-    }
-    step_ms = times.pop("train_step")
-    host_ms = _priority_host_ms(cfg, B)
-    report = {
-        "metric": "learner_step_breakdown",
-        "value": round(step_ms, 3),
-        "unit": "ms/update",
-        "batch": B,
-        "core": cfg.recurrent_core
-        + (f"_c{cfg.lru_chunk}" if cfg.lru_chunk else ""),
-        "precision": cfg.precision,
-        "model_preset": model_preset or "base",
-        "phases": {
-            name: {
-                "ms": round(ms, 3),
-                "frac_of_step": round(ms / step_ms, 3),
-            }
-            for name, ms in times.items()
-        },
-        # host-thread occupancy of the PRIORITY plane per update,
-        # for both settings of config.priority_plane: "host" pays
-        # a numpy tree sample+update on the host critical path
-        # every update; "device" pays only the dispatch of the
-        # in-jit sample/IS/write-back program (the tree math rides
-        # the device stream)
-        "host_ms_per_update": host_ms,
-    }
-
-    # vs_r09: per-phase deltas against the committed round-9 breakdown,
-    # only when the run is apples-to-apples (same batch/core/precision)
-    base = _load_r09_breakdown()
-    if (
-        base
-        and base.get("batch") == B
-        and base.get("precision") == cfg.precision
-        and base.get("core") == report["core"]
-    ):
-        report["vs_r09"] = {
-            "step_ms": round(step_ms - base["value"], 3),
-            "phases": {
-                name: {
-                    "ms": round(ms - base["phases"][name]["ms"], 3),
-                    "frac_of_step": round(
-                        ms / step_ms - base["phases"][name]["frac_of_step"], 3
-                    ),
-                }
-                for name, ms in times.items()
-                if name in base.get("phases", {})
-            },
-        }
-    else:
-        report["vs_r09"] = None
-
-    # vs_r14: same apples-to-apples gating against the round-14 baseline
-    # — the column that shows what the manual-partition round moved
-    # (r14's loss_grad was ~the whole step: frac 1.027)
-    base14 = _load_r14_breakdown()
-    if (
-        base14
-        and base14.get("batch") == B
-        and base14.get("precision") == cfg.precision
-        and base14.get("core") == report["core"]
-    ):
-        report["vs_r14"] = {
-            "step_ms": round(step_ms - base14["value"], 3),
-            "phases": {
-                name: {
-                    "ms": round(ms - base14["phases"][name]["ms"], 3),
-                    "frac_of_step": round(
-                        ms / step_ms - base14["phases"][name]["frac_of_step"], 3
-                    ),
-                }
-                for name, ms in times.items()
-                if name in base14.get("phases", {})
-            },
-        }
-    else:
-        report["vs_r14"] = None
-
-    # largest-model-that-fits per mesh shape (config.MODEL_PRESETS sizing)
-    report["model_fits"] = _model_fits_table(cfg, hbm_gb=hbm_gb)
-
-    print(json.dumps(report))
-
-
 def multitask_main(
     updates: int = 1500,
     collect_per_update: int = 4,
@@ -3118,62 +2806,6 @@ def multitask_main(
     return report
 
 
-def _priority_host_ms(cfg, B: int, iters: int = 200) -> dict:
-    """Host milliseconds per update spent on the priority plane, for
-    priority_plane=host (numpy sum-tree sample + write-back, synchronous
-    on the host critical path) vs =device (deriving the key and
-    dispatching the in-jit sample/IS-weight/write-back program; async —
-    the device executes off the host thread). Measured on a synthetic
-    full tree at the config's exponents."""
-    from functools import partial
-
-    import jax.numpy as jnp
-
-    from r2d2_tpu.replay import device_sum_tree as dst
-    from r2d2_tpu.replay.sum_tree import SumTree
-
-    cap = min(cfg.num_sequences, 1 << 16)
-    rng = np.random.default_rng(0)
-    prios = (rng.random(cap) + 0.1).astype(np.float32)
-
-    host_tree = SumTree(cap, cfg.prio_exponent, cfg.is_exponent)
-    host_tree.update(np.arange(cap), prios)
-    for _ in range(3):  # warm numpy paths
-        idxes, _ = host_tree.sample(B, rng)
-        host_tree.update(idxes, (rng.random(B) + 0.1).astype(np.float32))
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        idxes, _ = host_tree.sample(B, rng)
-        host_tree.update(idxes, (rng.random(B) + 0.1).astype(np.float32))
-    host_ms = (time.perf_counter() - t0) / iters * 1e3
-
-    L = dst.tree_layers(cap)
-
-    @partial(jax.jit, donate_argnums=(0,))
-    def dev_update(tree, key):
-        ks, kp = jax.random.split(key)
-        leaf = dst.tree_sample(tree, L, B, ks)
-        _ = dst.is_weights(tree, L, leaf, cfg.is_exponent)
-        td = jax.random.uniform(kp, (B,), jnp.float32) + 0.1
-        return dst.tree_update(tree, L, leaf, td, cfg.prio_exponent)
-
-    dtree = dst.tree_from_leaves(prios, cap)
-    base = jax.random.PRNGKey(0)
-    dtree = jax.block_until_ready(dev_update(dtree, base))  # compile
-    t0 = time.perf_counter()
-    for i in range(iters):
-        dtree = dev_update(dtree, jax.random.fold_in(base, i))
-    dispatch_ms = (time.perf_counter() - t0) / iters * 1e3
-    jax.block_until_ready(dtree)
-    out = {
-        "priority_plane=host": round(host_ms, 4),
-        "priority_plane=device": round(dispatch_ms, 4),
-    }
-    for k, v in out.items():
-        print(f"[breakdown] priority host ms/update ({k}): {v}", file=sys.stderr)
-    return out
-
-
 if __name__ == "__main__":
     import argparse
 
@@ -3189,7 +2821,7 @@ if __name__ == "__main__":
     p.add_argument(
         "--mode", default="learner",
         choices=["learner", "system", "fused", "long_context", "serve",
-                 "recovery", "breakdown", "scenarios", "liveloop",
+                 "recovery", "scenarios", "liveloop",
                  "multitask", "autoscale", "podloop", "replay-scale"],
         help="learner: fused-update throughput on synthetic replay (the "
              "driver's default metric). system: concurrent on-device "
@@ -3200,9 +2832,7 @@ if __name__ == "__main__":
              "latency percentiles under concurrent stateful sessions with "
              "a mid-window checkpoint hot-reload. recovery: preempt a run "
              "with an injected SIGTERM and measure resume-to-first-update "
-             "wall time (utils/faults.py). breakdown: per-phase learner "
-             "step timing (unroll / head / loss+grad / optimizer as "
-             "separately jitted programs under jax.profiler spans). "
+             "wall time (utils/faults.py). "
              "scenarios: scenario x degradation-rung readiness matrix — "
              "every built-in traffic/chaos scenario (serve/scenarios.py) "
              "against every rung of the graceful-degradation ladder "
@@ -3475,17 +3105,6 @@ if __name__ == "__main__":
         "--replay-scale-out", default="BENCH_r19.json",
         help="replay-scale mode: report JSON path ('' to skip the file)",
     )
-    p.add_argument(
-        "--hbm-gb", type=float, default=16.0,
-        help="breakdown mode: per-device HBM budget for the largest-"
-             "model-that-fits table (analytic; activations not modeled)",
-    )
-    p.add_argument(
-        "--model-preset", default="",
-        help="breakdown mode: grow the benched model via "
-             "config.MODEL_PRESETS (wide/deep/xl/deep_wide) before "
-             "timing — the 'grow the brain' rung",
-    )
     args = p.parse_args()
     enable_compilation_cache()
     precision = args.precision or (
@@ -3499,9 +3118,6 @@ if __name__ == "__main__":
         )
     elif args.mode == "recovery":
         recovery_main(precision)
-    elif args.mode == "breakdown":
-        breakdown_main(args.core, args.lru_chunk, args.batch, precision,
-                       hbm_gb=args.hbm_gb, model_preset=args.model_preset)
     elif args.mode == "serve":
         if args.rate_search:
             serve_rate_search_main(

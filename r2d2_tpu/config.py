@@ -1271,10 +1271,7 @@ PRESETS = {
 # Model-size presets — the "grow the brain" dials (ISSUE 16). Orthogonal to
 # the run PRESETS above: a run preset fixes the task/replay geometry, a
 # model preset scales the net within it. Values are plain field overrides
-# (apply_model_preset), so the resulting config is fully explicit; bench.py
-# --mode breakdown's largest-model-that-fits table sizes each preset's
-# sharded TrainState + backward residuals against per-device HBM for every
-# mesh shape, which is how a preset gets picked for a given slice.
+# (apply_model_preset), so the resulting config is fully explicit.
 MODEL_PRESETS = {
     # historical dims of whatever run preset is active
     "base": {},

@@ -40,6 +40,7 @@ Semantics vs the threaded system mode (both reference-faithful):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Optional
 
 import jax
@@ -53,13 +54,26 @@ from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.utils.profiling import counted, register_program, scoped, span
 
 
-def _stack_coordinates(draws):
-    """K host draws -> the (K, ...) b / s / w device arrays of one dispatch."""
-    return (
-        jnp.asarray(np.stack([d.b for d in draws])),
-        jnp.asarray(np.stack([d.s for d in draws])),
-        jnp.asarray(np.stack([d.is_weights for d in draws])),
-    )
+def _reserve(rings, slots: int) -> list:
+    """Each ring's first reserved slot (callers hold the rings' locks).
+    Reserve BEFORE drawing: it retires the slots' old blocks and advances
+    the ring pointer, so the draws that follow can neither target the
+    in-flight chunk's slots nor produce priority rows the staleness mask
+    would miss."""
+    with span("r2d2.replay.reserve", slots=slots):
+        return [ring._reserve_advance(slots) for ring in rings]
+
+
+def _stack_coordinates(draws, starts=None):
+    """K host draws -> the (K, ...) b / s / w device arrays of one dispatch,
+    and the reserved slot start(s) as a device array (None without a chunk)."""
+    with span("r2d2.dispatch.upload"):
+        return (
+            jnp.asarray(np.stack([d.b for d in draws])),
+            jnp.asarray(np.stack([d.s for d in draws])),
+            jnp.asarray(np.stack([d.is_weights for d in draws])),
+            None if starts is None else jnp.asarray(starts, jnp.int32),
+        )
 
 
 def _priorities_span() -> span:
@@ -258,9 +272,10 @@ class _DeferredDrainRunner:
             collect = self._dispatch_count % self.collect_every == 0
         self._dispatch_count += 1
 
-        # one host span for the whole dispatch; its children (sample, launch,
-        # readback, account, priorities) nest inside it on this thread and
-        # share the `dispatch` id through it
+        # one host span for the whole dispatch; its children (sample with
+        # reserve and draw, launch with upload and call, readback, account,
+        # priorities) nest inside it on this thread and share the `dispatch`
+        # id through it
         with span("r2d2.dispatch", dispatch=self._dispatch_count, collect=int(collect)):
             state, m, prios, draws, token, chunk_host = self._dispatch(state, collect)
 
@@ -277,7 +292,8 @@ class _DeferredDrainRunner:
     def _launch(self, program, collect: bool, *args):
         """The jitted call, and the start of this dispatch's readbacks
         (async: collected next call, while the next dispatch executes)."""
-        out = program(*args)
+        with span("r2d2.dispatch.call", program=program.name):
+            out = program(*args)
         _start_async_copy((out[3], out[4]) if collect else out[2])
         return out
 
@@ -390,20 +406,17 @@ class FusedSystemRunner(_DeferredDrainRunner):
         with replay.lock:
             with span("r2d2.replay.sample"):
                 if collect:
-                    # reserve BEFORE drawing: retires the slots' old blocks and
-                    # advances the ring pointer, so the draws below can neither
-                    # target the in-flight chunk's slots nor produce priority
-                    # rows the staleness mask would miss
-                    ptr0 = replay._reserve_advance(self.E)
-                draws = [replay._draw_sample_idx(self.replay_rng) for _ in range(self.K)]
+                    (ptr0,) = _reserve([replay], self.E)
+                with span("r2d2.replay.draw", k=self.K):
+                    draws = [replay._draw_sample_idx(self.replay_rng) for _ in range(self.K)]
             with span("r2d2.dispatch.launch"):
-                b, s, w = _stack_coordinates(draws)
+                b, s, w, start = _stack_coordinates(draws, ptr0)
                 if collect:
                     (state, new_stores, m, prios, chunk_host, self.env_state, self.key) = (
                         self._launch(
                             self._mega, True,
                             state, replay.stores, self.env_state, self.epsilons,
-                            self.key, b, s, w, jnp.int32(ptr0),
+                            self.key, b, s, w, start,
                         )
                     )
                     replay.stores = new_stores
@@ -598,25 +611,23 @@ class ShardedFusedRunner(_DeferredDrainRunner):
                     lk.acquire()
                 try:
                     if collect:
-                        starts = np.asarray(
-                            [sh._reserve_advance(self.E_local) for sh in replay.shards],
-                            np.int32,
-                        )
-                    draws = [
-                        replay.sample_indices(self.replay_rng, locked=True)
-                        for _ in range(self.K)
-                    ]
+                        starts = _reserve(replay.shards, self.E_local)
+                    with span("r2d2.replay.draw", k=self.K):
+                        draws = [
+                            replay.sample_indices(self.replay_rng, locked=True)
+                            for _ in range(self.K)
+                        ]
                 finally:
                     for lk in reversed(locks):
                         lk.release()
             with span("r2d2.dispatch.launch"):
-                b, s, w = _stack_coordinates(draws)
+                b, s, w, starts_dev = _stack_coordinates(draws, starts)
                 if collect:
                     (state, new_stores, m, prios, chunk_host,
                      self.env_state, self.keys) = self._launch(
                         self._mega, True,
                         state, replay.stores, self.env_state, self.epsilons,
-                        self.keys, b, s, w, jnp.asarray(starts),
+                        self.keys, b, s, w, starts_dev,
                     )
                     replay.stores = new_stores
                 else:
@@ -765,26 +776,31 @@ class MultiHostFusedRunner(_DeferredDrainRunner):
         from jax.sharding import PartitionSpec as P
 
         replay = self.replay
-        starts_d = chunk_host = None
+        starts_d = chunk_host = None  # starts_d: {local shard: first reserved slot}
         with replay.lock:
             with span("r2d2.replay.sample"):
                 if collect:
-                    starts_d, per_start = {}, {}
-                    for g in replay.local_ids:
-                        sh = replay.shards[g]
-                        with sh.lock:
-                            starts_d[g] = sh._reserve_advance(self.E_local)
-                        per_start[g] = jax.device_put(
-                            # host int -> tiny per-shard upload, once per chunk
-                            np.asarray([starts_d[g]], np.int32),  # r2d2: disable=host-sync-in-hot-path
-                            replay._shard_device[g],
-                        )
-                    starts = replay._assemble(per_start, (self.dp,), P("dp"))
+                    rings = [replay.shards[g] for g in replay.local_ids]
+                    with contextlib.ExitStack() as held:
+                        for ring in rings:
+                            held.enter_context(ring.lock)
+                        starts_d = dict(zip(replay.local_ids, _reserve(rings, self.E_local)))
                 # the draws AND their upload (sample_global_k assembles the
-                # global coordinate arrays): this plane's sample span holds both
-                (b, s, w), draws = replay.sample_global_k(self.K)
+                # global coordinate arrays): this plane's draw span holds both
+                with span("r2d2.replay.draw", k=self.K):
+                    (b, s, w), draws = replay.sample_global_k(self.K)
             with span("r2d2.dispatch.launch"):
                 if collect:
+                    with span("r2d2.dispatch.upload"):
+                        per_start = {
+                            g: jax.device_put(
+                                # host int -> tiny per-shard upload, once per chunk
+                                np.asarray([start], np.int32),  # r2d2: disable=host-sync-in-hot-path
+                                replay._shard_device[g],
+                            )
+                            for g, start in starts_d.items()
+                        }
+                        starts = replay._assemble(per_start, (self.dp,), P("dp"))
                     (state, new_stores, m, prios, chunk_host,
                      self.env_state, self.keys) = self._launch(
                         self._mega, True,

@@ -1326,9 +1326,11 @@ class Trainer:
     def _dispatch_host_row(self) -> dict:
         """Host ms per fused dispatch since the last metrics row, from the
         span aggregates and counters (the operator's view of
-        utils/profiling.SPANS): draw, readback wait, everything but the wait,
-        and the share of priority rows the staleness mask let through. Empty
-        off the fused path."""
+        utils/profiling.SPANS): draw, readback wait, everything but the wait
+        by wall and by the thread's CPU clock (the difference is time the
+        dispatch thread was not running), the interpreter's collections, and
+        the share of priority rows the staleness mask let through. Empty off
+        the fused path."""
         now, mark = profiling.counters(), self._span_mark
         self._span_mark = now
 
@@ -1339,14 +1341,18 @@ class Trainer:
         if n <= 0:
             return {}
 
-        def ms(name: str) -> float:
-            return grown(name + ".total_ns") / n / 1e6
+        def ms(name: str, clock: str = "total_ns") -> float:
+            return grown(f"{name}.{clock}") / n / 1e6
 
         wait = ms("r2d2.dispatch.readback")
         row = {
             "host_sample_ms": round(ms("r2d2.replay.sample"), 3),
             "host_readback_ms": round(wait, 3),
             "host_busy_ms": round(ms("r2d2.dispatch") - wait, 3),
+            "host_cpu_ms": round(
+                ms("r2d2.dispatch", "cpu_ns") - ms("r2d2.dispatch.readback", "cpu_ns"), 3
+            ),
+            "host_gc_ms": round(ms("r2d2.host.gc"), 3),
         }
         offered = grown("replay.priority_rows_offered")
         if offered:

@@ -9,8 +9,14 @@ rates printed every 10 s (worker.py:126,135). Here:
   and written into the profiler's own trace, so it shares the device
   events' clock by construction; `ids` become the event's stats and tie one
   dispatch's or one batch's spans together) plus an always-on aggregate per
-  name (count, total ns). Nothing is kept per event. `spanned(name)` is the
-  same as a decorator.
+  name (count, total ns, CPU ns of the thread). At close the event is stamped
+  `cpu_us`: wall less CPU is the time the thread was not running (a lock, the
+  GIL, the kernel, a device queue). Nothing is kept per event. `spanned(name)`
+  is the same as a decorator.
+- `r2d2.host.gc` is the interpreter's own collections as a span, opened and
+  closed from two `gc.callbacks` entries on the thread that triggered the
+  collection, so it nests under whatever span is open there. It observes
+  only: no threshold, freeze or disable.
 - `count(name, n)` counts where the work happens; `counters()` returns the
   counts and the span aggregates as one flat dict. Readers: the benchmark's
   `program_counter` reader and the trainer's metrics row (host ms per dispatch).
@@ -40,6 +46,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import gc
 import re
 import threading
 import time
@@ -58,8 +65,12 @@ _server = None
 SPANS: Dict[str, tuple] = {
     # host spans
     "r2d2.dispatch": ("dispatch", "one fused dispatch, whole body of runner.step (ids: dispatch, collect)"),
-    "r2d2.replay.sample": ("replay", "lock(s), slot reservation and the K coordinate draws of one dispatch"),
-    "r2d2.dispatch.launch": ("dispatch", "stacking b/s/w, their upload, the jitted call, the async readback kick-off"),
+    "r2d2.replay.sample": ("replay", "reserve + draw and, as self time, taking the shard locks (multihost: the draws' upload is inside draw)"),
+    "r2d2.replay.reserve": ("replay", "the _reserve_advance call(s) of a collecting dispatch (ids: slots)"),
+    "r2d2.replay.draw": ("replay", "the K coordinate draws of one dispatch with their IS-weight arithmetic (ids: k)"),
+    "r2d2.dispatch.launch": ("dispatch", "upload + call and, as self time, the async readback kick-off and installing the new stores"),
+    "r2d2.dispatch.upload": ("dispatch", "stacking b/s/w and making them and the slot start(s) device arrays (multihost: the slot starts only)"),
+    "r2d2.dispatch.call": ("dispatch", "the jitted call alone: argument handling, placement of uncommitted arguments, the enqueue (ids: program)"),
     "r2d2.dispatch.readback": ("dispatch", "np.asarray of the previous dispatch's priorities / chunk bookkeeping: the host waits for the device here"),
     "r2d2.replay.account": ("replay", "installing a drained chunk's blocks into the tree(s)"),
     "r2d2.replay.priorities": ("replay", "the K priority rows of the previous dispatch applied to the tree(s) (ids: offered, applied = the two counters below as the span opens)"),
@@ -68,6 +79,7 @@ SPANS: Dict[str, tuple] = {
     "r2d2.setup.ring_fill": ("CLIs", "Trainer.warmup: collection until sampling opens"),
     "r2d2.serve.stage": ("serve", "stage + dispatch of one batch on the serve thread (ids: batch, rows, queue_wait_us)"),
     "r2d2.serve.complete": ("serve", "materialize q/action, resolve futures, retire the batch (ids: batch)"),
+    "r2d2.host.gc": ("dispatch", "one collection of the interpreter's garbage collector, on the thread that triggered it (ids: generation; collected at close)"),
     # step-correlated spans of the run loops (StepTraceAnnotation)
     "r2d2.step.update": ("run loop", "one learner update of the threaded/inline planes"),
     "r2d2.step.megastep": ("run loop", "one fused dispatch as Trainer.run_fused issues it"),
@@ -85,9 +97,10 @@ SPANS: Dict[str, tuple] = {
     "replay.priority_rows_applied": ("replay", "of those, rows the staleness mask let through to the tree"),
 }
 
-# name -> [count, total ns]; plain counts beside them. Single-writer
+# name -> [count, total ns, cpu ns]; plain counts beside them. Single-writer
 # per name on every hot path (the dispatch loop; one serve thread per span
-# name; counts under the replay lock), so no lock of their own.
+# name; counts under the replay lock; the collector does not re-enter), so no
+# lock of their own.
 _agg: Dict[str, list] = {}
 _counts: Dict[str, float] = {}
 
@@ -187,10 +200,11 @@ stop_trace = jax.profiler.stop_trace
 
 
 class span:
-    """Named host span: a TraceAnnotation (ids -> the event's stats) plus the
-    always-on aggregate of its name."""
+    """Named host span: a TraceAnnotation (ids -> the event's stats; `cpu_us`,
+    the thread's CPU time inside it, stamped at close) plus the always-on
+    aggregate of its name."""
 
-    __slots__ = ("_name", "_ann", "_t0")
+    __slots__ = ("_name", "_ann", "_t0", "_cpu0")
 
     def __init__(self, name: str, **ids):
         self._name = name
@@ -199,16 +213,23 @@ class span:
     def __enter__(self):
         self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
+        self._cpu0 = time.thread_time_ns()
         return self
 
     def __exit__(self, *exc):
+        # read inside the wall interval, so cpu <= wall on a fine clock; where
+        # the clock ticks (gVisor: 10 ms) a span reads whole ticks or none,
+        # and only a sum over many spans is a time
+        cpu = time.thread_time_ns() - self._cpu0
         dt = time.perf_counter_ns() - self._t0
+        self._ann.set_metadata(cpu_us=cpu / 1e3)
         self._ann.__exit__(*exc)
         a = _agg.get(self._name)
         if a is None:
-            a = _agg[_known(self._name)] = [0, 0]
+            a = _agg[_known(self._name)] = [0, 0, 0]
         a[0] += 1
         a[1] += dt
+        a[2] += cpu
         return False
 
 
@@ -247,13 +268,45 @@ def counted(name: str) -> float:
 
 
 def counters() -> Dict[str, float]:
-    """Every count, and `<span>.count|.total_ns` of every span that has run
-    in this process, as one flat dict."""
+    """Every count, and `<span>.count|.total_ns|.cpu_ns` of every span that
+    has run in this process, as one flat dict."""
     out: Dict[str, float] = dict(_counts)
-    for name, (n, total) in list(_agg.items()):
+    for name, (n, total, cpu) in list(_agg.items()):
         out[name + ".count"] = n
         out[name + ".total_ns"] = total
+        out[name + ".cpu_ns"] = cpu
     return out
+
+
+# ------------------------------------------------ the interpreter's own pauses
+
+_gc_open: list = []  # the collection in progress: the collector does not re-enter
+
+
+def _gc_span_start(phase: str, info: dict) -> None:
+    if phase == "start":
+        s = span("r2d2.host.gc", generation=info["generation"])
+        s.__enter__()
+        _gc_open.append(s)
+
+
+def _gc_span_stop(phase: str, info: dict) -> None:
+    if phase == "stop" and _gc_open:
+        s = _gc_open.pop()
+        s._ann.set_metadata(collected=info["collected"])
+        s.__exit__(None, None, None)
+
+
+def _install_gc_span() -> None:
+    """Once per process, however often this module is loaded: the start
+    handler first in `gc.callbacks` and the stop handler last, so that every
+    other callback (jax's own among them) runs inside the span."""
+    if not any(getattr(cb, "__module__", None) == __name__ for cb in gc.callbacks):
+        gc.callbacks.insert(0, _gc_span_start)
+        gc.callbacks.append(_gc_span_stop)
+
+
+_install_gc_span()
 
 
 # ------------------------------------------------------------ device scopes

@@ -18,6 +18,9 @@ K = 2
 DISPATCHES = 10
 DISPATCH_CHILDREN = ["r2d2.replay.sample", "r2d2.dispatch.launch", "r2d2.dispatch.readback",
                      "r2d2.replay.account", "r2d2.replay.priorities"]
+# part -> the child of the dispatch it sits in (PR 42)
+PARTS = {"r2d2.replay.reserve": "r2d2.replay.sample", "r2d2.replay.draw": "r2d2.replay.sample",
+         "r2d2.dispatch.upload": "r2d2.dispatch.launch", "r2d2.dispatch.call": "r2d2.dispatch.launch"}
 UPDATE_SCOPES = ["r2d2_update", "r2d2_gather", "r2d2_loss", "r2d2_optimizer"]
 
 
@@ -61,6 +64,21 @@ def fused_run(tmp_path_factory):
     return _events(trace_dir), delta, rows
 
 
+@pytest.fixture(scope="module")
+def sharded_run(tmp_path_factory):
+    """The same on the sharded plane, dp=4 on virtual devices: the trainer
+    after its run, and the spans it wrote."""
+    from r2d2_tpu.train import Trainer
+
+    tmp = tmp_path_factory.mktemp("sharded")
+    trace_dir = str(tmp / "prof")
+    cfg = _cfg(tmp, replay_plane="sharded", dp_size=4, buffer_capacity=2560,
+               training_steps=6 * K, metrics_path=None)
+    tr = Trainer(cfg, profile_dir=trace_dir, profile_steps=5 * K)
+    tr.run_fused()
+    return tr, _events(trace_dir)
+
+
 @pytest.mark.parametrize("name", ["r2d2.dispatch"] + DISPATCH_CHILDREN)
 def test_a_traced_fused_run_yields_every_host_span_of_the_table(fused_run, name):
     events, _, _ = fused_run
@@ -83,6 +101,35 @@ def test_children_nest_inside_their_dispatch_and_share_its_id(fused_run):
     inside = {n: (s, e) for n, _, s, e in events if ds <= s and e <= de}
     assert inside["r2d2.dispatch.launch"][1] <= inside["r2d2.dispatch.readback"][0]
     assert inside["r2d2.replay.sample"][1] <= inside["r2d2.dispatch.launch"][0]
+
+
+@pytest.mark.parametrize("plane", ["device", "sharded"])
+def test_the_sample_and_launch_spans_have_their_parts_at_the_same_boundaries(plane, fused_run, sharded_run):
+    """FusedSystemRunner and ShardedFusedRunner carry the same names: reserve
+    on collecting dispatches only, draw, upload and call once per dispatch,
+    each inside its parent, and the call says which step program it was."""
+    events = fused_run[0] if plane == "device" else sharded_run[1]
+    dispatches = [(int(st["collect"]), s, e) for n, st, s, e in events if n == "r2d2.dispatch"]
+    assert {c for c, *_ in dispatches} == {0, 1}
+    for collect, ds, de in dispatches:
+        inside = [(n, st, s, e) for n, st, s, e in events if ds <= s and e <= de]
+        got = [n for n, *_ in inside if n in PARTS]
+        want = ["r2d2.replay.draw", "r2d2.dispatch.upload", "r2d2.dispatch.call"]
+        assert sorted(got) == sorted(want + ["r2d2.replay.reserve"] * collect), (collect, got)
+        for n, st, s, e in inside:
+            if n in PARTS:
+                (ps, pe), = [(s2, e2) for n2, _, s2, e2 in inside if n2 == PARTS[n]]
+                assert ps <= s and e <= pe, n
+                assert "cpu_us" in st
+            if n == "r2d2.dispatch.call":
+                assert st["program"] == ("mega" if collect else "multi")
+            if n == "r2d2.replay.draw":
+                assert int(st["k"]) == K
+            if n == "r2d2.replay.reserve":
+                assert int(st["slots"]) >= 1
+        # in order on the one thread: reserve, draw | upload, call
+        order = [n for n, _, s, _ in sorted(inside, key=lambda ev: ev[2]) if n in PARTS]
+        assert order == ["r2d2.replay.reserve"] * collect + want
 
 
 def test_priority_rows_are_counted_where_they_are_applied(fused_run):
@@ -111,6 +158,10 @@ def test_setup_is_read_through_the_aggregates(fused_run):
     # every dispatch's priorities are drained once: one dispatch later, the last by finish()
     assert delta["r2d2.replay.priorities.count"] == DISPATCHES
     assert delta["r2d2.replay.sample.count"] == delta["r2d2.dispatch.launch.count"] == DISPATCHES
+    # the parts, once a dispatch (PR 42); the reserve on collecting dispatches only
+    for part in ("r2d2.replay.draw", "r2d2.dispatch.upload", "r2d2.dispatch.call"):
+        assert delta[part + ".count"] == DISPATCHES and 0 < delta[part + ".cpu_ns"] <= 1.03 * delta[part + ".total_ns"]
+    assert 0 < delta["r2d2.replay.reserve.count"] < DISPATCHES
 
 
 def test_the_metrics_row_carries_host_ms_per_dispatch(fused_run):
@@ -119,6 +170,8 @@ def test_the_metrics_row_carries_host_ms_per_dispatch(fused_run):
     assert with_host
     for r in with_host:
         assert r["host_busy_ms"] >= r["host_sample_ms"] >= 0.0 and r["host_readback_ms"] >= 0.0
+        # by the thread's CPU clock the same spans read no more than by wall time (two clocks: 3 %)
+        assert 0.0 < r["host_cpu_ms"] <= 1.03 * r["host_busy_ms"] + 1e-3 and r["host_gc_ms"] >= 0.0
     shares = [r["priority_applied_pct"] for r in rows if "priority_applied_pct" in r]
     assert shares and all(0.0 < s <= 100.0 for s in shares)
 
@@ -143,16 +196,11 @@ def test_the_step_programs_carry_every_device_scope(fused_run, program, scopes):
     assert any("transpose(jvp(jit(r2d2_loss)))" in v or "jvp(jit(r2d2_loss))" in v for v in names.values())
 
 
-def test_the_sharded_step_programs_carry_the_scopes_too(tmp_path):
+def test_the_sharded_step_programs_carry_the_scopes_too(sharded_run):
     """dp=4 on virtual devices: the psum inside r2d2_optimizer and the slab
     write run as named inner jits under shard_map."""
-    from r2d2_tpu.train import Trainer
-
-    cfg = _cfg(tmp_path, replay_plane="sharded", dp_size=4, buffer_capacity=2560,
-               training_steps=3 * K, metrics_path=None)
-    tr = Trainer(cfg)
-    tr.run_fused()
-    assert int(np.asarray(tr.state.step)) == 3 * K
+    tr, _ = sharded_run
+    assert int(np.asarray(tr.state.step)) == 6 * K
     names = profiling.program_scopes("mega")
     for scope in UPDATE_SCOPES + ["r2d2_collect", "r2d2_slab_write"]:
         assert any(f"jit({scope})" in v for v in names.values()), scope

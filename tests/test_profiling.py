@@ -3,7 +3,9 @@ aggregate always, ids reach the trace as stats, device scopes reach the
 executable's op_name AND the compilation cache's key, and a bounded trainer
 trace lands on disk."""
 
+import gc
 import glob
+import importlib
 import os
 import subprocess
 import sys
@@ -28,6 +30,13 @@ def _host_events(trace_dir, prefix="r2d2."):
     return [(e.name, dict(e.stats), e.start_ns, e.duration_ns)
             for plane in data.planes for line in plane.lines for e in line.events
             if e.name.startswith(prefix)]
+
+
+def _spin(seconds):
+    """Keep this thread on the CPU for `seconds` of its own CPU clock."""
+    t_end = time.thread_time() + seconds
+    while time.thread_time() < t_end:
+        pass
 
 
 def test_spans_are_noops_when_idle():
@@ -169,6 +178,89 @@ def test_ids_become_the_events_stats_and_children_nest(tmp_path):
     assert int(stats["dispatch"]) == 7 and int(stats["collect"]) == 1
     _, s1, d1 = ev["r2d2.dispatch.readback"]
     assert s0 <= s1 and s1 + d1 <= s0 + d0
+
+
+def test_a_closed_span_carries_cpu_us_beside_its_ids_and_its_children_still_nest(tmp_path):
+    """`cpu_us` is stamped at close (TraceAnnotation.set_metadata), so it sits
+    beside the ids given at open; wall less CPU is time the thread did not run."""
+    d = str(tmp_path / "trace")
+    profiling.start_trace(d)
+    with span("r2d2.dispatch", dispatch=7, collect=1):
+        with span("r2d2.dispatch.readback"):
+            time.sleep(0.02)
+        with span("r2d2.dispatch.call", program="mega"):
+            _spin(0.02)
+    profiling.stop_trace()
+    ev = {n: (stats, s, dur) for n, stats, s, dur in _host_events(d)}
+    stats, s0, d0 = ev["r2d2.dispatch"]
+    assert int(stats["dispatch"]) == 7 and int(stats["collect"]) == 1
+    for child in ("r2d2.dispatch.readback", "r2d2.dispatch.call"):
+        _, s1, d1 = ev[child]
+        assert s0 <= s1 and s1 + d1 <= s0 + d0
+    assert ev["r2d2.dispatch.call"][0]["program"] == "mega"
+    cpu = {n: float(ev[n][0]["cpu_us"]) for n in ev}
+    assert cpu["r2d2.dispatch.readback"] < 5e3          # asleep: off the CPU
+    assert 19e3 <= cpu["r2d2.dispatch.call"] < 40e3     # a busy loop: on it
+    assert cpu["r2d2.dispatch.call"] <= ev["r2d2.dispatch.call"][2] / 1e3 * 1.03  # CPU within wall (two clocks)
+    assert cpu["r2d2.dispatch"] >= cpu["r2d2.dispatch.call"] + cpu["r2d2.dispatch.readback"]
+
+
+def test_the_aggregate_keeps_cpu_ns_beside_total_ns():
+    def grown(fn):
+        before = counters()
+        with span("r2d2.replay.account"):
+            fn()
+        after = counters()
+        return [after[f"r2d2.replay.account.{k}"] - before.get(f"r2d2.replay.account.{k}", 0)
+                for k in ("count", "total_ns", "cpu_ns")]
+
+    n, wall, cpu = grown(lambda: _spin(0.03))
+    assert n == 1 and 29e6 <= cpu <= wall * 1.03 and cpu < 60e6
+    n, wall, cpu = grown(lambda: time.sleep(0.05))
+    assert n == 1 and wall >= 49e6 and cpu < wall / 5
+
+
+def test_a_collection_under_an_open_span_is_one_gc_span_inside_it(tmp_path):
+    d = str(tmp_path / "trace")
+    junk = [[] for _ in range(1000)]
+    for a, b in zip(junk, junk[1:]):
+        a.append(b), b.append(a)  # cycles: only the collector frees them
+    del junk, a, b
+    gc.disable()  # no collection of the allocator's own choosing inside the traced span
+    try:
+        before = counters().get("r2d2.host.gc.count", 0)
+        profiling.start_trace(d)
+        with span("r2d2.replay.priorities", offered=0, applied=0):
+            gc.collect()
+        profiling.stop_trace()
+    finally:
+        gc.enable()
+    ev = _host_events(d)
+    (_, _, s0, d0), = [e for e in ev if e[0] == "r2d2.replay.priorities"]
+    (_, stats, s1, d1), = [e for e in ev if e[0] == "r2d2.host.gc"]
+    assert s0 <= s1 and s1 + d1 <= s0 + d0
+    assert int(stats["generation"]) == 2 and int(stats["collected"]) >= 1000 and "cpu_us" in stats
+    assert counters()["r2d2.host.gc.count"] == before + 1
+    assert not profiling._gc_open  # closed: nothing left open for the next collection
+
+
+def test_the_gc_callbacks_are_installed_once_around_every_other_callback(tmp_path):
+    """The start handler first and the stop handler last, so that jax's own
+    collection callback runs inside the span; however often the module is
+    imported, the installer called or a Trainer built."""
+    from r2d2_tpu.config import tiny_test
+    from r2d2_tpu.train import Trainer
+
+    def ours():
+        return [cb for cb in gc.callbacks if getattr(cb, "__module__", None) == profiling.__name__]
+
+    assert ours() == [profiling._gc_span_start, profiling._gc_span_stop]
+    importlib.import_module("r2d2_tpu.utils.profiling")
+    profiling._install_gc_span()
+    Trainer(tiny_test().replace(env_name="catch", checkpoint_dir=str(tmp_path / "ckpt")))
+    assert ours() == [profiling._gc_span_start, profiling._gc_span_stop]
+    assert gc.callbacks[0] is profiling._gc_span_start and gc.callbacks[-1] is profiling._gc_span_stop
+    assert len(gc.callbacks) >= 3  # jax's own is between them
 
 
 def test_program_scopes_finds_the_scope_of_a_registered_program():
