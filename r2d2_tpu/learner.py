@@ -287,8 +287,10 @@ def make_store_gather(cfg: R2D2Config, as_stored: bool = False):
 
     `obs` is canonical (B, T, *obs_shape) frames whatever order the store
     keeps a frame's bytes in (replay/block.py). as_stored=True hands the
-    frames back in the store's own order, (B, T, *blocked_shape): a reshape
-    of the gathered rows and nothing else. The step programs ask for that;
+    frames back in the store's own order, (B, T, *blocked_shape): a slice and
+    a reshape of the gathered rows with (B, T) merged (replay/block.
+    rows_as_stored: the frame index stays one axis from this gather to the
+    first conv, which merges (B, T) itself). The step programs ask for that;
     the encoder takes either (models/encoders.BlockedConv).
 
     A sampled sequence is one window of its slot. accumulator.finish stores

@@ -24,7 +24,9 @@ Within its rows a frame's bytes stand in the order the encoder's first conv
 reads them: blocks of `cfg.resolved_frame_block` (models/encoders.frame_block;
 4 for the Nature trunk on 84x84, where a frame is 21 x 21 blocks of 16 bytes
 and the conv a 2x2/1 conv over 16 channels; 1 = the frame as it is). The step
-programs reshape gathered rows to the conv's input and nothing else.
+programs hand the conv gathered rows by `rows_as_stored`: a slice and a
+reshape over ONE merged frame axis, so that the chip re-tiles the batch once,
+in uint8, between the gather and the conv.
 `frames_to_rows` / `rows_to_frames` own that order: every writer and reader of
 a device store goes through them with the config's block.
 Blocks, the host ReplayBuffer, the disk tier and snapshot files keep frames.
@@ -107,13 +109,22 @@ def frames_to_rows(frames, obs_shape, block: int = 1):
 
 def rows_as_stored(rows, obs_shape, block: int = 1):
     """(..., R, 128) -> (..., *blocked_shape): the frames in the order the
-    rows keep them, by a slice and a reshape alone."""
+    rows keep them, by a slice and a reshape alone.
+
+    The rows become bytes with every leading axis MERGED, and only the result
+    has `lead` again: flattened under (B, T), the chip's compiler tiled the
+    batch's bytes over (T, bytes), 32 frames of one row to a tile, and the
+    model's own merge of (B, T) before the first conv then cost three more
+    passes over the batch, two of them at bf16's two bytes. Merged here, the
+    frame index is one axis from the store gather to the conv and the batch is
+    re-tiled once, in uint8 (PERF.md finding 43). The values are the same for
+    every `lead`, `()` and `(N,)` included."""
     obs_shape = tuple(obs_shape)
     n, R = int(np.prod(obs_shape)), obs_rows(obs_shape)
     if rows.shape[-2:] != (R, LANES):
         raise ValueError(f"rows {rows.shape} do not end in {(R, LANES)}")
     lead = rows.shape[:-2]
-    return rows.reshape(*lead, R * LANES)[..., :n].reshape(*lead, *blocked_shape(obs_shape, block))
+    return rows.reshape(-1, R * LANES)[:, :n].reshape(*lead, *blocked_shape(obs_shape, block))
 
 
 def rows_to_frames(rows, obs_shape, block: int = 1):

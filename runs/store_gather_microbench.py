@@ -141,7 +141,7 @@ def main(argv=None) -> int:
             """`keys` of fn's batch, the frames as rows_as_stored makes them less its last reshape."""
             def run(*a):
                 out = fn(*a)
-                out["obs"] = out["obs"].reshape(B, T, -1)[..., :n_bytes]
+                out["obs"] = out["obs"].reshape(B * T, -1)[:, :n_bytes]  # (B, T) merged: PR 43
                 return {k: out[k] for k in keys}
             return run
 

@@ -125,6 +125,41 @@ def test_nature_encoder_keeps_its_parameter_tree_and_takes_both_orders():
         np.asarray(enc.apply(got, block_frames(x, (84, 84, 1), 4))), np.asarray(enc.apply(got, x)))
 
 
+# ------------------------------------- (b) rows -> frames, whatever the lead
+
+
+def _parents_rows_as_stored(rows, obs_shape, block):
+    """`rows_as_stored` until PR 43: the rows flattened UNDER their leading
+    axes (the chip tiled that over (T, bytes); PERF.md finding 43)."""
+    n, R = int(np.prod(obs_shape)), rows.shape[-2]
+    lead = rows.shape[:-2]
+    return rows.reshape(*lead, R * 128)[..., :n].reshape(*lead, *blocked_shape(obs_shape, block))
+
+
+@pytest.mark.parametrize("array", ["numpy", "jax"])
+@pytest.mark.parametrize("block", [1, 4])
+@pytest.mark.parametrize("lead", [(), (5,), (3, 5), (2, 3, 5)], ids=lambda l: "x".join(map(str, l)) or "scalar")
+def test_rows_as_stored_merges_the_leading_axes_and_keeps_every_byte(lead, block, array):
+    """PR 43 merges every leading axis before the rows become bytes. The
+    result is the parent's formula byte for byte and shape for shape, for the
+    host planes' `()` and `(N,)` as for the step programs' `(B, T)`, numpy in
+    numpy out, and `rows_to_frames` still inverts `frames_to_rows`."""
+    from r2d2_tpu.replay.block import frames_to_rows, obs_rows
+
+    frames = np.random.default_rng(len(lead) + block).integers(0, 256, (*lead, *OBS), dtype=np.uint8)
+    rows = frames_to_rows(frames, OBS, block)
+    assert rows.shape == (*lead, obs_rows(OBS), 128) and 36 * 36 % 128  # a padded tail to slice off
+    given = rows if array == "numpy" else jnp.asarray(rows)
+    got = rows_as_stored(given, OBS, block)
+    assert isinstance(got, np.ndarray if array == "numpy" else jax.Array)
+    assert got.shape == (*lead, *blocked_shape(OBS, block)) and got.dtype == np.uint8
+    np.testing.assert_array_equal(np.asarray(got), _parents_rows_as_stored(rows, OBS, block))
+    np.testing.assert_array_equal(np.asarray(got), numpy_blocked(frames, block) if block > 1 else frames)
+    np.testing.assert_array_equal(np.asarray(rows_to_frames(given, OBS, block)), frames)
+    with pytest.raises(ValueError, match="do not end in"):
+        rows_as_stored(given[..., :-1, :], OBS, block)
+
+
 # ------------------------------------------------- (c) writers and gathers
 
 
@@ -321,21 +356,26 @@ def _step_program_texts(cfg):
 # 18bb6d7), which is what the name says: PR 38 left all six as they were.
 # Taken again in PR 41, whose store gather is every preset's (the scalar
 # fields read as windows, learner._windows: all six moved, +26 k to +56 k characters
-# each) and which changed nothing else in them. A jax release that prints a
-# jaxpr differently moves every row at once: take them again from a tree known
-# to be good
+# each) and which changed nothing else in them. Taken again in PR 43, whose
+# `rows_as_stored` is every preset's too: the gathered rows are flattened with
+# (B, T) merged, so in each program ONE reshape's `new_sizes`, ONE slice's
+# `limit_indices` / `start_indices` and their two results lose an axis
+# (`u8[8,10,256]` -> `u8[80,256]` in tiny_test) and every row is 3 (IMPALA) or
+# 13 characters shorter; a token-by-token diff of parent against change shows
+# those and nothing else. A jax release that prints a jaxpr differently moves
+# every row at once: take them again from a tree known to be good
 PARENT_PROGRAMS = {
     "procgen_impala": {
-        "mega": (615074, "6a19d279088fb2c90488597e556f993853ba49811159ef9a72948667a81a3511"),
-        "multi": (454988, "9bb0612f2d6bd056b112033278419c97845ebb14e483226b9c08bacf98b6a0d7"),
+        "mega": (615071, "61d1df5ea66b4e73667c67717393ae1139c9b7cc6bc6e0502360fa55a2134695"),
+        "multi": (454985, "dad61a7a016b0cd53d40c461fc73e38d7b4f92ecf85bfa2d39a2076488b6cb37"),
     },
     "tiny_test": {
-        "mega": (241909, "12797b2b4720480aa6d956fb3546ed9a6a2a494fb181e1531e416019370cf7f2"),
-        "multi": (176818, "9b2136999f535521b590080805c515fa511a44cc3a5ee8bce602faa10052c7c8"),
+        "mega": (241896, "fc2645200d7a5718376afc36657252f2659f235839759fa8e925f6491b69a3a8"),
+        "multi": (176805, "8142d5d398ce761455c9d9d75d9ac4bb82d86727861165d766a375e07f572e6c"),
     },
     "tiny_test-deep-bf16": {
-        "mega": (279954, "2b0378fc63f7acfe907ec90e9118634ee5aaf9494044f38ad45125d99f30445c"),
-        "multi": (206949, "f01c1a88a5177b6f6eead8c4365b77238c878320c8743bfb711ef52c3ce28488"),
+        "mega": (279941, "5f923d92c8244217332c147a90c847ff2106112391d29eed0badfa8f92b31a72"),
+        "multi": (206936, "ddde92d7babf24704509079aa0038d391a2fc707596891e57a12ff64f2422110"),
     },
 }
 

@@ -343,6 +343,72 @@ def test_conv1s_blocked_backward_filter_compiles_in_seconds_at_every_row_count(r
     assert seconds < 120, seconds
 
 
+def _rehearsal():
+    spec = importlib.util.spec_from_file_location(
+        "rehearse_step_programs", os.path.join(ROOT, "runs", "rehearse_step_programs.py"))
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+    return rehearse
+
+
+def _hand_over_passes(text, B, T, frame_bytes):
+    """Of a compiled step program's text: every `u8[B,T,bytes]` shape in it
+    (the batch's bytes tiled over (T, bytes)), and every instruction between
+    the store gather and conv1 (op_name under `r2d2_gather` or `_core_input`,
+    not under `enc/`), outside the fusions' bodies, that writes a bf16 array of
+    B x T x frame_bytes elements or more: a pass over the whole batch at two
+    bytes. A bitcast moves nothing and is not one."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    tiled = sorted(set(re.findall(rf"\bu8\[{B},{T},\d+\]", text)))
+    inside, passes = None, []
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = bf16\[([\d,]+)\]\S* ([a-z][\w\-]*)\(", line)
+        if not m or inside in fused or m.group(3) in ("bitcast", "parameter", "get-tuple-element"):
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        op = op.group(1) if op else ""
+        if re.search(r"jit\(r2d2_gather\)|_core_input", op) and "/enc/" not in op:
+            if math.prod(map(int, m.group(2).split(","))) >= B * T * frame_bytes:
+                passes.append(m.group(1))
+    return tiled, passes
+
+
+@pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581", "nature-lstm512-dp4"])
+def test_the_batch_reaches_conv1_with_the_frame_index_as_one_axis(config, topo, compiled_kernels):
+    """A configuration's update-only step program (`jit_multi`; dp4: the
+    shard_map body, 16 rows a chip) at real size: `replay/block.rows_as_stored`
+    turns the gathered rows into bytes with (B, T) merged, so no `u8[B,T,7168]`
+    or `u8[B,T,7056]` exists (flattened under (B, T) the chip tiled the batch
+    over (T, bytes) and every cell paid a pass for it), and between the
+    `r2d2_gather` gather and `enc/Conv_0` the only instruction that writes the
+    whole batch in bf16 is the one convert (lru; behind the LSTM's seam the
+    encoder reads two sub-batches and nothing is that large). PR 43's parent
+    failed this in lru (`copy bf16[32,581,7056]` and `reshape
+    bf16[18592,21,21,16]`, 1.87 ms of 21.7 an update) and in both nature cells
+    (`u8[64,85,7168]`, `u8[16,85,7168]`). The mechanism engages in every gather
+    or in none, so this text is its tripwire where a counter would read 100 %."""
+    from r2d2_tpu.replay.block import LANES, obs_rows
+
+    cfg, programs, _ = _rehearsal().step_programs(config, topo)
+    fn, args = programs["multi"]
+    text = fn.lower(*args).compile().as_text()
+    B, T, n = cfg._rows_per_device(), cfg.seq_len, math.prod(cfg.obs_shape)
+    assert (B, T, n, obs_rows(cfg.obs_shape) * LANES) == (
+        {"nature-lstm512": 64, "lru-seq581": 32, "nature-lstm512-dp4": 16}[config],
+        {"lru-seq581": 581}.get(config, 85), 7056, 7168)
+    assert re.search(rf"= u8\[{B * T},56,128\]\S* fusion\(", text)  # the gather, by its merged index
+    tiled, passes = _hand_over_passes(text, B, T, n)
+    assert tiled == [], tiled
+    if cfg.recurrent_core == "lru":  # no seam: the whole batch goes to conv1 in one call
+        assert len(passes) == 1 and "convert" in passes[0], passes
+    else:
+        assert passes == [], passes
+
+
 def test_lru_kernels_compile_at_the_cells_shape_named_after_their_wrappers(one_chip, compiled_kernels):
     """ops/pallas_lru.py at lru-seq581's own (T, B, H) = (581, 32, 512):
     forward and its VJP are two Mosaic custom calls (the chip's compiler
@@ -373,12 +439,9 @@ def test_lru_step_program_keeps_the_recurrence_in_three_kernel_calls(topo, compi
     reversed pass are Mosaic calls under `core/..._scan_states` (what
     `model.lru_recurrence_ms_per_update` anchors on), and the core is a few
     hundred instructions where the associative scan made 3,399 of it."""
-    spec = importlib.util.spec_from_file_location(
-        "rehearse_step_programs", os.path.join(ROOT, "runs", "rehearse_step_programs.py"))
-    rehearse = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rehearse)
     from r2d2_tpu.utils import profiling
 
+    rehearse = _rehearsal()
     _, programs, _ = rehearse.step_programs("lru-seq581", topo)
     fn, args = programs["multi"]
     text = fn.lower(*args).compile().as_text()
