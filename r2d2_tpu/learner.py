@@ -129,6 +129,18 @@ def init_train_state(cfg: R2D2Config, rng: jax.Array) -> Tuple[R2D2Network, Trai
     )
 
 
+def _q_at(q, a):
+    """q (..., A), a (...) int -> q[..., a], the bits of `take_along_axis`,
+    as a select over A and a sum with one term that is not zero. Indexed
+    entry by entry a v5e pays ~11 ns for each f32 (PERF.md finding 46), and
+    the gradient is a scatter; the select fuses into its neighbours and its
+    gradient is the same select. `where`, never a product with a one-hot:
+    the multi-task floor of -1e9 or a non-finite Q of an action NOT taken
+    must not reach the sum through a `0 *`."""
+    chosen = a[..., None] == jnp.arange(q.shape[-1], dtype=a.dtype)
+    return jnp.sum(jnp.where(chosen, q, 0), axis=-1)
+
+
 def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
     """The per-batch loss closure (params, target_params, batch, denom) ->
     (loss, (priorities, aux)), shared by every train-step builder and by
@@ -167,8 +179,7 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
         # the target math (tests/test_precision.py asserts the island).
         # double-Q: online selects, target evaluates (worker.py:402-406)
         a_star = jnp.argmax(jax.lax.stop_gradient(q_boot_online), axis=-1)  # (B, L)
-        q_tgt = jnp.take_along_axis(q_boot_target, a_star[..., None], axis=-1)[..., 0]
-        q_tgt = q_tgt.astype(jnp.float32)
+        q_tgt = _q_at(q_boot_target, a_star).astype(jnp.float32)
         y = value_rescale(
             n_step_reward.astype(jnp.float32)
             + gamma.astype(jnp.float32) * inverse_value_rescale(q_tgt, eps),
@@ -176,8 +187,7 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
         )
         y = jax.lax.stop_gradient(y)
 
-        q_taken = jnp.take_along_axis(q_learn, action[..., None], axis=-1)[..., 0]
-        q_taken = q_taken.astype(jnp.float32)
+        q_taken = _q_at(q_learn, action).astype(jnp.float32)
         td = y - q_taken
         w = is_weights.astype(jnp.float32)[:, None]
         loss = jnp.sum(w * jnp.square(td) * mask) / denom

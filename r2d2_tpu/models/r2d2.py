@@ -9,7 +9,8 @@ Capability parity with the reference Network (reference model.py:35-188):
 - `unroll`: the fixed-shape replacement for BOTH `calculate_q_`
   (model.py:99-158) and `calculate_q` (model.py:161-188). One lax.scan LSTM
   pass over the padded burn_in+learning+forward window, then two clamped
-  index gathers:
+  index views of its outputs (read as one window of each row, never as
+  indices: `_dueling_window`):
 
     learning view   idx(t) = burn_in + t                     (model.py:182)
     bootstrap view  idx(t) = min(burn_in + F_max + t,
@@ -319,19 +320,54 @@ class R2D2Network(nn.Module):
 
         outs, _ = self.core(x, unpack_state(hidden), burn_in=burn_in)  # (B, T, H)
 
-        t = jnp.arange(L, dtype=jnp.int32)
-        learn_idx = jnp.clip(burn_in[:, None] + t[None, :], 0, T - 1)
-        seq_end = burn_in + learning + forward  # (B,)
-        boot_idx = jnp.minimum(burn_in[:, None] + F + t[None, :], seq_end[:, None] - 1)
-        boot_idx = jnp.clip(boot_idx, 0, T - 1)
-
-        learn_h = jnp.take_along_axis(outs, learn_idx[:, :, None], axis=1)
-        boot_h = jnp.take_along_axis(outs, boot_idx[:, :, None], axis=1)
-
-        q_learn = self._dueling(learn_h, task)
-        q_boot = self._dueling(boot_h, task)
-        mask = (t[None, :] < learning[:, None]).astype(jnp.float32)
+        q_learn, q_boot = self._dueling_window(outs, burn_in, learning, forward, task)
+        mask = (jnp.arange(L, dtype=jnp.int32)[None, :] < learning[:, None]).astype(jnp.float32)
         return q_learn, q_boot, mask
+
+    def _dueling_window(self, outs, burn_in, learning, forward, task=None):
+        """outs (B, T, H) -> (q_learn, q_boot), each (B, L, A) f32: Q at
+
+            learning view   clip(burn_in + l, 0, T - 1)
+            bootstrap view  clip(min(burn_in + F + l,
+                                     burn_in + learning + forward - 1), 0, T - 1)
+
+        for l in [0, L): the module docstring's two index views, with no
+        index. Both lie in ONE window of W = L + F steps that starts at
+        `burn_in[b]` and share L - F of its positions. Gathered as B x L rows
+        of H each, twice, a v5e pays 8 ns a row and 17-70 ns for each row of
+        the transpose's scatter-add (PERF.md finding 46). So the window is
+        moved once, the heads run once over its W rows, and the views are a
+        static slice of the window's Q and its slice from F on with the tail
+        held at the row's last valid step.
+
+        The window is moved by a selection matmul: `band[b, j, t]` is 1 where
+        t is the window's j-th position and 0 elsewhere, so each output is
+        one 1.0 times an entry plus zeros, exact in any dtype ("highest"
+        costs bf16 operands nothing and keeps f32 ones whole), and the
+        transpose places the cotangent with the same band. The clip sits in
+        the band, so the answer is the indexed formula's for ANY `burn_in`,
+        as the store gather's windows keep it (`learner._windows`). (A row
+        with learning + forward = 0 has no valid step and an all-zero mask;
+        its bootstrap view reads the window's first position.)"""
+        B, T = outs.shape[:2]
+        L, F = self.learning_steps, self.forward_steps
+        if self.is_initializing():
+            # un-jitted initialisation wants the heads' parameters alone, not
+            # the window's work, which would compile op by op in every process
+            q = jnp.broadcast_to(self._dueling(outs[:, :1], task), (B, L, self.action_dim))
+            return q, q
+        j = jnp.arange(L + F, dtype=jnp.int32)
+        at = jnp.clip(burn_in[:, None] + j[None, :], 0, T - 1)  # (B, W)
+        band = at[:, :, None] == jnp.arange(T, dtype=jnp.int32)  # (B, W, T)
+        window = jnp.einsum(
+            "bjt,bth->bjh", band.astype(outs.dtype), outs,
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+        ).astype(outs.dtype)
+        q = self._dueling(window, task)  # (B, W, A) f32
+        last = jnp.maximum(learning + forward - 1, 0)[:, None, None]  # (B, 1, 1)
+        held = jnp.sum(jnp.where(j[None, :, None] == last, q, 0), axis=1, keepdims=True)
+        q_boot = jnp.where(j[None, F:, None] <= last, q[:, F:], held)
+        return q[:, :L], q_boot
 
     def __call__(
         self, obs, last_action, last_reward, hidden, burn_in, learning, forward,

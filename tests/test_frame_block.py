@@ -362,20 +362,25 @@ def _step_program_texts(cfg):
 # `limit_indices` / `start_indices` and their two results lose an axis
 # (`u8[8,10,256]` -> `u8[80,256]` in tiny_test) and every row is 3 (IMPALA) or
 # 13 characters shorter; a token-by-token diff of parent against change shows
-# those and nothing else. A jax release that prints a jaxpr differently moves
+# those and nothing else. Taken again in PR 46, whose tail of `unroll` and loss
+# are every preset's as well (`R2D2Network._dueling_window`, `learner._q_at`:
+# the two `take_along_axis` of the core's outputs and the two of Q by action
+# with their scatter-adds leave, a selection matmul, the heads on L + F rows and
+# a few selects come in: every program 430 to 750 characters shorter, and every
+# later variable renamed). A jax release that prints a jaxpr differently moves
 # every row at once: take them again from a tree known to be good
 PARENT_PROGRAMS = {
     "procgen_impala": {
-        "mega": (615071, "61d1df5ea66b4e73667c67717393ae1139c9b7cc6bc6e0502360fa55a2134695"),
-        "multi": (454985, "dad61a7a016b0cd53d40c461fc73e38d7b4f92ecf85bfa2d39a2076488b6cb37"),
+        "mega": (614573, "82e0c7c043ce2e6d07f951764f6a83c2e77e20a066fabace1388ac33a9d06463"),
+        "multi": (454553, "46ca85ff2664f7954adf092eeb89efcb89e5c51bad63c134ec1c6b812736d201"),
     },
     "tiny_test": {
-        "mega": (241896, "fc2645200d7a5718376afc36657252f2659f235839759fa8e925f6491b69a3a8"),
-        "multi": (176805, "8142d5d398ce761455c9d9d75d9ac4bb82d86727861165d766a375e07f572e6c"),
+        "mega": (241145, "298273b2ee0576bdd0dd019e0b888ceb38283c1bab773001bd5991c455f8a4b0"),
+        "multi": (176059, "7c09c9e31b567aa7fd5c08e6e2206d949b5dd061f079908a1bddd2629221c822"),
     },
     "tiny_test-deep-bf16": {
-        "mega": (279941, "5f923d92c8244217332c147a90c847ff2106112391d29eed0badfa8f92b31a72"),
-        "multi": (206936, "ddde92d7babf24704509079aa0038d391a2fc707596891e57a12ff64f2422110"),
+        "mega": (279449, "795a02538c3e4deeb5d1176e3f13d5129df1ef7405f6b784720e52833a0eef91"),
+        "multi": (206468, "bffde83058a032b7c477863c579cdaaac028ec75449b53ebacb23714d9e5cb4b"),
     },
 }
 

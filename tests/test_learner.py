@@ -257,3 +257,108 @@ def test_cosine_lr_schedule_decays_updates():
 
     with pytest.raises(ValueError, match="lr_schedule"):
         tiny_test().replace(lr_schedule="warmup")
+
+
+# ---------------------------------------------------------------------------
+# The loss picks Q by action as a select over A (PR 46): `learner._q_at` and
+# the whole island against the `take_along_axis` they replaced, kept HERE as
+# the oracle.
+
+
+def _by_index(q, a):
+    return jnp.take_along_axis(q, a[..., None], axis=-1)[..., 0]
+
+
+@pytest.mark.parametrize("others", ["finite", "floor", "inf", "nan"])
+@pytest.mark.parametrize("actions", [3, 4, 18])
+def test_q_at_is_take_along_axis_to_the_bit_and_nothing_leaks_from_the_other_actions(actions, others):
+    """Values and the gradient w.r.t. q, bit for bit; whatever the actions NOT
+    taken hold (the multi-task floor, an overflow, a NaN) stays out of both."""
+    from r2d2_tpu.learner import _q_at
+
+    rng = np.random.default_rng(actions)
+    B, L = 5, 7
+    a = jnp.asarray(rng.integers(0, actions, size=(B, L)), jnp.int32)
+    q = rng.normal(size=(B, L, actions)).astype(np.float32)
+    taken = np.arange(actions) == np.asarray(a)[..., None]
+    fill = {"finite": None, "floor": -1e9, "inf": np.inf, "nan": np.nan}[others]
+    if fill is not None:
+        q = np.where(taken, q, np.float32(fill))
+    q = jnp.asarray(q)
+    cot = jnp.asarray(rng.normal(size=(B, L)).astype(np.float32))
+
+    got, got_dq = jax.value_and_grad(lambda q: jnp.sum(_q_at(q, a) * cot))(q)
+    want, want_dq = jax.value_and_grad(lambda q: jnp.sum(_by_index(q, a) * cot))(q)
+    assert np.isfinite(float(got)) and float(got) == float(want)
+    np.testing.assert_array_equal(np.asarray(_q_at(q, a)), np.asarray(_by_index(q, a)))
+    np.testing.assert_array_equal(np.asarray(got_dq), np.asarray(want_dq))
+    assert (np.asarray(got_dq)[~taken] == 0).all() and np.isfinite(np.asarray(got_dq)).all()
+
+
+class _GivenQ:
+    """Stands where make_loss_fn expects the network and hands back the Q
+    views it was given as `params` (as benchmark/correct.py reaches the
+    island)."""
+
+    @staticmethod
+    def apply(given, obs, last_action, last_reward, hidden, burn_in, learning, forward, task=None):
+        return given["q_learn"], given["q_boot"], given["mask"]
+
+
+def _island_with_indices(cfg, q_learn, q_boot, q_boot_target, mask, b, denom):
+    """make_loss_fn's island until PR 46, to the letter."""
+    from r2d2_tpu.ops.priority import mixed_td_priorities
+    from r2d2_tpu.ops.value_rescale import inverse_value_rescale, value_rescale
+
+    eps = cfg.value_rescale_eps
+    a_star = jnp.argmax(jax.lax.stop_gradient(q_boot), axis=-1)
+    y = jax.lax.stop_gradient(value_rescale(
+        b.n_step_reward + b.gamma * inverse_value_rescale(_by_index(q_boot_target, a_star), eps), eps))
+    q_taken = _by_index(q_learn, b.action)
+    td = y - q_taken
+    loss = jnp.sum(b.is_weights[:, None] * jnp.square(td) * mask) / denom
+    abs_td = jnp.abs(td) * mask
+    return loss, (mixed_td_priorities(abs_td, mask, cfg.td_mix_eta), {
+        "q_mean": jnp.sum(q_taken * mask) / denom, "target_mean": jnp.sum(y * mask) / denom,
+        "td_abs_mean": jnp.sum(abs_td) / denom})
+
+
+@pytest.mark.parametrize("floor", [False, True], ids=["single-task", "multi-task-floor"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_loss_island_is_the_indexed_islands(cfg, seed, floor):
+    """dloss/dq_learn of the program's island equals the `take_along_axis`
+    island's bit for bit, and loss, priorities and the aux means to the order
+    of a sum, with the invalid actions of a multi-task row at the -1e9 floor
+    too."""
+    from r2d2_tpu.learner import make_loss_fn
+
+    b = random_batch(cfg, seed)
+    rng = np.random.default_rng(100 + seed)
+    B, L, A = cfg.batch_size, cfg.learning_steps, cfg.action_dim
+    views = {k: rng.normal(size=(B, L, A)).astype(np.float32) for k in ("q_learn", "q_boot", "q_boot_target")}
+    if floor:
+        valid = np.arange(A) < rng.integers(2, A + 1, size=(B, 1, 1))
+        views = {k: np.where(valid, v, np.float32(-1e9)) for k, v in views.items()}
+        b = b._replace(action=jnp.asarray(rng.integers(0, 2, size=(B, L)), jnp.int32))
+    views = {k: jnp.asarray(v) for k, v in views.items()}
+    mask = (jnp.arange(L)[None, :] < b.learning_steps[:, None]).astype(jnp.float32)
+    denom = jnp.maximum(jnp.sum(b.learning_steps).astype(jnp.float32), 1.0)
+    loss_fn = make_loss_fn(cfg, _GivenQ)
+
+    def program(q):
+        online = {"q_learn": q, "q_boot": views["q_boot"], "mask": mask}
+        target = {"q_learn": q, "q_boot": views["q_boot_target"], "mask": mask}
+        return loss_fn(online, target, b, denom)
+
+    def oracle(q):
+        return _island_with_indices(cfg, q, views["q_boot"], views["q_boot_target"], mask, b, denom)
+
+    (got, got_aux), got_dq = jax.jit(jax.value_and_grad(program, has_aux=True))(views["q_learn"])
+    (want, want_aux), want_dq = jax.jit(jax.value_and_grad(oracle, has_aux=True))(views["q_learn"])
+    # entry by entry the same bits; a sum over (B, L) is the compiler's to order
+    np.testing.assert_array_equal(np.asarray(got_dq), np.asarray(want_dq))
+    assert np.abs(np.asarray(got_dq)).max() > 0
+    assert np.isfinite(float(got))
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got_aux), jax.tree.leaves(want_aux)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-6, atol=1e-7)

@@ -7,12 +7,16 @@ frame at a time must reproduce exactly the Q values the scan-based unroll
 gathers, including the bootstrap view's edge-repeat clamp semantics.
 """
 
+import functools
+import itertools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from r2d2_tpu.config import R2D2Config, tiny_test
+from r2d2_tpu.models.core import unpack_state
 from r2d2_tpu.models.lstm import LSTM
 from r2d2_tpu.models.r2d2 import R2D2Network, init_params
 
@@ -220,15 +224,9 @@ def test_encoder_depth_adds_dense_layers():
 
 def _views_from_core_input(m, x, hid, burn, learn, fwd):
     """Core + both Q views from a time-ordered core input (B, T, D)."""
-    T = x.shape[1]
-    L, F = m.learning_steps, m.forward_steps
     outs, _ = m.core(x, (hid[:, 0], hid[:, 1]), burn_in=burn)
-    t = jnp.arange(L, dtype=jnp.int32)
-    learn_idx = jnp.clip(burn[:, None] + t[None, :], 0, T - 1)
-    boot_idx = jnp.minimum(burn[:, None] + F + t[None, :], (burn + learn + fwd)[:, None] - 1)
-    boot_idx = jnp.clip(boot_idx, 0, T - 1)
-    q_learn = m._dueling(jnp.take_along_axis(outs, learn_idx[:, :, None], axis=1))
-    q_boot = m._dueling(jnp.take_along_axis(outs, boot_idx[:, :, None], axis=1))
+    q_learn, q_boot = indexed_tail(m, outs, burn, learn, fwd)
+    t = jnp.arange(m.learning_steps, dtype=jnp.int32)
     return q_learn, q_boot, (t[None, :] < learn[:, None]).astype(jnp.float32)
 
 
@@ -372,3 +370,153 @@ def test_parameter_tree_is_the_parents():
     want["['params']['core']['wh']"] = (H, 4 * H)
     want["['params']['core']['b']"] = (4 * H,)
     assert got == want
+
+
+# ---------------------------------------------------------------------------
+# The tail of `unroll` (PR 46): each row's learning and bootstrap positions are
+# ONE window of the core's outputs, moved without an index per (row, step), and
+# the heads run once over it. The indexed formula it replaced lives HERE, as
+# the oracle, written out with `take_along_axis`.
+
+_TAIL_CFG = tiny_test()  # burn-in 4 + learning 4 + n-step 2: T = 10, window 6
+_TAIL_ROWS = list(itertools.product(
+    (0, 1, _TAIL_CFG.burn_in_steps - 1, _TAIL_CFG.burn_in_steps),      # burn_in
+    (1, _TAIL_CFG.learning_steps - 1, _TAIL_CFG.learning_steps),       # learning
+    (0, 1, _TAIL_CFG.forward_steps),                                   # forward
+))
+
+
+def _tail_indices(m, T, burn, learn, fwd):
+    L, F = m.learning_steps, m.forward_steps
+    t = jnp.arange(L, dtype=jnp.int32)
+    learn_idx = jnp.clip(burn[:, None] + t[None, :], 0, T - 1)
+    boot_idx = jnp.minimum(burn[:, None] + F + t[None, :], (burn + learn + fwd)[:, None] - 1)
+    return learn_idx, jnp.clip(boot_idx, 0, T - 1)
+
+
+def indexed_tail(m, outs, burn, learn, fwd, task=None):
+    """`unroll`'s tail until PR 46, to the letter: two gathers of B x L rows,
+    the heads over each."""
+    learn_idx, boot_idx = _tail_indices(m, outs.shape[1], burn, learn, fwd)
+    return (m._dueling(jnp.take_along_axis(outs, learn_idx[:, :, None], axis=1), task),
+            m._dueling(jnp.take_along_axis(outs, boot_idx[:, :, None], axis=1), task))
+
+
+def indexed_tail_one_head_call(m, outs, burn, learn, fwd, task=None):
+    """The same positions, every one by its index, with the heads called on the
+    rows `_dueling_window` calls them on (each row's window of L + F): a CPU
+    matmul rounds a row's last bit by how many rows it is given, so THIS is
+    what can be equal to the bit."""
+    T = outs.shape[1]
+    W = m.learning_steps + m.forward_steps
+    at = jnp.clip(burn[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :], 0, T - 1)
+    q = m._dueling(jnp.take_along_axis(outs, at[:, :, None], axis=1), task)
+    # where in the window each index sits: the first position that holds it
+    return tuple(
+        jnp.take_along_axis(q, jnp.argmax(at[:, None, :] == idx[:, :, None], axis=-1)[:, :, None], axis=1)
+        for idx in _tail_indices(m, T, burn, learn, fwd))
+
+
+@functools.lru_cache(maxsize=None)
+def _tail_case(core, tasks, dtype):
+    """One batch whose rows are `_TAIL_ROWS`, its core's outputs, and jitted
+    values and per-row gradients of the tail, the oracle and the oracle with
+    the tail's head call."""
+    cfg = _TAIL_CFG.replace(
+        recurrent_core=core, precision="bf16" if dtype == "bfloat16" else "fp32",
+        **({"num_tasks": 2, "task_action_dims": (2, 4)} if tasks == "multi" else {}))
+    net, params = make_net(cfg)
+    rng = np.random.default_rng(46)
+    B, T = len(_TAIL_ROWS), cfg.seq_len
+    burn, learn, fwd = (jnp.asarray(c, jnp.int32) for c in zip(*_TAIL_ROWS))
+    task = jnp.asarray(rng.integers(0, 2, size=B), jnp.int32) if tasks == "multi" else None
+    x = jnp.asarray(rng.normal(size=(B, T, cfg.hidden_dim + cfg.action_dim + 1)).astype(np.float32))
+    hid = jnp.asarray(rng.normal(size=(B, 2, cfg.hidden_dim)).astype(np.float32))
+    outs = net.apply(params, x, hid, method=lambda m, x, hid: m.core(x, unpack_state(hid), burn_in=burn)[0])
+    assert outs.dtype == jnp.dtype(dtype) and outs.shape == (B, T, cfg.hidden_dim)
+    weights = [jnp.asarray(rng.normal(size=(B, cfg.learning_steps, cfg.action_dim)).astype(np.float32))
+               for _ in range(2)]
+
+    def compiled(method):
+        views = lambda p, o: net.apply(p, o, burn, learn, fwd, task, method=method)
+
+        def of_row(p, o, row):
+            # the -1e9 floor is a constant under the select: keep it out of the sum's scale
+            return sum(jnp.sum(jnp.where(q > -1e8, q * w, 0.0) * row[:, None, None])
+                       for q, w in zip(views(p, o), weights))
+
+        return jax.jit(views), jax.jit(jax.grad(of_row, argnums=(0, 1)))
+
+    forms = {name: compiled(method) for name, method in [
+        ("tail", R2D2Network._dueling_window), ("indexed", indexed_tail),
+        ("one_head_call", indexed_tail_one_head_call)]}
+    values = {name: jax.device_get(views(params, outs)) for name, (views, _) in forms.items()}
+    return cfg, net, params, outs, task, values, {name: g for name, (_, g) in forms.items()}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tasks", ["single", "multi"])
+@pytest.mark.parametrize("core", ["lstm", "lru"])
+@pytest.mark.parametrize("row", range(len(_TAIL_ROWS)), ids=["burn{}-learn{}-fwd{}".format(*r) for r in _TAIL_ROWS])
+def test_the_window_tail_is_the_indexed_formula_in_values_and_gradients(row, core, tasks, dtype):
+    cfg, net, params, outs, task, values, grads = _tail_case(core, tasks, dtype)
+    burn, learn, fwd = _TAIL_ROWS[row]
+    for view in (0, 1):
+        got = values["tail"][view][row]
+        assert got.dtype == np.float32 and got.shape == (cfg.learning_steps, cfg.action_dim)
+        # to the bit where the heads are called on the same rows ...
+        np.testing.assert_array_equal(got, values["one_head_call"][view][row])
+        # ... and to a matmul's last bit against the formula as it stood
+        np.testing.assert_allclose(got, values["indexed"][view][row], rtol=2e-6, atol=2e-6)
+    if tasks == "multi":  # the floor survives the select, on the invalid actions only
+        floor = np.arange(cfg.action_dim) >= (2, 4)[int(task[row])]
+        assert (values["tail"][1][row][:, floor] == -1e9).all()
+        assert (values["tail"][1][row][:, ~floor] > -1e8).all()
+    # the held bootstrap tail: from the row's last valid step on, one Q repeated
+    last = learn + fwd - 1
+    held = [l for l in range(cfg.learning_steps) if cfg.forward_steps + l >= last]
+    assert held and all((values["tail"][1][row][l] == values["tail"][1][row][held[0]]).all() for l in held)
+
+    only = jnp.zeros(len(_TAIL_ROWS), jnp.float32).at[row].set(1.0)
+    (gp, go), (wp, wo) = grads["tail"](params, outs, only), grads["indexed"](params, outs, only)
+    # a bf16 `outs` takes a bf16 cotangent: where views share a row (the held
+    # tail: up to L + 1 of them) the oracle's scatter-add rounds after every
+    # term, the band's matmul sums them in f32 and rounds once
+    tol = 1e-6 if dtype == "float32" else 2.0 ** -5
+    go, wo = np.asarray(go, np.float32), np.asarray(wo, np.float32)
+    assert np.abs(wo[row]).max() > 0 and (go[np.arange(len(go)) != row] == 0).all()
+    np.testing.assert_allclose(go, wo, rtol=0, atol=tol * np.abs(wo).max())
+    # relative to the gradient's scale: a bias's entry is a sum that cancels
+    scale = max(float(np.abs(leaf).max()) for leaf in jax.tree.leaves(wp))
+    assert scale > 0
+    for (path, got), (_, want) in zip(
+            jax.tree_util.tree_leaves_with_path(gp), jax.tree_util.tree_leaves_with_path(wp)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=0, atol=1e-6 * scale, err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("core", ["lstm", "lru"])
+@pytest.mark.parametrize("burn", [5, 8, 9, 12, -1])
+def test_the_window_tail_keeps_the_clips_answer_for_any_burn_in(burn, core):
+    """No preset stores a `burn_in` with `burn_in + L + F > T`, and nothing in
+    the tail rests on that: past the end (or before the start) of the sequence
+    it reads what the clipped index read."""
+    cfg, net, params, outs, _, _, _ = _tail_case(core, "single", "float32")
+    assert cfg.seq_len == 10 and (burn + cfg.learning_steps + cfg.forward_steps > cfg.seq_len or burn < 0)
+    B = outs.shape[0]
+    b = jnp.full((B,), burn, jnp.int32)
+    learn, fwd = (jnp.asarray(c, jnp.int32) for c in list(zip(*_TAIL_ROWS))[1:])
+    got = net.apply(params, outs, b, learn, fwd, method="_dueling_window")
+    want = net.apply(params, outs, b, learn, fwd, method=indexed_tail_one_head_call)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("preset", ["atari", "atari_v4_8", "procgen_impala", "long_context", "tiny_test"])
+def test_every_presets_window_lies_inside_its_sequence(preset):
+    """What the band's clip never has to repair: a stored `burn_in` is at most
+    `cfg.burn_in_steps` (replay/accumulator.py), and T = burn-in + L + F."""
+    from r2d2_tpu.config import PRESETS
+
+    cfg = PRESETS[preset]()
+    assert cfg.seq_len == cfg.burn_in_steps + cfg.learning_steps + cfg.forward_steps

@@ -351,6 +351,21 @@ def _rehearsal():
     return rehearse
 
 
+_MULTI = {}
+
+
+def _multi_program(config, topo):
+    """(cfg, compiled text) of a configuration's update-only step program
+    (`jit_multi`; dp4: the shard_map body, 16 rows a chip) at real size, as
+    the chip builds it: compiled once for every test of this file that reads
+    it (under `compiled_kernels`, which each of them asks for)."""
+    if config not in _MULTI:
+        cfg, programs, _ = _rehearsal().step_programs(config, topo)
+        fn, args = programs["multi"]
+        _MULTI[config] = cfg, fn.lower(*args).compile().as_text()
+    return _MULTI[config]
+
+
 def _hand_over_passes(text, B, T, frame_bytes):
     """Of a compiled step program's text: every `u8[B,T,bytes]` shape in it
     (the batch's bytes tiled over (T, bytes)), and every instruction between
@@ -393,9 +408,7 @@ def test_the_batch_reaches_conv1_with_the_frame_index_as_one_axis(config, topo, 
     or in none, so this text is its tripwire where a counter would read 100 %."""
     from r2d2_tpu.replay.block import LANES, obs_rows
 
-    cfg, programs, _ = _rehearsal().step_programs(config, topo)
-    fn, args = programs["multi"]
-    text = fn.lower(*args).compile().as_text()
+    cfg, text = _multi_program(config, topo)
     B, T, n = cfg._rows_per_device(), cfg.seq_len, math.prod(cfg.obs_shape)
     assert (B, T, n, obs_rows(cfg.obs_shape) * LANES) == (
         {"nature-lstm512": 64, "lru-seq581": 32, "nature-lstm512-dp4": 16}[config],
@@ -407,6 +420,35 @@ def test_the_batch_reaches_conv1_with_the_frame_index_as_one_axis(config, topo, 
         assert len(passes) == 1 and "convert" in passes[0], passes
     else:
         assert passes == [], passes
+
+
+@pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581", "nature-lstm512-dp4"])
+def test_the_tail_of_unroll_and_the_loss_hold_no_index_per_row_and_step(config, topo, compiled_kernels):
+    """The same program's text: each row's learning and bootstrap positions
+    are ONE window of the core's outputs, moved by a selection matmul
+    (`R2D2Network._dueling_window`), and the loss picks Q by action with a
+    select over A (`learner._q_at`). So no instruction carries the op_name of
+    `unroll`'s own `take_along_axis` or of the loss's, no `scatter-add` is
+    named under `unroll` outside `_core_input` and the core (whose
+    re-ordering and slices keep theirs), and nothing H wide is scattered at
+    all (the scatter's own root carried no op_name: a trace booked it
+    unscoped, 0.043 / 0.365 / 0.046 ms an update, PERF.md finding 46). PR 46's
+    parent failed the first in all three. The mechanism engages in every
+    update or in none, so this text is its tripwire where a counter would
+    read 100 %."""
+    cfg, text = _multi_program(config, topo)
+    names = sorted(set(re.findall(r'op_name="([^"]*)"', text)))  # inside the fusions' bodies too
+    assert any("R2D2Network.unroll/R2D2Network._dueling_window/" in n for n in names)
+    assert any("jit(r2d2_loss)" in n for n in names)
+    indexed = [n for n in names if "R2D2Network.unroll/jit(take_along_axis)" in n
+               or re.search(r"jit\(r2d2_loss\)\)*/jit\(take_along_axis\)", n)]
+    assert indexed == [], indexed[:3]
+    scattered = [n for n in names if "scatter-add" in n and "R2D2Network.unroll/" in n
+                 and "_core_input" not in n and "R2D2Network.unroll/core/" not in n]
+    assert scattered == [], scattered[:3]
+    assert not re.search(rf"= \w+\[[\d,]*{cfg.hidden_dim}\]\S* scatter\(", text)
+    if cfg.recurrent_core == "lstm":  # the seam's re-ordering is another issue's (ROADMAP S3.3)
+        assert any("_core_input/jit(take_along_axis)" in n for n in names)
 
 
 def test_lru_kernels_compile_at_the_cells_shape_named_after_their_wrappers(one_chip, compiled_kernels):
@@ -441,12 +483,9 @@ def test_lru_step_program_keeps_the_recurrence_in_three_kernel_calls(topo, compi
     hundred instructions where the associative scan made 3,399 of it."""
     from r2d2_tpu.utils import profiling
 
-    rehearse = _rehearsal()
-    _, programs, _ = rehearse.step_programs("lru-seq581", topo)
-    fn, args = programs["multi"]
-    text = fn.lower(*args).compile().as_text()
+    _, text = _multi_program("lru-seq581", topo)
     op_names = profiling.parse_op_names(text)
     kernels = {k: v for k, v in op_names.items() if re.match(r"%?_lru_(fwd|rev)_call", k)}
     assert sorted(re.sub(r"^%|\.\d+$", "", k) for k in kernels) == ["_lru_fwd_call", "_lru_fwd_call", "_lru_rev_call"]
     assert all("R2D2Network.unroll/core" in v and "_scan_states" in v for v in kernels.values()), kernels
-    assert rehearse.instructions_in_buckets(text)["core"] < 600
+    assert _rehearsal().instructions_in_buckets(text)["core"] < 600
