@@ -68,6 +68,41 @@ class RowDense(nn.Module):
         return jax.lax.psum(x @ kernel, self.tp_axis) + bias
 
 
+def _moved_by(band, subscripts, x):
+    """`x`'s entries moved by a selection matmul: `band` (bool) holds at most
+    one True for each output position, so each output is one 1.0 times an
+    entry plus zeros, exact in any dtype ("highest" costs bf16 operands
+    nothing and keeps f32 ones whole), with no index; the transpose places
+    the cotangent with the same band, so nothing is scattered."""
+    return jnp.einsum(
+        subscripts, band.astype(x.dtype), x,
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    ).astype(x.dtype)
+
+
+def _time_order(window, others, start):
+    """window (B, W, D): steps [start[b], start[b] + W) of each row; others
+    (B, T - W, D): the row's remaining steps in order -> (B, T, D) in time
+    order, with no index. Step t of a row is `others[t]` below the window and
+    `others[t - W]` after it: two static slices of `others` (padded by W
+    behind, and by W in front) and a select on `t < start[b]`. Inside, it is
+    `window[t - start[b]]`: one run a row, moved as
+    `R2D2Network._dueling_window` moves its window: `band[b, t, j]` is 1 where
+    t is the window's j-th position and 0 elsewhere (`_moved_by`). Indexed as
+    B x T rows a v5e pays 20-30 ns a row and more for each row of the
+    transpose's scatter-add (PERF.md finding 49). `start` (B,) int32 is read
+    as it is given: any start in [0, T - W] gives the indexed formula's
+    values and gradients."""
+    W, T = window.shape[1], window.shape[1] + others.shape[1]
+    t = jnp.arange(T, dtype=jnp.int32)[None, :, None]
+    start = start[:, None, None]
+    band = t == start + jnp.arange(W, dtype=jnp.int32)[None, None, :]  # (B, T, W)
+    placed = _moved_by(band, "btj,bjd->btd", window)
+    below = jnp.pad(others, ((0, 0), (0, W), (0, 0)))
+    after = jnp.pad(others, ((0, 0), (W, 0), (0, 0)))
+    return jnp.where(t < start, below, jnp.where(t < start + W, placed, after))
+
+
 class R2D2Network(nn.Module):
     action_dim: int
     # the recurrent core, built by its registered class (models/core.py);
@@ -162,18 +197,23 @@ class R2D2Network(nn.Module):
         encoder therefore runs as two sub-batches, each row's L + F frames
         from its seam with gradient and its other T - L - F frames
         without. Per frame the forward is the one call's, and the gradient
-        is the same sum without its zero terms, whatever the loss."""
+        is the same sum without its zero terms, whatever the loss.
+
+        The two encoded parts go back to time order with no index
+        (`_time_order`); the one-hot of `last_action` and `last_reward`
+        arrive in time order, carry no gradient and never leave it."""
         dtype = jnp.dtype(self.compute_dtype)
 
-        def encode(obs, last_action, last_reward):
-            x = obs.astype(dtype) / 255.0
-            latent = self.enc(x)
+        def encode(obs):
+            return self.enc(obs.astype(dtype) / 255.0)
+
+        def beside(latent):
             onehot = jax.nn.one_hot(last_action, self.action_dim, dtype=dtype)
-            reward = last_reward.astype(dtype)[:, None]
+            reward = last_reward.astype(dtype)[..., None]
             return jnp.concatenate([latent, onehot, reward], axis=-1)
 
         if burn_in is None:
-            return encode(obs, last_action, last_reward)
+            return beside(encode(obs))
 
         B, T = obs.shape[:2]
         W = self.learning_steps + self.forward_steps
@@ -189,25 +229,23 @@ class R2D2Network(nn.Module):
         # as it does the one call's batch (gathered as (84, 84, 1) frames,
         # that re-layout lands inside the conv and doubles its time)
         frames = obs.reshape(B * T, -1)
-        actions, rewards = last_action.reshape(B * T), last_reward.reshape(B * T)
 
         def encode_at(idx):
             # ONE flattened index, as learner.make_store_gather: the
             # two-index gather of uint8 frames halts the v5e's core
             flat = (row0 + idx).reshape(-1)
-            take = lambda a: jnp.take(a, flat, axis=0, mode="clip")
-            return encode(
-                take(frames).reshape(-1, *obs.shape[2:]), take(actions), take(rewards)
-            ).reshape(B, idx.shape[1], -1)
+            taken = jnp.take(frames, flat, axis=0, mode="clip")
+            return encode(taken.reshape(-1, *obs.shape[2:])).reshape(B, idx.shape[1], -1)
 
-        x = jnp.concatenate(
-            [encode_at(window), jax.lax.stop_gradient(encode_at(others))], axis=1
-        )
-        # back to time order: t sits at W + t below the window, at
-        # t - start inside it, and at t after it
-        t = jnp.arange(T, dtype=jnp.int32)[None, :]
-        pos = jnp.where(t < start, W + t, jnp.where(t < start + W, t - start, t))
-        return jnp.take_along_axis(x, pos[:, :, None], axis=1)
+        x = beside(_time_order(
+            encode_at(window), jax.lax.stop_gradient(encode_at(others)), start[:, 0]
+        ))
+        # made once and kept, as the gather's result was: left to fuse, the
+        # chip's compiler makes x again for the core's backward, and with
+        # the longer lives of its parts the core's own arrays lose their
+        # place in the fast memory (PERF.md finding 49.2: the update slower
+        # by 3.4 % where this form is faster by 8 %)
+        return jax.lax.optimization_barrier(x)
 
     def _task_mask(self, task: jnp.ndarray | None) -> jnp.ndarray | None:
         """(B, A) bool valid-action mask for each row's task, or None when
@@ -340,11 +378,10 @@ class R2D2Network(nn.Module):
         static slice of the window's Q and its slice from F on with the tail
         held at the row's last valid step.
 
-        The window is moved by a selection matmul: `band[b, j, t]` is 1 where
-        t is the window's j-th position and 0 elsewhere, so each output is
-        one 1.0 times an entry plus zeros, exact in any dtype ("highest"
-        costs bf16 operands nothing and keeps f32 ones whole), and the
-        transpose places the cotangent with the same band. The clip sits in
+        The window is moved by a selection matmul (`_moved_by`): `band[b, j,
+        t]` is 1 where t is the window's j-th position and 0 elsewhere, so
+        each output is one 1.0 times an entry plus zeros, exact in any dtype,
+        and the transpose places the cotangent with the same band. The clip sits in
         the band, so the answer is the indexed formula's for ANY `burn_in`,
         as the store gather's windows keep it (`learner._windows`). (A row
         with learning + forward = 0 has no valid step and an all-zero mask;
@@ -359,10 +396,7 @@ class R2D2Network(nn.Module):
         j = jnp.arange(L + F, dtype=jnp.int32)
         at = jnp.clip(burn_in[:, None] + j[None, :], 0, T - 1)  # (B, W)
         band = at[:, :, None] == jnp.arange(T, dtype=jnp.int32)  # (B, W, T)
-        window = jnp.einsum(
-            "bjt,bth->bjh", band.astype(outs.dtype), outs,
-            precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
-        ).astype(outs.dtype)
+        window = _moved_by(band, "bjt,bth->bjh", outs)
         q = self._dueling(window, task)  # (B, W, A) f32
         last = jnp.maximum(learning + forward - 1, 0)[:, None, None]  # (B, 1, 1)
         held = jnp.sum(jnp.where(j[None, :, None] == last, q, 0), axis=1, keepdims=True)

@@ -373,6 +373,125 @@ def test_parameter_tree_is_the_parents():
 
 
 # ---------------------------------------------------------------------------
+# `_core_input` behind the LSTM's seam (PR 49): the encoder's two sub-batches
+# go back to time order with no index (`r2d2._time_order`: two static slices
+# of the no-gradient part under a select, the window by a selection matmul),
+# and the one-hot action and the reward never leave time order. The indexed
+# re-ordering it replaced lives HERE, as the oracle.
+
+
+def indexed_core_input(m, obs, last_action, last_reward, burn_in):
+    """`_core_input(..., burn_in)` until PR 49, to the letter: frames, actions
+    and rewards gathered by each part's flat index, the two 516-wide parts
+    concatenated and `take_along_axis` over B x T rows."""
+    dtype = jnp.dtype(m.compute_dtype)
+
+    def encode(obs, last_action, last_reward):
+        latent = m.enc(obs.astype(dtype) / 255.0)
+        onehot = jax.nn.one_hot(last_action, m.action_dim, dtype=dtype)
+        return jnp.concatenate([latent, onehot, last_reward.astype(dtype)[:, None]], axis=-1)
+
+    B, T = obs.shape[:2]
+    W = m.learning_steps + m.forward_steps
+    start = jnp.clip(burn_in, 0, T - W).astype(jnp.int32)[:, None]
+    window = start + jnp.arange(W, dtype=jnp.int32)[None, :]
+    c = jnp.arange(T - W, dtype=jnp.int32)[None, :]
+    others = jnp.where(c < start, c, c + W)
+    row0 = jnp.arange(B, dtype=jnp.int32)[:, None] * T
+    frames = obs.reshape(B * T, -1)
+    actions, rewards = last_action.reshape(B * T), last_reward.reshape(B * T)
+
+    def encode_at(idx):
+        flat = (row0 + idx).reshape(-1)
+        take = lambda a: jnp.take(a, flat, axis=0, mode="clip")
+        return encode(
+            take(frames).reshape(-1, *obs.shape[2:]), take(actions), take(rewards)
+        ).reshape(B, idx.shape[1], -1)
+
+    x = jnp.concatenate([encode_at(window), jax.lax.stop_gradient(encode_at(others))], axis=1)
+    t = jnp.arange(T, dtype=jnp.int32)[None, :]
+    pos = jnp.where(t < start, W + t, jnp.where(t < start + W, t - start, t))
+    return jnp.take_along_axis(x, pos[:, :, None], axis=1)
+
+
+# geometry -> (burn-in, learning, n-step): T = 10 with a window of 6, and the
+# benchmark cells' T = 85 with a window of 45 behind a toy encoder
+_SEAM_GEOMETRY = {"T10-W6": (4, 4, 2), "T85-W45": (40, 40, 5)}
+
+
+def _seam_burn_in(rows, T, W):
+    """The seams of one batch: both ends of the clip's range, one past it
+    (no preset stores it; the clip answers all the same), or all in one batch."""
+    return {
+        "seam0": [0, 0, 0], "seamT-W": [T - W] * 3, "past-the-clip": [T - W + 3, T, T - W + 1],
+        "mixed": [0, T - W, (T - W) // 2, T - W + 2, 1],
+    }[rows]
+
+
+@functools.lru_cache(maxsize=None)
+def _seam_case(geometry, dtype):
+    burn, learn, fwd = _SEAM_GEOMETRY[geometry]
+    cfg = tiny_test().replace(
+        burn_in_steps=burn, learning_steps=learn, forward_steps=fwd, block_length=2 * learn,
+        encoder="mlp", obs_shape=(12, 12, 1), precision="bf16" if dtype == "bfloat16" else "fp32")
+    net, params = make_net(cfg)
+    assert net.core.cuts_at_burn_in and cfg.seq_len > learn + fwd
+
+    def compiled(method):
+        def weighed(p, obs, la, lr, burn_in, weight):
+            x = net.apply(p, obs, la, lr, burn_in, method=method)
+            return jnp.sum(x.astype(jnp.float32) * weight), x
+
+        return jax.jit(jax.value_and_grad(weighed, has_aux=True))
+
+    return cfg, params, compiled(R2D2Network._core_input), compiled(indexed_core_input)
+
+
+@pytest.mark.parametrize("rows", ["seam0", "seamT-W", "past-the-clip", "mixed"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("geometry", list(_SEAM_GEOMETRY))
+def test_core_input_behind_the_seam_is_the_indexed_formula_in_values_and_gradients(geometry, dtype, rows):
+    cfg, params, committed, indexed = _seam_case(geometry, dtype)
+    T, W = cfg.seq_len, cfg.learning_steps + cfg.forward_steps
+    assert (T, W) == {"T10-W6": (10, 6), "T85-W45": (85, 45)}[geometry]
+    burn_in = jnp.asarray(_seam_burn_in(rows, T, W), jnp.int32)
+    rng = np.random.default_rng(49)
+    obs, la, lr, _ = random_inputs(cfg, rng, B=len(burn_in))
+    weight = jnp.asarray(rng.normal(size=(len(burn_in), T, cfg.hidden_dim + cfg.action_dim + 1)).astype(np.float32))
+    ((_, got), g_got), ((_, want), g_want) = (
+        f(params, obs, la, lr, burn_in, weight) for f in (committed, indexed))
+    assert got.dtype == jnp.dtype(dtype) and got.shape == weight.shape
+    # every entry is the one the index read (`==` takes a zero of either sign)
+    np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+    reached = 0
+    for (path, g), (_, w) in zip(
+            jax.tree_util.tree_leaves_with_path(g_got), jax.tree_util.tree_leaves_with_path(g_want)):
+        g, w = np.asarray(g), np.asarray(w)
+        reached += bool(np.abs(w).max() > 0)
+        # each cotangent row is the one term the scatter-add wrote
+        np.testing.assert_array_equal(g, w, err_msg=jax.tree_util.keystr(path))
+    assert reached >= 2  # the encoder's kernel and bias, at least
+
+
+@pytest.mark.parametrize("geometry", list(_SEAM_GEOMETRY))
+def test_core_inputs_last_columns_are_the_time_ordered_actions_and_rewards(geometry):
+    cfg, params, committed, _ = _seam_case(geometry, "float32")
+    T, W, A = cfg.seq_len, cfg.learning_steps + cfg.forward_steps, cfg.action_dim
+    burn_in = jnp.asarray(_seam_burn_in("mixed", T, W), jnp.int32)
+    rng = np.random.default_rng(50)
+    obs, la, lr, _ = random_inputs(cfg, rng, B=len(burn_in))
+    (_, x), _ = committed(params, obs, la, lr, burn_in, jnp.zeros((len(burn_in), T, cfg.hidden_dim + A + 1)))
+    x = np.asarray(x)
+    np.testing.assert_array_equal(x[..., -A - 1:-1], np.eye(A, dtype=np.float32)[np.asarray(la)])
+    np.testing.assert_array_equal(x[..., -1], np.asarray(lr))
+    # and the latent columns are each frame's own encoding, whatever its seam
+    net = R2D2Network.from_config(cfg)
+    one_call = net.apply(
+        params, obs.reshape(-1, *cfg.obs_shape), la.reshape(-1), lr.reshape(-1), method="_core_input")
+    np.testing.assert_allclose(x, np.asarray(one_call).reshape(x.shape), rtol=1e-6, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
 # The tail of `unroll` (PR 46): each row's learning and bootstrap positions are
 # ONE window of the core's outputs, moved without an index per (row, step), and
 # the heads run once over it. The indexed formula it replaced lives HERE, as

@@ -429,8 +429,8 @@ def test_the_tail_of_unroll_and_the_loss_hold_no_index_per_row_and_step(config, 
     (`R2D2Network._dueling_window`), and the loss picks Q by action with a
     select over A (`learner._q_at`). So no instruction carries the op_name of
     `unroll`'s own `take_along_axis` or of the loss's, no `scatter-add` is
-    named under `unroll` outside `_core_input` and the core (whose
-    re-ordering and slices keep theirs), and nothing H wide is scattered at
+    named under `unroll` outside `_core_input` and the core (whose frame
+    gathers and slices keep theirs), and nothing H wide is scattered at
     all (the scatter's own root carried no op_name: a trace booked it
     unscoped, 0.043 / 0.365 / 0.046 ms an update, PERF.md finding 46). PR 46's
     parent failed the first in all three. The mechanism engages in every
@@ -447,8 +447,33 @@ def test_the_tail_of_unroll_and_the_loss_hold_no_index_per_row_and_step(config, 
                  and "_core_input" not in n and "R2D2Network.unroll/core/" not in n]
     assert scattered == [], scattered[:3]
     assert not re.search(rf"= \w+\[[\d,]*{cfg.hidden_dim}\]\S* scatter\(", text)
-    if cfg.recurrent_core == "lstm":  # the seam's re-ordering is another issue's (ROADMAP S3.3)
-        assert any("_core_input/jit(take_along_axis)" in n for n in names)
+    # since PR 49 the seam's way back to time order holds none either
+    # (`r2d2._time_order`: static slices, a select and the same kind of band;
+    # its `take_along_axis` of B x T rows of 516 and the unnamed scatter-add
+    # of its transpose were 0.27 ms of nature's 4.28 an update, PERF.md
+    # finding 49): nothing under `_core_input` is indexed row by row but the
+    # frames, and nothing as wide as the core's input is scattered
+    band = [n for n in names if "_core_input/btj,bjd->btd/" in n]
+    if cfg.recurrent_core == "lstm":
+        assert any("transpose(jvp(" in n for n in band) and any("transpose(jvp(" not in n for n in band)
+        assert not any("_core_input/jit(take_along_axis)" in n for n in names)
+        assert not any("scatter-add" in n and "_core_input" in n for n in names)
+        width = cfg.hidden_dim + cfg.action_dim + 1  # (the latent's width is asserted above)
+        assert not re.search(rf"= \w+\[[\d,]*{width}\]\S* scatter\(", text)
+    else:  # no seam: the one call, and none of the band's names
+        assert band == [], band[:3]
+    if config == "nature-lstm512":
+        # what the first band form of PR 49 lost, with every predicted op gone:
+        # the compiler made the core's input again for the backward, and the
+        # core's own arrays lost their place in the chip's fast memory (`S(1)`
+        # in a layout): the update was 3.4 % SLOWER. `_core_input` keeps its
+        # result behind an `optimization_barrier`; a later change that evicts
+        # these fails here, on the CPU, where PR 49 needed a traced pair
+        backward = re.findall(r"^\s*%_lstm_seq_bwd_call[.\d]* = (\S+) custom-call\(", text, re.M)
+        assert len(backward) == 1 and "S(1)" in backward[0], backward
+        projections = re.findall(
+            r"= (bf16\[5440,2048\]\S*) fusion\([^\n]*R2D2Network\.unroll/core/dot_general", text)
+        assert len(projections) == 2 and all("S(1)" in p for p in projections), projections
 
 
 def test_lru_kernels_compile_at_the_cells_shape_named_after_their_wrappers(one_chip, compiled_kernels):
