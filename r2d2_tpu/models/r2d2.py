@@ -230,15 +230,31 @@ class R2D2Network(nn.Module):
         # that re-layout lands inside the conv and doubles its time)
         frames = obs.reshape(B * T, -1)
 
-        def encode_at(idx):
+        def frames_at(idx):
             # ONE flattened index, as learner.make_store_gather: the
             # two-index gather of uint8 frames halts the v5e's core
             flat = (row0 + idx).reshape(-1)
             taken = jnp.take(frames, flat, axis=0, mode="clip")
-            return encode(taken.reshape(-1, *obs.shape[2:])).reshape(B, idx.shape[1], -1)
+            return taken.reshape(-1, *obs.shape[2:])
 
+        def encoded(part, steps):
+            return encode(part).reshape(B, steps, -1)
+
+        # conv1 is handed each part as bytes in frame shape behind a barrier,
+        # which keeps `encode`'s convert behind the reshape: the chip's
+        # compiler re-lays the part out at one byte an entry and computes the
+        # convert inside each conv fusion that reads it. Left free, it hoists
+        # the convert onto the flat rows, once for both nets, and every conv
+        # reads a bf16 copy of the part from memory. The others' bytes are
+        # released together with the window's encoding, so the two parts are
+        # encoded one after the other: with both released at once the
+        # encoder's arrays of both parts take the fast memory from the core's
+        # and the update gains nothing (PERF.md finding 50.2: core +0.084 ms
+        # against -0.047 in this order, on an encoder 0.09-0.10 faster)
+        mine = encoded(jax.lax.optimization_barrier(frames_at(window)), W)
+        rest, mine = jax.lax.optimization_barrier((frames_at(others), mine))
         x = beside(_time_order(
-            encode_at(window), jax.lax.stop_gradient(encode_at(others)), start[:, 0]
+            mine, jax.lax.stop_gradient(encoded(rest, T - W)), start[:, 0]
         ))
         # made once and kept, as the gather's result was: left to fuse, the
         # chip's compiler makes x again for the core's backward, and with

@@ -13,7 +13,7 @@ as `R2D2Network._core_input` splits them where the core cuts at burn-in
 85, lru 32 x 581 in one call). runs/encoder_conv1_microbench.py starts from
 flat `u8[N, 7056]` rows and so never timed what lies between. K updates to a
 call under `lax.scan`, each with coordinates of its own, as the programs run
-them. Four forms of the hand-over, bit-equal at conv1's input:
+them. Five forms of the hand-over, bit-equal at conv1's input:
 
   bt           rows.reshape(B, T, 7168)[..., :7056] -> (B, T, 21, 21, 16); the
                model merges (B, T) afterwards (the program until PR 43)
@@ -24,6 +24,14 @@ them. Four forms of the hand-over, bit-equal at conv1's input:
                transposition cheaper at one byte, or fused into the convert at two
   rows         the model is handed (B, T, 56, 128) rows and slices / reshapes
                them at the conv, after its own convert
+  unshared     `merged`, and behind the seam each gathered part goes to the
+               convert in frame shape behind an `optimization_barrier`: the
+               re-layout at one byte, the convert inside the conv fusions, no
+               bf16 copy of a part that both nets read (`_core_input` since
+               PR 50, which also encodes the two parts one after the other:
+               that is about where the CORE's arrays live, PERF.md finding
+               50.2, and nothing here holds a core; with no seam, lru, it
+               is `merged` to the letter)
 
 One JSON line per reading: host clock around `--reps` calls in flight, per
 UPDATE (a call is K of them), median of 5 rounds (never one blocking call:
@@ -51,7 +59,7 @@ CELLS = {
     "dp4": (16, 85, 45, 16, 441, 160),
 }
 TINY = {"nature": (2, 6, 4, 2, 9, 3), "lru": (2, 6, 6, 2, 9, 3), "dp4": (1, 6, 4, 2, 9, 3)}
-FORMS = ("bt", "merged", "merged_bf16", "rows")
+FORMS = ("bt", "merged", "merged_bf16", "rows", "unshared")
 # --allow-cpu: the smallest frame the trunk takes whose sides its stride divides
 OBS_SHAPE, TINY_OBS_SHAPE, BLOCK = (84, 84, 1), (36, 36, 1), 4
 
@@ -87,15 +95,17 @@ def main(argv=None) -> int:
         B, T = rows.shape[:2]
         if form == "bt":
             return rows.reshape(B, T, R * LANES)[..., :n].reshape(B, T, *stored)
-        if form == "merged":
+        if form in ("merged", "unshared"):
             return rows_as_stored(rows, obs_shape, BLOCK)  # the program's own, since PR 43
         if form == "merged_bf16":
             return rows.astype(dtype).reshape(-1, R * LANES)[:, :n].reshape(B, T, *stored)
         return rows
 
-    def conv_input(form, frames):
+    def conv_input(form, frames, behind_seam=False):
         """`_core_input.encode`'s first line: (N, ...) as handed -> conv1's
         (N, 21, 21, 16) input, [0, 1] in bf16."""
+        if form == "unshared" and behind_seam:
+            frames = jax.lax.optimization_barrier(frames.reshape(-1, *stored))
         x = frames.astype(dtype) / 255.0
         if form == "rows":
             x = x.reshape(-1, R * LANES)[:, :n]
@@ -114,7 +124,8 @@ def main(argv=None) -> int:
         others = jnp.where(c < start, c, c + W)
         row0 = jnp.arange(B, dtype=jnp.int32)[:, None] * T
         frames = obs.reshape(B * T, -1)
-        at = lambda idx: conv_input(form, jnp.take(frames, (row0 + idx).reshape(-1), axis=0, mode="clip"))
+        at = lambda idx: conv_input(
+            form, jnp.take(frames, (row0 + idx).reshape(-1), axis=0, mode="clip"), behind_seam=True)
         return [at(window), at(others)]
 
     def programs(form, T, W, slot):

@@ -30,12 +30,15 @@ def test_every_form_of_the_hand_over_gives_conv1_the_parents_bits(cell, capsys, 
     lines = [json.loads(l) for l in capsys.readouterr().out.splitlines() if l.startswith("{")]
     assert rc == 0
     readings = [l for l in lines if "form" in l]
-    assert [l["form"] for l in readings] == list(bench.FORMS) == ["bt", "merged", "merged_bf16", "rows"]
+    assert [l["form"] for l in readings] == list(bench.FORMS) == ["bt", "merged", "merged_bf16", "rows", "unshared"]
     for l in readings:
         assert l["bit_equal_at_conv_input"] is True and l["update_ms"] > 0
         assert (l["cell"], l["rows"], l["T"], l["frames_with_gradient"]) == (cell, B, T, B * W)
-    assert sorted(lines[-1]["faster_than_bt_ms"]) == ["merged", "merged_bf16", "rows"]
+    assert sorted(lines[-1]["faster_than_bt_ms"]) == ["merged", "merged_bf16", "rows", "unshared"]
     assert sorted(os.listdir(tmp_path)) == sorted(f"{cell}.{form}.txt" for form in bench.FORMS)
+    # `unshared` changes the seam's path alone: with no seam it compiles to `merged`'s text
+    merged, unshared = ((tmp_path / f"{cell}.{form}.txt").read_text() for form in ("merged", "unshared"))
+    assert (merged == unshared) == (W == T)
 
 
 def test_it_reads_nothing_without_a_chip(capsys):

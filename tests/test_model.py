@@ -492,6 +492,105 @@ def test_core_inputs_last_columns_are_the_time_ordered_actions_and_rewards(geome
 
 
 # ---------------------------------------------------------------------------
+# Behind the seam each part's gathered frames reach conv1 as bytes in frame
+# shape, behind an `optimization_barrier` (PR 50: on the chip the convert then
+# sits inside the conv fusions, not in a bf16 copy both nets read). A barrier
+# changes no value: the form without it, where the compiler was free to make
+# the convert once on the flat rows, lives HERE as the plain reference.
+
+
+def shared_convert_core_input(m, obs, last_action, last_reward, burn_in=None):
+    """`_core_input` until PR 50, to the letter."""
+    from r2d2_tpu.models.r2d2 import _time_order
+
+    dtype = jnp.dtype(m.compute_dtype)
+
+    def encode(obs):
+        return m.enc(obs.astype(dtype) / 255.0)
+
+    def beside(latent):
+        onehot = jax.nn.one_hot(last_action, m.action_dim, dtype=dtype)
+        return jnp.concatenate([latent, onehot, last_reward.astype(dtype)[..., None]], axis=-1)
+
+    if burn_in is None:
+        return beside(encode(obs))
+    B, T = obs.shape[:2]
+    W = m.learning_steps + m.forward_steps
+    start = jnp.clip(burn_in, 0, T - W).astype(jnp.int32)[:, None]
+    window = start + jnp.arange(W, dtype=jnp.int32)[None, :]
+    c = jnp.arange(T - W, dtype=jnp.int32)[None, :]
+    others = jnp.where(c < start, c, c + W)
+    row0 = jnp.arange(B, dtype=jnp.int32)[:, None] * T
+    frames = obs.reshape(B * T, -1)
+
+    def encode_at(idx):
+        taken = jnp.take(frames, (row0 + idx).reshape(-1), axis=0, mode="clip")
+        return encode(taken.reshape(-1, *obs.shape[2:])).reshape(B, idx.shape[1], -1)
+
+    x = beside(_time_order(encode_at(window), jax.lax.stop_gradient(encode_at(others)), start[:, 0]))
+    return jax.lax.optimization_barrier(x)
+
+
+def shared_convert_unroll(m, obs, la, lr, hid, burn, learn, fwd):
+    """`unroll` around the reference above: the seam's split where the core
+    cuts at burn-in, the one call (`burn_in=None`) where it does not."""
+    B, T = obs.shape[:2]
+    if m.core.cuts_at_burn_in:
+        x = shared_convert_core_input(m, obs, la, lr, burn)
+    else:
+        x = shared_convert_core_input(
+            m, obs.reshape(B * T, *obs.shape[2:]), la.reshape(B * T), lr.reshape(B * T)
+        ).reshape(B, T, -1)
+    outs, _ = m.core(x, unpack_state(hid), burn_in=burn)
+    q_learn, q_boot = m._dueling_window(outs, burn, learn, fwd)
+    mask = (jnp.arange(m.learning_steps, dtype=jnp.int32)[None, :] < learn[:, None]).astype(jnp.float32)
+    return q_learn, q_boot, mask
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("burn_in", ["given", "None"])
+def test_unroll_is_the_shared_convert_forms_in_outputs_and_gradients_bit_for_bit(burn_in, dtype):
+    """`burn_in` given: the LSTM core, whose seam takes the changed branch of
+    `_core_input`; `None`: the LRU core, whose one call must be what it was."""
+    cfg = tiny_test().replace(
+        encoder="nature", obs_shape=(36, 36, 1), precision="bf16" if dtype == "bfloat16" else "fp32",
+        recurrent_core="lstm" if burn_in == "given" else "lru")
+    net, params = make_net(cfg)
+    assert net.core.cuts_at_burn_in == (burn_in == "given")
+    assert jnp.dtype(net.compute_dtype) == jnp.dtype(dtype)
+    T, L, F = cfg.seq_len, cfg.learning_steps, cfg.forward_steps
+    assert T > L + F  # the seam's split engages
+    rng = np.random.default_rng(50)
+    B = 4
+    obs, la, lr, hid = random_inputs(cfg, rng, B=B)
+    burn = jnp.array([0, T - L - F, 1, T - L - F + 2], jnp.int32)  # the last: past the clip
+    learn = jnp.array([L, L, L - 1, L], jnp.int32)
+    fwd = jnp.array([F, F, 1, F], jnp.int32)
+    action = jnp.asarray(rng.integers(0, cfg.action_dim, size=(B, L)).astype(np.int32))
+    reward = jnp.asarray(rng.normal(size=(B, L)).astype(np.float32))
+    boot_weight = jnp.asarray(rng.normal(size=(B, L, cfg.action_dim)).astype(np.float32))
+
+    def run(method):
+        def loss(p):
+            views = net.apply(p, obs, la, lr, hid, burn, learn, fwd, method=method)
+            return learner_like_loss(views, action, reward, boot_weight), views
+
+        return jax.jit(jax.value_and_grad(loss, has_aux=True))(params)
+
+    (loss_got, views_got), g_got = run(net.unroll)
+    (loss_want, views_want), g_want = run(shared_convert_unroll)
+    for got, want in zip(views_got, views_want):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert float(loss_got) == float(loss_want)
+    reached = 0
+    for (path, g), (_, w) in zip(
+            jax.tree_util.tree_leaves_with_path(g_got), jax.tree_util.tree_leaves_with_path(g_want)):
+        reached += bool(np.abs(np.asarray(w)).max() > 0)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), err_msg=jax.tree_util.keystr(path))
+    assert reached == len(jax.tree_util.tree_leaves(g_want))  # every parameter is reached
+
+
+# ---------------------------------------------------------------------------
 # The tail of `unroll` (PR 46): each row's learning and bootstrap positions are
 # ONE window of the core's outputs, moved without an index per (row, step), and
 # the heads run once over it. The indexed formula it replaced lives HERE, as

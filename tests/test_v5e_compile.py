@@ -366,29 +366,35 @@ def _multi_program(config, topo):
     return _MULTI[config]
 
 
+def _top_level(text):
+    """Of a compiled program's text: (name, dtype, dims, opcode, op_name) of
+    every instruction with an array result outside the fusions' bodies: what
+    the program writes to memory. A bitcast moves nothing and is left out."""
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    inside = None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            inside = head.group(1)
+            continue
+        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = (\w+)\[([\d,]+)\]\S* ([a-z][\w\-]*)\(", line)
+        if not m or inside in fused or m.group(4) in ("bitcast", "parameter", "get-tuple-element"):
+            continue
+        op = re.search(r'op_name="([^"]*)"', line)
+        yield m.group(1), m.group(2), tuple(map(int, m.group(3).split(","))), m.group(4), op.group(1) if op else ""
+
+
 def _hand_over_passes(text, B, T, frame_bytes):
     """Of a compiled step program's text: every `u8[B,T,bytes]` shape in it
     (the batch's bytes tiled over (T, bytes)), and every instruction between
     the store gather and conv1 (op_name under `r2d2_gather` or `_core_input`,
     not under `enc/`), outside the fusions' bodies, that writes a bf16 array of
     B x T x frame_bytes elements or more: a pass over the whole batch at two
-    bytes. A bitcast moves nothing and is not one."""
-    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    bytes."""
     tiled = sorted(set(re.findall(rf"\bu8\[{B},{T},\d+\]", text)))
-    inside, passes = None, []
-    for line in text.splitlines():
-        head = re.match(r"\s*(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
-        if head:
-            inside = head.group(1)
-            continue
-        m = re.match(r"\s*(?:ROOT )?(%[\w.\-]+) = bf16\[([\d,]+)\]\S* ([a-z][\w\-]*)\(", line)
-        if not m or inside in fused or m.group(3) in ("bitcast", "parameter", "get-tuple-element"):
-            continue
-        op = re.search(r'op_name="([^"]*)"', line)
-        op = op.group(1) if op else ""
-        if re.search(r"jit\(r2d2_gather\)|_core_input", op) and "/enc/" not in op:
-            if math.prod(map(int, m.group(2).split(","))) >= B * T * frame_bytes:
-                passes.append(m.group(1))
+    passes = [name for name, dtype, dims, _, op in _top_level(text)
+              if dtype == "bf16" and math.prod(dims) >= B * T * frame_bytes
+              and re.search(r"jit\(r2d2_gather\)|_core_input", op) and "/enc/" not in op]
     return tiled, passes
 
 
@@ -420,6 +426,46 @@ def test_the_batch_reaches_conv1_with_the_frame_index_as_one_axis(config, topo, 
         assert len(passes) == 1 and "convert" in passes[0], passes
     else:
         assert passes == [], passes
+
+
+@pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581", "nature-lstm512-dp4"])
+def test_behind_the_seam_conv1_is_handed_bytes_and_no_bf16_copy_of_a_part_is_written(config, topo, compiled_kernels):
+    """The same program's text. Behind the LSTM's seam `_core_input` gathers
+    each row's window (B x (L + F) frames) and its other frames (B x (T - L -
+    F)) once for both nets, and hands each part to conv1 as bytes in frame
+    shape behind an `optimization_barrier`: the program writes no bf16 array
+    the size of a part (PR 50's parent wrote `convert_multiply_fusion
+    bf16[2880,7056]` and `bf16[2560,7056]`, re-laid the first out at two
+    bytes an entry and had every conv1 read those copies: 0.14 of nature's
+    3.96 ms an update, PERF.md finding 50), each part is re-laid-out for the
+    conv as `u8`, and the convert sits inside the conv fusions, which take a
+    `u8[N,21,21,16]` operand. There is still ONE gather of each part. (That
+    the others' bytes are released only with the window's encoding shows in
+    where the core's arrays live: the `S(1)` pins of the next test.) The
+    no-seam path is untouched: lru keeps its one `convert_multiply_fusion
+    bf16[18592,7056]` (there the barrier would part the 7,168 -> 7,056 slice
+    from the convert: a pass of its own, finding 50.1). The mechanism engages
+    in every update or in none, so this text is its tripwire."""
+    cfg, text = _multi_program(config, topo)
+    B, T, W, n = cfg._rows_per_device(), cfg.seq_len, cfg.learning_steps + cfg.forward_steps, math.prod(cfg.obs_shape)
+    written = list(_top_level(text))
+    if cfg.recurrent_core == "lru":
+        converts = [name for name, dtype, dims, _, _ in written if (dtype, dims) == ("bf16", (B * T, n))]
+        assert len(converts) == 1 and "convert_multiply_fusion" in converts[0], converts
+        return
+    parts = {"window": B * W, "others": B * (T - W)}
+    assert parts == {"nature-lstm512": {"window": 2880, "others": 2560},
+                     "nature-lstm512-dp4": {"window": 720, "others": 640}}[config]
+    for part, N in parts.items():
+        copies = [(name, dims) for name, dtype, dims, _, op in written
+                  if dtype == "bf16" and math.prod(dims) == N * n and "_core_input" in op]
+        assert copies == [], (part, copies)
+        gathers = [name for name, dtype, dims, opcode, op in written
+                   if (dtype, dims, opcode) == ("u8", (N, n), "fusion") and op.endswith("_core_input/jit(_take)/gather")]
+        assert len(gathers) == 1, (part, gathers)
+        # the conv fusions' own signatures: a part's bytes go in, in frame shape
+        convs = re.findall(rf"^%fused_computation\S* \(([^)]*u8\[{N},21,21,16\][^)]*)\) -> \(?(?:bf16|f32|u32)\[", text, re.M)
+        assert any("bf16[2,2,16,32]" in operands for operands in convs), (part, convs)  # with conv1's blocked kernel
 
 
 @pytest.mark.parametrize("config", ["nature-lstm512", "lru-seq581", "nature-lstm512-dp4"])
@@ -468,7 +514,12 @@ def test_the_tail_of_unroll_and_the_loss_hold_no_index_per_row_and_step(config, 
         # core's own arrays lost their place in the chip's fast memory (`S(1)`
         # in a layout): the update was 3.4 % SLOWER. `_core_input` keeps its
         # result behind an `optimization_barrier`; a later change that evicts
-        # these fails here, on the CPU, where PR 49 needed a traced pair
+        # these fails here, on the CPU, where PR 49 needed a traced pair.
+        # (PR 50's first form did: both parts' bytes released to conv1 at
+        # once, the target's projection out of `S(1)`, the core +0.084 ms
+        # on an encoder 0.10 faster, the rate -0.04 %. Released one after
+        # the other the same bytes leave both projections where they were
+        # and the rate reads +3.1 %: PERF.md finding 50.2)
         backward = re.findall(r"^\s*%_lstm_seq_bwd_call[.\d]* = (\S+) custom-call\(", text, re.M)
         assert len(backward) == 1 and "S(1)" in backward[0], backward
         projections = re.findall(
