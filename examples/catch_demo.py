@@ -6,7 +6,7 @@ catch, IMPALA encoder, 128-hidden LSTM, bf16, on-device collection (E=64
 envs in one jitted scan), HBM replay, K=8 fused learner dispatches.
 
 --full switches to the flagship Atari-scale system (84x84, Nature trunk,
-512-hidden LSTM — the bench.py configuration). Value propagation across
+512-hidden LSTM — the benchmark's nature-lstm512 network). Value propagation across
 82-step episodes from a terminal-only reward needs tens of thousands of
 updates (the reference budgets 100k, config.py:15); `--full
 --steps 100000 --mode fused` runs that complete budget in ~1 h on one v5e chip and

@@ -176,14 +176,14 @@ def resharded_train_step_jaxpr(precision: str, dp: int = 2) -> str:
     the dp the run started with — so the gate traces that layout too."""
     import jax
 
-    from r2d2_tpu.learner import make_sharded_fused_train_step
+    from r2d2_tpu.learner import make_sharded_fused_multi_train_step
     from r2d2_tpu.parallel.mesh import make_mesh
     from r2d2_tpu.replay.block import store_field_specs
 
     cfg = _cfg(precision).replace(replay_plane="sharded", dp_size=dp)
     net, state = _net_and_state(precision)
     mesh = make_mesh(dp=dp, tp=1, devices=jax.devices()[:dp])
-    step = make_sharded_fused_train_step(cfg, net, mesh, donate=False)
+    step = make_sharded_fused_multi_train_step(cfg, net, mesh, 1, donate=False)
     sds = jax.ShapeDtypeStruct
     stores = {
         k: sds((cfg.num_blocks, *shape), dt)
@@ -191,9 +191,9 @@ def resharded_train_step_jaxpr(precision: str, dp: int = 2) -> str:
     }
     B = cfg.batch_size // dp
     coords = (
-        sds((dp, B), np.int32),  # per-shard LOCAL block ids
-        sds((dp, B), np.int32),  # sequence-in-block
-        sds((dp, B), np.float32),  # IS weights
+        sds((1, dp, B), np.int32),  # per-shard LOCAL block ids
+        sds((1, dp, B), np.int32),  # sequence-in-block
+        sds((1, dp, B), np.float32),  # IS weights
     )
     return str(jax.make_jaxpr(step)(state, stores, *coords))
 

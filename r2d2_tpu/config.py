@@ -313,9 +313,8 @@ class R2D2Config:
     # BlockStreamPublisher in as the bridge's replay sink; the learner
     # runs an IngestService that fans N host streams into its replay
     # plane. None of these knobs change any behavior unless the transport
-    # endpoints are actually constructed (bench.py --mode podloop, the
-    # podloop CLI, tests) — the single-process golden paths never read
-    # them.
+    # endpoints are actually constructed (the podloop CLI, tests) — the
+    # single-process golden paths never read them.
     #
     # Publisher spool bound, in blocks: finished Blocks awaiting
     # acknowledgement (including the whole disconnected window) are kept
@@ -403,8 +402,7 @@ class R2D2Config:
     # run preset's own dims; "wide"/"xl" grow hidden_dim, "deep"/
     # "deep_wide" stack encoder_depth extra latent layers. Applied as
     # plain field overrides by apply_model_preset() (train.py
-    # --model-preset); bench.py's largest-model-that-fits probe sizes
-    # them against each mesh shape's per-device HBM.
+    # --model-preset).
     model_preset: str = "base"
     # Extra Dense(latent)+relu layers appended to the encoder trunk after
     # the (possibly tp-sharded) latent projection — the deeper-encoder
@@ -1198,9 +1196,9 @@ def long_context(
     comes out right: 82 rows x fall-12 = 984). Pass any other env name
     to retarget (e.g. a NetHack/Craftax-class env where one is
     installed) and override the net defaults per env; the catch-specific
-    geometry below applies only to catch-family names. bench.py's
-    long_context mode pins its own shapes to the config-5 spec, so this
-    default does not move the bench row's workload."""
+    geometry below applies only to catch-family names. The benchmark's
+    lru-seq581 configuration pins its own shapes (benchmark/configs/), so
+    this default does not move its workload."""
     from r2d2_tpu.envs.catch import catch_params, is_catch_name
 
     kw = {}

@@ -144,8 +144,8 @@ def _q_at(q, a):
 def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
     """The per-batch loss closure (params, target_params, batch, denom) ->
     (loss, (priorities, aux)), shared by every train-step builder and by
-    the bench's per-phase breakdown (which times it as its own jitted
-    program to isolate loss+grad cost from the optimizer)."""
+    the benchmark's correctness check (benchmark/correct.py runs it as its
+    own program against the plain reference)."""
     eps = cfg.value_rescale_eps
 
     def loss_fn(params, target_params, b: DeviceBatch, denom):
@@ -365,40 +365,22 @@ def make_store_gather(cfg: R2D2Config, as_stored: bool = False):
     return gather_batch
 
 
-def make_fused_train_step(cfg: R2D2Config, net: R2D2Network, donate: bool = True):
-    """Train step over a DEVICE-RESIDENT replay store.
-
-    Signature: (state, stores, b, s, is_weights) -> (state, metrics,
-    priorities). The batch windows are gathered in-jit straight from HBM
-    (see replay/device_store.py), so only the (B,) sample coordinates cross
-    the host->device boundary per update — the whole point on hardware
-    where transfer, not compute, bounds the learner. Numerically identical
-    to make_train_step on the equivalent host-assembled batch (pinned by
-    test)."""
-    raw = _raw_train_step(cfg, net)
-    gather_batch = make_store_gather(cfg, as_stored=True)
-
-    def fused(state: TrainState, stores, b, s, is_weights):
-        batch = gather_batch(stores, b, s, is_weights)
-        return raw(state, batch)
-
-    return jax.jit(fused, donate_argnums=(0,) if donate else ())
-
-
 def make_fused_multi_train_step(
     cfg: R2D2Config, net: R2D2Network, num_steps: int, donate: bool = True
 ):
-    """K train steps in ONE dispatch: lax.scan over stacked sample
-    coordinates, each iteration gathering its batch from the HBM store and
-    applying the full update (in-jit target sync included).
+    """The train step over a DEVICE-RESIDENT replay store
+    (replay/device_store.py): K updates in ONE dispatch, a lax.scan over
+    stacked sample coordinates in which each iteration gathers its batch
+    in-jit straight from HBM and applies the full update (in-jit target sync
+    included). Only the (K, B) coordinates cross the host->device boundary.
 
-    Exactly equivalent to running the K single fused steps sequentially on
-    the same pre-drawn coordinates (pinned by test) — the host simply was
-    not involved between them. This is the dispatch-overhead amortizer: the
-    host's per-call launch cost is paid once per K updates (whether that
-    still pays on a directly attached chip is a benchmark question,
-    ROADMAP S2). The semantic trade is that
-    priorities and new blocks apply to the tree at K-update granularity —
+    K = 1 is the same program with one iteration; there is no other way to
+    run an update on an HBM store. Each iteration is numerically identical to
+    make_train_step on the equivalent host-assembled batch (pinned by
+    tests/test_device_store.py for K in {1, 4}). The host's per-call launch
+    cost is paid once per K updates (whether that still pays on a directly
+    attached chip is a benchmark question, ROADMAP S2). The semantic trade is
+    that priorities and new blocks apply to the tree at K-update granularity:
     the reference's own pipeline already tolerates a deeper lag (its batch
     queue + learner prefetch hold ~12 batches, reference worker.py:364-371).
 
@@ -425,14 +407,15 @@ def make_multi_update_core(
 
     axis_name="dp": the body runs per-shard under shard_map — gathers hit
     the LOCAL store shard and gradients/denominators psum over the axis
-    (same contract as make_sharded_fused_train_step); b/s/w are then the
-    local (K, B/dp) coordinate stacks.
+    (exact thanks to the globally-psum'd loss denominator); b/s/w are then
+    the local (K, B/dp) coordinate stacks.
 
     is_from_priorities=True (needs axis_name): w carries RAW sampled tree
     priorities; each scan iteration normalizes ITS OWN batch against that
-    update's batch-global minimum via a pmin over the axis — per-update
-    semantics identical to K single is_from_priorities steps (the
-    multihost K-dispatch contract, replay/multihost_store.py)."""
+    update's batch-global minimum via a pmin over the axis. This is how the
+    multi-host replay gets exact single-tree IS semantics with zero
+    cross-host control traffic (replay/multihost_store.py): each host only
+    knows its local priorities, the collective finds the global min."""
     if is_from_priorities and axis_name is None:
         raise ValueError("is_from_priorities needs an axis_name (pmin)")
     raw = _raw_train_step(cfg, net, axis_name=axis_name)
@@ -447,7 +430,8 @@ def make_multi_update_core(
         def body(state, xs):
             bb, ss, ww = xs
             if is_from_priorities:
-                # same formula as make_sharded_fused_train_step's body
+                # same formula as SumTree.sample (zero-priority leaves clamp
+                # to the min -> weight 1.0)
                 p = ww
                 pos_min = jnp.min(jnp.where(p > 0, p, jnp.inf))
                 min_p = jax.lax.pmin(pos_min, axis_name)
@@ -469,10 +453,12 @@ def make_sharded_fused_multi_train_step(
     cfg: R2D2Config, net: R2D2Network, mesh, num_steps: int, donate: bool = True,
     is_from_priorities: bool = False,
 ):
-    """K updates in ONE shard_map dispatch over a dp-SHARDED replay store:
-    the multi-chip form of make_fused_multi_train_step. Each device scans
-    K updates gathering its (B/dp) sub-batches from its LOCAL store shard
-    and psums gradients over dp per update (ICI).
+    """K updates in ONE shard_map dispatch over a dp-SHARDED replay store
+    (replay/sharded_store.ShardedDeviceReplay, replay/multihost_store): the
+    multi-chip form of make_fused_multi_train_step, for every K >= 1. Each
+    device scans K updates gathering its (B/dp) sub-batches from its LOCAL
+    store shard (no cross-device data-plane traffic) and psums gradients
+    over dp per update (ICI). Params/opt state replicated in and out.
 
     Signature: (state, stores, b, s, w) with b/s/w of shape (K, dp, B/dp)
     and b LOCAL to each shard; returns (state, metrics-of-last-step,
@@ -493,7 +479,7 @@ def make_sharded_fused_multi_train_step(
         return state, metrics, prios[:, None]
 
     # P("dp") is a PREFIX spec for the stores dict: it applies to every
-    # field array (same idiom as make_sharded_fused_train_step).
+    # field array.
     # dp_manual_axes: with tp > 1 the map is MANUAL over dp only — the
     # mesh's tp axis stays GSPMD-auto, so params arriving with tp
     # NamedShardings (parallel/mesh.train_state_shardings) are
@@ -508,27 +494,6 @@ def make_sharded_fused_multi_train_step(
         check_vma=False,
     )
     return jax.jit(sharded, donate_argnums=(0,) if donate else ())
-
-
-def make_gather_step(cfg: R2D2Config):
-    """Jitted (stores, b, s, is_weights) -> DeviceBatch: materialize the
-    sampled windows into a fresh HBM batch AT SAMPLE TIME.
-
-    This is the pipelined-mode counterpart of the fused step: a queued
-    fused-step item holds only coordinates, so a store slot overwritten
-    while the item waits would be gathered as DIFFERENT data than was
-    sampled. Gathering under the store lock at sample time freezes the
-    batch; the queue then carries ~4 MB of HBM per item instead of a
-    staleness hazard."""
-    return jax.jit(make_store_gather(cfg))
-
-
-def make_batch_train_step(cfg: R2D2Config, net: R2D2Network, donate: bool = True):
-    """Jitted (state, DeviceBatch) -> (state, metrics, priorities) over a
-    pre-gathered device-resident batch (from make_gather_step). Donates the
-    batch too: it was materialized for exactly one update."""
-    raw = _raw_train_step(cfg, net)
-    return jax.jit(raw, donate_argnums=(0, 1) if donate else ())
 
 
 def make_stacked_batch_train_step(
@@ -564,100 +529,6 @@ def make_stacked_batch_train_step(
         return state, jax.tree.map(lambda x: x[-1], metrics), prios
 
     return jax.jit(multi, donate_argnums=(0, 1) if donate else ())
-
-
-def make_sharded_gather_step(cfg: R2D2Config, mesh):
-    """shard_map gather over the dp-sharded stores: each device gathers its
-    (B/dp) sub-batch locally; the result is one global DeviceBatch with
-    every leaf's batch axis sharded over dp — ready for the plain-jit train
-    step (XLA inserts the gradient psum)."""
-    from jax.sharding import PartitionSpec as P
-    from r2d2_tpu.parallel.jax_compat import shard_map
-    from r2d2_tpu.parallel.mesh import dp_manual_axes
-
-    gather_batch = make_store_gather(cfg)
-
-    def body(stores, b, s, is_weights):
-        return gather_batch(stores, b[0], s[0], is_weights[0])
-
-    out_specs = DeviceBatch(*([P("dp")] * len(DeviceBatch._fields)))
-    if cfg.num_tasks <= 1:
-        # single-task gathers return task=None; the spec tree must carry
-        # the same empty subtree for the structures to match
-        out_specs = out_specs._replace(task=None)
-    gathered = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P("dp"), P("dp"), P("dp"), P("dp")),
-        out_specs=out_specs,
-        axis_names=dp_manual_axes(mesh),
-        check_vma=False,
-    )
-    return jax.jit(gathered)
-
-
-def make_sharded_fused_train_step(
-    cfg: R2D2Config,
-    net: R2D2Network,
-    mesh,
-    donate: bool = True,
-    is_from_priorities: bool = False,
-):
-    """Fused train step over a dp-SHARDED device replay store
-    (replay/sharded_store.ShardedDeviceReplay).
-
-    shard_map over the mesh's dp axis: each device gathers its local
-    (B/dp)-sequence sub-batch from its OWN store shard — no cross-device
-    data-plane traffic — computes local gradients, and all-reduces them
-    with lax.psum over dp (ICI; exact thanks to the globally-psum'd loss
-    denominator). Params/opt state replicated in and out.
-
-    Signature: (state, stores, b, s, is_weights) -> (state, metrics,
-    priorities) where b/s/is_weights are (dp, B/dp) stacked per-shard
-    coordinates with b LOCAL to each shard, and priorities come back
-    (dp, B/dp).
-
-    is_from_priorities=True: the third coordinate array carries RAW sampled
-    tree priorities instead of precomputed IS weights; the step normalizes
-    them in-jit against the BATCH-GLOBAL minimum via a pmin collective over
-    dp. This is how the multi-host replay gets exact single-tree IS
-    semantics with zero cross-host control traffic (replay/
-    multihost_store.py) — each host only knows its local priorities, the
-    collective finds the global min."""
-    from jax.sharding import PartitionSpec as P
-    from r2d2_tpu.parallel.jax_compat import shard_map
-    from r2d2_tpu.parallel.mesh import dp_manual_axes
-
-    raw = _raw_train_step(cfg, net, axis_name="dp")
-    gather_batch = make_store_gather(cfg, as_stored=True)
-
-    def body(state: TrainState, stores, b, s, is_weights):
-        # local views: stores = this device's (nb/dp, ...) block shard;
-        # b/s/is_weights arrive (1, B/dp) from their stacked (dp, B/dp) form
-        w = is_weights[0]
-        if is_from_priorities:
-            p = w
-            pos_min = jnp.min(jnp.where(p > 0, p, jnp.inf))
-            min_p = jax.lax.pmin(pos_min, "dp")
-            min_p = jnp.where(jnp.isfinite(min_p), min_p, 1.0)
-            # same formula as SumTree.sample (zero-priority leaves clamp
-            # to the min -> weight 1.0)
-            w = jnp.power(jnp.maximum(p, min_p) / min_p, -cfg.is_exponent)
-        batch = gather_batch(stores, b[0], s[0], w)
-        new_state, metrics, priorities = raw(state, batch)
-        return new_state, metrics, priorities[None, :]
-
-    # manual over dp only; tp stays GSPMD-auto (see
-    # make_sharded_fused_multi_train_step) so tp-sharded params compose
-    sharded = shard_map(
-        body,
-        mesh=mesh,
-        in_specs=(P(), P("dp"), P("dp"), P("dp"), P("dp")),
-        out_specs=(P(), P(), P("dp")),
-        axis_names=dp_manual_axes(mesh),
-        check_vma=False,
-    )
-    return jax.jit(sharded, donate_argnums=(0,) if donate else ())
 
 
 def make_manual_train_step(cfg: R2D2Config, mesh, donate: bool = True):

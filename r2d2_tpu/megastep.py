@@ -170,9 +170,7 @@ class _DeferredDrainRunner:
         identifies the reserved slots; chunk_host the bookkeeping
         arrays, both None when collect is False);
       _account_chunk(token, arrays) -> recorded
-        install a drained chunk's accounting into the tree(s);
-      _apply_priorities(draw, row)
-        one K-row priority application under the draw's staleness stamp.
+        install a drained chunk's accounting into the tree(s).
     """
 
     def _init_protocol(
@@ -314,7 +312,9 @@ class _DeferredDrainRunner:
             rows = np.asarray(prios)
         with _priorities_span():
             for row, d in zip(rows, draws):
-                self._apply_priorities(d, row)
+                # each row under its own draw's staleness window and lap
+                # stamp (old_ptr: an int, or one per shard)
+                self.replay.update_priorities(d.idxes, row, d.old_ptr, d.old_advances)
 
     def finish(self) -> int:
         """Apply the final in-flight readbacks (chunk accounting first,
@@ -333,8 +333,8 @@ class _DeferredDrainRunner:
 class FusedSystemRunner(_DeferredDrainRunner):
     """Drives the megastep against a DeviceReplayBuffer + DeviceCollector.
 
-    Owns the per-dispatch protocol (the Trainer's fused mode and bench.py
-    both go through here):
+    Owns the per-dispatch protocol (the Trainer's fused mode, and through
+    it the benchmark, go through here):
 
       1. under the replay lock: draw K x B coordinates, reserve the next E
          ring slots, dispatch (donating the stores), install the returned
@@ -435,9 +435,6 @@ class FusedSystemRunner(_DeferredDrainRunner):
                 ptr0, num_seq, sizes, chunk_prios, ep_rewards, dones
             )
         return int(sizes.sum())
-
-    def _apply_priorities(self, d, row) -> None:
-        self.replay.update_priorities(d.idxes, row, d.old_ptr, d.old_advances)
 
 
 # ---------------------------------------------------------------------------
@@ -649,9 +646,6 @@ class ShardedFusedRunner(_DeferredDrainRunner):
                 )
             recorded += int(sizes[sl].sum())
         return recorded
-
-    def _apply_priorities(self, d, row) -> None:
-        self.replay.update_priorities(d.idxes, row, d.old_ptrs, d.old_advances)
 
 
 class MultiHostFusedRunner(_DeferredDrainRunner):

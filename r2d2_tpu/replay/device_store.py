@@ -18,7 +18,7 @@ TPU-native split instead:
   over block_length env steps) via a donated jitted dynamic-slice update.
 - a training update ships ONLY the sampled sequence coordinates
   (b, s, is_weights — about a kilobyte); the fused train step gathers the
-  windows in-jit straight out of HBM (learner.make_fused_train_step).
+  windows in-jit straight out of HBM (learner.make_fused_multi_train_step).
 
 Concurrency contract: `_write` DONATES the store buffers, so a stores
 reference obtained before an add_block is dead after it. Dispatch every
@@ -231,6 +231,11 @@ class DeviceReplayBuffer(ReplayControlPlane):
         with self.lock:
             draws = [self._draw_sample_idx(rng) for _ in range(k)]
             return draws, fn(self.stores, draws)
+
+    def superstep_keys(self, key: jax.Array) -> jax.Array:
+        """The superstep's key from one dispatch key: one tree, one stream
+        (ShardedDeviceReplay makes one per dp shard of it)."""
+        return key
 
     def superstep_run(self, fn: Callable):
         """Dispatch an in-jit sample/train/write-back superstep under ONE

@@ -11,7 +11,7 @@ architecture — per-host ASYNC data planes + one SYNCHRONOUS SPMD learner:
   round-robin into those LOCAL shards only. No replay bytes ever cross
   hosts.
 - the train step is the SAME shard_map step as single-host
-  (learner.make_sharded_fused_train_step over the global mesh). Every
+  (learner.make_sharded_fused_multi_train_step over the global mesh). Every
   process calls it in lockstep — standard SPMD — passing global array
   VIEWS assembled zero-copy from the per-host buffers with
   jax.make_array_from_single_device_arrays. Gradient psum rides ICI
@@ -31,17 +31,17 @@ mesh level but splits a shard's store across devices; single-host tp>1 is
 covered by ShardedDeviceReplay). IS-weight normalization is EXACT
 single-tree semantics: hosts ship raw sampled priorities and the train
 step finds the batch-global minimum with a pmin collective over dp
-(learner.make_sharded_fused_train_step(is_from_priorities=True)) — the
-device mesh does the one piece of global coordination the weights need.
+(learner.make_sharded_fused_multi_train_step(is_from_priorities=True)) —
+the device mesh does the one piece of global coordination the weights need.
 
 Verified end to end by tests/test_multihost.py: a REAL 2-process CPU run
-(jax.distributed) trains 3 single steps PLUS two K=2 run_step_k
-dispatches (deferred drain included, global tree mass folded into the
-checksum) whose losses match the single-process 4-device run of this
-plane exactly; the assembled data plane matches ShardedDeviceReplay
-loss-for-loss on identical contents and coordinates; and one K-scan
-dispatch is pinned update-for-update against K sequential single steps
-on the same pre-drawn coordinates.
+(jax.distributed) trains 3 K=1 PLUS two K=2 run_step_k dispatches
+(deferred drain included, global tree mass folded into the checksum)
+whose losses match the single-process 4-device run of this plane exactly;
+the assembled data plane matches ShardedDeviceReplay loss-for-loss on
+identical contents and coordinates; and one K-scan dispatch is pinned
+update-for-update against K sequential K=1 dispatches on the same
+pre-drawn coordinates.
 """
 
 from __future__ import annotations
@@ -84,7 +84,7 @@ class MultiHostShardedReplay:
             g: np.take(mesh.devices, g, axis=axis).ravel()[0] for g in self.local_ids
         }
         # fixed for the life of the store; hot paths (install_global_stores,
-        # update_priorities, drain_pending) map output shards back by device
+        # drain_pending) map output shards back by device
         self._dev_to_g = {d: g for g, d in self._shard_device.items()}
 
         specs = store_field_specs(cfg)
@@ -110,10 +110,10 @@ class MultiHostShardedReplay:
         self._write = jax.jit(_write, donate_argnums=(0,))
         self._rr = 0  # round-robin over LOCAL shards
         self._seed = seed
-        self._epoch = 0  # sample_global counter (part of the draw seeds)
+        self._epoch = 0  # draw counter (part of the draw seeds)
         self._pending = None  # run_step_k's deferred (priorities, draws)
         # store-level lock: add_block's donated write swaps stores[g], so a
-        # concurrent run_step must not be assembling/dispatching over the
+        # concurrent run_step_k must not be assembling/dispatching over the
         # old buffers (same contract as run_with_stores on the other device
         # planes). Lock order is ALWAYS self.lock -> shard.lock.
         self.lock = threading.Lock()
@@ -159,7 +159,7 @@ class MultiHostShardedReplay:
         """Round-robin shard assignment for the next n blocks. The only
         touch of self._rr, so callers can stage each block's H2D copy onto
         its shard device BEFORE taking the store lock — a concurrent
-        run_step must never wait on a device transfer."""
+        run_step_k must never wait on a device transfer."""
         with self.lock:
             out = []
             for _ in range(n):
@@ -264,107 +264,29 @@ class MultiHostShardedReplay:
         for g in self.local_ids:
             self.stores[g] = fresh[g]
 
-    def sample_global(self):
-        """Draw B/dp sequences per LOCAL shard and assemble the global
-        (dp, B/dp) coordinate arrays for the shard_map step.
-
-        Each shard's draw stream is seeded by (seed, GLOBAL shard id,
-        epoch) — host-layout-independent, so the same seeds produce the
-        same global sample whether the shards live on one process or many
-        (pinned by the 2-process test).
-
-        Returns (b, s, raw_priorities) global arrays plus host-side
-        (idxes_by_shard, old_ptrs_by_shard, old_advances_by_shard) for the
-        priority round trip. The third array feeds a step built with
-        is_from_priorities=True."""
-        Bs = self.cfg.batch_size // self.dp
-        epoch = self._epoch
-        self._epoch += 1
-        idxes_by_shard: Dict[int, np.ndarray] = {}
-        old_ptrs: Dict[int, int] = {}
-        old_advances: Dict[int, int] = {}
-        per_b, per_s, per_w = {}, {}, {}
-        for g in self.local_ids:
-            rng = np.random.default_rng((self._seed, g, epoch))
-            shard = self.shards[g]
-            with shard.lock:
-                b, s, idxes, _w = shard._draw(rng)
-                old_ptrs[g] = shard.block_ptr
-                old_advances[g] = shard.ptr_advances
-                p = shard.tree.priorities_of(idxes)
-            dev = self._shard_device[g]
-            per_b[g] = jax.device_put(b.astype(np.int32)[None], dev)
-            per_s[g] = jax.device_put(s.astype(np.int32)[None], dev)
-            # ship RAW priorities: IS weights are computed IN the train
-            # step against the batch-global minimum via a pmin collective
-            # over dp (make_sharded_fused_train_step(is_from_priorities=
-            # True)) — exact single-tree semantics, layout-independent,
-            # no cross-host control traffic
-            per_w[g] = jax.device_put(p.astype(np.float32)[None], dev)
-            idxes_by_shard[g] = idxes
-        shape = (self.dp, Bs)
-        return (
-            self._assemble(per_b, shape, P("dp")),
-            self._assemble(per_s, shape, P("dp")),
-            self._assemble(per_w, shape, P("dp")),
-            idxes_by_shard,
-            old_ptrs,
-            old_advances,
-        )
-
-    def update_priorities(
-        self,
-        idxes_by_shard: Dict[int, np.ndarray],
-        priorities,
-        old_ptrs: Dict[int, int],
-        old_advances: Optional[Dict[int, int]] = None,
-    ) -> None:
-        """Apply the step's (dp, B/dp) dp-sharded priorities: each host
-        reads only its addressable rows, under its shard's own staleness
-        window AND lap stamp (a full ring lap between draw and apply wraps
-        the pointer back into the window mask's blind spot — the stamp is
-        the only guard, control_plane.update_priorities)."""
-        dev_to_g = self._dev_to_g
-        for shard_piece in priorities.addressable_shards:
-            g = dev_to_g[shard_piece.device]
-            row = np.asarray(shard_piece.data)[0]
-            self.shards[g].update_priorities(
-                idxes_by_shard[g], row, old_ptrs[g],
-                None if old_advances is None else old_advances[g],
-            )
-
     # ------------------------------------------------------------- dispatch
-
-    def run_step(self, step_fn: Callable, state):
-        """One collective training step: sample locally, assemble global
-        views, run the shard_map step (EVERY process must call this in the
-        same order — standard SPMD), apply local priorities.
-
-        step_fn: learner.make_sharded_fused_train_step(cfg, net, mesh,
-        is_from_priorities=True) — the step computes IS weights from the
-        raw priorities with a global pmin."""
-        with self.lock:
-            # sample + assemble + dispatch under the store lock: a
-            # concurrent add_block's donated swap must not invalidate the
-            # buffers behind the global views mid-dispatch
-            b, s, w, idxes_by_shard, old_ptrs, old_advances = self.sample_global()
-            new_state, metrics, priorities = step_fn(state, self.global_stores(), b, s, w)
-        self.update_priorities(idxes_by_shard, priorities, old_ptrs, old_advances)
-        return new_state, metrics
 
     def sample_global_k(self, k: int):
         """K independent global draws stacked for one K-scan dispatch
         (learner.make_sharded_fused_multi_train_step(is_from_priorities=
-        True)). Consumes k draw epochs — the i-th stacked draw uses the
-        exact seed the i-th sequential sample_global call would have, so
-        the K-dispatch samples the same coordinate sequence as K single
-        dispatches from the same tree state (layout-independent, like
-        sample_global).
+        True)): B/dp sequences per LOCAL shard and draw, assembled into
+        the global (K, dp, B/dp) coordinate arrays of the shard_map step.
+
+        Each shard's draw stream is seeded by (seed, GLOBAL shard id,
+        epoch), one epoch per draw — host-layout-independent, so the same
+        seeds produce the same global sample whether the shards live on one
+        process or many (pinned by the 2-process test), and a K-dispatch
+        samples the same coordinate sequence as K dispatches of one from
+        the same tree state.
 
         Returns ((b, s, w) global arrays of shape (K, dp, B/dp), with b
-        LOCAL to each shard and w carrying RAW priorities, plus a list of
-        K host-side draw records {idxes, old_ptrs, old_advances} for the
-        deferred priority drain). Caller holds self.lock."""
+        LOCAL to each shard and w carrying RAW priorities: IS weights are
+        computed IN the train step against the batch-global minimum via a
+        pmin collective over dp — exact single-tree semantics,
+        layout-independent, no cross-host control traffic; plus a list of
+        K host-side draw records {idxes, old_ptrs, old_advances}, each
+        keyed by local shard, for the deferred priority drain). Caller
+        holds self.lock."""
         Bs = self.cfg.batch_size // self.dp
         epoch0 = self._epoch
         self._epoch += k
@@ -399,9 +321,10 @@ class MultiHostShardedReplay:
         ), draws
 
     def run_step_k(self, multi_fn: Callable, state, k: int):
-        """K collective updates in ONE shard_map dispatch, with the
-        priority readback DEFERRED one dispatch — the multihost form of
-        the device/sharded planes' K-update amortization. Reading this
+        """The plane's ONE dispatch, for every k >= 1: sample locally,
+        assemble global views, run k collective updates in one shard_map
+        dispatch, with the priority readback DEFERRED one dispatch — the
+        multihost form of the device/sharded planes' update. Reading this
         dispatch's (K, dp, B/dp) priorities synchronously would stall
         every host for the dispatch plus a device->host round trip per
         update burst (the >10x cliff ARCHITECTURE.md measures at 2.3 ms
@@ -415,6 +338,9 @@ class MultiHostShardedReplay:
         is_from_priorities=True). EVERY process calls this in the same
         order (SPMD); the drain itself is host-local."""
         with self.lock:
+            # sample + assemble + dispatch under the store lock: a
+            # concurrent add_block's donated swap must not invalidate the
+            # buffers behind the global views mid-dispatch
             (b, s, w), draws = self.sample_global_k(k)
             new_state, metrics, priorities = multi_fn(
                 state, self.global_stores(), b, s, w
@@ -428,7 +354,10 @@ class MultiHostShardedReplay:
     def drain_pending(self, pending=None) -> None:
         """Apply a deferred (priorities, draws) pair: each host reads only
         its addressable (K, 1, B/dp) pieces and applies row i under draw
-        i's own per-shard staleness window + lap stamp. Called with the
+        i's own per-shard staleness window AND lap stamp (a full ring lap
+        between draw and apply wraps the pointer back into the window
+        mask's blind spot — the stamp is the only guard,
+        control_plane.update_priorities). Called with the
         previous dispatch's pair each run_step_k, and once with the final
         in-flight pair when the run mode exits (Trainer.finish_updates)."""
         if pending is None:

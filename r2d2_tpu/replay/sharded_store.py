@@ -21,9 +21,9 @@ let collectives ride ICI):
   IS weights are renormalized across shards to the BATCH-global minimum
   priority, so weights match what a single global tree would produce for
   the same draws (min is over the sampled batch, replay/sum_tree.py).
-- TRAINING: learner.make_sharded_fused_train_step runs under shard_map —
-  each device gathers its sub-batch from its LOCAL shard (zero cross-device
-  data-plane traffic) and gradients pmean over dp.
+- TRAINING: learner.make_sharded_fused_multi_train_step runs under
+  shard_map — each device gathers its sub-batches from its LOCAL shard (zero
+  cross-device data-plane traffic) and gradients psum over dp.
 
 Priority round trip: update_priorities applies each shard's slice under
 that shard's own pointer-window staleness mask (reference worker.py:290-307
@@ -55,7 +55,7 @@ class ShardedSampleIdx:
     s: np.ndarray           # (dp, B/dp) sequence-in-block
     is_weights: np.ndarray  # (dp, B/dp) float32, batch-globally normalized
     idxes: np.ndarray       # (dp, B/dp) sequence slots LOCAL to each shard
-    old_ptrs: List[int]     # per-shard block pointer at sample time
+    old_ptr: List[int]       # per-shard block pointer at sample time
     old_advances: List[int]  # per-shard ptr_advances stamp (lap detection)
     env_steps: int
 
@@ -223,6 +223,12 @@ class ShardedDeviceReplay:
         # phase, snapshot.load_replay)  # r2d2: disable=lock-discipline
         self.dtree_stack = jax.device_put(host, self._dtree_shd)
 
+    def superstep_keys(self, key: jax.Array) -> jax.Array:
+        """The superstep's (dp, 2) key data from one dispatch key: an
+        independent stream per dp shard (fold_in by shard id), mirroring the
+        host plane's per-shard Generators."""
+        return jnp.stack([jax.random.fold_in(key, sid) for sid in range(self.dp)])
+
     def superstep_run(self, fn: Callable):
         """Dispatch an in-jit sharded superstep under ONE buffer-lock hold:
         fn(stores, dtree_stack, num_seq_store (dp, nb/dp)) -> (stack',
@@ -231,7 +237,7 @@ class ShardedDeviceReplay:
         device stream — the same serialization argument as
         DeviceReplayBuffer.superstep_run, per shard."""
         with self.lock:
-            nss = np.stack([sh.num_seq_store for sh in self.shards])
+            nss = jnp.asarray(np.stack([sh.num_seq_store for sh in self.shards]))
             stack_out, rest = fn(self.stores, self.dtree_stack, nss)
             self.dtree_stack = stack_out
             return rest
@@ -405,7 +411,7 @@ class ShardedDeviceReplay:
             s=np.stack(ss).astype(np.int32),
             is_weights=w.astype(np.float32),
             idxes=np.stack(idxs),
-            old_ptrs=old_ptrs,
+            old_ptr=old_ptrs,
             old_advances=old_advances,
             env_steps=self.env_steps,
         )

@@ -3,7 +3,7 @@
 Two transports over the same PolicyServer:
 
 - `LocalClient` — in-process blocking wrapper over `PolicyServer.submit`;
-  what tests, bench.py's load generator, and embedded callers use. One
+  what tests, load generators, and embedded callers use. One
   client instance is safe to share across session threads (the batcher
   queue is the synchronization point).
 - `serve_tcp` + `PolicyClient` — a stdlib JSON-lines TCP frontend for

@@ -204,7 +204,7 @@ class MultiDeviceServer:
 
     Mirrors the single-server lifecycle — construct, `warmup()`,
     `start()`, `submit()`/client wrappers, `check()`, `stop()` — so
-    bench.py and the CLI treat either interchangeably. The checkpoint
+    drivers and the CLI treat either interchangeably. The checkpoint
     watcher lives HERE (replicas start with watch_checkpoints=False): one
     restore per new step, one shared version, published to every replica.
     """

@@ -1,7 +1,7 @@
 """Seeded, declarative traffic scenarios for the serving plane.
 
-bench.py's open-loop serve bench drives ONE arrival shape: a
-constant-rate Poisson process. Production traffic is not that (ROADMAP
+An open-loop serve load of ONE arrival shape is a constant-rate
+Poisson process. Production traffic is not that (ROADMAP
 item 5): rates ramp diurnally, flash crowds multiply load in seconds,
 session lengths are heavy-tailed (a few sessions produce most requests),
 some clients straggle, and replicas stall or die mid-traffic. This module
@@ -18,8 +18,8 @@ makes each of those a DECLARATIVE, SEEDED scenario:
   replays bit-for-bit, like everything else under utils/faults.py.
 - `ScenarioRunner` replays a trace against a LIVE server on the wall
   clock, classifies every outcome (`ok` / `rejected` / `timeout` /
-  `transport`), and reduces to the readiness row bench.py's scenario
-  matrix reports: p50/p95/p99, SLO attainment, error breakdown.
+  `transport`), and reduces to a scenario matrix's readiness row:
+  p50/p95/p99, SLO attainment, error breakdown.
 
 Chaos composition runs through the fault plane, not ad-hoc flags: the
 runner merges `spec.faults` (e.g. a `serve.replica_stall@N=stall:1`

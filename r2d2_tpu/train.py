@@ -46,12 +46,9 @@ from r2d2_tpu.envs.catch import CatchVecEnv
 from r2d2_tpu.learner import (
     DeviceBatch,
     init_train_state,
-    make_batch_train_step,
-    make_fused_train_step,
-    make_gather_step,
+    make_fused_multi_train_step,
     make_manual_train_step,
-    make_sharded_fused_train_step,
-    make_sharded_gather_step,
+    make_sharded_fused_multi_train_step,
     make_stacked_batch_train_step,
     make_train_step,
 )
@@ -224,7 +221,7 @@ class _TieredPlane:
     the stacked chunk into HBM while the learner's K-update scan
     (make_stacked_batch_train_step) consumes the previous chunk — the
     host->device copy runs behind compute instead of ahead of it. The
-    priority readback is deferred one dispatch exactly like _DevicePlane's;
+    priority readback is deferred one dispatch exactly like _HbmPlane's;
     staleness needs no extra machinery because chunks are BY-VALUE (bytes
     copied out at stage time) and carry their stage-time window stamps.
     The TransferTimer's overlap fraction lands in the metrics stream via
@@ -270,7 +267,7 @@ class _TieredPlane:
         _, chunk, _, _ = item
         state, m, priorities = self.multi_fn(state, chunk.batch)
         priorities.copy_to_host_async()
-        # deferred one dispatch (_DevicePlane._multi_update rationale): the
+        # deferred one dispatch (_HbmPlane._multi_update rationale): the
         # readback lands while the NEXT chunk executes
         prev, self._pending = self._pending, (priorities, chunk)
         if prev is not None:
@@ -333,51 +330,71 @@ class _TieredPlane:
         return {**self.xfer.stats(), **self.replay.disk_stats()}
 
 
-class _DevicePlane:
-    """Single-chip HBM replay (replay/device_store.py).
+class _HbmPlane:
+    """HBM replay on one chip (replay_plane="device": replay/device_store.py,
+    a plain-jit step) or dp-sharded over the mesh ("sharded":
+    replay/sharded_store.py, a shard_map step with local gathers per shard
+    and a gradient psum over dp). The two differ in the store they build and
+    the step wrapper they call; everything else is one path.
 
-    Inline mode queues only sample COORDINATES and the fused step gathers
-    in-jit at update time (fastest: nothing but a kilobyte crosses the
-    wire, no intermediate batch). Pipelined mode materializes the batch in
-    HBM at sample time (make_gather_step) so an item sitting in the
-    prefetch queue cannot be invalidated by a concurrent block write."""
+    An update is ONE thing for every K = updates_per_dispatch >= 1: K
+    coordinate sets drawn when the update dispatches, under the store's lock
+    (an item waiting in threaded mode's queue therefore holds no coordinates
+    that a concurrent block write could retarget), one dispatch that gathers
+    and updates K times in-jit, and the (K, ...) priorities read back one
+    dispatch late. priority_plane="device" swaps that for the in-jit N x K
+    superstep, which draws and writes back against the HBM tree itself."""
 
     def __init__(self, tr: "Trainer"):
+        cfg = tr.cfg
         self.tr = tr
-        self.replay = DeviceReplayBuffer(tr.cfg)
-        self.K = self.steps_per_update = tr.cfg.updates_per_dispatch
+        self.K = self.steps_per_update = cfg.updates_per_dispatch
         self._pending = None  # deferred (priorities, draws) readback
-        self.device_priority = tr.cfg.priority_plane == "device"
+        self.device_priority = cfg.priority_plane == "device"
+        sharded = cfg.replay_plane == "sharded"
+        if sharded and tr.mesh is None:
+            raise ValueError("replay_plane='sharded' needs dp_size*tp_size > 1")
+        self.replay = ShardedDeviceReplay(cfg, tr.mesh) if sharded else DeviceReplayBuffer(cfg)
+        on_mesh = (tr.mesh,) if sharded else ()
         if self.device_priority:
-            from r2d2_tpu.megastep import make_priority_superstep
-
-            self.N = tr.cfg.superstep_dispatches
-            self.steps_per_update = self.N * self.K
-            self.superstep_fn = make_priority_superstep(
-                tr.cfg, tr.net, self.N, self.K
+            from r2d2_tpu.megastep import (
+                make_priority_superstep,
+                make_sharded_priority_superstep,
             )
+
+            build = make_sharded_priority_superstep if sharded else make_priority_superstep
+            self.N = cfg.superstep_dispatches
+            self.steps_per_update = self.N * self.K
+            self.superstep_fn = build(cfg, tr.net, *on_mesh, self.N, self.K)
             # key stream derived from the STEP COUNTER, not carried state:
             # a --resume at step s re-derives superstep s/(N*K)'s key
             # exactly, with nothing extra to snapshot
-            self._superstep_base_key = jax.random.PRNGKey(tr.cfg.seed + 4)
-        elif self.K > 1:
-            from r2d2_tpu.learner import make_fused_multi_train_step
+            self._superstep_base_key = jax.random.PRNGKey(cfg.seed + 4)
+        else:
+            build = make_sharded_fused_multi_train_step if sharded else make_fused_multi_train_step
+            self.multi_fn = build(cfg, tr.net, *on_mesh, self.K)
 
-            self.multi_fn = make_fused_multi_train_step(tr.cfg, tr.net, self.K)
-        self.step_fn = make_fused_train_step(tr.cfg, tr.net)
-        self.gather_fn = make_gather_step(tr.cfg)
-        self.batch_step_fn = make_batch_train_step(tr.cfg, tr.net)
+    def sample(self, pipelined: bool = False):
+        # nothing is drawn here: the update draws its own coordinates when it
+        # dispatches (the superstep in-jit, against the live tree)
+        return ("superstep" if self.device_priority else "multi", None, None, None)
+
+    def update(self, state, item):
+        if item[0] == "superstep":
+            return self._superstep_update(state)
+        return self._multi_update(state)
 
     def _superstep_update(self, state):
         """priority_plane="device": ONE dispatch runs N x K updates with
         sampling, IS weights, gather, train, and priority write-back all
-        in-jit against the HBM tree (megastep.make_priority_superstep).
-        Nothing is drawn on host, nothing drains afterwards — the host's
-        only work here is deriving the dispatch key and swapping the tree
-        handle under the buffer lock."""
-        key = jax.random.fold_in(
+        in-jit against the HBM tree (megastep.make_priority_superstep and
+        its sharded twin). Nothing is drawn on host, nothing drains
+        afterwards — the host's only work here is deriving the dispatch key
+        (the store makes of it one key, or one stream per dp shard) and
+        swapping the tree handle under the buffer lock."""
+        key = self.replay.superstep_keys(jax.random.fold_in(
             self._superstep_base_key, self.tr._step // self.steps_per_update
-        )
+        ))
 
         def dispatch(stores, tree, nss):
             new_state, tree_out, m = self.superstep_fn(state, stores, tree, nss, key)
@@ -385,28 +402,11 @@ class _DevicePlane:
 
         return self.replay.superstep_run(dispatch)
 
-    def sample(self, pipelined: bool = False):
-        if self.device_priority:
-            # sampling happens in-jit at update time, against the live tree
-            return ("superstep", None, None, None)
-        if self.K > 1:
-            # multi-update dispatch draws its own coordinates at update
-            # time (atomically with the dispatch) — queued coordinates
-            # could be retargeted by adds landing while the item waits
-            return ("multi", None, None, None)
-        with span("r2d2.replay.sample"):
-            si = self.replay.sample_indices(self.tr.sample_rng)
-            coords = (jax.device_put(si.b), jax.device_put(si.s), jax.device_put(si.is_weights))
-            stamp = (si.old_ptr, si.old_advances)
-            if pipelined:
-                batch = self.replay.run_with_stores(lambda stores: self.gather_fn(stores, *coords))
-                return "batch", batch, si.idxes, stamp
-            return "coords", coords, si.idxes, stamp
-
     def _multi_update(self, state):
         """K updates in one dispatch: draw + dispatch under one lock hold
-        (DeviceReplayBuffer.sample_and_run), then apply the (K, B)
-        priorities row-by-row under each draw's own staleness window.
+        (the store's sample_and_run), then apply the (K, B) or (K, dp, B/dp)
+        priorities row-by-row under each draw's own staleness window (per
+        shard on the sharded store).
 
         The priority readback is DEFERRED one dispatch: reading this
         chunk's priorities immediately would stall the host for the chunk's
@@ -447,8 +447,10 @@ class _DevicePlane:
             self.replay.update_priorities(d.idxes, row, d.old_ptr, d.old_advances)
 
     def capture_pending(self) -> Optional[dict]:
-        """Preemption capture of the K>1 deferred readback — same apply-
-        order-preservation rationale as _TieredPlane.capture_pending."""
+        """Preemption capture of the deferred readback — same apply-
+        order-preservation rationale as _TieredPlane.capture_pending. The
+        stamps are (K,) on the device store and (K, dp) on the sharded one,
+        whose restored draws carry them as (dp,) arrays."""
         pending, self._pending = self._pending, None
         if pending is None:
             return None
@@ -464,145 +466,10 @@ class _DevicePlane:
         import types
 
         draws = [
-            types.SimpleNamespace(
-                idxes=np.asarray(idx), old_ptr=int(p), old_advances=int(a)
-            )
+            types.SimpleNamespace(idxes=np.asarray(idx), old_ptr=p, old_advances=a)
             for idx, p, a in zip(d["idxes"], d["old_ptr"], d["old_advances"])
         ]
         self._pending = (np.asarray(d["prios"]), draws)
-
-    def update(self, state, item):
-        kind, payload, idxes, stamp = item
-        if kind == "superstep":
-            return self._superstep_update(state)
-        if kind == "multi":
-            return self._multi_update(state)
-        if kind == "batch":
-            state, m, priorities = self.batch_step_fn(state, payload)
-        else:
-            state, m, priorities = self.replay.run_with_stores(
-                lambda stores: self.step_fn(state, stores, *payload)
-            )
-        old_ptr, old_adv = stamp
-        self.replay.update_priorities(idxes, np.asarray(priorities), old_ptr, old_adv)
-        return state, m
-
-
-class _ShardedPlane:
-    """dp-sharded HBM replay + shard_map train step: local gathers per
-    shard, gradient psum over dp (replay/sharded_store.py). Same
-    inline/pipelined split as _DevicePlane; the pipelined gather runs under
-    shard_map so each device materializes its local sub-batch. K > 1 folds
-    K updates into one shard_map dispatch with the same deferred priority
-    readback as the device plane."""
-
-    def __init__(self, tr: "Trainer"):
-        if tr.mesh is None:
-            raise ValueError("replay_plane='sharded' needs dp_size*tp_size > 1")
-        self.tr = tr
-        self.replay = ShardedDeviceReplay(tr.cfg, tr.mesh)
-        self.K = self.steps_per_update = tr.cfg.updates_per_dispatch
-        self._pending = None  # deferred (priorities, draws) readback
-        self.device_priority = tr.cfg.priority_plane == "device"
-        if self.device_priority:
-            from r2d2_tpu.megastep import make_sharded_priority_superstep
-
-            self.N = tr.cfg.superstep_dispatches
-            self.steps_per_update = self.N * self.K
-            self.superstep_fn = make_sharded_priority_superstep(
-                tr.cfg, tr.net, tr.mesh, self.N, self.K
-            )
-            self._superstep_base_key = jax.random.PRNGKey(tr.cfg.seed + 4)
-        elif self.K > 1:
-            from r2d2_tpu.learner import make_sharded_fused_multi_train_step
-
-            self.multi_fn = make_sharded_fused_multi_train_step(
-                tr.cfg, tr.net, tr.mesh, self.K
-            )
-        self.step_fn = make_sharded_fused_train_step(tr.cfg, tr.net, tr.mesh)
-        self.gather_fn = make_sharded_gather_step(tr.cfg, tr.mesh)
-        self.batch_step_fn = make_batch_train_step(tr.cfg, tr.net)
-
-    def _superstep_update(self, state):
-        """Sharded in-jit superstep: one independent key stream per dp
-        shard (fold_in by shard id, then by superstep counter — counter-
-        derived like _DevicePlane's, so --resume re-derives the streams)."""
-        ctr = self.tr._step // self.steps_per_update
-        base = jax.random.fold_in(self._superstep_base_key, ctr)
-        keys = jnp.stack(
-            [jax.random.fold_in(base, sid) for sid in range(self.replay.dp)]
-        )
-
-        def dispatch(stores, trees, nss):
-            new_state, trees_out, m = self.superstep_fn(
-                state, stores, trees, jnp.asarray(nss), keys
-            )
-            return trees_out, (new_state, m)
-
-        return self.replay.superstep_run(dispatch)
-
-    def sample(self, pipelined: bool = False):
-        if self.device_priority:
-            return ("superstep", None, None, None)
-        if self.K > 1:
-            # multi-update dispatch draws its own coordinates at update
-            # time, atomically with the dispatch (_DevicePlane rationale)
-            return ("multi", None, None, None)
-        with span("r2d2.replay.sample"):
-            si = self.replay.sample_indices(self.tr.sample_rng)
-            coords = (jnp.asarray(si.b), jnp.asarray(si.s), jnp.asarray(si.is_weights))
-            stamp = (si.old_ptrs, si.old_advances)
-            if pipelined:
-                batch = self.replay.run_with_stores(lambda stores: self.gather_fn(stores, *coords))
-                return "batch", batch, si.idxes, stamp
-            return "coords", coords, si.idxes, stamp
-
-    def _multi_update(self, state):
-        """K sharded updates in one dispatch; priorities (K, dp, B/dp)
-        drain one dispatch late under each draw's per-shard windows."""
-
-        def dispatch(stores, draws):
-            b = jnp.asarray(np.stack([d.b for d in draws]))
-            s = jnp.asarray(np.stack([d.s for d in draws]))
-            w = jnp.asarray(np.stack([d.is_weights for d in draws]))
-            return self.multi_fn(state, stores, b, s, w)
-
-        draws, (new_state, m, priorities) = self.replay.sample_and_run(
-            self.tr.sample_rng, self.K, dispatch
-        )
-        priorities.copy_to_host_async()
-        prev, self._pending = self._pending, (priorities, draws)
-        if prev is not None:
-            self.drain_pending(prev)
-        return new_state, m
-
-    def drain_pending(self, pending=None) -> None:
-        if pending is None:
-            pending, self._pending = self._pending, None
-        if pending is None:
-            return
-        prios, draws = pending
-        for row, d in zip(np.asarray(prios), draws):
-            self.replay.update_priorities(d.idxes, row, d.old_ptrs, d.old_advances)
-
-    def update(self, state, item):
-        kind, payload, idxes, stamp = item
-        if kind == "superstep":
-            return self._superstep_update(state)
-        if kind == "multi":
-            return self._multi_update(state)
-        old_ptrs, old_adv = stamp
-        if kind == "batch":
-            # gathered batch is dp-sharded; plain jit inserts the grad psum
-            state, m, priorities = self.batch_step_fn(state, payload)
-            priorities = np.asarray(priorities).reshape(self.replay.dp, -1)
-        else:
-            state, m, priorities = self.replay.run_with_stores(
-                lambda stores: self.step_fn(state, stores, *payload)
-            )
-            priorities = np.asarray(priorities)
-        self.replay.update_priorities(idxes, priorities, old_ptrs, old_adv)
-        return state, m
 
 
 class _MultiHostPlane:
@@ -613,11 +480,10 @@ class _MultiHostPlane:
     lockstep through the step dispatches themselves; collection, logging,
     and the priority drain are host-local.
 
-    K = updates_per_dispatch > 1 folds K collective updates into ONE
-    shard_map K-scan dispatch with the priority readback deferred one
-    dispatch (replay.run_step_k) — the same dispatch-latency amortization
-    the repo measured as mandatory on single-chip (ARCHITECTURE.md
-    "dispatch granularity"), now on the scale-out plane."""
+    Every update is ONE shard_map K-scan dispatch of K =
+    updates_per_dispatch >= 1 collective updates, with the priority readback
+    deferred one dispatch (replay.run_step_k): the device and sharded
+    planes' one path (_HbmPlane), on the scale-out plane."""
 
     def __init__(self, tr: "Trainer"):
         from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
@@ -627,24 +493,16 @@ class _MultiHostPlane:
         self.tr = tr
         self.replay = MultiHostShardedReplay(tr.cfg, tr.mesh, seed=tr.cfg.seed + 3)
         self.K = self.steps_per_update = tr.cfg.updates_per_dispatch
-        if self.K > 1:
-            from r2d2_tpu.learner import make_sharded_fused_multi_train_step
-
-            self.multi_fn = make_sharded_fused_multi_train_step(
-                tr.cfg, tr.net, tr.mesh, self.K, is_from_priorities=True
-            )
-        self.step_fn = make_sharded_fused_train_step(
-            tr.cfg, tr.net, tr.mesh, is_from_priorities=True
+        self.multi_fn = make_sharded_fused_multi_train_step(
+            tr.cfg, tr.net, tr.mesh, self.K, is_from_priorities=True
         )
 
     def sample(self, pipelined: bool = False):
-        # draws happen inside run_step(_k), atomically with the dispatch
+        # draws happen inside run_step_k, atomically with the dispatch
         return ("multihost", None, None, None)
 
     def update(self, state, item):
-        if self.K > 1:
-            return self.replay.run_step_k(self.multi_fn, state, self.K)
-        return self.replay.run_step(self.step_fn, state)
+        return self.replay.run_step_k(self.multi_fn, state, self.K)
 
     def drain_pending(self, pending=None) -> None:
         self.replay.drain_pending(pending)
@@ -653,8 +511,8 @@ class _MultiHostPlane:
 _PLANES = {
     "host": _HostPlane,
     "tiered": _TieredPlane,
-    "device": _DevicePlane,
-    "sharded": _ShardedPlane,
+    "device": _HbmPlane,
+    "sharded": _HbmPlane,
     "multihost": _MultiHostPlane,
 }
 
@@ -1088,10 +946,10 @@ class Trainer:
         return local + self.env_steps_offset
 
     def finish_updates(self) -> None:
-        """Flush any deferred per-plane work (e.g. the K>1 device plane's
-        in-flight priority readback). Every update-driving loop — the run
-        modes here and external drivers like bench.py — calls this once
-        when it stops updating."""
+        """Flush any deferred per-plane work (e.g. the HBM planes' in-flight
+        priority readback). Every update-driving loop — the run modes here
+        and external drivers (the live loop, tests) — calls this once when
+        it stops updating."""
         drain = getattr(self.plane, "drain_pending", None)
         if drain is not None:
             drain()
@@ -1577,8 +1435,10 @@ class Trainer:
 
         def sampler_body():
             if pending[0] is None:
-                # pipelined: gather/copy at sample time so queued items
-                # cannot be invalidated by concurrent block writes
+                # pipelined: the host and tiered planes copy the batch out at
+                # sample time, so a queued item cannot be invalidated by a
+                # concurrent block write; the HBM planes queue a token and
+                # draw when the update dispatches
                 pending[0] = self.plane.sample(pipelined=True)
             try:
                 batch_q.put(pending[0], timeout=0.5)

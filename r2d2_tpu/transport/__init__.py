@@ -6,7 +6,7 @@
 - `IngestService` — learner side: N host connections, seq dedup, skew
   stamping, replay fan-in, checkpoint broadcast
 - `podloop` — the two process bodies (`--role serve|learner`) used by
-  `bench.py --mode podloop` and the transport tests
+  the module's own CLI and the transport tests
 """
 
 from r2d2_tpu.transport import framing

@@ -1,9 +1,8 @@
 """Pod-loop processes: many serve hosts feed one learner over the
 block-stream transport, across REAL process boundaries.
 
-This module is the single definition of both process bodies — `bench.py
---mode podloop` and the transport tests spawn the same code paths the
-module's own CLI exposes:
+This module is the single definition of both process bodies — the
+transport tests spawn the same code paths the module's own CLI exposes:
 
     python -m r2d2_tpu.transport.podloop --role serve \
         --learner-port P --host-id h0 --spool-dir /tmp/spool --stats s.jsonl

@@ -38,8 +38,8 @@ print(rows[-1]["mean_reward"] if rows else -9)
 PY
 }
 
-python runs/measure_mfu.py --out runs/mfu.json
-echo "=== MFU EXIT: $? ==="
+# runs/measure_mfu.py (which wrote runs/mfu.json here) left at PR 51:
+# benchmark/flops.py owns the FLOP count, the benchmark's mfu reader the rate
 python runs/bench_lru_breakdown.py --out runs/lru_breakdown.jsonl
 echo "=== LRU_BREAKDOWN EXIT: $? ==="
 python runs/bench_core_unroll.py --out runs/core_unroll_r4.jsonl

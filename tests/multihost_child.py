@@ -1,7 +1,8 @@
 """Shared driver for the multi-host replay tests.
 
 `build_and_run(mesh)` fills a MultiHostShardedReplay with per-shard
-deterministic blocks and runs 3 collective train steps — called BOTH by the
+deterministic blocks and runs 3 collective K=1 dispatches and 2 of K=2 —
+called BOTH by the
 in-process single-host reference (4 fake devices, all shards local) and by
 the real 2-process children this file spawns as `python multihost_child.py
 <pid> <nprocs> <port>`. Identical per-shard content + layout-independent
@@ -18,7 +19,7 @@ def _seed_replay(replay, cfg):
     processes. Equal priorities -> IS weights exactly 1.0."""
     import numpy as np
 
-    from bench import synth_block
+    from synth import synth_block
 
     rngs = {g: np.random.default_rng(100 + g) for g in replay.local_ids}
     for _ in range(2):
@@ -47,7 +48,7 @@ def build_and_run(mesh):
     import numpy as np
 
     from r2d2_tpu.config import tiny_test
-    from r2d2_tpu.learner import init_train_state, make_sharded_fused_train_step
+    from r2d2_tpu.learner import init_train_state, make_sharded_fused_multi_train_step
     from r2d2_tpu.parallel.mesh import replicated_sharding
     from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
 
@@ -57,18 +58,16 @@ def build_and_run(mesh):
 
     net, state = init_train_state(cfg, jax.random.PRNGKey(0))
     state = jax.device_put(state, replicated_sharding(mesh))
-    step_fn = make_sharded_fused_train_step(
-        cfg, net, mesh, donate=False, is_from_priorities=True
+    step_fn = make_sharded_fused_multi_train_step(
+        cfg, net, mesh, 1, donate=False, is_from_priorities=True
     )
     losses = []
     for _ in range(3):
-        state, metrics = replay.run_step(step_fn, state)
+        state, metrics = replay.run_step_k(step_fn, state, 1)
         losses.append(float(metrics["loss"]))
-    # K-dispatch phase: two K=2 collective scan dispatches (the second
-    # also drains the first's deferred priorities), then the final drain —
-    # the full run_step_k lifecycle on both process topologies
-    from r2d2_tpu.learner import make_sharded_fused_multi_train_step
-
+    # then two K=2 collective scan dispatches (each dispatch also drains
+    # the one before's deferred priorities, whatever its K), then the final
+    # drain — the full run_step_k lifecycle on both process topologies
     multi_fn = make_sharded_fused_multi_train_step(
         cfg, net, mesh, 2, donate=False, is_from_priorities=True
     )
@@ -110,7 +109,7 @@ def build_elastic(mesh, shared_dir, phase):
     import numpy as np
 
     from r2d2_tpu.config import tiny_test
-    from r2d2_tpu.learner import init_train_state, make_sharded_fused_train_step
+    from r2d2_tpu.learner import init_train_state, make_sharded_fused_multi_train_step
     from r2d2_tpu.parallel.mesh import replicated_sharding
     from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
     from r2d2_tpu.replay.reshard import reshard_replay, snapshot_paths
@@ -121,14 +120,14 @@ def build_elastic(mesh, shared_dir, phase):
     net, state = init_train_state(cfg, jax.random.PRNGKey(0))
     treedef = jax.tree.structure(state)
     state = jax.device_put(state, replicated_sharding(mesh))
-    step_fn = make_sharded_fused_train_step(
-        cfg, net, mesh, donate=False, is_from_priorities=True
+    step_fn = make_sharded_fused_multi_train_step(
+        cfg, net, mesh, 1, donate=False, is_from_priorities=True
     )
 
     if phase == "save":
         _seed_replay(replay, cfg)
         for _ in range(3):
-            state, _ = replay.run_step(step_fn, state)
+            state, _ = replay.run_step_k(step_fn, state, 1)
         replay.drain_pending()  # snapshot post-drain: no pending write-backs lost
         extra = {
             f"st_{j}": np.asarray(v) for j, v in enumerate(jax.tree.leaves(state))
@@ -145,8 +144,9 @@ def build_elastic(mesh, shared_dir, phase):
 
     losses = []
     for _ in range(3):
-        state, metrics = replay.run_step(step_fn, state)
+        state, metrics = replay.run_step_k(step_fn, state, 1)
         losses.append(float(metrics["loss"]))
+    replay.drain_pending()  # before the trees are read
     checksum = float(
         sum(np.abs(np.asarray(x)).sum() for x in jax.tree.leaves(state.params))
     )

@@ -38,8 +38,7 @@ def _py_files():
     for root in ("r2d2_tpu", "runs", "examples"):
         for d, _, names in os.walk(os.path.join(REPO, root)):
             yield from (os.path.join(d, n) for n in sorted(names) if n.endswith(".py"))
-    yield from (os.path.join(REPO, n) for n in ("bench.py", "chip_smoke.py",
-                                                 "__graft_entry__.py"))
+    yield from (os.path.join(REPO, n) for n in ("chip_smoke.py", "__graft_entry__.py"))
 
 
 def test_no_second_cache_rule_anywhere():

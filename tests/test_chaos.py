@@ -54,6 +54,11 @@ _PLANE_CFG = {
         replay_plane="tiered", deterministic_staging=True, updates_per_dispatch=2
     ),
     "device": dict(replay_plane="device"),
+    # PR 51: the sharded plane captures and restores its deferred readback too
+    "sharded": dict(
+        replay_plane="sharded", updates_per_dispatch=2, dp_size=4, batch_size=8,
+        buffer_capacity=16 * 40,
+    ),
 }
 
 
@@ -103,6 +108,9 @@ def _next_draw_idxes(trainer):
     item = trainer.plane.sample()
     if item[0] == "staged":
         return np.asarray(item[1].idxes)
+    if item[0] == "multi":
+        # the HBM planes draw when the update dispatches: draw as it would
+        return np.asarray(trainer.replay.sample_indices(trainer.sample_rng).idxes)
     return np.asarray(item[2])
 
 
@@ -148,6 +156,7 @@ def _kill_and_resume(cfg, site, call):
         ("tiered", "trainer.update", 3),
         ("tiered", "tiered.stage_h2d", 2),  # mid-stage delivery
         ("device", "trainer.update", 4),
+        ("sharded", "trainer.update", 3),
     ],
 )
 def test_sigterm_resume_is_bit_identical(tmp_path, plane, site, call):

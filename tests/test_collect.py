@@ -17,7 +17,7 @@ from r2d2_tpu.collect import DeviceCollector, make_collect_fn
 from r2d2_tpu.config import tiny_test
 from r2d2_tpu.envs.catch import CatchEnv
 from r2d2_tpu.envs.fake import ScriptedEnv, ScriptedFnEnv
-from r2d2_tpu.learner import init_train_state, make_fused_train_step
+from r2d2_tpu.learner import init_train_state, make_fused_multi_train_step
 from r2d2_tpu.replay.block import frames_to_rows, rows_to_frames
 from r2d2_tpu.replay.device_store import DeviceReplayBuffer
 
@@ -150,13 +150,14 @@ def test_collector_feeds_device_replay_end_to_end():
     assert n_ep > 0 and r_sum == pytest.approx(n_ep * sum(i % 3 for i in range(9)))
 
     si = replay.sample_indices(np.random.default_rng(0))
-    step_fn = make_fused_train_step(cfg, net, donate=False)
+    step_fn = make_fused_multi_train_step(cfg, net, 1, donate=False)
     state2, metrics, priorities = replay.run_with_stores(
         lambda stores: step_fn(
-            state, stores, jax.numpy.asarray(si.b), jax.numpy.asarray(si.s),
-            jax.numpy.asarray(si.is_weights),
+            state, stores, jax.numpy.asarray(si.b)[None], jax.numpy.asarray(si.s)[None],
+            jax.numpy.asarray(si.is_weights)[None],
         )
     )
+    priorities = priorities[0]
     assert np.isfinite(float(metrics["loss"]))
     assert np.asarray(priorities).shape == (cfg.batch_size,)
     assert np.isfinite(np.asarray(priorities)).all()

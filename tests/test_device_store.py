@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from r2d2_tpu.config import tiny_test
-from r2d2_tpu.learner import DeviceBatch, init_train_state, make_fused_train_step, make_train_step
+from r2d2_tpu.learner import DeviceBatch, init_train_state, make_fused_multi_train_step, make_train_step
 from r2d2_tpu.replay.device_store import DeviceReplayBuffer
 from r2d2_tpu.replay.replay_buffer import ReplayBuffer
 from tests.test_replay_buffer import make_block, small_cfg
@@ -34,13 +34,14 @@ def test_same_sampling_stream(both_buffers):
     assert hb.env_steps == di.env_steps
 
 
-def test_fused_step_matches_host_step():
-    cfg = tiny_test()
+def _host_and_device_filled(cfg):
+    """A host buffer and a device store holding the same episodes (ragged
+    lengths, so windows meet the clip), block for block."""
+    from r2d2_tpu.replay.accumulator import SequenceAccumulator
+
     host = ReplayBuffer(cfg)
     dev = DeviceReplayBuffer(cfg)
     rng = np.random.default_rng(0)
-    from r2d2_tpu.replay.accumulator import SequenceAccumulator
-
     acc = SequenceAccumulator(cfg)
     for ep in range(12):
         acc.reset(rng.integers(0, 255, size=cfg.obs_shape, dtype=np.uint8))
@@ -59,10 +60,16 @@ def test_fused_step_matches_host_step():
                 )
                 host.add_block(block, prios, r)
                 dev.add_block(block, prios, r)
+    return host, dev
+
+
+def test_fused_step_matches_host_step():
+    cfg = tiny_test()
+    host, dev = _host_and_device_filled(cfg)
 
     net, state0 = init_train_state(cfg, jax.random.PRNGKey(0))
     host_step = make_train_step(cfg, net, donate=False)
-    fused_step = make_fused_train_step(cfg, net, donate=False)
+    fused_step = make_fused_multi_train_step(cfg, net, 1, donate=False)
 
     hb = host.sample_batch(np.random.default_rng(3))
     di = dev.sample_indices(np.random.default_rng(3))
@@ -70,11 +77,11 @@ def test_fused_step_matches_host_step():
 
     s_host, m_host, p_host = host_step(state0, DeviceBatch.from_sampled(hb))
     s_dev, m_dev, p_dev = fused_step(
-        state0, dev.stores, np.asarray(di.b), np.asarray(di.s), np.asarray(di.is_weights)
+        state0, dev.stores, np.asarray(di.b)[None], np.asarray(di.s)[None], np.asarray(di.is_weights)[None]
     )
 
     np.testing.assert_allclose(float(m_host["loss"]), float(m_dev["loss"]), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(p_host), np.asarray(p_dev), rtol=1e-4, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(p_host), np.asarray(p_dev)[0], rtol=1e-4, atol=1e-6)
     for a, b in zip(jax.tree.leaves(s_host.params), jax.tree.leaves(s_dev.params)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7)
 
@@ -94,51 +101,39 @@ def test_device_store_eviction_and_staleness(both_buffers):
     np.testing.assert_allclose(after[6:], 9.0**cfg.prio_exponent)
 
 
-def test_multi_step_matches_sequential_fused():
-    """K updates folded into one dispatch == K sequential fused steps on
-    the same pre-drawn coordinates: same final params, same priorities."""
-    import jax.numpy as jnp
-
-    from r2d2_tpu.learner import make_fused_multi_train_step, make_fused_train_step
-
-    cfg = tiny_test().replace(target_net_update_interval=2)  # sync mid-chunk
+@pytest.mark.parametrize("K", [1, 4])
+def test_multi_step_matches_sequential_fused(K):
+    """The one update on an HBM store, K updates over pre-drawn coordinates in
+    one dispatch, against the independent reference: K sequential
+    `make_train_step` calls on the host-assembled batches of the same draws.
+    Same final params and targets (a sync falls mid-chunk), same priorities
+    row for row, held to what `test_fused_step_matches_host_step` holds one
+    update to."""
+    cfg = tiny_test().replace(target_net_update_interval=2)
+    host, dev = _host_and_device_filled(cfg)
     net, state0 = init_train_state(cfg, jax.random.PRNGKey(0))
-    replay = DeviceReplayBuffer(cfg)
-    rng = np.random.default_rng(0)
-    from bench import synth_block
 
-    for _ in range(6):
-        replay.add_block(
-            synth_block(cfg, rng),
-            rng.uniform(0.5, 2.0, cfg.seqs_per_block).astype(np.float32),
-            None,
-        )
-    K = 3
-    draws = [replay.sample_indices(np.random.default_rng(i)) for i in range(K)]
+    hbs = [host.sample_batch(np.random.default_rng(i)) for i in range(K)]
+    draws = [dev.sample_indices(np.random.default_rng(i)) for i in range(K)]
+    for hb, di in zip(hbs, draws):
+        np.testing.assert_array_equal(hb.idxes, di.idxes)
 
-    single = make_fused_train_step(cfg, net, donate=False)
-    state = state0
-    prios_seq = []
-    for si in draws:
-        state, m, p = replay.run_with_stores(
-            lambda stores, si=si: single(
-                state, stores, jnp.asarray(si.b), jnp.asarray(si.s), jnp.asarray(si.is_weights)
-            )
-        )
+    host_step = make_train_step(cfg, net, donate=False)
+    state, prios_seq = state0, []
+    for hb in hbs:
+        state, m, p = host_step(state, DeviceBatch.from_sampled(hb))
         prios_seq.append(np.asarray(p))
 
     multi = make_fused_multi_train_step(cfg, net, K, donate=False)
-    b = jnp.stack([jnp.asarray(si.b) for si in draws])
-    s = jnp.stack([jnp.asarray(si.s) for si in draws])
-    w = jnp.stack([jnp.asarray(si.is_weights) for si in draws])
-    state_m, m_m, p_m = replay.run_with_stores(lambda stores: multi(state0, stores, b, s, w))
+    b, s, w = (np.stack([getattr(di, f) for di in draws]) for f in ("b", "s", "is_weights"))
+    state_m, m_m, p_m = dev.run_with_stores(lambda stores: multi(state0, stores, b, s, w))
 
     assert int(state_m.step) == int(state.step) == K
-    for a, bb in zip(jax.tree.leaves(state_m.params), jax.tree.leaves(state.params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bb), atol=1e-6)
-    for a, bb in zip(jax.tree.leaves(state_m.target_params), jax.tree.leaves(state.target_params)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(bb), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(p_m), np.stack(prios_seq), atol=1e-5)
+    np.testing.assert_allclose(float(m_m["loss"]), float(m["loss"]), rtol=1e-5)
+    for got, want in ((state_m.params, state.params), (state_m.target_params, state.target_params)):
+        for a, bb in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(bb), rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(p_m), np.stack(prios_seq), rtol=1e-4, atol=1e-6)
 
 
 # --------------------------------------------------------------------------

@@ -21,7 +21,7 @@ import pytest
 from r2d2_tpu.config import PRESETS, apply_model_preset, tiny_test
 from r2d2_tpu.learner import (
     init_train_state,
-    make_fused_train_step,
+    make_fused_multi_train_step,
     make_loss_fn,
     make_store_gather,
 )
@@ -308,7 +308,8 @@ def test_fused_update_from_a_blocked_store_is_the_loss_on_the_canonical_batch():
     si = replay.sample_indices(np.random.default_rng(2))
     b, s, w = jnp.asarray(si.b), jnp.asarray(si.s), jnp.asarray(si.is_weights)
     _, metrics, priorities = replay.run_with_stores(
-        lambda st: make_fused_train_step(cfg, net, donate=False)(state, st, b, s, w))
+        lambda st: make_fused_multi_train_step(cfg, net, 1, donate=False)(state, st, b[None], s[None], w[None]))
+    priorities = priorities[0]
 
     batch = replay.run_with_stores(lambda st: jax.jit(make_store_gather(cfg))(st, b, s, w))
     assert batch.obs.shape == (6, cfg.seq_len, *OBS)

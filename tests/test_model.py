@@ -177,9 +177,8 @@ def test_bfloat16_compute_path():
 
 
 def test_model_presets_grow_the_brain():
-    """config.MODEL_PRESETS: named sizes for the largest-model-that-fits
-    probe (bench.py fits table). Applying one changes exactly the fields
-    it names; encoder_depth grows real Dense layers."""
+    """config.MODEL_PRESETS: named model sizes. Applying one changes
+    exactly the fields it names; encoder_depth grows real Dense layers."""
     from r2d2_tpu.config import MODEL_PRESETS, apply_model_preset
 
     base = tiny_test()

@@ -72,7 +72,7 @@ def test_multihost_store_single_process():
 
     mesh = make_global_mesh(dp=4, tp=1, devices=jax.devices()[:4])
     losses, checksum = build_and_run(mesh)
-    # 3 single-step losses + 2 K=2-dispatch losses (multihost_child)
+    # 3 K=1-dispatch losses + 2 K=2-dispatch losses (multihost_child)
     assert len(losses) == 5 and all(np.isfinite(l) for l in losses)
     assert np.isfinite(checksum)
 
@@ -152,9 +152,9 @@ def test_multihost_data_plane_matches_sharded_store():
     sample coordinates through MultiHostShardedReplay's assembled global
     views and through ShardedDeviceReplay's native global stores must give
     the same loss from the same shard_map step."""
-    from bench import synth_block
+    from synth import synth_block
     from r2d2_tpu.config import tiny_test
-    from r2d2_tpu.learner import init_train_state, make_sharded_fused_train_step
+    from r2d2_tpu.learner import init_train_state, make_sharded_fused_multi_train_step
     from r2d2_tpu.parallel.mesh import replicated_sharding
     from r2d2_tpu.parallel.multihost import make_global_mesh
     from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
@@ -176,13 +176,13 @@ def test_multihost_data_plane_matches_sharded_store():
             mh.add_block(block, prios, None)
             sh.add_block(block, prios, None)
 
-    b, s, raw_p, idxes_by_shard, old_ptrs, old_advances = mh.sample_global()
+    (b, s, raw_p), _ = mh.sample_global_k(1)
     net, state = init_train_state(cfg, jax.random.PRNGKey(0))
     state = jax.device_put(state, replicated_sharding(mesh))
-    flagged = make_sharded_fused_train_step(
-        cfg, net, mesh, donate=False, is_from_priorities=True
+    flagged = make_sharded_fused_multi_train_step(
+        cfg, net, mesh, 1, donate=False, is_from_priorities=True
     )
-    plain = make_sharded_fused_train_step(cfg, net, mesh, donate=False)
+    plain = make_sharded_fused_multi_train_step(cfg, net, mesh, 1, donate=False)
 
     # multihost path: assembled global views + in-step IS normalization
     _, m_mh, p_mh = flagged(state, mh.global_stores(), b, s, raw_p)
@@ -232,7 +232,7 @@ def test_multihost_device_collector_and_run_step():
     from r2d2_tpu.collect import DeviceCollector
     from r2d2_tpu.config import tiny_test
     from r2d2_tpu.envs.catch import CatchEnv
-    from r2d2_tpu.learner import init_train_state, make_sharded_fused_train_step
+    from r2d2_tpu.learner import init_train_state, make_sharded_fused_multi_train_step
     from r2d2_tpu.parallel.mesh import replicated_sharding
     from r2d2_tpu.parallel.multihost import make_global_mesh
     from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
@@ -259,8 +259,9 @@ def test_multihost_device_collector_and_run_step():
     assert replay.env_steps > 0
     # every local shard received blocks (round-robin dealing)
     assert all(len(replay.shards[g]) > 0 for g in replay.local_ids)
-    step = make_sharded_fused_train_step(cfg, net, mesh, is_from_priorities=True)
-    state2, m = replay.run_step(step, state)
+    step = make_sharded_fused_multi_train_step(cfg, net, mesh, 1, is_from_priorities=True)
+    state2, m = replay.run_step_k(step, state, 1)
+    replay.drain_pending()
     assert np.isfinite(float(m["loss"]))
     assert int(np.asarray(state2.step)) == 1
 
@@ -281,12 +282,12 @@ def test_multihost_snapshot_roundtrip(tmp_path):
     )
     mesh = make_global_mesh(dp=4, tp=1, devices=jax.devices()[:4])
     replay = MultiHostShardedReplay(cfg, mesh, seed=1)
-    import bench
+    from synth import synth_block
 
     rng = np.random.default_rng(0)
     for _ in range(2 * 4):
         replay.add_block(
-            bench.synth_block(cfg, rng),
+            synth_block(cfg, rng),
             rng.uniform(0.5, 2.0, cfg.seqs_per_block).astype(np.float32),
             1.0,
         )
@@ -296,10 +297,10 @@ def test_multihost_snapshot_roundtrip(tmp_path):
     fresh = MultiHostShardedReplay(cfg, mesh, seed=1)
     restore_replay(fresh, path)
     assert len(fresh) == len(replay) and fresh.env_steps == replay.env_steps
-    b1 = replay.sample_global()
-    b2 = fresh.sample_global()
-    np.testing.assert_array_equal(np.asarray(b1[0]), np.asarray(b2[0]))
-    np.testing.assert_array_equal(np.asarray(b1[2]), np.asarray(b2[2]))
+    (b1, _, w1), _ = replay.sample_global_k(1)
+    (b2, _, w2), _ = fresh.sample_global_k(1)
+    np.testing.assert_array_equal(np.asarray(b1), np.asarray(b2))
+    np.testing.assert_array_equal(np.asarray(w1), np.asarray(w2))
     for g in replay.local_ids:
         np.testing.assert_array_equal(
             np.asarray(replay.stores[g]["obs"]), np.asarray(fresh.stores[g]["obs"])
@@ -309,10 +310,10 @@ def test_multihost_snapshot_roundtrip(tmp_path):
 def test_multihost_priority_lap_stamp():
     """A FULL ring lap between draw and apply wraps each shard's pointer
     back to its draw-time value — invisible to the pointer-window mask —
-    and only the ptr_advances stamp threaded through sample_global /
-    update_priorities rejects the stale batch (the same guard every other
+    and only the ptr_advances stamp threaded through sample_global_k /
+    drain_pending rejects the stale batch (the same guard every other
     plane has, control_plane.update_priorities)."""
-    from bench import synth_block
+    from synth import synth_block
     from r2d2_tpu.config import tiny_test
     from r2d2_tpu.parallel.multihost import make_global_mesh
     from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
@@ -336,26 +337,27 @@ def test_multihost_priority_lap_stamp():
             )
 
     lap()
-    b, s, w, idxes_by_shard, old_ptrs, old_advances = replay.sample_global()
+    _, draws = replay.sample_global_k(1)
+    idxes_by_shard = draws[0]["idxes"]
     lap()  # full lap: every slot overwritten, pointers back where they were
     for g in replay.local_ids:
-        assert replay.shards[g].block_ptr == old_ptrs[g]
+        assert replay.shards[g].block_ptr == draws[0]["old_ptrs"][g]
 
     Bs = cfg.batch_size // replay.dp
     per = {
         g: jax.device_put(
-            np.full((1, Bs), 99.0, np.float32), replay._shard_device[g]
+            np.full((1, 1, Bs), 99.0, np.float32), replay._shard_device[g]
         )
         for g in replay.local_ids
     }
-    prios = replay._assemble(per, (replay.dp, Bs), P("dp"))
+    prios = replay._assemble(per, (1, replay.dp, Bs), P(None, "dp"))
 
     before = {
         g: replay.shards[g].tree.priorities_of(idxes_by_shard[g]).copy()
         for g in replay.local_ids
     }
     # stamped path: the whole batch is stale (one full lap) -> rejected
-    replay.update_priorities(idxes_by_shard, prios, old_ptrs, old_advances)
+    replay.drain_pending((prios, draws))
     for g in replay.local_ids:
         np.testing.assert_array_equal(
             replay.shards[g].tree.priorities_of(idxes_by_shard[g]), before[g]
@@ -363,7 +365,8 @@ def test_multihost_priority_lap_stamp():
 
     # the window mask ALONE cannot see the lap: without the stamp the
     # stale batch is (wrongly) applied — documents why the stamp exists
-    replay.update_priorities(idxes_by_shard, prios, old_ptrs, None)
+    draws[0]["old_advances"] = dict.fromkeys(replay.local_ids)
+    replay.drain_pending((prios, draws))
     for g in replay.local_ids:
         got = replay.shards[g].tree.priorities_of(idxes_by_shard[g])
         assert np.all(got != before[g])
@@ -371,17 +374,13 @@ def test_multihost_priority_lap_stamp():
 
 def test_multihost_k_dispatch_matches_sequential():
     """One run_step_k K-scan dispatch must equal K sequential
-    is_from_priorities single steps on the SAME pre-drawn coordinates:
+    is_from_priorities dispatches of one on the SAME pre-drawn coordinates:
     identical per-update priorities out and identical final params (the
     make_fused_multi_train_step equivalence contract, now on the
     multihost plane's raw-priority pmin-normalized path)."""
-    from bench import synth_block
+    from synth import synth_block
     from r2d2_tpu.config import tiny_test
-    from r2d2_tpu.learner import (
-        init_train_state,
-        make_sharded_fused_multi_train_step,
-        make_sharded_fused_train_step,
-    )
+    from r2d2_tpu.learner import init_train_state, make_sharded_fused_multi_train_step
     from r2d2_tpu.parallel.mesh import replicated_sharding
     from r2d2_tpu.parallel.multihost import make_global_mesh
     from r2d2_tpu.replay.multihost_store import MultiHostShardedReplay
@@ -412,18 +411,18 @@ def test_multihost_k_dispatch_matches_sequential():
     )
     state_k, m_k, prios_k = multi_fn(state0, replay.global_stores(), b, s, w)
 
-    single_fn = make_sharded_fused_train_step(
-        cfg, net, mesh, donate=False, is_from_priorities=True
+    single_fn = make_sharded_fused_multi_train_step(
+        cfg, net, mesh, 1, donate=False, is_from_priorities=True
     )
     state_seq = state0
     b_np, s_np, w_np = (np.asarray(x) for x in (b, s, w))
     for i in range(K):
         state_seq, m_i, p_i = single_fn(
             state_seq, replay.global_stores(),
-            jnp.asarray(b_np[i]), jnp.asarray(s_np[i]), jnp.asarray(w_np[i]),
+            jnp.asarray(b_np[i : i + 1]), jnp.asarray(s_np[i : i + 1]), jnp.asarray(w_np[i : i + 1]),
         )
         np.testing.assert_allclose(
-            np.asarray(prios_k)[i], np.asarray(p_i), rtol=2e-5, atol=1e-6
+            np.asarray(prios_k)[i], np.asarray(p_i)[0], rtol=2e-5, atol=1e-6
         )
     np.testing.assert_allclose(float(m_k["loss"]), float(m_i["loss"]), rtol=1e-5)
     for a, bb in zip(jax.tree.leaves(state_k.params), jax.tree.leaves(state_seq.params)):

@@ -349,7 +349,7 @@ def test_multitask_convergence_smoke_beats_random():
     """Slow convergence smoke (out of tier-1; `pytest -m multitask` or
     `-m slow` runs it): one learner over the two dense-reward family
     members must beat a seeded random policy PER TASK after a few hundred
-    updates — the miniature of the BENCH_r13 acceptance bar."""
+    updates — the miniature of round 13's acceptance bar."""
     from r2d2_tpu.multitask.trainer import rollout_returns
 
     cfg = tiny_test().replace(

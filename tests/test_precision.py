@@ -142,7 +142,7 @@ def test_no_float64_in_train_step():
 
 
 def _fill(replay, cfg, n_blocks=4, seed=0):
-    from bench import synth_block
+    from synth import synth_block
 
     rng = np.random.default_rng(seed)
     for _ in range(n_blocks):

@@ -11,7 +11,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import synth_block
+from synth import synth_block
 from r2d2_tpu.config import tiny_test
 from r2d2_tpu.parallel.mesh import make_mesh, slab_partition_map
 from r2d2_tpu.replay.device_store import DeviceReplayBuffer

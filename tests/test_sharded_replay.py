@@ -16,7 +16,7 @@ from r2d2_tpu.config import R2D2Config, tiny_test
 from r2d2_tpu.learner import (
     DeviceBatch,
     init_train_state,
-    make_sharded_fused_train_step,
+    make_sharded_fused_multi_train_step,
     make_train_step,
 )
 from r2d2_tpu.parallel.mesh import make_mesh
@@ -59,6 +59,18 @@ def fill(replay, cfg, n_blocks=12):
         replay.add_block(block, prios, ep)
 
 
+def _one_update(cfg, net, mesh):
+    """The sharded step at num_steps=1 over one draw: (dp, B/dp) coordinates
+    in, (dp, B/dp) priorities out."""
+    step = make_sharded_fused_multi_train_step(cfg, net, mesh, 1, donate=False)
+
+    def one(state, stores, b, s, w):
+        state, metrics, priorities = step(state, stores, b[None], s[None], w[None])
+        return state, metrics, priorities[0]
+
+    return one
+
+
 def test_round_robin_and_accounting(mesh):
     cfg = sharded_cfg()
     replay = ShardedDeviceReplay(cfg, mesh)
@@ -92,7 +104,7 @@ def test_sharded_step_matches_single_device(mesh):
     fill(replay, cfg)
 
     net, state0 = init_train_state(cfg, jax.random.PRNGKey(3))
-    sharded_step = make_sharded_fused_train_step(cfg, net, mesh, donate=False)
+    sharded_step = _one_update(cfg, net, mesh)
     si = replay.sample_indices(np.random.default_rng(1))
 
     new_state, metrics, prio_sharded = replay.run_with_stores(
@@ -155,7 +167,7 @@ def test_priority_roundtrip_per_shard_staleness(mesh):
     for _ in range(replay.dp):  # one full round-robin lap -> shard 0 written
         replay.add_block(block, prios, ep)
     tds = np.full((8, 2), 7.7, np.float32)
-    replay.update_priorities(si.idxes, tds, si.old_ptrs)
+    replay.update_priorities(si.idxes, tds, si.old_ptr)
     # every shard's tree changed (fresh priorities) but totals stay finite
     after = [s.tree.total for s in replay.shards]
     assert all(np.isfinite(a) for a in after)
@@ -175,7 +187,7 @@ def _stack_block_fields(cfg, blocks):
 def test_sharded_add_blocks_batch_matches_sequential():
     """The collector's batched scatter lands blocks in the same slots with
     the same accounting as E sequential add_block calls."""
-    from bench import synth_block
+    from synth import synth_block
 
     dp = 4
     mesh = make_mesh(dp=dp, tp=1, devices=jax.devices()[:dp])
@@ -220,7 +232,7 @@ def test_sharded_add_blocks_batch_post_wrap_tail_retirement():
     deducted, slots freed), where the sequential path would wrap slot by
     slot without retiring. This pins the documented intended divergence
     (the add_blocks_batch docstring) instead of leaving it folklore."""
-    from bench import synth_block
+    from synth import synth_block
 
     dp = 2
     mesh = make_mesh(dp=dp, tp=1, devices=jax.devices()[:dp])
@@ -309,7 +321,7 @@ def test_sharded_step_tp2_matches_single_device():
 
     net, state0 = init_train_state(cfg, jax.random.PRNGKey(3))
     state_tp = jax.device_put(state0, train_state_shardings(state0, mesh))
-    sharded_step = make_sharded_fused_train_step(cfg, net, mesh, donate=False)
+    sharded_step = _one_update(cfg, net, mesh)
     si = replay.sample_indices(np.random.default_rng(1))
 
     new_state, metrics, prio_sharded = replay.run_with_stores(

@@ -16,7 +16,7 @@ from r2d2_tpu.replay.sum_tree import SumTree
 
 
 def _fill(replay, cfg, n_blocks=8, seed=0):
-    from bench import synth_block
+    from synth import synth_block
 
     rng = np.random.default_rng(seed)
     for _ in range(n_blocks):
@@ -155,7 +155,7 @@ def _fixture_replay(cfg, cls):
     """The buffer tests/fixtures/replay_snapshot_pr23_device.npz was saved
     from: written by the tree of PR 23 (commit 2dd2d55, whose device store
     held raw frames) with exactly these calls on a DeviceReplayBuffer."""
-    from bench import synth_block
+    from synth import synth_block
 
     replay = cls(cfg)
     rng = np.random.default_rng(24)

@@ -120,17 +120,3 @@ def test_threaded_host_env_pool_matches_serial():
         np.testing.assert_array_equal(n1, n2)
     # rewards are per-env-identity: ordering held through the pool
     assert list(r2) == [0.0, 1.0, 2.0, 3.0, 4.0, 5.0]
-
-
-def test_bench_core_overrides():
-    """bench --core/--lru-chunk mapping: lstm stays default, lru selects
-    the time-parallel core, and --lru-chunk without --core lru is a
-    usage error (SystemExit), not a silent misconfiguration."""
-    import pytest
-
-    from bench import _core_overrides
-
-    assert _core_overrides("lstm", 0) == {"recurrent_core": "lstm", "lru_chunk": 0}
-    assert _core_overrides("lru", 85) == {"recurrent_core": "lru", "lru_chunk": 85}
-    with pytest.raises(SystemExit):
-        _core_overrides("lstm", 128)

@@ -10,6 +10,7 @@ import pytest
 from r2d2_tpu.config import tiny_test
 from r2d2_tpu.envs.catch import CatchVecEnv
 from r2d2_tpu.train import Trainer
+from r2d2_tpu.utils import profiling
 
 
 def run_trainer(cfg, steps=10):
@@ -19,20 +20,35 @@ def run_trainer(cfg, steps=10):
     return trainer
 
 
-def test_device_plane_end_to_end(tmp_path):
+def _assert_every_drawn_priority_reached_the_tree(tr, offered_before):
+    """The HBM planes read an update's priorities back one dispatch late, at
+    every K (K = 1 included: there is one path); after finish_updates()
+    nothing is in flight and every row of every update was offered to a
+    tree."""
+    assert tr.plane._pending is None
+    offered = profiling.counted("replay.priority_rows_offered") - offered_before
+    assert offered == tr.cfg.training_steps * tr.cfg.batch_size
+
+
+@pytest.mark.parametrize("K", [1, 2])
+def test_device_plane_end_to_end(tmp_path, K):
     cfg = tiny_test().replace(
         env_name="catch",
         replay_plane="device",
+        updates_per_dispatch=K,
         checkpoint_dir=str(tmp_path / "ckpt"),
         training_steps=10,
         save_interval=10,
         learning_starts=48,
     )
+    offered = profiling.counted("replay.priority_rows_offered")
     tr = run_trainer(cfg)
     assert int(tr.state.step) == 10
     assert tr.replay.env_steps > 0
     # priorities actually landed in the tree (round trip exercised)
     assert tr.replay.tree.total > 0
+    assert tr.plane.sample()[0] == "multi"  # drawn when the update dispatches
+    _assert_every_drawn_priority_reached_the_tree(tr, offered)
 
 
 def test_tiered_plane_end_to_end(tmp_path):
@@ -97,11 +113,13 @@ def test_tiered_plane_torn_shutdown_drain(tmp_path):
     tr.finish_updates()  # idempotent
 
 
-def test_sharded_plane_end_to_end(tmp_path):
+@pytest.mark.parametrize("K", [1, 2])
+def test_sharded_plane_end_to_end(tmp_path, K):
     assert len(jax.devices()) >= 8
     cfg = tiny_test().replace(
         env_name="catch",
         replay_plane="sharded",
+        updates_per_dispatch=K,
         dp_size=4,
         tp_size=2,
         batch_size=8,  # 2 per dp shard
@@ -111,10 +129,12 @@ def test_sharded_plane_end_to_end(tmp_path):
         save_interval=10,
         learning_starts=48,
     )
+    offered = profiling.counted("replay.priority_rows_offered")
     tr = run_trainer(cfg)
     assert tr.mesh is not None and tr.mesh.shape == {"dp": 4, "tp": 2}
     assert int(tr.state.step) == 10
     assert all(s.tree.total > 0 for s in tr.replay.shards)
+    _assert_every_drawn_priority_reached_the_tree(tr, offered)
     # tp=2 on the sharded plane is REAL tensor parallelism now: the
     # core-agnostic probe kernel (tp_probe_kernel — resolves to core/wi
     # here since tiny_test uses the default LSTM core; it falls back to
@@ -131,58 +151,49 @@ def test_sharded_plane_end_to_end(tmp_path):
     )
 
 
-def test_device_plane_threaded_pipelined(tmp_path):
-    """Threaded mode gathers at sample time (make_gather_step): queued
-    items carry materialized batches, immune to store overwrites."""
+def _atari_v4_8_placement() -> dict:
+    """What the preset atari_v4_8 decides about the path an update takes
+    (sharded over dp 4, updates_per_dispatch left at its default), at
+    tiny_test's sizes. `python -m r2d2_tpu.train --preset atari_v4_8` is
+    threaded by default, so this is that command's path; a preset that moves
+    to another K or plane needs its own default path driven here instead."""
+    from r2d2_tpu.config import atari_v4_8
+
+    preset = atari_v4_8()
+    placement = dict(
+        replay_plane=preset.replay_plane,
+        dp_size=preset.dp_size,
+        updates_per_dispatch=preset.updates_per_dispatch,
+    )
+    assert placement == dict(replay_plane="sharded", dp_size=4, updates_per_dispatch=1)
+    return dict(placement, batch_size=8, buffer_capacity=16 * 40)  # 2 rows, 10 blocks a shard
+
+
+@pytest.mark.parametrize(
+    "placement",
+    [lambda: dict(replay_plane="device"), _atari_v4_8_placement],
+    ids=["device", "sharded-as-atari_v4_8"],
+)
+def test_device_plane_threaded_pipelined(tmp_path, placement):
+    """Threaded mode on the one path, at the default K = 1: the sampler
+    thread queues tokens, each update draws its coordinates when it
+    dispatches, under the store's lock (a queued item holds nothing a block
+    write could retarget), write-back lags one update, and run_threaded's
+    exit drains the last one."""
     cfg = tiny_test().replace(
         env_name="catch",
-        replay_plane="device",
         checkpoint_dir=str(tmp_path / "ckpt"),
         training_steps=6,
         save_interval=6,
         learning_starts=48,
+        **placement(),
     )
     vec_env = CatchVecEnv(num_envs=cfg.num_actors, height=12, width=12, seed=0)
+    offered = profiling.counted("replay.priority_rows_offered")
     trainer = Trainer(cfg, vec_env=vec_env)
     trainer.run_threaded()
     assert int(trainer.state.step) == 6
-
-
-def test_sharded_pipelined_gather_matches_fused(tmp_path):
-    """The pipelined path (sharded gather -> plain-jit batch step with
-    XLA-inserted psum) must equal the fused shard_map step numerically."""
-    import jax.numpy as jnp
-    from r2d2_tpu.learner import (
-        init_train_state,
-        make_batch_train_step,
-        make_sharded_fused_train_step,
-        make_sharded_gather_step,
-    )
-    from r2d2_tpu.parallel.mesh import make_mesh
-    from r2d2_tpu.replay.sharded_store import ShardedDeviceReplay
-    from tests.test_sharded_replay import fill, sharded_cfg
-
-    mesh = make_mesh(dp=8, tp=1, devices=jax.devices()[:8])
-    cfg = sharded_cfg()
-    replay = ShardedDeviceReplay(cfg, mesh)
-    fill(replay, cfg)
-    net, state0 = init_train_state(cfg, jax.random.PRNGKey(5))
-    si = replay.sample_indices(np.random.default_rng(4))
-    coords = (jnp.asarray(si.b), jnp.asarray(si.s), jnp.asarray(si.is_weights))
-
-    fused = make_sharded_fused_train_step(cfg, net, mesh, donate=False)
-    _, m_fused, p_fused = replay.run_with_stores(
-        lambda st: fused(state0, st, *coords)
-    )
-    gather = make_sharded_gather_step(cfg, mesh)
-    batch = replay.run_with_stores(lambda st: gather(st, *coords))
-    step = make_batch_train_step(cfg, net, donate=False)
-    _, m_piped, p_piped = step(state0, batch)
-
-    np.testing.assert_allclose(float(m_fused["loss"]), float(m_piped["loss"]), rtol=1e-5)
-    np.testing.assert_allclose(
-        np.asarray(p_fused).reshape(-1), np.asarray(p_piped), rtol=1e-5
-    )
+    _assert_every_drawn_priority_reached_the_tree(trainer, offered)
 
 
 def test_sharded_plane_requires_mesh():
@@ -244,3 +255,69 @@ def test_sharded_plane_tp_resume(tmp_path):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     resumed.run_inline(env_steps_per_update=4)
     assert int(resumed.state.step) == 12
+
+
+def test_sharded_plane_preempt_restores_pending_readback(tmp_path):
+    """A preempted run on the sharded plane serializes its deferred priority
+    readback instead of applying it (the uninterrupted run's next draw
+    happens BEFORE that write-back lands), and the resumed plane holds it
+    again: same priorities, each draw's per-shard stamps as (dp,) arrays, and
+    the same trees once it drains. Until PR 51 only the device plane did."""
+    cfg = tiny_test().replace(
+        env_name="catch",
+        replay_plane="sharded",
+        updates_per_dispatch=2,
+        dp_size=4,
+        batch_size=8,
+        buffer_capacity=16 * 40,
+        checkpoint_dir=str(tmp_path / "ckpt"),
+        snapshot_replay=True,
+        training_steps=100,
+        save_interval=1000,
+        learning_starts=48,
+    )
+    K, dp = 2, 4
+    tr = Trainer(cfg, vec_env=CatchVecEnv(num_envs=cfg.num_actors, height=12, width=12, seed=0))
+    tr.reset_clock()
+    tr.warmup()
+    for _ in range(2):  # the second update drains the first's readback
+        tr._one_update(tr.plane.sample())
+    assert tr.plane._pending is not None
+
+    def leaves(replay):
+        return [s.tree.priorities_of(np.arange(s.tree.capacity)).copy() for s in replay.shards]
+
+    before = leaves(tr.replay)
+    tr.preempted = True
+    carry = tr._capture_carry_safe()
+    assert tr.plane._pending is None
+    for a, b in zip(leaves(tr.replay), before):  # captured, not applied
+        np.testing.assert_array_equal(a, b)
+    assert carry["pend_prios"].shape == (K, dp, cfg.batch_size // dp)
+    assert carry["pend_old_ptr"].shape == carry["pend_old_advances"].shape == (K, dp)
+    tr._snapshot_on_exit(extra=carry)
+    tr._finalize_preempt()
+
+    resumed = Trainer(
+        cfg, vec_env=CatchVecEnv(num_envs=cfg.num_actors, height=12, width=12, seed=0), resume=True
+    )
+    assert resumed._initial_step == 2 * K
+    prios, draws = resumed.plane._pending
+    np.testing.assert_array_equal(prios, carry["pend_prios"])
+    assert len(draws) == K
+    for k, d in enumerate(draws):
+        assert isinstance(d.old_ptr, np.ndarray) and d.old_ptr.shape == (dp,)
+        np.testing.assert_array_equal(d.old_ptr, carry["pend_old_ptr"][k])
+        np.testing.assert_array_equal(d.old_advances, carry["pend_old_advances"][k])
+        np.testing.assert_array_equal(d.idxes, carry["pend_idxes"][k])
+    for a, b in zip(leaves(resumed.replay), before):
+        np.testing.assert_array_equal(a, b)
+
+    # draining it moves both runs' trees the same way
+    tr.plane.restore_pending({k[len("pend_"):]: v for k, v in carry.items() if k.startswith("pend_")})
+    tr.finish_updates()
+    resumed.finish_updates()
+    after = leaves(resumed.replay)
+    assert any((a != b).any() for a, b in zip(after, before))
+    for a, b in zip(after, leaves(tr.replay)):
+        np.testing.assert_array_equal(a, b)
