@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
-from r2d2_tpu.models.core import Carry, pack_state, zero_carry
+from r2d2_tpu.models.core import Carry, core_class, pack_state, zero_carry
 from r2d2_tpu.models.encoders import block_frames, blocked_shape
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.epsilon import epsilon_ladder
@@ -180,6 +180,16 @@ def make_collect_core(
     t1 = jnp.arange(T + 1)
     tT = jnp.arange(T)
     sid = jnp.arange(S)
+    # where each of a block's S windows starts: sid * L less its burn-in,
+    # min(sid * L, Bn), which `_pack` gives every window that holds a step.
+    # Static, so a core whose state is large (its class says
+    # `keeps_window_starts`, models/core.py) has its carry kept there alone:
+    # the chunk's scan runs in segments that end at the starts, and the
+    # carry between two segments is the state `_pack` stores. Every other
+    # core stacks its state at every step, as before.
+    window_starts = np.clip(np.arange(S) * L - np.minimum(np.arange(S) * L, Bn), 0, T)
+    starts_only = getattr(core_class(cfg), "keeps_window_starts", False)
+    segment_ends = sorted({*window_starts.tolist(), T} - {0}) if starts_only else [T]
 
     def _pack(obs, final_obs, actions, rewards, qs, hiddens, size, done, qf,
               init_la, init_lr, init_hid):
@@ -194,7 +204,9 @@ def make_collect_core(
         init_la/init_lr/init_hid
         are the pre-chunk last action / last reward / recurrent state:
         zeros at an episode start, the carried values on a continuation
-        chunk (carry_episodes)."""
+        chunk (carry_episodes). Where the collector keeps the carry at the
+        window starts alone (`starts_only`), hiddens is (S, *state_shape):
+        the state BEFORE each of `window_starts`, init_hid among them."""
         valid_t1 = t1 <= size          # stored entries 0..size
         valid_T = tT < size            # recorded transitions
 
@@ -247,9 +259,12 @@ def make_collect_core(
         # hidden_buf[t] = state before consuming obs t; index 0 is the
         # episode-start zero state, or the carried state on a
         # continuation chunk (carry_episodes)
-        stored_hid = jnp.concatenate([init_hid[None], hiddens], axis=0)
-        wstart = jnp.clip(sid * L - burn, 0, T)
-        hid_seq = jnp.where(valid_seq[:, None, None], stored_hid[wstart], 0.0)
+        if starts_only:
+            hid_seq = jnp.where(valid_seq[:, None, None], hiddens, 0.0)
+        else:
+            stored_hid = jnp.concatenate([init_hid[None], hiddens], axis=0)
+            wstart = jnp.clip(sid * L - burn, 0, T)
+            hid_seq = jnp.where(valid_seq[:, None, None], stored_hid[wstart], 0.0)
 
         # actor-side initial priorities in rescaled space (quirk-6/7 fix):
         # bootstrap value is max_a Q(s_{min(t+max_fwd, size)}), zeroed at a
@@ -335,7 +350,8 @@ def make_collect_core(
                 "action": act,
                 "reward": reward,
                 "q": q.astype(jnp.float32),
-                "hidden": pack_state(core2).astype(jnp.float32),
+                # stacked at every step unless the class keeps the starts alone
+                **({} if starts_only else {"hidden": pack_state(core2).astype(jnp.float32)}),
                 "applied": active,
                 "done": done,
             }
@@ -345,7 +361,18 @@ def make_collect_core(
 
         keys = jax.random.split(key, T + 2)
         init = (env_state, core0, la0, lr0, jnp.ones(E, bool))
-        (env_f, core_f, la_f, lr_f, alive_f), rec = jax.lax.scan(body, init, keys[:T])
+        carry, recs, at_start, begin = init, [], {0: core0}, 0
+        for end in segment_ends:
+            carry, rec = jax.lax.scan(body, carry, keys[begin:end])
+            recs.append(rec)
+            at_start[end] = carry[1]
+            begin = end
+        env_f, core_f, la_f, lr_f, alive_f = carry
+        rec = recs[0] if len(recs) == 1 else jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *recs)
+        if starts_only:  # (E, S, *state_shape): the state before each window's first step
+            hiddens = jnp.stack(
+                [pack_state(at_start[int(w)]) for w in window_starts], axis=1
+            ).astype(jnp.float32)
 
         final_obs = stored_order(vrender(env_f))
         q_final, _ = net.apply(
@@ -363,7 +390,7 @@ def make_collect_core(
             env_major(rec["action"]),
             env_major(rec["reward"]),
             env_major(rec["q"]),
-            env_major(rec["hidden"]),
+            hiddens if starts_only else env_major(rec["hidden"]),
             sizes,
             dones,
             q_final,

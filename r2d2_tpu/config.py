@@ -24,6 +24,7 @@ from typing import Optional, Tuple
 RECURRENT_CORES = {
     "lstm": "r2d2_tpu.models.lstm:LSTM",
     "lru": "r2d2_tpu.models.lru:LRU",
+    "hybrid_stack": "r2d2_tpu.models.hybrid_stack:HybridStack",
 }
 
 
@@ -439,6 +440,12 @@ class R2D2Config:
     # (models/lru.py _ring_init).
     lru_r_min: float = 0.9
     lru_r_max: float = 0.999
+    # hybrid_stack only: the layer pattern and the layers' widths under the
+    # names their published config gives them (models/hybrid_stack.py
+    # `StackSpec` lists and checks the keys; a core without such keys leaves
+    # it empty). Given as a dict (a benchmark configuration file's JSON) or
+    # as pairs; held as sorted pairs, so the config stays hashable.
+    core_config: Tuple = ()
 
     # --- infra ------------------------------------------------------------
     seed: int = 0
@@ -896,6 +903,13 @@ class R2D2Config:
                 f"unknown recurrent_core {self.recurrent_core!r}; registered: "
                 f"{sorted(RECURRENT_CORES)}"
             )
+        if bool(self.core_config) != (self.recurrent_core == "hybrid_stack"):
+            raise ValueError(
+                "core_config holds the hybrid_stack core's pattern and widths "
+                "(models/hybrid_stack.py): that core needs it and no other "
+                f"takes it; recurrent_core={self.recurrent_core!r} with "
+                f"{len(self.core_config)} keys"
+            )
         if self.lru_chunk < 0:
             raise ValueError("lru_chunk must be >= 0")
         if self.lru_chunk > 0 and self.recurrent_core != "lru":
@@ -1106,6 +1120,15 @@ class R2D2Config:
             if self.batch_size % max(self.dp_size, 1) != 0:
                 raise ValueError("batch_size must divide evenly over dp_size")
         return self
+
+    def __post_init__(self):
+        pairs = self.core_config
+        if isinstance(pairs, dict):
+            pairs = pairs.items()
+        frozen = lambda v: tuple(v) if isinstance(v, list) else v
+        object.__setattr__(
+            self, "core_config", tuple(sorted((str(k), frozen(v)) for k, v in pairs))
+        )
 
     def replace(self, **kw) -> "R2D2Config":
         return dataclasses.replace(self, **kw).validate()

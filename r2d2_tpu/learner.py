@@ -147,6 +147,10 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
     the benchmark's correctness check (benchmark/correct.py runs it as its
     own program against the plain reference)."""
     eps = cfg.value_rescale_eps
+    # a core whose layers count what they route says how to read what an
+    # unroll sowed (`counts_of`); the benchmark's stand-in for the network
+    # has no core and counts nothing
+    counts_of = getattr(getattr(net, "core", None), "counts_of", None)
 
     def loss_fn(params, target_params, b: DeviceBatch, denom):
         """denom is the GLOBAL valid-step count: under shard_map it has
@@ -156,18 +160,24 @@ def make_loss_fn(cfg: R2D2Config, net: R2D2Network):
         not)."""
         # b.task is None on the single-task golden path (a no-op input);
         # multi-task batches condition the dueling head per sequence
-        q_learn, q_boot_online, mask = net.apply(
-            params, b.obs, b.last_action, b.last_reward, b.hidden,
-            b.burn_in_steps, b.learning_steps, b.forward_steps, b.task,
-        )
-        _, q_boot_target, _ = net.apply(
-            target_params, b.obs, b.last_action, b.last_reward, b.hidden,
-            b.burn_in_steps, b.learning_steps, b.forward_steps, b.task,
-        )
-        return island(
+        inputs = (b.obs, b.last_action, b.last_reward, b.hidden,
+                  b.burn_in_steps, b.learning_steps, b.forward_steps, b.task)
+        counted = {}
+        if counts_of is not None:
+            # what the core counted in the online unroll leaves with the
+            # update's metrics
+            (q_learn, q_boot_online, mask), sown = net.apply(
+                params, *inputs, mutable=["intermediates"]
+            )
+            counted = counts_of(sown)
+        else:
+            q_learn, q_boot_online, mask = net.apply(params, *inputs)
+        _, q_boot_target, _ = net.apply(target_params, *inputs)
+        loss, (priorities, aux) = island(
             q_learn, q_boot_online, q_boot_target, mask, b.action,
             b.n_step_reward, b.gamma, b.is_weights, denom,
         )
+        return loss, (priorities, {**aux, **counted})
 
     def island(q_learn, q_boot_online, q_boot_target, mask, action,
                n_step_reward, gamma, is_weights, denom):

@@ -51,7 +51,7 @@ from r2d2_tpu.config import R2D2Config
 from r2d2_tpu.collect import default_chunk_len, make_collect_core
 from r2d2_tpu.learner import TrainState, make_multi_update_core
 from r2d2_tpu.models.r2d2 import R2D2Network
-from r2d2_tpu.utils.profiling import counted, register_program, scoped, span
+from r2d2_tpu.utils.profiling import SPANS, counted, put, register_program, scoped, span
 
 
 def _reserve(rings, slots: int) -> list:
@@ -242,7 +242,7 @@ class _DeferredDrainRunner:
         self._inserted0 = replay.env_steps
         self._dispatch_count = 0
         self.total_env_steps = 0
-        self._pending = None        # deferred (priorities, draws) readback
+        self._pending = None        # deferred (priorities, draws, core counts) readback
         self._pending_chunk = None  # deferred (token, chunk bookkeeping)
         self.replay_rng = (
             sample_rng if sample_rng is not None else np.random.default_rng(0)
@@ -282,7 +282,11 @@ class _DeferredDrainRunner:
             self._pending_chunk = (token, chunk_host) if collect else None
             if prev_chunk is not None:
                 recorded = self._drain_chunk(prev_chunk)
-            prev, self._pending = self._pending, (prios, draws)
+            # what the core counted in this dispatch's last update (a core
+            # that counts: models/hybrid_stack.py) rides with the priorities
+            core_counts = {k: v for k, v in m.items() if k in SPANS}
+            _start_async_copy(core_counts)
+            prev, self._pending = self._pending, (prios, draws, core_counts)
             if prev is not None:
                 self._drain(prev)
         return state, m, recorded
@@ -307,9 +311,15 @@ class _DeferredDrainRunner:
         return recorded
 
     def _drain(self, pending) -> None:
-        prios, draws = pending
+        prios, draws, core_counts = pending
         with span("r2d2.dispatch.readback"):
             rows = np.asarray(prios)
+            core_counts = jax.device_get(core_counts)
+        if core_counts:  # the last drained update's readings
+            put("moe.rows_offered", core_counts["moe.rows_offered"])
+            put("moe.rows_dropped", core_counts["moe.rows_dropped"])
+            put("moe.dropped_share", core_counts["moe.dropped_share"])
+            put("moe.load_max_over_mean", core_counts["moe.load_max_over_mean"])
         with _priorities_span():
             for row, d in zip(rows, draws):
                 # each row under its own draw's staleness window and lap
@@ -840,7 +850,7 @@ class MultiHostFusedRunner(_DeferredDrainRunner):
         # staleness window + lap stamp. It reads the priorities back itself,
         # so this plane's readback wait is inside the priorities span
         with _priorities_span():
-            self.replay.drain_pending(pending)
+            self.replay.drain_pending(pending[:2])
 
 
 # ---------------------------------------------------------------------------

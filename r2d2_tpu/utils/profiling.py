@@ -95,6 +95,12 @@ SPANS: Dict[str, tuple] = {
     "setup.compile_s": ("CLIs", "jax's compile-duration total (trace + lower + backend) when the last step program's first call returned"),
     "replay.priority_rows_offered": ("replay", "priority rows handed to ReplayControlPlane.update_priorities"),
     "replay.priority_rows_applied": ("replay", "of those, rows the staleness mask let through to the tree"),
+    # what a core's expert mixtures counted in the last update of the last drained dispatch (models/hybrid_stack.py),
+    # set (`put`) as that dispatch's priorities are drained: readings, not sums
+    "moe.rows_offered": ("model", "token assignments routed to the experts held here, summed over the core's mixtures"),
+    "moe.rows_dropped": ("model", "of those, assignments beyond an expert's static capacity: left out and counted"),
+    "moe.dropped_share": ("model", "rows_dropped over rows_offered, percent"),
+    "moe.load_max_over_mean": ("model", "the fullest of ALL routed experts over the mean one, each summed over the mixtures"),
 }
 
 # name -> [count, total ns, cpu ns]; plain counts beside them. Single-writer
@@ -260,6 +266,12 @@ def count(name: str, n: float = 1) -> None:
         _counts[name] += n
     except KeyError:
         _counts[_known(name)] = n
+
+
+def put(name: str, value: float) -> None:
+    """Set the counter `name` (a name of the table) to `value`: a reading
+    that stands for itself, not a sum."""
+    _counts[_known(name)] = float(value)
 
 
 def counted(name: str) -> float:

@@ -17,6 +17,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+import importlib
+
 import pytest
 
 from r2d2_tpu import config as config_mod
@@ -100,12 +102,15 @@ def _fn_env(cfg):
 
 
 def test_registry_names_are_what_validation_accepts():
-    assert set(config_mod.RECURRENT_CORES) == {"lstm", "lru"}
-    for name in config_mod.RECURRENT_CORES:
+    assert set(config_mod.RECURRENT_CORES) == {"lstm", "lru", "hybrid_stack"}
+    for name in ("lstm", "lru"):
         cfg = tiny_test().replace(recurrent_core=name)
         cls = core_class(cfg)
         assert cls.state_shape(cfg) == (2, cfg.hidden_dim)
         assert isinstance(cls.cuts_at_burn_in, bool)
+    # the stack states the rule's (1, S) form (tests/test_hybrid_stack.py)
+    stack = importlib.import_module("r2d2_tpu.models.hybrid_stack").HybridStack
+    assert (stack.cuts_at_burn_in, stack.keeps_window_starts) == (False, True)
     assert core_class(tiny_test()).cuts_at_burn_in                       # lstm
     assert not core_class(tiny_test().replace(recurrent_core="lru")).cuts_at_burn_in
     with pytest.raises(ValueError, match="unknown recurrent_core 'toy'"):

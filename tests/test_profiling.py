@@ -133,8 +133,8 @@ def test_a_name_outside_the_table_is_refused(use):
 
 def test_every_name_in_the_program_is_in_the_table():
     """No span, scope or counter outside the one table: every literal first
-    argument of span / spanned / step_span / count / counted / scoped in
-    r2d2_tpu/ is a key."""
+    argument of span / spanned / step_span / count / counted / put / scoped
+    in r2d2_tpu/ is a key."""
     import ast
 
     root = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "r2d2_tpu")
@@ -146,9 +146,9 @@ def test_every_name_in_the_program_is_in_the_table():
             tree = ast.parse(open(os.path.join(d, f)).read())
             for node in ast.walk(tree):
                 if (isinstance(node, ast.Call) and getattr(node.func, "id", getattr(node.func, "attr", ""))
-                        in ("span", "spanned", "step_span", "count", "counted", "scoped") and node.args):
+                        in ("span", "spanned", "step_span", "count", "counted", "put", "scoped") and node.args):
                     lit = [a for a in node.args if isinstance(a, ast.Constant) and isinstance(a.value, str)]
-                    used.update(a.value for a in lit if a.value.startswith(("r2d2", "setup.", "replay.")))
+                    used.update(a.value for a in lit if a.value.startswith(("r2d2", "setup.", "replay.", "moe.")))
     assert used and used <= set(SPANS), used - set(SPANS)
     # and nothing in the table that no code uses
     assert set(SPANS) - used <= {"setup.compile_s"}  # set by key in _Program.__call__
