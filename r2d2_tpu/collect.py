@@ -60,7 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from r2d2_tpu.config import R2D2Config
-from r2d2_tpu.models.core import Carry, core_class, pack_state, zero_carry
+from r2d2_tpu.models.core import Carry, close_carry, core_class, open_carry, pack_state, zero_carry
 from r2d2_tpu.models.encoders import block_frames, blocked_shape
 from r2d2_tpu.models.r2d2 import R2D2Network
 from r2d2_tpu.ops.epsilon import epsilon_ladder
@@ -323,7 +323,7 @@ def make_collect_core(
             # argmax/where pair, selection fused with the core step
             q, act, core2 = net.apply(
                 params, obs, la, lr, core, explore, rand_a,
-                task=task_vec, method=net.act_select,
+                task=task_vec, opened=True, method=net.act_select,
             )
             # scan carry stays f32 regardless of compute dtype (bf16->f32
             # is exact, and act re-casts on use — same values as the host
@@ -351,7 +351,7 @@ def make_collect_core(
                 "reward": reward,
                 "q": q.astype(jnp.float32),
                 # stacked at every step unless the class keeps the starts alone
-                **({} if starts_only else {"hidden": pack_state(core2).astype(jnp.float32)}),
+                **({} if starts_only else {"hidden": pack_state(close_carry(net.core, core2)).astype(jnp.float32)}),
                 "applied": active,
                 "done": done,
             }
@@ -360,14 +360,19 @@ def make_collect_core(
             return (env_state, core2, la2, lr2, active & ~done), rec
 
         keys = jax.random.split(key, T + 2)
-        init = (env_state, core0, la0, lr0, jnp.ones(E, bool))
+        # the scans carry the core's state in its OPENED form (models/core.py:
+        # a stack's parts, each a loop-carried buffer of its own; the carry
+        # itself for a core that states nothing): opened once before the first
+        # segment, closed where a state leaves a scan, at each segment's end
+        init = (env_state, open_carry(net.core, core0), la0, lr0, jnp.ones(E, bool))
         carry, recs, at_start, begin = init, [], {0: core0}, 0
         for end in segment_ends:
             carry, rec = jax.lax.scan(body, carry, keys[begin:end])
             recs.append(rec)
-            at_start[end] = carry[1]
+            at_start[end] = close_carry(net.core, carry[1])
             begin = end
-        env_f, core_f, la_f, lr_f, alive_f = carry
+        env_f, _, la_f, lr_f, alive_f = carry
+        core_f = at_start[T]
         rec = recs[0] if len(recs) == 1 else jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *recs)
         if starts_only:  # (E, S, *state_shape): the state before each window's first step
             hiddens = jnp.stack(

@@ -3,7 +3,7 @@
 A recurrent core (models/lstm.py `LSTM`, models/lru.py `LRU`,
 models/hybrid_stack.py `HybridStack`) is a flax module registered by name in
 `config.RECURRENT_CORES`. Besides `__call__(xs, carry, burn_in=None)` and
-`step(x, carry)` its class states three things, and a fourth where its state
+`step(x, carry)` its class states three things, and two more where its state
 is large, and every other module asks the class rather than knowing:
 
 - `state_shape(cfg)`: the per-sequence shape of its STORED state, `(n, W)`
@@ -40,6 +40,23 @@ is large, and every other module asks the class rather than knowing:
   compile from 15.6 s to 28.0 s (compiled for the described v5e, PR 53,
   PERF.md finding 53.8) against a bound of a tenth on `setup_s`, to spare
   0.7 GB of temporaries that fit.
+- `open_carry` / `close_carry` / `step_open` (optional, all three or none): a
+  core whose `step` does not work on its stored form says how a `Carry` is
+  OPENED into the form its step carries from one step to the next
+  (`HybridStack`: a tuple of its layers' parts, float32), how that form is
+  CLOSED back into the `Carry`, and a step on the opened form, with `step ==
+  close_carry . step_open . open_carry` bit for bit. The opened form is for
+  ONE caller, a loop that steps many times and stores rarely: the device
+  collector opens the carry once a chunk, scans its env steps over the
+  opened form (`R2D2Network.act_select(..., opened=True)`) and closes where a
+  state is stored (a block's window starts, the chunk's end), so a step moves
+  what the recurrence needs and not the whole stored row twice more (138 MB
+  joined, then padded, at each of 1,024 env steps: PERF.md finding 54). A
+  class that states nothing (the LSTM, the LRU) has the identity for both and
+  its `step` (`open_carry`, `close_carry`, `step_open` below), so its programs
+  are the same text either way. Nothing outside that loop sees the opened
+  form: the stores, the gather, `batch["hidden"]`, the snapshot and
+  `CollectCarry` hold the `Carry`. No config knob here either.
 
 `serve/state_cache.py` and `liveloop/tap.py` still hold the state as two
 arrays of H (ROADMAP D1b); `check_two_row_state` makes them refuse any
@@ -96,6 +113,22 @@ def pack_state(carry):
 def unpack_state(stored) -> Carry:
     """Stored `(B, n, W)` -> carry: the inverse of pack_state."""
     return tuple(stored[:, i] for i in range(stored.shape[1]))
+
+
+def open_carry(core, carry: Carry):
+    """`carry` in the form `core`'s step carries between steps: the core's own
+    statement, or the carry itself (module docstring)."""
+    return core.open_carry(carry) if hasattr(core, "open_carry") else carry
+
+
+def close_carry(core, opened) -> Carry:
+    """The inverse of `open_carry`."""
+    return core.close_carry(opened) if hasattr(core, "close_carry") else opened
+
+
+def step_open(core, x, opened):
+    """One step of the bound module `core` on the opened form -> (out, opened)."""
+    return getattr(core, "step_open", core.step)(x, opened)
 
 
 def check_two_row_state(state_shape, hidden_dim: int, core: str, who: str) -> None:

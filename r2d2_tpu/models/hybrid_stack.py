@@ -42,7 +42,13 @@ gather and `batch["hidden"]` see an array like any other. Its statements for
 the seam: `cuts_at_burn_in = False` (as the LRU: burn-in is backpropagated
 through), `keeps_window_starts = True` (a row's state is megabytes: the
 collector keeps the carry at a block's static window starts alone,
-collect.py). What the mixtures count in an `unroll` is sown
+collect.py), and `open_carry` / `close_carry` / `step_open`: the OPENED form
+is the tuple of the `segments()` parts, float32 (`split_state` opens,
+`join_state` closes), and the layers are written once, over parts (`_layers`).
+`step` and `unroll` split the flat row, run the layers and join; the
+collector's scan carries the parts from one env step to the next, each a
+buffer of its own that its layer updates in place, and joins where a state is
+stored. Only that scan sees the opened form. What the mixtures count in an `unroll` is sown
 (`counts_of` reads it); the loss hands it on with its metrics and the fused runners
 publish it with a readback they already make.
 
@@ -507,13 +513,11 @@ class HybridStack(nn.Module):
         self.layers = [_layer(s, self.dtype, kind, i) for i, kind in enumerate(s.hybrid_override_pattern)]
         self.final_norm = self.param("final_norm", nn.initializers.ones, (s.hidden_size,))
 
-    def _run(self, x, carry):
-        """x (B, T, in_dim), or (B, in_dim) for one step."""
+    def _layers(self, x, parts):
+        """x (B, T, in_dim), or (B, in_dim) for one step; `parts` the carry as
+        `split_state` gives it -> (out, parts', counts)."""
         s = self.spec
-        # the row as the gather (or the scan) hands it over, whole: left free,
-        # the chip's compiler slices the STORE into the layers' parts ahead of
-        # the gather and copies all of it, every update (PERF.md finding 53)
-        parts = split_state(s, jax.lax.optimization_barrier(carry[0]))
+        parts = dict(parts)
         count = _count_of(parts[(-1, "count")])
         counts = jnp.zeros((len(COUNTS),), F32)
         x = _mm(x, self.embed, self.dtype)
@@ -523,7 +527,16 @@ class HybridStack(nn.Module):
             parts.update({(i, name): value for name, value in zip(STATE_NAMES[kind], state)})
             counts = counts + c
         parts[(-1, "count")] = _count_pair(count + (1 if x.ndim == 2 else x.shape[1]))
-        return rms_norm(x, self.final_norm, s.norm_eps), (join_state(s, parts),), counts
+        return rms_norm(x, self.final_norm, s.norm_eps), parts, counts
+
+    def _run(self, x, carry):
+        """`_layers` on the stored form: split, run, join."""
+        s = self.spec
+        # the row as the gather (or the scan) hands it over, whole: left free,
+        # the chip's compiler slices the STORE into the layers' parts ahead of
+        # the gather and copies all of it, every update (PERF.md finding 53)
+        out, parts, counts = self._layers(x, split_state(s, jax.lax.optimization_barrier(carry[0])))
+        return out, (join_state(s, parts),), counts
 
     def __call__(self, xs, carry: Carry, burn_in=None) -> Tuple[jnp.ndarray, Carry]:
         """Unroll over (B, T, D) from carry -> ((B, T, hidden), final carry).
@@ -542,6 +555,26 @@ class HybridStack(nn.Module):
         """One acting step on (B, D): the recurrence itself, a ring write."""
         out, carry, _ = self._run(x, carry)
         return out, carry
+
+    # the opened form (models/core.py): the parts themselves, in `segments()` order
+
+    @nn.nowrap
+    def open_carry(self, carry: Carry):
+        return tuple(split_state(self.spec, carry[0]).values())
+
+    @nn.nowrap
+    def close_carry(self, opened) -> Carry:
+        return (join_state(self.spec, self._parts(opened)),)
+
+    @nn.nowrap
+    def _parts(self, opened):
+        return {(i, name): part for (i, name, _), part in zip(self.spec.segments(), opened)}
+
+    def step_open(self, x, opened):
+        """`step` between two joins: close_carry(step_open(x, open_carry(c))[1])
+        is step(x, c)[1], bit for bit."""
+        out, parts, _ = self._layers(x, self._parts(opened))
+        return out, tuple(parts.values())
 
     @staticmethod
     def counts_of(intermediates) -> dict:

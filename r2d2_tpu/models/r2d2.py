@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from r2d2_tpu.config import R2D2Config
-from r2d2_tpu.models.core import Carry, core_class, state_spec, unpack_state
+from r2d2_tpu.models.core import Carry, core_class, state_spec, step_open, unpack_state
 from r2d2_tpu.models.encoders import make_encoder
 
 
@@ -311,9 +311,10 @@ class R2D2Network(nn.Module):
         last_reward: jnp.ndarray,  # (B,) float32
         carry: Carry,              # models/core.py
         task: jnp.ndarray | None = None,  # (B,) int32 (multi-task only)
+        opened: bool = False,      # carry is the core's OPENED form (models/core.py)
     ) -> Tuple[jnp.ndarray, Carry]:
         x = self._core_input(obs, last_action, last_reward)
-        h, carry = self.core.step(x, carry)
+        h, carry = step_open(self.core, x, carry) if opened else self.core.step(x, carry)
         return self._dueling(h, task), carry
 
     def act_select(
@@ -325,6 +326,7 @@ class R2D2Network(nn.Module):
         explore: jnp.ndarray,         # (B,) bool ε-coin per row
         random_actions: jnp.ndarray,  # (B,) int random draws in [0, A)
         task: jnp.ndarray | None = None,  # (B,) int32 (multi-task only)
+        opened: bool = False,         # as `act`
     ) -> Tuple[jnp.ndarray, jnp.ndarray, Carry]:
         """Fused act tail: core step + dueling + ε-greedy select in one op.
 
@@ -337,7 +339,7 @@ class R2D2Network(nn.Module):
         """
         from r2d2_tpu.ops.act_tail import epsilon_greedy_actions
 
-        q, carry = self.act(obs, last_action, last_reward, carry, task)
+        q, carry = self.act(obs, last_action, last_reward, carry, task, opened)
         return q, epsilon_greedy_actions(q, explore, random_actions), carry
 
     # --------------------------------------------------------------- unroll

@@ -5,7 +5,10 @@ against `unroll`; attention through the carried memory against full causal
 attention; the share test (every chip's routed part plus the shared expert once
 is the uncut layer); the static capacity's drops, counted; the collector that
 keeps the carry at the window starts alone against the one that stacks it at
-every step, bit for bit, for every core; what the fused runner publishes."""
+every step, bit for bit, for every core; the collector that scans over the
+stack's OPENED form (its layers' parts) against the one that steps the flat
+row, bit for bit, and the joins it makes, counted; what the fused runner
+publishes."""
 
 import importlib.util
 import json
@@ -18,7 +21,7 @@ import pytest
 
 from r2d2_tpu.config import tiny_test
 from r2d2_tpu.models import hybrid_stack as hs
-from r2d2_tpu.models.core import core_class, state_spec, zero_carry
+from r2d2_tpu.models.core import close_carry, core_class, open_carry, state_spec, zero_carry
 from r2d2_tpu.models.r2d2 import init_params
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -370,7 +373,221 @@ def test_the_collector_stores_the_same_carries_at_the_window_starts_alone(core, 
         assert a.dtype == b.dtype and np.array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_only_the_stack_asks_for_window_starts():
-    asks = {name: getattr(core_class(tiny_test().replace(recurrent_core=name) if name != "hybrid_stack" else tiny_cfg()),
-                          "keeps_window_starts", False) for name in ("lstm", "lru", "hybrid_stack")}
+@pytest.mark.parametrize("statement", ["keeps_window_starts", "open_carry", "close_carry", "step_open"])
+def test_only_the_stack_asks_for_window_starts(statement):
+    """...and only the stack states an opened form: the LSTM and the LRU state
+    nothing, and the seam gives them the identity and their own `step`."""
+    asks = {name: bool(getattr(core_class(tiny_test().replace(recurrent_core=name) if name != "hybrid_stack"
+                                          else tiny_cfg()), statement, False))
+            for name in ("lstm", "lru", "hybrid_stack")}
     assert asks == {"lstm": False, "lru": False, "hybrid_stack": True}
+
+
+# ------------------------------------------------- the opened form (models/core.py)
+
+
+class _Countdown:
+    """A functional env whose episode ends after a number of steps drawn at
+    reset from `limits`: some slots end inside a chunk, some outlive it."""
+
+    def __init__(self, obs_shape, limits):
+        self.obs_shape, self.limits = obs_shape, jnp.asarray(limits, jnp.int32)
+
+    def reset(self, key):
+        k1, k2 = jax.random.split(key)
+        return {"t": jnp.int32(0), "limit": jax.random.choice(k1, self.limits),
+                "salt": jax.random.randint(k2, (), 0, 251)}
+
+    def step(self, state, action):
+        t = state["t"] + 1
+        return dict(state, t=t), (action == t % 3).astype(jnp.float32), t >= state["limit"]
+
+    def render(self, state):
+        n = int(np.prod(self.obs_shape))
+        return ((jnp.arange(n) * (state["t"] + 3) + state["salt"]) % 251).astype(jnp.uint8).reshape(self.obs_shape)
+
+
+E_OPEN = 4
+OPEN_CFG = dict(action_dim=3, max_episode_steps=16, block_length=16, learning_steps=4, burn_in_steps=2,
+                forward_steps=2, num_actors=E_OPEN, recurrent_core="hybrid_stack", hidden_dim=64,
+                core_config=TINY_CORE)
+
+
+@pytest.fixture(scope="module")
+def open_built():
+    cfg = tiny_test().replace(**OPEN_CFG)
+    return (cfg, *init_params(jax.random.PRNGKey(0), cfg), _Countdown(cfg.obs_shape, (5, 11, 40)))
+
+
+def _window_starts(T):
+    """collect.py's, for OPEN_CFG's block: 0, 2, 6, 10, held to the chunk."""
+    return np.clip(np.arange(4) * 4 - np.minimum(np.arange(4) * 4, 2), 0, T)
+
+
+def _collector_inputs(cfg, fn_env, carry_episodes, seed=7):
+    from r2d2_tpu.collect import initial_carry
+
+    key = jax.random.PRNGKey(seed)
+    if carry_episodes:  # a chunk in mid-episode: a carried state, action and reward that are not zero
+        first = initial_carry(cfg, fn_env, E_OPEN, key)
+        rng = np.random.default_rng(seed)
+        row = np.asarray(_inputs(cfg, E_OPEN, 1, seed=seed, seen=3)[1][:, 0])
+        env_state = first._replace(core=(jnp.asarray(row),), last_action=jnp.asarray(rng.integers(0, 3, E_OPEN), jnp.int32),
+                                   last_reward=jnp.asarray(rng.normal(size=E_OPEN), jnp.float32),
+                                   prefix_reward=jnp.ones(E_OPEN, jnp.float32), ep_steps=jnp.full(E_OPEN, 3, jnp.int32))
+    else:
+        env_state = jax.vmap(fn_env.reset)(jax.random.split(key, E_OPEN))
+    return env_state, jnp.asarray([0.0, 0.3, 0.6, 1.0], jnp.float32), key
+
+
+@pytest.mark.parametrize("carry_episodes", [False, True], ids=["fresh_chunks", "carry_episodes"])
+def test_the_collector_over_the_opened_form_stores_what_a_loop_of_steps_on_the_flat_row_stores(
+        carry_episodes, open_built, monkeypatch):
+    """The scan over the stack's parts, joined at the segments' ends, against
+    (i) the same collector with the class's three statements taken away, whose
+    scan carries the flat row through `HybridStack.step` at every env step (the
+    parent's program), every output bit for bit: each field of `fields`,
+    `priorities`, `num_seq`, sizes, dones, rewards, the returned carry; and
+    (ii) a plain Python loop of `net.act_select` on the flat row over the same
+    keys, for what the core itself hands on: the state before each stored
+    window and the state the chunk leaves. A slot that ends early is among them."""
+    from r2d2_tpu.collect import make_collect_fn
+
+    T = 8 if carry_episodes else 16
+    cfg, net, params, fn_env = open_built
+    env_state, eps, key = _collector_inputs(cfg, fn_env, carry_episodes)
+    opened = make_collect_fn(cfg, net, fn_env, E_OPEN, T, carry_episodes)(params, env_state, eps, key)
+    with monkeypatch.context() as m:
+        for statement in ("open_carry", "close_carry", "step_open"):
+            m.delattr(core_class(cfg), statement)
+        flat = make_collect_fn(cfg, net, fn_env, E_OPEN, T, carry_episodes)(params, env_state, eps, key)
+    sizes = np.asarray(opened[3])
+    assert sizes.min() < T and sizes.max() == T, sizes       # one slot ends early, one runs the chunk out
+    assert jax.tree.structure(opened) == jax.tree.structure(flat)
+    for a, b in zip(jax.tree.leaves(flat), jax.tree.leaves(opened)):
+        assert a.dtype == b.dtype and a.shape == b.shape and np.array_equal(np.asarray(a), np.asarray(b))
+    assert float(jnp.abs(opened[0]["hidden"][:, 1:].astype(jnp.float32)).max()) > 0
+
+    # (ii) the loop: collect.py's body by hand, one jitted act_select on the flat row a step
+    act = jax.jit(lambda p, o, la, lr, c, ex, ra: net.apply(p, o, la, lr, c, ex, ra, method=net.act_select))
+    vrender, vstep = jax.vmap(fn_env.render), jax.vmap(fn_env.step)
+    if carry_episodes:
+        env, core, la, lr = env_state.env_state, env_state.core, env_state.last_action, env_state.last_reward
+    else:
+        env, core, la, lr = env_state, zero_carry(cfg, E_OPEN), jnp.zeros(E_OPEN, jnp.int32), jnp.zeros(E_OPEN)
+    active, keys, before = jnp.ones(E_OPEN, bool), jax.random.split(key, T + 2), []
+    for t in range(T):
+        before.append(core[0])
+        ke, ka = jax.random.split(keys[t])
+        explore, rand_a = jax.random.uniform(ke, (E_OPEN,)) < eps, jax.random.randint(ka, (E_OPEN,), 0, 3)
+        _, a, core = act(params, vrender(env), la, lr, core, explore, rand_a)
+        new_env, reward, done = vstep(env, a)
+        env = jax.tree.map(lambda new, old: jnp.where(active.reshape(-1, *[1] * (new.ndim - 1)), new, old), new_env, env)
+        la, lr = jnp.where(active, a, la), jnp.where(active, reward.astype(jnp.float32), lr)
+        active = active & ~done
+    starts = _window_starts(T)
+    stored = np.asarray(opened[0]["hidden"])
+    for e in range(E_OPEN):
+        for w in range(-(-int(sizes[e]) // 4)):
+            assert np.array_equal(stored[e, w, 0], np.asarray(before[starts[w]][e])), (e, w)
+    if carry_episodes:
+        cont = np.asarray(active & (3 + opened[3] < cfg.max_episode_steps))
+        assert cont.any() and not cont.all()
+        assert np.array_equal(np.asarray(opened[6].core[0]), np.where(cont[:, None], np.asarray(core[0]), 0.0))
+
+
+def _scan_bodies(jaxpr):
+    """Every scan's body under `jaxpr`, nested ones too."""
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            if eqn.primitive.name == "scan":
+                yield sub
+            yield from _scan_bodies(sub)
+
+
+def _shapes_in(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield from (tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars) if hasattr(v.aval, "shape"))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _shapes_in(sub)
+
+
+def _count_outside_scans(jaxpr, wanted):
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += wanted(eqn)
+        if eqn.primitive.name != "scan":
+            n += sum(_count_outside_scans(sub, wanted) for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
+
+
+@pytest.mark.parametrize("carry_episodes", [False, True], ids=["fresh_chunks", "carry_episodes"])
+def test_the_traced_collector_joins_the_stacks_carry_at_the_segments_ends_and_nowhere_inside_a_scan(
+        carry_episodes, open_built):
+    """The engagement counter, static: inside the env steps' scans no value
+    has the flat row's shape (E, state_size), or its length before the pad;
+    outside them the carry is joined (one `concatenate` of the parts and one
+    `pad` to whole lanes) once for each segment's end (the window starts
+    after 0 that `_pack` stores, and the chunk's end for `q_final` and the
+    returned carry) and once more inside `q_final`'s `HybridStack.step` on the
+    stored form, whose new carry nobody reads: ISSUE 54's `len(set(
+    window_starts)) + 1` where the chunk's end is no window start, T = 16 here
+    (3 a chunk in the nemotron cell, starts 0 and 448 of 1,024 steps), where
+    the flat-row collector joined at every env step."""
+    from r2d2_tpu.collect import make_collect_core
+
+    cfg, net, params, fn_env = open_built
+    spec = hs.StackSpec.of(cfg)
+    T = 8 if carry_episodes else 16
+    env_state, eps, key = _collector_inputs(cfg, fn_env, carry_episodes)
+    collect = make_collect_core(cfg, net, fn_env, E_OPEN, T, carry_episodes)
+    jaxpr = jax.make_jaxpr(collect)(params, env_state, eps, key).jaxpr
+    raw = sum(int(np.prod(shape)) for _, _, shape in spec.segments())
+    flat_rows = {(E_OPEN, spec.state_size), (E_OPEN, raw)}
+    bodies = list(_scan_bodies(jaxpr))
+    window_starts = set(_window_starts(T).tolist())
+    ends = (window_starts | {T}) - {0}
+    assert len([b for b in bodies if len(b.eqns) > 50]) >= len(ends)      # a scan of env steps a segment
+    for body in bodies:
+        assert not flat_rows & set(_shapes_in(body))
+    joins = _count_outside_scans(jaxpr, lambda e: e.primitive.name == "concatenate"
+                                 and tuple(e.outvars[0].aval.shape) == (E_OPEN, raw))
+    pads = _count_outside_scans(jaxpr, lambda e: e.primitive.name == "pad"
+                                and tuple(e.outvars[0].aval.shape) == (E_OPEN, spec.state_size))
+    assert joins == pads == len(ends) + 1 == (4 if carry_episodes else 5)
+    assert T in window_starts or joins == len(window_starts) + 1
+
+
+def test_close_of_open_is_the_carry_and_step_is_close_of_the_opened_step_of_open(built):
+    cfg, net, params = built
+    spec = hs.StackSpec.of(cfg)
+    x, hidden = _inputs(cfg, 3, 1, seed=4, seen=7)
+    carry, core = (hidden[:, 0],), {"params": params["params"]["core"]}
+    opened = open_carry(net.core, carry)
+    assert [p.shape[1:] for p in opened] == [shape for _, _, shape in spec.segments()]
+    assert all(p.dtype == jnp.float32 for p in opened)
+    assert np.array_equal(close_carry(net.core, opened)[0], carry[0])
+    out, after = jax.jit(lambda c: net.core.apply(core, x[:, 0], c, method="step"))(carry)
+    out_o, after_o = jax.jit(lambda o: net.core.apply(core, x[:, 0], o, method="step_open"))(opened)
+    assert np.array_equal(out, out_o) and np.array_equal(after[0], close_carry(net.core, after_o)[0])
+    assert float(jnp.abs(after[0] - carry[0]).max()) > 0
+    # two steps on the opened form, closed once, are two steps on the flat row
+    twice = jax.jit(lambda c: net.core.apply(core, x[:, 0] * 0.5, net.core.apply(core, x[:, 0], c, method="step")[1],
+                                             method="step"))(carry)
+    twice_o = jax.jit(lambda o: net.core.apply(core, x[:, 0] * 0.5, net.core.apply(core, x[:, 0], o, method="step_open")[1],
+                                               method="step_open"))(opened)
+    assert np.array_equal(twice[0], twice_o[0]) and np.array_equal(twice[1][0], close_carry(net.core, twice_o[1])[0])
+
+
+@pytest.mark.parametrize("core", ["lstm", "lru"])
+def test_a_core_that_states_nothing_is_opened_and_closed_by_the_identity_and_steps_as_it_steps(core):
+    """`act` on the opened form is `act`: one traced program either way."""
+    cfg = tiny_test().replace(recurrent_core=core)
+    net, params = init_params(jax.random.PRNGKey(0), cfg)
+    carry = tuple(jnp.full((2, cfg.hidden_dim), v, jnp.float32) for v in (0.25, -0.5))
+    assert open_carry(net.core, carry) is carry and close_carry(net.core, carry) is carry
+    obs = jnp.zeros((2, *cfg.obs_shape), jnp.uint8)
+    la, lr = jnp.asarray([1, 2], jnp.int32), jnp.asarray([0.5, -1.0], jnp.float32)
+    texts = [str(jax.make_jaxpr(lambda c: net.apply(params, obs, la, lr, c, opened=opened, method=net.act))(carry))
+             for opened in (False, True)]
+    assert texts[0] == texts[1]

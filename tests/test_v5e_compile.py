@@ -565,3 +565,59 @@ def test_lru_step_program_keeps_the_recurrence_in_three_kernel_calls(topo, compi
     assert sorted(re.sub(r"^%|\.\d+$", "", k) for k in kernels) == ["_lru_fwd_call", "_lru_fwd_call", "_lru_rev_call"]
     assert all("R2D2Network.unroll/core" in v and "_scan_states" in v for v in kernels.values()), kernels
     assert _rehearsal().instructions_in_buckets(text)["core"] < 600
+
+
+def _computations(text):
+    """{computation's name: its instruction lines} of a compiled program's text."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"\s*(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.strip() == "}":
+            name = None
+        elif name:
+            comps[name].append(line)
+    return comps
+
+
+def _called_from(comps, root):
+    """`root` and every computation it calls, directly or not (fusions, nested loops)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in comps and name not in seen:
+            seen.add(name)
+            for line in comps[name]:
+                todo += re.findall(r"(?:calls|body|condition|to_apply)=(%[\w.\-]+)", line)
+    return seen
+
+
+def test_the_stacks_collect_scans_carry_the_layers_parts_and_no_flat_row(topo, compiled_kernels):
+    """nemotron-twotower-30b-a3b-ep16's collecting step program (`jit_mega`) at
+    published widths: the two scans of a chunk's env steps (segments of 448 and
+    576, the `while`s of the `r2d2_collect` scope) carry the stack's state as
+    its layers' parts (`models/core.py`, the opened form): the three mixers'
+    `f32[16,64,64,128]` and the attention's keys and values are loop-carried
+    buffers of their own, and nothing inside a body, its fusions included, has
+    the flat row's `[16,2152576]` or its unpadded `[16,2152450]`. PR 54's parent
+    failed this with six `fusion f32[16,2152450]` and a `pad f32[16,2152576]` in
+    each body (138 MB written twice more at every one of 1,024 env steps: 7.8 %
+    of the device by the ledger's op list, PERF.md finding 54). The mechanism
+    engages in every chunk or in none, so this text is its tripwire."""
+    cfg, programs, _ = _rehearsal().step_programs("nemotron-twotower-30b-a3b-ep16", topo)
+    fn, args = programs["mega"]
+    comps = _computations(fn.lower(*args).compile().as_text())
+    scans = [line for lines in comps.values() for line in lines
+             if " while(" in line and 'op_name="jit(mega)/jit(r2d2_collect)/while"' in line]
+    assert len(scans) == 2, len(scans)
+    E = cfg.num_actors
+    for line in scans:
+        carried = line.split(" while(")[0]
+        assert len(re.findall(rf"f32\[{E},64,64,128\]", carried)) == 3, carried[:400]
+        assert len(re.findall(rf"f32\[{E},1024,2,128\]", carried)) == 2, carried[:400]
+        body = re.search(r"body=(%[\w.\-]+)", line).group(1)
+        flat = [l.split(", metadata=")[0].strip() for name in _called_from(comps, body) for l in comps[name]
+                if re.search(rf"\[{E},(2152450|2152576)\]", l)]
+        assert flat == [], flat[:4]
