@@ -55,6 +55,17 @@ publish it with a readback they already make.
 Matmuls run in the compute dtype with float32 accumulation; the residual
 stream, the recurrence, the norms, the softmax and the router are float32.
 Each layer of `unroll` is rematerialised in the backward pass.
+
+WHERE THE AXES LIVE in the mixer's sequence form (the chip's tiles are 8
+sublanes x 128 lanes, so an axis of 8 or 64 in a big array's minor place
+costs a pass to re-tile it, PERF.md finding 55): per-channel arrays stay `(B,
+T, C)` with C a multiple of 128 from `in_proj`'s output to `out_proj`'s input
+(z, xBC, dt and x, B, C are lane-aligned slices of it, and the grouped norm
+takes its groups' statistics by a membership matmul, not by a `(.., 8, 512)`
+view); inside `ssd_chunked` a chunk's 128 steps are the minor axis of every
+per-head scalar and of `x dt` and y, with heads a batch axis of the einsums,
+reached by one transposition in and one out. `step` (one row of 16 a call)
+keeps heads and head_dim as axes: its arrays are a tile or two.
 """
 
 from __future__ import annotations
@@ -195,8 +206,15 @@ def _mm(x, w, dtype):
 
 def rms_norm(x, weight, eps, groups: int = 1):
     """x * rsqrt(mean(x^2) + eps) * weight over the last axis, in `groups`
-    equal parts of it, float32."""
+    equal parts of it, float32. The groups' statistics are taken on x as it
+    lies, `(.., C)`: a `(.., groups, C / groups)` view would put the groups
+    where the chip's tiles have the time axis, a pass over x for every reshape."""
     x = x.astype(F32)
+    if groups > 1:
+        width = x.shape[-1] // groups
+        member = (jnp.arange(x.shape[-1])[:, None] // width == jnp.arange(groups)[None, :]).astype(F32)
+        mean = jnp.dot(x * x, member, precision=jax.lax.Precision.HIGHEST) / width       # (.., groups)
+        return x * jnp.dot(jax.lax.rsqrt(mean + eps), member.T, precision=jax.lax.Precision.HIGHEST) * weight
     parts = x.reshape(*x.shape[:-1], groups, x.shape[-1] // groups)
     parts = parts * jax.lax.rsqrt(jnp.mean(parts * parts, axis=-1, keepdims=True) + eps)
     return parts.reshape(x.shape) * weight
@@ -221,50 +239,68 @@ _matrix = nn.initializers.variance_scaling(1.0, "fan_in", "normal")
 _expert_matrix = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=(0,))
 
 
+def running_sum(a):
+    """The running sum over the last axis (a chunk's steps, in the lanes) as a
+    product with a triangle, float32."""
+    q = jnp.arange(a.shape[-1])
+    return jnp.dot(a, (q[:, None] <= q[None, :]).astype(F32), precision=jax.lax.Precision.HIGHEST)
+
+
 def ssd_chunked(x, dt, a_log, b, c, h0, chunk: int, dtype):
     """The Mamba-2 recurrence over a sequence, in chunks.
 
-    x (B, T, H, P), dt (B, T, H) after softplus, a_log (H,), b and c (B, T,
-    G, N), h0 (B, H, P, N), all float32 -> (y (B, T, H, P) without the `D x`
-    term, h_T). Inside a chunk of Q steps the outputs are matmuls: `y_i = sum_
-    {j<=i} (C_i . B_j) exp(cum_i - cum_j) dt_j x_j + C_i . h_in exp(cum_i)`
-    with `cum` the running sum of `-exp(a_log) dt`; the chunks' states follow
-    one from the other by a scan. Padding has dt = 0: decay 1, no input."""
-    B, T, H, P = x.shape
-    G, N = b.shape[2:]
+    x (B, T, H P), dt (B, T, H) after softplus, a_log (H,), b and c (B, T, G
+    N), h0 (B, H, P, N), all float32 and per channel as the projection and
+    the convolution leave them -> (y (B, T, H P) without the `D x` term, h_T).
+    Inside a chunk of Q steps the outputs are matmuls: `y_i = sum_{j<=i} (C_i .
+    B_j) exp(cum_i - cum_j) dt_j x_j + C_i . h_in exp(cum_i)` with `cum` the
+    running sum of `-exp(a_log) dt`; the chunks' states follow one from the
+    other by a scan. Padding has dt = 0: decay 1, no input.
+
+    Where the axes live: a chunk's steps are the MINOR axis of every per-head
+    scalar (`dt`, `cum` and each exp of it, `(B, n, G, R, Q)`) and of `x dt`
+    and y (`(B, n, G, R, P, Q)`), heads are a batch axis of the einsums, and
+    B and C keep their state axis minor (`(B, n, G, Q, N)`): x goes there by
+    one transposition of `(Q, H P)` blocks and y comes back by one. G and R
+    stay apart on the big arrays: merged into H between an elementwise pass
+    and its broadcast operand they leave the broadcast a pass of its own."""
+    B, T, _ = x.shape
+    H, N = dt.shape[-1], h0.shape[-1]
+    G, P = b.shape[-1] // N, x.shape[-1] // H
     R = H // G  # heads a group
     Q = min(chunk, T)
     pad = (-T) % Q
-    if pad:
-        x, dt, b, c = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (v.ndim - 2)) for v in (x, dt, b, c))
     n = (T + pad) // Q
-    x = (x * dt[..., None]).reshape(B, n, Q, G, R, P)
-    cum = jnp.cumsum((-jnp.exp(a_log) * dt).reshape(B, n, Q, G, R), axis=2)
-    b, c = b.reshape(B, n, Q, G, N).astype(dtype), c.reshape(B, n, Q, G, N).astype(dtype)
+
+    def chunks(v):
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0))) if pad else v
+        return v.reshape(B, n, Q, v.shape[-1])
+
+    dt = jnp.swapaxes(chunks(dt), 2, 3)                              # (B, n, H, Q)
+    cum = running_sum(-jnp.exp(a_log)[:, None] * dt).reshape(B, n, G, R, Q)
+    x = jnp.swapaxes(chunks(x), 2, 3).reshape(B, n, G, R, P, Q) * dt.reshape(B, n, G, R, 1, Q)
+    b, c = (jnp.swapaxes(chunks(v.astype(dtype)).reshape(B, n, Q, G, N), 2, 3) for v in (b, c))
     # inside each chunk
     i = jnp.arange(Q)
-    lower = (i[:, None] >= i[None, :])[None, None, :, :, None, None]
-    seg = cum[:, :, :, None] - cum[:, :, None, :]                    # (B, n, Qi, Qj, G, R)
-    decay = jnp.where(lower, jnp.exp(jnp.where(lower, seg, 0.0)), 0.0)
-    cb = jnp.einsum("bnigs,bnjgs->bnijg", c, b, preferred_element_type=F32)
-    y = jnp.einsum("bnijgr,bnjgrp->bnigrp", (cb[..., None] * decay).astype(dtype), x.astype(dtype),
+    lower = i[:, None] >= i[None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, cum[..., :, None] - cum[..., None, :], 0.0)), 0.0)
+    cb = jnp.einsum("bngis,bngjs->bngij", c, b, preferred_element_type=F32)
+    y = jnp.einsum("bngrpj,bngrij->bngrpi", x.astype(dtype), (cb[:, :, :, None] * decay).astype(dtype),
                    preferred_element_type=F32)
     # each chunk's own contribution to the state at its end, then the scan
-    to_end = jnp.exp(cum[:, :, -1:] - cum)                          # (B, n, Q, G, R)
-    own = jnp.einsum("bnjgs,bnjgrp->bngrps", b, (x * to_end[..., None]).astype(dtype),
-                     preferred_element_type=F32)
-    whole = jnp.exp(cum[:, :, -1])                                   # (B, n, G, R)
+    to_end = jnp.exp(cum[..., -1:] - cum)[..., None, :]              # (B, n, G, R, 1, Q)
+    own = jnp.einsum("bngrpj,bngjs->nbgrps", (x * to_end).astype(dtype), b, preferred_element_type=F32)
+    whole = jnp.moveaxis(jnp.exp(cum[..., -1]), 1, 0)                # (n, B, G, R)
 
     def across(h, inp):
         own_n, whole_n = inp
         return whole_n[..., None, None] * h + own_n, h
 
-    h_last, h_in = jax.lax.scan(
-        across, h0.reshape(B, G, R, P, N).astype(F32), (jnp.moveaxis(own, 1, 0), jnp.moveaxis(whole, 1, 0))
-    )
-    y = y + jnp.einsum("bnigs,nbgrps->bnigrp", c, h_in.astype(dtype),
-                       preferred_element_type=F32) * jnp.exp(cum)[..., None]
-    return y.reshape(B, T + pad, H, P)[:, :T], h_last.reshape(B, H, P, N)
+    h_last, h_in = jax.lax.scan(across, h0.reshape(B, G, R, P, N).astype(F32), (own, whole))
+    y = y + jnp.einsum("nbgrps,bngis->bngrpi", h_in.astype(dtype), c,
+                       preferred_element_type=F32) * jnp.exp(cum)[..., None, :]
+    y = jnp.swapaxes(y.reshape(B, n, H * P, Q), 2, 3).reshape(B, n * Q, H * P)
+    return y[:, :T], h_last.reshape(B, H, P, N)
 
 
 class Mamba2Mixer(nn.Module):
@@ -287,21 +323,23 @@ class Mamba2Mixer(nn.Module):
     def _project(self, x):
         s = self.spec
         zxbcdt = _mm(rms_norm(x, self.pre_norm, s.norm_eps), self.in_proj, self.dtype)
-        z, xbc, dt = jnp.split(zxbcdt, [s.d_inner, s.d_inner + s.conv_dim], axis=-1)
+        # static slices, read by the passes that use them (no copies of the parts)
+        cut = s.d_inner + s.conv_dim
+        z, xbc, dt = zxbcdt[..., :s.d_inner], zxbcdt[..., s.d_inner:cut], zxbcdt[..., cut:]
         # time_step_limit (0, inf) of the published config clamps nothing
         return z, xbc, jax.nn.softplus(dt + self.dt_bias)
 
     def _heads(self, xbc):
+        """(.., conv_dim) after the convolution -> x (.., H P), B and C (.., G N): per channel."""
         s = self.spec
-        x, b, c = jnp.split(xbc, [s.d_inner, s.d_inner + s.n_groups * s.ssm_state_size], axis=-1)
-        lead = xbc.shape[:-1]
-        return (x.reshape(*lead, s.mamba_num_heads, s.mamba_head_dim),
-                b.reshape(*lead, s.n_groups, s.ssm_state_size), c.reshape(*lead, s.n_groups, s.ssm_state_size))
+        bc = s.d_inner + s.n_groups * s.ssm_state_size
+        return xbc[..., :s.d_inner], xbc[..., s.d_inner:bc], xbc[..., bc:]
 
-    def _out(self, y, z):
+    def _out(self, y, xs, z):
+        """y and xs (.., H P) -> out_proj of the gated grouped norm of y + D x."""
         s = self.spec
-        y = rms_norm(y.reshape(z.shape) * jax.nn.silu(z), self.norm, s.norm_eps, groups=s.n_groups)
-        return _mm(y, self.out_proj, self.dtype)
+        y = (y + jnp.repeat(self.d_skip, s.mamba_head_dim) * xs) * jax.nn.silu(z)
+        return _mm(rms_norm(y, self.norm, s.norm_eps, groups=s.n_groups), self.out_proj, self.dtype)
 
     def __call__(self, x, ssm, tail):
         """x (B, T, D), ssm (B, H, P, N), tail (B, K-1, conv_dim) -> the same three."""
@@ -311,8 +349,7 @@ class Mamba2Mixer(nn.Module):
         conv = sum(self.conv_weight[k] * seq[:, k:k + T] for k in range(s.conv_kernel)) + self.conv_bias
         xs, b, c = self._heads(jax.nn.silu(conv))
         y, ssm = ssd_chunked(xs, dt, self.a_log, b, c, ssm, s.chunk_size, self.dtype)
-        y = y + self.d_skip[:, None] * xs
-        return x + self._out(y, z), ssm, seq[:, T:]
+        return x + self._out(y, xs, z), ssm, seq[:, T:]
 
     def step(self, x, ssm, tail):
         """One step of the recurrence itself: x (B, D)."""
@@ -320,12 +357,13 @@ class Mamba2Mixer(nn.Module):
         z, xbc, dt = self._project(x)
         seq = jnp.concatenate([tail, xbc[:, None]], axis=1)          # (B, K, conv_dim)
         xs, b, c = self._heads(jax.nn.silu(jnp.sum(self.conv_weight * seq, axis=1) + self.conv_bias))
-        R = s.mamba_num_heads // s.n_groups
-        b, c = jnp.repeat(b, R, axis=1), jnp.repeat(c, R, axis=1)    # (B, H, N)
+        H, R = s.mamba_num_heads, s.mamba_num_heads // s.n_groups
+        heads = xs.reshape(-1, H, s.mamba_head_dim)
+        b, c = (jnp.repeat(v.reshape(-1, s.n_groups, s.ssm_state_size), R, axis=1) for v in (b, c))  # (B, H, N)
         a = jnp.exp(-jnp.exp(self.a_log) * dt)                       # (B, H)
-        ssm = a[..., None, None] * ssm + (dt[..., None] * xs)[..., None] * b[:, :, None, :]
-        y = jnp.einsum("bhpn,bhn->bhp", ssm, c) + self.d_skip[:, None] * xs
-        return x + self._out(y, z), ssm, seq[:, 1:]
+        ssm = a[..., None, None] * ssm + (dt[..., None] * heads)[..., None] * b[:, :, None, :]
+        y = jnp.einsum("bhpn,bhn->bhp", ssm, c).reshape(xs.shape)
+        return x + self._out(y, xs, z), ssm, seq[:, 1:]
 
 
 class Experts(nn.Module):

@@ -152,6 +152,73 @@ def test_mamba_unroll_and_steps_against_the_reference_recurrence(built, ref):
     np.testing.assert_allclose(tail_T, c, rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("groups,width", [(1, 64), (2, 64), (8, 4096), (8, 200), (4, 132)])
+def test_the_grouped_norm_on_x_as_it_lies_is_the_norm_of_the_reshaped_groups(groups, width):
+    """`rms_norm(groups > 1)` takes the groups' statistics without the `(..,
+    groups, width / groups)` view (PR 55): against that view, at the cell's
+    4096 / 8, at widths that are no whole lane tiles, and at groups = 1 (the
+    other callers' path, which keeps the view of one group)."""
+    rng = np.random.default_rng(groups * width)
+    x = jnp.asarray(rng.normal(size=(3, 7, width)) * rng.uniform(0.1, 10.0, size=(3, 7, 1)), jnp.float32)
+    weight = jnp.asarray(rng.normal(size=(width,)), jnp.float32)
+    parts = x.reshape(3, 7, groups, width // groups)
+    want = (parts * jax.lax.rsqrt(jnp.mean(parts * parts, axis=-1, keepdims=True) + 1e-5)).reshape(x.shape) * weight
+    np.testing.assert_allclose(hs.rms_norm(x, weight, 1e-5, groups=groups), want, rtol=2e-6, atol=2e-6)
+    grad = lambda f: jax.grad(lambda v: jnp.sum(jnp.sin(f(v))))(x)
+    np.testing.assert_allclose(
+        grad(lambda v: hs.rms_norm(v, weight, 1e-5, groups=groups)),
+        grad(lambda v: (v.reshape(parts.shape) * jax.lax.rsqrt(jnp.mean(
+            v.reshape(parts.shape) ** 2, axis=-1, keepdims=True) + 1e-5)).reshape(x.shape) * weight),
+        rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("steps", [1, 8, 128])
+def test_the_time_minor_running_sum_is_cumsum(steps):
+    rng = np.random.default_rng(steps)
+    a = jnp.asarray(-rng.uniform(1e-4, 2.0, size=(2, 3, 4, steps)), jnp.float32)   # -exp(A_log) dt: one sign
+    np.testing.assert_allclose(hs.running_sum(a), jnp.cumsum(a, axis=-1), rtol=1e-6, atol=0)
+
+
+def _recurrence(x, dt, a_log, b, c, h0):
+    """The recurrence itself over per-channel arrays, a step at a time."""
+    B, T, _ = x.shape
+    H, (_, _, P, N) = dt.shape[-1], h0.shape
+    G = b.shape[-1] // N
+    heads = lambda v: jnp.repeat(v.reshape(B, G, N), H // G, axis=1)
+
+    def step(h, inp):
+        x_t, dt_t, b_t, c_t = inp
+        h = jnp.exp(-jnp.exp(a_log) * dt_t)[..., None, None] * h \
+            + (dt_t[..., None] * x_t.reshape(B, H, P))[..., None] * heads(b_t)[:, :, None, :]
+        return h, jnp.einsum("bhpn,bhn->bhp", h, heads(c_t)).reshape(B, H * P)
+
+    h, y = jax.lax.scan(step, h0, tuple(jnp.swapaxes(v, 0, 1) for v in (x, dt, b, c)))
+    return jnp.swapaxes(y, 0, 1), h
+
+
+@pytest.mark.parametrize("T,chunk", [(21, 8), (5, 8), (16, 8), (1, 8)],
+                         ids=["not_whole_chunks", "shorter_than_a_chunk", "whole_chunks", "one_step"])
+def test_the_chunked_scan_and_its_gradient_against_the_recurrence(T, chunk):
+    """`ssd_chunked` on per-channel arrays (time-minor inside, PR 55) against
+    the loop over time, forward and `jax.grad`, where the sequence is padded
+    to whole chunks, where it is shorter than one, and where it is neither."""
+    B, H, P, N, G = 3, 4, 16, 16, 2
+    rng = np.random.default_rng(T)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    x, b, c, h0 = normal(B, T, H * P), normal(B, T, G * N), normal(B, T, G * N), normal(B, H, P, N)
+    dt = jnp.asarray(rng.uniform(0.001, 0.5, size=(B, T, H)), jnp.float32)
+    a_log = jnp.log(jnp.asarray(rng.uniform(1.0, 16.0, size=(H,)), jnp.float32))
+    args = (x, dt, a_log, b, c, h0)
+    chunked = lambda *a: hs.ssd_chunked(*a, chunk, jnp.float32)
+    for got, want in zip(chunked(*args), _recurrence(*args)):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    weigh = normal(B, T, H * P), normal(B, H, P, N)
+    scalar = lambda f: lambda *a: sum(jnp.sum(w * o) for w, o in zip(weigh, f(*a)))
+    got, want = (jax.grad(scalar(f), argnums=tuple(range(6)))(*args) for f in (chunked, _recurrence))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5 * float(jnp.max(jnp.abs(w))))
+
+
 @pytest.mark.parametrize("capacity_factor,drops", [(2.0, False), (0.02, True)])
 def test_mixture_against_the_reference_with_and_without_drops(built, ref, capacity_factor, drops):
     cfg = tiny_cfg(capacity_factor=capacity_factor)

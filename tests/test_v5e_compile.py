@@ -351,19 +351,24 @@ def _rehearsal():
     return rehearse
 
 
-_MULTI = {}
+_PROGRAMS = {}
+
+
+def _step_program(config, topo, which):
+    """(cfg, compiled text) of one of a configuration's two step programs at
+    real size, as the chip builds it: compiled once for every test of this
+    file that reads it (under `compiled_kernels`, which each of them asks for)."""
+    if (config, which) not in _PROGRAMS:
+        cfg, programs, _ = _rehearsal().step_programs(config, topo)
+        fn, args = programs[which]
+        _PROGRAMS[config, which] = cfg, fn.lower(*args).compile().as_text()
+    return _PROGRAMS[config, which]
 
 
 def _multi_program(config, topo):
-    """(cfg, compiled text) of a configuration's update-only step program
-    (`jit_multi`; dp4: the shard_map body, 16 rows a chip) at real size, as
-    the chip builds it: compiled once for every test of this file that reads
-    it (under `compiled_kernels`, which each of them asks for)."""
-    if config not in _MULTI:
-        cfg, programs, _ = _rehearsal().step_programs(config, topo)
-        fn, args = programs["multi"]
-        _MULTI[config] = cfg, fn.lower(*args).compile().as_text()
-    return _MULTI[config]
+    """The update-only step program (`jit_multi`; dp4: the shard_map body, 16
+    rows a chip)."""
+    return _step_program(config, topo, "multi")
 
 
 def _top_level(text):
@@ -606,9 +611,8 @@ def test_the_stacks_collect_scans_carry_the_layers_parts_and_no_flat_row(topo, c
     each body (138 MB written twice more at every one of 1,024 env steps: 7.8 %
     of the device by the ledger's op list, PERF.md finding 54). The mechanism
     engages in every chunk or in none, so this text is its tripwire."""
-    cfg, programs, _ = _rehearsal().step_programs("nemotron-twotower-30b-a3b-ep16", topo)
-    fn, args = programs["mega"]
-    comps = _computations(fn.lower(*args).compile().as_text())
+    cfg, text = _step_program("nemotron-twotower-30b-a3b-ep16", topo, "mega")
+    comps = _computations(text)
     scans = [line for lines in comps.values() for line in lines
              if " while(" in line and 'op_name="jit(mega)/jit(r2d2_collect)/while"' in line]
     assert len(scans) == 2, len(scans)
@@ -621,3 +625,36 @@ def test_the_stacks_collect_scans_carry_the_layers_parts_and_no_flat_row(topo, c
         flat = [l.split(", metadata=")[0].strip() for name in _called_from(comps, body) for l in comps[name]
                 if re.search(rf"\[{E},(2152450|2152576)\]", l)]
         assert flat == [], flat[:4]
+
+
+def test_the_mixers_sequence_form_holds_no_array_with_groups_or_heads_in_the_tiles_minor_axes(topo, compiled_kernels):
+    """nemotron-twotower-30b-a3b-ep16 at published widths, the K updates of its
+    collecting step program (`jit_mega` holds the scan over updates that
+    `jit_multi` is, beside the collector; the text is the one the test above
+    compiled, so the file pays no second compile of this cell): under the
+    mixers' op_names (`/ssm_<i>`) nothing has the grouped norm's `(.., 8, 512)`
+    view, and no instruction outside the fusions writes the chunked scan's
+    `(B, n, Q, G, R, P)` operands, their `(B, T', H, P)` padded form or the
+    `(B, n, Q, G, R)` scalars: the axes that are 8 or 64 long are nowhere a
+    big array's minor axis, so no pass exists only to re-tile one. PR 55's
+    parent failed this with 24 `f32[8,581,8,512]` rows, 24 + 18 bare
+    `reshape` / `copy f32[8,5,128,8,8,64]`, 24 `f32[8,640,64,64]` and a hundred
+    of `f32[8,5,128,8,8]` in its traced run (~45 of the mixers' 112.7 ms an
+    update, PERF.md finding 55). What is LEFT with a minor axis of 64 is the
+    projection's 64-wide `dt` slice itself, 1.3 MB, on its way to `(B, n, H,
+    Q)`: pinned by size, not by count. The mechanism is compiled in or it is
+    not, so this text is its tripwire."""
+    cfg, text = _step_program("nemotron-twotower-30b-a3b-ep16", topo, "mega")
+    B, T = cfg.batch_size, cfg.seq_len
+    n = -(-T // 128)
+    mixers = [(dtype, dims, opcode) for _, dtype, dims, opcode, op in _top_level(text)
+              if re.search(r"/ssm_\d+", op) and dims[:1] == (B,)]
+    assert len(mixers) > 300, len(mixers)  # the three layers, four times an update
+    assert f"f32[{B},{T},8,512]" not in text
+    heads_minor = {(B, n, 128, 8, 8, 64), (B, n * 128, 64, 64), (B, n, 128, 8, 8)}
+    assert [m for m in mixers if m[1] in heads_minor and m[2] in ("reshape", "copy")] == []
+    # time is the minor axis of the chunk operands and of the scalars
+    assert any(dims == (B, n, 4096, 128) for _, dims, _ in mixers)
+    narrow = [m for m in mixers if m[0] == "f32" and m[1][-1] in (8, 64) and m[2] != "fusion"]
+    assert all(math.prod(dims) * 4 <= B * n * 128 * 64 * 4 for _, dims, _ in narrow), narrow[:4]
+
