@@ -1,12 +1,28 @@
-"""A core that is a pattern of residual layers: state-space mixers, routed
-expert mixtures held as one chip's share, and attention over the episode.
+"""A core that is a stack of residual blocks: state-space or delta-rule mixers,
+routed expert mixtures held as one chip's share, and attention over the episode.
 
-`recurrent_core="hybrid_stack"` puts a stack of pre-norm residual layers in
-the core slot, `x <- x + mixer(RMSNorm(x))`, one mixer for each letter of
-`core_config["hybrid_override_pattern"]`, between an input projection
-`(latent + A + 1) -> hidden` (it stands where a language model has its token
-embedding) and a final RMSNorm. The widths come from `config.core_config`
-under the names a published `nemotron_h` config gives them (`StackSpec`):
+`recurrent_core="hybrid_stack"` puts a stack of pre-norm residual blocks in
+the core slot, `x <- x + block(norm(x))`, between an input projection `(latent
++ A + 1) -> hidden` (it stands where a language model has its token
+embedding) and a final norm. TWO FAMILIES of published models are read from
+`config.core_config`, each under the names its own `config.json` gives its
+widths; `core_config["model_type"]` says which (`spec_of`, the one place that
+asks for a family by name; absent: `nemotron_h`):
+
+- `nemotron_h` (`StackSpec`): one block for each letter of
+  `hybrid_override_pattern`, `M` a Mamba-2 mixer, `E` a mixture, `*` attention.
+- `qwen3_next` (`Qwen3NextSpec`): a decoder layer is TWO blocks, its mixer then
+  its mixture; layer `i` of `num_hidden_layers` mixes by attention where `(i +
+  1) % full_attention_interval == 0` and by the gated delta rule otherwise. Its
+  norms scale by `1 + weight`.
+
+What `HybridStack` asks of a spec is the same for both: `hidden_size`, `eps`
+and `norm_offset`, `blocks` (the residual blocks in order, `(kind, layer
+index)`), `sizes(kind)` (that block's own sizes, handed to its class: each
+class reads the sizes IT uses, not a union of the families' keys), and what
+`_Stack` makes of those (`segments()`, `state_size`, `capacity()`). A third
+family is a spec class with those, an entry in `FAMILIES`, and a block class in
+`KINDS` for whatever mechanism the four kinds lack. The kinds:
 
 - `M`, a Mamba-2 mixer (Dao & Gu 2024, "Transformers are SSMs"): `[z | xBC |
   dt] = in_proj(u)`; a causal depthwise convolution and silu over `xBC`, whose
@@ -16,26 +32,39 @@ under the names a published `nemotron_h` config gives them (`StackSpec`):
   recurrence in its chunked form (matmuls inside a chunk of `chunk_size`
   steps, a scan over the chunks' states, the sequence padded to whole chunks
   with `dt = 0`, which leaves the state as it is); `step` is the recurrence.
-- `E`, a routed mixture held as a SHARE: sigmoid scores over all
-  `n_routed_experts`, the top `num_experts_per_tok` of score + correction
-  bias, weights normalised and scaled, experts `W_down relu(W_up x)^2`, and a
-  shared expert for every token. This chip holds experts
+- `D`, a Gated DeltaNet mixer (Yang et al. 2024, "Gated Delta Networks"): `[q |
+  k | v | z] = in_proj_qkvz(u)`, `[b | a] = in_proj_ba(u)`; the convolution and
+  silu over `q | k | v`; per value head a MATRIX state updated by the delta
+  rule, `S <- exp(g_t) S; S <- S + k_t (beta_t (v_t - S^T k_t))^T; o_t = S^T
+  q_t`: not diagonal and not a sum of outer products of the inputs, so its
+  chunked form (`delta_rule_chunked`) solves a unit lower-triangular system a
+  chunk and head before it scans over the chunks; `step` is the recurrence.
+- `E`, a routed mixture held as a SHARE: scores over ALL routed experts
+  (sigmoid + correction bias, scaled | a softmax, renormalised), the top k,
+  experts of two matrices (`W_down relu(W_up x)^2`) or three (`W_down (silu(
+  W_gate x) * W_up x)`), and a shared expert for every token (as it is | times
+  the sigmoid of a learned scalar gate). This chip holds experts
   `[first_expert_held, first_expert_held + num_experts_held)`: it routes over
   all of them, computes its own, and leaves out what the others would add (no
   code stands in for the absent chips or their exchange). The device work is
   STATIC: each held expert computes exactly `C = capacity(tokens)` rows, an
   assignment beyond an expert's `C` in flattened `(b, t)` order is dropped
   and counted, and no shape, loop bound or branch depends on the routed load.
-- `*`, grouped-query attention without a positional encoding (position comes
-  from the mixers). The carry holds the keys and values, after projection, of
-  the last `config.max_episode_steps` positions as a ring, and a count;
-  `unroll`'s T queries see the valid part of that memory and their own
-  sequence causally. No episode is longer than the ring, so every position
-  attends to its whole episode and nothing is ever truncated.
+- `*`, grouped-query attention over the episode. The carry holds the keys and
+  values, after projection, of the last `config.max_episode_steps` positions as
+  a ring, and a count; `unroll`'s T queries see the valid part of that memory
+  and their own sequence causally. No episode is longer than the ring, so
+  every position attends to its whole episode and nothing is ever truncated.
+  `nemotron_h` has no positional encoding (position comes from the mixers);
+  `qwen3_next` norms each head's query and key, rotates the first `rotary_dim`
+  dimensions of both to the position the count gives (`count + t`; the ring
+  holds keys already rotated), and gates the heads' outputs by a sigmoid taken
+  from the second half of the query projection. With the three off the layer
+  is the first family's, op for op.
 
 THE CARRY is one flat float32 vector a row, `state_shape(cfg) = (1, S)`
-(models/core.py: the rule's `n = 1`): every `M` layer's state and convolution
-tail, every `*` layer's keys and values, and the count as two numbers below
+(models/core.py: the rule's `n = 1`): every mixer's state and convolution
+tail, every `*` block's keys and values, and the count as two numbers below
 256 (so a bfloat16 store holds it exactly), padded to whole 128-lanes. Zero
 is the episode start. The class splits and joins it; stores, accumulator,
 gather and `batch["hidden"]` see an array like any other. Its statements for
@@ -53,19 +82,22 @@ stored. Only that scan sees the opened form. What the mixtures count in an `unro
 publish it with a readback they already make.
 
 Matmuls run in the compute dtype with float32 accumulation; the residual
-stream, the recurrence, the norms, the softmax and the router are float32.
-Each layer of `unroll` is rematerialised in the backward pass.
+stream, the recurrences (the delta rule's triangular inverse at "highest"),
+the norms, the softmax, the router and the rotary embedding are float32.
+Each block of `unroll` is rematerialised in the backward pass.
 
-WHERE THE AXES LIVE in the mixer's sequence form (the chip's tiles are 8
+WHERE THE AXES LIVE in a mixer's sequence form (the chip's tiles are 8
 sublanes x 128 lanes, so an axis of 8 or 64 in a big array's minor place
 costs a pass to re-tile it, PERF.md finding 55): per-channel arrays stay `(B,
-T, C)` with C a multiple of 128 from `in_proj`'s output to `out_proj`'s input
-(z, xBC, dt and x, B, C are lane-aligned slices of it, and the grouped norm
-takes its groups' statistics by a membership matmul, not by a `(.., 8, 512)`
-view); inside `ssd_chunked` a chunk's 128 steps are the minor axis of every
-per-head scalar and of `x dt` and y, with heads a batch axis of the einsums,
-reached by one transposition in and one out. `step` (one row of 16 a call)
-keeps heads and head_dim as axes: its arrays are a tile or two.
+T, C)` with C a multiple of 128 from the input projection's output to
+`out_proj`'s input (z, xBC, dt and x, B, C, or q, k, v, z, are lane-aligned
+slices of it, and a grouped norm takes its groups' statistics by a membership
+matmul, not by a `(.., 8, 512)` view); inside `ssd_chunked` a chunk's 128
+steps are the minor axis of every per-head scalar and of `x dt` and y, with
+heads a batch axis of the einsums, reached by one transposition in and one
+out; `delta_rule_chunked` keeps its heads' 128 dimensions minor and puts a
+chunk's steps minor on the per-head scalars alone. `step` (one row of 16 a
+call) keeps heads and head_dim as axes: its arrays are a tile or two.
 """
 
 from __future__ import annotations
@@ -88,9 +120,119 @@ COUNTS = ("rows_offered", "rows_dropped", "load_max", "load_mean")
 
 
 @dataclasses.dataclass(frozen=True)
-class StackSpec:
-    """`config.core_config`, checked: the published keys by their published
-    names, and below them what is this repo's own (ARCHITECTURE.md)."""
+class AttentionSizes:
+    """What `EpisodeAttention` uses, whatever the family. The three options
+    are off in `nemotron_h`: no norm on a head's query and key, no rotary
+    embedding, no output gate."""
+
+    hidden_size: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    max_episode_steps: int
+    eps: float
+    norm_offset: float = 0.0      # a norm scales by `norm_offset + weight`
+    qk_norm: bool = False
+    rotary_dim: int = 0
+    rope_theta: float = 0.0
+    output_gate: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class MixtureSizes:
+    """What `ExpertMixture` uses, whatever the family: `softmax` is the
+    router (off: sigmoid + correction bias), `gated` the form of an expert
+    (three matrices, `down(silu(gate x) * up x)`; off: two, `down relu(up
+    x)^2`), `shared_gate` the sigmoid of a learned scalar on the shared expert."""
+
+    hidden_size: int
+    experts: int
+    top_k: int
+    expert_width: int
+    shared_width: int
+    held: int
+    first_held: int
+    capacity_factor: float
+    eps: float
+    norm_offset: float = 0.0
+    scale: float = 1.0
+    softmax: bool = False
+    gated: bool = False
+    shared_gate: bool = False
+
+    def capacity(self, tokens: int) -> int:
+        """Rows each held expert computes for `tokens` tokens: the balanced
+        share times `capacity_factor`, up to whole 128-row tiles."""
+        share = self.capacity_factor * tokens * self.top_k / self.experts
+        return LANES * max(math.ceil(share / LANES), 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaSizes:
+    """What `GatedDeltaNet` uses."""
+
+    hidden_size: int
+    key_heads: int
+    key_dim: int
+    value_heads: int
+    value_dim: int
+    conv_kernel: int
+    eps: float
+    norm_offset: float
+    chunk: int
+
+    @property
+    def conv_dim(self) -> int:
+        return 2 * self.key_heads * self.key_dim + self.value_heads * self.value_dim
+
+
+class _Stack:
+    """What `HybridStack` reads of a family's spec beyond `hidden_size`,
+    `blocks` (the residual blocks in order, `(kind, layer index)`) and
+    `sizes(kind)` (that block's own sizes), made from those two."""
+
+    def capacity(self, tokens: int) -> int:
+        return self.sizes("E").capacity(tokens)
+
+    def segments(self):
+        """[(layer index, name, shape)] of one row's carry, in order."""
+        out = [(i, name, shape) for kind, i in self.blocks
+               for name, shape in zip(STATE_NAMES[kind], KINDS[kind][1].state_shapes(self.sizes(kind)))]
+        return out + [(-1, "count", (2,))]
+
+    @property
+    def state_size(self) -> int:
+        n = sum(math.prod(shape) for _, _, shape in self.segments())
+        return LANES * math.ceil(n / LANES)
+
+
+def _read(cls, cfg, experts: str):
+    """`cfg.core_config` as a spec of `cls`, refused if it has a key `cls`
+    does not name or lacks one `cls` requires; then what every family checks:
+    the width against the config's, and the held experts (default: all of
+    the `experts` the family counts under that key) against the routed ones."""
+    given = dict(cfg.core_config)
+    names = {f.name for f in dataclasses.fields(cls)} - {"max_episode_steps"}
+    required = {f.name for f in dataclasses.fields(cls) if f.default is dataclasses.MISSING}
+    if set(given) - names or required - set(given):
+        raise ValueError(
+            f"core_config: unknown keys {sorted(set(given) - names)}, "
+            f"missing keys {sorted(required - set(given))}"
+        )
+    spec = cls(**given, max_episode_steps=cfg.max_episode_steps)
+    spec = dataclasses.replace(spec, num_experts_held=spec.num_experts_held or getattr(spec, experts))
+    if spec.hidden_size != cfg.hidden_dim:
+        raise ValueError(f"core_config hidden_size {spec.hidden_size} is not hidden_dim {cfg.hidden_dim}")
+    if not 0 <= spec.first_expert_held <= getattr(spec, experts) - spec.num_experts_held:
+        raise ValueError("the held experts lie outside the routed ones")
+    return spec
+
+
+@dataclasses.dataclass(frozen=True)
+class StackSpec(_Stack):
+    """`config.core_config` of a `nemotron_h` stack, checked: the published
+    keys by their published names, and below them what is this repo's own
+    (ARCHITECTURE.md)."""
 
     hidden_size: int
     hybrid_override_pattern: str
@@ -116,29 +258,17 @@ class StackSpec:
     num_experts_held: int = 0
     first_expert_held: int = 0
     capacity_factor: float = 2.0
+    model_type: str = "nemotron_h"
     # no key of core_config: the config's own, the length of the attention's memory
     max_episode_steps: int = 0
 
     @classmethod
     def of(cls, cfg) -> "StackSpec":
-        given = dict(cfg.core_config)
-        names = {f.name for f in dataclasses.fields(cls)} - {"max_episode_steps"}
-        required = {f.name for f in dataclasses.fields(cls) if f.default is dataclasses.MISSING}
-        if set(given) - names or required - set(given):
-            raise ValueError(
-                f"core_config: unknown keys {sorted(set(given) - names)}, "
-                f"missing keys {sorted(required - set(given))}"
-            )
-        spec = cls(**given, max_episode_steps=cfg.max_episode_steps)
-        spec = dataclasses.replace(spec, num_experts_held=spec.num_experts_held or spec.n_routed_experts)
-        if spec.hidden_size != cfg.hidden_dim:
-            raise ValueError(f"core_config hidden_size {spec.hidden_size} is not hidden_dim {cfg.hidden_dim}")
+        spec = _read(cls, cfg, "n_routed_experts")
         if not spec.hybrid_override_pattern or set(spec.hybrid_override_pattern) - set("ME*"):
             raise ValueError(f"pattern {spec.hybrid_override_pattern!r}: letters M, E and *")
         if spec.mamba_num_heads % spec.n_groups or spec.num_attention_heads % spec.num_key_value_heads:
             raise ValueError("heads must divide into their groups")
-        if not 0 <= spec.first_expert_held <= spec.n_routed_experts - spec.num_experts_held:
-            raise ValueError("the held experts lie outside the routed ones")
         return spec
 
     @property
@@ -149,31 +279,121 @@ class StackSpec:
     def conv_dim(self) -> int:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
-    def capacity(self, tokens: int) -> int:
-        """Rows each held expert computes for `tokens` tokens: the balanced
-        share times `capacity_factor`, up to whole 128-row tiles."""
-        share = self.capacity_factor * tokens * self.num_experts_per_tok / self.n_routed_experts
-        return LANES * max(math.ceil(share / LANES), 1)
+    # what the stack asks of a family's spec (`_Stack` makes the rest of these)
 
-    def segments(self):
-        """[(layer index, name, shape)] of one row's carry, in order."""
-        out = []
-        kv = (self.max_episode_steps, self.num_key_value_heads, self.head_dim)
-        for i, kind in enumerate(self.hybrid_override_pattern):
-            if kind == "M":
-                out.append((i, "ssm", (self.mamba_num_heads, self.mamba_head_dim, self.ssm_state_size)))
-                out.append((i, "conv", (self.conv_kernel - 1, self.conv_dim)))
-            elif kind == "*":
-                out += [(i, "keys", kv), (i, "values", kv)]
-        return out + [(-1, "count", (2,))]
+    norm_offset = 0.0  # a norm scales by its weight
 
     @property
-    def state_size(self) -> int:
-        n = sum(math.prod(shape) for _, _, shape in self.segments())
-        return LANES * math.ceil(n / LANES)
+    def eps(self) -> float:
+        return self.norm_eps
+
+    @property
+    def blocks(self):
+        """One residual block for each letter, at its place in the pattern."""
+        return tuple((kind, i) for i, kind in enumerate(self.hybrid_override_pattern))
+
+    def sizes(self, kind: str):
+        if kind == "M":
+            return self  # the mixer is this family's own: its sizes are these keys
+        if kind == "*":
+            return AttentionSizes(self.hidden_size, self.num_attention_heads, self.num_key_value_heads, self.head_dim,
+                                  self.max_episode_steps, self.norm_eps)
+        return MixtureSizes(self.hidden_size, self.n_routed_experts, self.num_experts_per_tok,
+                            self.moe_intermediate_size, self.moe_shared_expert_intermediate_size,
+                            self.num_experts_held, self.first_expert_held, self.capacity_factor, self.norm_eps,
+                            scale=self.routed_scaling_factor)
 
 
-def split_state(spec: StackSpec, flat):
+DELTA_CHUNK = 64  # steps of the delta rule that `GatedDeltaNet` takes as one chunk: the program's own
+
+
+@dataclasses.dataclass(frozen=True)
+class Qwen3NextSpec(_Stack):
+    """`config.core_config` of a `qwen3_next` stack, checked: that family's
+    keys by their published names, and this repo's own three. A decoder layer
+    is two residual blocks, the mixer then the mixture; layer `i` mixes by
+    attention where `(i + 1) % full_attention_interval == 0` and by the gated
+    delta rule otherwise (the family publishes no pattern string)."""
+
+    model_type: str
+    hidden_size: int
+    num_hidden_layers: int
+    full_attention_interval: int
+    linear_num_key_heads: int
+    linear_key_head_dim: int
+    linear_num_value_heads: int
+    linear_value_head_dim: int
+    linear_conv_kernel_dim: int
+    num_attention_heads: int
+    num_key_value_heads: int
+    head_dim: int
+    partial_rotary_factor: float
+    rope_theta: float
+    num_experts: int
+    num_experts_per_tok: int
+    moe_intermediate_size: int
+    shared_expert_intermediate_size: int
+    norm_topk_prob: bool
+    rms_norm_eps: float
+    num_experts_held: int = 0
+    first_expert_held: int = 0
+    capacity_factor: float = 2.0
+    max_episode_steps: int = 0
+
+    @classmethod
+    def of(cls, cfg) -> "Qwen3NextSpec":
+        spec = _read(cls, cfg, "num_experts")
+        if spec.num_hidden_layers < 1 or spec.full_attention_interval < 1:
+            raise ValueError("num_hidden_layers and full_attention_interval: 1 or more")
+        if (spec.linear_num_value_heads % spec.linear_num_key_heads
+                or spec.num_attention_heads % spec.num_key_value_heads):
+            raise ValueError("heads must divide into their groups")
+        if int(spec.head_dim * spec.partial_rotary_factor) % 2:
+            raise ValueError("the rotary part of a head is pairs of dimensions")
+        if spec.norm_topk_prob is not True:
+            raise ValueError("norm_topk_prob: true (the mixture divides the chosen weights by their sum)")
+        return spec
+
+    norm_offset = 1.0  # a norm scales by 1 + weight
+
+    @property
+    def eps(self) -> float:
+        return self.rms_norm_eps
+
+    @property
+    def blocks(self):
+        mixer = lambda i: "*" if (i + 1) % self.full_attention_interval == 0 else "D"
+        return tuple(block for i in range(self.num_hidden_layers) for block in ((mixer(i), i), ("E", i)))
+
+    def sizes(self, kind: str):
+        if kind == "D":
+            return DeltaSizes(self.hidden_size, self.linear_num_key_heads, self.linear_key_head_dim,
+                              self.linear_num_value_heads, self.linear_value_head_dim, self.linear_conv_kernel_dim,
+                              self.rms_norm_eps, self.norm_offset, DELTA_CHUNK)
+        if kind == "*":
+            return AttentionSizes(self.hidden_size, self.num_attention_heads, self.num_key_value_heads, self.head_dim,
+                                  self.max_episode_steps, self.rms_norm_eps, norm_offset=self.norm_offset, qk_norm=True,
+                                  rotary_dim=int(self.head_dim * self.partial_rotary_factor),
+                                  rope_theta=self.rope_theta, output_gate=True)
+        return MixtureSizes(self.hidden_size, self.num_experts, self.num_experts_per_tok, self.moe_intermediate_size,
+                            self.shared_expert_intermediate_size, self.num_experts_held, self.first_expert_held,
+                            self.capacity_factor, self.rms_norm_eps, norm_offset=self.norm_offset, softmax=True,
+                            gated=True, shared_gate=True)
+
+
+FAMILIES = {"nemotron_h": StackSpec, "qwen3_next": Qwen3NextSpec}
+
+
+def spec_of(cfg):
+    """The spec of the family `core_config["model_type"]` names (absent:
+    `nemotron_h`): the one place that asks for a family by name."""
+    family = dict(cfg.core_config).get("model_type", "nemotron_h")
+    if family not in FAMILIES:
+        raise ValueError(f"core_config model_type {family!r}: one of {sorted(FAMILIES)}")
+    return FAMILIES[family].of(cfg)
+
+
+def split_state(spec, flat):
     """(B, S) -> {(layer, name): (B, *shape)} float32."""
     out, at = {}, 0
     for i, name, shape in spec.segments():
@@ -183,7 +403,7 @@ def split_state(spec: StackSpec, flat):
     return out
 
 
-def join_state(spec: StackSpec, parts):
+def join_state(spec, parts):
     flat = jnp.concatenate(
         [parts[(i, name)].reshape(parts[(i, name)].shape[0], -1).astype(F32) for i, name, _ in spec.segments()],
         axis=1,
@@ -220,6 +440,20 @@ def rms_norm(x, weight, eps, groups: int = 1):
     return parts.reshape(x.shape) * weight
 
 
+def _norm_weight(module, name: str, width: int, offset: float):
+    """A norm's scale, `offset + weight` with the weight initialised so that
+    the scale starts at one (`nemotron_h`: the weight itself; `qwen3_next`:
+    `1 + weight` from zero)."""
+    if not offset:
+        return module.param(name, nn.initializers.ones, (width,))
+    return offset + module.param(name, nn.initializers.zeros, (width,))
+
+
+def _sizes(spec, kind: str):
+    """A block's own sizes: a family's spec says them, sizes are themselves."""
+    return spec.sizes(kind) if hasattr(spec, "sizes") else spec
+
+
 def _dt_bias_init(lo, hi, floor):
     def init(key, shape, dtype=F32):
         dt = jnp.exp(jax.random.uniform(key, shape, dtype) * (math.log(hi) - math.log(lo)) + math.log(lo))
@@ -231,6 +465,11 @@ def _dt_bias_init(lo, hi, floor):
 
 def _a_log_init(key, shape, dtype=F32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _a_log_init_from_zero(key, shape, dtype=F32):
+    """log U(0, 16) as `qwen3_next` has it, floored away from log 0."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1e-3, 16.0))
 
 
 # normal with variance 1 / fan-in; not truncated: at these widths the un-jitted
@@ -307,6 +546,10 @@ class Mamba2Mixer(nn.Module):
     spec: StackSpec
     dtype: jnp.dtype
 
+    @staticmethod
+    def state_shapes(s):
+        return (s.mamba_num_heads, s.mamba_head_dim, s.ssm_state_size), (s.conv_kernel - 1, s.conv_dim)
+
     def setup(self):
         s = self.spec
         D, H = s.hidden_size, s.mamba_num_heads
@@ -367,53 +610,73 @@ class Mamba2Mixer(nn.Module):
 
 
 class Experts(nn.Module):
-    """The held experts' two matmuls, batched over the experts."""
+    """The held experts' matmuls, batched over the experts: `down relu(up
+    x)^2`, or gated, `down(silu(gate x) * up x)`."""
 
-    spec: StackSpec
+    spec: MixtureSizes
     dtype: jnp.dtype
 
     @nn.compact
     def __call__(self, rows):
         s = self.spec
-        up = self.param("up", _expert_matrix, (s.num_experts_held, s.hidden_size, s.moe_intermediate_size))
-        down = self.param("down", _expert_matrix, (s.num_experts_held, s.moe_intermediate_size, s.hidden_size))
-        h = jnp.einsum("ecd,edf->ecf", rows.astype(self.dtype), up.astype(self.dtype), preferred_element_type=F32)
-        h = jnp.square(jax.nn.relu(h))
+        into = lambda name: self.param(name, _expert_matrix, (s.held, s.hidden_size, s.expert_width)).astype(self.dtype)
+        rows = rows.astype(self.dtype)
+        if s.gated:
+            gate = jnp.einsum("ecd,edf->ecf", rows, into("gate"), preferred_element_type=F32)
+        up = into("up")
+        down = self.param("down", _expert_matrix, (s.held, s.expert_width, s.hidden_size))
+        h = jnp.einsum("ecd,edf->ecf", rows, up, preferred_element_type=F32)
+        h = jax.nn.silu(gate) * h if s.gated else jnp.square(jax.nn.relu(h))
         return jnp.einsum("ecf,efd->ecd", h.astype(self.dtype), down.astype(self.dtype), preferred_element_type=F32)
 
 
 class ExpertMixture(nn.Module):
-    spec: StackSpec
+    spec: MixtureSizes
     dtype: jnp.dtype
 
+    @staticmethod
+    def state_shapes(s):
+        return ()
+
     def setup(self):
-        s = self.spec
+        s = self.sizes = _sizes(self.spec, "E")
         D = s.hidden_size
-        self.pre_norm = self.param("pre_norm", nn.initializers.ones, (D,))
-        self.router = self.param("router", _matrix, (D, s.n_routed_experts))
-        self.correction_bias = self.param("e_score_correction_bias", nn.initializers.zeros, (s.n_routed_experts,))
+        self.pre_norm = _norm_weight(self, "pre_norm", D, s.norm_offset)
+        self.router = self.param("router", _matrix, (D, s.experts))
+        if not s.softmax:
+            self.correction_bias = self.param("e_score_correction_bias", nn.initializers.zeros, (s.experts,))
         self.experts = Experts(s, self.dtype, name="experts")
-        self.shared_up = self.param("shared_up", _matrix, (D, s.moe_shared_expert_intermediate_size))
-        self.shared_down = self.param("shared_down", _matrix, (s.moe_shared_expert_intermediate_size, D))
+        if s.gated:
+            self.shared_gate = self.param("shared_gate", _matrix, (D, s.shared_width))
+        self.shared_up = self.param("shared_up", _matrix, (D, s.shared_width))
+        self.shared_down = self.param("shared_down", _matrix, (s.shared_width, D))
+        if s.shared_gate:
+            self.shared_expert_gate = self.param("shared_expert_gate", _matrix, (D, 1))
 
     def scores(self, x):
-        """x (N, D) normalised tokens -> (sigmoid scores over ALL routed
-        experts (N, E) in float32, the top `num_experts_per_tok` of score +
-        correction bias (N, K))."""
-        scores = jax.nn.sigmoid(jnp.dot(x, self.router, precision=jax.lax.Precision.HIGHEST))
-        return scores, jax.lax.top_k(scores + self.correction_bias, self.spec.num_experts_per_tok)[1]
+        """x (N, D) normalised tokens -> (scores over ALL routed experts (N,
+        E) in float32, the top `top_k` (N, K)): a softmax and its top, or
+        sigmoids and the top of score + correction bias."""
+        logits = jnp.dot(x, self.router, precision=jax.lax.Precision.HIGHEST)
+        if self.sizes.softmax:
+            scores = jax.nn.softmax(logits, axis=-1)
+            return scores, jax.lax.top_k(scores, self.sizes.top_k)[1]
+        scores = jax.nn.sigmoid(logits)
+        return scores, jax.lax.top_k(scores + self.correction_bias, self.sizes.top_k)[1]
 
     def routed(self, x):
         """x (N, D) normalised tokens in (b, t) order -> (what the held
         experts add (N, D), counts (4,) in COUNTS' order)."""
-        s = self.spec
+        s = self.sizes
         N, D = x.shape
-        E, K, Eh, C = s.n_routed_experts, s.num_experts_per_tok, s.num_experts_held, s.capacity(N)
+        E, K, Eh, C = s.experts, s.top_k, s.held, s.capacity(N)
         scores, chosen = self.scores(x)                                                # (N, E), (N, K)
         weight = jnp.take_along_axis(scores, chosen, axis=1)
-        weight = weight / jnp.sum(weight, axis=1, keepdims=True) * s.routed_scaling_factor
+        weight = weight / jnp.sum(weight, axis=1, keepdims=True)
+        if s.scale != 1.0:
+            weight = weight * s.scale
         # a token's place in each held expert's queue, in (b, t) order
-        local = chosen - s.first_expert_held
+        local = chosen - s.first_held
         mine = local[..., None] == jnp.arange(Eh)                                      # (N, K, Eh)
         queue = jnp.cumsum(jnp.any(mine, axis=1).astype(jnp.int32), axis=0) - 1        # (N, Eh)
         place = jnp.sum(jnp.where(mine, queue[:, None, :], 0), axis=-1)                # (N, K)
@@ -432,12 +695,16 @@ class ExpertMixture(nn.Module):
         return y, jax.lax.stop_gradient(counts)
 
     def shared(self, x):
-        h = jnp.square(jax.nn.relu(_mm(x, self.shared_up, self.dtype)))
-        return _mm(h, self.shared_down, self.dtype)
+        h = _mm(x, self.shared_up, self.dtype)
+        h = jax.nn.silu(_mm(x, self.shared_gate, self.dtype)) * h if self.sizes.gated else jnp.square(jax.nn.relu(h))
+        y = _mm(h, self.shared_down, self.dtype)
+        if self.sizes.shared_gate:
+            y = y * jax.nn.sigmoid(jnp.dot(x, self.shared_expert_gate, precision=jax.lax.Precision.HIGHEST))
+        return y
 
     def __call__(self, x):
         """x (..., D) -> (x + held experts' part + shared expert, counts)."""
-        flat = rms_norm(x, self.pre_norm, self.spec.norm_eps).reshape(-1, x.shape[-1])
+        flat = rms_norm(x, self.pre_norm, self.sizes.eps).reshape(-1, x.shape[-1])
         routed, counts = self.routed(flat)
         return x + (routed + self.shared(flat)).reshape(x.shape), counts
 
@@ -453,30 +720,60 @@ def _ring_write(memory, new, count):
     return jnp.where((first < T).reshape(*first.shape, *tail), taken, memory)
 
 
+def rotary(x, positions, rotary_dim: int, theta: float):
+    """x (B, T, ..., Dh) with its first `rotary_dim` dimensions rotated to
+    `positions` (B, T), rotate-half convention (dimension i pairs with i +
+    rotary_dim / 2, `inv_freq_i = theta^(-2 i / rotary_dim)`), float32."""
+    half = rotary_dim // 2
+    angle = positions.astype(F32)[..., None] * theta ** (-jnp.arange(half, dtype=F32) / half)   # (B, T, half)
+    angle = angle.reshape(*angle.shape[:2], *(1,) * (x.ndim - 3), half)
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x1, x2, rest = x[..., :half], x[..., half:rotary_dim], x[..., rotary_dim:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
 class EpisodeAttention(nn.Module):
-    spec: StackSpec
+    spec: AttentionSizes
     dtype: jnp.dtype
 
+    @staticmethod
+    def state_shapes(s):
+        return ((s.max_episode_steps, s.num_key_value_heads, s.head_dim),) * 2
+
     def setup(self):
-        s = self.spec
+        s = self.sizes = _sizes(self.spec, "*")
         D = s.hidden_size
-        self.pre_norm = self.param("pre_norm", nn.initializers.ones, (D,))
-        self.q_proj = self.param("q_proj", _matrix, (D, s.num_attention_heads * s.head_dim))
+        self.pre_norm = _norm_weight(self, "pre_norm", D, s.norm_offset)
+        # with an output gate a head's projection is [query | gate]
+        self.q_proj = self.param("q_proj", _matrix, (D, s.num_attention_heads * s.head_dim * (2 if s.output_gate else 1)))
         self.k_proj = self.param("k_proj", _matrix, (D, s.num_key_value_heads * s.head_dim))
         self.v_proj = self.param("v_proj", _matrix, (D, s.num_key_value_heads * s.head_dim))
+        if s.qk_norm:
+            self.q_norm = _norm_weight(self, "q_norm", s.head_dim, s.norm_offset)
+            self.k_norm = _norm_weight(self, "k_norm", s.head_dim, s.norm_offset)
         self.o_proj = self.param("o_proj", _matrix, (s.num_attention_heads * s.head_dim, D))
 
     def __call__(self, x, keys, values, count):
         """x (B, T, D); keys, values (B, W, KV, Dh) the ring; count (B,) int
-        positions seen so far -> (x', keys', values')."""
-        s = self.spec
+        positions seen so far -> (x', keys', values'). Where the family has
+        them: a norm on each head's query and key, then the rotary embedding
+        at positions `count + t` (so the ring holds keys already rotated),
+        and a sigmoid gate on the heads' outputs."""
+        s = self.sizes
         B, T, _ = x.shape
         KV, Dh, W = s.num_key_value_heads, s.head_dim, s.max_episode_steps
         R = s.num_attention_heads // KV
-        h = rms_norm(x, self.pre_norm, s.norm_eps)
-        q = _mm(h, self.q_proj, self.dtype).reshape(B, T, KV, R, Dh)
+        h = rms_norm(x, self.pre_norm, s.eps)
+        q = _mm(h, self.q_proj, self.dtype).reshape(B, T, KV, R, -1)
         k = _mm(h, self.k_proj, self.dtype).reshape(B, T, KV, Dh)
         v = _mm(h, self.v_proj, self.dtype).reshape(B, T, KV, Dh)
+        if s.output_gate:
+            q, gate = q[..., :Dh], q[..., Dh:].reshape(B, T, KV * R * Dh)
+        if s.qk_norm:
+            q, k = rms_norm(q, self.q_norm, s.eps), rms_norm(k, self.k_norm, s.eps)
+        if s.rotary_dim:
+            positions = count[:, None] + jnp.arange(T)
+            q, k = (rotary(a, positions, s.rotary_dim, s.rope_theta) for a in (q, k))
         all_k = jnp.concatenate([keys, k], axis=1).astype(self.dtype)             # (B, W + T, KV, Dh)
         all_v = jnp.concatenate([values, v], axis=1).astype(self.dtype)
         remembered = jnp.arange(W)[None, :] < jnp.minimum(count, W)[:, None]      # (B, W)
@@ -497,11 +794,183 @@ class EpisodeAttention(nn.Module):
 
         out = jax.lax.map(attend, (jnp.moveaxis(blocks, 1, 0), jnp.arange(blocks.shape[1]) * Q))
         out = jnp.moveaxis(out, 0, 1).reshape(B, T + pad, KV * R * Dh)[:, :T]
+        if s.output_gate:
+            out = out * jax.nn.sigmoid(gate)
         return x + _mm(out, self.o_proj, self.dtype), _ring_write(keys, k, count), _ring_write(values, v, count)
 
 
-KINDS = {"M": ("ssm", Mamba2Mixer), "E": ("moe", ExpertMixture), "*": ("attention", EpisodeAttention)}
-STATE_NAMES = {"M": ("ssm", "conv"), "E": (), "*": ("keys", "values")}
+def unit_lower_solve(L, rhs):
+    """`(I + L)^-1 rhs` for L (.., Q, Q) strictly lower triangular and rhs
+    (.., Q, m), float32: forward substitution (`solve_triangular`:
+    differentiable, static in shape)."""
+    return jax.scipy.linalg.solve_triangular(jnp.eye(L.shape[-1], dtype=F32) + L, rhs, lower=True, unit_diagonal=True)
+
+
+def delta_rule_chunked(q, k, v, g, beta, s0, chunk: int, dtype):
+    """The gated delta rule over a sequence, in chunks.
+
+    q and k (B, T, Hk dk), q scaled and both L2-normalised per head; v (B, T,
+    Hv dv); g <= 0 and beta in (0, 1) (B, T, Hv); s0 (B, Hv, dk, dv): per
+    value head, served by key head `h // (Hv / Hk)`, the recurrence `S <-
+    exp(g_t) S; S <- S + k_t (beta_t (v_t - S^T k_t))^T; o_t = S^T q_t` ->
+    (o (B, T, Hv dv), S_T), float32.
+
+    The state is a matrix and its update is not a sum of outer products of
+    the inputs (each step's `v_t - S^T k_t` reads the state the steps before
+    it wrote), so a chunk of Q steps first solves for what its steps write
+    GIVEN the state it starts from: with `G` the running sum of g inside the
+    chunk and `L_ij = beta_i (k_i . k_j) exp(G_i - G_j)` for j < i, the
+    written rows are `v' = T (beta v) - T (beta k exp(G)) S` with `T = (I +
+    L)^-1`, a unit lower-triangular system solved by forward substitution in
+    float32 (`solve_triangular`: differentiable, static). NOT by the series `(I
+    - L)(I + L^2)(I + L^4) ...`, exact in exact arithmetic after log2(Q)
+    squarings and as fast on the chip: an agent's consecutive frames give keys
+    with `k_i . k_j` near 1, L is then near `beta` times all ones, the series'
+    terms reach binomials of Q (1e11 and more at Q = 64) while their sum stays
+    of order one, and float32 loses it all: a training run's loss went to NaN
+    (PERF.md finding 56). Then, chunk after
+    chunk (a scan), `o = (q exp(G)) S + tril(q k^T exp(G_i - G_j)) v'` and `S
+    <- exp(G_Q) S + (k exp(G_Q - G))^T v'`. Every exponent is <= 0. Padding
+    has g = 0 and beta = 0: decay 1, nothing written.
+
+    Where the axes live (PERF.md finding 55): q, k, v go from `(B, T, H d)`
+    to `(B, n, H, Q, d)` by one transposition of `(Q, H)` blocks (d = 128 is
+    the lane width and stays minor) and o comes back by one; heads are a
+    batch axis of the einsums; a chunk's steps are the minor axis of every
+    per-head scalar (`g`, `G`, `beta`: `(B, n, Hk, R, Q)`)."""
+    B, T, _ = q.shape
+    Hv, dk, dv = s0.shape[1:]
+    Hk = q.shape[-1] // dk
+    R = Hv // Hk  # value heads a key head
+    Q = min(chunk, T)
+    pad = (-T) % Q
+    n = (T + pad) // Q
+    highest = jax.lax.Precision.HIGHEST
+
+    def chunks(a, *heads):
+        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0))) if pad else a
+        return a.reshape(B, n, Q, *heads, -1)
+
+    q, k = (jnp.moveaxis(chunks(a, Hk), 2, 3) for a in (q, k))             # (B, n, Hk, Q, dk)
+    v = jnp.moveaxis(chunks(v, Hk, R), 2, 4)                               # (B, n, Hk, R, Q, dv)
+    g, beta = (jnp.moveaxis(chunks(a, Hk), 2, 4) for a in (g, beta))       # (B, n, Hk, R, Q)
+    G = running_sum(g)
+    i = jnp.arange(Q)
+    lower, strict = i[:, None] >= i[None, :], i[:, None] > i[None, :]
+    decay = jnp.where(lower, jnp.exp(jnp.where(lower, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+    # what the chunk's steps write, given the state it starts from
+    kk = jnp.einsum("bnkid,bnkjd->bnkij", k, k, precision=highest)
+    L = jnp.where(strict, beta[..., :, None] * kk[:, :, :, None] * decay, 0.0)   # (B, n, Hk, R, Q, Q)
+    k_r = k[:, :, :, None]                                                       # a key head's R value heads
+    solved = unit_lower_solve(L, jnp.concatenate([(beta * jnp.exp(G))[..., None] * k_r, beta[..., None] * v], axis=-1))
+    w, u = solved[..., :dk], solved[..., dk:]
+    # what each step reads of its own chunk, and what the chunk leaves in the state
+    qk = jnp.einsum("bnkid,bnkjd->bnkij", q.astype(dtype), k.astype(dtype), preferred_element_type=F32)
+    inside = (qk[:, :, :, None] * decay).astype(dtype)
+    q_in = (jnp.exp(G)[..., None] * q[:, :, :, None]).astype(dtype)              # reads the incoming state
+    k_out = (jnp.exp(G[..., -1:] - G)[..., None] * k_r).astype(dtype)            # writes the outgoing one
+    whole = jnp.exp(G[..., -1])                                                  # (B, n, Hk, R)
+
+    def across(S, chunk_n):
+        w_n, u_n, q_n, k_n, inside_n, whole_n = chunk_n
+        S_low = S.astype(dtype)
+        written = u_n - jnp.einsum("bkrid,bkrde->bkrie", w_n.astype(dtype), S_low, preferred_element_type=F32)
+        o = (jnp.einsum("bkrid,bkrde->bkrie", q_n, S_low, preferred_element_type=F32)
+             + jnp.einsum("bkrij,bkrje->bkrie", inside_n, written.astype(dtype), preferred_element_type=F32))
+        S = whole_n[..., None, None] * S + jnp.einsum("bkrid,bkrie->bkrde", k_n, written.astype(dtype),
+                                                      preferred_element_type=F32)
+        return S, o
+
+    per_chunk = tuple(jnp.moveaxis(a, 1, 0) for a in (w, u, q_in, k_out, inside, whole))
+    S, o = jax.lax.scan(across, s0.reshape(B, Hk, R, dk, dv).astype(F32), per_chunk)   # o (n, B, Hk, R, Q, dv)
+    o = jnp.transpose(o, (1, 0, 4, 2, 3, 5)).reshape(B, n * Q, Hv * dv)
+    return o[:, :T], S.reshape(B, Hv, dk, dv)
+
+
+class GatedDeltaNet(nn.Module):
+    """The `qwen3_next` linear-attention mixer (Yang et al. 2024, "Gated Delta
+    Networks"): `[q | k | v | z] = in_proj_qkvz(u)`, `[b | a] = in_proj_ba(u)`;
+    a causal depthwise convolution and silu over `q | k | v`, whose last
+    `conv_kernel - 1` inputs are state; q and k L2-normalised per head, q
+    scaled; `beta = sigmoid(b)`, `g = -exp(A_log) softplus(a + dt_bias)`; the
+    delta rule; `out_proj(norm(o) silu(z))` with the norm over each head."""
+
+    spec: DeltaSizes
+    dtype: jnp.dtype
+
+    @staticmethod
+    def state_shapes(s):
+        return (s.value_heads, s.key_dim, s.value_dim), (s.conv_kernel - 1, s.conv_dim)
+
+    def setup(self):
+        s = self.spec
+        D, Hv = s.hidden_size, s.value_heads
+        self.pre_norm = _norm_weight(self, "pre_norm", D, s.norm_offset)
+        self.in_proj_qkvz = self.param("in_proj_qkvz", _matrix, (D, s.conv_dim + Hv * s.value_dim))
+        self.in_proj_ba = self.param("in_proj_ba", _matrix, (D, 2 * Hv))
+        self.conv_weight = self.param("conv_weight", _matrix, (s.conv_kernel, s.conv_dim))
+        self.a_log = self.param("A_log", _a_log_init_from_zero, (Hv,))
+        self.dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,))
+        self.norm = self.param("norm", nn.initializers.ones, (s.value_dim,))
+        self.out_proj = self.param("out_proj", _matrix, (Hv * s.value_dim, D))
+
+    def _project(self, x):
+        """x (.., D) -> qkv (.., conv_dim) before the convolution, z, beta, g."""
+        s = self.spec
+        h = rms_norm(x, self.pre_norm, s.eps)
+        qkvz, ba = _mm(h, self.in_proj_qkvz, self.dtype), _mm(h, self.in_proj_ba, self.dtype)
+        b, a = ba[..., :s.value_heads], ba[..., s.value_heads:]
+        g = -jnp.exp(self.a_log) * jax.nn.softplus(a + self.dt_bias)
+        return qkvz[..., :s.conv_dim], qkvz[..., s.conv_dim:], jax.nn.sigmoid(b), g
+
+    def _heads(self, qkv):
+        """(.., conv_dim) after the convolution -> q scaled, k, v: per channel,
+        q and k L2-normalised per head (`x rsqrt(sum(x^2) + 1e-6)`, which is a
+        grouped RMS norm with the weight `dk^-1/2`)."""
+        s = self.spec
+        cut = s.key_heads * s.key_dim
+        unit = lambda a, scale: rms_norm(a, scale * s.key_dim ** -0.5, 1e-6 / s.key_dim, groups=s.key_heads)
+        return unit(qkv[..., :cut], s.key_dim ** -0.5), unit(qkv[..., cut:2 * cut], 1.0), qkv[..., 2 * cut:]
+
+    def _out(self, o, z):
+        s = self.spec
+        o = rms_norm(o, jnp.tile(self.norm, s.value_heads), s.eps, groups=s.value_heads) * jax.nn.silu(z)
+        return _mm(o, self.out_proj, self.dtype)
+
+    def __call__(self, x, delta, tail):
+        """x (B, T, D), delta (B, Hv, dk, dv), tail (B, K-1, conv_dim) -> the same three."""
+        s, T = self.spec, x.shape[1]
+        qkv, z, beta, g = self._project(x)
+        seq = jnp.concatenate([tail, qkv], axis=1)
+        conv = sum(self.conv_weight[j] * seq[:, j:j + T] for j in range(s.conv_kernel))
+        q, k, v = self._heads(jax.nn.silu(conv))
+        o, delta = self.recurrence(q, k, v, g, beta, delta)
+        return x + self._out(o, z), delta, seq[:, T:]
+
+    def recurrence(self, q, k, v, g, beta, delta):
+        """The chunked delta rule under a name of its own (`gdn_<i>.recurrence`)."""
+        return delta_rule_chunked(q, k, v, g, beta, delta, self.spec.chunk, self.dtype)
+
+    def step(self, x, delta, tail):
+        """One step of the recurrence itself: x (B, D). Sums, not matmuls: the
+        state is float32 and a row's is 2 MB."""
+        s = self.spec
+        qkv, z, beta, g = self._project(x)
+        seq = jnp.concatenate([tail, qkv[:, None]], axis=1)                      # (B, K, conv_dim)
+        q, k, v = self._heads(jax.nn.silu(jnp.sum(self.conv_weight * seq, axis=1)))
+        R = s.value_heads // s.key_heads
+        q, k = (jnp.repeat(a.reshape(-1, s.key_heads, s.key_dim), R, axis=1) for a in (q, k))   # (B, Hv, dk)
+        v = v.reshape(-1, s.value_heads, s.value_dim)
+        delta = jnp.exp(g)[..., None, None] * delta
+        written = beta[..., None] * (v - jnp.sum(delta * k[..., None], axis=2))
+        delta = delta + k[..., None] * written[:, :, None, :]
+        o = jnp.sum(delta * q[..., None], axis=2).reshape(x.shape[0], -1)
+        return x + self._out(o, z), delta, seq[:, 1:]
+
+
+KINDS = {"M": ("ssm", Mamba2Mixer), "D": ("gdn", GatedDeltaNet), "E": ("moe", ExpertMixture),
+         "*": ("attention", EpisodeAttention)}
+STATE_NAMES = {"M": ("ssm", "conv"), "D": ("delta", "conv"), "E": (), "*": ("keys", "values")}
 
 
 def _layer(spec, dtype, kind: str, index: int):
@@ -516,9 +985,9 @@ def _run_layer(kind: str, layer, x, state, count):
     carry -> (x', state', counts)."""
     step = x.ndim == 2
     nothing = jnp.zeros((len(COUNTS),), F32)
-    if kind == "M":
-        x, ssm, tail = (layer.step if step else layer)(x, *state)
-        return x, (ssm, tail), nothing
+    if kind in "MD":
+        x, matrix, tail = (layer.step if step else layer)(x, *state)
+        return x, (matrix, tail), nothing
     if kind == "E":
         x, counts = layer(x)
         return x, (), counts
@@ -527,7 +996,7 @@ def _run_layer(kind: str, layer, x, state, count):
 
 
 class HybridStack(nn.Module):
-    spec: StackSpec
+    spec: _Stack
     in_dim: int
     dtype: jnp.dtype = F32
 
@@ -537,19 +1006,19 @@ class HybridStack(nn.Module):
 
     @staticmethod
     def state_shape(cfg):
-        return (1, StackSpec.of(cfg).state_size)
+        return (1, spec_of(cfg).state_size)
 
     @classmethod
     def from_config(cls, cfg, in_dim: int, tp_size: int = 1) -> "HybridStack":
         if tp_size > 1:
             raise ValueError("the hybrid_stack core has no tensor-parallel form")
-        return cls(StackSpec.of(cfg), in_dim=in_dim, dtype=jnp.dtype(cfg.resolved_compute_dtype))
+        return cls(spec_of(cfg), in_dim=in_dim, dtype=jnp.dtype(cfg.resolved_compute_dtype))
 
     def setup(self):
         s = self.spec
         self.embed = self.param("in_proj", _matrix, (self.in_dim, s.hidden_size))
-        self.layers = [_layer(s, self.dtype, kind, i) for i, kind in enumerate(s.hybrid_override_pattern)]
-        self.final_norm = self.param("final_norm", nn.initializers.ones, (s.hidden_size,))
+        self.layers = [_layer(s.sizes(kind), self.dtype, kind, i) for kind, i in s.blocks]
+        self.final_norm = _norm_weight(self, "final_norm", s.hidden_size, s.norm_offset)
 
     def _layers(self, x, parts):
         """x (B, T, in_dim), or (B, in_dim) for one step; `parts` the carry as
@@ -559,13 +1028,13 @@ class HybridStack(nn.Module):
         count = _count_of(parts[(-1, "count")])
         counts = jnp.zeros((len(COUNTS),), F32)
         x = _mm(x, self.embed, self.dtype)
-        for i, (kind, layer) in enumerate(zip(s.hybrid_override_pattern, self.layers)):
+        for (kind, i), layer in zip(s.blocks, self.layers):
             state = tuple(parts[(i, name)] for name in STATE_NAMES[kind])
             x, state, c = _run_layer(kind, layer, x, state, count)
             parts.update({(i, name): value for name, value in zip(STATE_NAMES[kind], state)})
             counts = counts + c
         parts[(-1, "count")] = _count_pair(count + (1 if x.ndim == 2 else x.shape[1]))
-        return rms_norm(x, self.final_norm, s.norm_eps), parts, counts
+        return rms_norm(x, self.final_norm, s.eps), parts, counts
 
     def _run(self, x, carry):
         """`_layers` on the stored form: split, run, join."""

@@ -368,11 +368,18 @@ def test_forward_loss_and_gradient_against_the_reference(built, ref):
     assert counted["moe.load_max_over_mean"] >= 1.0 and counted["moe.dropped_share"] == 0.0
 
 
-def test_the_update_has_no_loop_whose_bound_is_data_and_no_dynamic_shape(built):
-    """Static work whatever the routed load: scans with static lengths, no
-    `while`, and a compiled text without a bounded-dynamic dimension."""
+@pytest.mark.parametrize("family", ["nemotron_h", "qwen3_next"])
+def test_the_update_has_no_loop_whose_bound_is_data_and_no_dynamic_shape(built, family):
+    """Static work whatever the routed load, in either family: scans with
+    static lengths, no `while`, and a compiled text without a bounded-dynamic
+    dimension."""
     cfg, net, params = built
     b = _batch(cfg, 2)
+    if family == "qwen3_next":
+        import test_qwen3_next_stack as second
+
+        cfg = second.tiny_qwen_cfg()
+        (net, params), b = init_params(jax.random.PRNGKey(0), cfg), second._batch(cfg, 2)
     fn = lambda p: jnp.sum(net.apply(p, b["obs"], b["last_action"], b["last_reward"], b["hidden"], b["burn_in"],
                                      b["learning"], b["forward"])[0])
     jaxpr = str(jax.make_jaxpr(jax.grad(fn))(params))
