@@ -111,6 +111,7 @@ import jax
 import jax.numpy as jnp
 
 from r2d2_tpu.models.core import Carry
+from r2d2_tpu.ops import pallas_delta
 
 F32 = jnp.float32
 LANES = 128
@@ -822,7 +823,11 @@ def delta_rule_chunked(q, k, v, g, beta, s0, chunk: int, dtype):
     chunk and `L_ij = beta_i (k_i . k_j) exp(G_i - G_j)` for j < i, the
     written rows are `v' = T (beta v) - T (beta k exp(G)) S` with `T = (I +
     L)^-1`, a unit lower-triangular system solved by forward substitution in
-    float32 (`solve_triangular`: differentiable, static). NOT by the series `(I
+    float32: where the triangles fill whole lanes (`pallas_delta.kernel_fits`)
+    by the kernel of ops/pallas_delta.py, a triangle a lane and the rows in
+    VMEM, then one matmul at "highest" (PERF.md finding 57: XLA's own
+    inversion was a quarter of the qwen3-next cell's step); otherwise by
+    `unit_lower_solve` (`solve_triangular`). NOT by the series `(I
     - L)(I + L^2)(I + L^4) ...`, exact in exact arithmetic after log2(Q)
     squarings and as fast on the chip: an agent's consecutive frames give keys
     with `k_i . k_j` near 1, L is then near `beta` times all ones, the series'
@@ -862,7 +867,8 @@ def delta_rule_chunked(q, k, v, g, beta, s0, chunk: int, dtype):
     kk = jnp.einsum("bnkid,bnkjd->bnkij", k, k, precision=highest)
     L = jnp.where(strict, beta[..., :, None] * kk[:, :, :, None] * decay, 0.0)   # (B, n, Hk, R, Q, Q)
     k_r = k[:, :, :, None]                                                       # a key head's R value heads
-    solved = unit_lower_solve(L, jnp.concatenate([(beta * jnp.exp(G))[..., None] * k_r, beta[..., None] * v], axis=-1))
+    solve = pallas_delta.unit_lower_solve if pallas_delta.kernel_fits(B * n * Hv, Q) else unit_lower_solve
+    solved = solve(L, jnp.concatenate([(beta * jnp.exp(G))[..., None] * k_r, beta[..., None] * v], axis=-1))
     w, u = solved[..., :dk], solved[..., dk:]
     # what each step reads of its own chunk, and what the chunk leaves in the state
     qk = jnp.einsum("bnkid,bnkjd->bnkij", q.astype(dtype), k.astype(dtype), preferred_element_type=F32)

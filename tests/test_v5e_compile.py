@@ -658,3 +658,65 @@ def test_the_mixers_sequence_form_holds_no_array_with_groups_or_heads_in_the_til
     narrow = [m for m in mixers if m[0] == "f32" and m[1][-1] in (8, 64) and m[2] != "fusion"]
     assert all(math.prod(dims) * 4 <= B * n * 128 * 64 * 4 for _, dims, _ in narrow), narrow[:4]
 
+
+def _gdn_kernel_pattern():
+    with open(os.path.join(ROOT, "benchmark", "layers", "kernels.gdn_solve_ms_per_update.json")) as fh:
+        return re.compile(json.load(fh)["pattern"])
+
+
+def _mosaic_calls(text):
+    return [re.sub(r"^ROOT ", "", l.strip()) for l in text.splitlines() if 'custom_call_target="tpu_custom_call"' in l]
+
+
+def _xlas_triangular_solves(text):
+    """The instructions XLA's `solve_triangular` leaves in a compiled text: the
+    `InvertDiagBlocksLowerTriangular` custom call itself, and whatever carries
+    the primitive's name in its op_name (the matmuls of its expansion)."""
+    return [l.strip()[:160] for l in text.splitlines()
+            if "InvertDiagBlocksLowerTriangular" in l or re.search(r'op_name="[^"]*triangular_solve', l)]
+
+
+def test_the_delta_rules_chunk_solve_compiles_at_the_cells_shape_named_after_its_wrapper(one_chip, compiled_kernels):
+    """ops/pallas_delta.py at qwen3-next-80b-a3b-ep32's own shape (B 8, n 10,
+    Hk 16, R 2: 2,560 triangles of Q = 64; 256 right-hand columns): the solve
+    forward + backward holds ONE Mosaic custom call, the inverse, named after
+    its jitted wrapper, which `kernels.gdn_solve_ms_per_update`'s own pattern
+    finds; the backward pass is matmuls on the saved inverse and launches no
+    kernel; and nothing of XLA's triangular solve is left."""
+    from r2d2_tpu.ops import pallas_delta
+
+    lead, Q = (8, 10, 16, 2), 64
+    assert pallas_delta.kernel_fits(math.prod(lead), Q)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    loss = lambda L, rhs: jnp.sum(jnp.square(pallas_delta.unit_lower_solve(L, rhs)))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(sds(*lead, Q, Q), sds(*lead, Q, 256)).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert len(calls) == 1 and _gdn_kernel_pattern().search(calls[0]), calls
+    assert re.sub(r"^%|\.\d+$", "", calls[0].split(" = ")[0]) == "_gdn_inverse_call"
+    assert f"f32[{Q},{Q},{math.prod(lead)}]" in calls[0]            # a triangle a lane: nothing padded
+    assert _xlas_triangular_solves(text) == []
+
+
+def test_the_cells_delta_layer_holds_no_triangular_solve_of_xlas(one_chip, compiled_kernels):
+    """One Gated DeltaNet layer of qwen3-next-80b-a3b-ep32 at published widths
+    and the cell's B x T, forward + recompute + backward as the step programs
+    run it: its chunk solves are the kernel's calls under the recurrence's
+    scope, and the compiled text has no `InvertDiagBlocksLowerTriangular`
+    (12.9 ms a call on the chip, nine an update: PERF.md finding 57) and no
+    `triangular_solve`."""
+    from r2d2_tpu.models import hybrid_stack as hs
+
+    cfg = _cell_config("qwen3-next-80b-a3b-ep32")
+    sizes = hs.spec_of(cfg).sizes("D")
+    B, T = cfg.batch_size, cfg.seq_len
+    assert (B, T, sizes.chunk, sizes.key_heads, sizes.value_heads) == (8, 581, 64, 16, 32)
+    layer = hs._layer(sizes, jnp.dtype(cfg.resolved_compute_dtype), "D", 1)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    x, (delta, tail) = sds(B, T, sizes.hidden_size), (sds(B, *s) for s in hs.GatedDeltaNet.state_shapes(sizes))
+    params = jax.tree.map(lambda a: sds(*a.shape), jax.eval_shape(layer.init, jax.random.PRNGKey(0), x, delta, tail))
+    loss = lambda p, x, delta, tail: jnp.sum(jnp.square(layer.apply(p, x, delta, tail)[0]))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(params, x, delta, tail).compile().as_text()
+    calls = _mosaic_calls(text)
+    assert calls and all(_gdn_kernel_pattern().search(l) for l in calls), calls
+    assert all(re.search(r'op_name="[^"]*gdn_1\.recurrence', l) for l in calls)
+    assert _xlas_triangular_solves(text) == []
