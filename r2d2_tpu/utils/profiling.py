@@ -29,10 +29,10 @@ rates printed every 10 s (worker.py:126,135). Here:
   runs an executable without the names (PERF.md section 3). XLA inlines the
   call, so instructions and their times do not change.
 - `register_program(name, jitted)` wraps a step program: the first call notes
-  the abstract signature and its seconds; `program_scopes(name)` lowers and
-  compiles from that signature ONLY WHEN ASKED (the benchmark's reader, after
-  the window; the executable the run built serves it, 0.1 s on the chip) and
-  returns {instruction: op_name}.
+  the abstract signature and its seconds. ONLY WHEN ASKED (the benchmark's
+  readers, after the window) it is lowered and compiled from that signature,
+  once: `program_scopes(name)` is {instruction: op_name} of that text, and
+  `program_heirs(name)` an owner for the instructions that have no op_name.
 - `SPANS` is the one table of names; a name outside it is refused.
 - `start_trace(dir)` / `stop_trace()` start jax's profiler with the Python
   tracer OFF (it hooks every call of every thread: 8,000 -> 2,500 req/s
@@ -50,7 +50,7 @@ import gc
 import re
 import threading
 import time
-from typing import Callable, Dict, Iterator, Optional
+from typing import Callable, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 import jax
 
@@ -354,6 +354,10 @@ class _Program:
         _counts["setup.compile_s"] = compile_seconds()
         return out
 
+    # the executable's text once a reader has asked (`program_text`). Down here, and no line of `__init__`: a line
+    # added above would move `__call__`, a frame of the first call's trace like `scoped`'s `inner` (finding 51.1)
+    text: Optional[str] = None
+
 
 def _abstract(x):
     """ShapeDtypeStruct of one argument; the sharding only where the array is
@@ -380,17 +384,31 @@ def registered_programs() -> list:
     return [n for n, p in _programs.items() if p.signature is not None]
 
 
-def program_scopes(name: str) -> Dict[str, str]:
-    """{HLO instruction name: op_name} of the executable of program `name`,
-    lowered and compiled from the signature of its first call. Costs a trace,
-    a lowering and, where the run's executable is no longer at hand, a cache
-    load or a compile: for a reader after the measured window, never for the
-    program itself."""
+def program_text(name: str) -> str:
+    """The text of the executable of program `name`, lowered and compiled from
+    the signature of its first call and kept on the program after the first
+    ask (the fifth cell's `mega` is 90 MB: `program_scopes` and
+    `program_heirs` read ONE text). Costs a trace, a lowering and, where the
+    run's executable is no longer at hand, a cache load or a compile: for a
+    reader after the measured window, never for the program itself."""
     prog = _programs[name]
     if prog.signature is None:
         raise ValueError(f"program {name!r} has not been called yet")
-    text = prog.jitted.lower(*prog.signature).compile().as_text()
-    return parse_op_names(text)
+    if prog.text is None:
+        prog.text = prog.jitted.lower(*prog.signature).compile().as_text()
+    return prog.text
+
+
+def program_scopes(name: str) -> Dict[str, str]:
+    """{HLO instruction name: op_name} of the executable of program `name`."""
+    return parse_op_names(program_text(name))
+
+
+def program_heirs(name: str) -> Dict[str, Tuple[str, str]]:
+    """{HLO instruction name: (op_name, how)} for the instructions of the
+    executable of program `name` that have NO op_name of their own: who owns
+    their device time, by the rules of `parse_heirs`."""
+    return parse_heirs(program_text(name))
 
 
 def parse_op_names(hlo_text: str) -> Dict[str, str]:
@@ -400,6 +418,177 @@ def parse_op_names(hlo_text: str) -> Dict[str, str]:
         if m:
             out[m.group(1)] = m.group(2)
     return out
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+) = ")
+_OPCODE = re.compile(r"\s*([a-z][\w\-]*)\(")
+_BRACKET = re.compile(r"[()]")
+_OPERAND = re.compile(r"%?([\w.\-]+)\s*(?:,|$)")
+_CALLED = {"fusion": re.compile(r"\bcalls=%?([\w.\-]+)"), "while": re.compile(r"\bbody=%?([\w.\-]+)")}
+_INDEX = re.compile(r"\bindex=(\d+)")
+
+WAITS_FOR, FUSED, FEEDS = "waits_for", "fused", "feeds"
+PLUMBING = ("while", "tuple", "get-tuple-element", "parameter")  # they hand values on: named or not, a walk goes through
+
+
+class Instruction(NamedTuple):
+    name: str
+    opcode: str
+    shape: str            # the result's, with its layout: `bf16[516,128]{1,0:T(8,128)(2,1)S(1)}`, or a tuple of such
+    operands: List[str]   # the words of the operand list that may be names (the caller keeps those it knows)
+    root: bool
+    calls: Optional[str]  # the computation a `fusion` calls, the body of a `while`
+    index: int            # of a `get-tuple-element`, else -1
+
+
+def _closing(line: str, start: int) -> int:
+    """Index just past the bracket that closes the `(` at `line[start]`."""
+    depth = 0
+    for m in _BRACKET.finditer(line, start):
+        depth += 1 if m.group() == "(" else -1
+        if depth == 0:
+            return m.end()
+    return len(line)
+
+
+def parse_instructions(hlo_text: str) -> Dict[str, List[Instruction]]:
+    """{computation: its instructions in text order} of a compiled module's
+    text. In a scheduled module (`is_scheduled=true`, what a TPU executable
+    prints) text order is schedule order."""
+    out: Dict[str, List[Instruction]] = {}
+    body: Optional[List[Instruction]] = None
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c:
+                body = out[c.group(1)] = []
+            continue
+        if body is None:
+            continue
+        # the result's shape: a tuple in brackets (with tiles `T(8,128)` inside), else one word
+        shape_end = _closing(line, m.end()) if line.startswith("(", m.end()) else line.find(" ", m.end())
+        op = _OPCODE.match(line, shape_end) if shape_end >= 0 else None
+        if op is None:
+            continue
+        opcode = op.group(1)
+        end = _closing(line, op.end() - 1)
+        called = _CALLED[opcode].search(line, end) if opcode in _CALLED else None
+        index = _INDEX.search(line, end) if opcode == "get-tuple-element" else None
+        body.append(Instruction(m.group(2), opcode, line[m.end():shape_end], _OPERAND.findall(line, op.end(), end - 1),
+                                bool(m.group(1)), called.group(1) if called else None,
+                                int(index.group(1)) if index else -1))
+    return out
+
+
+def top_level(comps: Dict[str, List[Instruction]]) -> Iterator[Instruction]:
+    """The instructions outside the fusions' bodies: what the device runs as
+    events of its own, and what a program writes to memory."""
+    fused = {i.calls for body in comps.values() for i in body if i.opcode == "fusion"}
+    for comp, body in comps.items():
+        if comp not in fused:
+            yield from body
+
+
+def parse_heirs(hlo_text: str) -> Dict[str, Tuple[str, str]]:
+    """{instruction: (op_name, how)} for the instructions of a compiled
+    module's text that carry NO op_name, each from a named instruction beside
+    it. Three rules, by what the unnamed instruction is:
+
+    - `how = "fused"`: a `fusion`. The op_name of the ROOT of the computation
+      it calls, else of the root's nearest named producer inside that
+      computation (a mask packer's root is an unnamed `reduce` over the
+      model's own `gt`). A fusion whose computation names nothing falls to
+      the third rule.
+    - `how = "waits_for"`: the `-done` of an asynchronous pair (`copy-done`,
+      `slice-done`, `all-gather-done`, ...), what memory-space assignment puts
+      in for a prefetch: the name of its nearest consumer. The wait belongs to
+      whoever needed the operand.
+    - `how = "feeds"`: anything else (`copy`, `convert`, `bitcast`, a
+      `-start`, ...): the name of its nearest consumer, else of its nearest
+      producer. A `-done` that nobody consumes but its loop's carry (a write
+      back) takes its producer's name this way too.
+
+    "The name" of a neighbour is its own op_name or, for an unnamed fusion,
+    what the first rule gave it; a neighbour that has neither is walked
+    THROUGH (an unnamed `bitcast` or `ConcatBitcast` in between), and so is
+    every `tuple`, `get-tuple-element`, `parameter` and `while`, named or not
+    (`PLUMBING`): they hand values on, the elements of a scan's result all
+    carry the scan's name and an argument its path. "Nearest" is fewest steps,
+    then schedule order for consumers and operand order for producers. A
+    `while` is walked through element by element: what feeds element k of its
+    operand tuple is consumed by the body's `get-tuple-element` k, and element
+    k of its result is produced by operand k of the body's root (a weight
+    prefetched before the scan waits for the layer that reads it inside). A
+    name is taken as it is, whether a bucket wants it or not. An instruction
+    that none of this names is absent from the map, and instructions inside
+    fused computations, which are no device events, get no entry."""
+    named = parse_op_names(hlo_text)
+    comps = parse_instructions(hlo_text)
+
+    def nearest(start: str, step: Dict[str, List[str]], name_of: Dict[str, str]) -> Optional[str]:
+        seen, level = {start}, [start]
+        while level:
+            following = []
+            for at in level:
+                for nxt in step.get(at, ()):
+                    if nxt in name_of:
+                        return name_of[nxt]
+                    if nxt not in seen:
+                        seen.add(nxt)
+                        following.append(nxt)
+            level = following
+        return None
+
+    # who produces and who consumes what, inside each computation
+    producers: Dict[str, List[str]] = {}
+    consumers: Dict[str, List[str]] = {}
+    by_name: Dict[str, Instruction] = {}
+    for body in comps.values():
+        here = {i.name for i in body}
+        by_name.update((i.name, i) for i in body)
+        for i in body:
+            producers[i.name] = [o for o in dict.fromkeys(i.operands) if o in here]
+            if i.opcode != "while":
+                for o in producers[i.name]:
+                    consumers.setdefault(o, []).append(i.name)
+    for body in comps.values():
+        for w in (i for i in body if i.opcode == "while"):
+            inside = comps.get(w.calls or "", [])
+            operand = by_name.get(w.operands[0]) if w.operands else None
+            root = next((j for j in inside if j.root), None)
+            if root is None or operand is None or operand.opcode != "tuple":
+                continue
+            parameter = next((j.name for j in inside if j.opcode == "parameter"), None)
+            for j in inside:  # into the body: element k of the operand tuple is the body's get-tuple-element k
+                if j.opcode == "get-tuple-element" and j.operands[:1] == [parameter] and j.index < len(operand.operands):
+                    consumers.setdefault(operand.operands[j.index], []).append(j.name)
+                    producers[j.name] = [operand.operands[j.index]]
+            for j in body:    # out of it: element k of the result is operand k of the body's root
+                if j.opcode == "get-tuple-element" and j.operands[:1] == [w.name] and j.index < len(root.operands):
+                    consumers.setdefault(root.operands[j.index], []).append(j.name)
+                    producers[j.name] = [root.operands[j.index]]
+
+    heirs: Dict[str, Tuple[str, str]] = {}
+    for f in (i for body in comps.values() for i in body if i.opcode == "fusion" and i.name not in named):
+        root = next((j.name for j in comps.get(f.calls or "", []) if j.root), None)
+        got = root and (named.get(root) or nearest(root, producers, named))
+        if got:
+            heirs[f.name] = (got, FUSED)
+    name_of = {k: v for k, v in named.items() if k in by_name and by_name[k].opcode not in PLUMBING}
+    name_of.update({k: v[0] for k, v in heirs.items()})
+    for i in top_level(comps):
+        if i.name in named or i.name in heirs:
+            continue
+        got = nearest(i.name, consumers, name_of)
+        if got:
+            heirs[i.name] = (got, WAITS_FOR if i.opcode.endswith("-done") else FEEDS)
+        else:
+            got = nearest(i.name, producers, name_of)
+            if got:
+                heirs[i.name] = (got, FEEDS)
+    return heirs
 
 
 _RELAYOUT = re.compile(r"^\s*(?:ROOT\s+)?(%?[\w.\-]+ = ([a-z]+\d*)\[([\d,]*)\]\S* (?:copy|transpose|reshape)\([^)]*\))")

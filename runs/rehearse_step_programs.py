@@ -19,8 +19,13 @@ wherever it sits; must be empty), the compiler's memory count (it refuses what
 does not fit 15.75 GB; `temp_gb` is slab and batch only, `alias_gb` the donated
 arguments the output reuses: the whole store in `mega`), and how many
 instructions carry each device scope of utils/profiling.SPANS and land in each
-bucket of benchmark/trace_scopes.json. A renamed scope recompiles the step
-programs cold on the chip (~200 s, nature): settle names here.
+bucket of benchmark/trace_scopes.json, and how many MB of arrays it writes into
+the fast memory (`S(1)` in the layout) under each bucket, by the instruction's
+own op_name or its heir's (`s1_mb_by_owner`; with `--against <dir>`, another
+tree's `--hlo-dir`, that tree's beside it: whenever the producer or a consumer
+of a large array changes, compare before calling the chip). A renamed scope
+recompiles the step programs cold on the chip (~200 s, nature): settle names
+here.
 
 PR 22 found the whole-store copy and the 20 GB refusal this way; PR 23 settled
 its scopes this way (same instruction count as the parent, 9,508 / 4,274); PR
@@ -30,6 +35,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import re
 import sys
@@ -105,13 +111,19 @@ def step_programs(config: str, topo, buffer_capacity=None):
     return cfg, programs, obs_store_bytes
 
 
+def _ordered_buckets() -> list:
+    """[(bucket, compiled regex)] of benchmark/trace_scopes.json, in the reader's own order."""
+    from benchmark.readers import trace_scope
+
+    return [(name, re.compile(rx)) for name, rx in trace_scope.load_scopes(os.path.join(ROOT, "benchmark"))["buckets"]]
+
+
 def instructions_in_buckets(text: str) -> dict:
     """{bucket of benchmark/trace_scopes.json: named instructions of a
     compiled program's text that it claims}, by the reader's own order."""
-    from benchmark.readers import trace_scope
     from r2d2_tpu.utils import profiling
 
-    buckets = [(name, re.compile(rx)) for name, rx in trace_scope.load_scopes(os.path.join(ROOT, "benchmark"))["buckets"]]
+    buckets = _ordered_buckets()
     in_bucket = {b: 0 for b, _ in buckets}
     for op in profiling.parse_op_names(text).values():
         hit = next((b for b, rx in buckets if rx.search(op)), None)
@@ -120,12 +132,43 @@ def instructions_in_buckets(text: str) -> dict:
     return in_bucket
 
 
+_ARRAY = re.compile(r"([a-z]+\d*)\[([\d,]*)\]\{([^}]*)\}")
+
+
+def s1_bytes_by_owner(text: str) -> dict:
+    """{bucket of benchmark/trace_scopes.json, or "unowned": bytes} of the
+    arrays a compiled program writes into the chip's fast memory: every array
+    with `S(1)` in its layout in the result of an instruction outside the
+    fusions' bodies, under the bucket that wants the instruction's op_name or,
+    where it has none, its heir's (profiling.parse_heirs: a prefetch's
+    `copy-done` is its consumer's). What hands a value on without writing it
+    (`bitcast`, `get-tuple-element`, `tuple`, `parameter`, `while`, a `-start`,
+    whose array its `-done` carries) is left out. The count PRs 49, 50 and 55
+    made by hand before calling the chip (ROADMAP D9)."""
+    from r2d2_tpu.utils import profiling
+
+    buckets = _ordered_buckets()
+    owner = {**profiling.parse_op_names(text), **{i: op for i, (op, _) in profiling.parse_heirs(text).items()}}
+    out = {**{b: 0 for b, _ in buckets}, "unowned": 0}
+    for i in profiling.top_level(profiling.parse_instructions(text)):
+        if i.opcode in (*profiling.PLUMBING, "bitcast") or i.opcode.endswith("-start"):
+            continue
+        size = sum(profiling._DTYPE_BYTES.get(dtype, 0) * math.prod(int(d) for d in dims.split(",") if d)
+                   for dtype, dims, layout in _ARRAY.findall(i.shape) if "S(1)" in layout)
+        if size:
+            op = owner.get(i.name, "")
+            out[next((b for b, rx in buckets if rx.search(op)), "unowned")] += size
+    return out
+
+
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     p.add_argument("config", help="a name under benchmark/configs/")
     p.add_argument("--hlo-dir", default=None, help="write each program's as_text() here")
     p.add_argument("--buffer-capacity", type=int, default=None,
                    help="compile at another replay capacity than the configuration's (what would fit)")
+    p.add_argument("--against", default=None,
+                   help="a directory another tree's --hlo-dir wrote: its S(1) bytes by owner are printed beside this tree's")
     args = p.parse_args(argv)
 
     import jax
@@ -171,7 +214,11 @@ def main(argv=None) -> int:
             "with_op_name": len(op_names),
             "in_scope": {s: sum(f"jit({s})" in v for v in op_names.values()) for s in device_scopes},
             "in_bucket": instructions_in_buckets(text),
+            "s1_mb_by_owner": {k: round(v / 1e6, 2) for k, v in s1_bytes_by_owner(text).items()},
         }
+        if args.against:
+            with open(os.path.join(args.against, f"{args.config}.{name}.hlo")) as fh:
+                row["s1_mb_by_owner_against"] = {k: round(v / 1e6, 2) for k, v in s1_bytes_by_owner(fh.read()).items()}
         print(json.dumps(row), flush=True)
     return 0
 

@@ -379,3 +379,142 @@ def test_relayouts_at_least_finds_store_sized_passes_only(line, found):
     if found:  # the instruction up to its operands, without the attributes after them
         body = line.strip().removeprefix("ROOT ")
         assert len(got) == 1 and body.startswith(got[0]) and got[0].endswith(")") and "metadata" not in got[0]
+
+
+# ------------------------------------------------ owners for what has no op_name
+
+_FIXTURES = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures")
+_GATHER = "jit(multi)/jit(r2d2_update)/while/body/closed_call/jit(r2d2_gather)/gather"
+_SELECT = "jit(multi)/jit(r2d2_update)/while/body/closed_call/jit(r2d2_optimizer)/jit(_where)/select_n"
+_ENC = "jit(multi)/jit(r2d2_update)/while/body/closed_call/jvp(R2D2Network)/R2D2Network.unroll/R2D2Network._core_input/enc/"
+_ENC_T = _ENC.replace("jvp(R2D2Network)", "transpose(jvp(R2D2Network))")
+
+
+@pytest.fixture(scope="module")
+def v5e_excerpt():
+    """Cut from `runs/rehearse_step_programs.py nature-lstm512 --hlo-dir`'s
+    `multi` (scheduled, for the described v5e): the instructions the cases
+    below name with their neighbours, the scan's `while` with its operand
+    tuple, body and condition whole lines, `backend_config` cut off all lines
+    but two."""
+    with open(os.path.join(_FIXTURES, "v5e_nature_multi_excerpt.hlo")) as fh:
+        return fh.read()
+
+
+@pytest.mark.parametrize("instruction, heir", [
+    # a prefetch into the fast memory: the wait is its consumer's
+    ("copy-done.110", ("jit(multi)/jit(r2d2_update)/while/body/closed_call/jvp(jit(r2d2_loss))/reduce_max", "waits_for")),
+    # a weight slice inside the scan's body, joined by an unnamed ConcatBitcast before conv2 reads it
+    ("slice-done.19", (_ENC + "Conv_1/conv_general_dilated", "waits_for")),
+    ("custom-call.63", (_ENC + "Conv_1/conv_general_dilated", "feeds")),
+    # reached through an unnamed bitcast
+    ("copy-done.2", (_ENC_T + "Conv_0/conv_general_dilated", "waits_for")),
+    # before the scan, consumed inside it: through the operand tuple and the body's get-tuple-element
+    ("copy-done.10", (_GATHER, "waits_for")),
+    ("slice-done.130", (_GATHER, "waits_for")),
+    # an unnamed convert of the hidden store, named by its consumer (inside the scan too)
+    ("convert.159", (_GATHER, "feeds")),
+    # the ReLU mask packer: the fused computation's root is an unnamed reduce over the encoder's own `gt`
+    ("fusion.661", (_ENC + "gt", "fused")),
+    # written back to the loop's carry, nobody consumes it here: its producer's
+    ("copy-done.82", (_SELECT, "feeds")),
+    # after the scan: element k of the result is operand k of the body's root
+    ("copy-done.7", (_SELECT, "feeds")),
+    # a prefetch for the NEXT iteration's gather, carried: no rule follows a loop's carry
+    ("copy-done.11", None),
+    # a reducer's own add: no neighbour has a name
+    ("add.2259", None),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_an_instruction_without_op_name_gets_its_owner_from_the_text(v5e_excerpt, instruction, heir):
+    assert instruction not in profiling.parse_op_names(v5e_excerpt)
+    assert f"%{instruction} = " in v5e_excerpt
+    assert profiling.parse_heirs(v5e_excerpt).get(instruction) == heir
+
+
+def test_parse_op_names_reads_what_it_read_and_heirs_name_only_the_rest(v5e_excerpt):
+    import json
+
+    with open(os.path.join(_FIXTURES, "v5e_nature_multi_excerpt.op_names.json")) as fh:
+        parents = json.load(fh)  # the parent commit's parse_op_names on the same text
+    named = profiling.parse_op_names(v5e_excerpt)
+    assert named == parents and len(named) == 29
+    heirs = profiling.parse_heirs(v5e_excerpt)
+    assert heirs and not set(heirs) & set(named)
+    assert {how for _, how in heirs.values()} == {"waits_for", "fused", "feeds"}
+    # whatever name the scan's own `while` and its results carry, a walk goes through them
+    assert named["while.178"].endswith("jit(r2d2_update)/while")
+    assert not any(op == named["while.178"] for op, _ in heirs.values())
+
+
+def test_a_fusion_takes_its_roots_name_before_any_other_inside():
+    text = "\n".join([
+        "HloModule m, is_scheduled=true", "",
+        "%fused_computation.1 (p: f32[8]) -> f32[8] {",
+        "  %p = f32[8]{0} parameter(0)",
+        '  %mul.1 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(f)/first"}',
+        '  ROOT %add.1 = f32[8]{0} add(%mul.1, %p), metadata={op_name="jit(f)/root"}',
+        "}", "",
+        "%fused_computation.2 (q: f32[8]) -> f32[8] {",
+        "  %q = f32[8]{0} parameter(0)",
+        "  ROOT %neg.1 = f32[8]{0} negate(%q)",
+        "}", "",
+        "ENTRY %main (a: f32[8]) -> f32[8] {",
+        "  %a = f32[8]{0} parameter(0)",
+        "  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1",
+        "  %fusion.2 = f32[8]{0} fusion(%fusion.1), kind=kLoop, calls=%fused_computation.2",
+        '  ROOT %copy.1 = f32[8]{0} copy(%fusion.2), metadata={op_name="jit(f)/out"}',
+        "}", ""])
+    heirs = profiling.parse_heirs(text)
+    assert heirs["fusion.1"] == ("jit(f)/root", "fused")
+    assert heirs["fusion.2"] == ("jit(f)/out", "feeds")  # its computation names nothing: the third rule
+    assert heirs["a"] == ("jit(f)/root", "feeds") and set(heirs) == {"a", "fusion.1", "fusion.2"}
+
+
+def test_program_heirs_shares_one_compiled_text_with_program_scopes(monkeypatch):
+    from r2d2_tpu.utils.compilation_cache import compile_cache_stats
+
+    enc = scoped(lambda x, w: jnp.tanh(x @ w), "r2d2_gather")
+    prog = register_program("test_heirs", jax.jit(lambda x, w: enc(x, w).sum() + 1.0))
+    with pytest.raises(ValueError, match="not been called"):
+        profiling.program_heirs("test_heirs")
+    prog(jnp.ones((4, 8)), jnp.ones((8, 8)))
+    entry = profiling._programs["test_heirs"]
+    assert entry.text is None  # nothing is lowered before a reader asks
+
+    class Counting:
+        def __init__(self, jitted):
+            self.jitted, self.lowered = jitted, 0
+
+        def lower(self, *args):
+            self.lowered += 1
+            return self.jitted.lower(*args)
+
+    monkeypatch.setattr(entry, "jitted", Counting(entry.jitted))
+    scopes = program_scopes("test_heirs")
+    asked = compile_cache_stats()
+    heirs = profiling.program_heirs("test_heirs")
+    assert program_scopes("test_heirs") == scopes and entry.jitted.lowered == 1
+    assert compile_cache_stats() == asked  # the second reader compiled and loaded nothing
+    assert scopes == profiling.parse_op_names(entry.text) and heirs == profiling.parse_heirs(entry.text)
+    assert not set(heirs) & set(scopes) and all(how in ("waits_for", "fused", "feeds") for _, how in heirs.values())
+
+
+def test_the_rehearsal_counts_fast_memory_bytes_under_the_owners_bucket(v5e_excerpt):
+    """ROADMAP D9's count on the saved text: bytes of the arrays outside the
+    fusions' bodies whose layout carries `S(1)`, by the bucket of the
+    instruction's own op_name or of its heir's."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "rehearse_step_programs", os.path.join(os.path.dirname(_FIXTURES), os.pardir, "runs", "rehearse_step_programs.py"))
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+    got = rehearse.s1_bytes_by_owner(v5e_excerpt)
+    assert list(got) == ["collect", "slab_write", "gather", "optimizer", "heads_loss", "encoder", "core", "unowned"]
+    # the next iteration's prefetch that no rule names, `copy-done.11 s32[1280,10]{0,1:T(8,128)S(1)}`, and nothing else
+    assert got["unowned"] == 1280 * 10 * 4
+    # conv2's four weight slices `slice-done.19..22 u8[2560,1768]{..S(1)}` are the encoder's through their heir,
+    # the hidden store's `convert.159 bf16[1280,10,2,512]`, which stays in HBM, is nobody's
+    assert got["encoder"] == 139289728 and got["encoder"] >= 4 * 2560 * 1768
+    assert (got["gather"], got["optimizer"], got["heads_loss"]) == (4811776, 8390656, 21504)
+    assert got["collect"] == got["slab_write"] == got["core"] == 0

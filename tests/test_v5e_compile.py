@@ -720,3 +720,43 @@ def test_the_cells_delta_layer_holds_no_triangular_solve_of_xlas(one_chip, compi
     assert calls and all(_gdn_kernel_pattern().search(l) for l in calls), calls
     assert all(re.search(r'op_name="[^"]*gdn_1\.recurrence', l) for l in calls)
     assert _xlas_triangular_solves(text) == []
+
+
+def test_every_prefetch_wait_of_natures_update_program_has_an_owner(topo, compiled_kernels):
+    """nature-lstm512's `multi` as the chip builds it: memory-space assignment
+    puts in some 280 asynchronous copies and slices, none with an op_name, 3 %
+    of the chip's time (`device.prefetch_wait_share`). `profiling.parse_heirs`
+    gives the `-done` of each an owner (its consumer; its producer for a write
+    back), the scan's `while` walked through. What it leaves is a prefetch
+    for the NEXT iteration, carried: its source is an element of the body's
+    parameter and the body's root its only consumer."""
+    from r2d2_tpu.utils import profiling
+
+    _, text = _multi_program("nature-lstm512", topo)
+    named, heirs = profiling.parse_op_names(text), profiling.parse_heirs(text)
+    comps = profiling.parse_instructions(text)
+    scheduled = {i.name for i in profiling.top_level(comps)}
+    dones, unexplained = [], []
+    for body in comps.values():
+        if body[0].name not in scheduled:
+            continue
+        by_name = {i.name: i for i in body}
+        for i in body:
+            if not i.opcode.endswith("-done") or i.name in named:
+                continue
+            dones.append(i.name)
+            if i.name in heirs:
+                continue
+            users = [j for j in body if i.name in j.operands]
+            source = by_name.get((by_name[i.operands[0]].operands or [""])[0])
+            carried = (all(j.root and j.opcode == "tuple" for j in users) and source is not None
+                       and source.opcode == "get-tuple-element" and by_name[source.operands[0]].opcode == "parameter")
+            if not carried:
+                unexplained.append(f"{i.name} {i.shape} (consumers {[j.name for j in users]})")
+    assert {"copy-done", "slice-done"} <= {re.sub(r"\.\d+$", "", d) for d in dones} and len(dones) > 200
+    assert not unexplained, f"`-done` instructions without an heir that are no carried prefetch: {unexplained}"
+    owned = [heirs[d] for d in dones if d in heirs]
+    assert len(owned) >= len(dones) - 2
+    assert sum(how == "waits_for" for _, how in owned) > 0.6 * len(dones)
+    # an heir is a name of the program's own work (a device scope, a module path), never an argument's
+    assert all("jit(r2d2_" in op or "R2D2Network." in op for op, _ in owned)
