@@ -50,6 +50,9 @@ family is a spec class with those, an entry in `FAMILIES`, and a block class in
   STATIC: each held expert computes exactly `C = capacity(tokens)` rows, an
   assignment beyond an expert's `C` in flattened `(b, t)` order is dropped
   and counted, and no shape, loop bound or branch depends on the routed load.
+  Where the tokens are no more than `C` (an acting step's 16 rows) nothing can
+  be dropped and there is no queue: each held expert computes the tokens
+  themselves, and a token takes its weighted sum of them (`unqueued`).
 - `*`, grouped-query attention over the episode. The carry holds the keys and
   values, after projection, of the last `config.max_episode_steps` positions as
   a ring, and a count; `unroll`'s T queries see the valid part of that memory
@@ -665,17 +668,26 @@ class ExpertMixture(nn.Module):
         scores = jax.nn.sigmoid(logits)
         return scores, jax.lax.top_k(scores + self.correction_bias, self.sizes.top_k)[1]
 
-    def routed(self, x):
-        """x (N, D) normalised tokens in (b, t) order -> (what the held
-        experts add (N, D), counts (4,) in COUNTS' order)."""
+    @nn.nowrap
+    def routing(self, x):
+        """x (N, D) normalised tokens -> (the chosen experts' weights (N, K),
+        normalised to sum to `scale`; the chosen experts (N, K))."""
         s = self.sizes
-        N, D = x.shape
-        E, K, Eh, C = s.experts, s.top_k, s.held, s.capacity(N)
         scores, chosen = self.scores(x)                                                # (N, E), (N, K)
         weight = jnp.take_along_axis(scores, chosen, axis=1)
         weight = weight / jnp.sum(weight, axis=1, keepdims=True)
         if s.scale != 1.0:
             weight = weight * s.scale
+        return weight, chosen
+
+    @nn.nowrap
+    def queued(self, x, weight, chosen):
+        """Any N: each held expert computes the first `capacity(N)` tokens
+        that chose it, in (b, t) order, and the rest are dropped -> (what the
+        held experts add (N, D), which choices are held (N, K), which kept)."""
+        s = self.sizes
+        N, D = x.shape
+        K, Eh, C = s.top_k, s.held, s.capacity(N)
         # a token's place in each held expert's queue, in (b, t) order
         local = chosen - s.first_held
         mine = local[..., None] == jnp.arange(Eh)                                      # (N, K, Eh)
@@ -689,8 +701,30 @@ class ExpertMixture(nn.Module):
         slot_weight = jnp.zeros((Eh * C,), F32).at[slot].set(weight.reshape(-1), mode="drop")
         rows = jnp.take(jnp.pad(x, ((0, 1), (0, 0))), slot_token, axis=0).reshape(Eh, C, D)
         out = self.experts(rows).reshape(Eh * C, D) * slot_weight[:, None]
-        y = jnp.zeros((N + 1, D), F32).at[slot_token].add(out)[:N]
-        load = jnp.sum((chosen[..., None] == jnp.arange(E)).astype(F32), axis=(0, 1))  # (E,)
+        return jnp.zeros((N + 1, D), F32).at[slot_token].add(out)[:N], held, kept
+
+    @nn.nowrap
+    def unqueued(self, x, weight, chosen):
+        """N <= capacity(N): no held expert can be offered more rows than it
+        computes, so a place in a queue means nothing. The held experts run on
+        the N tokens themselves and token n takes `sum_e w[n, e] out[e, n]`,
+        `w[n, e]` the weight it gave held expert e (0 where it did not choose
+        it), the product and the sum in float32 as `queued` has them."""
+        s = self.sizes
+        mine = (chosen - s.first_held)[..., None] == jnp.arange(s.held)                # (N, K, Eh)
+        each = jnp.sum(jnp.where(mine, weight[..., None], 0.0), axis=1)                # (N, Eh)
+        out = self.experts(jnp.broadcast_to(x, (s.held, *x.shape)))                    # (Eh, N, D)
+        held = jnp.any(mine, axis=-1)
+        return jnp.sum(out * each.T[..., None], axis=0), held, held
+
+    def routed(self, x):
+        """x (N, D) normalised tokens in (b, t) order -> (what the held
+        experts add (N, D), counts (4,) in COUNTS' order). Which form combines
+        them is read off the shape: the queue only where a token can be dropped."""
+        s = self.sizes
+        weight, chosen = self.routing(x)
+        y, held, kept = (self.unqueued if x.shape[0] <= s.capacity(x.shape[0]) else self.queued)(x, weight, chosen)
+        load = jnp.sum((chosen[..., None] == jnp.arange(s.experts)).astype(F32), axis=(0, 1))  # (E,)
         counts = jnp.stack([jnp.sum(held).astype(F32), jnp.sum(held & ~kept).astype(F32),
                             jnp.max(load), jnp.mean(load)])
         return y, jax.lax.stop_gradient(counts)

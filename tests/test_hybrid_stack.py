@@ -3,9 +3,10 @@ each layer and the whole unroll against the plain float32 reference
 (benchmark/reference/nemotron_h.py) on seeded weights; `step` applied T times
 against `unroll`; attention through the carried memory against full causal
 attention; the share test (every chip's routed part plus the shared expert once
-is the uncut layer); the static capacity's drops, counted; the collector that
-keeps the carry at the window starts alone against the one that stacks it at
-every step, bit for bit, for every core; the collector that scans over the
+is the uncut layer); the static capacity's drops, counted; the mixture with
+no queue where every token fits against the reference and the queue; the
+collector that keeps the carry at the window starts alone against the one that
+stacks it at every step, bit for bit, for every core; the collector that scans over the
 stack's OPENED form (its layers' parts) against the one that steps the flat
 row, bit for bit, and the joins it makes, counted; what the fused runner
 publishes."""
@@ -238,6 +239,70 @@ def test_mixture_against_the_reference_with_and_without_drops(built, ref, capaci
         asks = [(chosen == e).any(-1).sum() for e in range(4)]
         assert offered == sum(asks) and dropped == sum(max(a - 128, 0) for a in asks)
         assert not np.allclose(got, ref.moe_layer(p, x, s, drop=False), atol=1e-3)
+
+
+def _mixture_through(form):
+    """The mixture layer with its held experts' part made by `form`
+    (`queued` / `unqueued`) whatever the shape -> (x', held, kept)."""
+    def layer(m, x):
+        flat = hs.rms_norm(x, m.pre_norm, m.sizes.eps).reshape(-1, x.shape[-1])
+        y, held, kept = getattr(m, form)(flat, *m.routing(flat))
+        return x + (y + m.shared(flat)).reshape(x.shape), held, kept
+    return layer
+
+
+def check_the_mixture_where_every_token_fits(layer, p, shape, reference):
+    """`layer` on seeded tokens of `shape` (no more than a held expert's rows;
+    one of them chose two held experts, one none) builds no slot table, is
+    `reference(p, x)` and is the queue's result on the same input, forward and
+    gradient, with the queue's counts and nothing dropped."""
+    N = shape[0] * shape[1]
+    assert N <= hs._sizes(layer.spec, "E").capacity(N)
+    queue = lambda p, x: layer.apply({"params": p}, x, method=_mixture_through("queued"))
+    for seed in range(64):
+        x = jnp.asarray(np.random.default_rng(seed).normal(size=shape), jnp.float32)
+        want, held, kept = queue(p, x)
+        asked = np.asarray(held).sum(axis=1)
+        if asked.max() == 2 and asked.min() == 0:
+            break
+    assert asked.max() == 2 and asked.min() == 0
+    assert "scatter" not in str(jax.make_jaxpr(lambda p, x: layer.apply({"params": p}, x))(p, x))
+    got, counts = layer.apply({"params": p}, x)
+    np.testing.assert_allclose(got, reference(p, x), rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert np.array_equal(held, kept) and float(counts[0]) == asked.sum() > 0 and float(counts[1]) == 0.0
+    assert float(counts[3]) == N * 2 / 16 and float(counts[2]) >= float(counts[3])
+    weigh = lambda fn: lambda p, x: jnp.sum(fn(p, x) * jnp.cos(jnp.arange(x.size, dtype=jnp.float32).reshape(x.shape)))
+    grads = [jax.grad(weigh(fn), argnums=(0, 1))(p, x)
+             for fn in (lambda p, x: layer.apply({"params": p}, x)[0], lambda p, x: queue(p, x)[0], reference)]
+    for other in grads[1:]:
+        for g, w in zip(jax.tree.leaves(grads[0]), jax.tree.leaves(other)):
+            np.testing.assert_allclose(g, w, rtol=2e-5, atol=2e-5 * max(float(jnp.max(jnp.abs(w))), 1e-3))
+
+
+@pytest.mark.parametrize("tokens,first", [(16, 0), (16, 8), (128, 0), (128, 12)])
+def test_where_every_token_fits_the_mixture_takes_no_queue_and_is_the_reference_and_the_queues_result(
+        built, ref, tokens, first):
+    cfg = tiny_cfg(first_expert_held=first)
+    layer = hs.ExpertMixture(hs.StackSpec.of(cfg), jnp.float32)
+    check_the_mixture_where_every_token_fits(layer, _params_of(built, "moe_2"), (2, tokens // 2, 64),
+                                             lambda p, x: ref.moe_layer(p, x, ref.stack_of(cfg)))
+
+
+def check_one_token_more_than_a_held_experts_rows_takes_the_queue(layer, p, reference):
+    x = jnp.asarray(np.random.default_rng(8).normal(size=(1, 129, 64)), jnp.float32)
+    capacity = hs._sizes(layer.spec, "E").capacity
+    assert capacity(129) == 128 == capacity(128)
+    text = lambda x: str(jax.make_jaxpr(lambda x: layer.apply({"params": p}, x))(x))
+    assert "scatter-add" in text(x) and "scatter" not in text(x[:, :128])
+    np.testing.assert_allclose(layer.apply({"params": p}, x)[0], reference(p, x), rtol=2e-5, atol=2e-5)
+
+
+def test_one_token_more_than_a_held_experts_rows_takes_the_queue(built, ref):
+    cfg = tiny_cfg()
+    check_one_token_more_than_a_held_experts_rows_takes_the_queue(
+        hs.ExpertMixture(hs.StackSpec.of(cfg), jnp.float32), _params_of(built, "moe_2"),
+        lambda p, x: ref.moe_layer(p, x, ref.stack_of(cfg)))
 
 
 def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_uncut_layer(built, ref):

@@ -4,9 +4,10 @@ at tiny widths on the CPU, each piece against the plain float32 reference
 and its gradient against the recurrence; the mixer's unroll against its steps;
 gated attention through the ring against full causal attention with the
 rotary embedding at absolute positions; the softmax mixture with and without
-drops; the share test; the whole forward, loss and gradient; the hand counts
-at published widths; what the spec refuses; and that the first family's
-layers trace to the jaxprs they had before the second came."""
+drops, and with no queue where every token fits; the share test; the whole
+forward, loss and gradient; the hand counts at published widths; what the spec
+refuses; and that the first family's layers trace to the jaxprs they had
+before the second came."""
 
 import dataclasses
 import hashlib
@@ -289,6 +290,27 @@ def test_the_softmax_mixture_against_the_reference_with_and_without_drops(built,
     assert (float(jnp.max(jnp.abs(got - undropped))) > 1e-3) == drops
 
 
+@pytest.mark.parametrize("tokens,first", [(16, 0), (16, 8), (128, 0), (128, 12)])
+def test_where_every_token_fits_the_softmax_mixture_takes_no_queue_and_is_the_reference_and_the_queues_result(
+        built, ref, tokens, first):
+    from test_hybrid_stack import check_the_mixture_where_every_token_fits
+
+    cfg = tiny_qwen_cfg(first_expert_held=first)
+    _, layer, p = _layer((cfg, *built[1:]), "moe_2")
+    with jax.default_matmul_precision("highest"):
+        check_the_mixture_where_every_token_fits(layer, p, (2, tokens // 2, 64),
+                                                 lambda p, x: ref.moe_layer(p, x, ref.stack_of(cfg)))
+
+
+def test_one_token_more_than_a_held_experts_rows_takes_the_queue(built, ref):
+    from test_hybrid_stack import check_one_token_more_than_a_held_experts_rows_takes_the_queue
+
+    _, layer, p = _layer(built, "moe_2")
+    with jax.default_matmul_precision("highest"):
+        check_one_token_more_than_a_held_experts_rows_takes_the_queue(
+            layer, p, lambda p, x: ref.moe_layer(p, x, ref.stack_of(built[0])))
+
+
 def test_the_shares_routed_parts_and_the_shared_expert_once_are_the_uncut_layer(built, ref):
     """Four chips of four experts each: what each holds, summed, plus the
     shared expert once, is the layer that holds all sixteen."""
@@ -419,21 +441,23 @@ def test_published_widths_give_the_hand_counts():
 # -------------------------------------- the first family, as it was before this one
 
 # sha256 of str(make_jaxpr(grad(sum of the layer's output))) at test_hybrid_stack's tiny widths, read on the
-# parent commit (PR 55) and on this tree: the same text. jax 0.9.0.
+# parent commit (PR 55) and on this tree: the same text. jax 0.9.0. The mixture's is of 140 tokens since PR 59
+# (read on ITS parent, 1883efd, and on its tree: the same text): 18 fit a held expert's 128 rows and take no queue.
 BEFORE = {"attention": "5f3b602abab04f023833b54e9540cdf0596cd1c684cb7438cce1a92aa2ecfecb",
-          "mixture": "944a97d3b0b867935fc85e29834895e3982ba916c05362cc6d3a4c5513302edf"}
+          "mixture": "80f552b893e5dbcc640278a55a494c3b277f3ef55cc7021081139761236b6e22"}
 
 
 @pytest.mark.parametrize("name", sorted(BEFORE))
 def test_with_its_options_off_a_shared_layer_traces_to_the_jaxpr_it_had(name):
     """`EpisodeAttention` with no query / key norm, no rotary and no gate, and
-    `ExpertMixture` as `nemotron_h` has it, op for op what they were."""
+    `ExpertMixture` as `nemotron_h` has it on more tokens than a held expert's
+    rows (the queue), op for op what they were."""
     from test_hybrid_stack import tiny_cfg
 
     spec = hs.StackSpec.of(tiny_cfg())
     x, kv, count = jnp.zeros((2, 9, 64)), jnp.zeros((2, 16, 2, 16)), jnp.zeros((2,), jnp.int32)
     layer, args = {"attention": (hs.EpisodeAttention(spec, jnp.float32), (x, kv, kv, count)),
-                   "mixture": (hs.ExpertMixture(spec, jnp.float32), (x,))}[name]
+                   "mixture": (hs.ExpertMixture(spec, jnp.float32), (jnp.zeros((2, 70, 64)),))}[name]
     sizes = spec.sizes("*")
     assert (sizes.qk_norm, sizes.rotary_dim, sizes.output_gate, sizes.norm_offset) == (False, 0, False, 0.0)
     params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), *args)

@@ -722,6 +722,44 @@ def test_the_cells_delta_layer_holds_no_triangular_solve_of_xlas(one_chip, compi
     assert _xlas_triangular_solves(text) == []
 
 
+@pytest.mark.parametrize("config,held,width", [("nemotron-twotower-30b-a3b-ep16", 8, 2688),
+                                               ("qwen3-next-80b-a3b-ep32", 16, 2048)])
+def test_an_acting_step_of_the_cells_mixture_builds_no_slot_table_and_the_update_still_does(
+        config, held, width, one_chip, compiled_kernels):
+    """One mixture layer of each stack cell at published widths, forward, as
+    the chip's compiler has it. At acting's `num_actors` = 16 tokens, which fit
+    a held expert's 128 rows: no scatter, no gather but the K chosen scores
+    a token, and no array of `held x 128` rows (the queue's `f32[8,128,2688]`
+    / `f32[16,128,2048]`, PERF.md finding 59): the held experts run on the 16
+    tokens themselves. At the update's B x T tokens: the queue, as before. The
+    form follows the shape, so the compiled text is its engagement record."""
+    from r2d2_tpu.models import hybrid_stack as hs
+
+    cfg = _cell_config(config)
+    sizes = hs.spec_of(cfg).sizes("E")
+    assert (cfg.num_actors, sizes.held, sizes.hidden_size) == (16, held, width)
+    layer = hs._layer(sizes, jnp.dtype(cfg.resolved_compute_dtype), "E", 0)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def text_at(*shape):
+        x = sds(*shape, width)
+        params = jax.tree.map(lambda a: sds(*a.shape), jax.eval_shape(layer.init, jax.random.PRNGKey(0), x))
+        return jax.jit(lambda p, x: layer.apply(p, x)).lower(params, x).compile().as_text()
+
+    gathers = lambda text: re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text)
+    rows = lambda tokens: rf"\[{held},{sizes.capacity(tokens)},{width}\]"
+    acting = text_at(cfg.num_actors)
+    assert sizes.capacity(cfg.num_actors) == 128
+    assert "scatter" not in acting and gathers(acting) == [f"f32[16,{sizes.top_k}]"]
+    assert not re.search(rows(cfg.num_actors), acting)
+    assert re.search(rf"f32\[{held},16,{width}\]", acting)            # each held expert's output for the 16 tokens
+    tokens = cfg.batch_size * cfg.seq_len
+    update = text_at(cfg.batch_size, cfg.seq_len)
+    assert tokens == 4648 > sizes.capacity(tokens) in (256, 512)
+    assert "scatter(" in update and re.search(rows(tokens), update)
+    assert f"f32[{held * sizes.capacity(tokens)},{width}]" in gathers(update)
+
+
 def test_every_prefetch_wait_of_natures_update_program_has_an_owner(topo, compiled_kernels):
     """nature-lstm512's `multi` as the chip builds it: memory-space assignment
     puts in some 280 asynchronous copies and slices, none with an op_name, 3 %
