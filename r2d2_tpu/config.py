@@ -1125,7 +1125,12 @@ class R2D2Config:
         pairs = self.core_config
         if isinstance(pairs, dict):
             pairs = pairs.items()
-        frozen = lambda v: tuple(v) if isinstance(v, list) else v
+        def frozen(v):
+            # a published group of keys (a dict) as sorted pairs, a list as a tuple
+            if isinstance(v, dict):
+                return tuple(sorted((str(k), frozen(w)) for k, w in v.items()))
+            return tuple(frozen(w) for w in v) if isinstance(v, list) else v
+
         object.__setattr__(
             self, "core_config", tuple(sorted((str(k), frozen(v)) for k, v in pairs))
         )

@@ -1,10 +1,11 @@
 """A core that is a stack of residual blocks: state-space or delta-rule mixers,
-routed expert mixtures held as one chip's share, and attention over the episode.
+routed expert mixtures held as one chip's share or a dense MLP, and attention
+over the episode's stored keys and values or its stored latents.
 
 `recurrent_core="hybrid_stack"` puts a stack of pre-norm residual blocks in
 the core slot, `x <- x + block(norm(x))`, between an input projection `(latent
 + A + 1) -> hidden` (it stands where a language model has its token
-embedding) and a final norm. TWO FAMILIES of published models are read from
+embedding) and a final norm. THREE FAMILIES of published models are read from
 `config.core_config`, each under the names its own `config.json` gives its
 widths; `core_config["model_type"]` says which (`spec_of`, the one place that
 asks for a family by name; absent: `nemotron_h`):
@@ -15,14 +16,19 @@ asks for a family by name; absent: `nemotron_h`):
   its mixture; layer `i` of `num_hidden_layers` mixes by attention where `(i +
   1) % full_attention_interval == 0` and by the gated delta rule otherwise. Its
   norms scale by `1 + weight`.
+- `kimi_linear` (`KimiLinearSpec`): a decoder layer is TWO blocks, its mixer
+  then its MLP, both read from published lists: `linear_attn_config` names
+  the layers (counted from 1) that mix by Kimi Delta Attention and those that
+  mix by latent attention, and the first `first_k_dense_replace` layers' MLP
+  is dense where the later ones' is the mixture.
 
 What `HybridStack` asks of a spec is the same for both: `hidden_size`, `eps`
 and `norm_offset`, `blocks` (the residual blocks in order, `(kind, layer
 index)`), `sizes(kind)` (that block's own sizes, handed to its class: each
 class reads the sizes IT uses, not a union of the families' keys), and what
-`_Stack` makes of those (`segments()`, `state_size`, `capacity()`). A third
+`_Stack` makes of those (`segments()`, `state_size`, `capacity()`). A further
 family is a spec class with those, an entry in `FAMILIES`, and a block class in
-`KINDS` for whatever mechanism the four kinds lack. The kinds:
+`KINDS` for whatever mechanism the seven kinds lack. The kinds:
 
 - `M`, a Mamba-2 mixer (Dao & Gu 2024, "Transformers are SSMs"): `[z | xBC |
   dt] = in_proj(u)`; a causal depthwise convolution and silu over `xBC`, whose
@@ -39,6 +45,16 @@ family is a spec class with those, an entry in `FAMILIES`, and a block class in
   q_t`: not diagonal and not a sum of outer products of the inputs, so its
   chunked form (`delta_rule_chunked`) solves a unit lower-triangular system a
   chunk and head before it scans over the chunks; `step` is the recurrence.
+- `K`, a Kimi Delta Attention mixer (Kimi Team 2025, "Kimi Linear"): the delta
+  rule again, with separate q, k, v projections and convolutions, two
+  low-rank gates, and a forget gate PER KEY CHANNEL: `S <- Diag(exp(g_t)) S`
+  scales each row of the state by its own decay. The chunk's pair terms are
+  then `sum_d k_id k_jd exp(G_id - G_jd)`, which no single `k k^T` gives
+  without exponentiating something positive, so `kda_chunked` (the third
+  chunked matrix recurrence, beside `ssd_chunked` and `delta_rule_chunked`)
+  builds them between sub-chunks by matmuls of operands decayed towards a
+  shared row and inside a sub-chunk column by column; the triangular solve
+  and the scan over chunks are `D`'s.
 - `E`, a routed mixture held as a SHARE: scores over ALL routed experts
   (sigmoid + correction bias, scaled | a softmax, renormalised), the top k,
   experts of two matrices (`W_down relu(W_up x)^2`) or three (`W_down (silu(
@@ -53,6 +69,11 @@ family is a spec class with those, an entry in `FAMILIES`, and a block class in
   Where the tokens are no more than `C` (an acting step's 16 rows) nothing can
   be dropped and there is no queue: each held expert computes the tokens
   themselves, and a token takes its weighted sum of them (`unqueued`).
+  `kimi_linear` takes the first family's router (sigmoid + correction bias,
+  scaled) with the second's experts (three matrices) and an ungated shared
+  expert: options of `MixtureSizes`, no line of the class.
+- `F`, a dense gated MLP, `down(silu(gate x) * up x)`, for a layer whose
+  neighbours' MLPs are mixtures. No state, no counts.
 - `*`, grouped-query attention over the episode. The carry holds the keys and
   values, after projection, of the last `config.max_episode_steps` positions as
   a ring, and a count; `unroll`'s T queries see the valid part of that memory
@@ -64,10 +85,19 @@ family is a spec class with those, an entry in `FAMILIES`, and a block class in
   holds keys already rotated), and gates the heads' outputs by a sigmoid taken
   from the second half of the query projection. With the three off the layer
   is the first family's, op for op.
+- `L`, multi-head latent attention without positional encoding. The carry
+  holds NOT keys and values but the latent they are projected from, `[norm(c)
+  | k_pe]`, 576 numbers a position where 32 heads' keys and values would be
+  10,240, as a ring of the last `config.max_episode_steps` positions. So
+  `unroll` and `step` are two forms against one up-projection: a sequence
+  up-projects the ring and itself to keys and values once and attends as `*`
+  does; an acting step ABSORBS the key half of the up-projection into its
+  query, scores and sums the stored latents themselves, and applies the
+  value half after the sum: no per-head key or value over the ring.
 
 THE CARRY is one flat float32 vector a row, `state_shape(cfg) = (1, S)`
 (models/core.py: the rule's `n = 1`): every mixer's state and convolution
-tail, every `*` block's keys and values, and the count as two numbers below
+tail, every `*` block's keys and values, every `L` block's latents, and the count as two numbers below
 256 (so a bfloat16 store holds it exactly), padded to whole 128-lanes. Zero
 is the episode start. The class splits and joins it; stores, accumulator,
 gather and `batch["hidden"]` see an array like any other. Its statements for
@@ -99,8 +129,9 @@ matmul, not by a `(.., 8, 512)` view); inside `ssd_chunked` a chunk's 128
 steps are the minor axis of every per-head scalar and of `x dt` and y, with
 heads a batch axis of the einsums, reached by one transposition in and one
 out; `delta_rule_chunked` keeps its heads' 128 dimensions minor and puts a
-chunk's steps minor on the per-head scalars alone. `step` (one row of 16 a
-call) keeps heads and head_dim as axes: its arrays are a tile or two.
+chunk's steps minor on the per-head scalars alone, and so does `kda_chunked`,
+whose gate sums are per channel and lie as the keys do. `step` (one row of 16
+a call) keeps heads and head_dim as axes: its arrays are a tile or two.
 """
 
 from __future__ import annotations
@@ -188,6 +219,52 @@ class DeltaSizes:
     @property
     def conv_dim(self) -> int:
         return 2 * self.key_heads * self.key_dim + self.value_heads * self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class KdaSizes:
+    """What `KimiDeltaAttention` uses: `heads` heads whose keys and values
+    are both `head_dim` wide, the two low-rank gates through `gate_rank`."""
+
+    hidden_size: int
+    heads: int
+    head_dim: int
+    conv_kernel: int
+    gate_rank: int
+    eps: float
+    chunk: int
+
+    @property
+    def width(self) -> int:
+        return self.heads * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """What `LatentAttention` uses: a position is remembered as `latent +
+    rope_dim` numbers, whatever the heads."""
+
+    hidden_size: int
+    heads: int
+    latent: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+    max_episode_steps: int
+    eps: float
+
+    @property
+    def stored(self) -> int:
+        return self.latent + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class MlpSizes:
+    """What `DenseMlp` uses."""
+
+    hidden_size: int
+    width: int
+    eps: float
 
 
 class _Stack:
@@ -385,7 +462,91 @@ class Qwen3NextSpec(_Stack):
                             gated=True, shared_gate=True)
 
 
-FAMILIES = {"nemotron_h": StackSpec, "qwen3_next": Qwen3NextSpec}
+@dataclasses.dataclass(frozen=True)
+class KimiLinearSpec(_Stack):
+    """`config.core_config` of a `kimi_linear` stack, checked: that family's
+    keys by their published names (`linear_attn_config` the published group,
+    whole: its two lists count layers from 1 and may name more than
+    `num_hidden_layers`), and this repo's own three. A decoder layer is two
+    residual blocks: its mixer, Kimi Delta Attention where `kda_layers` lists
+    it and latent attention where `full_attn_layers` does, then a dense MLP
+    in the first `first_k_dense_replace` layers and the mixture after them."""
+
+    model_type: str
+    hidden_size: int
+    num_hidden_layers: int
+    linear_attn_config: Tuple
+    num_attention_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    first_k_dense_replace: int
+    intermediate_size: int
+    num_experts: int
+    num_experts_per_token: int
+    moe_intermediate_size: int
+    num_shared_experts: int
+    routed_scaling_factor: float
+    moe_renormalize: bool
+    rms_norm_eps: float
+    num_experts_held: int = 0
+    first_expert_held: int = 0
+    capacity_factor: float = 2.0
+    max_episode_steps: int = 0
+
+    @classmethod
+    def of(cls, cfg) -> "KimiLinearSpec":
+        spec = _read(cls, cfg, "num_experts")
+        linear = spec.linear
+        wanted = {"kda_layers", "full_attn_layers", "num_heads", "head_dim", "short_conv_kernel_size"}
+        if set(linear) != wanted:
+            raise ValueError(f"linear_attn_config: keys {sorted(linear)}, not {sorted(wanted)}")
+        layers = range(1, spec.num_hidden_layers + 1)
+        kda, full = set(linear["kda_layers"]), set(linear["full_attn_layers"])
+        if spec.num_hidden_layers < 1 or kda & full or not set(layers) <= kda | full:
+            raise ValueError("every layer from 1 to num_hidden_layers is in kda_layers or in full_attn_layers, not both")
+        if spec.moe_renormalize is not True:
+            raise ValueError("moe_renormalize: true (the mixture divides the chosen weights by their sum)")
+        if not 0 <= spec.first_k_dense_replace <= spec.num_hidden_layers or spec.num_shared_experts < 1:
+            raise ValueError("first_k_dense_replace: 0 to num_hidden_layers; num_shared_experts: 1 or more")
+        return spec
+
+    norm_offset = 0.0  # a norm scales by its weight
+
+    @property
+    def linear(self) -> dict:
+        """`linear_attn_config`, which the config holds as pairs, as the group of keys it is."""
+        return dict(self.linear_attn_config)
+
+    @property
+    def eps(self) -> float:
+        return self.rms_norm_eps
+
+    @property
+    def blocks(self):
+        full = set(self.linear["full_attn_layers"])
+        mixer = lambda i: "L" if i + 1 in full else "K"
+        mlp = lambda i: "F" if i < self.first_k_dense_replace else "E"
+        return tuple(block for i in range(self.num_hidden_layers) for block in ((mixer(i), i), (mlp(i), i)))
+
+    def sizes(self, kind: str):
+        linear = self.linear
+        if kind == "K":
+            return KdaSizes(self.hidden_size, linear["num_heads"], linear["head_dim"], linear["short_conv_kernel_size"],
+                            linear["head_dim"], self.rms_norm_eps, DELTA_CHUNK)
+        if kind == "L":
+            return LatentSizes(self.hidden_size, self.num_attention_heads, self.kv_lora_rank, self.qk_nope_head_dim,
+                               self.qk_rope_head_dim, self.v_head_dim, self.max_episode_steps, self.rms_norm_eps)
+        if kind == "F":
+            return MlpSizes(self.hidden_size, self.intermediate_size, self.rms_norm_eps)
+        return MixtureSizes(self.hidden_size, self.num_experts, self.num_experts_per_token, self.moe_intermediate_size,
+                            self.moe_intermediate_size * self.num_shared_experts, self.num_experts_held,
+                            self.first_expert_held, self.capacity_factor, self.rms_norm_eps,
+                            scale=self.routed_scaling_factor, gated=True)
+
+
+FAMILIES = {"nemotron_h": StackSpec, "qwen3_next": Qwen3NextSpec, "kimi_linear": KimiLinearSpec}
 
 
 def spec_of(cfg):
@@ -1008,9 +1169,357 @@ class GatedDeltaNet(nn.Module):
         return x + self._out(o, z), delta, seq[:, 1:]
 
 
+KDA_SUB = 16  # steps of a chunk whose pair terms `kda_chunked` takes column by column
+
+
+def _kda_pairs(q, k, G, sub: int, dtype):
+    """The two pair matrices of a chunk of the delta rule whose decay is per
+    key channel: q, k and G (.., Q, dk), G the running sum of g <= 0 inside
+    the chunk -> (`A_ij = sum_d k_id k_jd exp(G_id - G_jd)` for j < i, float32
+    at "highest"; `P_ij = sum_d q_id k_jd exp(G_id - G_jd)` for j <= i, from
+    operands in `dtype`), both (.., Q, Q) and zero elsewhere.
+
+    No single matmul gives them: `exp(-G_j)` alone overflows float32 inside a
+    chunk. EVERY EXPONENT EVALUATED IS <= 0: between two sub-chunks of `sub`
+    steps the decay is split at the later one's first row, `exp(G_i - G_ref)
+    exp(G_ref - G_j)` with `G_i <= G_ref <= G_j`, and the block is a matmul of
+    `k exp(G - G_ref)` against `k exp(G_ref - G)`; inside a sub-chunk the
+    `sub` columns are taken one after the other, elementwise over d and
+    reduced, each recomputed in the backward pass, so that no `(.., sub, sub,
+    dk)` array outlives its column."""
+    Q, dk = q.shape[-2:]
+    m = Q // sub
+    rows = jnp.arange(sub)
+    # inside each sub-chunk, column by column
+    inner = lambda a: a.reshape(*a.shape[:-2], m, sub, dk)
+    q_in, k_in, G_in = inner(q), inner(k), inner(G)
+
+    @jax.checkpoint
+    def column(_, j):
+        k_j, G_j = (jax.lax.dynamic_index_in_dim(a, j, axis=-2) for a in (k_in, G_in))      # (.., m, 1, dk)
+        after = (rows >= j)[:, None]
+        decayed = k_j * jnp.exp(jnp.where(after, G_in - G_j, 0.0))                    # (.., m, sub, dk)
+        return None, (jnp.sum(jnp.where(rows[:, None] > j, k_in * decayed, 0.0), axis=-1),
+                      jnp.sum(jnp.where(after, q_in * decayed, 0.0), axis=-1))
+
+    _, (a_own, p_own) = jax.lax.scan(column, None, rows)                              # (sub, .., m, sub)
+    a_own, p_own = jnp.moveaxis(a_own, 0, -1), jnp.moveaxis(p_own, 0, -1)             # (.., m, sub rows, sub columns)
+    a_rows, p_rows = [], []
+    for a in range(m):
+        # the sub-chunks before this one, the decay split at this one's first row
+        at = a * sub
+        parts_a, parts_p = [a_own[..., a, :, :]], [p_own[..., a, :, :]]
+        if a:
+            ref = G[..., at:at + 1, :]
+            late = jnp.exp(G[..., at:at + sub, :] - ref)                                # (.., sub, dk), exponents <= 0
+            early = k[..., :at, :] * jnp.exp(ref - G[..., :at, :])                      # (.., at, dk), exponents <= 0
+            parts_a.insert(0, jnp.einsum("...id,...jd->...ij", k[..., at:at + sub, :] * late, early,
+                                         precision=jax.lax.Precision.HIGHEST))
+            parts_p.insert(0, jnp.einsum("...id,...jd->...ij", (q[..., at:at + sub, :] * late).astype(dtype),
+                                         early.astype(dtype), preferred_element_type=F32))
+        if Q - at - sub:
+            later = jnp.zeros((*q.shape[:-2], sub, Q - at - sub), F32)
+            parts_a.append(later)
+            parts_p.append(later)
+        a_rows.append(jnp.concatenate(parts_a, axis=-1))
+        p_rows.append(jnp.concatenate(parts_p, axis=-1))
+    return jnp.concatenate(a_rows, axis=-2), jnp.concatenate(p_rows, axis=-2)
+
+
+def kda_chunked(q, k, v, g, beta, s0, chunk: int, dtype, sub: int = KDA_SUB):
+    """The delta rule with a decay per key channel (Kimi Delta Attention)
+    over a sequence, in chunks: the third chunked matrix recurrence here,
+    beside `ssd_chunked` and `delta_rule_chunked`.
+
+    q and k (B, T, H dk), q scaled and both L2-normalised per head; v (B, T,
+    H dv); g <= 0 (B, T, H dk); beta in (0, 1) (B, T, H); s0 (B, H, dk, dv):
+    per head the recurrence `S <- Diag(exp(g_t)) S; S <- S + k_t (beta_t (v_t
+    - S^T k_t))^T; o_t = S^T q_t` -> (o (B, T, H dv), S_T), float32.
+
+    As in `delta_rule_chunked` a chunk of Q steps first solves for what its
+    steps write given the state it starts from, `v' = T (beta v) - T (beta k
+    exp(G)) S` with `T = (I + L)^-1`, but G, the running sum of g inside the
+    chunk, is now a vector over the key dimension and `L_ij = beta_i sum_d
+    k_id k_jd exp(G_id - G_jd)`: the pair term is no longer one `k k^T` times
+    a scalar decay (`_kda_pairs`, which also gives the same form for `q_i,
+    k_j`). Then the scan over the chunks: `o = (q exp(G)) S + P v'` and `S <-
+    Diag(exp(G_Q)) S + (k exp(G_Q - G))^T v'`, the state's decay a scaling of
+    its rows. Every exponent is <= 0; g is not clamped. The triangular system
+    is `delta_rule_chunked`'s: the kernel where the triangles fill whole
+    lanes, `solve_triangular` elsewhere. Padding has g = 0, beta = 0 and k =
+    0: decay 1, nothing written.
+
+    Where the axes live (PERF.md finding 55): q, k, v and g go from `(B, T, H
+    d)` to `(B, n, H, Q, d)` by one transposition of `(Q, H)` blocks (d = 128
+    stays minor; G lies as k does) and o comes back by one; a chunk's steps
+    are the minor axis of `beta` alone, the one per-head scalar left."""
+    B, T, _ = q.shape
+    H, dk, dv = s0.shape[1:]
+    sub = min(sub, chunk)
+    Q = min(chunk, sub * math.ceil(T / sub))
+    if Q % sub:
+        raise ValueError(f"a chunk of {chunk} steps is not whole sub-chunks of {sub}")
+    pad = (-T) % Q
+    n = (T + pad) // Q
+    highest = jax.lax.Precision.HIGHEST
+
+    def chunks(a):
+        a = jnp.pad(a, ((0, 0), (0, pad), (0, 0))) if pad else a
+        return jnp.moveaxis(a.reshape(B, n, Q, H, -1), 2, 3)                     # (B, n, H, Q, d)
+
+    q, k, v, g = chunks(q), chunks(k), chunks(v), chunks(g)
+    beta = chunks(beta)[..., 0]                                                  # (B, n, H, Q)
+    i = jnp.arange(Q)
+    G = jnp.einsum("ij,bnhjd->bnhid", (i[:, None] >= i[None, :]).astype(F32), g, precision=highest)
+    A, inside = _kda_pairs(q, k, G, sub, dtype)
+    # what the chunk's steps write, given the state it starts from
+    L = beta[..., None] * A                                                      # (B, n, H, Q, Q), strictly lower
+    solve = pallas_delta.unit_lower_solve if pallas_delta.kernel_fits(B * n * H, Q) else unit_lower_solve
+    solved = solve(L, beta[..., None] * jnp.concatenate([jnp.exp(G) * k, v], axis=-1))
+    w, u = solved[..., :dk], solved[..., dk:]
+    q_in = (jnp.exp(G) * q).astype(dtype)                                        # reads the incoming state
+    k_out = (jnp.exp(G[..., -1:, :] - G) * k).astype(dtype)                      # writes the outgoing one
+    whole = jnp.exp(G[..., -1, :])                                               # (B, n, H, dk)
+
+    def across(S, chunk_n):
+        w_n, u_n, q_n, k_n, inside_n, whole_n = chunk_n
+        S_low = S.astype(dtype)
+        written = u_n - jnp.einsum("bhid,bhde->bhie", w_n.astype(dtype), S_low, preferred_element_type=F32)
+        o = (jnp.einsum("bhid,bhde->bhie", q_n, S_low, preferred_element_type=F32)
+             + jnp.einsum("bhij,bhje->bhie", inside_n, written.astype(dtype), preferred_element_type=F32))
+        S = whole_n[..., None] * S + jnp.einsum("bhid,bhie->bhde", k_n, written.astype(dtype),
+                                                preferred_element_type=F32)
+        return S, o
+
+    per_chunk = tuple(jnp.moveaxis(a, 1, 0) for a in (w, u, q_in, k_out, inside.astype(dtype), whole))
+    S, o = jax.lax.scan(across, s0.astype(F32), per_chunk)                       # o (n, B, H, Q, dv)
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(B, n * Q, H * dv)
+    return o[:, :T], S
+
+
+class KimiDeltaAttention(nn.Module):
+    """The `kimi_linear` linear-attention mixer (Kimi Team 2025, "Kimi
+    Linear", arXiv:2510.26692): `q, k, v = q_proj(u), k_proj(u), v_proj(u)`,
+    each through its own causal depthwise convolution and silu, whose last
+    `conv_kernel - 1` inputs are state; q and k L2-normalised per head, q
+    scaled; `beta = sigmoid(b_proj(u))` a head; the forget gate PER KEY
+    CHANNEL, `g = -exp(A_log) softplus(f_b(f_a(u)) + dt_bias)` through rank
+    `gate_rank`; the delta rule with `Diag(exp(g))` on the state's rows;
+    `o_proj(norm(o) sigmoid(g_b(g_a(u))))` with the norm over each head."""
+
+    spec: KdaSizes
+    dtype: jnp.dtype
+
+    @staticmethod
+    def state_shapes(s):
+        return (s.heads, s.head_dim, s.head_dim), (s.conv_kernel - 1, 3 * s.width)
+
+    def setup(self):
+        s = self.spec
+        D, C, r = s.hidden_size, s.width, s.gate_rank
+        self.pre_norm = self.param("pre_norm", nn.initializers.ones, (D,))
+        self.q_proj = self.param("q_proj", _matrix, (D, C))
+        self.k_proj = self.param("k_proj", _matrix, (D, C))
+        self.v_proj = self.param("v_proj", _matrix, (D, C))
+        self.q_conv = self.param("q_conv", _matrix, (s.conv_kernel, C))
+        self.k_conv = self.param("k_conv", _matrix, (s.conv_kernel, C))
+        self.v_conv = self.param("v_conv", _matrix, (s.conv_kernel, C))
+        self.f_a = self.param("f_a", _matrix, (D, r))
+        self.f_b = self.param("f_b", _matrix, (r, C))
+        self.g_a = self.param("g_a", _matrix, (D, r))
+        self.g_b = self.param("g_b", _matrix, (r, C))
+        self.b_proj = self.param("b_proj", _matrix, (D, s.heads))
+        self.a_log = self.param("A_log", _a_log_init, (s.heads,))
+        self.dt_bias = self.param("dt_bias", _dt_bias_init(1e-3, 0.1, 1e-4), (C,))
+        self.norm = self.param("norm", nn.initializers.ones, (s.head_dim,))
+        self.o_proj = self.param("o_proj", _matrix, (C, D))
+
+    def _project(self, x):
+        """x (.., D) -> [q | k | v] (.., 3 C) before the convolutions, the
+        output gate (.., C), beta (.., H), g (.., C) <= 0."""
+        s = self.spec
+        h = rms_norm(x, self.pre_norm, s.eps)
+        qkv = jnp.concatenate([_mm(h, w, self.dtype) for w in (self.q_proj, self.k_proj, self.v_proj)], axis=-1)
+        gate = _mm(_mm(h, self.g_a, self.dtype), self.g_b, self.dtype)
+        forget = _mm(_mm(h, self.f_a, self.dtype), self.f_b, self.dtype)
+        g = -jnp.repeat(jnp.exp(self.a_log), s.head_dim) * jax.nn.softplus(forget + self.dt_bias)
+        return qkv, gate, jax.nn.sigmoid(_mm(h, self.b_proj, self.dtype)), g
+
+    @property
+    def conv_weight(self):
+        return jnp.concatenate([self.q_conv, self.k_conv, self.v_conv], axis=-1)         # (K, 3 C)
+
+    def _heads(self, qkv):
+        """(.., 3 C) after the convolutions -> q scaled, k, v: per channel, q
+        and k L2-normalised per head (`x rsqrt(sum(x^2) + 1e-6)`)."""
+        s = self.spec
+        C = s.width
+        unit = lambda a, scale: rms_norm(a, scale * s.head_dim ** -0.5, 1e-6 / s.head_dim, groups=s.heads)
+        return unit(qkv[..., :C], s.head_dim ** -0.5), unit(qkv[..., C:2 * C], 1.0), qkv[..., 2 * C:]
+
+    def _out(self, o, gate):
+        s = self.spec
+        o = rms_norm(o, jnp.tile(self.norm, s.heads), s.eps, groups=s.heads) * jax.nn.sigmoid(gate)
+        return _mm(o, self.o_proj, self.dtype)
+
+    def __call__(self, x, delta, tail):
+        """x (B, T, D), delta (B, H, dk, dv), tail (B, K-1, 3 C) -> the same three."""
+        s, T = self.spec, x.shape[1]
+        qkv, gate, beta, g = self._project(x)
+        seq = jnp.concatenate([tail, qkv], axis=1)
+        weight = self.conv_weight
+        q, k, v = self._heads(jax.nn.silu(sum(weight[j] * seq[:, j:j + T] for j in range(s.conv_kernel))))
+        o, delta = self.recurrence(q, k, v, g, beta, delta)
+        return x + self._out(o, gate), delta, seq[:, T:]
+
+    def recurrence(self, q, k, v, g, beta, delta):
+        """The chunked form under a name of its own (`kda_<i>.recurrence`)."""
+        return kda_chunked(q, k, v, g, beta, delta, self.spec.chunk, self.dtype)
+
+    def step(self, x, delta, tail):
+        """One step of the recurrence itself: x (B, D). Sums, not matmuls: the
+        state is float32 and a row's is 2 MB."""
+        s = self.spec
+        qkv, gate, beta, g = self._project(x)
+        seq = jnp.concatenate([tail, qkv[:, None]], axis=1)                      # (B, K, 3 C)
+        q, k, v = (a.reshape(-1, s.heads, s.head_dim)
+                   for a in self._heads(jax.nn.silu(jnp.sum(self.conv_weight * seq, axis=1))))
+        delta = jnp.exp(g.reshape(-1, s.heads, s.head_dim))[..., None] * delta   # the rows of S, each by its own decay
+        written = beta[..., None] * (v - jnp.sum(delta * k[..., None], axis=2))
+        delta = delta + k[..., None] * written[:, :, None, :]
+        o = jnp.sum(delta * q[..., None], axis=2).reshape(x.shape[0], -1)
+        return x + self._out(o, gate), delta, seq[:, 1:]
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2) as `kimi_linear` has it, with
+    no positional encoding (`mla_use_nope`: position comes from the KDA
+    layers): `q_proj(u)` is each head's `[q_nope | q_pe]`; `[c | k_pe] =
+    kv_a_proj(u)`, `c <- norm(c)`; each head's `[k_nope | v] = kv_b_proj(c)`
+    and its key is `[k_nope | k_pe]`, `k_pe` shared by the heads; `softmax(q
+    k^T / sqrt(nope_dim + rope_dim)) v`, causal; `o_proj`.
+
+    THE CARRY HOLDS THE LATENT: a ring of the last `max_episode_steps`
+    positions' `[c | k_pe]` after the norm (`latent + rope_dim` numbers a
+    position, where the heads' keys and values would be `heads x (nope_dim +
+    rope_dim + value_dim)`), and the stack's count. That makes two forms
+    against ONE parameter `kv_b_proj`: `__call__` (T queries a row)
+    up-projects the ring and its own T positions to keys and values once and
+    attends in query blocks as `EpisodeAttention` does; `step` (one query a
+    row) ABSORBS: `q_c = q_nope W_UK^T` (heads x latent), scores `q_c . c +
+    q_pe . k_pe` over the ring, the weighted sum of c, then `W_UV`: no array
+    of per-head keys or values over the ring."""
+
+    spec: LatentSizes
+    dtype: jnp.dtype
+
+    @staticmethod
+    def state_shapes(s):
+        return ((s.max_episode_steps, s.stored),)
+
+    def setup(self):
+        s = self.spec
+        D, H = s.hidden_size, s.heads
+        self.pre_norm = self.param("pre_norm", nn.initializers.ones, (D,))
+        self.q_proj = self.param("q_proj", _matrix, (D, H * (s.nope_dim + s.rope_dim)))
+        self.kv_a_proj = self.param("kv_a_proj", _matrix, (D, s.stored))
+        self.kv_a_norm = self.param("kv_a_norm", nn.initializers.ones, (s.latent,))
+        self.kv_b_proj = self.param("kv_b_proj", _matrix, (s.latent, H * (s.nope_dim + s.value_dim)))
+        self.o_proj = self.param("o_proj", _matrix, (H * s.value_dim, D))
+
+    def _project(self, x):
+        """x (.., D) -> (q (.., H, nope + rope), what the ring stores of these
+        positions (.., latent + rope): `[norm(c) | k_pe]`)."""
+        s = self.spec
+        h = rms_norm(x, self.pre_norm, s.eps)
+        q = _mm(h, self.q_proj, self.dtype).reshape(*x.shape[:-1], s.heads, s.nope_dim + s.rope_dim)
+        stored = _mm(h, self.kv_a_proj, self.dtype)
+        return q, jnp.concatenate([rms_norm(stored[..., :s.latent], self.kv_a_norm, s.eps), stored[..., s.latent:]], axis=-1)
+
+    def __call__(self, x, latent, count):
+        """x (B, T, D); latent (B, W, latent + rope) the ring; count (B,) int
+        positions seen so far -> (x', latent')."""
+        s = self.spec
+        B, T, _ = x.shape
+        H, W = s.heads, s.max_episode_steps
+        q, new = self._project(x)
+        memory = jnp.concatenate([latent, new], axis=1).astype(self.dtype)        # (B, W + T, latent + rope)
+        kv = _mm(memory[..., :s.latent], self.kv_b_proj, self.dtype).reshape(B, W + T, H, s.nope_dim + s.value_dim)
+        k_nope, values = kv[..., :s.nope_dim].astype(self.dtype), kv[..., s.nope_dim:].astype(self.dtype)
+        k_pe = memory[..., s.latent:]
+        remembered = jnp.arange(W)[None, :] < jnp.minimum(count, W)[:, None]      # (B, W)
+        Q = min(QUERY_BLOCK, T)
+        pad = (-T) % Q
+        blocks = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))).reshape(B, -1, Q, H, s.nope_dim + s.rope_dim)
+
+        @jax.checkpoint
+        def attend(args):
+            q_block, t0 = args                                                    # (B, Q, H, nope + rope)
+            q_block = q_block.astype(self.dtype)
+            scores = (jnp.einsum("bqhd,bshd->bhqs", q_block[..., :s.nope_dim], k_nope, preferred_element_type=F32)
+                      + jnp.einsum("bqhd,bsd->bhqs", q_block[..., s.nope_dim:], k_pe, preferred_element_type=F32)
+                      ) / math.sqrt(s.nope_dim + s.rope_dim)
+            causal = jnp.arange(T)[None, :] <= (t0 + jnp.arange(Q))[:, None]      # (Q, T)
+            seen = jnp.concatenate([jnp.broadcast_to(remembered[:, None, :], (B, Q, W)),
+                                    jnp.broadcast_to(causal[None], (B, Q, T))], axis=-1)
+            probs = jax.nn.softmax(jnp.where(seen[:, None], scores, -1e30), axis=-1)
+            return jnp.einsum("bhqs,bshd->bqhd", probs.astype(self.dtype), values, preferred_element_type=F32)
+
+        out = jax.lax.map(attend, (jnp.moveaxis(blocks, 1, 0), jnp.arange(blocks.shape[1]) * Q))
+        out = jnp.moveaxis(out, 0, 1).reshape(B, T + pad, H * s.value_dim)[:, :T]
+        return x + _mm(out, self.o_proj, self.dtype), _ring_write(latent, new, count)
+
+    def step(self, x, latent, count):
+        """One position a row, absorbed: x (B, D). What it attends to is what
+        `__call__` does at T = 1: the valid part of the ring and itself."""
+        s = self.spec
+        H, W = s.heads, s.max_episode_steps
+        q, new = self._project(x)                                                 # (B, H, nope + rope), (B, latent + rope)
+        up = self.kv_b_proj.reshape(s.latent, H, s.nope_dim + s.value_dim).astype(self.dtype)
+        q_c = jnp.einsum("bhd,chd->bhc", q[..., :s.nope_dim].astype(self.dtype), up[..., :s.nope_dim],
+                         preferred_element_type=F32)                              # (B, H, latent)
+        query = jnp.concatenate([q_c, q[..., s.nope_dim:]], axis=-1).astype(self.dtype)     # against [c | k_pe] as stored
+        scores = jnp.concatenate([
+            jnp.einsum("bhc,bwc->bhw", query, latent.astype(self.dtype), preferred_element_type=F32),
+            jnp.einsum("bhc,bc->bh", query, new.astype(self.dtype), preferred_element_type=F32)[..., None]],
+            axis=-1) / math.sqrt(s.nope_dim + s.rope_dim)                          # (B, H, W + 1)
+        seen = jnp.concatenate([jnp.arange(W)[None, :] < jnp.minimum(count, W)[:, None],
+                                jnp.ones((x.shape[0], 1), bool)], axis=-1)
+        probs = jax.nn.softmax(jnp.where(seen[:, None], scores, -1e30), axis=-1).astype(self.dtype)
+        mixed = (jnp.einsum("bhw,bwc->bhc", probs[..., :W], latent[..., :s.latent].astype(self.dtype),
+                            preferred_element_type=F32)
+                 + probs[..., W:].astype(F32) * new[:, None, :s.latent].astype(self.dtype))                   # (B, H, latent)
+        out = jnp.einsum("bhc,chd->bhd", mixed.astype(self.dtype), up[..., s.nope_dim:], preferred_element_type=F32)
+        return (x + _mm(out.reshape(x.shape[0], -1), self.o_proj, self.dtype),
+                _ring_write(latent, new[:, None], count))
+
+
+class DenseMlp(nn.Module):
+    """A layer's MLP where it is no mixture (`kimi_linear`'s first
+    `first_k_dense_replace` layers): `down(silu(gate x) * up x)`, pre-norm."""
+
+    spec: MlpSizes
+    dtype: jnp.dtype
+
+    @staticmethod
+    def state_shapes(s):
+        return ()
+
+    @nn.compact
+    def __call__(self, x):
+        s = self.spec
+        h = rms_norm(x, self.param("pre_norm", nn.initializers.ones, (s.hidden_size,)), s.eps)
+        gate = self.param("gate", _matrix, (s.hidden_size, s.width))
+        up = self.param("up", _matrix, (s.hidden_size, s.width))
+        down = self.param("down", _matrix, (s.width, s.hidden_size))
+        return x + _mm(jax.nn.silu(_mm(h, gate, self.dtype)) * _mm(h, up, self.dtype), down, self.dtype)
+
+
 KINDS = {"M": ("ssm", Mamba2Mixer), "D": ("gdn", GatedDeltaNet), "E": ("moe", ExpertMixture),
-         "*": ("attention", EpisodeAttention)}
-STATE_NAMES = {"M": ("ssm", "conv"), "D": ("delta", "conv"), "E": (), "*": ("keys", "values")}
+         "*": ("attention", EpisodeAttention), "K": ("kda", KimiDeltaAttention), "L": ("mla", LatentAttention),
+         "F": ("mlp", DenseMlp)}
+STATE_NAMES = {"M": ("ssm", "conv"), "D": ("delta", "conv"), "E": (), "*": ("keys", "values"),
+               "K": ("delta", "conv"), "L": ("latent",), "F": ()}
 
 
 def _layer(spec, dtype, kind: str, index: int):
@@ -1025,12 +1534,17 @@ def _run_layer(kind: str, layer, x, state, count):
     carry -> (x', state', counts)."""
     step = x.ndim == 2
     nothing = jnp.zeros((len(COUNTS),), F32)
-    if kind in "MD":
+    if kind in "MDK":
         x, matrix, tail = (layer.step if step else layer)(x, *state)
         return x, (matrix, tail), nothing
     if kind == "E":
         x, counts = layer(x)
         return x, (), counts
+    if kind == "F":
+        return layer(x), (), nothing
+    if kind == "L":  # its step is a form of its own (absorbed), not its sequence form at T = 1
+        x, latent = (layer.step if step else layer)(x, *state, count)
+        return x, (latent,), nothing
     seq, keys, values = layer(x[:, None] if step else x, *state, count)
     return (seq[:, 0] if step else seq), (keys, values), nothing
 
